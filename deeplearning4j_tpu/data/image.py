@@ -257,8 +257,8 @@ class ImageRecordReader(RecordReader):
         self.seed = seed
         #: decode/augment parallelism: >1 maps the per-file work over
         #: a thread pool (cv2 releases the GIL, so this scales on
-        #: multi-core hosts — the BASELINE.md ETL sizing says ~10
-        #: cores feed one v5e chip at full ResNet-50 rate), with
+        #: multi-core hosts — an earlier ETL sizing put ~10 cores
+        #: per v5e chip at full ResNet-50 rate; not re-measured), with
         #: bounded read-ahead and ORDERED yield. Augmentation rng is
         #: per-file (seeded by (seed, epoch, index)) so output is
         #: deterministic regardless of thread timing while each epoch

@@ -79,12 +79,19 @@ _register("DL4J_TPU_FUSED_NORM_MIN_F", 256, int,
           "for no bandwidth win")
 
 # -- compile subsystem (perf/: persistent XLA cache + retrace sentry) ------
-_register("DL4J_TPU_COMPILE_CACHE",
-          os.path.expanduser("~/.dl4j_tpu/compile_cache"), str,
+#: fixed in-checkout default of the persistent compile cache: the
+#: path is part of what makes a later run hit, so it holds no
+#: temporary name, pid or time (.gitignore lists it)
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+_register("DL4J_TPU_COMPILE_CACHE", DEFAULT_COMPILE_CACHE, str,
           "persistent XLA compilation cache dir shared across "
           "processes/restarts ('' | '0' | 'off' | 'none' disables; "
-          "the default applies only on accelerator platforms — CPU "
-          "processes must opt in by setting the var)")
+          "default <checkout>/.jax_cache, skipped only when "
+          "JAX_PLATFORMS names the CPU alone). A set "
+          "JAX_COMPILATION_CACHE_DIR wins over this flag and over "
+          "DL4J_TPU_COMPILE_STORE")
 _register("DL4J_TPU_COMPILE_CACHE_MIN_BYTES", -1, int,
           "min serialized-executable size eligible for the persistent "
           "cache (-1: cache everything)")
@@ -158,11 +165,14 @@ _register("DL4J_TPU_DEVTIME_EVERY", 100, int,
           "costs ~a profiler session + an xplane parse — keep sparse)")
 _register("DL4J_TPU_DEVTIME_STEPS", 3, int,
           "fit steps each capture window stays open for")
-_register("DL4J_TPU_PEAK_TFLOPS", 197.0, float,
-          "roofline compute peak in TFLOP/s (default: v5e bf16 MXU) — "
-          "the denominator of devtime's per-scope utilization")
-_register("DL4J_TPU_PEAK_HBM_GBS", 819.0, float,
-          "roofline memory peak in GB/s (default: v5e HBM)")
+_register("DL4J_TPU_PEAK_TFLOPS", None, float,
+          "explicit override of the roofline compute peak in TFLOP/s "
+          "— the denominator of devtime's per-scope utilization "
+          "(unset: the attached device_kind's entry in "
+          "environment.DEVICE_PEAKS; an unknown kind is an error)")
+_register("DL4J_TPU_PEAK_HBM_GBS", None, float,
+          "explicit override of the roofline memory peak in GB/s "
+          "(unset: DEVICE_PEAKS by device_kind)")
 
 # -- communication observatory (obs/commtime.py) ---------------------------
 _register("DL4J_TPU_COMMTIME", "", str,
@@ -176,10 +186,11 @@ _register("DL4J_TPU_COMMTIME_EVERY", 100, int,
           "comm capture-window cadence in fit iterations")
 _register("DL4J_TPU_COMMTIME_STEPS", 3, int,
           "fit steps each comm capture window stays open for")
-_register("DL4J_TPU_PEAK_ICI_GBS", 45.0, float,
-          "interconnect roofline peak in GB/s per link direction "
-          "(default: v5e ICI; the denominator of commtime's link "
-          "utilization — CPU/gloo captures are estimate-only)")
+_register("DL4J_TPU_PEAK_ICI_GBS", None, float,
+          "explicit override of the interconnect roofline peak in "
+          "GB/s per link direction — the denominator of commtime's "
+          "link utilization (unset: DEVICE_PEAKS by device_kind; "
+          "CPU/gloo captures are estimate-only)")
 
 # -- elastic serving fleet (serving/fleet.py) ------------------------------
 _register("DL4J_TPU_FLEET_SHED_BUDGET", 8, int,
@@ -207,6 +218,51 @@ _register("DL4J_TPU_UI_PORT", 9000, int,
           "training dashboard HTTP port (DL4JSystemProperties UI port)")
 _register("DL4J_TPU_EXAMPLE_FAST", False, _bool,
           "examples run in seconds-scale FAST mode (CI smoke)")
+
+
+#: per-chip peaks the rooflines divide by, keyed by
+#: ``jax.devices()[0].device_kind`` — ONE table, with its source. A
+#: kind that is not here is an error (:func:`device_peaks`), never a
+#: default: a utilization against another chip's peak is a wrong
+#: number. The ``DL4J_TPU_PEAK_*`` flags are explicit overrides.
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "tflops": 197.0,        # bf16 MXU
+        "hbm_gbs": 819.0,
+        "ici_gbs": 45.0,        # per link, per direction
+        "source": "Google Cloud documentation, 'TPU v5e': 197 bf16 "
+                  "TFLOP/s, 819 GB/s HBM; ICI 45 GB/s per link per "
+                  "direction: jax-ml.github.io/scaling-book",
+    },
+}
+
+PEAK_FLAGS = {"tflops": "DL4J_TPU_PEAK_TFLOPS",
+               "hbm_gbs": "DL4J_TPU_PEAK_HBM_GBS",
+               "ici_gbs": "DL4J_TPU_PEAK_ICI_GBS"}
+
+
+def device_peaks(*keys: str) -> Dict[str, float]:
+    """Peaks for the attached device: each of ``keys`` (``tflops``,
+    ``hbm_gbs``, ``ici_gbs``) from its explicit ``DL4J_TPU_PEAK_*``
+    override when set, else from :data:`DEVICE_PEAKS` by
+    ``device_kind``. Raises ``LookupError`` for a kind the table does
+    not know — looked up only for keys without an override, so a
+    process that names all its peaks never touches a backend."""
+    out: Dict[str, float] = {}
+    for key in keys or tuple(PEAK_FLAGS):
+        val = get_flag(PEAK_FLAGS[key])
+        if val is None:
+            import jax
+            kind = jax.devices()[0].device_kind
+            if kind not in DEVICE_PEAKS:
+                raise LookupError(
+                    f"no published peaks for device kind {kind!r} "
+                    f"(known: {sorted(DEVICE_PEAKS)}): add it to "
+                    "environment.DEVICE_PEAKS with its source, or set "
+                    f"{PEAK_FLAGS[key]} explicitly")
+            val = DEVICE_PEAKS[kind][key]
+        out[key] = float(val)
+    return out
 
 
 def get_flag(name: str) -> Any:

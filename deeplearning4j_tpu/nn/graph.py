@@ -26,7 +26,8 @@ from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.nn.config import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, layer_from_dict
 from deeplearning4j_tpu.nn.layers.core import OutputLayer, LossLayer
-from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
+from deeplearning4j_tpu.nn.layers.recurrent import (BaseRecurrentLayer,
+                                                    RnnOutputLayer)
 from deeplearning4j_tpu.nn.layers.special import FrozenLayer
 from deeplearning4j_tpu.nn.multilayer import _FUSABLE
 from deeplearning4j_tpu.nn.vertices import (GraphVertex, vertex_from_dict)
@@ -291,10 +292,11 @@ class ComputationGraph:
                     and isinstance(layer, OutputLayer)):
                 with nscope:
                     x = xs[0]
-                    if x.ndim > 2 and not hasattr(layer, "loss_rnn"):
-                        # flatten to [B, features] like the
-                        # MultiLayerNetwork fused path (the old inner
-                        # `if x.ndim == 2` made this a dead no-op)
+                    if x.ndim > 2 and not isinstance(layer,
+                                                     RnnOutputLayer):
+                        # flatten to [B, features] exactly where
+                        # OutputLayer.apply does; RnnOutputLayer.apply
+                        # keeps [B, T, F] (per-timestep head)
                         x = x.reshape(x.shape[0], -1)
                     z = x @ params[node.name]["W"]
                     if layer.has_bias:

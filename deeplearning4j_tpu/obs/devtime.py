@@ -39,7 +39,7 @@ chosen from measured hot spots, so the hot spots must first be
    name to its ``metadata={op_name="...dl4j.<scope>..."}`` scope;
    per-op FLOP/byte estimates parsed from the HLO shapes give each
    scope an achieved-vs-roofline utilization (:func:`roofline`,
-   peaks from ``DL4J_TPU_PEAK_TFLOPS`` / ``DL4J_TPU_PEAK_HBM_GBS``),
+   peaks by ``device_kind`` from ``environment.DEVICE_PEAKS``),
    and ``Compiled.cost_analysis()`` program totals provide the
    per-module cross-check (the ``modules`` section: XLA's own
    FLOPs/bytes against measured device time, independent of the
@@ -411,6 +411,30 @@ def _op_cost(kind: str, rhs: str,
 
 _CALLEE_RE = re.compile(
     r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _line_shapes(kind: str, rhs: str,
+                 shape_of: Dict[str, Tuple[str, str]]
+                 ) -> List[Tuple[str, str]]:
+    """Result shape(s) then operand shapes of one HLO op line. The
+    installed jax prints operands as bare ``%names`` (no inline
+    shapes), so operand shapes come from ``shape_of`` — the result
+    shapes of the instructions defined above (HLO is printed in
+    definition order). A line that does carry inline operand shapes
+    is read as printed."""
+    printed = _SHAPE_RE.findall(rhs)
+    start = rhs.find(kind + "(")
+    if start < 0:
+        return printed
+    result = _SHAPE_RE.findall(rhs[:start])
+    if len(printed) > len(result):      # operand shapes are on the line
+        return printed
+    args_at = start + len(kind) + 1
+    end = rhs.find(")", args_at)
+    args = rhs[args_at:end if end >= 0 else len(rhs)]
+    return result + [shape_of[n] for n in _OPERAND_RE.findall(args)
+                     if n in shape_of]
 
 
 def hlo_scope_map(hlo_text: str) -> Dict[str, Any]:
@@ -427,6 +451,7 @@ def hlo_scope_map(hlo_text: str) -> Dict[str, Any]:
     m = _HLO_MODULE_RE.search(hlo_text)
     module = m.group(1) if m else ""
     ops: Dict[str, Dict[str, Any]] = {}
+    shape_of: Dict[str, Tuple[str, str]] = {}   # op -> result shape
     comp_of: Dict[str, str] = {}       # op -> enclosing computation
     caller_of: Dict[str, str] = {}     # computation -> calling op
     current_comp = ""
@@ -449,6 +474,11 @@ def hlo_scope_map(hlo_text: str) -> Dict[str, Any]:
         else:
             head = rhs.split("(")[0].split()
             kind = head[-1] if head else ""
+        # this jax prints operands as bare `%names`: remember every
+        # instruction's result shape so _line_shapes can look them up
+        first = _SHAPE_RE.search(rhs)
+        if first and not rhs.startswith("("):
+            shape_of[op] = first.groups()
         if not kind or kind == "parameter":
             continue
         for callee in _CALLEE_RE.findall(rhs):
@@ -460,7 +490,7 @@ def hlo_scope_map(hlo_text: str) -> Dict[str, Any]:
             hits = _SCOPE_RE.findall(nm.group(1))
             scope_ = hits[-1] if hits else None
             backward = "transpose(" in nm.group(1)
-        shapes = _SHAPE_RE.findall(rhs)
+        shapes = _line_shapes(kind, rhs, shape_of)
         flops, bytes_ = _op_cost(kind, rhs, shapes)
         comp_of[op] = current_comp
         ops[op] = {"scope": scope_, "backward": backward,
@@ -529,13 +559,14 @@ def executable_maps(executables: Iterable[Any]) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 def peaks_from_env() -> Tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) — ``DL4J_TPU_PEAK_TFLOPS`` /
-    ``DL4J_TPU_PEAK_HBM_GBS``, defaulting to the v5e chip (197 bf16
-    TFLOP/s, 819 GB/s). On a CPU smoke run the utilization numbers are
-    wiring-validation only (reports carry the peaks used)."""
+    """(peak FLOP/s, peak bytes/s) of the attached device
+    (``environment.device_peaks``: the ``device_kind`` table, or the
+    explicit ``DL4J_TPU_PEAK_TFLOPS`` / ``DL4J_TPU_PEAK_HBM_GBS``
+    overrides). A device the table does not know is an error. Reports
+    carry the peaks used."""
     from deeplearning4j_tpu import environment
-    return (float(environment.get_flag("DL4J_TPU_PEAK_TFLOPS")) * 1e12,
-            float(environment.get_flag("DL4J_TPU_PEAK_HBM_GBS")) * 1e9)
+    pk = environment.device_peaks("tflops", "hbm_gbs")
+    return pk["tflops"] * 1e12, pk["hbm_gbs"] * 1e9
 
 
 def roofline(flops: float, bytes_: float, seconds: float,
@@ -595,13 +626,28 @@ _COLLECTIVE_RE = re.compile(
     r"all-to-all)(?:-start)?(?:\.\d+)?$")
 
 
+#: the installed jax names an HLO instruction after the PRIMITIVE that
+#: made it (``reduce_scatter.31``, ``psum.7``), not after its opcode —
+#: an event name is all an offline capture (no executable) has
+_PRIMITIVE_KIND = {"psum": "all-reduce", "pmax": "all-reduce",
+                   "pmin": "all-reduce", "all_gather": "all-gather",
+                   "reduce_scatter": "reduce-scatter",
+                   "ppermute": "collective-permute",
+                   "all_to_all": "all-to-all"}
+_PRIMITIVE_RE = re.compile(
+    r"^(" + "|".join(_PRIMITIVE_KIND) + r")(?:-start)?(?:\.\d+)?$")
+
+
 def collective_kind(op_or_kind: str) -> Optional[str]:
     """Base collective kind of an HLO op name/opcode, or None. The
     async ``-start`` form classifies (its device event carries the
     transfer duration); ``-done`` does not (a sync point — counting
     both would double-book every async collective)."""
     m = _COLLECTIVE_RE.match(op_or_kind)
-    return m.group(1) if m else None
+    if m:
+        return m.group(1)
+    m = _PRIMITIVE_RE.match(op_or_kind)
+    return _PRIMITIVE_KIND[m.group(1)] if m else None
 
 
 def attribute(paths: Iterable[str],
@@ -648,7 +694,10 @@ def attribute(paths: Iterable[str],
             if sc is None and "op_name" in ev:
                 hits = _SCOPE_RE.findall(ev["op_name"])
                 sc = hits[-1] if hits else None
-            key = sc if sc is not None else f"op:{_op_class(ev['op'])}"
+            # unattributed ops bucket by class; collectives by their
+            # HLO kind whatever the instruction was named after
+            key = sc if sc is not None else (
+                f"op:{collective_kind(ev['op']) or _op_class(ev['op'])}")
             e = scopes.get(key)
             if e is None:
                 e = scopes[key] = {

@@ -508,54 +508,3 @@ def fused_kernels_report(rows: int = 2048, feats: int = 512,
         else:
             os.environ["DL4J_TPU_KERNEL_FORCE"] = prev
     return out
-
-
-def subprocess_report(timeout: int = 300):
-    """Run :func:`fused_kernels_report` in a fresh forced-CPU process
-    (the ``zero.subprocess_report`` pattern): callable from bench runs
-    without touching their backend."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("DL4J_TPU_KERNEL_FORCE", None)
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "deeplearning4j_tpu.ops.fused_norms"],
-            capture_output=True, text=True, timeout=timeout, env=env,
-            cwd=os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))))
-    except (subprocess.TimeoutExpired, OSError) as e:
-        return {"skipped": True, "reason": f"fused-kernels child: {e}"}
-    parsed = None
-    for line in proc.stdout.splitlines():
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                parsed = json.loads(line)
-            except ValueError:
-                pass
-    if proc.returncode != 0 or parsed is None:
-        tail = (proc.stderr or proc.stdout or "").strip()
-        return {"skipped": True,
-                "reason": "fused-kernels child rc=%d: %s"
-                          % (proc.returncode, tail.splitlines()[-1]
-                             if tail else "no output")}
-    return parsed
-
-
-def _main() -> None:
-    import json
-
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except RuntimeError:
-        pass
-    print(json.dumps(fused_kernels_report()))
-
-
-if __name__ == "__main__":
-    _main()

@@ -47,35 +47,23 @@ def _interpret() -> bool:
 def _vma(*xs) -> frozenset:
     """Union of the inputs' varying-manual-axes. Outside ``shard_map``
     this is empty; inside, ``pallas_call`` out_shapes must declare it
-    (check_vma) — outputs vary over every axis an input varies over.
-    Old jax (0.4.x) has neither ``jax.typeof`` nor vma tracking: there
-    the union is always empty and the vma plumbing degrades to no-ops,
-    which is exactly right — check_vma does not exist on that runtime."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
+    (check_vma) — outputs vary over every axis an input varies over."""
     out: frozenset = frozenset()
     for x in xs:
         if x is not None:
-            out = out | getattr(typeof(x), "vma", frozenset())
+            out = out | jax.typeof(x).vma
     return out
 
 
 def _sds(shape, dtype, vma: frozenset):
-    """``ShapeDtypeStruct`` carrying vma only when non-empty (the kwarg
-    does not exist on old jax, where vma is always empty anyway)."""
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _align_vma(x, vma: frozenset):
     """Broadcast a replicated operand onto varying manual axes so every
     kernel operand carries the same vma (mixed vmas trip check_vma
     inside pallas interpret mode)."""
-    if not vma:
-        return x                    # incl. old jax: vma never tracked
-    missing = vma - getattr(jax.typeof(x), "vma", frozenset())
+    missing = vma - jax.typeof(x).vma
     return lax.pcast(x, tuple(missing), to="varying") if missing else x
 
 
@@ -727,8 +715,8 @@ def flash_block_fwd(q, k, v, km=None, offs=None, causal: bool = False,
     materialised broadcast); km: [B·H/groups, Tk]; offs: int32 [2]
     dynamic global (q, k) offsets for causal. Default blocks follow
     the measured v5e sweep — (1024, 512) up to 4k-key blocks (the
-    usual ring regime; 1.44x vs the einsum pair at T/N=4096, see
-    BASELINE.md), block_k 1024 beyond."""
+    usual ring regime; 1.44x vs the einsum pair at T/N=4096 in that
+    sweep — not measured on today's code), block_k 1024 beyond."""
     from deeplearning4j_tpu.obs import devtime
     block_q, block_k = _ring_block_defaults(block_q, block_k,
                                             k.shape[1])

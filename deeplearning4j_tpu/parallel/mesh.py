@@ -56,23 +56,14 @@ def batch_sharded(mesh: Mesh, axis: str = "data") -> NamedSharding:
 def enable_cpu_collectives() -> bool:
     """Multi-process collectives on the CPU backend need the gloo
     transport (the default XLA:CPU backend refuses cross-process
-    computations outright). Must run before backends initialize; a jax
-    without the option (or a non-CPU platform) is a no-op. Returns
-    whether the option was applied."""
-    import os
-    platforms = str(os.environ.get("JAX_PLATFORMS", "")).lower()
-    try:
-        if jax.config.jax_platforms and \
-                "cpu" not in str(jax.config.jax_platforms).lower():
-            return False
-    except AttributeError:
-        if platforms and "cpu" not in platforms:
-            return False
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except Exception:               # pragma: no cover - old/new jax
+    computations outright). Must run before backends initialize; a
+    process whose named platforms leave out the CPU is a no-op.
+    Returns whether the option was applied."""
+    named = str(jax.config.jax_platforms or "").lower()
+    if named and "cpu" not in named:
         return False
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    return True
 
 
 def initialize_distributed(coordinator_address: Optional[str] = None,

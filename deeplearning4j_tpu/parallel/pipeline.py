@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro, *,

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from deeplearning4j_tpu.ops.pallas_kernels import (
     flash_block_fwd, flash_block_bwd)

@@ -56,8 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.parallel import _compat
-from deeplearning4j_tpu.parallel._compat import shard_map
+from jax import shard_map
 from deeplearning4j_tpu.parallel.zero import (FlatShardLayout,
                                               per_device_bytes)
 
@@ -276,11 +275,6 @@ class ParallelWrapper:
         return self._shard_layout
 
     def _check_sharded_update_supported(self):
-        if not _compat.supports_psum_scatter():
-            raise RuntimeError(
-                "sharded_update needs lax.psum_scatter/all_gather, "
-                "which this jax runtime cannot express — train with "
-                "sharded_update=False")
         gn = getattr(self.net.conf, "gradient_normalization", None)
         if gn and str(gn).lower() in _CROSS_LEAF_GRAD_NORMS:
             raise ValueError(

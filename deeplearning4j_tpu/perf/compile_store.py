@@ -50,27 +50,22 @@ CORRUPT_DIR = "corrupt"
 def default_jaxlib() -> str:
     """The jaxlib wheel version — the binary whose serialized
     executables the fence isolates."""
-    try:
-        import jaxlib
-        return str(getattr(jaxlib, "__version__", "") or "unknown")
-    except Exception:
-        try:
-            import jax
-            return str(jax.__version__)
-        except Exception:
-            return "unknown"
+    import jaxlib
+    return str(jaxlib.__version__)
 
 
 def default_topology() -> str:
-    """Configured platform string (config/env only — never
-    ``jax.devices()``, which would initialize a backend here)."""
-    try:
-        import jax
-        plats = (jax.config.jax_platforms
-                 or os.environ.get("JAX_PLATFORMS", ""))
-    except Exception:
-        plats = os.environ.get("JAX_PLATFORMS", "")
-    names = [p.strip() for p in str(plats).split(",") if p.strip()]
+    """The NAMED platform string (``jax_platforms`` config, which reads
+    ``JAX_PLATFORMS``) — never ``jax.devices()``, which would
+    initialize a backend here. A process whose TPU was auto-detected
+    names nothing and fences as ``"auto"``: that fence separates it
+    from CPU-named processes and by jaxlib, but it is PER MACHINE
+    TYPE — share one store root only among hosts with the same chips
+    (JAX's own cache key still carries the device kind, so a foreign
+    entry is a miss, never a wrong executable)."""
+    import jax
+    names = [p.strip() for p in
+             str(jax.config.jax_platforms or "").split(",") if p.strip()]
     return "-".join(names) if names else "auto"
 
 

@@ -24,11 +24,12 @@ Two load models (the standard serving-bench dichotomy):
   maxed, no client-thread scheduling noise; later requests' TTFT
   includes their real queue wait).
 
-``smoke_report()`` is the CPU wiring config consumed by ``bench.py``'s
-``serving`` section and ``tools/perf_dossier.py``'s
-``continuous_batching`` row (via :func:`subprocess_report`, the
-forced-CPU-subprocess idiom of ``parallel/zero.py``);
-``tools/serving_trace.py`` is the shell CLI over :func:`run_trace`.
+``smoke_report()`` is the small wiring config (``tools/serving_trace.py
+--smoke``; every report names the ``platform`` it ran on).
+:func:`subprocess_report` runs it in a fresh CPU process for the
+tier-1 acceptance test — a CPU number, never written beside a device
+metric. ``tools/serving_trace.py`` is the shell CLI over
+:func:`run_trace`.
 """
 from __future__ import annotations
 
@@ -251,9 +252,14 @@ def baseline_tokens_per_sec(model, net, requests,
     return tokens / (time.perf_counter() - t0)
 
 
+def _platform() -> str:
+    import jax
+    return jax.devices()[0].platform
+
+
 def smoke_report(n_requests: int = 32, max_new: int = 32,
                  max_slots: int = 16) -> Dict[str, Any]:
-    """CPU smoke config: a small weight-read-bound LM (h=256 — decode
+    """Smoke config: a small weight-read-bound LM (h=256 — decode
     is weight-bound there even on CPU, so in-flight batching has a
     real read to amortize, exactly the regime TPU serving lives in),
     closed-loop multi-tenant trace, continuous vs request-at-a-time
@@ -288,7 +294,8 @@ def smoke_report(n_requests: int = 32, max_new: int = 32,
     gw.shutdown()
     cont_tps = stats["tokens_per_sec"] or 0.0
     return {
-        "model": "causal-LM v512 L4 h256 (CPU smoke)",
+        "model": "causal-LM v512 L4 h256 (smoke)",
+        "platform": _platform(),
         "n_requests": n_requests,
         "max_new": max_new,
         "max_slots": max_slots,
@@ -366,7 +373,8 @@ def shared_prefix_report(n_requests: int = 32, prefix_len: int = 216,
     b_ttft, s_ttft = base["ttft_p50_ms"], both["ttft_p50_ms"]
     b_tps, s_tps = base["tokens_per_sec"], both["tokens_per_sec"]
     return {
-        "model": "causal-LM v512 L4 h256 (CPU smoke)",
+        "model": "causal-LM v512 L4 h256 (smoke)",
+        "platform": _platform(),
         "n_requests": n_requests,
         "prefix_len": prefix_len,
         "max_new": max_new,
@@ -392,54 +400,41 @@ def shared_prefix_report(n_requests: int = 32, prefix_len: int = 216,
 def subprocess_report(timeout: int = 420, report: str = "smoke"
                       ) -> Dict[str, Any]:
     """Run :func:`smoke_report` (or :func:`shared_prefix_report` with
-    ``report="shared-prefix"``) in a fresh forced-CPU process (the
-    ``parallel/zero.py`` idiom): callable from bench/dossier runs
-    without touching their backend; any failure returns a structured
-    skip instead of sinking the headline metric."""
+    ``report="shared-prefix"``) in a fresh process on the CPU backend
+    — the tier-1 acceptance test's harness (one device, outside the
+    suite's 8-virtual-device partitioning). The report's ``platform``
+    says ``"cpu"``: it is a CPU measurement and is never merged into
+    a device run's record. A child that fails raises."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     # a host partitioned into virtual devices (the SPMD test suite's
     # --xla_force_host_platform_device_count=8) throttles the
     # single-device serving loop ~30%; the smoke row is a ONE-device
     # measurement, so strip the forcing for the child
-    flags = " ".join(
+    env["XLA_FLAGS"] = " ".join(
         f for f in env.get("XLA_FLAGS", "").split()
         if not f.startswith("--xla_force_host_platform_device_count"))
-    env["XLA_FLAGS"] = flags
     argv = [sys.executable, "-m", "deeplearning4j_tpu.serving.loadgen"]
     if report == "shared-prefix":
         argv.append("--shared-prefix")
     elif report != "smoke":
-        return {"skipped": True,
-                "reason": f"unknown report {report!r}"}
-    try:
-        proc = subprocess.run(
-            argv,
-            capture_output=True, text=True, timeout=timeout, env=env,
-            cwd=os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))))
-    except (subprocess.TimeoutExpired, OSError) as e:
-        return {"skipped": True, "reason": f"serving child: {e}"}
-    parsed = None
-    for line in proc.stdout.splitlines():
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                parsed = json.loads(line)
-            except ValueError:
-                pass
-    if proc.returncode != 0 or parsed is None:
-        tail = (proc.stderr or proc.stdout or "").strip()
-        return {"skipped": True,
-                "reason": "serving child rc=%d: %s"
-                          % (proc.returncode, tail.splitlines()[-1]
-                             if tail else "no output")}
-    return parsed
+        raise ValueError(f"unknown report {report!r}")
+    proc = subprocess.run(
+        argv, capture_output=True, text=True, timeout=timeout, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))))
+    lines = [ln for ln in proc.stdout.splitlines()
+             if ln.strip().startswith("{")]
+    if proc.returncode != 0 or not lines:
+        raise RuntimeError(
+            f"serving report child rc={proc.returncode}: "
+            f"{(proc.stderr or proc.stdout)[-2000:]}")
+    rep = json.loads(lines[-1])
+    assert rep["platform"] == "cpu", rep["platform"]
+    return rep
 
 
 def _main() -> None:
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     if "--shared-prefix" in sys.argv[1:]:
         print(json.dumps(shared_prefix_report()), flush=True)
     else:

@@ -24,8 +24,6 @@ FAST = os.environ.get("DL4J_TPU_EXAMPLE_FAST") == "1"
 def main():
     import jax
 
-    if os.environ.get("DL4J_TPU_EXAMPLE_TPU") != "1":
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from deeplearning4j_tpu.zoo import GPTNano

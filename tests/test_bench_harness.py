@@ -1,7 +1,9 @@
-"""The driver's round-end artifacts must not rot: bench.py's headline
-JSON line and the perf-dossier smoke path are executed as real
-subprocesses (the round-4 device-loop signature change broke bench.py
-while the whole suite stayed green — this is the regression fence).
+"""The measurement entry points must not rot, and must not lie: each is
+ONE process that needs the chip. Without a TPU they exit non-zero and
+print no result — a CPU number is never written under a device metric
+(and no structured "skip" with exit code 0). The perf-dossier smoke
+path (tiny shapes, any backend, no MFU claim) is still executed as a
+real subprocess in the slow lane.
 """
 import json
 import os
@@ -23,17 +25,18 @@ def _run(args, timeout=600):
         capture_output=True, text=True)
 
 
-@pytest.mark.slow
-def test_bench_prints_one_json_line():
-    r = _run(["bench.py"])
-    assert r.returncode == 0, r.stderr[-2000:]
-    lines = [l for l in r.stdout.splitlines() if l.startswith("{")]
-    assert len(lines) == 1, r.stdout[-2000:]
-    payload = json.loads(lines[0])
-    assert payload["metric"] == "resnet50_train_images_per_sec_per_chip"
-    # CPU run must still produce a NUMBER (the skip path is for an
-    # unreachable TPU backend, not for running on CPU)
-    assert payload.get("value") and payload["value"] > 0, payload
+@pytest.mark.parametrize("script", [
+    "bench.py", "tools/perf_dossier.py", "chip_smoke.py",
+    "tools/flash_crossover.py"])
+def test_device_entry_point_refuses_the_cpu(script):
+    """JAX_PLATFORMS=cpu: non-zero exit, the reason on stderr, and no
+    result line (no JSON, no ``"ok": true``, no ``"skipped"``)."""
+    r = _run([script], timeout=120)
+    assert r.returncode != 0, r.stdout[-2000:]
+    assert "TPU" in r.stderr, r.stderr[-2000:]
+    assert '"ok"' not in r.stdout and '"skipped"' not in r.stdout
+    assert not [l for l in r.stdout.splitlines()
+                if l.startswith("{") and '"metric"' in l], r.stdout
 
 
 @pytest.mark.slow

@@ -28,7 +28,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from conftest import requires_modern_jax  # noqa: E402
 from deeplearning4j_tpu.nn import (MultiLayerNetwork,  # noqa: E402
                                    NeuralNetConfiguration)
 from deeplearning4j_tpu.nn.config import InputType  # noqa: E402
@@ -373,7 +372,6 @@ def test_ep_moe_rings_span_expert_axis():
         sum(r["wire_bytes"] for r in recs))
 
 
-@requires_modern_jax
 @needs_mesh
 def test_sp_ring_attention_permute_trips():
     from deeplearning4j_tpu.parallel.ring_attention import \

@@ -246,13 +246,89 @@ def test_cache_stats_shape():
             "persistent_hits", "persistent_misses"} <= stats.keys()
 
 
-def test_default_cache_gated_off_on_cpu(monkeypatch):
-    """Without the explicit env var, a CPU-pinned process (this one —
-    conftest forces JAX_PLATFORMS=cpu) must NOT get the default cache
-    dir: jaxlib 0.4.x can segfault deserializing XLA:CPU executables."""
+_DIR_CHILD = r"""
+import json
+import jax
+from jax._src import xla_bridge
+updates = []
+_update = jax.config.update
+def spy(name, val):
+    updates.append(name)
+    return _update(name, val)
+jax.config.update = spy
+from deeplearning4j_tpu.perf import compile_cache
+print(json.dumps({
+    "dir": compile_cache.cache_dir(),
+    "stats_dir": compile_cache.cache_stats()["dir"],
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "dir_updates": updates.count("jax_compilation_cache_dir"),
+    "floor_updates": updates.count(
+        "jax_persistent_cache_min_compile_time_secs"),
+    "backend_initialized": xla_bridge.backends_are_initialized(),
+}))
+"""
+
+_CACHE_VARS = ("JAX_COMPILATION_CACHE_DIR", "DL4J_TPU_COMPILE_CACHE",
+               "DL4J_TPU_COMPILE_STORE", "JAX_PLATFORMS")
+
+
+def _cache_dir_child(**env_set):
+    """What a FRESH process that only imports the package ends up
+    with, under exactly the given cache/platform variables."""
+    env = {k: v for k, v in os.environ.items() if k not in _CACHE_VARS}
+    env.update(env_set)
+    r = subprocess.run([sys.executable, "-c", _DIR_CHILD], cwd=REPO,
+                       env=env, timeout=120, capture_output=True,
+                       text=True)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    # configured at import from config alone — no backend touched
+    assert out["backend_initialized"] is False
+    assert out["dir"] == out["stats_dir"]
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "jax_env_dir_wins", "auto_detected_default", "cpu_named_default_off",
+    "cpu_named_explicit_dir"])
+def test_cache_dir_resolution(case, tmp_path):
+    """Where the persistent cache lives (perf/compile_cache.py): a set
+    JAX_COMPILATION_CACHE_DIR is used as is and no other directory is
+    ever set from code; otherwise the default is the fixed in-checkout
+    path, identical across fresh processes; a process NAMED as CPU-only
+    skips only that default (jaxlib 0.9.0's XLA:CPU AOT loader logs a
+    machine-mismatch error per cache hit)."""
+    jax_dir, flag_dir = str(tmp_path / "jax"), str(tmp_path / "flag")
+    if case == "jax_env_dir_wins":
+        out = _cache_dir_child(
+            JAX_COMPILATION_CACHE_DIR=jax_dir, JAX_PLATFORMS="cpu",
+            DL4J_TPU_COMPILE_CACHE=flag_dir,
+            DL4J_TPU_COMPILE_STORE=str(tmp_path / "store"))
+        assert out["dir"] == out["jax_dir"] == jax_dir
+        assert out["dir_updates"] == 0
+        assert out["floor_updates"] == 1    # floors still installed
+        assert not os.path.exists(flag_dir)
+        assert not os.path.exists(tmp_path / "store")
+    elif case == "auto_detected_default":
+        first, second = _cache_dir_child(), _cache_dir_child()
+        assert first["dir"] == str(REPO / ".jax_cache")
+        assert first["dir"] == first["jax_dir"]
+        assert second == first              # a fixed path: no pid/time
+    elif case == "cpu_named_default_off":
+        out = _cache_dir_child(JAX_PLATFORMS="cpu")
+        assert out["dir"] is None and out["dir_updates"] == 0
+    else:
+        out = _cache_dir_child(JAX_PLATFORMS="cpu",
+                               DL4J_TPU_COMPILE_CACHE=flag_dir)
+        assert out["dir"] == out["jax_dir"] == flag_dir
+        assert out["dir_updates"] == 1
+
+
+def test_default_cache_off_in_this_cpu_named_process(monkeypatch):
+    """In-process view of the same gate (conftest names the CPU)."""
     monkeypatch.delenv("DL4J_TPU_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert compile_cache.configure() is None
-    # explicit opt-in still wins on CPU
     monkeypatch.setenv("DL4J_TPU_COMPILE_CACHE", "off")
     assert compile_cache.configure() is None
     compile_cache.configure_from_env()

@@ -218,11 +218,12 @@ def test_compile_store_routes_persistent_cache(tmp_path):
 
 def test_compile_store_off_keeps_cpu_cache_disabled(tmp_path):
     """Without the store (and without DL4J_TPU_COMPILE_CACHE), a plain
-    CPU process keeps the persistent cache off — the jaxlib-0.4.x
-    deserialization segfault gate stays intact."""
+    CPU-named process keeps the persistent cache off (the default
+    dir is skipped there — see compile_cache.configure)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("DL4J_TPU_COMPILE_STORE", None)
     env.pop("DL4J_TPU_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run(
         [sys.executable, "-c", _ROUTING_CHILD % {"repo": str(REPO)}],
         capture_output=True, text=True, timeout=120, env=env)

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_modern_jax
-
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
 
@@ -49,7 +47,6 @@ def _run_steps(net, x, y, n=2):
     return losses, params
 
 @pytest.mark.parametrize("sp_mode", ["ring", "zigzag_ring"])
-@requires_modern_jax
 def test_composed_dp_sp_tp_matches_single_device(sp_mode):
     """Two train steps on the composed mesh == two single-device
     steps: same losses, same updated params (every leaf)."""
@@ -103,7 +100,6 @@ def test_composed_params_actually_sharded():
     assert found_col and found_row
 
 
-@requires_modern_jax
 def test_composed_gqa_matches_single_device():
     """Composed mesh with grouped-query attention: kv heads (2) shard
     over 'tensor' alongside the query heads (4) — the ring carries the

@@ -22,14 +22,9 @@ from deeplearning4j_tpu.nn.config import InputType
 from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
 from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.obs import metrics
-from deeplearning4j_tpu.parallel._compat import supports_psum_scatter
 from deeplearning4j_tpu.resilience import elastic, faults
 
 REPO = Path(__file__).resolve().parents[1]
-
-needs_scatter = pytest.mark.skipif(
-    not supports_psum_scatter(),
-    reason="jax runtime has no psum_scatter/all_gather")
 
 
 @pytest.fixture(autouse=True)
@@ -327,7 +322,6 @@ def test_mp_harness_kill_after(tmp_path):
 # PR 5 x PR 3 interplay: SIGTERM under a ZeRO wrapper -> SHARDED publish
 # =========================================================================
 
-@needs_scatter
 def test_preempt_sharded_wrapper_publishes_sharded_and_resumes_bitexact(
         tmp_path):
     """SIGTERM mid-fit with sharded_update=True publishes through
@@ -395,7 +389,6 @@ def test_preempt_sharded_wrapper_publishes_sharded_and_resumes_bitexact(
 @pytest.mark.slow
 @pytest.mark.skipif(os.environ.get("DL4J_TPU_SKIP_MP") == "1",
                     reason="multi-process test disabled")
-@needs_scatter
 def test_elastic_drill_sigkill_reform_reshard_baseline():
     """ISSUE 7 acceptance: SIGKILL one of three hosts mid-epoch →
     survivors raise out of the dead collective within the lease
@@ -430,7 +423,6 @@ def test_elastic_drill_sigkill_reform_reshard_baseline():
 @pytest.mark.slow
 @pytest.mark.skipif(os.environ.get("DL4J_TPU_SKIP_MP") == "1",
                     reason="multi-process test disabled")
-@needs_scatter
 def test_elastic_host_preempt_named_plan_drill():
     """DL4J_TPU_FAULT_PLAN=host-preempt on one host of a live fleet:
     the victim gets SIGTERM at its nth elastic step, leaves

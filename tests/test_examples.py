@@ -7,8 +7,6 @@ from pathlib import Path
 import jax
 import pytest
 
-from conftest import requires_modern_jax as ring
-
 EXAMPLES = Path(__file__).parent.parent / "examples"
 
 
@@ -16,12 +14,12 @@ EXAMPLES = Path(__file__).parent.parent / "examples"
 @pytest.mark.parametrize("name", [
     "lenet_mnist", "char_rnn_textgen", "bert_finetune",
     "distributed_data_parallel", "samediff_autodiff",
-    pytest.param("parallelism_modes", marks=ring),
+    "parallelism_modes",
     "hyperparameter_search", "transfer_learning",
     "model_serving", "pretrained_zoo",
-    pytest.param("long_context_attention", marks=ring),
+    "long_context_attention",
     "sharded_serving",
-    pytest.param("causal_lm", marks=ring),
+    "causal_lm",
     "bert_pretrain_mlm",
 ])
 def test_example_runs(name, monkeypatch, capsys):

@@ -492,11 +492,10 @@ def test_measure_publish_overhead_scrubs_probe_counters():
 # tpu_watch --fleet-dir: table + skew sparkline + alarms
 # =========================================================================
 
-def test_tpu_watch_fleet_dir_renders_view(tmp_path, monkeypatch):
+def test_tpu_watch_fleet_dir_renders_view(tmp_path, capsys):
     from deeplearning4j_tpu.obs import numerics
     sys.path.insert(0, str(REPO / "tools"))
     import tpu_watch
-    monkeypatch.setattr(tpu_watch, "LOG", tmp_path / "log.jsonl")
     eldir = tmp_path / "el"
     base = time.time()
     nf = numerics.NONFINITE.labels(layer="dense_0", kind="gradients")
@@ -517,7 +516,7 @@ def test_tpu_watch_fleet_dir_renders_view(tmp_path, monkeypatch):
             numerics.NONFINITE._children.pop(
                 ("dense_0", "gradients"), None)
     recs = [json.loads(ln) for ln in
-            (tmp_path / "log.jsonl").read_text().splitlines()]
+            capsys.readouterr().out.splitlines() if ln.startswith("{")]
     (rec,) = [r for r in recs if r["event"] == "fleet"]
     assert set(rec["hosts"]) == {"h0", "h1"}
     assert rec["hosts"]["h0"]["step"] == 9

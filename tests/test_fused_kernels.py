@@ -300,10 +300,6 @@ def test_gather_overlap_trajectory_matches_sharded():
     vs-replicated comparison, the two programs share the scatter/
     update/gather building blocks)."""
     from deeplearning4j_tpu.parallel import ParallelWrapper
-    from deeplearning4j_tpu.parallel._compat import \
-        supports_psum_scatter
-    if not supports_psum_scatter():
-        pytest.skip("no lax.psum_scatter")
 
     def drive(**kw):
         net = _mlp_net()
@@ -326,10 +322,6 @@ def test_gather_overlap_respects_params_reassignment():
     (review fix: they previously kept training the pre-assignment
     weights)."""
     from deeplearning4j_tpu.parallel import ParallelWrapper
-    from deeplearning4j_tpu.parallel._compat import \
-        supports_psum_scatter
-    if not supports_psum_scatter():
-        pytest.skip("no lax.psum_scatter")
 
     def drive(reassign):
         net = _mlp_net()
@@ -359,12 +351,8 @@ def test_gather_overlap_warmup_zero_retraces():
     first real batches dispatch to the warmed executables (aot_hits)
     with zero new traces under the strict sentry."""
     from deeplearning4j_tpu.parallel import ParallelWrapper
-    from deeplearning4j_tpu.parallel._compat import \
-        supports_psum_scatter
     from deeplearning4j_tpu.perf import sentry
     from deeplearning4j_tpu.perf.warmup import WarmupSpec
-    if not supports_psum_scatter():
-        pytest.skip("no lax.psum_scatter")
 
     net = _mlp_net(seed=11)
     net.monitor_numerics(every=2)

@@ -7,8 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_modern_jax
-
 from deeplearning4j_tpu.nn.layers.attention import (
     MultiHeadAttention, repeat_kv_heads, rotary_embedding,
     scaled_dot_attention)
@@ -152,7 +150,6 @@ def test_generate_uses_current_params(toy_lm):
     net.params = old                           # restore for other tests
 
 
-@requires_modern_jax
 def test_ring_attention_gqa_matches_dense():
     """GQA through the distributed ring: kv with fewer heads must
     equal dense attention with kv heads broadcast (only the small kv
@@ -179,7 +176,6 @@ def test_ring_attention_gqa_matches_dense():
                                rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_lm_trains_sequence_parallel():
     """The flagship long-context combination: the causal LM trains
     with ring sequence parallelism purely via the layer API."""
@@ -622,7 +618,7 @@ def test_decode_params_cache_invalidation():
 
 def test_head_geometry_quality_parity():
     """The round-5 flagship geometry change (6×d=128 instead of GPT-2's
-    12×d=64, BASELINE.md round-5 §3) is a hardware-mapping knob, not a
+    12×d=64) is a hardware-mapping knob, not a
     capacity change: at fixed hidden width, splitting the same
     projection matrices into fewer/wider vs more/narrower heads keeps
     the param count IDENTICAL and converges equivalently. Train the

@@ -10,8 +10,6 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from deeplearning4j_tpu.data import DataSet, ListDataSetIterator
 from deeplearning4j_tpu.nn import MultiLayerNetwork, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.config import InputType
@@ -22,11 +20,8 @@ from deeplearning4j_tpu.parallel import (
     ShardedDataSetIterator, SparkDl4jMultiLayer,
 )
 
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8,
-                       reason="needs 8 virtual devices"),
-    requires_shard_map,
-]
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
+                                reason="needs 8 virtual devices")
 
 
 def _net(seed=42):

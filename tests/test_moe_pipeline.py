@@ -7,16 +7,11 @@ import numpy as np
 import optax
 import pytest
 
-from conftest import requires_shard_map
-
 from deeplearning4j_tpu.parallel import make_mesh
 from deeplearning4j_tpu.parallel.moe import MixtureOfExperts, top_k_gating
 from deeplearning4j_tpu.parallel.pipeline import (
     pipeline_apply, make_mlp_stage, pipeline_train_step)
 
-
-
-pytestmark = requires_shard_map
 
 class TestGating:
     def test_dispatch_combine_shapes_and_capacity(self):

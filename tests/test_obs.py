@@ -331,11 +331,16 @@ def test_heartbeat_retire_clears_finished_worker():
     health.retire("never-registered")        # idempotent
 
 
-def test_tpu_watch_captures_healthz_503_body(tmp_path, monkeypatch):
+def _watch_lines(capsys):
+    """The watcher prints one JSON line per sample."""
+    return [json.loads(ln) for ln in
+            capsys.readouterr().out.splitlines() if ln.startswith("{")]
+
+
+def test_tpu_watch_captures_healthz_503_body(capsys):
     import sys
     sys.path.insert(0, "tools")
     import tpu_watch
-    monkeypatch.setattr(tpu_watch, "LOG", tmp_path / "log.jsonl")
     health.reset()
     health.heartbeat("w-stuck", t=obs.now() - 1e4)
     srv = metrics.MetricsServer(port=0).start()
@@ -345,20 +350,18 @@ def test_tpu_watch_captures_healthz_503_body(tmp_path, monkeypatch):
     finally:
         srv.stop()
         health.reset()
-    recs = [json.loads(ln) for ln in
-            (tmp_path / "log.jsonl").read_text().splitlines()]
-    (rec,) = [r for r in recs if r["event"] == "healthz"]
+    (rec,) = [r for r in _watch_lines(capsys)
+              if r["event"] == "healthz"]
     # the 503 body — naming the stale worker — must be captured, not
     # swallowed as an HTTPError
     assert rec["status"] == 503
     assert rec["body"]["stale_workers"] == ["w-stuck"]
 
 
-def test_tpu_watch_trace_tail_is_incremental(tmp_path, monkeypatch):
+def test_tpu_watch_trace_tail_is_incremental(tmp_path, capsys):
     import sys
     sys.path.insert(0, "tools")
     import tpu_watch
-    monkeypatch.setattr(tpu_watch, "LOG", tmp_path / "log.jsonl")
     tpu_watch._TRACE_POS.clear()
     tpu_watch._SPAN_TOTALS.clear()
     path = tmp_path / "t.jsonl"
@@ -375,8 +378,7 @@ def test_tpu_watch_trace_tail_is_incremental(tmp_path, monkeypatch):
     trace.disable()
     assert off2 > off1 > 0                    # only the tail is re-read
     assert tpu_watch._SPAN_TOTALS["a"] == pytest.approx(3000, rel=0.01)
-    recs = [json.loads(ln) for ln in
-            (tmp_path / "log.jsonl").read_text().splitlines()]
+    recs = _watch_lines(capsys)
     assert recs[-1]["top_spans_ms"]["a"] == pytest.approx(3.0,
                                                           rel=0.01)
 

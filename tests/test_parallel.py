@@ -9,9 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import (requires_modern_jax,
-                      requires_shard_map)
-
 from deeplearning4j_tpu.data import DataSet, ListDataSetIterator
 from deeplearning4j_tpu.nn import MultiLayerNetwork, \
     NeuralNetConfiguration
@@ -26,11 +23,8 @@ from deeplearning4j_tpu.parallel import (
 from deeplearning4j_tpu.parallel.ring_attention import (
     ring_self_attention, ulysses_attention)
 
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8,
-                       reason="needs 8 virtual devices"),
-    requires_shard_map,
-]
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
+                                reason="needs 8 virtual devices")
 
 
 def _net(seed=42):
@@ -198,7 +192,7 @@ def test_async_exchange_staleness_semantics():
     in-flight queues are empty); step 2 must deliver step-1 peer
     messages — the one-step staleness contract."""
     from jax.sharding import Mesh
-    from deeplearning4j_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     acc = EncodedGradientsAccumulator(
@@ -250,7 +244,6 @@ def test_bitmap_pack_roundtrip():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(sign))
 
 
-@requires_modern_jax
 def test_ring_attention_matches_full():
     mesh = make_mesh({"seq": 8})
     b, t, h, d = 2, 32, 4, 8
@@ -267,7 +260,6 @@ def test_ring_attention_matches_full():
                                rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_ring_attention_masked():
     mesh = make_mesh({"seq": 8})
     b, t, h, d = 1, 16, 2, 4
@@ -373,7 +365,6 @@ def test_do_evaluation_multi_io_graph():
     assert 0.0 <= ev.accuracy() <= 1.0
 
 
-@requires_modern_jax
 def test_ring_attention_causal_matches_full():
     """Causal ring attention (VERDICT r2 #2): per-ring-step block
     offsets must land the causal diagonal exactly — the long-context
@@ -392,7 +383,6 @@ def test_ring_attention_causal_matches_full():
                                rtol=2e-4, atol=1e-5)
 
 
-@requires_modern_jax
 def test_ring_attention_causal_gradients_match():
     """Backward ring (dk/dv accumulators traveling with their kv block)
     must match autodiff through dense causal attention."""
@@ -419,7 +409,6 @@ def test_ring_attention_causal_gradients_match():
                                    rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_ring_attention_masked_gradients_match():
     mesh = make_mesh({"seq": 8})
     b, t, h, d = 1, 16, 2, 4
@@ -438,7 +427,6 @@ def test_ring_attention_masked_gradients_match():
                                rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_ring_attention_causal_masked():
     """Causal + key-mask together (padded causal LM batch)."""
     mesh = make_mesh({"seq": 8})
@@ -456,7 +444,6 @@ def test_ring_attention_causal_masked():
                                rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_zigzag_ring_matches_dense_causal():
     """Load-balanced zigzag layout: permute → distributed causal
     attention → unpermute must equal dense causal attention in the
@@ -480,7 +467,6 @@ def test_zigzag_ring_matches_dense_causal():
                                rtol=2e-4, atol=1e-5)
 
 
-@requires_modern_jax
 def test_zigzag_ring_gradients_match():
     from deeplearning4j_tpu.parallel import (
         zigzag_permute, zigzag_ring_self_attention, zigzag_unpermute)
@@ -505,7 +491,6 @@ def test_zigzag_ring_gradients_match():
                                rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_zigzag_ring_masked_matches_dense():
     """Key-masked zigzag (padded / packed-document causal batch) must
     equal dense causal+mask — the balanced schedule is not given up
@@ -531,7 +516,6 @@ def test_zigzag_ring_masked_matches_dense():
                                rtol=2e-4, atol=1e-5)
 
 
-@requires_modern_jax
 def test_zigzag_ring_masked_gradients_match():
     from deeplearning4j_tpu.parallel import (
         zigzag_permute, zigzag_ring_self_attention, zigzag_unpermute)
@@ -570,7 +554,6 @@ def test_zigzag_permute_roundtrip():
 
 
 @pytest.mark.parametrize("mode", ["ring", "ulysses", "zigzag_ring"])
-@requires_modern_jax
 def test_sequence_parallel_layer_api(mode):
     """MultiHeadAttention(sequence_parallel=...) under an ambient
     distributed_context must equal the same layer outside the context
@@ -592,7 +575,6 @@ def test_sequence_parallel_layer_api(mode):
 
 
 @pytest.mark.parametrize("mode", ["ring", "zigzag_ring"])
-@requires_modern_jax
 def test_sequence_parallel_layer_api_masked(mode):
     """Padded batches through the layer API: the key mask reaches the
     distributed attention (zigzag included — VERDICT r3 #5) and the
@@ -617,7 +599,6 @@ def test_sequence_parallel_layer_api_masked(mode):
                                rtol=2e-4, atol=2e-5)
 
 
-@requires_modern_jax
 def test_sequence_parallel_context_invalidates_traces():
     """A net fit OUTSIDE the context first must re-trace when entering
     it (and vice versa) — the ambient decision is never baked into a
@@ -661,7 +642,6 @@ def test_sequence_parallel_context_invalidates_traces():
         bad.apply(params, {}, jnp.zeros((1, 8, 16)))
 
 
-@requires_modern_jax
 def test_sequence_parallel_transformer_trains():
     """A full MultiLayerNetwork with a sequence-parallel transformer
     block trains under the ambient context (grads flow through the

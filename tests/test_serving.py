@@ -547,21 +547,20 @@ def test_continuous_batching_beats_request_at_a_time(tiny):
     """The ISSUE 13 acceptance row: under the synthetic multi-tenant
     closed-loop trace the gateway sustains >= 1.5x the sequential B=1
     generate() baseline with zero retraces after warmup. Runs via
-    ``loadgen.subprocess_report`` — a one-device measurement (the
-    bench/dossier environment), outside this suite's 8-virtual-device
-    partitioning which throttles the device loop. The serving-family
+    ``loadgen.subprocess_report`` — a one-device CPU measurement,
+    outside this suite's 8-virtual-device partitioning which
+    throttles the device loop. The serving-family
     /metrics export is asserted in-process on a small trace."""
     from deeplearning4j_tpu.obs import metrics
     from deeplearning4j_tpu.serving import loadgen
 
     rep = loadgen.subprocess_report()
-    if not rep.get("skipped") and (rep.get("speedup") or 0) < 1.5:
-        # throughput measurements on a busy 1-core CI box jitter (the
-        # bench protocol medians 3 estimates for the same reason):
-        # one fresh-process retry before calling the regression real
+    if (rep.get("speedup") or 0) < 1.5:
+        # throughput measurements on a busy CI box jitter: one
+        # fresh-process retry before calling the regression real
         rep = {**loadgen.subprocess_report(),
                "first_attempt_speedup": rep.get("speedup")}
-    assert not rep.get("skipped"), rep
+    assert rep["platform"] == "cpu"     # a CPU number, and it says so
     assert rep["retraces_after_warmup"] == 0
     assert rep["completed"] == rep["n_requests"] and rep["failed"] == 0
     assert rep["ttft_p99_ms"] is not None

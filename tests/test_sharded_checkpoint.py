@@ -11,15 +11,10 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel._compat import supports_psum_scatter
 from deeplearning4j_tpu.serialization import ShardedCheckpointer
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices")
-
-needs_scatter = pytest.mark.skipif(
-    not supports_psum_scatter(),
-    reason="jax runtime has no psum_scatter/all_gather")
 
 
 def test_sharded_roundtrip_preserves_sharding(tmp_path):
@@ -161,7 +156,6 @@ def _host_flat_opt(wrapper):
             for l in jax.tree_util.tree_leaves(wrapper._dp_state)]
 
 
-@needs_scatter
 def test_reshard_fence_8_to_4_and_back(tmp_path):
     """Acceptance fence: opt/param state saved at N=8 restores onto
     M=4 (and 4→8) with the gathered flat leaves bit-identical to the
@@ -210,7 +204,6 @@ def test_reshard_fence_8_to_4_and_back(tmp_path):
         assert np.isfinite(net8b.score_)
 
 
-@needs_scatter
 def test_same_topology_restore_stays_fast_path(tmp_path):
     """n_src == wrapper.n keeps the sharded-target restore (shards
     land on their devices; nothing gathers): the restored opt leaves
@@ -229,7 +222,6 @@ def test_same_topology_restore_stays_fast_path(tmp_path):
         assert np.array_equal(a, b)
 
 
-@needs_scatter
 def test_reshard_refused_without_opt_in(tmp_path):
     net8, w8 = _zero_wrapper(8)
     _fit_steps(w8)
@@ -240,7 +232,6 @@ def test_reshard_refused_without_opt_in(tmp_path):
             ck.restore_wrapper(w4, reshard=False)
 
 
-@needs_scatter
 def test_layout_mismatch_fails_fast_without_quarantine(tmp_path):
     """Restoring a checkpoint dir written by a DIFFERENT net is a
     configuration error: the strict zero-pad invariant raises
@@ -259,7 +250,6 @@ def test_layout_mismatch_fails_fast_without_quarantine(tmp_path):
         assert not (tmp_path / "ck" / "corrupt").exists()
 
 
-@needs_scatter
 def test_restore_degradation_order_quarantines_then_reshards(tmp_path):
     """Satellite: newest checkpoint written at N=8 is CORRUPT →
     restore_latest_valid onto M=4 quarantines it (with its world

@@ -7,8 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_shard_map
-
 from deeplearning4j_tpu.nn import (MultiLayerNetwork,
                                    NeuralNetConfiguration)
 from deeplearning4j_tpu.nn.config import InputType
@@ -17,9 +15,6 @@ from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.parallel import (ParallelInference, make_mesh,
                                          shard_model_params)
 
-
-
-pytestmark = requires_shard_map
 
 def _wide_net(hidden=512, n_in=64, classes=8):
     conf = (NeuralNetConfiguration.builder().seed(11)

@@ -31,16 +31,10 @@ from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.parallel import (FlatShardLayout,
                                          ParallelWrapper,
                                          per_device_bytes)
-from deeplearning4j_tpu.parallel._compat import (shard_map,
-                                                 supports_psum_scatter)
+from jax import shard_map
 
-pytestmark = [
-    pytest.mark.skipif(len(jax.devices()) < 8,
-                       reason="needs 8 virtual devices"),
-    pytest.mark.skipif(not supports_psum_scatter(),
-                       reason="this jax cannot express "
-                              "psum_scatter/all_gather"),
-]
+pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
+                                reason="needs 8 virtual devices")
 
 N = 8
 

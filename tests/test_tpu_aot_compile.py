@@ -1,0 +1,149 @@
+"""Ask the TPU's own compiler, without a chip.
+
+The Pallas kernels of the main path run in interpret mode under the
+CPU backend every other test uses, and interpret mode accepts what
+Mosaic refuses (a block not aligned to the tiling, more VMEM than a
+kernel may take). libtpu is installed here and compiles for a chip
+that is DESCRIBED and not attached, so each kernel below is lowered
+and compiled for a ``v5e:2x2`` device at the widths ``chip_smoke.py``
+runs, and must contain its ``tpu_custom_call``.
+
+A compile that passes is not a chip run: nothing executes, so results
+and times come from ``chip_smoke.py`` on the chip.
+
+Everything that touches the topology lives in the module-scoped
+fixtures (only one process may hold libtpu, and pytest-xdist workers
+all import this file): nothing at import time, no child process. The
+code under test asks ``jax.default_backend()`` and would take its
+interpret branch here, so the fixture pins ``_interpret`` to False in
+the two kernel modules for the duration of the module.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops import fused_norms, pallas_kernels
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """SingleDeviceSharding on the described chip, with the kernels
+    steered onto their Mosaic branch and the persistent compile cache
+    off (an entry written for a described device cannot be read back
+    without the chip and would warn on the next run)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pallas_kernels, "_interpret", lambda: False)
+    mp.setattr(fused_norms, "_interpret", lambda: False)
+    # the norm gate also asks jax.default_backend(); the existing
+    # force flag takes its kernel branch (interpret already pinned off)
+    mp.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _compile(fn, chip, *shapes):
+    """Lower ``fn`` from (shape, dtype) pairs placed on the described
+    chip, compile it with the TPU compiler, return the HLO text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_fwd_bwd(causal=True, masked=False):
+    def loss(q, k, v, *m):
+        o = pallas_kernels.flash_attention(
+            q, k, v, causal=causal, mask=m[0] if masked else None)
+        return o.astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+# [B, T, H, D] — chip_smoke's GPT step (12×64), the wide-head
+# geometry (6×128), the 8k-key case whose fused backward raises
+# vmem_limit_bytes, and one masked grouped-query case in f32
+FLASH_CASES = {
+    "b16_t1024_12x64_bf16": dict(b=16, t=1024, h=12, d=64, dt=BF16),
+    "b16_t1024_6x128_bf16": dict(b=16, t=1024, h=6, d=128, dt=BF16),
+    "b2_t8192_6x128_bf16": dict(b=2, t=8192, h=6, d=128, dt=BF16),
+    "b8_t2048_8x64_gqa2_masked_f32": dict(b=8, t=2048, h=8, d=64,
+                                          dt=jnp.float32, h_kv=2,
+                                          masked=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_attention_fwd_bwd_compiles_for_v5e(chip, case):
+    c = FLASH_CASES[case]
+    q = ((c["b"], c["t"], c["h"], c["d"]), c["dt"])
+    kv = ((c["b"], c["t"], c.get("h_kv", c["h"]), c["d"]), c["dt"])
+    shapes = [q, kv, kv]
+    if c.get("masked"):
+        shapes.append(((c["b"], c["t"]), jnp.float32))
+    hlo = _compile(_flash_fwd_bwd(masked=c.get("masked", False)), chip,
+                   *shapes)
+    # forward + backward (fused, or dQ and dK/dV) kernels
+    assert hlo.count("tpu_custom_call") >= 2, case
+
+
+def _norm_fwd_bwd(kind):
+    if kind == "rms":
+        def loss(x, g):
+            return fused_norms.rms_norm(x, g).astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1)), 1
+    if kind == "add_rms":
+        def loss(x, d, g):
+            y, s = fused_norms.add_rms_norm(x, d, g)
+            return (y.astype(jnp.float32).sum()
+                    + s.astype(jnp.float32).sum())
+        return jax.value_and_grad(loss, argnums=(0, 1, 2)), 2
+    def loss(x, g, b):
+        return fused_norms.layer_norm(x, g, b).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2)), 1
+
+
+# rows: the b16·t1024 train step, and the gateway's decode step at 1
+# and 16 slots (bf16 tiles are 16 rows; _blocks aligns rows to 8)
+@pytest.mark.parametrize("kind,rows", [
+    ("rms", 16 * 1024), ("add_rms", 16 * 1024), ("layer", 16 * 1024),
+    ("rms", 1), ("rms", 16), ("add_rms", 16),
+])
+def test_fused_norm_fwd_bwd_compiles_for_v5e(chip, kind, rows):
+    fn, n_x = _norm_fwd_bwd(kind)
+    feats = 768
+    shapes = [((rows, feats), BF16)] * n_x
+    shapes += [((feats,), jnp.float32)] * (2 if kind == "layer" else 1)
+    hlo = _compile(fn, chip, *shapes)
+    assert hlo.count("tpu_custom_call") >= 2, (kind, rows)
+
+
+def test_threshold_codec_compiles_for_v5e(chip):
+    n = 1 << 20
+
+    def roundtrip(g, tau):
+        packed, resid = pallas_kernels.threshold_encode(g, tau)
+        return pallas_kernels.threshold_decode(packed, tau, n), resid
+
+    hlo = _compile(roundtrip, chip, ((n,), jnp.float32),
+                   ((), jnp.float32))
+    assert hlo.count("tpu_custom_call") >= 2

@@ -29,6 +29,9 @@ if "--xla_force_host_platform_device_count" not in flags:
 
 import jax  # noqa: E402
 
+# CPU-only by nature: the collective byte volumes are read from HLO
+# compiled for 8 VIRTUAL devices — a static analysis that needs no
+# chip and must not take one from the process that holds it
 jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp  # noqa: E402
@@ -38,10 +41,11 @@ import numpy as np  # noqa: E402
 # observatory; this tool is a thin analytic front-end over it
 from deeplearning4j_tpu.obs import commtime as _commtime  # noqa: E402
 
-# public v5e figure (jax-ml.github.io/scaling-book): ICI 45 GB/s per
-# link per direction (2D torus; ring collectives ride one link
-# direction per neighbor hop)
-V5E_ICI_GBPS = 45e9
+# the projection is FOR a v5e, from the one peaks table (2D torus;
+# ring collectives ride one link direction per neighbor hop)
+from deeplearning4j_tpu.environment import DEVICE_PEAKS  # noqa: E402
+
+V5E_ICI_GBPS = DEVICE_PEAKS["TPU v5 lite"]["ici_gbs"] * 1e9
 
 def collectives_of(compiled, n_devices=8):
     """Parse optimized HLO → [(kind, tensor_bytes, wire_bytes)].
@@ -148,8 +152,8 @@ def analyze(name, jitted, args, n_devices=8):
     """HLO-derived collective counts + wire bytes + projected ICI time.
 
     No compute-time column here: XLA-CPU cost analysis is meaningless
-    for TPU projection — BASELINE.md pairs these ICI times with the
-    round-1 MEASURED per-step times on the real chip instead.
+    for TPU projection, and a projected ICI time is not a measurement
+    — per-step times come from a chip run.
     """
     compiled = jitted.lower(*args).compile()
     colls = collectives_of(compiled, n_devices)

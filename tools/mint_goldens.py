@@ -17,6 +17,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import jax
 
+# CPU-only by nature: the goldens pin the float32 CPU numerics the
+# tier-1 tests compare against, on any host (chip attached or not)
 jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402

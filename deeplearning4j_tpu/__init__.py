@@ -41,7 +41,11 @@ environment.apply_startup_flags()
 # import so every jit in this process — and every sibling worker
 # process — reads/writes the shared on-disk cache (DL4J_TPU_COMPILE_CACHE)
 from deeplearning4j_tpu.perf import compile_cache as _compile_cache
+from deeplearning4j_tpu.perf import sentry as _sentry
 
 _compile_cache.configure_from_env()
+# every compile's phases (trace, lower, compile or load) join the
+# always-on record ring, the eager ones too (obs/trace.py)
+_sentry.install_compile_listener()
 
 __all__ = ["NDArray", "Nd4j", "dtypes", "environment", "__version__"]

@@ -115,11 +115,16 @@ _register("DL4J_TPU_RETRACE_STRICT", False, _bool,
 
 # -- telemetry spine (obs/: span tracer + metrics + worker health) ---------
 _register("DL4J_TPU_TRACE", "", str,
-          "span tracer (obs/trace.py): '' off; '1' writes Chrome-trace "
-          "JSONL to dl4j_tpu_trace_<pid>.jsonl; any other value is the "
-          "output path (drop the file into chrome://tracing/Perfetto)")
-_register("DL4J_TPU_TRACE_RING", 4096, int,
-          "in-memory span ring size (crash dumps carry its tail)")
+          "record export (obs/trace.py; the record ring itself is "
+          "always on): '' none; '1' writes Chrome-trace JSONL to "
+          "dl4j_tpu_trace_<pid>.jsonl; any other value is the output "
+          "path (drop the file into chrome://tracing/Perfetto)")
+_register("DL4J_TPU_TRACE_RING", 65536, int,
+          "size of the always-on record ring, in records of about "
+          "100 B: half a minute of a saturated gateway (some 5 records "
+          "a 33 ms iteration, one a request) after a set-up's few "
+          "thousand compile records; readers and crash dumps take its "
+          "tail, and a reader refuses a window it has overwritten")
 _register("DL4J_TPU_METRICS_PORT", 0, int,
           "serve Prometheus /metrics + /healthz on this port from "
           "startup (0: don't autostart; obs.metrics.start_server() "

@@ -497,16 +497,17 @@ class ComputationGraph:
                         f"sparser cadence); crash dump written to "
                         f"{path}") from e
             raise
-        obs.record_step("ComputationGraph.fit", t0, t1, t2, obs.now())
+        obs.record_step("ComputationGraph.fit", t0, t1, t2, obs.now(),
+                        cause=self._call_id)
         self.iteration += 1
         self._numerics.process(self, diag, self._layer_names(),
                                entry="ComputationGraph")
         tl0 = obs.now()
         for l in self.listeners:
             l.iteration_done(self, self.iteration, self.epoch)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("ComputationGraph.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("ComputationGraph.fit/listeners", tl0,
+                       obs.now(), self._call_id)
 
     def _make_train_loop(self):
         """K train steps per dispatched executable (``lax.scan`` over
@@ -565,11 +566,13 @@ class ComputationGraph:
             for item in group:
                 self._fit_batch(*item)
             return
-        t0 = obs.now()
+        start = obs.now()
         faults.inject("step")       # site: step dispatch (resilience/)
         self._refresh_ambient_trace()
         if self._train_loop_fn is None:
             self._train_loop_fn = self._make_train_loop()
+        # h2d is the staging alone: what came before is ``prep``
+        t0 = obs.now()
         inputs = {n: jnp.stack([jnp.asarray(np.asarray(item[0][i]))
                                 for item in group])
                   for i, n in enumerate(self.conf.inputs)}
@@ -585,10 +588,12 @@ class ComputationGraph:
                                 for item in group])
                   for j, n in enumerate(self.conf.outputs)
                   if lms0 and j < len(lms0) and lms0[j] is not None}
+        t1 = obs.now()
+        # the rng stack's small programs are dispatched while the
+        # staged bytes are still on their way, under ``dispatch``
         base = jax.random.PRNGKey(self.conf.seed)
         rngs = jnp.stack([jax.random.fold_in(base, self.iteration + i)
                           for i in range(len(group))])
-        t1 = obs.now()
         try:
             self.params, self.opt_state, self.state, losses = \
                 self._train_loop_fn(self.params, self.opt_state,
@@ -608,8 +613,12 @@ class ComputationGraph:
         t2 = obs.now()
         losses = np.asarray(losses)   # one host transfer for the group
         t3 = obs.now()
+        staged = sum(a.nbytes for a in jax.tree.leaves(
+            (inputs, labels, masks, lmasks)))
         obs.record_step("ComputationGraph.fit", t0, t1, t2, t3,
-                        args={"steps": len(group)})
+                        args={"steps": len(group), "bytes": staged,
+                              "iteration": self.iteration},
+                        cause=self._call_id, start=start)
         tl0 = obs.now()
         for loss in losses:
             self.score_ = float(loss)
@@ -618,9 +627,13 @@ class ComputationGraph:
                 l.iteration_done(self, self.iteration, self.epoch)
         if nm is not None:
             nm.note_score(self.score_)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("ComputationGraph.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("ComputationGraph.fit/listeners", tl0,
+                       obs.now(), self._call_id)
+
+    #: cause id of the running ``fit`` call's records: its first
+    #: iteration number
+    _call_id = None
 
     def fit(self, features, labels=None, *, epochs: int = 1,
             features_masks=None, labels_masks=None,
@@ -631,11 +644,18 @@ class ComputationGraph:
         None); ``labels_masks``: aligned with outputs — reference
         MultiDataSet mask semantics (per-position loss masking, e.g.
         MLM masked positions)."""
+        # no frame is added around the loop for the call's record: the
+        # time jax takes to lower ``fit``'s program moves by seconds
+        # with the Python stack it is traced under (PERF.md, PR 24)
+        tc0 = obs.now()
+        self._call_id = self.iteration   # cause of this call's records
         if labels is not None:
             xs = features if isinstance(features, (list, tuple)) \
                 else [features]
             ys = labels if isinstance(labels, (list, tuple)) else [labels]
             self._fit_batch(xs, ys, features_masks, labels_masks)
+            obs.record("ComputationGraph.fit/call", tc0, obs.now(),
+                       self._call_id)
             return self
         it = features
         for _ in range(epochs):
@@ -652,7 +672,8 @@ class ComputationGraph:
                     mds = next(src)
                 except StopIteration:
                     break
-                obs.record_etl("ComputationGraph.fit", te0, obs.now())
+                obs.record_etl("ComputationGraph.fit", te0, obs.now(),
+                               self._call_id)
                 if hasattr(mds, "features"):
                     xs = (mds.features
                           if isinstance(mds.features, list)
@@ -685,6 +706,8 @@ class ComputationGraph:
             for l in self.listeners:
                 l.on_epoch_end(self)
             self.epoch += 1
+        obs.record("ComputationGraph.fit/call", tc0, obs.now(),
+                   self._call_id)
         return self
 
     def _flush_group(self, group):
@@ -730,16 +753,17 @@ class ComputationGraph:
         self.score_ = float(loss)     # blocking device sync
         obs.devtime.step_ended(self._train_step_fn)
         obs.commtime.step_ended(self._train_step_fn)
-        obs.record_step("ComputationGraph.fit", t0, t1, t2, obs.now())
+        obs.record_step("ComputationGraph.fit", t0, t1, t2, obs.now(),
+                        cause=self._call_id)
         self.iteration += 1
         if nm is not None:
             nm.note_score(self.score_)
         tl0 = obs.now()
         for l in self.listeners:
             l.iteration_done(self, self.iteration, self.epoch)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("ComputationGraph.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("ComputationGraph.fit/listeners", tl0,
+                       obs.now(), self._call_id)
 
     # ------------------------------------------------------------------
     def _make_output_fn(self):

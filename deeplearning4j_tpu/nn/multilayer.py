@@ -420,9 +420,9 @@ class MultiLayerNetwork:
         tl0 = obs.now()
         for l in self.listeners:
             l.iteration_done(self, self.iteration, self.epoch)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("MultiLayerNetwork.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("MultiLayerNetwork.fit/listeners", tl0,
+                       obs.now())
 
     def _make_train_loop(self):
         """K train steps per dispatched executable (``lax.scan`` over
@@ -474,19 +474,23 @@ class MultiLayerNetwork:
             for x, y in group:
                 self._fit_batch(x, y)
             return
-        t0 = obs.now()
+        start = obs.now()
         faults.inject("step")       # site: step dispatch (resilience/)
         self._refresh_ambient_trace()
         if self._train_loop_fn is None:
             self._train_loop_fn = self._make_train_loop()
         obs.devtime.step_started(self.iteration)
         obs.commtime.step_started(self.iteration)
+        # h2d is the staging alone: what came before is ``prep``
+        t0 = obs.now()
         xs = jnp.stack([jnp.asarray(np.asarray(x)) for x, _ in group])
         ys = jnp.stack([jnp.asarray(np.asarray(y)) for _, y in group])
+        t1 = obs.now()
+        # the rng stack's small programs are dispatched while the
+        # staged bytes are still on their way, under ``dispatch``
         base = jax.random.PRNGKey(self.conf.seed)
         rngs = jnp.stack([jax.random.fold_in(base, self.iteration + i)
                           for i in range(len(group))])
-        t1 = obs.now()
         try:
             self.params, self.opt_state, self.state, losses = \
                 self._train_loop_fn(self.params, self.opt_state,
@@ -508,7 +512,9 @@ class MultiLayerNetwork:
         obs.devtime.step_ended(self._train_loop_fn)
         obs.commtime.step_ended(self._train_loop_fn)
         obs.record_step("MultiLayerNetwork.fit", t0, t1, t2, t3,
-                        args={"steps": len(group)})
+                        args={"steps": len(group),
+                              "bytes": xs.nbytes + ys.nbytes},
+                        start=start)
         tl0 = obs.now()
         for loss in losses:
             self.score_ = float(loss)
@@ -517,9 +523,9 @@ class MultiLayerNetwork:
                 l.iteration_done(self, self.iteration, self.epoch)
         if nm is not None:
             nm.note_score(self.score_)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("MultiLayerNetwork.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("MultiLayerNetwork.fit/listeners", tl0,
+                       obs.now())
 
     def _flush_group(self, group):
         if not group:
@@ -637,9 +643,9 @@ class MultiLayerNetwork:
         tl0 = obs.now()
         for l in self.listeners:
             l.iteration_done(self, self.iteration, self.epoch)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("MultiLayerNetwork.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("MultiLayerNetwork.fit/listeners", tl0,
+                       obs.now())
 
     # -- truncated BPTT (reference: fit segments of tbpttLength, carrying
     #    rnn state across segments; MultiLayerNetwork truncated-BPTT path)
@@ -716,9 +722,9 @@ class MultiLayerNetwork:
         tl0 = obs.now()
         for l in self.listeners:
             l.iteration_done(self, self.iteration, self.epoch)
-        if self.listeners and obs.trace.enabled():
-            obs.trace.add_span("MultiLayerNetwork.fit/listeners",
-                               tl0, obs.now())
+        if self.listeners:
+            obs.record("MultiLayerNetwork.fit/listeners", tl0,
+                       obs.now())
 
     _tbptt_step_fn_ = None
     _tbptt_loop_fn_ = None

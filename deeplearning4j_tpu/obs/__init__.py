@@ -4,10 +4,10 @@ Replaces the three ad-hoc timing mechanisms that grew around the stack
 (``train/stats.py`` wall clocks, ``utils/profiler.py`` sections,
 per-tool private formats) with one layer (ARCHITECTURE.md §9):
 
-- :mod:`~deeplearning4j_tpu.obs.trace` — process-wide span tracer
-  writing Chrome-trace/Perfetto JSONL (``DL4J_TPU_TRACE``); nesting,
-  explicit t0/t1, thread/worker ids, bounded ring; the off path is one
-  branch.
+- :mod:`~deeplearning4j_tpu.obs.trace` — the process-wide record ring
+  (always on: one tuple append a record, explicit stamps, thread and
+  cause ids, bounded) and its Chrome-trace/Perfetto JSONL exporter
+  (``DL4J_TPU_TRACE`` gates export, never recording).
 - :mod:`~deeplearning4j_tpu.obs.metrics` — counters/gauges/histograms
   with Prometheus text exposition on a stdlib ``/metrics`` +
   ``/healthz`` endpoint; the retrace sentry and persistent compile
@@ -35,10 +35,13 @@ per-tool private formats) with one layer (ARCHITECTURE.md §9):
   ``tools/perf_dossier.py``, and ``utils/crashreport.py``.
 
 Hot-path contract: instrumented loops call :func:`record_step` /
-:func:`record_etl` with explicit :func:`now` timestamps — metrics are
-always on (a few dict lookups + float adds per step), spans cost one
-branch when tracing is off (asserted by ``tests/test_obs.py`` and
-measured as the ``obs`` section of ``bench.py``).
+:func:`record_etl` / :func:`record` with explicit :func:`now`
+timestamps — metrics are always on (a few dict lookups + float adds
+per step) and so is the ring: ONE tuple append a record, under 1 µs,
+whether or not anything is exported (asserted by ``tests/test_obs.py``
+through :func:`overhead_report`). The fan-out of a step into its
+``/h2d``, ``/dispatch``, ``/sync`` spans and every event dict belong
+to the exporter, which runs per record only under ``DL4J_TPU_TRACE``.
 """
 from __future__ import annotations
 
@@ -51,44 +54,56 @@ from deeplearning4j_tpu.obs import metrics as metrics
 from deeplearning4j_tpu.obs import numerics as numerics
 from deeplearning4j_tpu.obs import trace as trace
 from deeplearning4j_tpu.obs import fleet as fleet
-from deeplearning4j_tpu.obs.trace import now as now, span as span
+from deeplearning4j_tpu.obs.trace import (now as now, record as record,
+                                          span as span)
+
+#: phase names of a step record by which optional stamps it carries:
+#: ``_STEP_PHASES[prep given][deliver given]``
+_STEP_PHASES = (
+    (("h2d", "dispatch", "sync"),
+     ("h2d", "dispatch", "sync", "deliver")),
+    (("prep", "h2d", "dispatch", "sync"),
+     ("prep", "h2d", "dispatch", "sync", "deliver")))
+_WORKER_PHASES = ("h2d", "dispatch", "collective_sync")
 
 
 def record_step(entry: str, t0: float, t1: float, t2: float,
-                t3: float, args: Optional[Dict[str, Any]] = None
-                ) -> None:
+                t3: float, args: Optional[Dict[str, Any]] = None,
+                *, cause=None, start: Optional[float] = None,
+                end: Optional[float] = None) -> None:
     """One completed train/serve step with phase attribution:
     ``t0→t1`` host→device feed, ``t1→t2`` dispatch (async on TPU),
-    ``t2→t3`` blocking device sync. Metrics always; spans when
-    tracing."""
-    metrics.observe_step(entry, t3 - t0, t1 - t0, t3 - t2)
-    if trace.enabled():
-        trace.add_span(entry + "/step", t0, t3, args)
-        trace.add_span(entry + "/h2d", t0, t1)
-        trace.add_span(entry + "/dispatch", t1, t2)
-        trace.add_span(entry + "/sync", t2, t3)
+    ``t2→t3`` blocking device sync; ``start→t0`` host preparation and
+    ``t3→end`` delivery of the results where the caller stamps them.
+    Metrics always, and always one ring record (``args`` are its
+    counts, ``cause`` the id of what caused the step); the exporter
+    makes the ``/step``, ``/h2d``, ... spans of it."""
+    stamps = (t0, t1, t2, t3)
+    if start is not None:
+        stamps = (start,) + stamps
+    metrics.observe_step(entry, t3 - stamps[0], t1 - t0, t3 - t2)
+    if end is not None:
+        stamps += (end,)
+    trace.record_phases(
+        entry, stamps, _STEP_PHASES[start is not None][end is not None],
+        cause, args)
 
 
-def record_etl(entry: str, t0: float, t1: float) -> None:
+def record_etl(entry: str, t0: float, t1: float, cause=None) -> None:
     """Fit-loop wait on its data iterator."""
     metrics.FIT_ETL_SECONDS.labels(entry=entry).inc(t1 - t0)
-    if trace.enabled():
-        trace.add_span(entry + "/etl", t0, t1)
+    trace.record(entry + "/etl", t0, t1, cause)
 
 
 def record_worker_step(worker: str, t0: float, t1: float, t2: float,
                        t3: float) -> None:
     """ParallelWrapper worker loop: per-worker latency histogram,
-    collective-sync wall time, liveness heartbeat, spans."""
+    collective-sync wall time, liveness heartbeat, one ring record."""
     metrics.WORKER_STEP.labels(worker=worker).observe(t3 - t0)
     metrics.WORKER_SYNC.labels(worker=worker).inc(t3 - t2)
     health.heartbeat(worker)
-    if trace.enabled():
-        w = {"worker": worker}
-        trace.add_span("ParallelWrapper.fit/step", t0, t3, w)
-        trace.add_span("ParallelWrapper.fit/h2d", t0, t1)
-        trace.add_span("ParallelWrapper.fit/dispatch", t1, t2)
-        trace.add_span("ParallelWrapper.fit/collective_sync", t2, t3)
+    trace.record_phases("ParallelWrapper.fit", (t0, t1, t2, t3),
+                        _WORKER_PHASES, None, {"worker": worker})
 
 
 def summary() -> Dict[str, Any]:
@@ -103,15 +118,18 @@ def summary() -> Dict[str, Any]:
 
 
 def report(spans: int = 20) -> Dict[str, Any]:
-    """The merged telemetry snapshot: tracer state + last ``spans``
-    ring events, every metric family (sentry/compile-cache collector
-    families included), and worker health. Crash dumps call this with
-    a larger ``spans`` so the last moments of a dying run survive."""
+    """The merged telemetry snapshot: tracer state + the ring's last
+    ``spans`` events (expanded on demand: the ring is always on, so
+    they are there whether or not export was asked for), every metric
+    family (sentry/compile-cache collector families included), and
+    worker health. Crash dumps call this with a larger ``spans`` so
+    the last moments of a dying run survive."""
     return {
         "trace": {
             "enabled": trace.enabled(),
             "path": trace.trace_path(),
             "events_recorded": trace.events_recorded(),
+            "records_dropped": trace.dropped(),
         },
         "spans": trace.events(last=spans) if spans else [],
         "metrics": metrics.snapshot(),
@@ -121,15 +139,19 @@ def report(spans: int = 20) -> Dict[str, Any]:
 
 def overhead_report(step_seconds: Optional[float] = None,
                     iters: int = 2000) -> Dict[str, Any]:
-    """Measure the tracing-OFF per-step cost of the instrumentation
-    (the exact calls ``record_step``+``record_etl`` make on the off
-    path) and express it as a fraction of ``step_seconds`` — the
-    ``obs`` section of ``bench.py`` / the dossier. Restores the
-    tracer's enabled state."""
-    was_enabled = trace.enabled()
-    # flip the gate only (file/ring untouched) so the off path is what
+    """Measure the export-OFF per-step cost of the instrumentation
+    (the exact calls ``record_step``+``record_etl`` make: metrics and
+    one ring append each) and express it as a fraction of
+    ``step_seconds`` — the ``obs`` section of ``bench.py`` / the
+    dossier; ``ring_append_us`` is one step record's append alone.
+    Restores the exporter's state; the probe's records go to a scratch
+    ring, not the process's."""
+    from collections import deque
+    was_enabled, fh, ring = trace._enabled, trace._fh, trace._ring
+    # flip the gates only (file untouched) so the off path is what
     # gets timed even mid-trace
-    trace._enabled = False
+    trace._enabled, trace._fh = False, None
+    trace._ring = deque(maxlen=ring.maxlen)
     try:
         t0 = now()
         for _ in range(iters):
@@ -138,8 +160,14 @@ def overhead_report(step_seconds: Optional[float] = None,
             b = now()
             record_etl("obs_overhead_probe", b, now())
         per_step = (now() - t0) / iters
+        stamps = (t0, t0, t0, t0)
+        t0 = now()
+        for _ in range(iters):
+            trace.record_phases("obs_overhead_probe", stamps,
+                                _STEP_PHASES[0][0])
+        per_append = (now() - t0) / iters
     finally:
-        trace._enabled = was_enabled
+        trace._enabled, trace._fh, trace._ring = was_enabled, fh, ring
         # scrub the probe's synthetic samples — they measured the off
         # path but must not masquerade as real telemetry in /metrics,
         # step_summary(), or StatsListener records
@@ -147,6 +175,7 @@ def overhead_report(step_seconds: Optional[float] = None,
     out: Dict[str, Any] = {
         "tracing": was_enabled,
         "off_path_cost_us": round(per_step * 1e6, 3),
+        "ring_append_us": round(per_append * 1e6, 3),
     }
     if step_seconds:
         out["step_ms"] = round(step_seconds * 1e3, 3)
@@ -161,7 +190,7 @@ def snapshot() -> Dict[str, Any]:
 
 
 __all__ = ["trace", "metrics", "health", "numerics", "fleet",
-           "devtime", "commtime", "span", "now", "record_step",
-           "record_etl",
+           "devtime", "commtime", "span", "now", "record",
+           "record_step", "record_etl",
            "record_worker_step", "summary", "report",
            "overhead_report", "snapshot"]

@@ -19,6 +19,19 @@ budget — the budget meters surprises, not declared buckets.
 Metrics surface through :func:`stats` (consumed by
 ``train.stats.StatsListener``, ``bench.py`` and
 ``tools/perf_dossier.py``).
+
+**Compile lifecycle records.** :func:`install_compile_listener`
+(called at package import) listens to JAX's own duration events and
+appends each as a ``compile/<phase>`` record to the ``obs.trace`` ring
+(``jaxpr_trace``, ``jaxpr_to_mlir``, ``backend_compile``, and on a
+persistent-cache hit ``cache_retrieval``, which lies inside
+``backend_compile``), caused by the sentried function on the compiling
+thread's stack or by ``eager`` when there is none (``jnp.stack`` on
+host arrays, a weights' maker). A nested jit's tracing is reported by
+JAX inside its caller's: a reader takes the union of the records'
+intervals, and :attr:`FunctionStats.phase_s` (plain sums, nested ones
+counted twice) says where one function's ``compile_time_s`` went:
+tracing, lowering, or compiling/loading.
 """
 from __future__ import annotations
 
@@ -44,6 +57,57 @@ def _live_stats() -> List["FunctionStats"]:
     if len(out) != len(_REGISTRY):
         _REGISTRY[:] = [r for r in _REGISTRY if r() is not None]
     return out
+
+#: JAX's duration events → the phase name of the record made of each
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_tls = threading.local()    # .owner: FunctionStats compiling up-stack
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    """One ``compile/<phase>`` ring record a JAX duration event; JAX
+    calls this on the compiling thread as the phase ends."""
+    phase = COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    from deeplearning4j_tpu.obs import trace
+    owner = getattr(_tls, "owner", None)
+    t1 = trace.now()
+    trace.record("compile/" + phase, t1 - duration, t1,
+                 owner.name if owner is not None else "eager",
+                 fun=kw.get("fun_name"))
+    if owner is not None:
+        with _LOCK:
+            owner.phase_s[phase] += duration
+
+
+def install_compile_listener() -> None:
+    """Register :func:`_on_duration` with ``jax.monitoring`` (once)."""
+    global _listening
+    if _listening:
+        return
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    _listening = True
+
+
+@contextlib.contextmanager
+def _compiling(stats: "FunctionStats"):
+    """Name ``stats`` as the cause of what this thread compiles
+    meanwhile (the outermost sentried function wins a nested call)."""
+    prev = getattr(_tls, "owner", None)
+    if prev is None:
+        _tls.owner = stats
+    try:
+        yield
+    finally:
+        _tls.owner = prev
+
 
 # strict()/budget() context overrides (None -> read the env flags)
 _STRICT_OVERRIDE: Optional[bool] = None
@@ -103,6 +167,9 @@ class FunctionStats:
         self.aot_hits = 0             # live calls served by a warmed
                                       # executable (zero-compile proof)
         self.compile_time_s = 0.0     # wall-time spent compiling
+        # seconds JAX reported per compile phase while this function
+        # was on the compiling thread's stack
+        self.phase_s = dict.fromkeys(COMPILE_PHASES.values(), 0.0)
         self.signatures: set = set()  # every distinct traced aval sig
         self.planned: set = set()     # declared via warmup()
 
@@ -144,6 +211,7 @@ class FunctionStats:
                 "warmed": self.warmed,
                 "aot_hits": self.aot_hits,
                 "compile_time_s": self.compile_time_s,
+                **{f"{k}_s": v for k, v in self.phase_s.items()},
             }
 
 
@@ -201,7 +269,8 @@ class SentryJit:
                     return out
         before = st.traces
         t0 = time.perf_counter()
-        out = self._jitted(*args, **kwargs)
+        with _compiling(st):
+            out = self._jitted(*args, **kwargs)
         if st.traces != before:     # this call traced -> it compiled
             dt = time.perf_counter() - t0
             with _LOCK:
@@ -222,7 +291,9 @@ class SentryJit:
             if sig in st.signatures:
                 return 0.0          # already traced/compiled
         t0 = time.perf_counter()
-        self._aot[sig] = self._jitted.lower(*args, **kwargs).compile()
+        with _compiling(st):
+            self._aot[sig] = self._jitted.lower(*args,
+                                                **kwargs).compile()
         dt = time.perf_counter() - t0
         with _LOCK:
             st.warmed += 1
@@ -302,5 +373,6 @@ def reset() -> None:
         for s in _live_stats():
             s.traces = s.compiles = s.warmed = s.aot_hits = 0
             s.compile_time_s = 0.0
+            s.phase_s = dict.fromkeys(COMPILE_PHASES.values(), 0.0)
             s.signatures.clear()
             s.planned.clear()

@@ -73,40 +73,34 @@ class TokenStream:
         self.t_submit = obs.now()
         self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None
+        self.t_last: Optional[float] = None     # newest token's push
+        self.t_done: Optional[float] = None     # finish() or fail()
         self._tokens: list = []
         self._done = False
         self._error: Optional[Exception] = None
         self._cond = threading.Condition()
 
-    def _trace_done(self, outcome: str) -> None:
-        """Emit the request's async trace track (submit → admit →
-        prefill → decode-steps → retire/abort) at terminal time — one
-        ``trace.enabled()`` branch on the off path, like PR 2."""
-        if not obs.trace.enabled():
-            return
-        t1 = obs.now()
-        a = {"rid": self.rid, "tenant": self.tenant,
-             "outcome": outcome, "tokens": len(self._tokens)}
-        obs.trace.async_span("serving.request", self.rid,
-                             self.t_submit, t1, a)
-        if self.t_admit is not None:
-            obs.trace.async_span("serving.request/queue_wait",
-                                 self.rid, self.t_submit,
-                                 self.t_admit)
-            if self.t_first is not None:
-                obs.trace.async_span("serving.request/prefill",
-                                     self.rid, self.t_admit,
-                                     self.t_first)
-                obs.trace.async_span("serving.request/decode_steps",
-                                     self.rid, self.t_first, t1,
-                                     {"tokens": len(self._tokens)})
+    def _record_done(self, outcome: str) -> None:
+        """The request's one ring record, at terminal time: its stamps
+        (submit, admit, first and last token, done) and counts. The
+        exporter makes its async track (submit → admit → prefill →
+        decode-steps → retire/abort) of it."""
+        self.t_done = obs.now()
+        obs.trace.record_request(
+            "serving.request", self.rid,
+            (self.t_submit, self.t_admit, self.t_first, self.t_last,
+             self.t_done),
+            {"rid": self.rid, "tenant": self.tenant, "outcome": outcome,
+             "tokens": len(self._tokens),
+             "prompt": int(self.prompt.size)})
 
     # -- scheduler-facing callbacks (duck-typed request protocol) --------
     def push(self, tok: int) -> None:
         with self._cond:
             self._tokens.append(int(tok))
+            self.t_last = obs.now()
             if self.t_first is None:
-                self.t_first = obs.now()
+                self.t_first = self.t_last
                 obs.metrics.SERVING_TTFT.observe(
                     self.t_first - self.t_submit)
             self._cond.notify_all()
@@ -116,7 +110,7 @@ class TokenStream:
             if self._done:
                 return
             self._done = True
-            self._trace_done("retired")
+            self._record_done("retired")
             self._cond.notify_all()
 
     def fail(self, e: Exception) -> None:
@@ -127,7 +121,7 @@ class TokenStream:
                 e.tokens = list(self._tokens)
             self._error = e
             self._done = True
-            self._trace_done(f"aborted:{type(e).__name__}")
+            self._record_done(f"aborted:{type(e).__name__}")
             self._cond.notify_all()
 
     # -- client API ------------------------------------------------------
@@ -340,11 +334,6 @@ class ServingGateway:
             self.eos_id,
             deadline=(obs.now() + deadline_s
                       if deadline_s is not None else None))
-        if obs.trace.enabled():     # off path: one branch, zero events
-            obs.trace.instant("serving.request/submit",
-                              {"rid": stream.rid, "tenant": tenant,
-                               "prompt": int(prompt.size),
-                               "max_new": max_new})
         with self._lock:
             # re-check under the lock: shutdown() drains the queues
             # under this same lock, so a submit that raced past the
@@ -527,8 +516,8 @@ class ServingGateway:
             head = self._next_admission()
             if head is None:
                 return admitted
-            # the admit timestamp anchors the request's queue_wait /
-            # prefill trace phases (emitted at terminal time)
+            # the admit timestamp anchors the request record's
+            # queue_wait / prefill phases (made at terminal time)
             head.t_admit = obs.now()
             try:
                 if not self._sched.admit(head):
@@ -553,33 +542,55 @@ class ServingGateway:
             self._sched.evict(st)
 
     def _loop(self) -> None:
+        """The worker: one ``serving.loop/iter`` record an iteration,
+        whose number every record made inside it carries as its cause;
+        what its ``admit``, ``park`` and step records leave uncovered
+        is cancels, locks and bookkeeping."""
         obs.trace.set_thread_name("serving-gateway")
+        it = 0
         while not self._stop.is_set():
-            if self._pause.is_set():
-                self._parked.set()
-                with self._lock:
-                    self._work.wait(0.05)
-                continue
-            self._drain_cancels()
-            if not self._shutdown.is_set():
-                self._admit_queued()
-            if self._sched.active_count() == 0:
-                with self._lock:
-                    if not (self._queued() or self._cancels):
-                        # park until a submit arrives (or shutdown)
-                        self._work.wait(0.05)
-                continue
-            try:
-                # fault site shared with the ParallelInference worker:
-                # a serving-site plan drills the gateway's step loop.
-                # NB: no gateway lock here — submit() never waits out
-                # a decode iteration
-                faults.inject("serving")
-                self._sched.step()
-            except Exception as e:
-                n = self._sched.shed_all(lambda: SequenceAborted(
-                    f"in-flight sequences shed by serving fault: "
-                    f"{type(e).__name__}: {e}", cause=e))
-                for _ in range(n):
-                    obs.metrics.SERVING_SHED.labels(
-                        reason="fault").inc()
+            it += 1
+            self._sched.cause = it
+            t0 = obs.now()
+            self._iterate(it)
+            obs.record("serving.loop/iter", t0, obs.now(), it)
+
+    def _park(self, it: int) -> None:
+        """Wait for work (call under the lock)."""
+        t0 = obs.now()
+        self._work.wait(0.05)
+        obs.record("serving.loop/park", t0, obs.now(), it)
+
+    def _iterate(self, it: int) -> None:
+        if self._pause.is_set():
+            self._parked.set()
+            with self._lock:
+                self._park(it)
+            return
+        self._drain_cancels()
+        if not self._shutdown.is_set():
+            t0 = obs.now()
+            active = self._sched.active_count()
+            admitted = self._admit_queued()
+            obs.record("serving.loop/admit", t0, obs.now(), it,
+                       admitted=admitted, active=active)
+        if self._sched.active_count() == 0:
+            with self._lock:
+                if not (self._queued() or self._cancels):
+                    # park until a submit arrives (or shutdown)
+                    self._park(it)
+            return
+        try:
+            # fault site shared with the ParallelInference worker:
+            # a serving-site plan drills the gateway's step loop.
+            # NB: no gateway lock here — submit() never waits out
+            # a decode iteration
+            faults.inject("serving")
+            self._sched.step()
+        except Exception as e:
+            n = self._sched.shed_all(lambda: SequenceAborted(
+                f"in-flight sequences shed by serving fault: "
+                f"{type(e).__name__}: {e}", cause=e))
+            for _ in range(n):
+                obs.metrics.SERVING_SHED.labels(
+                    reason="fault").inc()

@@ -204,6 +204,9 @@ class DecodeScheduler:
         self._temp_one = jnp.asarray(1.0, jnp.float32)
         self.steps = 0
         self.tokens_out = 0
+        #: id of the caller's iteration (the gateway loop's), stamped
+        #: on the records made inside it
+        self.cause = None
         self._step_fn = self._build_step_fn()
         self._admit_fns: Dict[int, object] = {}
         self._spec_fn = (self._build_spec_step_fn(self.spec_k)
@@ -684,7 +687,9 @@ class DecodeScheduler:
             raise
         ts3 = obs.now()
         obs.record_step("serving.prefill", ts0, ts1, ts2, ts3,
-                        args={"bucket": tb, "t0": t0, "slot": slot})
+                        args={"bucket": tb, "t0": t0, "slot": slot,
+                              "rid": getattr(req, "rid", None)},
+                        cause=self.cause)
         obs.metrics.SERVING_PREFILL.observe(ts3 - ts0)
         if self.prefix_sharing:
             # publish this prompt's page chain so later admissions
@@ -765,7 +770,9 @@ class DecodeScheduler:
         ts3 = obs.now()
         obs.record_step("serving.prefill", ts0, ts1, ts2, ts3,
                         args={"bucket": sb, "t0": t0, "slot": slot,
-                              "shared": shared_len})
+                              "shared": shared_len,
+                              "rid": getattr(req, "rid", None)},
+                        cause=self.cause)
         obs.metrics.SERVING_PREFILL.observe(ts3 - ts0)
         obs.metrics.SERVING_PREFIX_HITS.inc()
         obs.metrics.SERVING_PREFIX_SAVED.inc(shared_len)
@@ -861,8 +868,11 @@ class DecodeScheduler:
             if s.remaining <= 0 or tok == getattr(s.req, "eos_id",
                                                   None):
                 self._retire(i)
+        # ``deliver`` (ts3 → here) is the push/retire loop above: host
+        # time the device waits out before its next step
         obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
-                        args={"active": len(act)})
+                        args={"active": len(act)}, cause=self.cause,
+                        end=obs.now())
         obs.metrics.SERVING_STEP.observe(ts3 - ts0)
         obs.metrics.SERVING_TOKENS.inc(len(act))
         self.tokens_out += len(act)
@@ -928,7 +938,8 @@ class DecodeScheduler:
                 self._retire(i)
         obs.record_step("serving.spec_step", ts0, ts1, ts2, ts3,
                         args={"active": len(act), "k": k,
-                              "produced": produced})
+                              "produced": produced}, cause=self.cause,
+                        end=obs.now())
         obs.metrics.SERVING_STEP.observe(ts3 - ts0)
         obs.metrics.SERVING_TOKENS.inc(produced)
         self.tokens_out += produced

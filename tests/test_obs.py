@@ -3,8 +3,9 @@ health — including the PR acceptance criteria: a 10-step fit under
 tracing yields Chrome-trace JSONL whose spans cover >= 95% of wall
 time with ETL/step/sync attribution; /metrics exposes step-latency
 histograms plus sentry retrace counters in valid Prometheus text; and
-tracing disabled records ZERO events on the step path with an
-off-path cost far under 1% of a bench-class step.
+tracing disabled builds ZERO Chrome events on the step path, which
+pays one ring record a step (under 1 µs) and far under 1% of a
+bench-class step in all.
 """
 import json
 import urllib.request
@@ -104,10 +105,22 @@ def test_tracing_disabled_records_nothing_on_step_path():
         pass
     t0 = obs.now()
     trace.add_span("also-not", t0, t0)
-    # zero events allocated/recorded while disabled — the counter is
-    # the zero-allocation guard the step path is held to
+    # nothing exported while disabled: no Chrome event built, no file
     assert trace.events_recorded() == base == 0
-    assert trace.events() == []
+    assert trace.trace_path() is None and not trace.enabled()
+    # the ring is always on: exactly ONE record a step, its phases
+    # names and not spans of their own; the gated span API adds none
+    recs = trace.records()
+    steps = [r for r in recs if r.name == "MultiLayerNetwork.fit"]
+    assert len(steps) == 3
+    assert all(r.phases == ("h2d", "dispatch", "sync")
+               and len(r.stamps) == 4 for r in steps)
+    names = {r.name for r in recs}
+    assert "MultiLayerNetwork.fit/etl" in names
+    assert not names & {"should-not-record", "also-not",
+                        "MultiLayerNetwork.fit/step",
+                        "MultiLayerNetwork.fit/h2d"}
+    assert trace.dropped() == 0
 
 
 def test_off_path_overhead_under_one_percent_of_bench_step():
@@ -116,16 +129,120 @@ def test_off_path_overhead_under_one_percent_of_bench_step():
     # bench step is far larger)
     # min of 3 probes: the measurement itself is µs-scale and a busy
     # box can inflate any single run
-    rep = min((obs.overhead_report(step_seconds=0.005, iters=500)
-               for _ in range(3)),
-              key=lambda r: r["off_path_cost_us"])
+    reps = [obs.overhead_report(step_seconds=0.005, iters=500)
+            for _ in range(3)]
+    rep = min(reps, key=lambda r: r["off_path_cost_us"])
     assert rep["tracing"] is False
     assert rep["off_path_cost_us"] < 50.0
     assert rep["overhead_pct_of_step"] < 1.0
+    # the always-on part: one step record is one ring append
+    assert min(r["ring_append_us"] for r in reps) < 1.0
     # the probe scrubs its synthetic samples from the live registry
+    # and the process's ring
     assert "obs_overhead_probe" not in metrics.step_summary()
     assert "obs_overhead_probe" not in str(
         metrics.STEPS.snapshot())
+    assert not any(r.name.startswith("obs_overhead_probe")
+                   for r in trace.records())
+
+
+# --- the always-on ring: clock, overwrites, export, causes ------------------
+
+@pytest.mark.parametrize("fresh", [False, True])
+def test_anchor_maps_a_stamp_to_epoch_ns_and_back(fresh):
+    import time
+    anchor = trace.clock() if fresh else None
+    t, wall = obs.now(), time.time_ns()
+    ns = trace.to_epoch_ns(t, anchor)
+    assert abs(ns - wall) < 50_000          # within 50 µs of the wall
+    assert abs(trace.from_epoch_ns(ns, anchor) - t) < 50e-6
+    a = trace.clock()
+    assert a.width_s < 50e-6 and a.epoch_ns > 0
+
+
+def test_small_ring_reports_dropped_and_refuses_the_window():
+    trace.enable(ring=8)
+    trace.disable()
+    t_open = obs.now()
+    for i in range(20):
+        t = obs.now()
+        obs.record_step("tiny", t, t, t, obs.now())
+    assert len(trace.records()) == 8
+    assert trace.dropped() == 12
+    assert obs.report(spans=1)["trace"]["records_dropped"] == 12
+    # the window's start was overwritten: the reader's helper refuses
+    with pytest.raises(LookupError, match="DL4J_TPU_TRACE_RING"):
+        trace.records(since=t_open)
+    # a window that begins after the oldest survivor is served
+    kept = trace.records()
+    assert trace.records(since=kept[3].stamps[-1])[0].seq == kept[3].seq
+
+
+@pytest.mark.parametrize("entry, call, names", [
+    ("E", lambda t: obs.record_step("E", t, t + 1, t + 2, t + 3,
+                                    {"steps": 2}),
+     ["E/step", "E/h2d", "E/dispatch", "E/sync"]),
+    ("E", lambda t: obs.record_step("E", t, t + 1, t + 2, t + 3,
+                                    start=t - 1, end=t + 4),
+     ["E/step", "E/prep", "E/h2d", "E/dispatch", "E/sync",
+      "E/deliver"]),
+    ("E", lambda t: obs.record_etl("E", t, t + 1), ["E/etl"]),
+    ("ParallelWrapper.fit",
+     lambda t: obs.record_worker_step("w0", t, t + 1, t + 2, t + 3),
+     ["ParallelWrapper.fit/step", "ParallelWrapper.fit/h2d",
+      "ParallelWrapper.fit/dispatch",
+      "ParallelWrapper.fit/collective_sync"]),
+])
+def test_export_expands_one_record_into_the_old_event_names(
+        tmp_path, entry, call, names):
+    # the JSONL keeps, name for name and in order, what the four-way
+    # add_span fan-out wrote before; on demand from the ring likewise
+    path = tmp_path / "e.jsonl"
+    trace.enable(str(path))
+    call(100.0)
+    trace.disable()
+    assert len(trace.records()) == 1
+    try:
+        written = [e for e in trace.read_trace(str(path))
+                   if e["ph"] == "X"]
+        assert [e["name"] for e in written] == names
+        assert [e["name"] for e in trace.events()] == names
+        whole = written[0]
+        assert sum(e["dur"] for e in written[1:]) in (
+            0, pytest.approx(whole["dur"]))
+        meta = {e["name"]: e for e in trace.read_trace(str(path))
+                if e["ph"] == "M"}
+        assert meta["clock_anchor"]["args"]["epoch_ns"] > 0
+    finally:
+        metrics.drop_entry("E")
+
+
+def test_sentried_first_call_writes_compile_records_caused_by_it():
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.perf import sentry
+    fn = sentry.jit(lambda x: jnp.tanh(x) * 3.0 + x,
+                    name="probe.compile_cause")
+    x = jnp.ones((5, 7))     # made before: its own compiles are eager
+
+    def mine():
+        return [r for r in trace.records()
+                if r.name.startswith("compile/")
+                and r.cause == "probe.compile_cause"]
+
+    fn(x)
+    first = mine()
+    assert {"compile/jaxpr_trace", "compile/jaxpr_to_mlir",
+            "compile/backend_compile"} <= {r.name for r in first}
+    assert all(r.stamps[0] <= r.stamps[1] for r in first)
+    snap = fn.stats.snapshot()
+    assert snap["jaxpr_trace_s"] > 0 and snap["backend_compile_s"] > 0
+    assert snap["compile_time_s"] >= snap["backend_compile_s"]
+    fn(x)
+    assert len(mine()) == len(first)        # the second call: none
+    # what no sentried function asked for is caused by "eager"
+    jnp.ones((3, 11)) + 1.0
+    assert any(r.cause == "eager" for r in trace.records()
+               if r.name.startswith("compile/"))
 
 
 # --- the acceptance fit: 10 steps, traced -----------------------------------
@@ -499,7 +616,12 @@ def test_report_merges_trace_metrics_health(tmp_path):
 def test_crash_dump_carries_compile_and_obs_state():
     from deeplearning4j_tpu.utils import crashreport
     net = _net()
+    # DL4J_TPU_TRACE unset: the dump still holds the last steps
+    assert not trace.enabled()
+    net.fit(ListDataSetIterator(_batches(2)))
     report = crashreport.generate_memory_status_report(net)
+    assert "MultiLayerNetwork.fit/step" in report
+    assert "MultiLayerNetwork.fit/sync" in report
     assert "compile subsystem (perf.compile_report)" in report
     assert "telemetry (obs.report" in report
     assert "compile_time_s" in report

@@ -600,9 +600,73 @@ def test_request_traces_off_path_zero_events(tiny):
                         max_context=32, default_max_new=4)
     gw.warmup(prompt_lens=(4,))
     e0 = obs.trace.events_recorded()
-    gw.submit(np.arange(4, dtype=np.int32) % 64).result(timeout=60)
+    t0 = obs.now()
+    st = gw.submit(np.arange(4, dtype=np.int32) % 64)
+    st.result(timeout=60)
     gw.shutdown()
+    # nothing exported: no Chrome event built, no file
     assert obs.trace.events_recorded() == e0
+    assert obs.trace.trace_path() is None
+    # the ring is always on: the request left exactly ONE record, with
+    # its five stamps in order
+    mine = [r for r in obs.trace.records(since=t0)
+            if r.name == "serving.request"]
+    assert len(mine) == 1 and mine[0].cause == st.rid
+    assert list(mine[0].stamps) == sorted(mine[0].stamps)
+    assert mine[0].stamps == (st.t_submit, st.t_admit, st.t_first,
+                              st.t_last, st.t_done)
+    assert mine[0].counts["tokens"] == 4
+
+
+def test_worker_records_cover_its_wall_time_and_join_requests(tiny):
+    """The gateway worker's records tile its thread: every iteration
+    is a ``serving.loop/iter`` record (>= 95% of the thread's wall
+    time between the first and the last), whose children carry its
+    number; every prefill record's rid has a request record."""
+    from deeplearning4j_tpu import obs
+
+    model, net = tiny
+    gw = ServingGateway(model, net, max_slots=2, block=8,
+                        max_context=32, default_max_new=6)
+    gw.warmup(prompt_lens=(4,))
+    t0 = obs.now()
+    streams = [gw.submit(np.arange(4, dtype=np.int32) % 64,
+                         tenant=f"t{i % 2}") for i in range(5)]
+    for st in streams:
+        st.result(timeout=60)
+    gw.shutdown()
+    recs = obs.trace.records(since=t0)
+    iters = [r for r in recs if r.name == "serving.loop/iter"]
+    assert iters and len({r.tid for r in iters}) == 1
+    tid = iters[0].tid
+    wall = iters[-1].stamps[-1] - iters[0].stamps[0]
+    assert sum(r.stamps[-1] - r.stamps[0] for r in iters) >= 0.95 * wall
+    # children lie inside the iteration that caused them
+    by_iter = {r.cause: r for r in iters}
+    kids = [r for r in recs if r.tid == tid and r.name in (
+        "serving.loop/admit", "serving.loop/park", "serving.prefill",
+        "serving.decode_step")]
+    assert {r.name for r in kids} >= {"serving.loop/admit",
+                                      "serving.prefill",
+                                      "serving.decode_step"}
+    for r in kids:
+        if r.cause not in by_iter:      # an iteration cut by since=
+            continue
+        parent = by_iter[r.cause]
+        assert parent.stamps[0] <= r.stamps[0]
+        assert r.stamps[-1] <= parent.stamps[-1]
+    steps = [r for r in kids if r.name == "serving.decode_step"]
+    assert all(r.phases[-1] == "deliver" and len(r.stamps) == 5
+               for r in steps)
+    admits = [r for r in kids if r.name == "serving.loop/admit"]
+    assert sum(r.counts["admitted"] for r in admits) == 5
+    assert any(r.counts["active"] > 0 for r in admits)
+    # requests join their prefills by rid
+    done = {r.counts["rid"] for r in recs if r.name == "serving.request"}
+    prefills = [r for r in recs if r.name == "serving.prefill"]
+    assert len(prefills) == 5
+    assert {r.counts["rid"] for r in prefills} \
+        == {st.rid for st in streams} <= done
 
 
 def test_request_traces_nested_phases_with_ids(tiny, tmp_path):
@@ -635,7 +699,12 @@ def test_request_traces_nested_phases_with_ids(tiny, tmp_path):
         pair = by_phase[phase]
         assert {p["ph"] for p in pair} == {"b", "e"}
         assert len(pair) == 6       # 3 requests x (b, e)
-    assert len(by_phase["serving.request/submit"]) == 3
+    # the request's one record carries what the separate submit
+    # instant did; the exporter writes the same phase names as before
+    assert set(by_phase) == {"serving.request",
+                             "serving.request/queue_wait",
+                             "serving.request/prefill",
+                             "serving.request/decode_steps"}
     # ids: one async track per request, phases share their request's
     # id, and args carry rid + tenant + outcome
     ids = {e["id"] for e in reqs if e.get("ph") in ("b", "e")}
@@ -645,6 +714,7 @@ def test_request_traces_nested_phases_with_ids(tiny, tmp_path):
     assert {e["args"]["tenant"] for e in lives} == {"t0", "t1"}
     assert all(e["args"]["outcome"] == "retired" for e in lives)
     assert all(e["args"]["tokens"] == 4 for e in lives)
+    assert all(e["args"]["prompt"] == 4 for e in lives)
     # nesting: each request's inner phases sit inside its life span
     for life in lives:
         rid = life["id"]

@@ -267,12 +267,12 @@ def _gateway_scenario(plan_name: str) -> dict:
     req_begins = [e for e in evs if e.get("ph") == "b"
                   and e.get("name") == "serving.request"]
     phases = {e.get("name") for e in evs
-              if e.get("ph") in ("b", "i")
+              if e.get("ph") == "b"
               and str(e.get("name", "")).startswith("serving.request")}
     outcomes = [e["args"].get("outcome") for e in req_begins
                 if "args" in e]
     spans_ok = (len(req_begins) >= 11
-                and {"serving.request", "serving.request/submit",
+                and {"serving.request",
                      "serving.request/queue_wait",
                      "serving.request/prefill",
                      "serving.request/decode_steps"} <= phases

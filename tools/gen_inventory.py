@@ -404,8 +404,9 @@ leaves an async track in the Chrome JSONL keyed by its request id:
 `serving.request` (submit → retire/abort, tenant + outcome + token
 count in the args) with nested `serving.request/queue_wait`,
 `/prefill`, and `/decode_steps` phases — drop the file into Perfetto
-to see exactly where one tenant's p99 went. With tracing off the
-request path emits zero events (one branch, the PR 2 contract).
+to see exactly where one tenant's p99 went. With or without the
+flag each request leaves one record in the always-on ring
+(ARCHITECTURE.md §9); with it off no event is built or written.
 
 **KV-page occupancy.** `dl4j_tpu_serving_kv_page_occupancy` (fraction
 of usable pages reserved — 1.0 means admission control is the

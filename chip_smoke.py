@@ -18,7 +18,10 @@ and no TPU is an error. Phases of the default run:
    from two tenants, every stream completes, zero retraces after
    warmup, pager invariants, paged greedy decode == dense ``generate``
    (see :func:`check_paged_equals_dense`); then again with int8 KV
-   pages.
+   pages. Heads of 64 lanes keep both on the reference paged
+   attention (not a ``paged_decode_attention`` in their lowered
+   step); a third, untrained gateway with 8 kv heads of 128 lanes
+   must have the kernel in it, one lowering for all its layers.
 4. train   — ResNet-50 b256 bf16 through ``net.fit(steps_per_loop=4)``.
 
 Weights and data are random, made from ``SEED``. The last stdout line
@@ -43,6 +46,13 @@ GPT = dict(vocab_size=50257, hidden=768, n_layers=12, n_heads=12,
 LM_TRAIN = dict(batch=16, seq_len=1024, steps=6)
 SERVE = dict(max_slots=16, block=16, max_new=64,
              prompt_lens=(17, 700, 45, 130, 333, 64, 512, 250))
+#: over the paged kernel's dispatch line: kv heads in whole 8-row
+#: tiles, a head of whole 128-lane tiles (ops/pallas_kernels.py)
+GPT_WIDE = dict(vocab_size=8192, hidden=2048, n_layers=2, n_heads=16,
+                n_kv_heads=8, max_len=1024, ffn_mult=2,
+                tie_embeddings=True, compute_dtype="bfloat16")
+SERVE_WIDE = dict(max_slots=16, block=16, max_new=48,
+                  prompt_lens=(17, 700, 45, 130))
 RESNET = dict(num_classes=1000, image=224, batch=256, steps=8,
               steps_per_loop=4, compute_dtype="bfloat16")
 MULTICHIP = dict(n_in=784, width=2048, hidden_layers=4, n_out=10,
@@ -187,7 +197,15 @@ def check_paged_equals_dense(tag, net, paged, dense):
           f"by {gap:.3f} nats — not a rounding tie")
 
 
-def phase_serve(model, net, cfg=SERVE, tag="serve"):
+def kernels_in_decode_step(model, net, sched):
+    """Pallas kernel names in the LOWERED ``serving.decode_step``."""
+    text = sched._step_fn.lower(
+        _shapes(model._decode_params(net)), _shapes(sched.pager.pool),
+        *sched._step_feed_shapes()).as_text()
+    return re.findall(r'kernel_name = "([^"]+)"', text)
+
+
+def phase_serve(model, net, cfg=SERVE, tag="serve", paged_kernel=False):
     from deeplearning4j_tpu.perf import sentry
     from deeplearning4j_tpu.serving import ServingGateway
 
@@ -207,6 +225,13 @@ def phase_serve(model, net, cfg=SERVE, tag="serve"):
     rep = gw.warmup()
     log(f"[{tag}] warmup compiled={rep['compiled']} "
         f"seconds={rep['seconds']:.2f} buckets={rep['buckets']}")
+    paged = [n for n in kernels_in_decode_step(model, net, gw._sched)
+             if "paged_decode" in n]
+    log(f"[{tag}] paged_decode_attention lowerings in the decode "
+        f"step: {len(paged)} (layers={model.n_layers})")
+    check(len(paged) == (1 if paged_kernel else 0),
+          f"{tag}: paged kernel {'missing from' if paged_kernel else 'in'}"
+          " the lowered decode step")
     traces_before = sentry.total_traces()
     t0 = time.perf_counter()
     with sentry.strict():
@@ -397,7 +422,10 @@ def main():
         from deeplearning4j_tpu.zoo import CausalTransformerLM
         phase_serve(CausalTransformerLM(cache_quant="int8", **GPT), net,
                     tag="serve-int8kv")
-        del model, net
+        wide = CausalTransformerLM(seed=SEED % 1000, **GPT_WIDE)
+        phase_serve(wide, wide.init(), cfg=SERVE_WIDE, tag="serve-wide",
+                    paged_kernel=True)
+        del model, net, wide
         gc.collect()
         phase_train_resnet()
     from deeplearning4j_tpu.perf import compile_cache

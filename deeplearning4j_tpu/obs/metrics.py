@@ -108,6 +108,7 @@ FAMILIES = {
     "dl4j_tpu_serving_kv_pages_free": "gauge",
     "dl4j_tpu_serving_kv_page_occupancy": "gauge",
     "dl4j_tpu_serving_kv_pages_reserved": "gauge",
+    "dl4j_tpu_serving_kv_pages_walked": "gauge",
     # speculative multi-token decode (serving/scheduler.py)
     "dl4j_tpu_serving_spec_accept_rate": "histogram",
     "dl4j_tpu_serving_spec_drafted_total": "counter",
@@ -511,6 +512,11 @@ SERVING_KV_RESERVED = REGISTRY.gauge(
     "dl4j_tpu_serving_kv_pages_reserved",
     "KV pages reserved per tenant (whole-life reservations, the "
     "admission-control currency)", ("tenant",))
+SERVING_KV_WALKED = REGISTRY.gauge(
+    "dl4j_tpu_serving_kv_pages_walked",
+    "KV pages the last decode step's attention read: the sum over "
+    "active slots of ceil(length / block), against max_slots x "
+    "max_pages_per_seq page-table entries")
 
 # speculative multi-token decode + copy-on-write prefix sharing
 # (serving/scheduler.py + serving/kv_pager.py): accept rate is the

@@ -62,6 +62,17 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "closes": (),
         "gate": "flash",
     },
+    "paged_decode_attention": {
+        "module": "ops/pallas_kernels.py",
+        "fallback": "_reference_paged_attention",
+        "parity":
+            "tests/test_pallas.py::test_paged_decode_matches_reference",
+        "scope": "ops.paged_decode_attention",
+        # the serving decode step's blocks: their attention now reads
+        # the KV pages in place (the projections and the MLP stay XLA)
+        "closes": ("paged_decode.block_*",),
+        "gate": "paged_decode",
+    },
     "threshold_encode": {
         "module": "ops/pallas_kernels.py",
         "fallback": "_jnp_threshold_encode",

@@ -15,15 +15,31 @@ decode step compiles exactly once.
 Layout (one layer-stacked array pair, the tuple the jitted step
 carries as its donated pool argument):
 
-- ``codes``  ``[L, P, Hkv, 2D, block]`` — page ``p`` of layer ``l``
-  holds ``block`` consecutive positions of the k (rows ``0:D``) and v
-  (rows ``D:2D``) halves, the exact minor-dim tiling the dense cache
-  uses (``zoo/gpt.py::_token_logits`` layout note). dtype is ``int8``
-  under ``cache_quant="int8"`` (codes from ``zoo.gpt._quant_kv``, the
-  same quantiser the dense path uses — the pager-correctness fence
-  demands token identity), else the model's compute dtype.
+- ``codes``  ``[L, P, block, Hkv, 2D]`` — page ``p`` of layer ``l``
+  holds ``block`` consecutive positions, each position the k (lanes
+  ``0:D``) and v (lanes ``D:2D``) rows of every kv head. The head
+  dimension is minor and a page is ONE contiguous run
+  (``block * Hkv * 2D`` elements, 64 KB for 16 x 8 x 256 in bf16), so
+  the decode step's kernel (``ops.paged_decode_attention``) copies
+  whole pages HBM -> VMEM through the page table and reads nothing
+  else; a position's ``[Hkv, 2D]`` is one whole (8, 128)-tiled run
+  too, which is what lets XLA scatter each step's new KV row IN
+  PLACE (with the kv heads outside the positions,
+  ``[L, P, Hkv, block, 2D]``, the TPU compiler re-tiled the whole pool
+  around every layer's scatter: seven 2 GB copies a step, read off the
+  compiled step, PR 25). With ``Hkv`` a multiple of 8 the tiled bytes
+  are the plain ones: the pool takes exactly its
+  ``L * P * block * Hkv * 2D`` elements on the device. (The previous
+  layout, ``[L, P, Hkv, 2D, block]``, was the dense cache's: the
+  minor dimension was the page's 16 positions, an eighth of a
+  128-lane tile.) dtype is ``int8`` under ``cache_quant="int8"``
+  (codes from ``zoo.gpt._quant_kv``, the same quantiser the dense
+  path uses — the pager-correctness fence demands token identity),
+  else the model's compute dtype.
 - ``scales`` ``[L, P, Hkv, 2, block]`` f32 — per-(page, head, k/v
-  half, position) dequant scales; present only under int8.
+  half, position) dequant scales; present only under int8. Positions
+  stay minor here: a minor dimension of 2 would pad every pair of
+  scales to a 128-lane row.
 
 Page 0 is the reserved **trash page**: inactive slots' writes and
 unallocated page-table entries route there, so a fixed-shape step can
@@ -93,7 +109,7 @@ class KVPager:
         self.n_pages = n_pages
         self.block = block
         self.cache_quant = cache_quant
-        shape = (n_layers, n_pages, n_kv_heads, 2 * head_dim, block)
+        shape = (n_layers, n_pages, block, n_kv_heads, 2 * head_dim)
         if cache_quant == "int8":
             self._pool: Tuple = (
                 jnp.zeros(shape, jnp.int8),

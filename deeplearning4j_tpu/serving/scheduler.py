@@ -14,13 +14,16 @@ anything changing shape. Shapes never vary, so after
 traces no matter how traffic arrives (the low-latency JIT-graph-capture
 decode contract, PAPERS.md: arxiv 2604.23467).
 
-Attention math deliberately mirrors ``zoo/gpt.py::_token_logits``
-value-for-value (same ``_quant_kv`` codes/scales, same scale factoring
-out of the einsums, same ``-1e9`` mask): padded/trash positions
-contribute exact zeros after softmax, so paged greedy decode is
-TOKEN-IDENTICAL to dense ``generate()`` — the pager-correctness fence
-in ``tests/test_serving.py`` asserts it for both the float and the
-int8-KV cache paths.
+The single-token step's attention is ``ops.paged_decode_attention``:
+on the TPU a kernel that reads each slot's KV pages in place, up to
+the slot's length; elsewhere (and for every multi-row query) the plain
+``_reference_paged_attention``, whose math deliberately mirrors
+``zoo/gpt.py::_token_logits`` value-for-value (same ``_quant_kv``
+codes/scales, same scale factoring out of the einsums, same ``-1e9``
+mask): padded/trash positions contribute exact zeros after softmax,
+so paged greedy decode is TOKEN-IDENTICAL to dense ``generate()`` —
+the pager-correctness fence in ``tests/test_serving.py`` asserts it
+for both the float and the int8-KV cache paths.
 
 Two opt-in multipliers ride the same machinery (PR 16). With
 ``spec_k > 1`` each iteration drafts k-1 tokens on the host (prompt
@@ -47,6 +50,8 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu import obs
+from deeplearning4j_tpu.ops.pallas_kernels import (
+    _reference_paged_attention, paged_decode_attention)
 from deeplearning4j_tpu.serving.kv_pager import KVPager
 from deeplearning4j_tpu.zoo.gpt import _quant_kv, _rms, prompt_bucket
 
@@ -235,14 +240,14 @@ class DecodeScheduler:
             # devtime scopes (obs/devtime.py): trace-time HLO metadata
             # naming each paged block's share of the serving hot path
             with obs.devtime.scope("paged_decode.embed"):
-                x = params["layer_0"]["W"][prev]        # [S, F]
+                x = params["layer_0"]["W"][prev][:, None]   # [S, 1, F]
             for i in range(L):
                 with obs.devtime.scope(f"paged_decode.block_{i}"):
-                    x, pool = self._paged_block_step(
+                    x, pool = self._paged_rows_step(
                         params[f"layer_{i + 1}"], i, x, pool,
-                        page_table, lengths, active)
+                        page_table, lengths[:, None], active[:, None])
             with obs.devtime.scope("paged_decode.lm_head"):
-                x = _rms(x, params[f"layer_{L + 1}"]["gamma"])
+                x = _rms(x[:, 0], params[f"layer_{L + 1}"]["gamma"])
                 logits = model._head_logits(params, x)
             key = jax.random.fold_in(
                 jax.random.PRNGKey(self.seed), ctr)
@@ -262,91 +267,21 @@ class DecodeScheduler:
         return sentry.jit(step, name="serving.decode_step",
                           donate_argnums=(1,))
 
-    def _paged_block_step(self, pblk, li, x, pool, pt, pos, active):
-        """One transformer block at one position per slot, reading and
-        writing the paged pool. Mirrors ``_token_logits.block_step``
-        value-for-value (the identity fence's contract); only the
-        cache addressing differs: write goes to page
-        ``pt[s, pos//block]`` offset ``pos%block``, the context is the
-        slot's page-table gather reshaped back to position order."""
-        import jax
-        import jax.numpy as jnp
-
-        model = self.model
-        S = self.max_slots
-        hd = model.hidden // model.n_heads
-        n_kv = model.n_kv_heads
-        block = self.block
-        h = _rms(x, pblk["ln1"]["gamma"])
-        mha = pblk["mha"]
-        q = (h @ mha["Wq"]).reshape(S, model.n_heads, hd)
-        k = (h @ mha["Wk"]).reshape(S, n_kv, hd)
-        v = (h @ mha["Wv"]).reshape(S, n_kv, hd)
-        q = _rotary_rows(q, model.rope_theta, pos)
-        k = _rotary_rows(k, model.rope_theta, pos)
-        kv = jnp.concatenate([k, v], axis=2)            # [S, Kv, 2D]
-        # inactive slots scatter into the reserved trash page — the
-        # step's shape never depends on how many slots are live
-        pids = jnp.where(active, pt[jnp.arange(S), pos // block], 0)
-        offs = pos % block
-        if model.cache_quant:
-            codes, scales = pool
-            q8, s_new = _quant_kv(kv.reshape(S, n_kv, 2, hd), 3)
-            codes = codes.at[li, pids, :, :, offs].set(
-                q8.reshape(S, n_kv, 2 * hd))
-            scales = scales.at[li, pids, :, :, offs].set(s_new)
-            pool = (codes, scales)
-            dt = x.dtype
-            gath = codes[li, pt]    # [S, MP, Kv, 2D, block]
-            ctx = gath.transpose(0, 2, 3, 1, 4).reshape(
-                S, n_kv, 2 * hd, -1)
-            sc = scales[li, pt].transpose(0, 2, 3, 1, 4).reshape(
-                S, n_kv, 2, -1)
-            ck = ctx[:, :, :hd, :].astype(dt)
-            cv = ctx[:, :, hd:, :].astype(dt)
-            k_scale = sc[:, :, 0, None, :]
-            v_scale = sc[:, :, 1, None, :]
-        else:
-            (kvpool,) = pool
-            kvpool = kvpool.at[li, pids, :, :, offs].set(
-                kv.astype(kvpool.dtype))
-            pool = (kvpool,)
-            ctx = kvpool[li, pt].transpose(0, 2, 3, 1, 4).reshape(
-                S, n_kv, 2 * hd, -1)
-            ck, cv = ctx[:, :, :hd, :], ctx[:, :, hd:, :]
-            k_scale = v_scale = None
-        groups = model.n_heads // n_kv
-        qg = q.reshape(S, n_kv, groups, hd)
-        s = jnp.einsum("bkgd,bkdt->bkgt", qg, ck) / jnp.sqrt(
-            jnp.asarray(hd, x.dtype))
-        if k_scale is not None:
-            s = (s * k_scale).astype(x.dtype)
-        # per-slot causal mask; positions past a slot's pages resolve
-        # to trash-page junk but always sit beyond its length, so the
-        # mask keeps them at exact-zero softmax weight
-        live = (jnp.arange(ck.shape[3])[None, None, None, :]
-                <= pos[:, None, None, None])
-        s = jnp.where(live, s, -1e9)
-        w = jax.nn.softmax(s, axis=-1)
-        if v_scale is not None:
-            w = (w * v_scale).astype(x.dtype)
-        a = jnp.einsum("bkgt,bkdt->bkgd", w, cv).reshape(S, -1)
-        x = x + a @ mha["Wo"] + mha["bo"]
-        h = _rms(x, pblk["ln2"]["gamma"])
-        h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
-        return x + h @ pblk["Wd"], pool
-
     def _paged_rows_step(self, pblk, li, x, pool, pt, pos, act):
-        """One transformer block at R positions per slot — the
-        multirow generalization of :meth:`_paged_block_step` the
-        speculative verify step and the shared-prefix suffix prefill
-        both run. ``x`` is [S, R, F], ``pos`` [S, R] i32, ``act``
-        bool broadcastable to [S, R] (False rows scatter into the
-        trash page). Every matmul runs on the flattened [S*R, F] view
-        and the attention einsums just grow an ``r`` axis, so each
-        row's arithmetic matches the single-row path element-for-
-        element — the spec-decode identity fence leans on that.
-        Out-of-bounds positions (a row past the slot's page table)
+        """One transformer block at R positions per slot, reading and
+        writing the paged pool: THE paged block, which the decode step
+        (R = 1), the speculative verify step and the shared-prefix
+        suffix prefill all run. Mirrors ``_token_logits.block_step``
+        value-for-value (the identity fence's contract); only the
+        cache addressing differs: row r's KV goes to page
+        ``pt[s, pos//block]`` at offset ``pos%block``, and the
+        attention reads the slot's pages through its page-table row.
+        ``x`` is [S, R, F], ``pos`` [S, R] i32, ``act`` bool
+        broadcastable to [S, R] (False rows scatter into the trash
+        page). Every matmul runs on the flattened [S*R, F] view, so
+        each row's arithmetic is the same whatever R is — the
+        spec-decode identity fence leans on that. Out-of-bounds
+        positions (a row past the slot's page table)
         are clamped EXPLICITLY and routed to trash: JAX gathers clamp
         silently, and a junk row must never land in a live page."""
         import jax
@@ -377,48 +312,29 @@ class DecodeScheduler:
         if model.cache_quant:
             codes, scales = pool
             q8, s_new = _quant_kv(kv.reshape(S, R, n_kv, 2, hd), 4)
-            codes = codes.at[li, pids, :, :, offs].set(
+            codes = codes.at[li, pids, offs].set(
                 q8.reshape(S, R, n_kv, 2 * hd))
             scales = scales.at[li, pids, :, :, offs].set(s_new)
             pool = (codes, scales)
-            dt = x.dtype
-            gath = codes[li, pt]    # [S, MP, Kv, 2D, block]
-            ctx = gath.transpose(0, 2, 3, 1, 4).reshape(
-                S, n_kv, 2 * hd, -1)
-            sc = scales[li, pt].transpose(0, 2, 3, 1, 4).reshape(
-                S, n_kv, 2, -1)
-            ck = ctx[:, :, :hd, :].astype(dt)
-            cv = ctx[:, :, hd:, :].astype(dt)
-            k_scale = sc[:, :, 0, None, None, :]
-            v_scale = sc[:, :, 1, None, None, :]
         else:
             (kvpool,) = pool
-            kvpool = kvpool.at[li, pids, :, :, offs].set(
-                kv.reshape(S, R, n_kv, 2 * hd).astype(kvpool.dtype))
+            kvpool = kvpool.at[li, pids, offs].set(
+                kv.astype(kvpool.dtype))
             pool = (kvpool,)
-            ctx = kvpool[li, pt].transpose(0, 2, 3, 1, 4).reshape(
-                S, n_kv, 2 * hd, -1)
-            ck, cv = ctx[:, :, :hd, :], ctx[:, :, hd:, :]
-            k_scale = v_scale = None
-        groups = model.n_heads // n_kv
-        qg = q.transpose(0, 2, 1, 3).reshape(S, n_kv, groups, R, hd)
-        s = jnp.einsum("bkgrd,bkdt->bkgrt", qg, ck) / jnp.sqrt(
-            jnp.asarray(hd, x.dtype))
-        if k_scale is not None:
-            s = (s * k_scale).astype(x.dtype)
-        # per-ROW causal mask: row r sees keys <= pos[s, r]. The
-        # scatter above runs before the gather, so a row attends its
-        # own key and every earlier row's — later rows' keys (and any
+        # the scatter above runs before the read, so a row attends its
+        # own key and every earlier row's; later rows' keys (and any
         # stale speculative garbage past the accepted length) sit
         # strictly beyond pos and stay at exact-zero softmax weight
-        live = (jnp.arange(ck.shape[3])[None, None, None, None, :]
-                <= pos[:, None, None, :, None])
-        s = jnp.where(live, s, -1e9)
-        w = jax.nn.softmax(s, axis=-1)
-        if v_scale is not None:
-            w = (w * v_scale).astype(x.dtype)
-        a = jnp.einsum("bkgrt,bkdt->bkgrd", w, cv).transpose(
-            0, 3, 1, 2, 4).reshape(S * R, -1)
+        if R == 1:
+            # THE decode step: the kernel reads each slot's pages in
+            # place, up to its length (a routed-to-trash row is an
+            # inactive slot: it walks no page and returns zeros)
+            a = paged_decode_attention(
+                q[:, 0], pool, li, pt,
+                jnp.where(inb[:, 0], pos[:, 0] + 1, 0))
+        else:
+            a = _reference_paged_attention(q, pool, li, pt, pos)
+        a = a.reshape(S * R, -1)
         x = x + (a @ mha["Wo"] + mha["bo"]).reshape(S, R, -1)
         h = _rms(x.reshape(S * R, -1), pblk["ln2"]["gamma"])
         h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
@@ -498,27 +414,26 @@ class DecodeScheduler:
                   ctr):
             logits0, caches = model._prefill_forward(
                 params, prompt_pad, tb, t0)
+
+            def paged(c, perm):
+                # dense [L, Kv, X, tb] -> [L, n_chunks, Kv, ...]: page
+                # p covers positions p*block..(p+1)*block-1
+                return c.reshape(*c.shape[:3], n_chunks,
+                                 block).transpose(perm)
+
             if model.cache_quant:
                 codes, scales = pool
                 w8 = jnp.stack([c[0][0] for c in caches])
                 sc = jnp.stack([c[1][0] for c in caches])
-                # [L, Kv, 2D, tb] -> [L, n_chunks, Kv, 2D, block]:
-                # page p covers positions p*block..(p+1)*block-1
-                codes = codes.at[:, page_ids].set(
-                    w8.reshape(w8.shape[0], w8.shape[1], w8.shape[2],
-                               n_chunks, block)
-                    .transpose(0, 3, 1, 2, 4))
-                scales = scales.at[:, page_ids].set(
-                    sc.reshape(sc.shape[0], sc.shape[1], 2, n_chunks,
-                               block).transpose(0, 3, 1, 2, 4))
-                pool = (codes, scales)
+                pool = (codes.at[:, page_ids].set(
+                            paged(w8, (0, 3, 4, 1, 2))),
+                        scales.at[:, page_ids].set(
+                            paged(sc, (0, 3, 1, 2, 4))))
             else:
                 (kvpool,) = pool
                 kv = jnp.stack([c[0] for c in caches])
                 pool = (kvpool.at[:, page_ids].set(
-                    kv.reshape(kv.shape[0], kv.shape[1], kv.shape[2],
-                               n_chunks, block)
-                    .transpose(0, 3, 1, 2, 4).astype(kvpool.dtype)),)
+                    paged(kv, (0, 3, 4, 1, 2)).astype(kvpool.dtype)),)
             key = jax.random.fold_in(
                 jax.random.PRNGKey(self.seed), ctr)
             _, sub = jax.random.split(key)
@@ -845,6 +760,9 @@ class DecodeScheduler:
         ts0 = obs.now()
         self._ctr += 1
         f = self._ensure_feed(act)
+        # the pages this step's attention walks (the position being
+        # written included), from the host's mirror: no device read
+        kv_pages = int(np.sum(self._lengths[act] // self.block + 1))
         ts1 = obs.now()
         nxt, pool, len_next = self._step_fn(
             self.model._decode_params(self.net), self.pager.pool,
@@ -871,8 +789,9 @@ class DecodeScheduler:
         # ``deliver`` (ts3 → here) is the push/retire loop above: host
         # time the device waits out before its next step
         obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
-                        args={"active": len(act)}, cause=self.cause,
-                        end=obs.now())
+                        args={"active": len(act), "kv_pages": kv_pages},
+                        cause=self.cause, end=obs.now())
+        obs.metrics.SERVING_KV_WALKED.set(kv_pages)
         obs.metrics.SERVING_STEP.observe(ts3 - ts0)
         obs.metrics.SERVING_TOKENS.inc(len(act))
         self.tokens_out += len(act)
@@ -1040,6 +959,20 @@ class DecodeScheduler:
         return False
 
     # -- AOT warmup ------------------------------------------------------
+    def _step_feed_shapes(self) -> tuple:
+        """The decode step's feed after ``(params, pool)``, as shapes
+        (WARMUP_FEEDS["_build_step_fn"]): what :meth:`warmup` compiles
+        from, and what ``chip_smoke.py`` and the AOT compile test
+        lower the step from to read its kernels."""
+        import jax
+        import jax.numpy as jnp
+
+        sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+        S, MP = self.max_slots, self.max_pages_per_seq
+        return (sds((S, MP), i32), sds((S,), i32), sds((S,), jnp.bool_),
+                sds((S,), i32), sds((S,), jnp.float32),
+                sds((), jnp.float32), sds((), i32))
+
     def warmup(self, prompt_lens=None) -> Dict[str, float]:
         """AOT-compile the decode step (one signature) and the prefill
         executable of every reachable prompt bucket BEFORE traffic —
@@ -1064,11 +997,8 @@ class DecodeScheduler:
         i32 = jnp.int32
         sds = jax.ShapeDtypeStruct
         S, MP = self.max_slots, self.max_pages_per_seq
-        seconds = self._step_fn.warmup(
-            params, pool_sds, sds((S, MP), i32), sds((S,), i32),
-            sds((S,), jnp.bool_), sds((S,), i32),
-            sds((S,), jnp.float32), sds((), jnp.float32),
-            sds((), i32))
+        seconds = self._step_fn.warmup(params, pool_sds,
+                                       *self._step_feed_shapes())
         compiled = seconds > 0
         for tb in buckets:
             dt = self._admit_fn(tb).warmup(

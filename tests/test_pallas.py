@@ -301,6 +301,101 @@ def _dense_causal(q, k, v):
     return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
 
 
+# ---------------------------------------------------------------------------
+# paged decode attention
+# ---------------------------------------------------------------------------
+def _paged_case(rng, dtype, h, n_kv, d, block, mp, n_live, layers=2):
+    """A pool whose live pages hold noise and whose TRASH page (and
+    every page no table row names) holds NaN: a kernel that reads one
+    of them poisons its output. Unused table entries point at trash,
+    as the scheduler leaves them."""
+    s_ = len(n_live)
+    n_pages = 1 + s_ * mp
+    pool = np.full((layers, n_pages, block, n_kv, 2 * d), np.nan,
+                   np.float32)
+    pt = np.zeros((s_, mp), np.int32)
+    for i, n in enumerate(n_live):
+        used = -(-n // block)
+        pt[i, :used] = 1 + i * mp + np.arange(used)
+        pool[:, pt[i, :used]] = rng.standard_normal(
+            (layers, used, block, n_kv, 2 * d))
+    q = jnp.asarray(rng.standard_normal((s_, h, d)), dtype)
+    return (q, jnp.asarray(pool, dtype), jnp.asarray(pt),
+            jnp.asarray(n_live, jnp.int32))
+
+
+# one batch holds the lengths that break page walks: 1, block - 1,
+# block, block + 1, an inactive slot, a slot at max_context, ragged
+_PAGED_N_LIVE = (1, 15, 16, 17, 0, 96, 33, 70)
+
+
+@pytest.mark.parametrize("pages_per_chunk", [2, None])
+@pytest.mark.parametrize("h,n_kv", [(32, 8), (8, 8)],
+                         ids=["gqa4to1", "mha1to1"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_paged_decode_matches_reference(monkeypatch, rng, dtype, tol,
+                                        h, n_kv, pages_per_chunk):
+    """The kernel (interpret mode) against the registered fallback,
+    layer 1 of 2, block 16, 6 pages a slot. (The int8 pool keeps the
+    fallback on every platform: ``_use_paged_kernel``.)"""
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    block, mp = 16, 6
+    q, pool, pt, n_live = _paged_case(rng, dtype, h, n_kv, 128, block,
+                                      mp, _PAGED_N_LIVE)
+    assert pk._use_paged_kernel(q, (pool,))
+    out = np.asarray(pk.paged_decode_attention(
+        q, (pool,), 1, pt, n_live, pages_per_chunk=pages_per_chunk),
+        np.float32)
+    # the fallback gathers every table entry, trash included, and
+    # masks afterwards: give it zeros where the kernel must not look
+    clean = jnp.nan_to_num(pool)
+    ref = np.asarray(pk._reference_paged_attention(
+        q[:, None], (clean,), 1, pt, (n_live - 1)[:, None])[:, 0],
+        np.float32)
+    live = np.asarray(n_live) > 0
+    assert not np.isnan(out).any()          # trash was never read
+    assert np.abs(out[live] - ref[live]).max() < tol
+    assert (out[~live] == 0).all()          # inactive: zeros, no walk
+
+
+def test_paged_decode_dispatch_line(monkeypatch, rng):
+    """Which shapes the kernel takes is read off the operands: a
+    128-lane head over 8-row kv tiles in a float pool on the kernel
+    platform; everything else runs the reference, and says the same."""
+    calls = []
+    real = pk._paged_decode_call
+    monkeypatch.setattr(
+        pk, "_paged_decode_call",
+        lambda *a, **kw: calls.append(a[0].shape) or real(*a, **kw))
+
+    def run(h, n_kv, d, quant=False):
+        q, pool, pt, n_live = _paged_case(rng, jnp.float32, h, n_kv, d,
+                                          8, 3, (5, 0, 24))
+        pool = jnp.nan_to_num(pool)
+        tup = (pool,)
+        if quant:
+            tup = (pool.astype(jnp.int8),
+                   jnp.ones((2, pool.shape[1], n_kv, 2, 8), jnp.float32))
+        out = pk.paged_decode_attention(q, tup, 0, pt, n_live)
+        ref = pk._reference_paged_attention(
+            q[:, None], tup, 0, pt, (n_live - 1)[:, None])[:, 0]
+        assert out.shape == q.shape
+        assert float(jnp.abs(out[0] - ref[0]).max()) < 2e-5
+        assert float(jnp.abs(out[1]).max()) == 0.0      # inactive
+        return len(calls)
+
+    assert run(8, 8, 128) == 0              # CPU: the fallback
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    assert run(8, 8, 128) == 1              # over the line: the kernel
+    assert run(16, 8, 128) == 2
+    assert run(8, 8, 64) == 2               # head under 128 lanes
+    assert run(12, 12, 128) == 2            # kv heads not whole tiles
+    assert run(4, 2, 128) == 2
+    assert run(8, 8, 128, quant=True) == 2  # int8 codes and scales
+
+
 def test_reference_scan_matches_full_attention(rng):
     # the O(T)-memory backward path is itself correct
     bh, t, d = 3, 130, 16

@@ -105,6 +105,109 @@ def test_paged_decode_token_identical_to_dense(cache_quant):
     gw.shutdown()
 
 
+def test_pool_holds_positions_in_order_after_prefill_and_decode():
+    """The layout fence: what ``serving.prefill`` scatters into pages
+    and what each decode step appends, read back through the slot's
+    page-table row as ``[position, kv head, K|V]``, is the dense cache
+    of the same tokens, position for position, in every layer."""
+    import jax.numpy as jnp
+    model = GPTNano(vocab_size=64, max_len=64, seed=7)
+    net = model.init()
+    prompt = np.random.default_rng(3).integers(0, 64, 11).astype(np.int32)
+    sched = DecodeScheduler(model, net, max_slots=2, block=8,
+                            max_context=64)
+    r = _Req(prompt, 9)
+    assert sched.admit(r)
+    pages = list(sched.pager.owned(r))
+    for _ in range(7):                      # crosses into a new page
+        sched.step()
+    (kv,) = sched.pager.pool
+    n = 11 + 7                              # positions written so far
+    assert kv.shape[2:] == (8, model.n_kv_heads,
+                            2 * model.hidden // model.n_heads)
+    got = np.asarray(kv[:, np.asarray(pages)]).reshape(
+        model.n_layers, -1, *kv.shape[3:])[:, :n]
+    toks = np.concatenate([prompt, np.asarray(r.tokens[:7], np.int32)])
+    pad = np.zeros((1, 32), np.int32)
+    pad[0, :n] = toks
+    _, caches = model._prefill_forward(
+        model._decode_params(net), jnp.asarray(pad), 32,
+        jnp.asarray(n, jnp.int32))
+    # dense cache: [1, Hkv, 2D, T] a layer -> [T, Hkv, 2D]
+    want = np.stack([np.asarray(c)[0].transpose(2, 0, 1)[:n]
+                     for c in caches])
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the trash page took nothing but inactive slots' writes, and no
+    # page outside the reservation was touched
+    other = np.setdiff1d(np.arange(1, sched.pager.n_pages), pages)
+    assert not np.asarray(kv[:, other]).any()
+
+
+def test_decode_step_record_counts_the_pages_walked(tiny):
+    """``kv_pages`` on every ``serving.decode_step`` record, and the
+    gauge beside the occupancy gauge: sum(ceil(length / block)) over
+    the active slots, the position being written included."""
+    from deeplearning4j_tpu import obs
+    model, net = tiny
+    sched = DecodeScheduler(model, net, max_slots=3, block=8,
+                            max_context=64)
+    rng = np.random.default_rng(1)
+    reqs = [_Req(rng.integers(0, 64, t).astype(np.int32), n)
+            for t, n in ((7, 12), (16, 3), (25, 9))]
+    for r in reqs:
+        assert sched.admit(r)
+    mark = obs.now()
+    seen = 0
+    while any(not r.done for r in reqs):
+        act = [i for i, sl in enumerate(sched._slots) if sl is not None]
+        want = sum(-(-(int(sched._lengths[i]) + 1) // 8) for i in act)
+        sched.step()
+        rec = [e for e in obs.trace.records(since=mark)
+               if e.name == "serving.decode_step"][-1]
+        assert rec.counts["kv_pages"] == want, (rec.counts, want)
+        assert rec.counts["active"] == len(act)
+        assert obs.metrics.SERVING_KV_WALKED.snapshot()[""] == want
+        seen += 1
+    assert seen >= 11
+
+
+def test_paged_kernel_in_the_step_token_identical_to_dense(monkeypatch):
+    """The decode step with the KERNEL in it (forced, interpret mode;
+    8 kv heads of 128 lanes put the shape over the dispatch line)
+    against dense ``generate()``, float32, staggered admissions."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    calls = []
+    real = pk._paged_decode_call
+    monkeypatch.setattr(
+        pk, "_paged_decode_call",
+        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    model = CausalTransformerLM(vocab_size=64, hidden=1024, n_layers=2,
+                                n_heads=8, n_kv_heads=8, max_len=64,
+                                ffn_mult=1, seed=4)
+    net = model.init()
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 64, t).astype(np.int32)
+               for t in (5, 17, 9)]
+    budgets = [9, 4, 12]
+    dense = [np.asarray(model.generate(net, p[None], n_new=n))[0]
+             for p, n in zip(prompts, budgets)]
+    sched = DecodeScheduler(model, net, max_slots=2, block=8,
+                            max_context=64)
+    reqs = [_Req(p, n) for p, n in zip(prompts, budgets)]
+    waiting = list(reqs)
+    while waiting or sched.active_count():
+        while waiting and sched.can_admit(len(waiting[0].prompt),
+                                          waiting[0].max_new):
+            assert sched.admit(waiting.pop(0))
+        sched.step()
+    assert len(calls) == model.n_layers     # traced once: one a layer
+    for r, p, d in zip(reqs, prompts, dense):
+        np.testing.assert_array_equal(
+            np.concatenate([p, np.asarray(r.tokens, np.int32)]), d)
+    sched.pager.check_invariants()
+
+
 # =========================================================================
 # pager invariants
 # =========================================================================

@@ -147,3 +147,104 @@ def test_threshold_codec_compiles_for_v5e(chip):
     hlo = _compile(roundtrip, chip, ((n,), jnp.float32),
                    ((), jnp.float32))
     assert hlo.count("tpu_custom_call") >= 2
+
+
+# the serving cells' gateway (benchmarks/configs/mistral-7b-v0.3-6l:
+# 32 slots x 160 pages of 16, 32 heads over 8 kv heads x 128, 6 layers)
+_CELL = dict(slots=32, max_pages=160, block=16, heads=32, kv_heads=8,
+             head=128, layers=6)
+
+
+def _cell_pool(chip):
+    c = _CELL
+    return jax.ShapeDtypeStruct(
+        (c["layers"], 1 + c["slots"] * c["max_pages"], c["block"],
+         c["kv_heads"], 2 * c["head"]), BF16, sharding=chip)
+
+
+def _pool_sized_ops(hlo, pool):
+    """Opcodes of the instructions whose result is the pool's shape."""
+    import re
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    return sorted(set(re.findall(
+        r"= " + re.escape(shape) + r"\S* ([\w\-]+)\(", hlo)))
+
+
+def test_paged_decode_attention_compiles_for_v5e(chip):
+    c = _CELL
+    pool = _cell_pool(chip)
+
+    def attend(q, kv, pt, n_live):
+        return pallas_kernels.paged_decode_attention(q, (kv,), 3, pt,
+                                                     n_live)
+
+    compiled = jax.jit(attend).lower(
+        jax.ShapeDtypeStruct((c["slots"], c["heads"], c["head"]), BF16,
+                             sharding=chip),
+        pool,
+        jax.ShapeDtypeStruct((c["slots"], c["max_pages"]), jnp.int32,
+                             sharding=chip),
+        jax.ShapeDtypeStruct((c["slots"],), jnp.int32, sharding=chip),
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode_attention" in hlo
+    # the pool reaches the kernel as it lies: a parameter and a bitcast
+    assert _pool_sized_ops(hlo, pool) == ["parameter"], hlo[:2000]
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_serving_decode_step_compiles_for_v5e_without_touching_the_pool(
+        chip):
+    """The whole ``serving.decode_step`` at the cell's sizes: one
+    kernel a layer, lowered ONCE, and nothing of the pool's size but
+    the in-place scatters of each layer's new KV row (no gather of the
+    pool, no copy into another layout in front of the kernel, no
+    temporary: the parent's step held 2.7 GB of gathered contexts)."""
+    import re
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.serving import DecodeScheduler
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    c = _CELL
+    model = CausalTransformerLM(
+        vocab_size=32768, hidden=c["heads"] * c["head"],
+        n_layers=c["layers"], n_heads=c["heads"],
+        n_kv_heads=c["kv_heads"], max_len=4096, ffn_mult=3.5,
+        rope_theta=1e6, tie_embeddings=False,
+        updater=upd.Sgd(learning_rate=0.0), compute_dtype="bfloat16",
+        seed=1)
+    # shapes only: nothing of the 1.6 B parameters is made here, and
+    # the scheduler's own pool is two pages (the step takes the pool
+    # it is handed)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        jax.eval_shape(lambda: model._cast_decode(model.init().params)))
+    sched = DecodeScheduler(
+        model, None, max_slots=c["slots"], block=c["block"],
+        max_context=c["max_pages"] * c["block"], n_pages=2)
+    pool = _cell_pool(chip)
+    lowered = sched._step_fn.lower(
+        params, (pool,),
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes()))
+    funcs = re.findall(r"func\.func private @(\w*paged_decode\w*)",
+                       lowered.as_text())
+    assert len(funcs) == 1, funcs           # one lowering for 6 layers
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    kernels = re.findall(
+        r"= \S+ custom-call\([^\n]*tpu_custom_call[^\n]*"
+        r"paged_decode_attention", hlo)
+    assert len(kernels) == c["layers"], len(kernels)
+    # parameter -> scatter fusion a layer -> (bitcast into the kernel)
+    assert set(_pool_sized_ops(hlo, pool)) <= {
+        "parameter", "fusion", "scatter", "bitcast"}, \
+        _pool_sized_ops(hlo, pool)
+    for comp in re.split(r"\n(?=\S)", hlo):
+        if "bf16[" + ",".join(map(str, pool.shape)) + "]" in comp \
+                and not comp.startswith("ENTRY"):
+            assert " gather(" not in comp and " copy(" not in comp, \
+                comp[:400]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+    assert mem.alias_size_in_bytes >= 2 * 10 ** 9   # the pool, in place

@@ -414,6 +414,12 @@ bottleneck, add pages or shed earlier) and
 `dl4j_tpu_serving_kv_pages_reserved` per tenant (whole-life
 reservations — one tenant pinning the pool starves the rest; the
 `tpu_watch` serving view surfaces both next to `kv_pages_free`).
+`dl4j_tpu_serving_kv_pages_walked` is the pages the last decode
+step's attention read (the sum over active slots of
+`ceil(length / block)`, also `kv_pages` on every
+`serving.decode_step` record): against `max_slots × max_context /
+block` page-table entries it is the share of the pool a step touches,
+and what the paged kernel's time should follow (ARCHITECTURE.md §17).
 """
 
 # hand-maintained operations doc, re-emitted on every regeneration

@@ -242,8 +242,9 @@ SCOPE_SITES = {
                              "_build_suffix_admit_fn"),
     "parallel/zero.py": ("scatter_mean", "gather"),
     "ops/pallas_kernels.py": ("flash_attention", "flash_block_fwd",
-                              "flash_block_bwd", "threshold_encode",
-                              "threshold_decode"),
+                              "flash_block_bwd",
+                              "paged_decode_attention",
+                              "threshold_encode", "threshold_decode"),
     "ops/fused_norms.py": ("rms_norm", "add_rms_norm", "layer_norm"),
 }
 

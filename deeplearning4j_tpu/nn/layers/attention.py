@@ -363,6 +363,78 @@ class TransformerEncoderBlock(Layer):
 
 @register_layer
 @dataclass
+class PowerRetention(Layer):
+    """The power-retention sequence mixer (``ops/retention.py`` has
+    the equations): grouped queries against a gated second-power
+    state in place of a softmax over every cached key. Projections as
+    :class:`MultiHeadAttention` has them, plus a per-head RMSNorm of q
+    and k (gains ``q_gamma``/``k_gamma``), rotary positions, and a
+    gate ``g = sigmoid(h Wgate + bgate)`` per KV head. The training
+    forward is the chunked form in plain ``jnp``
+    (``retention.TRAIN_CHUNK`` positions by the attention form, the
+    state across them), differentiated by autodiff; the gate's bias
+    starts at ``retention.GATE_BIAS_INIT``. Causal by construction."""
+    n_in: Optional[int] = None
+    n_heads: int = 1
+    n_kv_heads: Optional[int] = None
+    rope_theta: float = 10000.0
+
+    def init(self, key, input_shape, dtype=jnp.float32):
+        from deeplearning4j_tpu.ops import retention
+        f = self.n_in or input_shape[-1]
+        if f % self.n_heads:
+            raise ValueError(f"n_in={f} not divisible by "
+                             f"n_heads={self.n_heads}")
+        n_kv = self.n_kv_heads or self.n_heads
+        if self.n_heads % n_kv:
+            raise ValueError(f"n_heads={self.n_heads} not divisible "
+                             f"by n_kv_heads={n_kv}")
+        hd = f // self.n_heads
+        if hd % 8:
+            raise ValueError(f"head_dim={hd} must be a multiple of 8 "
+                             "(the retention state's row tiles)")
+        wi = winit.get(self.weight_init or "xavier")
+        kq, kk, kv_, ko, kg = jax.random.split(key, 5)
+        params = {"Wq": wi(kq, (f, f), dtype),
+                  "Wk": wi(kk, (f, hd * n_kv), dtype),
+                  "Wv": wi(kv_, (f, hd * n_kv), dtype),
+                  "Wo": wi(ko, (f, f), dtype),
+                  "bo": jnp.zeros((f,), dtype),
+                  "Wgate": wi(kg, (f, n_kv), dtype),
+                  "bgate": jnp.full((n_kv,), retention.GATE_BIAS_INIT,
+                                    dtype),
+                  "q_gamma": jnp.ones((hd,), dtype),
+                  "k_gamma": jnp.ones((hd,), dtype)}
+        return params, {}, (input_shape[0], f)
+
+    def apply(self, params, state, x, *, train=False, rng=None,
+              mask=None):
+        from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
+        from deeplearning4j_tpu.ops import retention
+        b, t, f = x.shape
+        n_kv = self.n_kv_heads or self.n_heads
+
+        def rotate(z):      # [B*T, heads, d] -> rotated by position
+            return rotary_embedding(
+                z.reshape(b, t, *z.shape[1:]),
+                self.rope_theta).reshape(z.shape)
+
+        q, k, v, log_g = retention.project(
+            params, x.reshape(b * t, f), self.n_heads, n_kv, rotate,
+            RMSNORM_EPS)
+        y, _ = retention.retention_sequence(
+            q.reshape(b, t, self.n_heads, -1),
+            k.reshape(b, t, n_kv, -1), v.reshape(b, t, n_kv, -1),
+            log_g.reshape(b, t, n_kv), retention.TRAIN_CHUNK,
+            valid=None if mask is None else mask.astype(bool))
+        o = _merge_heads(y) @ params["Wo"] + params["bo"]
+        if mask is not None:
+            o = o * mask[..., None].astype(o.dtype)
+        return o, state
+
+
+@register_layer
+@dataclass
 class TransformerDecoderBlock(Layer):
     """Pre-RMSNorm causal decoder block (modern-LM style): grouped-
     query attention with rotary embeddings + SwiGLU MLP, residuals
@@ -385,16 +457,34 @@ class TransformerDecoderBlock(Layer):
     rope_theta: float = 10000.0
     sequence_parallel: Optional[str] = None
     remat: bool = False
+    #: the sequence mixer: "softmax" (attention over every cached key)
+    #: or "power_retention" (:class:`PowerRetention`; its parameters
+    #: take the same place, ``params["mha"]``)
+    mixer: str = "softmax"
 
     def _subs(self):
         if not hasattr(self, "_mha"):
             from deeplearning4j_tpu.nn.layers.core import RMSNorm
             f = self.n_in
-            self._mha = MultiHeadAttention(
-                n_in=f, n_out=f, n_heads=self.n_heads,
-                n_kv_heads=self.n_kv_heads, causal=True, rope=True,
-                rope_theta=self.rope_theta,
-                sequence_parallel=self.sequence_parallel)
+            if self.mixer == "power_retention":
+                if self.sequence_parallel:
+                    raise ValueError(
+                        "mixer='power_retention' has no sequence-"
+                        "parallel form: the state passes from chunk "
+                        "to chunk, there is no ring to turn")
+                self._mha = PowerRetention(
+                    n_in=f, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads,
+                    rope_theta=self.rope_theta)
+            elif self.mixer != "softmax":
+                raise ValueError(f"mixer={self.mixer!r} "
+                                 "('softmax' | 'power_retention')")
+            else:
+                self._mha = MultiHeadAttention(
+                    n_in=f, n_out=f, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, causal=True, rope=True,
+                    rope_theta=self.rope_theta,
+                    sequence_parallel=self.sequence_parallel)
             self._ln1 = RMSNorm()
             self._ln2 = RMSNorm()
 

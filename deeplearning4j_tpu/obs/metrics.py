@@ -109,6 +109,8 @@ FAMILIES = {
     "dl4j_tpu_serving_kv_page_occupancy": "gauge",
     "dl4j_tpu_serving_kv_pages_reserved": "gauge",
     "dl4j_tpu_serving_kv_pages_walked": "gauge",
+    "dl4j_tpu_serving_state_pool_bytes": "gauge",
+    "dl4j_tpu_serving_state_bytes_moved": "counter",
     # speculative multi-token decode (serving/scheduler.py)
     "dl4j_tpu_serving_spec_accept_rate": "histogram",
     "dl4j_tpu_serving_spec_drafted_total": "counter",
@@ -517,6 +519,14 @@ SERVING_KV_WALKED = REGISTRY.gauge(
     "KV pages the last decode step's attention read: the sum over "
     "active slots of ceil(length / block), against max_slots x "
     "max_pages_per_seq page-table entries")
+SERVING_STATE_POOL = REGISTRY.gauge(
+    "dl4j_tpu_serving_state_pool_bytes",
+    "bytes of the recurrent-state pool as stored (0 for a KV-page "
+    "pool): one fixed-size page a sequence, trash page included")
+SERVING_STATE_MOVED = REGISTRY.counter(
+    "dl4j_tpu_serving_state_bytes_moved",
+    "bytes of recurrent state the decode steps have read and written "
+    "(logical size d(d+1)/2 rows a kv head, float32; both directions)")
 
 # speculative multi-token decode + copy-on-write prefix sharing
 # (serving/scheduler.py + serving/kv_pager.py): accept rate is the

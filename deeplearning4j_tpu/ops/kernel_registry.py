@@ -73,6 +73,17 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "closes": ("paged_decode.block_*",),
         "gate": "paged_decode",
     },
+    "retention_decode": {
+        "module": "ops/pallas_kernels.py",
+        "fallback": "_reference_retention_decode",
+        "parity":
+            "tests/test_pallas.py::test_retention_decode_matches_reference",
+        "scope": "ops.retention_decode",
+        # the retention model's decode blocks: their mixer streams the
+        # state pages through VMEM (projections and the MLP stay XLA)
+        "closes": ("retention_decode.block_*",),
+        "gate": "retention_decode",
+    },
     "threshold_encode": {
         "module": "ops/pallas_kernels.py",
         "fallback": "_jnp_threshold_encode",

@@ -1080,6 +1080,227 @@ def paged_decode_attention(q, pool, li, pt, n_live,
 
 
 # ---------------------------------------------------------------------------
+# retention decode over the paged state pool
+# ---------------------------------------------------------------------------
+#
+# One decode position of a power-retention block (ops/retention.py has
+# the mathematics and the stored layout) for every live slot, in place:
+# the grid walks slots x kv heads; each step streams ONE head's state
+# ``[rows, d]`` HBM -> VMEM (Pallas' own double-buffered pipeline, the
+# block chosen through the scalar-prefetched page ids), computes
+# ``g * S + phi(k) v^T`` tile by tile, reads the group's query heads
+# from the same tiles, and writes the state back through
+# ``input_output_aliases``. An inactive slot maps to the block of the
+# last live (slot, head) before it and skips its body: the pipeline
+# sees an unchanged block index, so nothing of that slot is fetched or
+# written.
+#
+# The arithmetic is the VPU's: the state is float32 and a float32
+# matmul on the MXU would push every state tile through it as weights,
+# several passes each. A tile is 8 rows of one stored block: the rows
+# ``(i, 8J .. 8J+7)``; ``k_i`` and the queries' ``q_i`` reach it as
+# sublane broadcasts, ``k_j v`` and ``q_j`` as whole tiles.
+
+
+def _retention_decode_kernel(li_ref, page_ref, head_ref, act_ref,
+                             q_ref, k_ref, v_ref, g_ref, s_in, z_in,
+                             o_ref, s_out, z_out, kb, qb, kv, *,
+                             nb: int, groups: int, eps: float):
+    # scalar prefetch: li [1], page/head/act [S]. q_ref [G, d], k_ref /
+    # v_ref / g_ref [1, d] (g repeated along the lanes), s_in/s_out
+    # [rows, d], z_in/z_out [d, d], o_ref [G, d]; scratch kb [d, d]
+    # (row j = k_j on every lane), qb [G, d, d] likewise, kv [d, d]
+    # (row j = k_j * v)
+    del li_ref, page_ref, head_ref
+    d = 8 * nb
+
+    @pl.when(act_ref[pl.program_id(0)] != 0)
+    def _():
+        g8 = jnp.broadcast_to(g_ref[...], (8, d))
+        k_row = k_ref[...]
+        kb[...] = jnp.broadcast_to(k_row, (d, d)).T
+        for h in range(groups):
+            qb[h] = jnp.broadcast_to(q_ref[h:h + 1, :], (d, d)).T
+        kv[...] = kb[...] * v_ref[...]
+        sub = lax.broadcasted_iota(jnp.int32, (8, d), 0)
+        zeros = tuple(jnp.zeros((8, d), jnp.float32)
+                      for _ in range(groups))
+
+        def tiles(base, i0, weight, acc):
+            # the 8 tiles of one stored block: tile a holds the pairs
+            # (i0 + a, 8J .. 8J+7); ``weight(a)`` is k_j v with the
+            # write side's multiplicity
+            ki = kb[pl.ds(i0, 8), :]
+            qi = [qb[h, pl.ds(i0, 8), :] for h in range(groups)]
+            acc = list(acc)
+            for a in range(8):
+                rows = pl.ds(pl.multiple_of(base + 8 * a, 8), 8)
+                t = (g8 * s_in[rows, :] + jnp.broadcast_to(
+                    ki[a:a + 1, :], (8, d)) * weight(a))
+                s_out[rows, :] = t
+                for h in range(groups):
+                    acc[h] = acc[h] + jnp.broadcast_to(
+                        qi[h][a:a + 1, :], (8, d)) * t
+            return tuple(acc)
+
+        def column(jb, out):
+            j0 = pl.multiple_of(jb * 8, 8)
+            kvj = kv[pl.ds(j0, 8), :]
+            kvj2 = kvj + kvj
+            first = jb * (jb + 1) // 2      # stored blocks before J's
+
+            def block(ib, acc):
+                return tiles((first + ib) * 64,
+                             pl.multiple_of(ib * 8, 8), lambda a: kvj2,
+                             acc)
+
+            acc = lax.fori_loop(0, jb, block, zeros)
+            # the diagonal block: 1 on the diagonal, 2 above it, 0 for
+            # the mirror images below
+            acc = tiles((first + jb) * 64, j0, lambda a: kvj * jnp.where(
+                sub > a, 2.0, jnp.where(sub == a, 1.0, 0.0)), acc)
+            return tuple(out[h] + qb[h, pl.ds(j0, 8), :] * acc[h]
+                         for h in range(groups))
+
+        num = lax.fori_loop(0, nb, column, zeros)
+        z = (jnp.broadcast_to(g_ref[...], (d, d)) * z_in[...]
+             + kb[...] * k_row)
+        z_out[...] = z
+        for h in range(groups):
+            den = jnp.sum(jnp.sum(z * qb[h] * q_ref[h:h + 1, :], axis=0,
+                                  keepdims=True), axis=1, keepdims=True)
+            o_ref[h:h + 1, :] = (
+                jnp.sum(num[h], axis=0, keepdims=True)
+                / (den + eps)).astype(o_ref.dtype)
+
+    @pl.when(act_ref[pl.program_id(0)] == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _retention_decode_call(q, k, v, g, s_pool, z_pool, li, pages, active,
+                           eps, interpret):
+    """ONE lowering for every layer of a step (the layer index is a
+    scalar operand), as :func:`_paged_decode_call`."""
+    n_s, n_kv, groups, d = q.shape
+    rows = s_pool.shape[3]
+    act = active.astype(jnp.int32)
+    # an inactive slot rides the block of the nearest live slot before
+    # it (that slot's last head), or of the first live slot (its first
+    # head) when none precedes it: an unchanged block is not moved
+    idx = jnp.arange(n_s, dtype=jnp.int32)
+    last = lax.cummax(jnp.where(act != 0, idx, -1))
+    src = jnp.where(last >= 0, last, jnp.argmax(act).astype(jnp.int32))
+    page = jnp.where(jnp.any(act != 0), pages.astype(jnp.int32)[src], 0)
+    head = jnp.where(last >= 0, n_kv - 1, 0).astype(jnp.int32)
+
+    def pooled(s, h, li_ref, page_ref, head_ref, act_ref):
+        return (li_ref[0], page_ref[s],
+                jnp.where(act_ref[s] != 0, h, head_ref[s]), 0, 0)
+
+    def row(s, h, *_):
+        return (s, h, 0, 0)
+
+    vec = pl.BlockSpec((None, None, 1, d), row)
+    state = pl.BlockSpec((None, None, None, rows, d), pooled)
+    norm = pl.BlockSpec((None, None, None, d, d), pooled)
+    qspec = pl.BlockSpec((None, None, groups, d), row)
+    y, s_pool, z_pool = pl.pallas_call(
+        functools.partial(_retention_decode_kernel, nb=d // 8,
+                          groups=groups, eps=eps),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype),
+                   jax.ShapeDtypeStruct(z_pool.shape, z_pool.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_s, n_kv),
+            in_specs=[qspec, vec, vec, vec, state, norm],
+            out_specs=(qspec, state, norm),
+            scratch_shapes=[pltpu.VMEM((d, d), jnp.float32),
+                            pltpu.VMEM((groups, d, d), jnp.float32),
+                            pltpu.VMEM((d, d), jnp.float32)]),
+        # operands count from the scalar-prefetch ones: the pools are
+        # the 9th and 10th
+        input_output_aliases={8: 1, 9: 2},
+        # the unchanged-block rule above needs the grid in order
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_RETENTION_VMEM_BYTES),
+        interpret=interpret,
+        name="retention_decode",
+    )(li.reshape(1).astype(jnp.int32), page, head, act, q,
+      k[:, :, None, :], v[:, :, None, :],
+      jnp.broadcast_to(g[:, :, None, None], (n_s, n_kv, 1, d)),
+      s_pool, z_pool)
+    return y, s_pool, z_pool
+
+
+#: the state block in and out, double-buffered (4 x 4.46 MB at d =
+#: 128), the normaliser's, the scratch tiles, and room for the
+#: compiler's own: over the 16 MiB the compiler allows by default
+_RETENTION_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _reference_retention_decode(q, k, v, g, pool, li, pages, active,
+                                eps):
+    """One retention decode position per slot in plain jnp over the
+    paged state pool: the registered fallback of
+    :func:`retention_decode` (the CPU runs it, the parity test
+    compares against it). An inactive slot reads and writes the trash
+    page and returns zeros."""
+    from deeplearning4j_tpu.ops import retention
+    n_s, n_kv, groups, d = q.shape
+    pids = jnp.where(active, pages, 0)
+    state = (pool[0][li, pids], pool[1][li, pids])
+    y, (s1, z1) = retention.retention_step(
+        q.reshape(n_s, n_kv * groups, d), k, v, jnp.log(g), state, eps)
+    y = jnp.where(active[:, None, None], y.astype(jnp.float32), 0.0)
+    return (y.reshape(q.shape), pool[0].at[li, pids].set(s1),
+            pool[1].at[li, pids].set(z1))
+
+
+def _use_retention_kernel(q) -> bool:
+    """The dispatch line of :func:`retention_decode`: the platform gate
+    every kernel uses, and a head that fills whole 128-lane tiles (the
+    state's minor dimension is the value head's width)."""
+    from deeplearning4j_tpu.ops.kernel_registry import gate_active
+    return gate_active("retention_decode") and q.shape[-1] % 128 == 0
+
+
+def retention_decode(q, k, v, g, pool, li, pages, active,
+                     eps: Optional[float] = None):
+    """One decode position of a power-retention block for every slot,
+    the recurrent state updated in place in the paged pool. ``q``
+    [S, H, d] and ``k``/``v`` [S, Hkv, d] (normalised, rotated), ``g``
+    [S, Hkv] float32 in (0, 1); ``pool`` the pager's ``(S, Z)`` arrays
+    (``serving/kv_pager.py``: ``[L, P, Hkv, rows, d]`` and
+    ``[L, P, Hkv, d, d]`` float32); ``li`` the layer (Python int or
+    i32 scalar); ``pages`` [S] i32 each slot's state page; ``active``
+    [S] bool. Returns ``(y [S, H, d] in q's dtype, pool)``; an inactive
+    slot's rows are zeros and its page is neither read nor written.
+    Shapes the kernel does not take (:func:`_use_retention_kernel`)
+    run :func:`_reference_retention_decode`."""
+    from deeplearning4j_tpu.obs import devtime
+    from deeplearning4j_tpu.ops.retention import RETENTION_EPS
+    eps = RETENTION_EPS if eps is None else float(eps)
+    n_s, n_h, d = q.shape
+    n_kv = k.shape[1]
+    with devtime.scope("ops.retention_decode"):
+        args = (q.reshape(n_s, n_kv, n_h // n_kv, d).astype(jnp.float32),
+                k.astype(jnp.float32), v.astype(jnp.float32),
+                g.astype(jnp.float32))
+        if _use_retention_kernel(q):
+            y, s_pool, z_pool = _retention_decode_call(
+                *args, pool[0], pool[1], jnp.asarray(li, jnp.int32),
+                pages, active, eps=eps, interpret=_interpret())
+        else:
+            y, s_pool, z_pool = _reference_retention_decode(
+                *args, pool, li, pages, active, eps)
+        return y.reshape(q.shape).astype(q.dtype), (s_pool, z_pool)
+
+
+# ---------------------------------------------------------------------------
 # threshold compression codec
 # ---------------------------------------------------------------------------
 _GROUP = 16          # 16 two-bit codes per int32 word

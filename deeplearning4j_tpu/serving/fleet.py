@@ -58,6 +58,7 @@ from deeplearning4j_tpu.serving.gateway import SequenceAborted
 STARTUP_PREFETCH = (
     "_build_step_fn",
     "_build_admit_fn",
+    "_build_chunk_admit_fn",
     "_build_spec_step_fn",
     "_build_suffix_admit_fn",
     "_build_cow_fn",

@@ -41,6 +41,24 @@ carries as its donated pool argument):
   stay minor here: a minor dimension of 2 would pad every pair of
   scales to a 128-lane row.
 
+A model whose blocks keep a recurrent state instead of a KV cache
+(``CausalTransformerLM(mixer="power_retention")``) gets a pool of
+**fixed-size state pages** from the same pager (``state_rows``): ONE
+page a sequence whatever its length, the pair
+
+- ``S`` ``[L, P, Hkv, rows, d]`` float32: page ``p`` of layer ``l``
+  holds each kv head's second-power state in the stored layout of
+  ``ops/retention.py`` (``rows`` = ``retention.state_rows(d)``), one
+  head's ``[rows, d]`` a contiguous run of whole (8, 128) tiles, which
+  is what ``ops.retention_decode`` streams through VMEM and writes
+  back in place;
+- ``Z`` ``[L, P, Hkv, d, d]`` float32: the normaliser.
+
+Allocation, reservation, the free list and every invariant below are
+the same; ``pages_for`` answers 1, and nothing is ever shared (a
+state is no pure function of a prefix's tokens alone that another
+sequence could adopt mid-way: there is no chain index to consult).
+
 Page 0 is the reserved **trash page**: inactive slots' writes and
 unallocated page-table entries route there, so a fixed-shape step can
 always scatter/gather without corrupting live sequences (reads of
@@ -91,8 +109,12 @@ class KVPager:
 
     def __init__(self, *, n_layers: int, n_kv_heads: int, head_dim: int,
                  n_pages: int, block: int, cache_quant: Optional[str],
-                 dtype: str = "float32"):
+                 dtype: str = "float32",
+                 state_rows: Optional[int] = None):
         import jax.numpy as jnp
+        if state_rows is not None and cache_quant is not None:
+            raise ValueError("a recurrent-state pool is float32: "
+                             "cache_quant does not apply to it")
         if block < 1 or block & (block - 1):
             raise ValueError(f"block={block} must be a power of two "
                              "(pages must tile the power-of-two "
@@ -109,8 +131,16 @@ class KVPager:
         self.n_pages = n_pages
         self.block = block
         self.cache_quant = cache_quant
+        #: rows of a kv head's stored state (None: a KV-page pool)
+        self.state_rows = state_rows
         shape = (n_layers, n_pages, block, n_kv_heads, 2 * head_dim)
-        if cache_quant == "int8":
+        if state_rows is not None:
+            self._pool: Tuple = (
+                jnp.zeros((n_layers, n_pages, n_kv_heads, state_rows,
+                           head_dim), jnp.float32),
+                jnp.zeros((n_layers, n_pages, n_kv_heads, head_dim,
+                           head_dim), jnp.float32))
+        elif cache_quant == "int8":
             self._pool: Tuple = (
                 jnp.zeros(shape, jnp.int8),
                 jnp.zeros((n_layers, n_pages, n_kv_heads, 2, block),
@@ -141,13 +171,16 @@ class KVPager:
         self._tenant_pages: Dict[str, int] = {}
         self._tenant_labels: set = set()
         self.max_tenant_labels = 64
+        _metrics.SERVING_STATE_POOL.set(
+            self.pool_bytes() if state_rows is not None else 0)
         self._gauge()
 
     # -- device pool -----------------------------------------------------
     @property
     def pool(self) -> Tuple:
         """The layer-stacked device arrays the jitted step reads and
-        rewrites: ``(codes,)`` or ``(codes, scales)``."""
+        rewrites: ``(codes,)``, ``(codes, scales)`` or, for a
+        recurrent-state pool, ``(S, Z)``."""
         return self._pool
 
     @pool.setter
@@ -163,7 +196,10 @@ class KVPager:
         return len(self._free)
 
     def pages_for(self, n_tokens: int) -> int:
-        """Pages needed to hold ``n_tokens`` cache positions."""
+        """Pages needed to hold ``n_tokens`` cache positions (a
+        recurrent state takes one page whatever the length)."""
+        if self.state_rows is not None:
+            return 1
         return -(-int(n_tokens) // self.block)
 
     def alloc(self, n: int, owner) -> Optional[List[int]]:

@@ -38,6 +38,16 @@ sits in live pages ADOPTS them (refcount++), prefill runs only on the
 novel suffix, and any write to a page with refcount > 1 first clones
 it (copy-on-write) so siblings never observe the writer.
 
+A model of ``mixer="power_retention"`` blocks runs the same loop over
+a pool of fixed-size recurrent-state pages (one a sequence,
+``kv_pager.py``): admission runs ONE prefill program of
+:data:`PREFILL_CHUNK` rows ``ceil(t0 / chunk)`` times, carrying the
+state in the sequence's page, so no prompt needs a bucket as long as
+itself; the decode step updates every live slot's state in place
+(``ops.retention_decode``) and leaves an inactive slot's page alone.
+Prefix sharing and speculative decode would need snapshots of a state
+and are refused at construction for such a model.
+
 The scheduler is single-threaded host logic (the gateway's worker
 drives it); requests are duck-typed: ``.prompt`` (1-D int32),
 ``.max_new``, ``.temperature``, ``.eos_id``, and ``push(tok)`` /
@@ -51,9 +61,10 @@ import numpy as np
 
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.ops.pallas_kernels import (
-    _reference_paged_attention, paged_decode_attention)
+    _reference_paged_attention, paged_decode_attention, retention_decode)
 from deeplearning4j_tpu.serving.kv_pager import KVPager
-from deeplearning4j_tpu.zoo.gpt import _quant_kv, _rms, prompt_bucket
+from deeplearning4j_tpu.zoo.gpt import (
+    _block_tail, _quant_kv, _rms, prompt_bucket)
 
 #: every ``_build_*`` jitted entry point in this module must have an
 #: entry here describing its warmup feed, and :meth:`warmup` must
@@ -69,6 +80,11 @@ WARMUP_FEEDS = {
         "(params, pool, page_ids[tb/block]i32, prompt[1,tb]i32, "
         "t0 i32, temp f32, top_p f32, ctr i32) — one signature per "
         "power-of-two prompt bucket (prompt_bucket), each warmed",
+    "_build_chunk_admit_fn":
+        "(params, pool, history, page i32, tokens[1,chunk]i32, "
+        "start i32, t0 i32, temp f32, top_p f32, ctr i32) — a "
+        "retention model's prefill: one signature total "
+        "(PREFILL_CHUNK rows), warmed once in place of the buckets",
     "_build_spec_step_fn":
         "(params, pool, page_table[S,MP]i32, lengths[S]i32, "
         "active[S]bool, prev[S]i32, drafts[S,k-1]i32) — one "
@@ -90,6 +106,11 @@ WARMUP_FEEDS = {
 #: ``_build_spec_step_fn`` WARMUP_FEEDS entry and the warmup() body in
 #: lockstep (an off-grid k would cold-trace on the first spec step)
 SPEC_KS = (2, 4, 8)
+
+#: rows of a retention model's one prefill program (clamped to the
+#: gateway's ``max_context``): at 512 rows the weights' matmuls are
+#: bound by the MXU, not by reading the weights once a call
+PREFILL_CHUNK = 512
 
 
 def _rotary_rows(x, theta: float, pos):
@@ -179,13 +200,47 @@ class DecodeScheduler:
                     "under sampling it would skew the distribution")
         self.prefix_sharing = bool(prefix_sharing)
         hd = model.hidden // model.n_heads
+        #: a retention model: one fixed-size state page a sequence
+        self.recurrent = getattr(model, "mixer",
+                                 "softmax") == "power_retention"
+        state_rows = None
+        if self.recurrent:
+            from deeplearning4j_tpu.ops.retention import (
+                logical_state_rows, state_rows as rows_of, zero_history)
+            for name, on in (("prefix_sharing", self.prefix_sharing),
+                             ("spec_k", self.spec_k != 1)):
+                if on:
+                    raise ValueError(
+                        f"{name} with mixer='power_retention': a "
+                        "recurrent state cannot be adopted at a page "
+                        "boundary nor rolled back after a rejected "
+                        "draft; both need an index of state "
+                        "snapshots, which this scheduler does not "
+                        "keep")
+            state_rows = rows_of(hd)
+            self.max_pages_per_seq = 1
+            self.prefill_chunk = min(PREFILL_CHUNK, mc)
+            #: bytes of state one live slot's decode step reads and
+            #: writes, by the logical size (d (d + 1) / 2 rows of
+            #: d values and the normaliser's, float32, both ways)
+            self.state_bytes_per_slot = (
+                2 * 4 * model.n_layers * model.n_kv_heads
+                * logical_state_rows(hd) * (hd + 1))
+            #: the prompt being admitted, as its later chunks read it:
+            #: every layer's keys, values and cumulative log-gates,
+            #: for prompts up to max_context (one admission at a time)
+            self._prefill_hist = zero_history(
+                model.n_layers,
+                -(-mc // self.prefill_chunk) * self.prefill_chunk,
+                model.n_kv_heads, hd, model.compute_dtype or "float32")
         self.pager = KVPager(
             n_layers=model.n_layers, n_kv_heads=model.n_kv_heads,
             head_dim=hd, block=self.block,
             n_pages=(int(n_pages) if n_pages
                      else 1 + self.max_slots * self.max_pages_per_seq),
             cache_quant=model.cache_quant,
-            dtype=model.compute_dtype or "float32")
+            dtype=model.compute_dtype or "float32",
+            state_rows=state_rows)
         # per-slot host state, mirrored into the small int arrays the
         # fixed-shape step consumes each iteration
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
@@ -213,6 +268,8 @@ class DecodeScheduler:
         #: on the records made inside it
         self.cause = None
         self._step_fn = self._build_step_fn()
+        self._chunk_fn = (self._build_chunk_admit_fn()
+                          if self.recurrent else None)
         self._admit_fns: Dict[int, object] = {}
         self._spec_fn = (self._build_spec_step_fn(self.spec_k)
                          if self.spec_k > 1 else None)
@@ -241,8 +298,10 @@ class DecodeScheduler:
             # naming each paged block's share of the serving hot path
             with obs.devtime.scope("paged_decode.embed"):
                 x = params["layer_0"]["W"][prev][:, None]   # [S, 1, F]
+            block = ("retention_decode" if self.recurrent
+                     else "paged_decode")
             for i in range(L):
-                with obs.devtime.scope(f"paged_decode.block_{i}"):
+                with obs.devtime.scope(f"{block}.block_{i}"):
                     x, pool = self._paged_rows_step(
                         params[f"layer_{i + 1}"], i, x, pool,
                         page_table, lengths[:, None], active[:, None])
@@ -294,6 +353,22 @@ class DecodeScheduler:
         block = self.block
         h = _rms(x.reshape(S * R, -1), pblk["ln1"]["gamma"])
         mha = pblk["mha"]
+        if self.recurrent:
+            # the retention mixer: the slot's ONE state page (pt's
+            # only column) is updated in place and read; an inactive
+            # slot's page is neither (R is 1: the multi-row programs
+            # are refused at construction)
+            from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
+            from deeplearning4j_tpu.ops import retention
+            q, k, v, log_g = retention.project(
+                mha, h, model.n_heads, n_kv,
+                lambda z: _rotary_rows(z, model.rope_theta,
+                                       pos.reshape(S)), RMSNORM_EPS)
+            a, pool = retention_decode(
+                q, k, v, jnp.exp(log_g), pool, li, pt[:, 0],
+                jnp.broadcast_to(act, (S, 1))[:, 0])
+            return _block_tail(pblk, x.reshape(S, -1), a.reshape(
+                S, -1)).reshape(S, 1, -1), pool
         q = (h @ mha["Wq"]).reshape(S * R, model.n_heads, hd)
         k = (h @ mha["Wk"]).reshape(S * R, n_kv, hd)
         v = (h @ mha["Wv"]).reshape(S * R, n_kv, hd)
@@ -444,6 +519,66 @@ class DecodeScheduler:
         return sentry.jit(admit, name="serving.prefill",
                           donate_argnums=(1,))
 
+    def _build_chunk_admit_fn(self):
+        """A retention model's prefill: ONE program of
+        ``prefill_chunk`` rows, run ``ceil(t0 / chunk)`` times for a
+        prompt of ``t0`` tokens. A call reads the sequence's state
+        page (an empty state when ``start`` is 0: a page comes off
+        the free list as its last owner left it), runs its rows by the
+        chunked form, every block's state carried through, and writes
+        the page back: after the last call it holds the state after
+        position ``t0 - 1`` exactly, since rows at and past ``t0`` are
+        masked out of it. What a chunk's queries need of the chunks
+        before it they read from ``history`` (those chunks' keys and
+        values, one layer a row; ``ops.retention.retention_chunk``
+        says why not from the state). The head runs only in the call
+        that holds row ``t0 - 1``; the others return token 0."""
+        import jax
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.perf import sentry
+
+        model = self.model
+        L = model.n_layers
+        chunk = self.prefill_chunk
+
+        def admit(params, pool, history, page, toks, start, t0, temp,
+                  top_p, ctr):
+            valid = (start + jnp.arange(chunk, dtype=jnp.int32)
+                     < t0)[None, :]
+            with obs.devtime.scope("chunk_prefill.embed"):
+                x = params["layer_0"]["W"][toks]        # [1, C, F]
+            for i in range(L):
+                with obs.devtime.scope(f"chunk_prefill.block_{i}"):
+                    x, state, hist = model._retention_rows(
+                        params[f"layer_{i + 1}"], x, start, valid,
+                        tuple(jnp.where(start > 0, a[i, page], 0.0)[None]
+                              for a in pool),
+                        history=tuple(a[i, None] for a in history))
+                    pool = tuple(a.at[i, page].set(new[0])
+                                 for a, new in zip(pool, state))
+                    history = tuple(a.at[i].set(new[0])
+                                    for a, new in zip(history, hist))
+
+            def first_token(x):
+                with obs.devtime.scope("chunk_prefill.lm_head"):
+                    row = jax.lax.dynamic_slice_in_dim(
+                        x[0], t0 - 1 - start, 1, axis=0)
+                    hrow = _rms(row, params[f"layer_{L + 1}"]["gamma"])
+                    logits0 = model._head_logits(params, hrow)
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(self.seed), ctr)
+                _, sub = jax.random.split(key)
+                return model._pick(logits0, temp, top_p, sub,
+                                   sample=self.sample, top_k=self.top_k,
+                                   nucleus=self.top_p is not None)
+
+            g0 = jax.lax.cond(t0 <= start + chunk, first_token,
+                              lambda x: jnp.zeros((1,), jnp.int32), x)
+            return pool, history, g0
+
+        return sentry.jit(admit, name="serving.prefill",
+                          donate_argnums=(1, 2))
+
     def _admit_fn(self, tb: int):
         fn = self._admit_fns.get(tb)
         if fn is None:
@@ -561,11 +696,15 @@ class DecodeScheduler:
             if match is not None:
                 return self._admit_shared(req, slot, prompt, t0,
                                           max_new, match)
-        tb = prompt_bucket(t0, self.max_context)
+        # a retention model's prompt runs as chunks of one program; a
+        # softmax model's as ONE bucket
+        tb = (self.prefill_chunk if self.recurrent
+              else prompt_bucket(t0, self.max_context))
+        n_chunks = -(-t0 // tb) if self.recurrent else 1
         # resolve (possibly build) the bucket executable BEFORE taking
         # pages: everything after the reservation is under the
         # release-on-failure try below
-        fn = self._admit_fn(tb)
+        fn = self._chunk_fn if self.recurrent else self._admit_fn(tb)
         pages = self.pager.alloc(self.pages_needed(t0, max_new), req)
         if pages is None:
             return False
@@ -573,7 +712,7 @@ class DecodeScheduler:
         row = self._page_table[slot]
         row[:] = 0
         row[:len(pages)] = pages
-        pad = np.zeros((1, tb), np.int32)
+        pad = np.zeros((1, n_chunks * tb), np.int32)
         pad[0, :t0] = prompt
         self._ctr += 1
         # `is not None`, never truthiness (the falsy-deadline lesson):
@@ -581,16 +720,26 @@ class DecodeScheduler:
         temp = getattr(req, "temperature", None)
         ts1 = obs.now()
         try:
-            pool, g0 = fn(
-                self.model._decode_params(self.net), self.pager.pool,
-                jnp.asarray(np.asarray(pages[:tb // self.block],
-                                       np.int32)),
-                jnp.asarray(pad), jnp.asarray(t0, jnp.int32),
-                (self._temp_one if temp is None
-                 else jnp.asarray(temp, jnp.float32)),
-                self._topp_dev,
-                jnp.asarray(self._ctr, jnp.int32))
-            self.pager.pool = pool
+            params = self.model._decode_params(self.net)
+            tail = (jnp.asarray(t0, jnp.int32),
+                    (self._temp_one if temp is None
+                     else jnp.asarray(temp, jnp.float32)),
+                    self._topp_dev, jnp.asarray(self._ctr, jnp.int32))
+            if self.recurrent:
+                page = jnp.asarray(pages[0], jnp.int32)
+                for c in range(n_chunks):
+                    pool, self._prefill_hist, g0 = fn(
+                        params, self.pager.pool, self._prefill_hist,
+                        page, jnp.asarray(pad[:, c * tb:(c + 1) * tb]),
+                        jnp.asarray(c * tb, jnp.int32), *tail)
+                    self.pager.pool = pool
+            else:
+                pool, g0 = fn(
+                    params, self.pager.pool,
+                    jnp.asarray(np.asarray(pages[:tb // self.block],
+                                           np.int32)),
+                    jnp.asarray(pad), *tail)
+                self.pager.pool = pool
             ts2 = obs.now()
             first = int(np.asarray(g0)[0])  # blocking device sync
         except BaseException:
@@ -603,6 +752,7 @@ class DecodeScheduler:
         ts3 = obs.now()
         obs.record_step("serving.prefill", ts0, ts1, ts2, ts3,
                         args={"bucket": tb, "t0": t0, "slot": slot,
+                              "chunks": n_chunks,
                               "rid": getattr(req, "rid", None)},
                         cause=self.cause)
         obs.metrics.SERVING_PREFILL.observe(ts3 - ts0)
@@ -762,7 +912,12 @@ class DecodeScheduler:
         f = self._ensure_feed(act)
         # the pages this step's attention walks (the position being
         # written included), from the host's mirror: no device read
-        kv_pages = int(np.sum(self._lengths[act] // self.block + 1))
+        # (a retention model walks no KV page: it moves its live
+        # slots' states, once each way)
+        kv_pages = 0 if self.recurrent else int(
+            np.sum(self._lengths[act] // self.block + 1))
+        state_bytes = (len(act) * self.state_bytes_per_slot
+                       if self.recurrent else 0)
         ts1 = obs.now()
         nxt, pool, len_next = self._step_fn(
             self.model._decode_params(self.net), self.pager.pool,
@@ -789,9 +944,11 @@ class DecodeScheduler:
         # ``deliver`` (ts3 → here) is the push/retire loop above: host
         # time the device waits out before its next step
         obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
-                        args={"active": len(act), "kv_pages": kv_pages},
+                        args={"active": len(act), "kv_pages": kv_pages,
+                              "state_bytes": state_bytes},
                         cause=self.cause, end=obs.now())
         obs.metrics.SERVING_KV_WALKED.set(kv_pages)
+        obs.metrics.SERVING_STATE_MOVED.inc(state_bytes)
         obs.metrics.SERVING_STEP.observe(ts3 - ts0)
         obs.metrics.SERVING_TOKENS.inc(len(act))
         self.tokens_out += len(act)
@@ -975,7 +1132,8 @@ class DecodeScheduler:
 
     def warmup(self, prompt_lens=None) -> Dict[str, float]:
         """AOT-compile the decode step (one signature) and the prefill
-        executable of every reachable prompt bucket BEFORE traffic —
+        executable of every reachable prompt bucket (a retention
+        model's one chunk program in their place) BEFORE traffic —
         after this the sentry sees zero new traces from any admission
         order (the acceptance fence). Iterates :data:`WARMUP_FEEDS`'
         builder table so lint rule 7 can hold the two in lockstep."""
@@ -984,13 +1142,12 @@ class DecodeScheduler:
 
         assert set(WARMUP_FEEDS) == {"_build_step_fn",
                                      "_build_admit_fn",
+                                     "_build_chunk_admit_fn",
                                      "_build_spec_step_fn",
                                      "_build_suffix_admit_fn",
                                      "_build_cow_fn"}
         if prompt_lens is None:
             prompt_lens = range(1, self.max_context)
-        buckets = sorted({prompt_bucket(t, self.max_context)
-                          for t in prompt_lens})
         params = self.model._decode_params(self.net)
         pool_sds = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
                          for a in self.pager.pool)
@@ -1000,13 +1157,24 @@ class DecodeScheduler:
         seconds = self._step_fn.warmup(params, pool_sds,
                                        *self._step_feed_shapes())
         compiled = seconds > 0
-        for tb in buckets:
-            dt = self._admit_fn(tb).warmup(
+        scalars = (sds((), i32), sds((), jnp.float32),
+                   sds((), jnp.float32), sds((), i32))
+        if self.recurrent:
+            # every prompt runs the one chunk program
+            buckets = [self.prefill_chunk]
+            warmed = [self._chunk_fn.warmup(
+                params, pool_sds,
+                tuple(sds(a.shape, a.dtype) for a in self._prefill_hist),
+                sds((), i32), sds((1, self.prefill_chunk), i32),
+                sds((), i32), *scalars)]
+        else:
+            buckets = sorted({prompt_bucket(t, self.max_context)
+                              for t in prompt_lens})
+            warmed = [self._admit_fn(tb).warmup(
                 params, pool_sds, sds((tb // self.block,), i32),
-                sds((1, tb), i32), sds((), i32), sds((), jnp.float32),
-                sds((), jnp.float32), sds((), i32))
-            compiled += dt > 0
-            seconds += dt
+                sds((1, tb), i32), *scalars) for tb in buckets]
+        compiled += sum(dt > 0 for dt in warmed)
+        seconds += sum(warmed)
         if self.spec_k > 1:
             # the configured k is the one the live path runs; __init__
             # pinned it to the SPEC_KS grid so this warm covers it

@@ -43,6 +43,17 @@ def _rms(x, gamma):
     return fused_norms.rms_norm(x, gamma, eps=RMSNORM_EPS)
 
 
+def _block_tail(pblk, x, a):
+    """What follows a retention block's mixer: its heads' outputs
+    ``a`` (flattened to ``x``'s leading shape) through ``Wo`` into the
+    residual, then the pre-norm SwiGLU."""
+    mha = pblk["mha"]
+    x = x + a @ mha["Wo"] + mha["bo"]
+    h = _rms(x, pblk["ln2"]["gamma"])
+    h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
+    return x + h @ pblk["Wd"]
+
+
 def prompt_bucket(t0: int, max_len: Optional[int] = None) -> int:
     """THE prompt-length bucket table: power-of-two (min 16), clamped
     to ``max_len`` when given. ``generate()``/``warmup_decode`` and the
@@ -136,7 +147,21 @@ class CausalTransformerLM(ZooModel):
                  serve_quant: Optional[str] = None,
                  cache_quant: Optional[str] = None,
                  seed: int = 123, updater=None,
-                 compute_dtype: Optional[str] = None):
+                 compute_dtype: Optional[str] = None,
+                 mixer: str = "softmax"):
+        # the blocks' sequence mixer: "softmax" attention over a KV
+        # cache, or "power_retention" (ops/retention.py): a fixed-size
+        # recurrent state per sequence, whatever its length
+        if mixer not in ("softmax", "power_retention"):
+            raise ValueError(f"mixer={mixer!r} "
+                             "('softmax' | 'power_retention')")
+        if mixer == "power_retention" and (cache_quant
+                                           or sequence_parallel):
+            raise ValueError(
+                "mixer='power_retention' keeps a float32 recurrent "
+                "state, not a KV cache: cache_quant and "
+                "sequence_parallel do not apply to it")
+        self.mixer = mixer
         self.remat = remat
         # GPT-2/LLaMA convention: the LM head reuses the embedding
         # matrix (transposed) — ~V·F fewer params, logits stay exact
@@ -191,7 +216,8 @@ class CausalTransformerLM(ZooModel):
                 n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
                 ffn_mult=self.ffn_mult, rope_theta=self.rope_theta,
                 dropout=self.dropout or None, remat=self.remat,
-                sequence_parallel=self.sequence_parallel))
+                sequence_parallel=self.sequence_parallel,
+                mixer=self.mixer))
         b.layer(RMSNorm())
         # fused-from-logits sparse softmax CE over the vocabulary —
         # integer next-token labels, no [B,T,V] one-hot materialised
@@ -407,7 +433,22 @@ class CausalTransformerLM(ZooModel):
         n_kv = self.n_kv_heads
         rms = _rms
 
+        def retention_block_step(pblk, x, state):
+            # the recurrence: the layer's "cache" is its state
+            # (S [rows, Hkv, D, d], Z [rows, Hkv, d, d]), one update
+            from deeplearning4j_tpu.ops import retention
+            h = rms(x, pblk["ln1"]["gamma"])
+            q, k, v, log_g = retention.project(
+                pblk["mha"], h, self.n_heads, n_kv,
+                lambda z: rotary_embedding(z[:, None], self.rope_theta,
+                                           offset=pos)[:, 0],
+                RMSNORM_EPS)
+            a, state = retention.retention_step(q, k, v, log_g, state)
+            return _block_tail(pblk, x, a.reshape(rows, -1)), state
+
         def block_step(pblk, x, ckv):
+            if self.mixer == "power_retention":
+                return retention_block_step(pblk, x, ckv)
             # per-layer cache is ONE [rows, Hkv, 2D, T] array (k rows
             # 0:D, v rows D:2D): the minor (2D, T) dims tile the TPU's
             # (8, 128) layout exactly (no padded-tile bandwidth waste —
@@ -527,6 +568,17 @@ class CausalTransformerLM(ZooModel):
             pblk = params[f"layer_{i + 1}"]
             # devtime scope: names each prefill block's device share
             with obs.devtime.scope(f"prefill.block_{i}"):
+                if self.mixer == "power_retention":
+                    # the layer's "cache" is its state; it has no
+                    # causal shelter from padding: rows at and past
+                    # t0 are masked out of it
+                    from deeplearning4j_tpu.ops import retention
+                    x, st = self._retention_rows(
+                        pblk, x, 0, jnp.broadcast_to(
+                            jnp.arange(tb)[None, :] < t0, (bsz, tb)),
+                        retention.zero_state(bsz, n_kv, hd))
+                    caches.append(st)
+                    continue
                 h = rms(x, pblk["ln1"]["gamma"])
                 mha = pblk["mha"]
                 q = (h @ mha["Wq"]).reshape(bsz, tb, self.n_heads, hd)
@@ -560,6 +612,38 @@ class CausalTransformerLM(ZooModel):
                                                   keepdims=False)
             logits = self._head_logits(params, x_last)
         return logits, tuple(caches)
+
+    def _retention_rows(self, pblk, x, start, valid, state,
+                        history=None):
+        """One retention block over a chunk of rows per sequence, by
+        the chunked form: ``x`` [B, C, F] at positions ``start ..
+        start + C - 1`` (``start`` may be traced),
+        ``valid`` [B, C] (a row that is not valid leaves the state as
+        it was), ``state`` the layer's state before the chunk. Returns
+        ``(x, state after the chunk)``, and the layer's ``history``
+        with the chunk's rows added where one was given
+        (``ops.retention.retention_chunk``). Dense prefill runs it
+        once over the padded prompt; the gateway's admission runs it
+        chunk after chunk (``serving/scheduler.py``)."""
+        from deeplearning4j_tpu.ops import retention
+        b, c, f = x.shape
+        n_kv = self.n_kv_heads
+
+        def rotate(z):      # [B*C, heads, d]
+            return rotary_embedding(
+                z.reshape(b, c, *z.shape[1:]), self.rope_theta,
+                offset=start).reshape(z.shape)
+
+        with obs.devtime.scope("ops.retention_prefill"):
+            h = _rms(x.reshape(b * c, f), pblk["ln1"]["gamma"])
+            q, k, v, log_g = retention.project(
+                pblk["mha"], h, self.n_heads, n_kv, rotate, RMSNORM_EPS)
+            a, *carried = retention.retention_chunk(
+                q.reshape(b, c, self.n_heads, -1),
+                k.reshape(b, c, n_kv, -1), v.reshape(b, c, n_kv, -1),
+                log_g.reshape(b, c, n_kv), valid, state,
+                history=history, start=start)
+        return (_block_tail(pblk, x, a.reshape(b, c, -1)), *carried)
 
     def _pick(self, logits, temperature, top_p, key, *, sample, top_k,
               nucleus):
@@ -620,10 +704,18 @@ class CausalTransformerLM(ZooModel):
         comparison; dead weakrefs likewise invalidate. Weakrefs don't
         pin the old tree, so resumed training doesn't hold a stale
         f32 copy in HBM (the PREPARED copy stays cached until the
-        next generate() against new params replaces it)."""
+        next generate() against new params replaces it). A net whose
+        float leaves already have the compute dtype is served as it
+        is: no copy is made."""
         if self.compute_dtype is None and self.serve_quant is None:
             return net.params
         leaves = jax.tree.leaves(net.params)
+        if self.serve_quant is None and all(
+                l.dtype == jnp.dtype(self.compute_dtype) for l in leaves
+                if jnp.issubdtype(l.dtype, jnp.floating)):
+            # weights served from a checkpoint already in the compute
+            # dtype: the cast would make a second copy of every leaf
+            return net.params
         cached = getattr(self, "_decode_params_cache", None)
         if (cached is not None and len(cached[0]) == len(leaves)
                 and all(w() is l for w, l in zip(cached[0], leaves))):

@@ -76,6 +76,7 @@ SPECS = {
     "LearnedSelfAttentionLayer": (dict(n_heads=2, n_queries=3), (5, 4)),
     "RecurrentAttentionLayer": (dict(n_out=4, n_heads=2), (5, 4)),
     "MultiHeadAttention": (dict(n_out=4, n_heads=2), (5, 4)),
+    "PowerRetention": (dict(n_heads=2, n_kv_heads=1), (5, 16)),
     "TransformerEncoderBlock": (dict(n_heads=2, ffn_mult=2), (5, 4)),
     "PositionalEmbeddingLayer": ({}, (5, 4)),
     "ClsTokenPoolLayer": ({}, (5, 4)),

@@ -550,3 +550,84 @@ def test_flash_cross_attention_matches_einsum(rng, causal):
                   argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         assert float(jnp.max(jnp.abs(a - b))) < 5e-5
+
+
+# -- retention decode over the paged state pool ----------------------------
+
+def _retention_case(rng, d, n_slots=4, n_kv=2, groups=3, pages=6,
+                    layers=2):
+    from deeplearning4j_tpu.ops import retention
+    f32 = jnp.float32
+    rows = retention.state_rows(d)
+    s_pool = jnp.asarray(rng.standard_normal(
+        (layers, pages, n_kv, rows, d)), f32)
+    z = rng.standard_normal((layers, pages, n_kv, d, d))
+    z_pool = jnp.asarray(z + z.swapaxes(-1, -2), f32)
+    q = jnp.asarray(rng.standard_normal((n_slots, n_kv * groups, d)), f32)
+    k, v = (jnp.asarray(rng.standard_normal((n_slots, n_kv, d)), f32)
+            for _ in range(2))
+    g = jax.nn.sigmoid(jnp.asarray(
+        rng.standard_normal((n_slots, n_kv)) + 3.0, f32))
+    return q, k, v, g, (s_pool, z_pool)
+
+
+@pytest.mark.parametrize("active", [
+    (True, False, True, True), (False, True, False, False),
+    (False, False, False, False), (True, True, True, True)],
+    ids=["gap", "one", "none", "all"])
+@pytest.mark.parametrize("d", [16, 128])
+def test_retention_decode_matches_reference(rng, d, active):
+    """The kernel (interpret mode) against the registered fallback,
+    layer 1 of 2: outputs, the live slots' updated pages, and every
+    other page of the pool bit for bit (an inactive slot's state is
+    neither read nor written, nor is any page the step does not own)."""
+    n_kv, groups = (1, 2) if d == 128 else (2, 3)
+    q, k, v, g, pool = _retention_case(rng, d, n_kv=n_kv, groups=groups)
+    pages = jnp.asarray([3, 2, 1, 4], jnp.int32)
+    act = jnp.asarray(active)
+    args = (q.reshape(4, n_kv, groups, d), k, v, g)
+    yr, sr, zr = pk._reference_retention_decode(
+        *args, pool, 1, pages, act, 1e-6)
+    yk, sk, zk = pk._retention_decode_call(
+        *args, *pool, jnp.asarray(1, jnp.int32), pages, act, eps=1e-6,
+        interpret=True)
+    live = np.asarray(pages)[np.asarray(active)]
+    scale = float(jnp.abs(yr).max()) + 1.0
+    assert float(jnp.abs(yk - yr).max()) < 2e-5 * scale
+    assert float(jnp.abs(yk[~np.asarray(active)]).max(initial=0)) == 0
+    for new, ref, old in ((sk, sr, pool[0]), (zk, zr, pool[1])):
+        if len(live):
+            np.testing.assert_allclose(new[1, live], ref[1, live],
+                                       rtol=1e-5, atol=1e-5)
+        rest = [p for p in range(1, 6) if p not in set(live.tolist())]
+        np.testing.assert_array_equal(new[1, rest], old[1, rest])
+        np.testing.assert_array_equal(new[0], old[0])   # other layer
+
+
+def test_retention_decode_dispatch_line(monkeypatch, rng):
+    """A 128-lane head on the kernel platform takes the kernel;
+    everything else the reference, with the same answer."""
+    calls = []
+    real = pk._retention_decode_call
+    monkeypatch.setattr(
+        pk, "_retention_decode_call",
+        lambda *a, **kw: calls.append(a[0].shape) or real(*a, **kw))
+
+    def run(d):
+        q, k, v, g, pool = _retention_case(rng, d, n_slots=2, n_kv=1,
+                                           groups=2, pages=3)
+        pages = jnp.asarray([2, 1], jnp.int32)
+        act = jnp.asarray([True, False])
+        y, new = pk.retention_decode(q, k, v, g, pool, 0, pages, act)
+        yr, sr, _ = pk._reference_retention_decode(
+            q.reshape(2, 1, 2, d), k, v, g, pool, 0, pages, act, 1e-6)
+        assert y.shape == q.shape
+        assert float(jnp.abs(y - yr.reshape(q.shape)).max()) < 1e-3
+        np.testing.assert_allclose(new[0][0, 2], sr[0, 2], rtol=1e-5,
+                                   atol=1e-5)
+        return len(calls)
+
+    assert run(128) == 0                    # CPU: the fallback
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    assert run(128) == 1                    # over the line: the kernel
+    assert run(16) == 1                     # head under 128 lanes

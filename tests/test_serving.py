@@ -1157,3 +1157,152 @@ def test_cow_isolation_against_sibling():
     pager.release(a)
     pager.release(b)
     assert pager.free_pages() == pager.n_pages - 1
+
+
+# =========================================================================
+# a retention model behind the same scheduler: one state page a sequence
+# =========================================================================
+
+def _retention_model(**kw):
+    return CausalTransformerLM(vocab_size=64, hidden=64, n_layers=2,
+                               n_heads=4, n_kv_heads=2,
+                               max_len=kw.pop("max_len", 128),
+                               seed=kw.pop("seed", 3),
+                               mixer="power_retention", **kw)
+
+
+@pytest.fixture(scope="module")
+def retention():
+    model = _retention_model()
+    return model, model.init()
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["fallback", "kernel"])
+def test_retention_paged_decode_token_identical_to_dense(
+        retention, monkeypatch, kernel):
+    """Chunked prefill into state pages, then the in-place decode step:
+    token for token what dense ``generate()`` returns, for prompts of
+    less than a chunk, a chunk exactly and several chunks, with the
+    kernel in the step (forced, interpret mode; a 128-wide head) or the
+    fallback."""
+    from deeplearning4j_tpu.serving import scheduler as sched_mod
+    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    if kernel:
+        monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+        model = CausalTransformerLM(
+            vocab_size=64, hidden=256, n_layers=1, n_heads=2,
+            n_kv_heads=1, max_len=64, seed=4, mixer="power_retention")
+        net = model.init()
+        lens, n_new = (5, 16, 21), 5
+    else:
+        model, net = retention
+        lens, n_new = (5, 16, 17, 40, 33, 7), 12
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 64, n).astype(np.int32) for n in lens]
+    gw = ServingGateway(model, net, max_slots=3, block=16,
+                        max_context=64 if kernel else 96)
+    report = gw.warmup(prompt_lens=lens)
+    assert report["buckets"] == [16]        # one program, no buckets
+    streams = [gw.submit(p, max_new=n_new) for p in prompts]
+    outs = [np.asarray(s.result(timeout=300)) for s in streams]
+    gw.shutdown()
+    for p, out in zip(prompts, outs):
+        dense = np.asarray(model.generate(net, p[None], n_new))[0]
+        np.testing.assert_array_equal(out, dense)
+
+
+def test_retention_state_pages_conserved_under_churn(retention,
+                                                     monkeypatch):
+    """Admit / step / evict churn over a pool with fewer pages than
+    slots: one page a sequence, every invariant after every
+    transition, the whole free list back at the end."""
+    from deeplearning4j_tpu.serving import scheduler as sched_mod
+    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    model, net = retention
+    sched = DecodeScheduler(model, net, max_slots=4, block=16,
+                            max_context=64, n_pages=4)
+    assert sched.pager.pages_for(1) == sched.pager.pages_for(999) == 1
+    sched.warmup()
+    rng = np.random.default_rng(4)
+    live, refused = [], 0
+    for it in range(90):
+        op = rng.integers(0, 3)
+        if op == 0:
+            r = _Req(rng.integers(0, 64, int(rng.integers(1, 40))),
+                     int(rng.integers(1, 9)))
+            if sched.can_admit(r.prompt.size, r.max_new):
+                assert sched.admit(r)
+                assert len(sched.pager.owned(r)) == (0 if r.done else 1)
+                if not r.done:
+                    live.append(r)
+            else:
+                refused += 1
+        elif op == 1:
+            sched.step()
+        elif live:
+            sched.evict(live.pop(int(rng.integers(0, len(live)))))
+        live = [r for r in live if not r.done]
+        sched.pager.check_invariants()
+    assert refused                  # three usable pages for four slots
+    while any(s is not None for s in sched._slots):
+        sched.step()
+        sched.pager.check_invariants()
+    assert sched.pager.free_pages() == sched.pager.n_pages - 1
+
+
+def test_retention_inactive_slot_state_is_untouched(retention,
+                                                    monkeypatch):
+    """A decode step reads and writes the live slots' pages only: the
+    page of a sequence that has left, and every free page, come out of
+    a step bit for bit as they went in."""
+    from deeplearning4j_tpu.serving import scheduler as sched_mod
+    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    model, net = retention
+    sched = DecodeScheduler(model, net, max_slots=3, block=16,
+                            max_context=64)
+    stay, leave = _Req(np.arange(9), 20), _Req(np.arange(20), 20)
+    assert sched.admit(leave) and sched.admit(stay)
+    sched.step()
+    left = sched.pager.owned(leave)[0]
+    kept = sched.pager.owned(stay)[0]
+    sched.evict(leave)
+    before = [np.asarray(a) for a in sched.pager.pool]
+    sched.step()
+    sched.step()
+    for a, b in zip(before, (np.asarray(a) for a in sched.pager.pool)):
+        others = [p for p in range(1, a.shape[1]) if p != kept]
+        np.testing.assert_array_equal(a[:, others], b[:, others])
+        assert (a[:, kept] != b[:, kept]).any()
+    assert left in others
+
+
+@pytest.mark.parametrize("kw,named", [
+    (dict(prefix_sharing=True), "prefix_sharing"),
+    (dict(spec_k=2), "spec_k")])
+def test_retention_refuses_sharing_and_speculation(retention, kw,
+                                                   named):
+    model, net = retention
+    with pytest.raises(ValueError, match=named + ".*snapshots"):
+        DecodeScheduler(model, net, max_slots=2, block=16,
+                        max_context=64, **kw)
+
+
+def test_retention_zero_retraces_after_warmup(retention):
+    from deeplearning4j_tpu.perf import sentry
+    model, net = retention
+    gw = ServingGateway(model, net, max_slots=3, block=16,
+                        max_context=64, default_max_new=6)
+    gw.warmup()
+    free = gw.stats()["free_pages"]
+    before = sentry.total_traces()
+    rng = np.random.default_rng(1)
+    with sentry.strict():
+        streams = [gw.submit(rng.integers(0, 64, int(t)), max_new=6)
+                   for t in rng.integers(1, 50, 10)]
+        for st in streams:
+            st.result(timeout=120)
+    assert sentry.total_traces() == before, \
+        "retention traffic retraced after warmup"
+    gw.shutdown()
+    assert gw.stats()["free_pages"] == free     # the leak check's read

@@ -248,3 +248,165 @@ def test_serving_decode_step_compiles_for_v5e_without_touching_the_pool(
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < (64 << 20), mem
     assert mem.alias_size_in_bytes >= 2 * 10 ** 9   # the pool, in place
+
+
+# -- the retention cell: 24 slots x 6 layers, 8 kv heads of 128 ------------
+
+_RET = dict(slots=24, heads=40, kv_heads=8, head=128, layers=6,
+            hidden=5120, vocab=151936, ffn=17408)
+
+
+def _retention_pool(chip):
+    from deeplearning4j_tpu.ops import retention
+    c = _RET
+    rows = retention.state_rows(c["head"])
+    assert rows == 8704
+    return tuple(
+        jax.ShapeDtypeStruct((c["layers"], 1 + c["slots"], c["kv_heads"],
+                              r, c["head"]), jnp.float32, sharding=chip)
+        for r in (rows, c["head"]))
+
+
+def test_retention_decode_compiles_for_v5e(chip):
+    """The kernel alone at the cell's sizes: Mosaic takes it (dynamic
+    8-row tiles, in-kernel transposes, 18 MB of pipelined state blocks
+    under the raised VMEM limit), and both pool arrays go through in
+    place: aliased, no temporary of their size."""
+    c = _RET
+    pool = _retention_pool(chip)
+    f32 = jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def decode(q, k, v, g, s_pool, z_pool, pages, active):
+        return pallas_kernels.retention_decode(
+            q, k, v, g, (s_pool, z_pool), 3, pages, active)
+
+    compiled = jax.jit(decode, donate_argnums=(4, 5)).lower(
+        sds((c["slots"], c["heads"], c["head"]), BF16),
+        sds((c["slots"], c["kv_heads"], c["head"]), BF16),
+        sds((c["slots"], c["kv_heads"], c["head"]), BF16),
+        sds((c["slots"], c["kv_heads"])), *pool,
+        sds((c["slots"],), jnp.int32), sds((c["slots"],), jnp.bool_),
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "retention_decode" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (4 << 20), mem
+    assert mem.alias_size_in_bytes >= 5 * 10 ** 9   # the pool, in place
+
+
+def _retention_sched(chip):
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.serving import DecodeScheduler
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    c = _RET
+    model = CausalTransformerLM(
+        vocab_size=c["vocab"], hidden=c["hidden"], n_layers=c["layers"],
+        n_heads=c["heads"], n_kv_heads=c["kv_heads"], max_len=8192,
+        ffn_mult=c["ffn"] / c["hidden"], rope_theta=1e6,
+        tie_embeddings=False, updater=upd.Sgd(learning_rate=0.0),
+        compute_dtype="bfloat16", seed=1, mixer="power_retention")
+    # bf16 leaves, as the benchmark's builder keeps them: shapes only
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=chip),
+        jax.eval_shape(lambda: model.init().params))
+    sched = DecodeScheduler(model, None, max_slots=c["slots"], block=16,
+                            max_context=5120, n_pages=2)
+    return sched, params
+
+
+def test_retention_decode_step_compiles_for_v5e_in_place(chip):
+    """The whole ``serving.decode_step`` of the retention cell: one
+    kernel a layer, lowered ONCE, the 5.4 GB pool aliased through all
+    six, and the step's temporaries a few MB beside 12.5 GB of
+    arguments (weights in bf16 alone and the pool)."""
+    import re
+    c = _RET
+    sched, params = _retention_sched(chip)
+    pool = _retention_pool(chip)
+    lowered = sched._step_fn.lower(
+        params, pool,
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes()))
+    funcs = re.findall(r"func\.func private @(\w*retention_decode\w*)",
+                       lowered.as_text())
+    assert len(funcs) == 1, funcs           # one lowering for 6 layers
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    shape = "f32[" + ",".join(map(str, pool[0].shape)) + "]"
+    # the kernel's calls return the two pool arrays beside its output
+    # (the norms' kernels under the same scope return one array)
+    kernels = [ln for ln in hlo.splitlines()
+               if "tpu_custom_call" in ln and shape in ln.split(
+                   " custom-call(")[0]]
+    assert len(kernels) == c["layers"], len(kernels)
+    touched = set(re.findall(
+        r"= " + re.escape(shape) + r"\S* ([\w\-]+)\(", hlo))
+    assert touched <= {"parameter", "get-tuple-element", "bitcast"}, \
+        touched
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+    assert mem.alias_size_in_bytes >= 5 * 10 ** 9
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.6e9, mem
+
+
+def test_retention_chunk_prefill_compiles_for_v5e_in_place(chip):
+    """The one prefill program (512 rows): the sequence's state page
+    is read and written back in place (dynamic-update-slices into the
+    donated pool, no copy of it), as is the prompt's history, and the
+    temporaries of a chunk stay under 1 GB (the chunk's keys expanded
+    to the state's rows for the state's update; no query is)."""
+    sched, params = _retention_sched(chip)
+    pool = _retention_pool(chip)
+    i32 = jnp.int32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    assert sched.prefill_chunk == 512
+    history = tuple(sds(a.shape, a.dtype) for a in sched._prefill_hist)
+    assert history[0].shape == (6, 5120, 8, 128)
+    compiled = sched._chunk_fn.lower(
+        params, pool, history, sds((), i32), sds((1, 512), i32),
+        sds((), i32), sds((), i32), sds((), jnp.float32),
+        sds((), jnp.float32), sds((), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 10 ** 9
+    assert mem.temp_size_in_bytes < (1 << 30), mem
+
+
+def test_softmax_decode_step_holds_nothing_of_the_retention_path(chip):
+    """A softmax model's step is lowered from the same
+    ``_paged_rows_step``: it must carry no trace of the retention
+    branch (no kernel, no gate, no state), so that the Mistral cells
+    run the step they ran."""
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.serving import DecodeScheduler
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    c = _CELL
+    model = CausalTransformerLM(
+        vocab_size=32768, hidden=c["heads"] * c["head"], n_layers=2,
+        n_heads=c["heads"], n_kv_heads=c["kv_heads"], max_len=4096,
+        ffn_mult=3.5, rope_theta=1e6, tie_embeddings=False,
+        updater=upd.Sgd(learning_rate=0.0), compute_dtype="bfloat16",
+        seed=1)
+    assert model.mixer == "softmax"
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        jax.eval_shape(lambda: model._cast_decode(model.init().params)))
+    assert "Wgate" not in params["layer_1"]["mha"]
+    sched = DecodeScheduler(
+        model, None, max_slots=c["slots"], block=c["block"],
+        max_context=c["max_pages"] * c["block"], n_pages=2)
+    assert not sched.recurrent and sched._chunk_fn is None
+    pool = jax.ShapeDtypeStruct(
+        (2,) + _cell_pool(chip).shape[1:], BF16, sharding=chip)
+    text = sched._step_fn.lower(
+        params, (pool,),
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes())).as_text()
+    assert "retention" not in text
+    assert "paged_decode.block_1" in text or "paged_decode" in text

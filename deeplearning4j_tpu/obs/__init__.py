@@ -96,14 +96,23 @@ def record_etl(entry: str, t0: float, t1: float, cause=None) -> None:
 
 
 def record_worker_step(worker: str, t0: float, t1: float, t2: float,
-                       t3: float) -> None:
+                       t3: float, nbytes: int,
+                       staged_ahead: bool) -> None:
     """ParallelWrapper worker loop: per-worker latency histogram,
-    collective-sync wall time, liveness heartbeat, one ring record."""
+    collective-sync wall time, liveness heartbeat, one ring record.
+    ``nbytes`` are the host bytes whose copies this iteration's
+    ``h2d`` enqueued (its own batch's, or those of the batch it staged
+    ahead for the next step, or both); ``staged_ahead`` says the step
+    ran on a batch enqueued during the step before it."""
     metrics.WORKER_STEP.labels(worker=worker).observe(t3 - t0)
     metrics.WORKER_SYNC.labels(worker=worker).inc(t3 - t2)
+    metrics.WORKER_STAGED_AHEAD.labels(worker=worker).inc(
+        int(staged_ahead))
     health.heartbeat(worker)
-    trace.record_phases("ParallelWrapper.fit", (t0, t1, t2, t3),
-                        _WORKER_PHASES, None, {"worker": worker})
+    trace.record_phases(
+        "ParallelWrapper.fit", (t0, t1, t2, t3), _WORKER_PHASES, None,
+        {"worker": worker, "bytes": nbytes,
+         "staged_ahead": int(staged_ahead)})
 
 
 def summary() -> Dict[str, Any]:

@@ -63,6 +63,7 @@ FAMILIES = {
     "dl4j_tpu_prefetch_depth": "gauge",
     "dl4j_tpu_worker_step_latency_seconds": "histogram",
     "dl4j_tpu_worker_collective_sync_seconds_total": "counter",
+    "dl4j_tpu_worker_staged_ahead_total": "counter",
     "dl4j_tpu_inference_requests_total": "counter",
     "dl4j_tpu_inference_request_latency_seconds": "histogram",
     "dl4j_tpu_inference_queue_depth": "gauge",
@@ -431,6 +432,12 @@ WORKER_STEP = REGISTRY.histogram(
 WORKER_SYNC = REGISTRY.counter(
     "dl4j_tpu_worker_collective_sync_seconds_total",
     "ParallelWrapper wait for step + averaging/all-reduce completion",
+    ("worker",))
+WORKER_STAGED_AHEAD = REGISTRY.counter(
+    "dl4j_tpu_worker_staged_ahead_total",
+    "ParallelWrapper steps dispatched on a batch staged onto the mesh "
+    "during the step before (over the step-latency count: the share "
+    "of steps whose host-to-device copy had compute to hide behind)",
     ("worker",))
 INFER_REQS = REGISTRY.counter(
     "dl4j_tpu_inference_requests_total",

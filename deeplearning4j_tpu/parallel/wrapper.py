@@ -47,6 +47,8 @@ schedule allows.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import math
 from functools import partial
 from typing import Any, Optional
@@ -66,6 +68,26 @@ from deeplearning4j_tpu.parallel.compression import \
 from deeplearning4j_tpu.parallel.mesh import data_parallel_mesh
 from deeplearning4j_tpu.perf import sentry
 from deeplearning4j_tpu.resilience import faults
+
+
+@contextlib.contextmanager
+def _gc_held():
+    """Keep the cyclic garbage collector from running inside the block
+    (and leave it as it was found). ``fit`` holds it from a step's
+    launch until the next batch's copy is enqueued: the launch makes
+    thousands of arrays, which set off a collection of the oldest
+    generation every five or six steps, 100–135 ms in which the host
+    stands still (as long as the step on the chips), so that the
+    batch staged ahead missed its step (PERF.md §6, PR 27). Held
+    through that burst, the step's arrays die young and are never
+    promoted, and the full collections all but stop."""
+    was_on = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was_on:
+            gc.enable()
 
 
 def _replica_view(tree):
@@ -1109,11 +1131,12 @@ class ParallelWrapper:
                                                     sharded_sds)
         net = self.net
         self._ensure_ready()
-        # fit feeds batch-sharded global arrays (make_global_batch /
-        # the SYNC in_shardings), and jit's dispatch cache keys on
-        # input sharding — lower from the SAME sharding or the first
-        # real step recompiles invisibly (sentry signatures ignore
-        # sharding by design)
+        # fit feeds batch-sharded global arrays (_stage lays every
+        # batch over the mesh through make_global_batch, on one host
+        # as on several), and jit's dispatch cache keys on input
+        # sharding — lower from the SAME sharding or the first real
+        # step recompiles invisibly (sentry signatures ignore sharding
+        # by design)
         dshard = NamedSharding(self.mesh, P("data"))
         rng = jax.random.fold_in(jax.random.PRNGKey(net.conf.seed), 0)
         entries = [(self._step, self._step_builder)]
@@ -1136,6 +1159,50 @@ class ParallelWrapper:
                 seconds += dt
         return {"compiled": compiled, "seconds": seconds}
 
+    def _pull(self, src):
+        """The iterator's next batch, or ``None`` at its end; the wait
+        is the loop's ETL time."""
+        te0 = obs.now()
+        try:
+            ds = next(src)
+        except StopIteration:
+            return None
+        obs.record_etl("ParallelWrapper.fit", te0, obs.now())
+        return ds
+
+    def _stage(self, ds, b_local):
+        """Trim a batch to what the mesh divides and enqueue its copy
+        from host memory onto the chips that will read it: every leaf
+        goes over the mesh under the sharding each step builder
+        declares for its batch arguments (``P("data")``), a 1/N slice
+        a chip — no copy of the whole batch on the default device, no
+        chip-to-chip re-lay in front of the step; multi-host, each
+        process feeds its local shard of ONE global array. The call
+        returns when the copies are enqueued, not when they land.
+        Returns ``(x, y, host bytes enqueued)``; ``x`` is ``None`` for
+        a batch smaller than the worker count, which ``fit`` drops."""
+        from deeplearning4j_tpu.parallel.master import make_global_batch
+        x, y = ds.features, ds.labels
+        bsz = jax.tree.leaves(x)[0].shape[0]
+        b = bsz - (bsz % self.n) if b_local is None else b_local
+        if bsz < b:
+            raise ValueError(
+                f"batch of {bsz} smaller than the "
+                f"agreed per-process size {b}: multi-host "
+                "training needs uniform batches (drop or pad "
+                "the ragged remainder)")
+        if b == 0:
+            import logging
+            logging.getLogger("deeplearning4j_tpu").warning(
+                "ParallelWrapper: dropping batch of %d examples "
+                "(< %d workers); use batch sizes divisible by "
+                "the worker count", bsz, self.n)
+            return None, None, 0
+        trim = lambda a: a[:b]
+        x, y = jax.tree.map(trim, x), jax.tree.map(trim, y)
+        nbytes = sum(a.nbytes for a in jax.tree.leaves((x, y)))
+        return (*make_global_batch(self.mesh, x, y), nbytes)
+
     def _guarded(self, fn):
         """Run a step dispatch under the elastic collective watchdog
         when a context is installed (the collective may block INSIDE
@@ -1147,6 +1214,18 @@ class ParallelWrapper:
 
     def fit(self, iterator, epochs: int = 1):
         """Reference: ParallelWrapper.fit(DataSetIterator).
+
+        The loop keeps one batch in flight: each batch goes from host
+        memory straight onto the chips that will read it, laid out as
+        the step declares its batch arguments (``_stage``), and once a
+        step is dispatched the NEXT batch is pulled and its copy
+        enqueued before the step's loss is fetched, so the copy
+        crosses while the chips compute. Only the first batch of a
+        call is staged with the chips idle. Every step's loss is
+        still fetched before that step's bookkeeping and listeners
+        run; a step that raises drops the batch staged ahead (the
+        iterator is then at most that one batch further). In every
+        mode; ``prefetch_buffer`` still counts HOST batches.
 
         Multi-host (jax.process_count() > 1): every jitted step is a
         collective spanning all hosts, so the processes must agree on
@@ -1189,7 +1268,6 @@ class ParallelWrapper:
             # materialise).
             self._pshard = self._init_param_shards()
         from deeplearning4j_tpu.data.iterators import AsyncDataSetIterator
-        from deeplearning4j_tpu.parallel.master import make_global_batch
         multi = jax.process_count() > 1
         # divisibility is a LOCAL constraint: this process's batch
         # splits over its local devices; equal trims keep the global
@@ -1229,13 +1307,19 @@ class ParallelWrapper:
                 it.reset()
             step_i = 0
             src = iter(it)
+            # (batch, its staged arrays): the iterator's next batch,
+            # enqueued onto the chips while the step before it ran;
+            # one deep, and dropped with the frame when a step raises
+            ahead = None
             while True:
-                te0 = obs.now()     # iterator wait = ETL attribution
-                try:
-                    ds = next(src)
-                except StopIteration:
+                if ahead is None:
+                    # nothing was staged ahead (the call's first
+                    # batch, or the lockstep budget was spent): this
+                    # iteration stages its own, with the chips idle
+                    ahead = (self._pull(src), None)
+                (ds, staged), ahead = ahead, None
+                if ds is None:
                     break
-                obs.record_etl("ParallelWrapper.fit", te0, obs.now())
                 faults.inject("worker_step")  # site: worker loop body
                 if n_steps is not None and step_i >= n_steps:
                     break               # stay in lockstep across hosts
@@ -1247,106 +1331,113 @@ class ParallelWrapper:
                     # a step the fleet will never dispatch
                     self.elastic.pre_step(net.iteration)
                 t0 = obs.now()
-                x, y = ds.features, ds.labels
-                bsz = jax.tree.leaves(x)[0].shape[0]
-                b = b_local if multi else bsz - (bsz % self.n)
-                if multi and bsz < b:
-                    raise ValueError(
-                        f"batch of {bsz} smaller than the "
-                        f"agreed per-process size {b}: multi-host "
-                        "training needs uniform batches (drop or pad "
-                        "the ragged remainder)")
-                if b == 0:
-                    import logging
-                    logging.getLogger("deeplearning4j_tpu").warning(
-                        "ParallelWrapper: dropping batch of %d examples "
-                        "(< %d workers); use batch sizes divisible by "
-                        "the worker count", bsz, self.n)
-                    continue
+                staged_ahead = staged is not None
+                if not staged_ahead:
+                    staged = self._stage(ds, b_local)
+                x, y, nbytes = staged
+                if x is None:
+                    continue            # smaller than the mesh: dropped
+                if staged_ahead:
+                    nbytes = 0      # enqueued by the iteration before
                 step_i += 1
-                trim = lambda a: a[:b]
-                x, y = jax.tree.map(trim, x), jax.tree.map(trim, y)
-                if multi:
-                    # each process feeds its local shard; assemble ONE
-                    # global device array spanning hosts
-                    x, y = make_global_batch(self.mesh, x, y)
-                else:
-                    x = jax.tree.map(jnp.asarray, x)
-                    y = jax.tree.map(jnp.asarray, y)
                 rng = jax.random.fold_in(
                     jax.random.PRNGKey(net.conf.seed), net.iteration)
                 t1 = obs.now()
-                diag = None
-                nm = getattr(net, "_numerics", None)
-                diag_due = nm is not None and nm.due(net.iteration)
-                if diag_due and self.mode != self.SYNC and \
-                        not self._diag_unsupported_warned:
-                    self._diag_unsupported_warned = True
-                    import logging
-                    logging.getLogger("deeplearning4j_tpu").warning(
-                        "numerics observatory: diagnostic steps are "
-                        "implemented for SYNC mode only; %r trains "
-                        "without in-step diagnostics", self.mode)
-                if diag_due and self.mode == self.SYNC:
-                    self._ensure_diag_step(nm)
-                    if self.sharded_update and self.gather_overlap:
-                        (self._pshard, self._dp_state, net.state, loss,
-                         diag) = self._guarded(
-                            lambda: self._diag_step(
-                                self._pshard, self._dp_state,
-                                net.state, x, y, rng))
-                        self._params_stale = True
-                    elif self.sharded_update:
-                        (net.params, self._dp_state, net.state, loss,
-                         diag) = self._guarded(
-                            lambda: self._diag_step(
-                                net.params, self._dp_state, net.state,
-                                x, y, rng))
-                    else:
-                        (net.params, net.opt_state, net.state, loss,
-                         diag) = self._guarded(
-                            lambda: self._diag_step(
-                                net.params, net.opt_state, net.state,
-                                x, y, rng))
-                elif self.mode == self.SYNC:
-                    if self.sharded_update and self.gather_overlap:
-                        (self._pshard, self._dp_state, net.state,
-                         loss) = self._guarded(
-                            lambda: self._step(
-                                self._pshard, self._dp_state,
-                                net.state, x, y, rng))
-                        self._params_stale = True
-                    elif self.sharded_update:
-                        (net.params, self._dp_state, net.state,
-                         loss) = self._guarded(
-                            lambda: self._step(
-                                net.params, self._dp_state, net.state,
-                                x, y, rng))
-                    else:
-                        net.params, net.opt_state, net.state, loss = \
-                            self._guarded(
-                                lambda: self._step(
-                                    net.params, net.opt_state,
+                # from the launch until the next batch is enqueued the
+                # host must not stop for a full collection (_gc_held)
+                with _gc_held():
+                    diag = None
+                    nm = getattr(net, "_numerics", None)
+                    diag_due = nm is not None and nm.due(net.iteration)
+                    if diag_due and self.mode != self.SYNC and \
+                            not self._diag_unsupported_warned:
+                        self._diag_unsupported_warned = True
+                        import logging
+                        logging.getLogger("deeplearning4j_tpu").warning(
+                            "numerics observatory: diagnostic steps are "
+                            "implemented for SYNC mode only; %r trains "
+                            "without in-step diagnostics", self.mode)
+                    if diag_due and self.mode == self.SYNC:
+                        self._ensure_diag_step(nm)
+                        if self.sharded_update and self.gather_overlap:
+                            (self._pshard, self._dp_state, net.state, loss,
+                             diag) = self._guarded(
+                                lambda: self._diag_step(
+                                    self._pshard, self._dp_state,
                                     net.state, x, y, rng))
-                elif self.mode == self.ENCODED:
-                    (net.params, net.opt_state, net.state,
-                     self._dp_state, loss) = self._guarded(
-                        lambda: self._step(
-                            net.params, net.opt_state, net.state,
-                            self._dp_state, x, y, rng))
-                elif self.mode == self.ASYNC:
-                    p, o, a = self._dp_state
-                    p, o, net.state, a, loss = self._guarded(
-                        lambda: self._step(p, o, net.state, a, x, y,
-                                           rng))
-                    self._dp_state = (p, o, a)
-                else:  # AVERAGING
-                    p, o = self._dp_state
-                    p, o, net.state, loss = self._guarded(
-                        lambda: self._step(
-                            p, o, net.state, x, y, rng,
-                            jnp.asarray(net.iteration, jnp.int32)))
-                    self._dp_state = (p, o)
+                            self._params_stale = True
+                        elif self.sharded_update:
+                            (net.params, self._dp_state, net.state, loss,
+                             diag) = self._guarded(
+                                lambda: self._diag_step(
+                                    net.params, self._dp_state, net.state,
+                                    x, y, rng))
+                        else:
+                            (net.params, net.opt_state, net.state, loss,
+                             diag) = self._guarded(
+                                lambda: self._diag_step(
+                                    net.params, net.opt_state, net.state,
+                                    x, y, rng))
+                    elif self.mode == self.SYNC:
+                        if self.sharded_update and self.gather_overlap:
+                            (self._pshard, self._dp_state, net.state,
+                             loss) = self._guarded(
+                                lambda: self._step(
+                                    self._pshard, self._dp_state,
+                                    net.state, x, y, rng))
+                            self._params_stale = True
+                        elif self.sharded_update:
+                            (net.params, self._dp_state, net.state,
+                             loss) = self._guarded(
+                                lambda: self._step(
+                                    net.params, self._dp_state, net.state,
+                                    x, y, rng))
+                        else:
+                            net.params, net.opt_state, net.state, loss = \
+                                self._guarded(
+                                    lambda: self._step(
+                                        net.params, net.opt_state,
+                                        net.state, x, y, rng))
+                    elif self.mode == self.ENCODED:
+                        (net.params, net.opt_state, net.state,
+                         self._dp_state, loss) = self._guarded(
+                            lambda: self._step(
+                                net.params, net.opt_state, net.state,
+                                self._dp_state, x, y, rng))
+                    elif self.mode == self.ASYNC:
+                        p, o, a = self._dp_state
+                        p, o, net.state, a, loss = self._guarded(
+                            lambda: self._step(p, o, net.state, a, x, y,
+                                               rng))
+                        self._dp_state = (p, o, a)
+                    else:  # AVERAGING
+                        p, o = self._dp_state
+                        p, o, net.state, loss = self._guarded(
+                            lambda: self._step(
+                                p, o, net.state, x, y, rng,
+                                jnp.asarray(net.iteration, jnp.int32)))
+                        self._dp_state = (p, o)
+                    ahead_s, ahead_error = 0.0, None
+                    if n_steps is None or step_i < n_steps:
+                        # the step is on the chips and nothing waits for
+                        # it yet: pull the next batch and enqueue its copy
+                        # now, so that it crosses while this step computes
+                        # and the next step finds it where it reads it
+                        try:
+                            nxt = self._pull(src)
+                            ta = obs.now()
+                            staged = None if nxt is None \
+                                else self._stage(nxt, b_local)
+                            ahead_s = obs.now() - ta
+                        except Exception as e:
+                            # the iterator's (or the trim's) error belongs
+                            # after this step's loss, bookkeeping and
+                            # listeners, where the loop met it before
+                            ahead_error = e
+                        else:
+                            ahead = (nxt, staged)
+                            if staged is not None:
+                                nbytes += staged[2]
                 t2 = obs.now()
                 # the float() blocks on the step AND its averaging /
                 # all-reduce collective — this wait is the visible
@@ -1366,7 +1457,12 @@ class ParallelWrapper:
                     # ring + cadence-gated telemetry publish (a no-op
                     # branch when no FleetTelemetry is installed)
                     self.elastic.post_step(net.iteration, net.score_)
-                obs.record_worker_step(worker, t0, t1, t2, t3)
+                # h2d: the enqueueing done inside this iteration,
+                # whichever batch it was for (ahead_s of it after the
+                # dispatch, drawn in front of it); collective_sync
+                # starts where the blocking fetch did
+                obs.record_worker_step(worker, t0, t1 + ahead_s, t2, t3,
+                                       nbytes, staged_ahead)
                 net.iteration += 1
                 if diag is not None:
                     # publishes per-layer gauges incl. the replica-
@@ -1378,6 +1474,8 @@ class ParallelWrapper:
                     nm.note_score(net.score_)
                 for l in net.listeners:
                     l.iteration_done(net, net.iteration, net.epoch)
+                if ahead_error is not None:
+                    raise ahead_error
             net.epoch += 1
         # normal completion: retire the liveness beat so a lingering
         # process doesn't read as a stale worker forever (a crashed

@@ -188,7 +188,8 @@ def test_small_ring_reports_dropped_and_refuses_the_window():
       "E/deliver"]),
     ("E", lambda t: obs.record_etl("E", t, t + 1), ["E/etl"]),
     ("ParallelWrapper.fit",
-     lambda t: obs.record_worker_step("w0", t, t + 1, t + 2, t + 3),
+     lambda t: obs.record_worker_step("w0", t, t + 1, t + 2, t + 3,
+                                      nbytes=64, staged_ahead=True),
      ["ParallelWrapper.fit/step", "ParallelWrapper.fit/h2d",
       "ParallelWrapper.fit/dispatch",
       "ParallelWrapper.fit/collective_sync"]),
@@ -520,13 +521,27 @@ def test_worker_step_recording_and_heartbeat(tmp_path):
     health.reset()
     trace.enable(str(tmp_path / "w.jsonl"))
     before = metrics.WORKER_STEP.labels(worker="procX").count
+    ahead = metrics.WORKER_STAGED_AHEAD.labels(worker="procX").value
     t0 = obs.now()
-    obs.record_worker_step("procX", t0, t0 + 0.001, t0 + 0.002,
-                           t0 + 0.010)
+    for staged_ahead in (False, True, True):
+        obs.record_worker_step("procX", t0, t0 + 0.001, t0 + 0.002,
+                               t0 + 0.010, nbytes=4096,
+                               staged_ahead=staged_ahead)
     trace.disable()
     assert metrics.WORKER_STEP.labels(worker="procX").count \
-        == before + 1
+        == before + 3
     assert metrics.WORKER_SYNC.labels(worker="procX").value > 0
+    # the engagement share's numerator: steps whose batch was staged
+    # during the step before (over WORKER_STEP's count)
+    assert metrics.WORKER_STAGED_AHEAD.labels(worker="procX").value \
+        == ahead + 2
+    recs = [r for r in trace.records()
+            if r.name == "ParallelWrapper.fit"][-3:]
+    assert [r.phases for r in recs] == [
+        ("h2d", "dispatch", "collective_sync")] * 3
+    assert [r.counts for r in recs] == [
+        {"worker": "procX", "bytes": 4096, "staged_ahead": s}
+        for s in (0, 1, 1)]
     assert not health.check(stale_after=30)["procX"]["stale"]
     names = {e["name"] for e in trace.events()}
     assert "ParallelWrapper.fit/step" in names

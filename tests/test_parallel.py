@@ -274,6 +274,267 @@ def test_ring_attention_masked():
                                rtol=2e-4, atol=2e-5)
 
 
+# --- fit stages the next batch onto the mesh while a step runs --------------
+
+_STAGING_MODES = {
+    "sync": {},
+    "sync-sharded": {"sharded_update": True},
+    "sync-overlap": {"sharded_update": True, "gather_overlap": True},
+    "encoded": {"mode": ParallelWrapper.ENCODED},
+    "async": {"mode": ParallelWrapper.ASYNC},
+    "averaging": {"mode": ParallelWrapper.AVERAGING,
+                  "averaging_frequency": 2},
+}
+#: rows of each batch an epoch feeds: all even, or with a ragged one
+#: (trimmed to 56 over 8 workers) and one smaller than the mesh
+#: (dropped with a warning) in the middle
+_STAGING_PLANS = {"even": (64, 64, 64, 64, 64),
+                  "ragged-dropped": (64, 64, 60, 5, 64, 64)}
+
+
+def _plan_batches(plan):
+    ds, out, at = _toy_data(sum(plan)), [], 0
+    for n in plan:
+        out.append(DataSet(ds.features[at:at + n], ds.labels[at:at + n]))
+        at += n
+    return out
+
+
+class _StepLog:
+    """A listener that keeps what each step showed it, in order."""
+
+    def __init__(self):
+        self.calls = []
+
+    def iteration_done(self, net, iteration, epoch):
+        self.calls.append((iteration, net.score_))
+
+
+def _spied(monkeypatch, **kw):
+    """A wrapper whose staging calls and step arguments are recorded:
+    ``puts`` holds (type of the source, its shape, the sharding asked
+    for) of every ``jax.device_put``, ``fed`` the batch arrays each
+    step was called with."""
+    net = _net()
+    net.listeners.append(_StepLog())
+    w = ParallelWrapper(net, workers=8, prefetch_buffer=0, **kw)
+    w._ensure_ready()
+    puts, fed = [], []
+    real_put, real_step = jax.device_put, w._step
+
+    def put(a, sharding=None, **k):
+        puts.append((type(a), np.shape(a), sharding))
+        return real_put(a, sharding, **k)
+
+    def step(*args):
+        # every builder's signature ends (..., x, y, rng[, iteration])
+        at = -4 if w.mode == ParallelWrapper.AVERAGING else -3
+        fed.append(args[at:at + 2])
+        return real_step(*args)
+
+    monkeypatch.setattr(jax, "device_put", put)
+    w._step = step
+    return w, net, puts, fed
+
+
+def _fit_records():
+    from deeplearning4j_tpu.obs import trace
+    return [r for r in trace.records() if r.name == "ParallelWrapper.fit"]
+
+
+@pytest.mark.parametrize("plan", sorted(_STAGING_PLANS))
+@pytest.mark.parametrize("mode", sorted(_STAGING_MODES))
+def test_fit_stages_ahead_and_trains_as_one_call_a_batch(
+        mode, plan, monkeypatch):
+    """One ``fit`` over the epoch (every batch but the first staged
+    while the step before it ran) against the same batches fed one
+    ``fit`` call each (which never stages ahead): same losses, same
+    listener calls, same parameters; the step reads arrays laid over
+    the mesh as it declares them, made straight from host memory."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    batches = _plan_batches(_STAGING_PLANS[plan])
+    kept = [b for b in batches if b.num_examples() >= 8]
+    w, net, puts, fed = _spied(monkeypatch, **_STAGING_MODES[mode])
+    seen = len(_fit_records())
+    w.fit(batches)
+    recs, puts = _fit_records()[seen:], list(puts)
+
+    w1, net1, _, _ = _spied(monkeypatch, **_STAGING_MODES[mode])
+    seen = len(_fit_records())
+    for b in batches:
+        w1.fit([b])
+    recs1 = _fit_records()[seen:]
+
+    log, log1 = net.listeners[0].calls, net1.listeners[0].calls
+    assert [i for i, _ in log] == list(range(1, len(kept) + 1))
+    assert log == log1                  # order, iteration, score: equal
+    assert net.iteration == net1.iteration == len(kept)
+    for a, b in zip(jax.tree.leaves(net.params),
+                    jax.tree.leaves(net1.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # what each step was given: the batch's rows in order, trimmed to
+    # what 8 workers divide, an eighth on each device
+    rows = NamedSharding(w.mesh, P("data"))
+    assert len(fed) == len(kept)
+    for (x, y), b in zip(fed, kept):
+        n = b.num_examples() - b.num_examples() % 8
+        np.testing.assert_array_equal(np.asarray(x), b.features[:n])
+        np.testing.assert_array_equal(np.asarray(y), b.labels[:n])
+        for a in (x, y):
+            assert a.sharding.is_equivalent_to(rows, a.ndim)
+            assert sorted(s.data.shape[0]
+                          for s in a.addressable_shards) == [n // 8] * 8
+    # ... each made by ONE put of the host array over the mesh: no put
+    # of a batch onto a single device, none of a device array
+    batch_puts = [p for p in puts if p[1][:1] and p[1][0] >= 8
+                  and len(p[1]) == 2]
+    assert len(batch_puts) == 2 * len(kept)
+    assert all(t is np.ndarray and sh == rows for t, _, sh in batch_puts)
+
+    # the record: same phases, what was enqueued in each iteration,
+    # and whether the step ran on a batch staged during the one before
+    assert all(r.phases == ("h2d", "dispatch", "collective_sync")
+               for r in recs + recs1)
+    size = lambda b: (b.features[:b.num_examples() // 8 * 8].nbytes
+                      + b.labels[:b.num_examples() // 8 * 8].nbytes)
+    assert sum(r.counts["bytes"] for r in recs) == sum(map(size, kept))
+    assert [r.counts["bytes"] for r in recs1] == [size(b) for b in kept]
+    assert not any(r.counts["staged_ahead"] for r in recs1)
+    ahead = [r.counts["staged_ahead"] for r in recs]
+    if plan == "even":
+        assert ahead == [0, 1, 1, 1, 1]         # N - 1 of N
+        assert [r.counts["bytes"] for r in recs] == \
+            [2 * size(kept[0])] + [size(kept[0])] * 3 + [0]
+    else:
+        # the dropped batch was the one pulled ahead, so the step
+        # after it staged its own
+        assert ahead == [0, 1, 1, 0, 1]
+
+
+@pytest.mark.parametrize("was_on", [True, False])
+def test_fit_holds_the_collector_from_launch_to_enqueue(
+        was_on, monkeypatch):
+    """No garbage collection between a step's launch and the enqueueing
+    of the batch staged ahead; the collector is left as it was found,
+    also when the step raises."""
+    import gc
+    from deeplearning4j_tpu.resilience import faults
+    w, net, _, _ = _spied(monkeypatch)
+    at_launch, at_stage = [], []
+    launch, stage = w._step, w._stage
+
+    def step(*args):
+        at_launch.append(gc.isenabled())
+        if len(at_launch) == 5:
+            raise faults.InjectedFault("in the launch")
+        return launch(*args)
+
+    def staged(ds, b_local):
+        at_stage.append(gc.isenabled())
+        return stage(ds, b_local)
+
+    w._step, w._stage = step, staged
+    (gc.enable if was_on else gc.disable)()
+    try:
+        w.fit(_plan_batches((64, 64, 64)))
+        assert gc.isenabled() == was_on
+        # the call's own first batch is staged before the launch, the
+        # two staged ahead inside the hold
+        assert at_launch == [False] * 3
+        assert at_stage == [was_on, False, False]
+        with pytest.raises(faults.InjectedFault):
+            w.fit(_plan_batches((64, 64, 64)))
+        assert gc.isenabled() == was_on
+    finally:
+        gc.enable()
+
+
+class _Counted:
+    """An iterable of batches that counts how many were pulled."""
+
+    def __init__(self, batches):
+        self.batches, self.pulled = batches, 0
+
+    def __iter__(self):
+        for b in self.batches:
+            self.pulled += 1
+            yield b
+
+
+class _FailsAt:
+    def __init__(self, iteration):
+        self.iteration = iteration
+
+    def iteration_done(self, net, iteration, epoch):
+        if iteration == self.iteration:
+            raise FloatingPointError(f"step {iteration} went wrong")
+
+
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["sharded", "gather-overlap"])
+@pytest.mark.parametrize("site", ["worker_step", "listener"])
+def test_fit_failure_drops_the_batch_staged_ahead(site, overlap):
+    """A failure in step 3 raises as before, has advanced the iterator
+    by at most one batch beyond it, and leaves a wrapper whose next
+    ``fit`` trains from what step 2 (``worker_step``: the fault fires
+    before the dispatch) or step 3 (a listener) left."""
+    from deeplearning4j_tpu.resilience import faults
+    batches = _plan_batches((64,) * 6)
+    kw = {"sharded_update": True, "gather_overlap": overlap,
+          "prefetch_buffer": 0}
+    net = _net()
+    w = ParallelWrapper(net, workers=8, **kw)
+    feed = _Counted(batches)
+    if site == "worker_step":
+        done = 2
+        with faults.active("worker_step:error=InjectedFault:nth=3:max=1"):
+            with pytest.raises(faults.InjectedFault):
+                w.fit(feed)
+    else:
+        done = 3
+        net.listeners.append(_FailsAt(3))
+        with pytest.raises(FloatingPointError, match="step 3"):
+            w.fit(feed)
+        net.listeners.clear()
+    assert net.iteration == done
+    assert done <= feed.pulled <= 4     # at most one beyond step 3
+    assert not w._params_stale          # fit's finally materialised
+
+    ref = _net()
+    wr = ParallelWrapper(ref, workers=8, **kw)
+    wr.fit(batches[:done])
+    for a, b in zip(jax.tree.leaves(net.params),
+                    jax.tree.leaves(ref.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    w.fit(batches[done:done + 2])
+    wr.fit(batches[done:done + 2])
+    assert net.iteration == ref.iteration == done + 2
+    assert net.score_ == ref.score_
+    for a, b in zip(jax.tree.leaves(net.params),
+                    jax.tree.leaves(ref.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fit_defers_the_iterators_error_until_the_step_is_booked():
+    """An iterator that fails while the batch after step 2 is pulled
+    (now during step 2) still raises after step 2's loss, bookkeeping
+    and listeners, as when the loop pulled it afterwards."""
+    def feed():
+        yield from _plan_batches((64, 64))
+        raise OSError("the reader lost its file")
+
+    net = _net()
+    log = _StepLog()
+    net.listeners.append(log)
+    w = ParallelWrapper(net, workers=8, prefetch_buffer=0)
+    with pytest.raises(OSError, match="lost its file"):
+        w.fit(feed())
+    assert net.iteration == 2
+    assert [i for i, _ in log.calls] == [1, 2]
+    assert log.calls[-1][1] == net.score_
+
+
 def _multi_io_graph(seed=1):
     from deeplearning4j_tpu.nn import NeuralNetConfiguration
     from deeplearning4j_tpu.nn.graph import ComputationGraph

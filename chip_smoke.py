@@ -200,7 +200,7 @@ def check_paged_equals_dense(tag, net, paged, dense):
 def kernels_in_decode_step(model, net, sched):
     """Pallas kernel names in the LOWERED ``serving.decode_step``."""
     text = sched._step_fn.lower(
-        _shapes(model._decode_params(net)), _shapes(sched.pager.pool),
+        _shapes(model.decode_params(net)), _shapes(sched.pager.pool),
         *sched._step_feed_shapes()).as_text()
     return re.findall(r'kernel_name = "([^"]+)"', text)
 

@@ -16,7 +16,7 @@ cross-row ``dgamma``/``dbeta`` reductions accumulated across the
 sequential TPU grid):
 
 - :func:`rms_norm` — RMSNorm over the trailing axis. Dispatched from
-  ``nn.layers.core.RMSNorm`` and ``zoo.gpt._rms`` (train blocks AND
+  ``nn.layers.core.RMSNorm`` and ``nn.decoder_infer.rms`` (train blocks AND
   the KV-cached decode/prefill paths).
 - :func:`add_rms_norm` — residual add + RMSNorm in one pass,
   returning ``(normed, summed)`` — the pre-norm transformer block's
@@ -191,7 +191,7 @@ _rms.defvjp(_rms_vjp_fwd, _rms_vjp_bwd)
 
 def rms_norm_reference(x, gamma, eps: float = RMSNORM_EPS):
     """The XLA fallback — EXACTLY the expression
-    ``nn.layers.core.RMSNorm`` / ``zoo.gpt._rms`` used before this
+    ``nn.layers.core.RMSNorm`` / ``nn.decoder_infer.rms`` used before this
     module existed (same ops, same order: the gate-off program is
     byte-identical, fenced in tests/test_fused_kernels.py)."""
     ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)

@@ -997,7 +997,7 @@ def _reference_paged_attention(q, pool, li, pt, pos):
     ``(codes, scales)`` of ``serving/kv_pager.py``; ``li`` the layer;
     ``pt`` [S, MP] i32; ``pos`` [S, R] i32: row r attends cache
     positions ``<= pos[s, r]``. Returns [S, R, H, D]. Mirrors
-    ``zoo/gpt.py::_token_logits`` value-for-value (same scale
+    ``nn/decoder_infer.py::DenseKV`` value-for-value (same scale
     factoring out of the einsums, same ``-1e9`` mask), which is what
     keeps paged greedy decode token-identical to dense ``generate()``:
     trash and stale positions sit past ``pos`` and get exact-zero

@@ -440,7 +440,7 @@ class ParallelWrapper:
         per call would retrace+recompile the full-tree flatten at
         every fit entry). The leaf weakrefs record WHICH params the
         shards came from (:meth:`_params_current_in_shards` — the
-        ``zoo.gpt._decode_params`` staleness idiom)."""
+        ``zoo.gpt`` ``decode_params`` staleness idiom)."""
         import weakref
         layout = self._layout()
         if self._flatten_jit is None:

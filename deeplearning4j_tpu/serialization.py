@@ -355,8 +355,9 @@ class ShardedCheckpointer:
         n_src = int(wm["n_shards"]) if wm else int(wrapper.n)
         src_layout = (wm or {}).get("layout", want_layout)
         if n_src == wrapper.n and src_layout == want_layout:
-            tree = self.restore(step,
-                                target=wrapper.checkpoint_target())
+            target = wrapper.checkpoint_target()
+            self._check_layout(step, target)
+            tree = self.restore(step, target=target)
             wrapper.load_checkpoint_tree(tree)
             return wrapper
         if not reshard:
@@ -403,8 +404,39 @@ class ShardedCheckpointer:
                                   jax.eval_shape(lambda: net.state)),
             "meta": {"iteration": 0, "epoch": 0},
         }
+        self._check_layout(step, target)
         return self.mngr.restore(
             step, args=self._ocp.args.StandardRestore(target))
+
+    def _check_layout(self, step: int, target) -> None:
+        """Hold the step's RECORDED leaf shapes (its metadata file: no
+        array is read) against the restore target's, leaf by name: an
+        intact record that names another shape was written by a
+        different net, a configuration error (``LayoutMismatch``),
+        never corruption. A record that cannot be read says nothing
+        here: the restore itself then fails as corruption does."""
+        from deeplearning4j_tpu.parallel.zero import LayoutMismatch
+        try:
+            recorded = {m.name: tuple(m.shape)
+                        for m in jax.tree.leaves(
+                            self.mngr.item_metadata(step))
+                        if getattr(m, "shape", None) is not None}
+        except Exception:           # unreadable record: not our call
+            return
+
+        def name(path):
+            return ".".join(str(getattr(k, "key", getattr(
+                k, "idx", getattr(k, "name", k)))) for k in path)
+
+        for path, leaf in jax.tree_util.tree_flatten_with_path(target)[0]:
+            want = tuple(getattr(leaf, "shape", ()))
+            have = recorded.get(name(path), want)
+            if have != want:
+                raise LayoutMismatch(
+                    f"checkpoint step {step} under {self.directory} "
+                    f"records {name(path)} with shape {have}, but the "
+                    f"net it is restored into wants {want}: this "
+                    "directory was written by a different net")
 
     def restore_latest_valid(self, net=None, *, target=None,
                              wrapper=None):

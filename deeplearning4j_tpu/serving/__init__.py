@@ -7,7 +7,7 @@ over a paged/block KV cache, behind a front end that keeps
 
 - :mod:`~deeplearning4j_tpu.serving.kv_pager` — fixed pool of
   block-token KV pages, per-sequence page table, free-list allocation,
-  int8 page storage (the ``zoo.gpt._quant_kv`` codes);
+  int8 page storage (the ``nn.decoder_infer.quant_kv`` codes);
 - :mod:`~deeplearning4j_tpu.serving.scheduler` — ONE fixed-shape
   jitted decode step over every slot + per-bucket prefill-into-pages;
   zero retraces after ``warmup()``;

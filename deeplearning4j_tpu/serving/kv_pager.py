@@ -33,9 +33,9 @@ carries as its donated pool argument):
   layout, ``[L, P, Hkv, 2D, block]``, was the dense cache's: the
   minor dimension was the page's 16 positions, an eighth of a
   128-lane tile.) dtype is ``int8`` under ``cache_quant="int8"``
-  (codes from ``zoo.gpt._quant_kv``, the same quantiser the dense
-  path uses — the pager-correctness fence demands token identity),
-  else the model's compute dtype.
+  (codes from ``nn.decoder_infer.quant_kv``, the same quantiser the
+  dense path uses — the pager-correctness fence demands token
+  identity), else the model's compute dtype.
 - ``scales`` ``[L, P, Hkv, 2, block]`` f32 — per-(page, head, k/v
   half, position) dequant scales; present only under int8. Positions
   stay minor here: a minor dimension of 2 would pad every pair of
@@ -73,9 +73,10 @@ full-page prefix covers: admission hashes the prompt's page chain
 (:meth:`KVPager.match_prefix`), adopts the shared pages with
 :meth:`KVPager.adopt` (refcount bump, no prefill), and the scheduler
 copies a page before writing it whenever its refcount exceeds one
-(:meth:`KVPager.cow` does the bookkeeping; the device copy is the
-scheduler's sentried page-copy program). A page returns to the free
-list only when its LAST reference releases.
+(:meth:`KVPager.cow` does the bookkeeping; the device copy is
+:meth:`KVPager.copy_page` inside the scheduler's sentried page-copy
+program). A page returns to the free list only when its LAST
+reference releases.
 
 The pager itself is host-side bookkeeping: free list, per-page
 refcounts, per-owner page lists, the chain index, and the invariants
@@ -84,14 +85,28 @@ references per page equals its refcount, trash page exempt — no page
 both free and referenced, allocation conservation). The device arrays
 live here too so the scheduler can thread them through its jitted
 step and write the updated pool back.
+
+The layouts above are known HERE and to the kernels that read them
+(``ops/pallas_kernels.py``), nowhere else: a program of the scheduler
+reaches its pool through the **cache objects** at the end of this
+file (``nn/decoder_infer.py``'s contract, ``attend(li, mha, h)``):
+:meth:`KVPager.rows` (R rows a slot; KV pages or states, decided once
+by ``state_rows``) and :class:`StateChunk`, and through
+:meth:`KVPager.write_prompt` and :meth:`KVPager.copy_page`.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu.nn import decoder_infer as di
+from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import metrics as _metrics
+from deeplearning4j_tpu.ops import retention
+from deeplearning4j_tpu.ops.pallas_kernels import (
+    _reference_paged_attention, paged_decode_attention, retention_decode)
 
 
 class PageTableError(RuntimeError):
@@ -111,7 +126,6 @@ class KVPager:
                  n_pages: int, block: int, cache_quant: Optional[str],
                  dtype: str = "float32",
                  state_rows: Optional[int] = None):
-        import jax.numpy as jnp
         if state_rows is not None and cache_quant is not None:
             raise ValueError("a recurrent-state pool is float32: "
                              "cache_quant does not apply to it")
@@ -190,6 +204,45 @@ class KVPager:
     def pool_bytes(self) -> int:
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize
                    for a in self._pool)
+
+    # -- inside a traced program, over ITS pool -------------------------
+    def rows(self, dims, pool, pt, pos, act):
+        """The cache object of R rows a slot: ``pt`` [S, MP] i32 the
+        slots' page-table rows, ``pos`` [S, R] i32 the rows'
+        positions, ``act`` bool broadcastable to [S, R] (False rows
+        write nothing a live sequence reads). Its ``pool`` is the pool
+        after the rows."""
+        kind = PagedKV if self.state_rows is None else PagedState
+        return kind(dims, pool, pt, pos, act)
+
+    @staticmethod
+    def write_prompt(pool, page_ids, layers):
+        """``pool`` with one sequence's bucket prefill written as whole
+        pages: ``layers`` each layer's ``(k, v) [1, Tb, Hkv, D]``
+        (``decoder_infer.causal_prefill``'s ``keep``), ``page_ids`` the
+        sequence's first ``Tb / block`` pages in position order. The
+        pool's own layout, so nothing is transposed on the way, and
+        all layers go in one scatter."""
+        kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
+                        for k, v in layers])    # [L, Tb, Hkv, 2D]
+        n_l, tb, n_kv, d2 = kv.shape
+        block = pool[0].shape[2]
+        paged = (n_l, tb // block, block, n_kv)
+        if len(pool) == 2:
+            codes, scales = pool
+            w8, s = di.quant_kv(kv.reshape(n_l, tb, n_kv, 2, d2 // 2), 4)
+            return (codes.at[:, page_ids].set(w8.reshape(*paged, d2)),
+                    scales.at[:, page_ids].set(
+                        s.reshape(*paged, 2).transpose(0, 1, 3, 4, 2)))
+        (kvpool,) = pool
+        return (kvpool.at[:, page_ids].set(
+            kv.reshape(*paged, d2).astype(kvpool.dtype)),)
+
+    @staticmethod
+    def copy_page(pool, src, dst):
+        """``pool`` with page ``src`` copied over page ``dst`` (all
+        layers, every array): the copy-on-write primitive."""
+        return tuple(a.at[:, dst].set(a[:, src]) for a in pool)
 
     # -- allocation ------------------------------------------------------
     def free_pages(self) -> int:
@@ -439,3 +492,120 @@ class KVPager:
                     raise PageTableError(
                         f"chain entry {key[:2]} references freed "
                         f"page {p}")
+
+
+# -- the pool's cache objects (nn/decoder_infer.py's contract) ---------------
+
+class _Rows:
+    def __init__(self, dims, pool, pt, pos, act):
+        self.dims = dims
+        self.pool = pool
+        self.pt = pt
+        self.pos = pos
+        self.act = act
+
+
+class PagedKV(_Rows):
+    """R positions a slot against the KV pool: row r's KV goes to page
+    ``pt[s, pos // block]`` at offset ``pos % block``, and the
+    attention reads the slot's pages through its page-table row.
+    ``decoder_infer.DenseKV``'s arithmetic value for value (the
+    token-identity fences of ``tests/test_serving.py``); only the
+    addressing differs. Every matmul runs on the flattened [S*R, F]
+    rows, so a row's arithmetic is the same whatever R is (the
+    spec-decode fence leans on that). A position past the slot's page
+    table is clamped EXPLICITLY and routed to the trash page: JAX
+    gathers clamp silently, and junk must never land in a live page."""
+
+    def attend(self, li, mha, h):
+        dims, pool, pt, pos = self.dims, self.pool, self.pt, self.pos
+        S, R = pos.shape
+        block = pool[0].shape[2]
+        pflat = pos.reshape(S * R)
+        q, k, v = di.qkv(mha, h, dims, lambda z: di.rotary_rows(
+            z, dims.rope_theta, pflat))
+        n_kv, hd = k.shape[1:]
+        q = q.reshape(S, R, dims.n_heads, hd)
+        kv = jnp.concatenate([k.reshape(S, R, n_kv, hd),
+                              v.reshape(S, R, n_kv, hd)],
+                             axis=3)                    # [S, R, Kv, 2D]
+        cap = pt.shape[1] * block
+        inb = self.act & (pos < cap)
+        pidx = jnp.minimum(pos // block, pt.shape[1] - 1)
+        pids = jnp.where(inb, jnp.take_along_axis(pt, pidx, axis=1), 0)
+        offs = pos % block
+        if len(pool) == 2:
+            codes, scales = pool
+            q8, s_new = di.quant_kv(kv.reshape(S, R, n_kv, 2, hd), 4)
+            pool = (codes.at[li, pids, offs].set(
+                        q8.reshape(S, R, n_kv, 2 * hd)),
+                    scales.at[li, pids, :, :, offs].set(s_new))
+        else:
+            (kvpool,) = pool
+            pool = (kvpool.at[li, pids, offs].set(
+                kv.astype(kvpool.dtype)),)
+        self.pool = pool
+        # the scatter above runs before the read, so a row attends its
+        # own key and every earlier row's; later rows' keys (and any
+        # stale speculative garbage past the accepted length) sit
+        # strictly beyond pos and stay at exact-zero softmax weight
+        if R == 1:
+            # THE decode step: the kernel reads each slot's pages in
+            # place, up to its length (a routed-to-trash row is an
+            # inactive slot: it walks no page and returns zeros)
+            a = paged_decode_attention(
+                q[:, 0], pool, li, pt,
+                jnp.where(inb[:, 0], pos[:, 0] + 1, 0))
+        else:
+            a = _reference_paged_attention(q, pool, li, pt, pos)
+        return a.reshape(S * R, -1)
+
+
+class PagedState(_Rows):
+    """One position a slot against the state pool: the slot's ONE
+    state page (``pt``'s only column) is updated in place and read
+    (``ops.retention_decode``); an inactive slot's page is neither.
+    (R is 1: the scheduler refuses the multi-row programs for a
+    retention model at construction.)"""
+
+    def attend(self, li, mha, h):
+        dims = self.dims
+        S = h.shape[0]
+        q, k, v, log_g = retention.project(
+            mha, h, dims.n_heads, dims.n_kv_heads,
+            lambda z: di.rotary_rows(z, dims.rope_theta,
+                                     self.pos.reshape(S)), RMSNORM_EPS)
+        a, self.pool = retention_decode(
+            q, k, v, jnp.exp(log_g), self.pool, li, self.pt[:, 0],
+            jnp.broadcast_to(self.act, (S, 1))[:, 0])
+        return a.reshape(S, -1)
+
+
+class StateChunk(di.RetentionRows):
+    """One chunk of a retention prompt (batch 1) against the
+    sequence's state page: a layer reads the state the chunk before
+    left there (an empty one when ``start`` is 0: a page comes off the
+    free list as its last owner left it) and writes its own back; what
+    its queries need of the chunks before they read from ``history``
+    (``ops.retention.zero_history``, one layer a row), which gets this
+    chunk's rows added. ``pool`` and ``hist`` are both after the
+    chunk."""
+
+    def __init__(self, dims, pool, history, page, start, valid):
+        super().__init__(dims, start, valid)
+        self.pool = pool
+        self.hist = history
+        self.page = page
+
+    def state(self, li):
+        return tuple(jnp.where(self.start > 0, a[li, self.page], 0.0)[None]
+                     for a in self.pool)
+
+    def history(self, li):
+        return tuple(a[li, None] for a in self.hist)
+
+    def keep(self, li, state, hist):
+        self.pool = tuple(a.at[li, self.page].set(new[0])
+                          for a, new in zip(self.pool, state))
+        self.hist = tuple(a.at[li].set(new[0])
+                          for a, new in zip(self.hist, hist))

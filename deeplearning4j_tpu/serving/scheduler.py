@@ -14,16 +14,22 @@ anything changing shape. Shapes never vary, so after
 traces no matter how traffic arrives (the low-latency JIT-graph-capture
 decode contract, PAPERS.md: arxiv 2604.23467).
 
-The single-token step's attention is ``ops.paged_decode_attention``:
-on the TPU a kernel that reads each slot's KV pages in place, up to
-the slot's length; elsewhere (and for every multi-row query) the plain
-``_reference_paged_attention``, whose math deliberately mirrors
-``zoo/gpt.py::_token_logits`` value-for-value (same ``_quant_kv``
-codes/scales, same scale factoring out of the einsums, same ``-1e9``
-mask): padded/trash positions contribute exact zeros after softmax,
-so paged greedy decode is TOKEN-IDENTICAL to dense ``generate()`` —
-the pager-correctness fence in ``tests/test_serving.py`` asserts it
-for both the float and the int8-KV cache paths.
+Every program here is the ONE inference block and stack loop of
+``nn/decoder_infer.py`` — the same ``generate()`` runs — over a cache
+object the pager builds for the program's pool
+(``kv_pager``: ``KVPager.rows``, ``.write_prompt``, ``StateChunk``): what a
+layer's rows write and read is the whole difference between the dense
+path and this one, and the pool's layout is the pager's alone. The
+single-token step's attention is ``ops.paged_decode_attention``: on
+the TPU a kernel that reads each slot's KV pages in place, up to the
+slot's length; elsewhere (and for every multi-row query) the plain
+``_reference_paged_attention``, whose math is the dense cache
+object's value for value (same ``quant_kv`` codes/scales, same scale
+factoring out of the einsums, same ``-1e9`` mask): padded/trash
+positions contribute exact zeros after softmax, so paged greedy
+decode is TOKEN-IDENTICAL to dense ``generate()`` — the
+pager-correctness fence in ``tests/test_serving.py`` asserts it for
+both the float and the int8-KV cache paths.
 
 Two opt-in multipliers ride the same machinery (PR 16). With
 ``spec_k > 1`` each iteration drafts k-1 tokens on the host (prompt
@@ -60,11 +66,9 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu import obs
-from deeplearning4j_tpu.ops.pallas_kernels import (
-    _reference_paged_attention, paged_decode_attention, retention_decode)
-from deeplearning4j_tpu.serving.kv_pager import KVPager
-from deeplearning4j_tpu.zoo.gpt import (
-    _block_tail, _quant_kv, _rms, prompt_bucket)
+from deeplearning4j_tpu.nn import decoder_infer as di
+from deeplearning4j_tpu.serving.kv_pager import KVPager, StateChunk
+from deeplearning4j_tpu.zoo.gpt import prompt_bucket
 
 #: every ``_build_*`` jitted entry point in this module must have an
 #: entry here describing its warmup feed, and :meth:`warmup` must
@@ -111,23 +115,6 @@ SPEC_KS = (2, 4, 8)
 #: gateway's ``max_context``): at 512 rows the weights' matmuls are
 #: bound by the MXU, not by reading the weights once a call
 PREFILL_CHUNK = 512
-
-
-def _rotary_rows(x, theta: float, pos):
-    """RoPE at one position PER ROW: ``x`` [S, H, D], ``pos`` [S] i32.
-    Bit-identical per row to ``rotary_embedding(x[:, None],
-    offset=pos_scalar)[:, 0]`` (same f32 angle math, same half-split
-    pairing) — the continuous batch just carries a different position
-    per slot."""
-    import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # [S, D/2]
-    cos = jnp.cos(ang)[:, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x1 * sin + x2 * cos], axis=-1)
 
 
 class _Slot:
@@ -278,46 +265,52 @@ class DecodeScheduler:
                         if self.prefix_sharing else None)
 
     # -- jitted entry points (lint rule 7: sentry.jit, WARMUP_FEEDS) -----
+    def _first_token(self, params, row, scope, temp, top_p, ctr):
+        """A prefill's TTFT token from the prompt's last row ``[1, F]``:
+        the head, then the pick rule of the decode step under the
+        admission's own key."""
+        import jax
+
+        logits0 = di.logits(params, row, self.model, scope)
+        key = jax.random.fold_in(jax.random.PRNGKey(self.seed), ctr)
+        _, sub = jax.random.split(key)
+        return di.pick(logits0, temp, top_p, sub, sample=self.sample,
+                       top_k=self.top_k,
+                       nucleus=self.top_p is not None)
+
     def _build_step_fn(self):
         """One decode iteration for every slot: token ids [S] -> next
         token ids [S], pool updated in place (each slot writes its
-        position's KV into its own page; inactive slots write the
-        trash page). Fixed shapes throughout — THE serving hot path."""
+        position's KV into its own page, or rewrites its state page;
+        inactive slots write nothing live). Fixed shapes throughout:
+        THE serving hot path."""
         import jax
         import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
-        L = model.n_layers
+        block_scope = "retention_decode" if self.recurrent else ""
 
         # pool is threaded through and returned so the caller rebinds
         # the pager's arrays (donation-friendly on accelerators)
         def step(params, pool, page_table, lengths, active, prev,
                  temps, top_p, ctr):
-            # devtime scopes (obs/devtime.py): trace-time HLO metadata
-            # naming each paged block's share of the serving hot path
-            with obs.devtime.scope("paged_decode.embed"):
-                x = params["layer_0"]["W"][prev][:, None]   # [S, 1, F]
-            block = ("retention_decode" if self.recurrent
-                     else "paged_decode")
-            for i in range(L):
-                with obs.devtime.scope(f"{block}.block_{i}"):
-                    x, pool = self._paged_rows_step(
-                        params[f"layer_{i + 1}"], i, x, pool,
-                        page_table, lengths[:, None], active[:, None])
-            with obs.devtime.scope("paged_decode.lm_head"):
-                x = _rms(x[:, 0], params[f"layer_{L + 1}"]["gamma"])
-                logits = model._head_logits(params, x)
+            cache = self.pager.rows(model, pool, page_table,
+                                    lengths[:, None], active[:, None])
+            x = di.stack(params, prev, model, cache.attend,
+                         "paged_decode", block_scope)
+            logits = di.logits(params, x, model, "paged_decode")
             key = jax.random.fold_in(
                 jax.random.PRNGKey(self.seed), ctr)
-            nxt = model._pick(
+            nxt = di.pick(
                 logits, temps[:, None], top_p, key, sample=self.sample,
                 top_k=self.top_k, nucleus=self.top_p is not None)
             nxt = jnp.where(active, nxt, jnp.zeros_like(nxt))
             # carry lengths forward ON DEVICE: steady-state steps feed
             # back (nxt, lengths+active) without any host->device
             # upload — only admissions/retirements dirty the feed
-            return nxt, pool, lengths + active.astype(lengths.dtype)
+            return nxt, cache.pool, lengths + active.astype(
+                lengths.dtype)
 
         # pool is donated: the caller always rebinds the returned pool
         # (scheduler invariant), so XLA may alias in/out and the step
@@ -325,95 +318,6 @@ class DecodeScheduler:
         # donation-capable backend copies the whole multi-MB pool
         return sentry.jit(step, name="serving.decode_step",
                           donate_argnums=(1,))
-
-    def _paged_rows_step(self, pblk, li, x, pool, pt, pos, act):
-        """One transformer block at R positions per slot, reading and
-        writing the paged pool: THE paged block, which the decode step
-        (R = 1), the speculative verify step and the shared-prefix
-        suffix prefill all run. Mirrors ``_token_logits.block_step``
-        value-for-value (the identity fence's contract); only the
-        cache addressing differs: row r's KV goes to page
-        ``pt[s, pos//block]`` at offset ``pos%block``, and the
-        attention reads the slot's pages through its page-table row.
-        ``x`` is [S, R, F], ``pos`` [S, R] i32, ``act`` bool
-        broadcastable to [S, R] (False rows scatter into the trash
-        page). Every matmul runs on the flattened [S*R, F] view, so
-        each row's arithmetic is the same whatever R is — the
-        spec-decode identity fence leans on that. Out-of-bounds
-        positions (a row past the slot's page table)
-        are clamped EXPLICITLY and routed to trash: JAX gathers clamp
-        silently, and a junk row must never land in a live page."""
-        import jax
-        import jax.numpy as jnp
-
-        model = self.model
-        S, R = x.shape[0], x.shape[1]
-        hd = model.hidden // model.n_heads
-        n_kv = model.n_kv_heads
-        block = self.block
-        h = _rms(x.reshape(S * R, -1), pblk["ln1"]["gamma"])
-        mha = pblk["mha"]
-        if self.recurrent:
-            # the retention mixer: the slot's ONE state page (pt's
-            # only column) is updated in place and read; an inactive
-            # slot's page is neither (R is 1: the multi-row programs
-            # are refused at construction)
-            from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
-            from deeplearning4j_tpu.ops import retention
-            q, k, v, log_g = retention.project(
-                mha, h, model.n_heads, n_kv,
-                lambda z: _rotary_rows(z, model.rope_theta,
-                                       pos.reshape(S)), RMSNORM_EPS)
-            a, pool = retention_decode(
-                q, k, v, jnp.exp(log_g), pool, li, pt[:, 0],
-                jnp.broadcast_to(act, (S, 1))[:, 0])
-            return _block_tail(pblk, x.reshape(S, -1), a.reshape(
-                S, -1)).reshape(S, 1, -1), pool
-        q = (h @ mha["Wq"]).reshape(S * R, model.n_heads, hd)
-        k = (h @ mha["Wk"]).reshape(S * R, n_kv, hd)
-        v = (h @ mha["Wv"]).reshape(S * R, n_kv, hd)
-        pflat = pos.reshape(S * R)
-        q = _rotary_rows(q, model.rope_theta, pflat).reshape(
-            S, R, model.n_heads, hd)
-        k = _rotary_rows(k, model.rope_theta, pflat)
-        kv = jnp.concatenate([k.reshape(S, R, n_kv, hd),
-                              v.reshape(S, R, n_kv, hd)],
-                             axis=3)                    # [S, R, Kv, 2D]
-        cap = pt.shape[1] * block
-        inb = act & (pos < cap)
-        pidx = jnp.minimum(pos // block, pt.shape[1] - 1)
-        pids = jnp.where(inb, jnp.take_along_axis(pt, pidx, axis=1), 0)
-        offs = pos % block
-        if model.cache_quant:
-            codes, scales = pool
-            q8, s_new = _quant_kv(kv.reshape(S, R, n_kv, 2, hd), 4)
-            codes = codes.at[li, pids, offs].set(
-                q8.reshape(S, R, n_kv, 2 * hd))
-            scales = scales.at[li, pids, :, :, offs].set(s_new)
-            pool = (codes, scales)
-        else:
-            (kvpool,) = pool
-            kvpool = kvpool.at[li, pids, offs].set(
-                kv.astype(kvpool.dtype))
-            pool = (kvpool,)
-        # the scatter above runs before the read, so a row attends its
-        # own key and every earlier row's; later rows' keys (and any
-        # stale speculative garbage past the accepted length) sit
-        # strictly beyond pos and stay at exact-zero softmax weight
-        if R == 1:
-            # THE decode step: the kernel reads each slot's pages in
-            # place, up to its length (a routed-to-trash row is an
-            # inactive slot: it walks no page and returns zeros)
-            a = paged_decode_attention(
-                q[:, 0], pool, li, pt,
-                jnp.where(inb[:, 0], pos[:, 0] + 1, 0))
-        else:
-            a = _reference_paged_attention(q, pool, li, pt, pos)
-        a = a.reshape(S * R, -1)
-        x = x + (a @ mha["Wo"] + mha["bo"]).reshape(S, R, -1)
-        h = _rms(x.reshape(S * R, -1), pblk["ln2"]["gamma"])
-        h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
-        return x + (h @ pblk["Wd"]).reshape(S, R, -1), pool
 
     def _build_spec_step_fn(self, k: int):
         """Speculative verify step: score ``prev`` plus the k-1 host
@@ -431,7 +335,6 @@ class DecodeScheduler:
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
-        L = model.n_layers
         S = self.max_slots
 
         def step(params, pool, page_table, lengths, active, prev,
@@ -439,20 +342,13 @@ class DecodeScheduler:
             toks = jnp.concatenate([prev[:, None], drafts], axis=1)
             pos = (lengths[:, None]
                    + jnp.arange(k, dtype=lengths.dtype)[None, :])
-            with obs.devtime.scope("spec_decode.embed"):
-                x = params["layer_0"]["W"][toks.reshape(-1)].reshape(
-                    S, k, -1)
-            for i in range(L):
-                with obs.devtime.scope(f"spec_decode.block_{i}"):
-                    x, pool = self._paged_rows_step(
-                        params[f"layer_{i + 1}"], i, x, pool,
-                        page_table, pos, active[:, None])
-            with obs.devtime.scope("spec_decode.lm_head"):
-                h = _rms(x.reshape(S * k, -1),
-                         params[f"layer_{L + 1}"]["gamma"])
-                logits = model._head_logits(params, h).reshape(
-                    S, k, -1)
-            # per-row greedy pick — same argmax `_pick(sample=False)`
+            cache = self.pager.rows(model, pool, page_table, pos,
+                                    active[:, None])
+            x = di.stack(params, toks.reshape(-1), model, cache.attend,
+                         "spec_decode")
+            logits = di.logits(params, x, model,
+                               "spec_decode").reshape(S, k, -1)
+            # per-row greedy pick — same argmax `pick(sample=False)`
             # runs, just vectorized over the k rows
             m = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             agree = (m[:, :-1] == drafts).astype(jnp.int32)
@@ -464,117 +360,70 @@ class DecodeScheduler:
             # lengths advance by the ACCEPTED count in-program — the
             # steady-state feedback loop needs no host upload beyond
             # the k-1 draft ints per slot
-            return m, e, pool, lengths + e, prev_next
+            return m, e, cache.pool, lengths + e, prev_next
 
         return sentry.jit(step, name=f"serving.spec_step_k{k}",
                           donate_argnums=(1,))
 
     def _build_admit_fn(self, tb: int):
         """Prefill-into-pages for prompt bucket ``tb``: ONE batched
-        causal forward over the padded prompt (the same
-        ``_prefill_forward`` + ``_pick`` the dense path runs — flash
-        dispatch, logits head on one row), its per-layer caches
-        scattered into this sequence's pages, first generated token
-        returned. One executable per power-of-two bucket, exactly the
-        ``generate()`` compile set."""
+        causal forward over the padded prompt (flash dispatch, the
+        head on one row: the forward of the dense ``generate()``
+        prefill), each layer's keys and values kept as this
+        sequence's pages, first generated token returned. One
+        executable per power-of-two bucket, exactly the ``generate()``
+        compile set."""
         import jax
-        import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
-        n_chunks = tb // self.block
-        block = self.block
 
         def admit(params, pool, page_ids, prompt_pad, t0, temp, top_p,
                   ctr):
-            logits0, caches = model._prefill_forward(
-                params, prompt_pad, tb, t0)
-
-            def paged(c, perm):
-                # dense [L, Kv, X, tb] -> [L, n_chunks, Kv, ...]: page
-                # p covers positions p*block..(p+1)*block-1
-                return c.reshape(*c.shape[:3], n_chunks,
-                                 block).transpose(perm)
-
-            if model.cache_quant:
-                codes, scales = pool
-                w8 = jnp.stack([c[0][0] for c in caches])
-                sc = jnp.stack([c[1][0] for c in caches])
-                pool = (codes.at[:, page_ids].set(
-                            paged(w8, (0, 3, 4, 1, 2))),
-                        scales.at[:, page_ids].set(
-                            paged(sc, (0, 3, 1, 2, 4))))
-            else:
-                (kvpool,) = pool
-                kv = jnp.stack([c[0] for c in caches])
-                pool = (kvpool.at[:, page_ids].set(
-                    paged(kv, (0, 3, 4, 1, 2)).astype(kvpool.dtype)),)
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(self.seed), ctr)
-            _, sub = jax.random.split(key)
-            g0 = model._pick(logits0, temp, top_p, sub,
-                             sample=self.sample, top_k=self.top_k,
-                             nucleus=self.top_p is not None)
-            return pool, g0
+            kv = []
+            x = di.stack(params, prompt_pad, model, di.causal_prefill(
+                model, lambda li, k, v: kv.append((k, v))), "prefill")
+            row = jax.lax.dynamic_index_in_dim(x, t0 - 1, axis=1,
+                                               keepdims=False)
+            return (self.pager.write_prompt(pool, page_ids, kv),
+                    self._first_token(params, row, "prefill", temp,
+                                      top_p, ctr))
         return sentry.jit(admit, name="serving.prefill",
                           donate_argnums=(1,))
 
     def _build_chunk_admit_fn(self):
         """A retention model's prefill: ONE program of
         ``prefill_chunk`` rows, run ``ceil(t0 / chunk)`` times for a
-        prompt of ``t0`` tokens. A call reads the sequence's state
-        page (an empty state when ``start`` is 0: a page comes off
-        the free list as its last owner left it), runs its rows by the
-        chunked form, every block's state carried through, and writes
-        the page back: after the last call it holds the state after
-        position ``t0 - 1`` exactly, since rows at and past ``t0`` are
-        masked out of it. What a chunk's queries need of the chunks
-        before it they read from ``history`` (those chunks' keys and
-        values, one layer a row; ``ops.retention.retention_chunk``
-        says why not from the state). The head runs only in the call
-        that holds row ``t0 - 1``; the others return token 0."""
+        prompt of ``t0`` tokens. A call runs its rows by the chunked
+        form against the sequence's state page (``StateChunk``):
+        after the last call the page holds the state after position
+        ``t0 - 1`` exactly, since rows at and past ``t0`` are masked
+        out of it. The head runs only in the call that holds row
+        ``t0 - 1``; the others return token 0."""
         import jax
         import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
-        L = model.n_layers
         chunk = self.prefill_chunk
 
         def admit(params, pool, history, page, toks, start, t0, temp,
                   top_p, ctr):
             valid = (start + jnp.arange(chunk, dtype=jnp.int32)
                      < t0)[None, :]
-            with obs.devtime.scope("chunk_prefill.embed"):
-                x = params["layer_0"]["W"][toks]        # [1, C, F]
-            for i in range(L):
-                with obs.devtime.scope(f"chunk_prefill.block_{i}"):
-                    x, state, hist = model._retention_rows(
-                        params[f"layer_{i + 1}"], x, start, valid,
-                        tuple(jnp.where(start > 0, a[i, page], 0.0)[None]
-                              for a in pool),
-                        history=tuple(a[i, None] for a in history))
-                    pool = tuple(a.at[i, page].set(new[0])
-                                 for a, new in zip(pool, state))
-                    history = tuple(a.at[i].set(new[0])
-                                    for a, new in zip(history, hist))
+            cache = StateChunk(model, pool, history, page, start, valid)
+            x = di.stack(params, toks, model, cache.attend,
+                         "chunk_prefill")               # [1, C, F]
 
             def first_token(x):
-                with obs.devtime.scope("chunk_prefill.lm_head"):
-                    row = jax.lax.dynamic_slice_in_dim(
-                        x[0], t0 - 1 - start, 1, axis=0)
-                    hrow = _rms(row, params[f"layer_{L + 1}"]["gamma"])
-                    logits0 = model._head_logits(params, hrow)
-                key = jax.random.fold_in(
-                    jax.random.PRNGKey(self.seed), ctr)
-                _, sub = jax.random.split(key)
-                return model._pick(logits0, temp, top_p, sub,
-                                   sample=self.sample, top_k=self.top_k,
-                                   nucleus=self.top_p is not None)
+                row = jax.lax.dynamic_slice_in_dim(
+                    x[0], t0 - 1 - start, 1, axis=0)
+                return self._first_token(params, row, "chunk_prefill",
+                                         temp, top_p, ctr)
 
             g0 = jax.lax.cond(t0 <= start + chunk, first_token,
                               lambda x: jnp.zeros((1,), jnp.int32), x)
-            return pool, history, g0
+            return cache.pool, cache.hist, g0
 
         return sentry.jit(admit, name="serving.prefill",
                           donate_argnums=(1, 2))
@@ -588,21 +437,19 @@ class DecodeScheduler:
     def _build_suffix_admit_fn(self, sb: int):
         """Prefill ONLY the novel suffix of a shared-prefix admission:
         the first ``start`` positions already sit in adopted pages, so
-        the forward runs the ``sb``-bucketed suffix rows through
-        :meth:`_paged_rows_step` (S=1) — they attend the shared pages
-        through the slot's page table and write their own KV into the
-        novel (or copy-on-write) pages. Admission cost scales with the
-        SUFFIX, not the prompt (PAPERS.md: arxiv 2603.09555's O(1)
-        shared-prefix caching contract). Logits are read at prompt row
-        ``t0-1-start`` and fed through the same ``_pick`` the dense
-        admit uses, so the first token comes from the identical
-        pick rule."""
+        the forward runs the ``sb``-bucketed suffix rows as the rows
+        of ONE slot (``KVPager.rows``, S=1) — they attend the shared
+        pages through the slot's page table and write their own KV
+        into the novel (or copy-on-write) pages. Admission cost scales
+        with the SUFFIX, not the prompt (PAPERS.md: arxiv 2603.09555's
+        O(1) shared-prefix caching contract). Logits are read at
+        prompt row ``t0-1-start`` and picked by the dense admit's own
+        rule."""
         import jax
         import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
-        L = model.n_layers
 
         def admit(params, pool, page_row, suffix_pad, start, t0, temp,
                   top_p, ctr):
@@ -610,27 +457,14 @@ class DecodeScheduler:
                    + jnp.arange(sb, dtype=jnp.int32))[None, :]
             act = jnp.arange(sb, dtype=jnp.int32)[None, :] < (t0
                                                               - start)
-            pt = page_row[None, :]
-            with obs.devtime.scope("suffix_prefill.embed"):
-                x = params["layer_0"]["W"][
-                    suffix_pad.reshape(-1)].reshape(1, sb, -1)
-            for i in range(L):
-                with obs.devtime.scope(f"suffix_prefill.block_{i}"):
-                    x, pool = self._paged_rows_step(
-                        params[f"layer_{i + 1}"], i, x, pool, pt,
-                        pos, act)
-            with obs.devtime.scope("suffix_prefill.lm_head"):
-                row = jax.lax.dynamic_slice_in_dim(
-                    x[0], t0 - 1 - start, 1, axis=0)
-                hrow = _rms(row, params[f"layer_{L + 1}"]["gamma"])
-                logits0 = model._head_logits(params, hrow)
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(self.seed), ctr)
-            _, sub = jax.random.split(key)
-            g0 = model._pick(logits0, temp, top_p, sub,
-                             sample=self.sample, top_k=self.top_k,
-                             nucleus=self.top_p is not None)
-            return pool, g0
+            cache = self.pager.rows(model, pool, page_row[None, :], pos,
+                                    act)
+            x = di.stack(params, suffix_pad.reshape(-1), model,
+                         cache.attend, "suffix_prefill")
+            row = jax.lax.dynamic_slice_in_dim(
+                x, t0 - 1 - start, 1, axis=0)
+            return cache.pool, self._first_token(
+                params, row, "suffix_prefill", temp, top_p, ctr)
 
         return sentry.jit(admit, name="serving.suffix_prefill",
                           donate_argnums=(1,))
@@ -642,13 +476,10 @@ class DecodeScheduler:
         sibling readers keep the original bytes."""
         from deeplearning4j_tpu.perf import sentry
 
-        def cow_copy(pool, src, dst):
-            return tuple(a.at[:, dst].set(a[:, src]) for a in pool)
-
         # donated: the clone is an in-place one-page write on a
         # donation-capable backend rather than a whole-pool copy —
         # this keeps shared admissions O(suffix), not O(pool)
-        return sentry.jit(cow_copy, name="serving.cow_copy",
+        return sentry.jit(self.pager.copy_page, name="serving.cow_copy",
                           donate_argnums=(0,))
 
     def _suffix_fn(self, sb: int):
@@ -720,7 +551,7 @@ class DecodeScheduler:
         temp = getattr(req, "temperature", None)
         ts1 = obs.now()
         try:
-            params = self.model._decode_params(self.net)
+            params = self.model.decode_params(self.net)
             tail = (jnp.asarray(t0, jnp.int32),
                     (self._temp_one if temp is None
                      else jnp.asarray(temp, jnp.float32)),
@@ -814,7 +645,7 @@ class DecodeScheduler:
             temp = getattr(req, "temperature", None)
             ts1 = obs.now()
             pool, g0 = fn(
-                self.model._decode_params(self.net), self.pager.pool,
+                self.model.decode_params(self.net), self.pager.pool,
                 jnp.asarray(np.asarray(row, np.int32)),
                 jnp.asarray(pad), jnp.asarray(shared_len, jnp.int32),
                 jnp.asarray(t0, jnp.int32),
@@ -920,7 +751,7 @@ class DecodeScheduler:
                        if self.recurrent else 0)
         ts1 = obs.now()
         nxt, pool, len_next = self._step_fn(
-            self.model._decode_params(self.net), self.pager.pool,
+            self.model.decode_params(self.net), self.pager.pool,
             f["pt"], f["lengths"], f["active"], f["prev"], f["temps"],
             f["top_p"], jnp.asarray(self._ctr, jnp.int32))
         self.pager.pool = pool
@@ -976,7 +807,7 @@ class DecodeScheduler:
             drafts_np[i] = self._draft(self._slots[i].history, k - 1)
         ts1 = obs.now()
         m, e, pool, len_next, prev_next = self._spec_fn(
-            self.model._decode_params(self.net), self.pager.pool,
+            self.model.decode_params(self.net), self.pager.pool,
             f["pt"], f["lengths"], f["active"], f["prev"],
             jnp.asarray(drafts_np))
         self.pager.pool = pool
@@ -1148,7 +979,7 @@ class DecodeScheduler:
                                      "_build_cow_fn"}
         if prompt_lens is None:
             prompt_lens = range(1, self.max_context)
-        params = self.model._decode_params(self.net)
+        params = self.model.decode_params(self.net)
         pool_sds = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
                          for a in self.pager.pool)
         i32 = jnp.int32

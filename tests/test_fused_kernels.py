@@ -181,13 +181,14 @@ def test_gate_off_programs_unchanged(rng, monkeypatch):
 
 def test_force_flag_routes_norm_layer_sites(rng, monkeypatch):
     """Each norm dispatch site (RMSNorm layer, LayerNormalization
-    layer, TransformerDecoderBlock residual epilogue, zoo.gpt._rms)
+    layer, TransformerDecoderBlock residual epilogue,
+    nn.decoder_infer.rms)
     takes the kernel path under the force flag and the fallback
     without it — counted at the pallas-call wrappers, with outputs
     agreeing across the two dispatches."""
     from deeplearning4j_tpu.nn.layers.core import (LayerNormalization,
                                                    RMSNorm)
-    from deeplearning4j_tpu.zoo.gpt import _rms as gpt_rms
+    from deeplearning4j_tpu.nn.decoder_infer import rms as gpt_rms
 
     calls = {"n": 0}
     orig_rms, orig_ln = fnorm._rms_fwd_call, fnorm._ln_fwd_call

@@ -470,14 +470,13 @@ def test_lint_rule8_missing_scope_annotation(tmp_path):
 def test_lint_rule8_renamed_annotation_point(tmp_path):
     """A SCOPE_SITES entry whose function vanished is reported — the
     table must follow refactors, not rot."""
-    zoo_dir = tmp_path / "zoo"
-    zoo_dir.mkdir()
-    (zoo_dir / "gpt.py").write_text(
-        "class CausalTransformerLM:\n"
-        "    def _renamed_decode(self):\n"
-        "        pass\n")
+    nn_dir = tmp_path / "nn"
+    nn_dir.mkdir()
+    (nn_dir / "decoder_infer.py").write_text(
+        "def renamed_stack(params, toks):\n"
+        "    pass\n")
     problems = lint_instrumentation.run(tmp_path)
-    assert any("gpt.py" in p and "_token_logits" in p
+    assert any("decoder_infer.py" in p and "'stack'" in p
                and "no longer exists" in p for p in problems)
 
 

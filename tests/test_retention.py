@@ -14,7 +14,8 @@ import pytest
 from deeplearning4j_tpu.ops import retention as R
 from deeplearning4j_tpu.serving import DecodeScheduler
 from deeplearning4j_tpu.serving import scheduler as sched_mod
-from deeplearning4j_tpu.zoo.gpt import CausalTransformerLM, _rms
+from deeplearning4j_tpu.nn import decoder_infer as di
+from deeplearning4j_tpu.zoo.gpt import CausalTransformerLM
 
 ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
@@ -150,11 +151,11 @@ def test_weights_already_in_the_compute_dtype_are_served_as_they_are():
     bf16 copy."""
     model = _model(compute_dtype="bfloat16")
     net = model.init()
-    copy = model._decode_params(net)
+    copy = model.decode_params(net)
     assert jax.tree.leaves(copy)[0] is not jax.tree.leaves(net.params)[0]
     net.params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
                               net.params)
-    served = model._decode_params(net)
+    served = model.decode_params(net)
     assert all(a is b for a, b in zip(jax.tree.leaves(served),
                                       jax.tree.leaves(net.params)))
 
@@ -193,7 +194,6 @@ def _served_logits(model, net, seq, t0, monkeypatch, chunk=16,
     sched = DecodeScheduler(model, net, max_slots=3, block=16,
                             max_context=96)
     assert sched.prefill_chunk == chunk
-    n_layers = model.n_layers
 
     def rounded(pool):
         if state_dtype is None:
@@ -211,15 +211,12 @@ def _served_logits(model, net, seq, t0, monkeypatch, chunk=16,
 
     @jax.jit
     def logits_step(params, pool, pt, lengths, active, prev):
-        x = params["layer_0"]["W"][prev][:, None]
-        for i in range(n_layers):
-            x, pool = sched._paged_rows_step(
-                params[f"layer_{i + 1}"], i, x, pool, pt,
-                lengths[:, None], active[:, None])
-        x = _rms(x[:, 0], params[f"layer_{n_layers + 1}"]["gamma"])
-        return model._head_logits(params, x), pool
+        cache = sched.pager.rows(model, pool, pt, lengths[:, None],
+                                 active[:, None])
+        x = di.stack(params, prev, model, cache.attend, "test")
+        return di.logits(params, x, model, "test"), cache.pool
 
-    params = model._decode_params(net)
+    params = model.decode_params(net)
     active = np.zeros(3, bool)
     active[slot] = True
     rows, first = [], req.tokens[0]

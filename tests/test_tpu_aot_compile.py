@@ -379,9 +379,9 @@ def test_retention_chunk_prefill_compiles_for_v5e_in_place(chip):
 
 
 def test_softmax_decode_step_holds_nothing_of_the_retention_path(chip):
-    """A softmax model's step is lowered from the same
-    ``_paged_rows_step``: it must carry no trace of the retention
-    branch (no kernel, no gate, no state), so that the Mistral cells
+    """A softmax model's step is lowered from the same stack loop
+    (``nn/decoder_infer.py``): it must carry no trace of the retention
+    cache object (no kernel, no gate, no state), so that the Mistral cells
     run the step they ran."""
     from deeplearning4j_tpu.nn import updaters as upd
     from deeplearning4j_tpu.serving import DecodeScheduler

@@ -237,9 +237,7 @@ FAMILY_TOKEN_ALLOWLIST = {
 SCOPE_SITES = {
     "nn/multilayer.py": ("_forward",),
     "nn/graph.py": ("_forward",),
-    "zoo/gpt.py": ("_token_logits", "_prefill_forward"),
-    "serving/scheduler.py": ("_build_step_fn", "_build_spec_step_fn",
-                             "_build_suffix_admit_fn"),
+    "nn/decoder_infer.py": ("stack", "logits"),
     "parallel/zero.py": ("scatter_mean", "gather"),
     "ops/pallas_kernels.py": ("flash_attention", "flash_block_fwd",
                               "flash_block_bwd",

@@ -103,6 +103,7 @@ FAMILIES = {
     "dl4j_tpu_serving_tokens_total": "counter",
     "dl4j_tpu_serving_ttft_seconds": "histogram",
     "dl4j_tpu_serving_step_seconds": "histogram",
+    "dl4j_tpu_serving_steps_ahead_total": "counter",
     "dl4j_tpu_serving_prefill_seconds": "histogram",
     "dl4j_tpu_serving_active_slots": "gauge",
     "dl4j_tpu_serving_queue_depth": "gauge",
@@ -482,8 +483,9 @@ HOSTS_EVICTED = REGISTRY.counter(
 
 # continuous-batching serving gateway (serving/): in-flight batched
 # decode over the paged KV cache — TTFT is the serving SLO metric
-# (queue wait + prefill), step_seconds is the per-token latency every
-# active slot pays per decode iteration, kv_pages_free is the
+# (queue wait + prefill), step_seconds is the wall time a decode step
+# adds (the gap between two tokens of every active slot while the
+# loop keeps a step in flight), kv_pages_free is the
 # admission-control currency
 SERVING_REQS = REGISTRY.counter(
     "dl4j_tpu_serving_requests_total",
@@ -499,8 +501,17 @@ SERVING_TTFT = REGISTRY.histogram(
     "submit -> first streamed token (queue wait + paged prefill)")
 SERVING_STEP = REGISTRY.histogram(
     "dl4j_tpu_serving_step_seconds",
-    "one fixed-shape continuous-batching decode iteration (== the "
-    "per-token latency of every active slot)")
+    "wall time one fixed-shape continuous-batching decode step adds, "
+    "observed once a device step when its tokens are read: from the "
+    "read before it (with a step in flight: the gap between two "
+    "tokens of every active slot), or from its own launch where that "
+    "came later (the first step after a drain: launch, device time "
+    "and read-back)")
+SERVING_AHEAD = REGISTRY.counter(
+    "dl4j_tpu_serving_steps_ahead_total",
+    "decode steps launched before their predecessor's tokens were "
+    "read (over the step-seconds count: the share of steps whose "
+    "launch and read-back the device did not wait for)")
 SERVING_PREFILL = REGISTRY.histogram(
     "dl4j_tpu_serving_prefill_seconds",
     "prompt prefill-into-pages wall time per admission")

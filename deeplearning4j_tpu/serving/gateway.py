@@ -10,8 +10,11 @@ round-robin cursor over per-tenant queues keeps one chatty tenant from
 starving the rest.
 
 The worker thread is the only mutator of scheduler/pager state:
-each iteration retires finished sequences, admits queued prompts into
-free pages, and runs the one fixed-shape decode step. An injected
+each iteration admits queued prompts into free pages, launches the
+one fixed-shape decode step, and reads the step launched the
+iteration before (``DecodeScheduler.step``: one step stays in flight,
+so the device never waits for the read or the launch); before it
+parks it drains that step. An injected
 fault in the step (site ``serving``, the same site the
 ``ParallelInference`` worker drills) sheds every in-flight sequence
 with a structured :class:`SequenceAborted` — pages released, worker
@@ -505,17 +508,14 @@ class ServingGateway:
                 f"{obs.now() - head.t_submit:.3f}s waiting for "
                 "admission"))
 
-    def _admit_queued(self) -> int:
-        """Admit until capacity or the queues run dry. An admission
-        failure (device error mid-prefill) sheds THAT request with a
-        structured error — the scheduler released its pages — and the
-        worker keeps serving; it must never die on a poisoned
-        request."""
+    def _admit_queued(self, head: Optional[TokenStream]) -> int:
+        """Admit ``head`` and on until capacity or the queues run dry.
+        An admission failure (device error mid-prefill) sheds THAT
+        request with a structured error — the scheduler released its
+        pages — and the worker keeps serving; it must never die on a
+        poisoned request."""
         admitted = 0
-        while True:
-            head = self._next_admission()
-            if head is None:
-                return admitted
+        while head is not None:
             # the admit timestamp anchors the request record's
             # queue_wait / prefill phases (made at terminal time)
             head.t_admit = obs.now()
@@ -534,6 +534,8 @@ class ServingGateway:
                     f"{type(e).__name__}: {e}", cause=e))
             else:
                 admitted += 1
+            head = self._next_admission()
+        return admitted
 
     def _drain_cancels(self) -> None:
         with self._lock:
@@ -563,18 +565,31 @@ class ServingGateway:
 
     def _iterate(self, it: int) -> None:
         if self._pause.is_set():
+            # the hold promises that any in-flight step finishes first
+            self._drain_step()
             self._parked.set()
             with self._lock:
                 self._park(it)
             return
         self._drain_cancels()
         if not self._shutdown.is_set():
+            head = self._next_admission()
+            if head is not None:
+                # the scheduler admits against a mirror level with the
+                # device. Read the step in flight here: a fault of it
+                # stays on the step's shed path, and the wait for it,
+                # with the device decoding, stays out of the admit
+                # record (the prefill stall)
+                self._drain_step()
             t0 = obs.now()
             active = self._sched.active_count()
-            admitted = self._admit_queued()
+            admitted = self._admit_queued(head)
             obs.record("serving.loop/admit", t0, obs.now(), it,
                        admitted=admitted, active=active)
         if self._sched.active_count() == 0:
+            # every slot ended with rows still in flight (cancels, an
+            # ``eos_id``): read them off before the worker sleeps
+            self._drain_step()
             with self._lock:
                 if not (self._queued() or self._cancels):
                     # park until a submit arrives (or shutdown)
@@ -588,9 +603,19 @@ class ServingGateway:
             faults.inject("serving")
             self._sched.step()
         except Exception as e:
-            n = self._sched.shed_all(lambda: SequenceAborted(
-                f"in-flight sequences shed by serving fault: "
-                f"{type(e).__name__}: {e}", cause=e))
-            for _ in range(n):
-                obs.metrics.SERVING_SHED.labels(
-                    reason="fault").inc()
+            self._shed_fault(e)
+
+    def _drain_step(self) -> None:
+        """Read the step in flight, if any; the read is where a device
+        error of that step surfaces, so it sheds like the step's."""
+        try:
+            self._sched.drain()
+        except Exception as e:
+            self._shed_fault(e)
+
+    def _shed_fault(self, e: Exception) -> None:
+        n = self._sched.shed_all(lambda: SequenceAborted(
+            f"in-flight sequences shed by serving fault: "
+            f"{type(e).__name__}: {e}", cause=e))
+        for _ in range(n):
+            obs.metrics.SERVING_SHED.labels(reason="fault").inc()

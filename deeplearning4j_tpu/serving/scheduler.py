@@ -54,6 +54,17 @@ itself; the decode step updates every live slot's state in place
 Prefix sharing and speculative decode would need snapshots of a state
 and are refused at construction for such a model.
 
+The loop keeps ONE decode step in flight: :meth:`DecodeScheduler.step`
+launches step n+1 from step n's device-resident outputs and only then
+reads step n's tokens, so neither the read-back nor the next launch
+lies between two steps on the device. The host's mirror therefore runs
+one step behind the device; :meth:`DecodeScheduler.drain` brings it
+level, and everything that needs it level (an admission, the
+gateway's pause, park and fault paths) drains or drops first. The
+speculative step drafts from the tokens it has just read and
+copy-on-write looks at lengths the host must hold, so ``spec_k > 1``
+and ``prefix_sharing`` read each step before the next.
+
 The scheduler is single-threaded host logic (the gateway's worker
 drives it); requests are duck-typed: ``.prompt`` (1-D int32),
 ``.max_new``, ``.temperature``, ``.eos_id``, and ``push(tok)`` /
@@ -130,6 +141,20 @@ class _Slot:
         # prompt + emitted tokens, host-side: the prompt-lookup draft
         # source for speculative decode (no second model needed)
         self.history = history if history is not None else []
+
+
+class _InFlight:
+    """A decode step launched and not yet read: its tokens on the
+    device, the slots that took part with the ``_Slot`` each held at
+    launch (a token goes to that request and to no other), and the
+    launching iteration's first stamp."""
+
+    __slots__ = ("nxt", "slots", "t0")
+
+    def __init__(self, nxt, slots, t0: float):
+        self.nxt = nxt
+        self.slots = slots
+        self.t0 = t0
 
 
 class DecodeScheduler:
@@ -238,10 +263,21 @@ class DecodeScheduler:
         self._temps = np.ones(self.max_slots, np.float32)
         # device-side feed cache: in steady state the step feeds back
         # its own outputs (prev=nxt, lengths carried in-program) and
-        # the static arrays stay resident — zero h2d per token; any
-        # admit/retire/shed marks the feed dirty for a one-shot rebuild
+        # the static arrays stay resident — zero h2d per token; an
+        # admission marks the feed dirty for a one-shot rebuild, any
+        # other change of membership sends a new ``active`` mask alone
         self._dev_feed: Optional[dict] = None
         self._feed_dirty = True
+        self._fed_act: Optional[list] = None    # slots of that mask
+        #: the step launched and not yet read (None: the mirror is
+        #: level with the device)
+        self._inflight: Optional[_InFlight] = None
+        #: whether a step may be launched before its predecessor's
+        #: tokens are read: not where the next launch needs them on
+        #: the host (``_step_spec`` drafts from them, ``_cow_writable``
+        #: reads the mirror's lengths)
+        self._run_ahead = self.spec_k == 1 and not self.prefix_sharing
+        self._t_read = 0.0          # when the last step's read returned
         self._ctr = 0               # rng fold counter (step + admit)
         # admission-path scalar constants, uploaded once: top_p never
         # changes per request and temp defaults to 1.0 — re-wrapping
@@ -514,11 +550,16 @@ class DecodeScheduler:
     def admit(self, req) -> bool:
         """Prefill ``req`` into free pages and occupy a slot; emits the
         first generated token (the TTFT token). Returns False when
-        capacity is lacking — the caller keeps it queued."""
+        capacity is lacking — the caller keeps it queued. The step in
+        flight is drained first: the slot and the pages are chosen,
+        and the feed rebuilt, from a mirror that is level with the
+        device (the prefill's own read would wait that step out
+        anyway, with its tokens undelivered)."""
         import jax.numpy as jnp
 
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         t0, max_new = prompt.shape[0], int(req.max_new)
+        self.drain()
         slot = self.free_slot()
         if slot is None:
             return False
@@ -698,18 +739,24 @@ class DecodeScheduler:
             self._retire(slot)
 
     def _ensure_feed(self, act) -> dict:
-        """Rebuild the device-side feed if an admit/retire/shed
-        dirtied it; otherwise hand back the resident arrays (the
-        zero-h2d steady state)."""
+        """The step's device-side feed for the slots ``act``. ``prev``
+        and ``lengths`` are the last launched step's own outputs (the
+        zero-h2d steady state) until an admission dirties the feed:
+        then all of it is rebuilt from the host's mirror, which is
+        level with the device because :meth:`admit` drained first.
+        The ``active`` mask is the host's alone and goes up whenever
+        the stepping slots changed (a retirement, an eviction, a
+        budget that ends with the step in flight): a masked-out slot
+        writes to the trash page and walks none, so nothing else of
+        its feed is read."""
         import jax.numpy as jnp
 
         if self._feed_dirty or self._dev_feed is None:
-            active = np.zeros(self.max_slots, bool)
-            active[act] = True
+            assert self._inflight is None, \
+                "feed rebuilt from a mirror one step behind the device"
             self._dev_feed = {
                 "pt": jnp.asarray(self._page_table),
                 "lengths": jnp.asarray(self._lengths),
-                "active": jnp.asarray(active),
                 "prev": jnp.asarray(self._prev),
                 "temps": jnp.asarray(self._temps),
                 "top_p": jnp.asarray(
@@ -717,20 +764,41 @@ class DecodeScheduler:
                     jnp.float32),
             }
             self._feed_dirty = False
+            self._fed_act = None
+        if act != self._fed_act:
+            active = np.zeros(self.max_slots, bool)
+            active[act] = True
+            self._dev_feed["active"] = jnp.asarray(active)
+            self._fed_act = act
         return self._dev_feed
 
     def step(self) -> int:
-        """One continuous-batching iteration: step every active slot
-        one token, deliver, retire finished sequences (their pages go
-        back to the free list). Returns tokens produced (0 = idle).
-        With ``spec_k > 1`` the iteration runs the speculative
+        """One continuous-batching iteration, one decode step in
+        flight: launch the next step for every slot that goes on past
+        the step in flight (a budget that ends with it is known now;
+        an ``eos_id`` is not, and that slot's row in the step launched
+        ahead is computed and discarded), THEN read the step in
+        flight, deliver its tokens and retire what finished (their
+        pages go back to the free list). The read returns while the
+        step just launched runs. With nothing in flight the launch
+        alone (the next call reads it); with nothing to launch the
+        read alone (:meth:`drain`). Returns tokens delivered by this
+        call (0 = idle, or a launch into an empty pipeline).
+        ``prefix_sharing`` reads each step before the next launch;
+        with ``spec_k > 1`` the iteration runs the speculative
         draft/verify/accept step instead and can emit up to k tokens
         per slot."""
         import jax.numpy as jnp
 
-        act = [i for i, s in enumerate(self._slots) if s is not None]
+        prior = self._inflight
+        # every occupied slot has a row in the step in flight (a slot
+        # is only occupied by ``admit``, which drains first), so that
+        # step takes one of each budget
+        pending = int(prior is not None)
+        act = [i for i, s in enumerate(self._slots) if s is not None
+               and s.remaining > pending]
         if not act:
-            return 0
+            return self.drain()
         if self.spec_k > 1:
             return self._step_spec(act)
         if self.prefix_sharing:
@@ -742,11 +810,12 @@ class DecodeScheduler:
         self._ctr += 1
         f = self._ensure_feed(act)
         # the pages this step's attention walks (the position being
-        # written included), from the host's mirror: no device read
-        # (a retention model walks no KV page: it moves its live
-        # slots' states, once each way)
+        # written included), from the host's mirror and what the step
+        # in flight adds to it: no device read (a retention model
+        # walks no KV page: it moves its live slots' states, once
+        # each way)
         kv_pages = 0 if self.recurrent else int(
-            np.sum(self._lengths[act] // self.block + 1))
+            np.sum((self._lengths[act] + pending) // self.block + 1))
         state_bytes = (len(act) * self.state_bytes_per_slot
                        if self.recurrent else 0)
         ts1 = obs.now()
@@ -757,33 +826,80 @@ class DecodeScheduler:
         self.pager.pool = pool
         # feed the step's own outputs back: no h2d on the clean path
         f["prev"], f["lengths"] = nxt, len_next
+        self._inflight = _InFlight(
+            nxt, [(i, self._slots[i]) for i in act], ts0)
         ts2 = obs.now()
-        toks = np.asarray(nxt)          # blocking device sync
-        ts3 = obs.now()
+        # the blocking read: the predecessor's tokens, while the step
+        # just launched runs; that step's own where the mode needs
+        # them before its next launch
+        due = prior if self._run_ahead else self._inflight
+        n, ts3 = self._collect(due) if due is not None else (0, ts2)
+        # ``deliver`` (ts3 → here) is the push/retire loop of the step
+        # that was read
+        obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
+                        args={"active": len(act), "kv_pages": kv_pages,
+                              "state_bytes": state_bytes,
+                              "ahead": pending},
+                        cause=self.cause, end=obs.now())
+        obs.metrics.SERVING_KV_WALKED.set(kv_pages)
+        obs.metrics.SERVING_STATE_MOVED.inc(state_bytes)
+        obs.metrics.SERVING_AHEAD.inc(pending)
+        return n
+
+    def _collect(self, fl: _InFlight) -> tuple:
+        """Read one launched step's tokens (the blocking device sync)
+        and deliver them: each to the request that held its slot when
+        the step was launched, and only if it still does (a slot that
+        ended meanwhile, by ``eos_id``, eviction or shed, has its row
+        discarded: never pushed, never counted); retire what
+        finished. Observes ``SERVING_STEP`` once a device step with
+        the wall time this step added: from the read before it, or
+        from its own launch where that came later. Returns ``(tokens
+        delivered, when the read returned)``."""
+        if self._inflight is fl:
+            self._inflight = None
+        toks = np.asarray(fl.nxt)       # blocking device sync
+        t_read = obs.now()
         self.steps += 1
-        for i in act:
-            s = self._slots[i]
+        n = 0
+        for i, s in fl.slots:
+            if self._slots[i] is not s:
+                continue
             tok = int(toks[i])
             self._lengths[i] += 1
             self._prev[i] = tok
             s.length += 1
             s.remaining -= 1
             s.req.push(tok)
+            n += 1
             if s.remaining <= 0 or tok == getattr(s.req, "eos_id",
                                                   None):
                 self._retire(i)
-        # ``deliver`` (ts3 → here) is the push/retire loop above: host
-        # time the device waits out before its next step
-        obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
-                        args={"active": len(act), "kv_pages": kv_pages,
-                              "state_bytes": state_bytes},
-                        cause=self.cause, end=obs.now())
-        obs.metrics.SERVING_KV_WALKED.set(kv_pages)
-        obs.metrics.SERVING_STATE_MOVED.inc(state_bytes)
-        obs.metrics.SERVING_STEP.observe(ts3 - ts0)
-        obs.metrics.SERVING_TOKENS.inc(len(act))
-        self.tokens_out += len(act)
-        return len(act)
+        if not n:
+            # every row discarded: no step or admission was launched
+            # since (each needs a live slot or drains first), so the
+            # next launch draws what it would have drawn had this one
+            # never been made
+            self._ctr -= 1
+        obs.metrics.SERVING_STEP.observe(
+            t_read - max(fl.t0, self._t_read))
+        self._t_read = t_read
+        obs.metrics.SERVING_TOKENS.inc(n)
+        self.tokens_out += n
+        return n, t_read
+
+    def drain(self) -> int:
+        """Read the step in flight, if there is one, outside a launch:
+        before an admission, a pause or a park, or when every live
+        slot's budget ends with it. Afterwards the host's mirror holds
+        every step launched. Returns tokens delivered."""
+        fl = self._inflight
+        if fl is None:
+            return 0
+        t0 = obs.now()
+        n, _ = self._collect(fl)
+        obs.record("serving.drain", t0, obs.now(), self.cause, tokens=n)
+        return n
 
     def _step_spec(self, act) -> int:
         """One speculative iteration: host-draft k-1 tokens per slot
@@ -907,7 +1023,6 @@ class DecodeScheduler:
         s = self._slots[slot]
         self._slots[slot] = None
         self._page_table[slot] = 0
-        self._feed_dirty = True
         self.pager.release(s.req)
         obs.metrics.SERVING_SLOTS.set(self.active_count())
         s.req.finish()
@@ -918,8 +1033,11 @@ class DecodeScheduler:
         wedged slot or a leaked page. ``make_error`` is a ZERO-ARG
         factory called once per stream: a shared exception instance
         would leak the first stream's tokens-so-far into every other
-        client's structured error."""
+        client's structured error. The step in flight is dropped
+        unread (the device may be what failed): no token of it
+        reaches a failed stream."""
         n = 0
+        self._inflight = None
         for i, s in enumerate(self._slots):
             if s is None:
                 continue
@@ -928,18 +1046,17 @@ class DecodeScheduler:
             self.pager.release(s.req)
             s.req.fail(make_error())
             n += 1
-        self._feed_dirty = True
         obs.metrics.SERVING_SLOTS.set(0)
         return n
 
     def evict(self, req) -> bool:
         """Cancel one in-flight sequence (client went away): free its
-        slot and pages without erroring the stream."""
+        slot and pages without erroring the stream. Its row in a step
+        already launched is discarded when that step is read."""
         for i, s in enumerate(self._slots):
             if s is not None and s.req is req:
                 self._slots[i] = None
                 self._page_table[i] = 0
-                self._feed_dirty = True
                 self.pager.release(req)
                 obs.metrics.SERVING_SLOTS.set(self.active_count())
                 req.finish()

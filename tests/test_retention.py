@@ -299,3 +299,124 @@ def test_records_count_state_bytes_and_chunks(retention_lm, monkeypatch):
             == 2 * per_slot)
     assert (obs.metrics.SERVING_STATE_POOL.snapshot()[""]
             == sched.pager.pool_bytes())
+
+
+# -- one decode step in flight over the state pool (ISSUE 29) ------------
+
+class _EosReq(_Req):
+    """Ends by ``eos_id`` at its ``stop_at``-th token, whatever that
+    is: the scheduler compares after the push, and cannot know before
+    it has read the step."""
+
+    def __init__(self, prompt, max_new, stop_at):
+        super().__init__(prompt, max_new)
+        self.stop_at = stop_at
+
+    @property
+    def eos_id(self):
+        return (self.tokens[-1] if len(self.tokens) == self.stop_at
+                else None)
+
+    @eos_id.setter
+    def eos_id(self, _):
+        pass
+
+
+@pytest.mark.parametrize("ends_by", ["eos", "budget"])
+def test_row_launched_ahead_rewrites_its_own_state_page_only(
+        retention_lm, monkeypatch, ends_by):
+    """The step launched before a sequence's last tokens are read: a
+    sequence that ends by ``eos_id`` has a row in it, which rewrites
+    the one state page it still holds; one that ends by budget has
+    none. The trash page with every slot live and the free pages come
+    out bit for bit; the page, released and handed to another
+    sequence, starts that one from an empty state."""
+    from deeplearning4j_tpu import obs
+    model, net = retention_lm
+    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    sched = DecodeScheduler(model, net, max_slots=2, block=16,
+                            max_context=96)
+    mark = obs.now()
+    rng = np.random.default_rng(4)
+    x = (_EosReq(rng.integers(0, 64, 21), 20, stop_at=4)
+         if ends_by == "eos" else _Req(rng.integers(0, 64, 21), 4))
+    nb = _Req(rng.integers(0, 64, 7), 12)
+    assert sched.admit(x) and sched.admit(nb)
+    (x_page,), (nb_page,) = sched.pager.owned(x), sched.pager.owned(nb)
+    for _ in range(3):
+        sched.step()                # the third of them in flight
+    assert len(x.tokens) == 3 and not x.done
+    before = [np.asarray(a) for a in sched.pager.pool]
+    sched.step()                    # launches the fourth, reads the third
+    assert x.done and len(x.tokens) == 4 and len(nb.tokens) == 4
+    assert sched._inflight is not None and not sched.pager.owned(x)
+    rec = [r for r in obs.trace.records(since=mark)
+           if r.name == "serving.decode_step"][-1]
+    live = 2 if ends_by == "eos" else 1
+    assert rec.counts["ahead"] == 1 and rec.counts["active"] == live
+    assert rec.counts["state_bytes"] == live * sched.state_bytes_per_slot
+    after = [np.asarray(a) for a in sched.pager.pool]
+    changed = set()
+    for a, b in zip(before, after):
+        axes = tuple(i for i in range(a.ndim) if i != 1)
+        changed |= {int(p) for p in np.nonzero((a != b).any(axis=axes))[0]}
+    if ends_by == "budget":
+        changed.discard(0)          # a slot masked out may write trash
+    assert changed == ({nb_page, x_page} if ends_by == "eos"
+                       else {nb_page})
+    y = _Req(rng.integers(0, 64, 33), 6)
+    assert sched.admit(y)
+    assert sched.pager.owned(y) == [x_page]
+    while sched.active_count() or sched._inflight is not None:
+        sched.step()
+    for r, n in ((y, 6), (nb, 12), (x, 4)):
+        dense = np.asarray(model.generate(net, r.prompt[None], n))
+        np.testing.assert_array_equal(r.tokens, dense[0, r.prompt.size:])
+    sched.pager.check_invariants()
+    assert sched.pager.free_pages() == sched.pager.n_pages - 1
+
+
+def test_gateway_ends_every_stream_with_a_retention_step_in_flight(
+        retention_lm, monkeypatch):
+    """The gateway's iterations made by hand over the state pool: a
+    cancel, a pause and a fault each meet a step in flight; no stream
+    gets a token after its end, none stays open, every state page
+    comes back."""
+    from deeplearning4j_tpu.resilience import faults
+    from deeplearning4j_tpu.serving import SequenceAborted, ServingGateway
+    model, net = retention_lm
+    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    gw = ServingGateway(model, net, max_slots=3, block=16,
+                        max_context=96, start=False)
+    rng = np.random.default_rng(5)
+    gone, held, shed = (gw.submit(rng.integers(0, 64, t).astype(np.int32),
+                                  max_new=12) for t in (5, 19, 33))
+    gw._iterate(1)
+    gw._iterate(2)
+    assert gw._sched._inflight is not None
+    assert gw.cancel(gone)
+    gw._iterate(3)                  # the cancelled row is discarded
+    assert gone.done() and gone.n_generated() == 2
+    assert held.n_generated() == shed.n_generated() == 3
+    gw._pause.set()
+    gw._iterate(4)                  # the hold reads the step in flight
+    assert gw._parked.is_set() and gw._sched._inflight is None
+    assert held.n_generated() == shed.n_generated() == 4
+    gw.resume()
+    gw._iterate(5)
+    with faults.active("serving:error=RuntimeError:nth=1:max=1"):
+        gw._iterate(6)
+    assert gw._sched._inflight is None
+    for st in (held, shed):
+        assert st.n_generated() == 4
+        with pytest.raises(SequenceAborted):
+            st.result(timeout=1)
+    post = gw.submit(rng.integers(0, 64, 9).astype(np.int32), max_new=5)
+    for it in range(7, 14):
+        gw._iterate(it)
+    dense = np.asarray(model.generate(net, post.prompt[None], 5))
+    np.testing.assert_array_equal(post.result(timeout=1), dense[0])
+    assert (gone.n_generated(), held.n_generated()) == (2, 4)
+    gw._sched.pager.check_invariants()
+    assert gw._sched.pager.free_pages() == gw._sched.pager.n_pages - 1
+    gw.shutdown(timeout=1)

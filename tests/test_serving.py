@@ -69,6 +69,43 @@ class _Req:
         self.done = True
 
 
+class _EosReq(_Req):
+    """A request that ends by ``eos_id`` at its ``stop_at``-th token,
+    whatever that token is: the scheduler compares each token with
+    ``eos_id`` after pushing it, and cannot know before it has read
+    the step (a budget it knows at launch)."""
+
+    def __init__(self, prompt, max_new, stop_at, **kw):
+        super().__init__(prompt, max_new, **kw)
+        self.stop_at = stop_at
+
+    @property
+    def eos_id(self):
+        return (self.tokens[-1] if len(self.tokens) == self.stop_at
+                else None)
+
+    @eos_id.setter
+    def eos_id(self, _):
+        pass
+
+
+def _drive(sched, plan, iters, drained):
+    """A scripted schedule on the scheduler alone: ``plan[it]`` is
+    admitted before iteration ``it``'s step. ``drained`` reads every
+    step before the next is launched (the order the loop had before
+    it kept a step in flight)."""
+    for it in range(iters):
+        for r in plan.get(it, ()):
+            assert sched.admit(r)
+        sched.step()
+        if drained:
+            sched.drain()
+    while sched.active_count() or sched._inflight is not None:
+        sched.step()
+    sched.pager.check_invariants()
+    assert sched.pager.free_pages() == sched.pager.n_pages - 1
+
+
 # =========================================================================
 # pager-correctness fence: paged decode == dense generate(), token for
 # token (float and int8 pages), across staggered admissions
@@ -162,6 +199,7 @@ def test_decode_step_record_counts_the_pages_walked(tiny):
         act = [i for i, sl in enumerate(sched._slots) if sl is not None]
         want = sum(-(-(int(sched._lengths[i]) + 1) // 8) for i in act)
         sched.step()
+        sched.drain()       # the mirror level again before it is read
         rec = [e for e in obs.trace.records(since=mark)
                if e.name == "serving.decode_step"][-1]
         assert rec.counts["kv_pages"] == want, (rec.counts, want)
@@ -1302,3 +1340,346 @@ def test_retention_zero_retraces_after_warmup(retention):
         "retention traffic retraced after warmup"
     gw.shutdown()
     assert gw.stats()["free_pages"] == free     # the leak check's read
+
+
+# =========================================================================
+# one decode step in flight (ISSUE 29): step n+1 is launched before
+# step n's tokens are read. The tokens, the pool outside every live
+# reservation, and every stream's end are what they were when each
+# step was read before the next launch.
+# =========================================================================
+
+def _ahead_plan(temperature):
+    """Admissions into a running batch; a budget (A) and an ``eos_id``
+    (C) that end in the same step; a budget (B) whose last write fills
+    its page, so the position after it opens a page it never reserved;
+    slots re-used the iteration after they were freed (D, E, then G);
+    the last live sequence ending by ``eos_id`` with a row in flight
+    that is then wholly discarded; an admission (F) after that."""
+    rng = np.random.default_rng(11)
+
+    def req(t0, max_new, stop_at=None):
+        prompt = rng.integers(0, 64, t0).astype(np.int32)
+        if stop_at is None:
+            return _Req(prompt, max_new, temperature=temperature)
+        return _EosReq(prompt, max_new, stop_at,
+                       temperature=temperature)
+
+    a, c = req(5, 5), req(9, 20, stop_at=5)     # both end in step 3
+    b = req(11, 6)                  # writes 11..15; 16 opens a page
+    d, e = req(3, 7), req(17, 4)    # into A's and C's slots
+    g = req(6, 20, stop_at=6)       # into E's slot; outlives D
+    f = req(6, 5)
+    plan = {0: [a, c], 2: [b], 4: [d, e], 7: [g], 15: [f]}
+    return plan, [a, b, c, d, e, f, g]
+
+
+@pytest.mark.parametrize("sample", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("mixer", ["softmax", "power_retention"])
+def test_step_in_flight_serves_the_tokens_of_the_drained_order(
+        tiny, retention, monkeypatch, mixer, sample):
+    """Request by request, the scheduler with a step in flight serves
+    what it serves when every step is read before the next launch
+    (same launches in the same order, so the same draws under
+    sampling), and under greedy decoding what dense ``generate()``
+    returns."""
+    from deeplearning4j_tpu.serving import scheduler as sched_mod
+    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    model, net = tiny if mixer == "softmax" else retention
+    kw = dict(max_slots=3, block=8 if mixer == "softmax" else 16,
+              max_context=64)
+    if sample:
+        kw.update(sample=True, top_k=16, seed=5)
+    served = {}
+    for drained in (True, False):
+        sched = DecodeScheduler(model, net, **kw)
+        plan, reqs = _ahead_plan(0.9 if sample else None)
+        _drive(sched, plan, 16, drained)
+        assert all(r.done and r.error is None for r in reqs)
+        served[drained] = [r.tokens for r in reqs]
+    assert served[False] == served[True]
+    for r, toks in zip(reqs, served[False]):
+        want = getattr(r, "stop_at", r.max_new)
+        assert len(toks) == want
+        if not sample:
+            dense = np.asarray(model.generate(
+                net, r.prompt[None], n_new=want))[0]
+            np.testing.assert_array_equal(toks, dense[r.prompt.size:])
+    assert sched.tokens_out == sum(map(len, served[False]))
+
+
+@pytest.mark.parametrize("ends_by", ["eos", "budget"])
+def test_row_launched_ahead_writes_inside_its_reservation(tiny, ends_by):
+    """The step launched ahead of a sequence's last read: a sequence
+    that ends by ``eos_id`` has a row in it, which writes one position
+    inside the pages it still holds and nothing else; one that ends
+    by budget, its last write filling a page, has none. The trash
+    page with every slot live, the neighbour's other positions and
+    every free page come out bit for bit as they went in; the freed
+    pages then serve another sequence."""
+    from deeplearning4j_tpu import obs
+    model, net = tiny
+    sched = DecodeScheduler(model, net, max_slots=2, block=8,
+                            max_context=64)
+    mark = obs.now()
+    rng = np.random.default_rng(2)
+    # four tokens either way: one at admission, three by steps; the
+    # budget's three writes (13, 14, 15) fill its second page
+    x = (_EosReq(rng.integers(0, 64, 11), 20, stop_at=4)
+         if ends_by == "eos" else _Req(rng.integers(0, 64, 13), 4))
+    nb = _Req(rng.integers(0, 64, 5), 20)
+    assert sched.admit(x) and sched.admit(nb)
+    x_pages, nb_pages = sched.pager.owned(x), sched.pager.owned(nb)
+    for _ in range(3):
+        sched.step()                # the third of them in flight
+    assert len(x.tokens) == 3 and not x.done
+    (before,) = (np.asarray(a) for a in sched.pager.pool)
+    sched.step()                    # launches the fourth, reads the third
+    assert x.done and len(x.tokens) == 4 and len(nb.tokens) == 4
+    assert sched._inflight is not None and not sched.pager.owned(x)
+    # a budget is known at launch, an ``eos_id`` only after the read
+    rec = [r for r in obs.trace.records(since=mark)
+           if r.name == "serving.decode_step"][-1]
+    assert rec.counts["active"] == (2 if ends_by == "eos" else 1)
+    (after,) = (np.asarray(a) for a in sched.pager.pool)
+    changed = {int(p) for p in np.nonzero(
+        (before != after).any(axis=(0, 2, 3, 4)))[0]}
+    # the neighbour wrote position 5 + 3, the first of its second page
+    wrote = {nb_pages[1]: 0}
+    if ends_by == "eos":
+        wrote[x_pages[1]] = 6       # position 11 + 3 of pages 8..15
+    else:
+        changed.discard(0)          # a slot masked out writes trash
+    assert changed == set(wrote)
+    for page, off in wrote.items():
+        rest = np.arange(8) != off
+        np.testing.assert_array_equal(before[:, page][:, rest],
+                                      after[:, page][:, rest])
+    # the freed pages go to the next admission, whose prefill follows
+    # the stray write on the device
+    y = _Req(rng.integers(0, 64, 13), 6)
+    assert sched.admit(y)
+    assert set(sched.pager.owned(y)) & set(x_pages)
+    while sched.active_count() or sched._inflight is not None:
+        sched.step()
+    for r, n in ((y, 6), (nb, 20), (x, 4)):
+        dense = np.asarray(model.generate(net, r.prompt[None], n_new=n))
+        np.testing.assert_array_equal(r.tokens, dense[0, r.prompt.size:])
+    sched.pager.check_invariants()
+    assert sched.pager.free_pages() == sched.pager.n_pages - 1
+
+
+def test_ahead_count_and_counter_follow_the_pipeline(tiny):
+    """``ahead`` is 0 on the step that enters an empty pipeline (the
+    first, and the first after a drain or an admission) and 1 on every
+    other; the counter's growth over the step histogram's is that
+    share; ``kv_pages`` counts the position each step writes although
+    the mirror is a step behind."""
+    from deeplearning4j_tpu import obs
+    model, net = tiny
+    sched = DecodeScheduler(model, net, max_slots=3, block=8,
+                            max_context=64)
+    mark = obs.now()
+    ahead0 = obs.metrics.SERVING_AHEAD.snapshot()[""]
+    steps0 = obs.metrics.SERVING_STEP.snapshot()[""]["count"]
+    assert sched.admit(_Req(np.arange(7), 30))
+    assert sched.admit(_Req(np.arange(14), 30))
+    for _ in range(5):
+        sched.step()
+    sched.drain()
+    for _ in range(3):
+        sched.step()
+    assert sched.admit(_Req(np.arange(3), 30))      # drains by itself
+    for _ in range(2):
+        sched.step()
+    sched.drain()
+    recs = [r for r in obs.trace.records(since=mark)
+            if r.name == "serving.decode_step"]
+    assert [r.counts["ahead"] for r in recs] \
+        == [0, 1, 1, 1, 1, 0, 1, 1, 0, 1]
+    assert [r.counts["active"] for r in recs] == [2] * 8 + [3] * 2
+    want = [(7 + j) // 8 + 1 + (14 + j) // 8 + 1 for j in range(8)]
+    want += [(7 + j) // 8 + (14 + j) // 8 + (3 + j - 8) // 8 + 3
+             for j in (8, 9)]
+    assert [r.counts["kv_pages"] for r in recs] == want
+    drains = [r for r in obs.trace.records(since=mark)
+              if r.name == "serving.drain"]
+    assert [r.counts["tokens"] for r in drains] == [2, 2, 3]
+    assert obs.metrics.SERVING_AHEAD.snapshot()[""] - ahead0 == 7
+    assert (obs.metrics.SERVING_STEP.snapshot()[""]["count"] - steps0
+            == sched.steps == 10)
+    assert sched.tokens_out == 3 + 8 * 2 + 2 * 3
+
+
+@pytest.mark.parametrize("kw", [dict(spec_k=2),
+                                dict(prefix_sharing=True)],
+                         ids=["spec_k2", "prefix_sharing"])
+def test_modes_that_read_each_step_keep_no_step_in_flight(tiny, kw):
+    """``spec_k > 1`` drafts from the tokens it has just read and
+    copy-on-write reads the mirror's lengths: both read every step
+    before the next launch, as before, and serve ``generate()``'s
+    tokens."""
+    from deeplearning4j_tpu import obs
+    model, net = tiny
+    sched = DecodeScheduler(model, net, max_slots=2, block=8,
+                            max_context=64, **kw)
+    mark = obs.now()
+    rng = np.random.default_rng(6)
+    reqs = [_Req(rng.integers(0, 64, t), n) for t, n in ((9, 8), (4, 5))]
+    for r in reqs:
+        assert sched.admit(r)
+    n = 0
+    while sched.active_count():
+        n += 1
+        assert sched.step() >= 1 and sched._inflight is None
+        assert sum(len(r.tokens) for r in reqs) >= n + 2
+    for r in reqs:
+        dense = np.asarray(model.generate(net, r.prompt[None],
+                                          n_new=r.max_new))[0]
+        np.testing.assert_array_equal(r.tokens, dense[r.prompt.size:])
+    steps = [r for r in obs.trace.records(since=mark)
+             if r.name in ("serving.decode_step", "serving.spec_step")]
+    assert len(steps) == n
+    assert not any(r.counts.get("ahead") for r in steps)
+
+
+def _handdriven_gateway(model, net, n_reqs=2, max_new=12):
+    """A gateway without its worker thread, its iterations made by the
+    test: after two of them one step has been read and one is in
+    flight."""
+    gw = ServingGateway(model, net, max_slots=3, block=8,
+                        max_context=64, start=False)
+    rng = np.random.default_rng(8)
+    streams = [gw.submit(rng.integers(0, 64, 5 + 3 * i).astype(np.int32),
+                         max_new=max_new) for i in range(n_reqs)]
+    gw._iterate(1)
+    gw._iterate(2)
+    assert gw._sched._inflight is not None
+    assert [s.n_generated() for s in streams] == [2] * n_reqs
+    return gw, streams
+
+
+def _pool_whole(gw):
+    gw._sched.pager.check_invariants()
+    return gw._sched.pager.free_pages() == gw._sched.pager.n_pages - 1
+
+
+def test_cancel_with_a_step_in_flight_discards_its_row(tiny):
+    model, net = tiny
+    gw, (gone, stays) = _handdriven_gateway(model, net)
+    assert gw.cancel(gone)
+    gw._iterate(3)      # evicts, launches for one slot, reads for two
+    assert gone.done() and gone.error() is None
+    assert gone.n_generated() == 2 and stays.n_generated() == 3
+    it = 3
+    while not stays.done():
+        it += 1
+        gw._iterate(it)
+    gw._iterate(it + 1)             # nothing live: drains, then parks
+    assert gone.n_generated() == 2 and gw._sched._inflight is None
+    dense = np.asarray(model.generate(net, stays.prompt[None], n_new=12))
+    np.testing.assert_array_equal(stays.result(timeout=1), dense[0])
+    assert _pool_whole(gw)
+    # the last sequence cancelled with its row in flight: the worker
+    # reads that step off before it parks
+    last = gw.submit(np.arange(6, dtype=np.int32), max_new=12)
+    for it in range(20, 23):
+        gw._iterate(it)
+    assert gw.cancel(last)
+    gw._iterate(23)
+    assert last.done() and last.n_generated() == 3
+    assert gw._sched._inflight is None and _pool_whole(gw)
+    gw.shutdown(timeout=1)
+
+
+@pytest.mark.parametrize("where", ["inject", "read"])
+def test_fault_with_a_step_in_flight_sheds_every_stream_once(tiny, where):
+    """A fault raised at the ``serving`` site, or by the read of the
+    step in flight (a device error surfaces one iteration after its
+    launch, with its successor already launched): every stream fails
+    with the tokens it had, the steps in flight deliver nothing more,
+    no page leaks and the gateway serves on."""
+    from deeplearning4j_tpu.resilience import faults
+    model, net = tiny
+    gw, streams = _handdriven_gateway(model, net, n_reqs=3)
+    if where == "inject":
+        with faults.active("serving:error=RuntimeError:nth=1:max=1"):
+            gw._iterate(3)
+    else:
+        class Lost:
+            def __array__(self, *a, **kw):
+                raise RuntimeError("device lost")
+        gw._sched._inflight.nxt = Lost()
+        gw._iterate(3)
+    assert gw._sched._inflight is None and _pool_whole(gw)
+    for st in streams:
+        assert st.done() and st.n_generated() == 2
+        with pytest.raises(SequenceAborted) as err:
+            st.result(timeout=1)
+        assert err.value.tokens == st._tokens
+    post = gw.submit(np.arange(7, dtype=np.int32), max_new=5)
+    for it in range(4, 12):
+        gw._iterate(it)
+    assert [st.n_generated() for st in streams] == [2] * 3
+    dense = np.asarray(model.generate(net, post.prompt[None], n_new=5))
+    np.testing.assert_array_equal(post.result(timeout=1), dense[0])
+    assert _pool_whole(gw)
+    gw.shutdown(timeout=1)
+
+
+def test_pause_drains_the_step_in_flight_and_resume_runs_on(tiny):
+    model, net = tiny
+    gw, streams = _handdriven_gateway(model, net)
+    gw._pause.set()
+    gw._iterate(3)
+    assert gw._parked.is_set() and gw._sched._inflight is None
+    assert [s.n_generated() for s in streams] == [3, 3]
+    gw._iterate(4)                  # held: nothing is launched
+    assert [s.n_generated() for s in streams] == [3, 3]
+    assert gw._sched._inflight is None
+    gw.resume()
+    it = 4
+    while not all(s.done() for s in streams):
+        it += 1
+        gw._iterate(it)
+    for st in streams:
+        dense = np.asarray(model.generate(net, st.prompt[None], n_new=12))
+        np.testing.assert_array_equal(st.result(timeout=1), dense[0])
+    assert _pool_whole(gw)
+    gw.shutdown(timeout=1)
+
+
+@pytest.mark.parametrize("drain", [True, False])
+def test_shutdown_with_a_step_in_flight_ends_every_stream(tiny, drain):
+    """The worker's own loop: ``shutdown(drain=True)`` serves every
+    live sequence to its end (``generate()``'s tokens),
+    ``drain=False`` sheds them; either way no stream gets a token
+    after its end, none is left open, no page leaks."""
+    model, net = tiny
+    gw = ServingGateway(model, net, max_slots=3, block=8,
+                        max_context=64)
+    gw.warmup(prompt_lens=(5, 8, 11))
+    rng = np.random.default_rng(9)
+    streams = [gw.submit(rng.integers(0, 64, 5 + 3 * i).astype(np.int32),
+                         max_new=40) for i in range(3)]
+    for _ in range(2000):
+        if all(s.n_generated() >= 3 for s in streams):
+            break
+        time.sleep(0.002)
+    gw.shutdown(drain=drain, timeout=60)
+    assert all(s.done() for s in streams)
+    ended = [s.n_generated() for s in streams]
+    assert gw._sched._inflight is None and _pool_whole(gw)
+    for st in streams:
+        dense = np.asarray(model.generate(net, st.prompt[None],
+                                          n_new=40))[0]
+        if drain:
+            np.testing.assert_array_equal(st.result(timeout=1), dense)
+        elif st.error() is not None:
+            assert isinstance(st.error(), ServingShutdownError)
+        got = np.asarray(st._tokens)
+        np.testing.assert_array_equal(
+            got, dense[st.prompt.size:st.prompt.size + got.size])
+    time.sleep(0.05)
+    assert [s.n_generated() for s in streams] == ended

@@ -366,8 +366,15 @@ double the sequences a pool holds.
 token: queue wait + prefill) is the admission-health histogram —
 a fattening p99 with free pages means slot pressure; with
 `dl4j_tpu_serving_kv_pages_free` at 0 it means pool pressure.
-`dl4j_tpu_serving_step_seconds` IS the per-token latency every
-in-flight sequence pays per iteration. Shed posture mirrors
+`dl4j_tpu_serving_step_seconds` is the wall time a decode step adds,
+observed when its tokens are read: while the loop keeps a step in
+flight that is the gap between two tokens of every in-flight
+sequence; for the first step after a drain it is launch, device time
+and read-back. `dl4j_tpu_serving_steps_ahead_total` over its count is
+the share of steps launched before their predecessor's tokens were
+read: it falls with admissions (each drains the step in flight), and
+reads 0 under `spec_k > 1` and `prefix_sharing`, which read every
+step before the next launch. Shed posture mirrors
 ParallelInference:
 `dl4j_tpu_serving_requests_shed_total{reason=queue_full|deadline|shutdown|fault}`
 — alert on its rate vs `dl4j_tpu_serving_requests_total`.
@@ -407,6 +414,21 @@ count in the args) with nested `serving.request/queue_wait`,
 to see exactly where one tenant's p99 went. With or without the
 flag each request leaves one record in the always-on ring
 (ARCHITECTURE.md §9); with it off no event is built or written.
+
+**Reading a decode-step record.** The worker keeps one decode step in
+flight, so a `serving.decode_step` record (one an iteration that
+launches a device step) stamps what the THREAD did, not one step's
+life: `/dispatch` is the launch of this record's step, `/sync` the
+blocking read of the step launched the iteration before (it returns
+while this one runs, so in steady state it is about one device step
+long and the device never waits for it), `/deliver` that earlier
+step's tokens pushed. `active`, `kv_pages` and `state_bytes` are those
+of the step launched; `ahead` is 1 when it was launched before its
+predecessor's tokens were read, 0 for the step that enters an empty
+pipeline (the first, and the first after an admission, a pause or a
+park: each drains the step in flight and leaves a `serving.drain`
+record). A device program therefore ends after the `sync` of the
+record that launched it: lay the next record's `sync` over it.
 
 **KV-page occupancy.** `dl4j_tpu_serving_kv_page_occupancy` (fraction
 of usable pages reserved — 1.0 means admission control is the

@@ -97,22 +97,38 @@ def record_etl(entry: str, t0: float, t1: float, cause=None) -> None:
 
 def record_worker_step(worker: str, t0: float, t1: float, t2: float,
                        t3: float, nbytes: int,
-                       staged_ahead: bool) -> None:
-    """ParallelWrapper worker loop: per-worker latency histogram,
-    collective-sync wall time, liveness heartbeat, one ring record.
+                       staged_ahead: bool, ahead: bool) -> None:
+    """ParallelWrapper worker loop, one iteration that launched a
+    step: per-worker latency histogram, collective-sync wall time,
+    liveness heartbeat, one ring record. ``t2→t3`` is the thread's
+    blocking read in that iteration: of the step launched the
+    iteration before, or of this one where the loop reads each step.
     ``nbytes`` are the host bytes whose copies this iteration's
     ``h2d`` enqueued (its own batch's, or those of the batch it staged
     ahead for the next step, or both); ``staged_ahead`` says the step
-    ran on a batch enqueued during the step before it."""
+    ran on a batch enqueued during the step before it, ``ahead`` that
+    it was launched before its predecessor's loss was read."""
     metrics.WORKER_STEP.labels(worker=worker).observe(t3 - t0)
     metrics.WORKER_SYNC.labels(worker=worker).inc(t3 - t2)
     metrics.WORKER_STAGED_AHEAD.labels(worker=worker).inc(
         int(staged_ahead))
+    metrics.WORKER_AHEAD.labels(worker=worker).inc(int(ahead))
     health.heartbeat(worker)
     trace.record_phases(
         "ParallelWrapper.fit", (t0, t1, t2, t3), _WORKER_PHASES, None,
         {"worker": worker, "bytes": nbytes,
-         "staged_ahead": int(staged_ahead)})
+         "staged_ahead": int(staged_ahead), "ahead": int(ahead)})
+
+
+def record_worker_drain(worker: str, t0: float, t1: float) -> None:
+    """ParallelWrapper worker loop: the blocking read of the step in
+    flight made OUTSIDE a launching iteration (an epoch's last step,
+    a step read before one that must run alone). No record of its own:
+    the step has its ``ParallelWrapper.fit`` record from its launch;
+    the wait joins the collective-sync wall time and beats the
+    heartbeat, as a launching iteration's read does."""
+    metrics.WORKER_SYNC.labels(worker=worker).inc(t1 - t0)
+    health.heartbeat(worker)
 
 
 def summary() -> Dict[str, Any]:

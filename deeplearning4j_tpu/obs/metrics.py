@@ -64,6 +64,7 @@ FAMILIES = {
     "dl4j_tpu_worker_step_latency_seconds": "histogram",
     "dl4j_tpu_worker_collective_sync_seconds_total": "counter",
     "dl4j_tpu_worker_staged_ahead_total": "counter",
+    "dl4j_tpu_worker_steps_ahead_total": "counter",
     "dl4j_tpu_inference_requests_total": "counter",
     "dl4j_tpu_inference_request_latency_seconds": "histogram",
     "dl4j_tpu_inference_queue_depth": "gauge",
@@ -439,6 +440,12 @@ WORKER_STAGED_AHEAD = REGISTRY.counter(
     "ParallelWrapper steps dispatched on a batch staged onto the mesh "
     "during the step before (over the step-latency count: the share "
     "of steps whose host-to-device copy had compute to hide behind)",
+    ("worker",))
+WORKER_AHEAD = REGISTRY.counter(
+    "dl4j_tpu_worker_steps_ahead_total",
+    "ParallelWrapper steps launched before their predecessor's loss "
+    "was read (over the step-latency count: the share of steps whose "
+    "launch and read-back the chips did not wait for)",
     ("worker",))
 INFER_REQS = REGISTRY.counter(
     "dl4j_tpu_inference_requests_total",

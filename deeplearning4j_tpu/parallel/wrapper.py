@@ -68,6 +68,7 @@ from deeplearning4j_tpu.parallel.compression import \
 from deeplearning4j_tpu.parallel.mesh import data_parallel_mesh
 from deeplearning4j_tpu.perf import sentry
 from deeplearning4j_tpu.resilience import faults
+from deeplearning4j_tpu.resilience.policy import Preempted
 
 
 @contextlib.contextmanager
@@ -166,6 +167,9 @@ class ParallelWrapper:
         self._step = None
         self._step_builder = None
         self._dp_state = None  # mode-specific device state
+        # fit: the loss of the step on the chips that no one has read
+        # yet, one deep; None when fit returns or raises
+        self._flight = None
         self._shard_layout = None
         # MultiLayerNetwork takes (x, y); ComputationGraph takes
         # ({name: x}, [y]) — adapt here so every mode's step body can
@@ -1212,20 +1216,115 @@ class ParallelWrapper:
             return fn()
         return self.elastic.run(fn)
 
+    def _book(self, diag=None):
+        """A step's bookkeeping once its loss is in ``net.score_``:
+        the iteration count, the numerics monitor, the listeners."""
+        net = self.net
+        net.iteration += 1
+        nm = getattr(net, "_numerics", None)
+        if diag is not None:
+            # publishes per-layer gauges incl. the replica-
+            # divergence family; raises NonFiniteError with
+            # cross-replica attribution when the sentinel fired
+            nm.process(net, diag, net._layer_names(),
+                       entry="ParallelWrapper")
+        elif nm is not None:
+            nm.note_score(net.score_)
+        for l in net.listeners:
+            l.iteration_done(net, net.iteration, net.epoch)
+
+    def _state_read_at(self, iteration) -> bool:
+        """Whether a listener says its ``iteration_done`` at
+        ``iteration`` reads the net's state (``reads_state``: a
+        checkpoint, an evaluation, the trainer's progress file)."""
+        for l in self.net.listeners:
+            reads = getattr(l, "reads_state", None)
+            if reads is not None and reads(iteration):
+                return True
+        return False
+
+    def _drain(self):
+        """Read and book the step in flight, if there is one, outside
+        a launch: at an epoch's end, before a diagnostic step, before
+        the step after one whose listeners read the net's state, and
+        when the host's side raises with a step on the chips."""
+        loss, self._flight = self._flight, None
+        if loss is None:
+            return
+        t0 = obs.now()
+        self.net.score_ = float(loss)
+        obs.record_worker_drain(f"proc{jax.process_index()}", t0,
+                                obs.now())
+        self._book()
+
     def fit(self, iterator, epochs: int = 1):
         """Reference: ParallelWrapper.fit(DataSetIterator).
 
-        The loop keeps one batch in flight: each batch goes from host
-        memory straight onto the chips that will read it, laid out as
-        the step declares its batch arguments (``_stage``), and once a
-        step is dispatched the NEXT batch is pulled and its copy
-        enqueued before the step's loss is fetched, so the copy
-        crosses while the chips compute. Only the first batch of a
-        call is staged with the chips idle. Every step's loss is
-        still fetched before that step's bookkeeping and listeners
-        run; a step that raises drops the batch staged ahead (the
-        iterator is then at most that one batch further). In every
-        mode; ``prefetch_buffer`` still counts HOST batches.
+        The loop keeps one BATCH and one STEP in flight. Each batch
+        goes from host memory straight onto the chips that will read
+        it, laid out as the step declares its batch arguments
+        (``_stage``); with step n on the chips and batch n+1 staged
+        beside it, the loop launches step n+1 on step n's device
+        outputs, pulls batch n+2 and enqueues its copy, and only THEN
+        reads step n's loss, advances ``net.iteration`` and calls step
+        n's listeners. The chips go from one step into the next; the
+        launch, the read-back, the bookkeeping and the listeners run
+        under a step. Only a call's (and an epoch's) first step finds
+        the pipeline empty, and ``fit`` returns when the last step's
+        loss has been read and its listeners called: ``net.params``,
+        ``net.opt_state``, ``net.score_`` and ``net.iteration`` are
+        final at return. In every mode; ``prefetch_buffer`` still
+        counts HOST batches.
+
+        What a listener may assume: every step's loss is read, in
+        order, before THAT step's bookkeeping and listeners, so the
+        sequence of ``(iteration, epoch, net.score_)`` it sees is the
+        blocking loop's. A listener that SAVES or EVALUATES the net
+        says so (``TrainingListener.reads_state(iteration)``, as
+        ``CheckpointListener``, ``EvaluativeListener`` and
+        ``FaultTolerantTrainer``'s progress tracker do at their
+        cadence): at such an iteration it finds ``net.params``,
+        ``net.opt_state`` and ``net.state`` as that very step left
+        them, so a periodic checkpoint resumes batch for batch. What a
+        listener that does not say so may not assume: ``net.params``
+        read inside ``iteration_done`` may be one step NEWER than
+        ``iteration`` — the step launched ahead has taken them over,
+        as ``fit(steps_per_loop=k)``'s listeners find the whole group
+        applied. A non-finite loss escalates the numerics monitor one
+        step later than it did.
+
+        When something raises: a step's ``rng`` and iteration come from
+        the number the step WILL have, so an interrupted run folds what
+        the blocking loop folds. An error of the host's side (the
+        iterator's or the trim's, held as before until the step is
+        booked; the ``worker_step`` site; a listener; a ``Preempted``)
+        finds a sound step on the chips whose update ``net.params``
+        already holds: that step is read, booked and shown to the
+        listeners, THEN the error is raised, so params, iteration and
+        the listeners' view agree on every exit (a preemption's
+        checkpoint resumes bit for bit). So a LISTENER's error at step
+        n books step n+1 too and calls the listeners once more, the
+        one that raised among them; what that drain raises in its turn
+        (the read, a listener again) is noted on the first error and
+        not raised in its place. A step whose READ raises (a device
+        fault) takes the step launched on its outputs with it: dropped
+        unread, no record, no bookkeeping, no listener,
+        ``net.iteration`` stands; so does an interrupt
+        (``KeyboardInterrupt``, ``SystemExit``), which waits for no
+        step and runs no listener. Either way the batch staged ahead
+        is dropped; the iterator is then at most one batch further
+        than the last step launched.
+
+        The loop reads a step before it launches the next, the order
+        it had, where it can see that the step's result is needed
+        first: under an elastic context (``self.elastic``: the
+        ``pre_step``/``post_step`` stamps, the watchdog's sync, the
+        barrier a dead peer must break), for a diagnostic step that is
+        due (the numerics monitor's cadence: the step in flight is
+        drained, the diagnostic step runs alone) and after a step at
+        which a listener ``reads_state`` (that step is read and its
+        listeners called before the next is launched). Told from
+        those three alone; there is no switch.
 
         Multi-host (jax.process_count() > 1): every jitted step is a
         collective spanning all hosts, so the processes must agree on
@@ -1237,7 +1336,23 @@ class ParallelWrapper:
         """
         try:
             return self._fit_epochs(iterator, epochs)
+        except (Exception, Preempted) as e:
+            # the host's side raised with a sound step on the chips
+            # whose update net.params already holds: book it, then
+            # raise. The error that stopped the loop is the one the
+            # caller's retry policy must see, so what the drain raises
+            # (the read after a host error, a listener once more: the
+            # trainer's tracker repeats its Preempted) rides on it
+            try:
+                self._drain()
+            except (Exception, Preempted) as drained:
+                e.add_note("draining the step in flight raised "
+                           f"{drained!r}")
+            raise
         finally:
+            # an interrupt (KeyboardInterrupt, SystemExit) waits for no
+            # step and runs no listener: the flight is dropped unread
+            self._flight = None
             # gather-overlap: net.params must not be left stale on ANY
             # exit — including NonFiniteError/preemption unwinds (the
             # carried shards are the live truth a post-mortem reads).
@@ -1309,13 +1424,15 @@ class ParallelWrapper:
             src = iter(it)
             # (batch, its staged arrays): the iterator's next batch,
             # enqueued onto the chips while the step before it ran;
-            # one deep, and dropped with the frame when a step raises
+            # one deep, and dropped with the frame when a step raises.
+            # The STEP in flight is self._flight: fit outlives the
+            # frame to read it when the host's side raises
             ahead = None
             while True:
                 if ahead is None:
                     # nothing was staged ahead (the call's first
                     # batch, or the lockstep budget was spent): this
-                    # iteration stages its own, with the chips idle
+                    # iteration stages its own
                     ahead = (self._pull(src), None)
                 (ds, staged), ahead = ahead, None
                 if ds is None:
@@ -1323,6 +1440,22 @@ class ParallelWrapper:
                 faults.inject("worker_step")  # site: worker loop body
                 if n_steps is not None and step_i >= n_steps:
                     break               # stay in lockstep across hosts
+                # the iteration number this step WILL have: the step in
+                # flight takes net.iteration when its loss is read
+                step_it = net.iteration + (self._flight is not None)
+                nm = getattr(net, "_numerics", None)
+                diag_due = nm is not None and nm.due(step_it)
+                run_diag = diag_due and self.mode == self.SYNC
+                if run_diag or (self._flight is not None and
+                                self._state_read_at(net.iteration + 1)):
+                    # a diagnostic step's numbers are processed against
+                    # the state it left: nothing unread behind it,
+                    # nothing launched ahead of it. So is what a
+                    # listener saves or evaluates: where one says the
+                    # step in flight is such a step (reads_state), its
+                    # listeners run before the next step takes
+                    # net.params over
+                    self._drain()
                 if self.elastic is not None:
                     # mesh-epoch stamp + lease renewal + the
                     # host_death drill site (resilience/elastic.py) —
@@ -1341,15 +1474,13 @@ class ParallelWrapper:
                     nbytes = 0      # enqueued by the iteration before
                 step_i += 1
                 rng = jax.random.fold_in(
-                    jax.random.PRNGKey(net.conf.seed), net.iteration)
+                    jax.random.PRNGKey(net.conf.seed), step_it)
                 t1 = obs.now()
                 # from the launch until the next batch is enqueued the
                 # host must not stop for a full collection (_gc_held)
                 with _gc_held():
                     diag = None
-                    nm = getattr(net, "_numerics", None)
-                    diag_due = nm is not None and nm.due(net.iteration)
-                    if diag_due and self.mode != self.SYNC and \
+                    if diag_due and not run_diag and \
                             not self._diag_unsupported_warned:
                         self._diag_unsupported_warned = True
                         import logging
@@ -1357,7 +1488,7 @@ class ParallelWrapper:
                             "numerics observatory: diagnostic steps are "
                             "implemented for SYNC mode only; %r trains "
                             "without in-step diagnostics", self.mode)
-                    if diag_due and self.mode == self.SYNC:
+                    if run_diag:
                         self._ensure_diag_step(nm)
                         if self.sharded_update and self.gather_overlap:
                             (self._pshard, self._dp_state, net.state, loss,
@@ -1415,13 +1546,24 @@ class ParallelWrapper:
                         p, o, net.state, loss = self._guarded(
                             lambda: self._step(
                                 p, o, net.state, x, y, rng,
-                                jnp.asarray(net.iteration, jnp.int32)))
+                                jnp.asarray(step_it, jnp.int32)))
                         self._dp_state = (p, o)
+                    # the step runs on its predecessor's device
+                    # outputs, whose loss is read below, under it. An
+                    # elastic context (stamps, the watchdog's sync, the
+                    # barrier a dead peer must break) and a diagnostic
+                    # step need each result before the next step may
+                    # start: they read this step's own
+                    launched_ahead = self._flight is not None
+                    if self.elastic is not None or run_diag:
+                        reads = loss
+                    else:
+                        reads, self._flight = self._flight, loss
                     ahead_s, ahead_error = 0.0, None
                     if n_steps is None or step_i < n_steps:
                         # the step is on the chips and nothing waits for
                         # it yet: pull the next batch and enqueue its copy
-                        # now, so that it crosses while this step computes
+                        # now, so that it crosses while the chips compute
                         # and the next step finds it where it reads it
                         try:
                             nxt = self._pull(src)
@@ -1431,51 +1573,57 @@ class ParallelWrapper:
                             ahead_s = obs.now() - ta
                         except Exception as e:
                             # the iterator's (or the trim's) error belongs
-                            # after this step's loss, bookkeeping and
-                            # listeners, where the loop met it before
+                            # after the loss, bookkeeping and listeners of
+                            # every step launched (fit drains the last)
                             ahead_error = e
                         else:
                             ahead = (nxt, staged)
                             if staged is not None:
                                 nbytes += staged[2]
-                t2 = obs.now()
-                # the float() blocks on the step AND its averaging /
-                # all-reduce collective — this wait is the visible
-                # collective-sync wall time; under an elastic context
-                # it runs on the watchdog so a dead peer raises
-                # within the lease window instead of hanging forever
-                net.score_ = float(loss) if self.elastic is None \
-                    else self.elastic.sync(loss)
-                # stamp the step end BEFORE the fleet hook: the
-                # cadence-gated snapshot publish fsyncs to the shared
-                # dir, and that I/O must not masquerade as
-                # collective-sync wall time in the very metrics the
-                # straggler hunt reads
-                t3 = obs.now()
+                t3 = t2 = obs.now()
+                if reads is not None:
+                    # the float() blocks on the step it reads AND its
+                    # averaging / all-reduce collective — this wait is
+                    # the visible collective-sync wall time: of the
+                    # step launched the iteration before, while the
+                    # one just launched waits behind it on the chips.
+                    # Under an elastic context it is this step's own
+                    # and runs on the watchdog, so a dead peer raises
+                    # within the lease window instead of hanging forever
+                    try:
+                        net.score_ = float(reads) if self.elastic is None \
+                            else self.elastic.sync(reads)
+                    except BaseException:
+                        # the step launched on a failed step's outputs
+                        # goes with it, unread: no record, no
+                        # bookkeeping, no listener
+                        self._flight = None
+                        raise
+                    # stamp the step end BEFORE the fleet hook: the
+                    # cadence-gated snapshot publish fsyncs to the
+                    # shared dir, and that I/O must not masquerade as
+                    # collective-sync wall time in the very metrics
+                    # the straggler hunt reads
+                    t3 = obs.now()
                 if self.elastic is not None:
                     # fleet plane: barrier-exit stamp + flight-recorder
                     # ring + cadence-gated telemetry publish (a no-op
                     # branch when no FleetTelemetry is installed)
                     self.elastic.post_step(net.iteration, net.score_)
-                # h2d: the enqueueing done inside this iteration,
+                # the record of the step LAUNCHED, each phase what
+                # the thread did in this iteration. h2d: the enqueueing,
                 # whichever batch it was for (ahead_s of it after the
-                # dispatch, drawn in front of it); collective_sync
-                # starts where the blocking fetch did
+                # dispatch, drawn in front of it); dispatch: this
+                # step's launch; collective_sync: the blocking read
+                # above, zero long where the pipeline was empty
                 obs.record_worker_step(worker, t0, t1 + ahead_s, t2, t3,
-                                       nbytes, staged_ahead)
-                net.iteration += 1
-                if diag is not None:
-                    # publishes per-layer gauges incl. the replica-
-                    # divergence family; raises NonFiniteError with
-                    # cross-replica attribution when the sentinel fired
-                    nm.process(net, diag, net._layer_names(),
-                               entry="ParallelWrapper")
-                elif nm is not None:
-                    nm.note_score(net.score_)
-                for l in net.listeners:
-                    l.iteration_done(net, net.iteration, net.epoch)
+                                       nbytes, staged_ahead, launched_ahead)
+                if reads is not None:
+                    self._book(diag)
                 if ahead_error is not None:
                     raise ahead_error
+            # the epoch's last step: its listeners see the epoch it ran in
+            self._drain()
             net.epoch += 1
         # normal completion: retire the liveness beat so a lingering
         # process doesn't read as a stale worker forever (a crashed

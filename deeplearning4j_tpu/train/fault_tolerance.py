@@ -142,6 +142,12 @@ class _ProgressTracker:
     def reset_epoch_tracking(self):
         self._cur_epoch = None
 
+    def reads_state(self, iteration):
+        """The progress file describes the checkpoint cut at the same
+        iteration: a loop that runs ahead lets no step pass it."""
+        t = self.trainer
+        return bool(t.every_iter) and iteration % t.every_iter == 0
+
     def iteration_done(self, net, iteration, epoch):
         t = self.trainer
         if self._cur_epoch != epoch:

@@ -20,6 +20,17 @@ class TrainingListener:
     def iteration_done(self, net, iteration: int, epoch: int):
         pass
 
+    def reads_state(self, iteration: int) -> bool:
+        """Whether ``iteration_done(net, iteration, ...)`` will read
+        ``net.params``, ``net.opt_state`` or ``net.state`` as step
+        ``iteration`` left them (to save or to evaluate them). A loop
+        that launches the next step before it calls a step's listeners
+        (``ParallelWrapper.fit``) asks every listener that has this
+        method, and where one says yes lets no step run ahead of that
+        one. A listener that reads the state without saying so may
+        find it one step newer than ``iteration``."""
+        return False
+
     def on_epoch_start(self, net):
         pass
 
@@ -129,8 +140,11 @@ class CheckpointListener(TrainingListener):
                 manifest_path
             manifest_path(old).unlink(missing_ok=True)
 
+    def reads_state(self, iteration):
+        return bool(self.every_iter) and iteration % self.every_iter == 0
+
     def iteration_done(self, net, iteration, epoch):
-        if self.every_iter and iteration % self.every_iter == 0:
+        if self.reads_state(iteration):
             self._save(net, f"iter_{iteration}")
 
     def on_epoch_end(self, net):
@@ -164,8 +178,12 @@ class EvaluativeListener(TrainingListener):
         self.last_evaluation = e
         self.callback(e)
 
+    def reads_state(self, iteration):
+        return bool(self.frequency_iters) and \
+            iteration % self.frequency_iters == 0
+
     def iteration_done(self, net, iteration, epoch):
-        if self.frequency_iters and iteration % self.frequency_iters == 0:
+        if self.reads_state(iteration):
             self._eval(net)
 
     def on_epoch_end(self, net):

@@ -189,7 +189,8 @@ def test_small_ring_reports_dropped_and_refuses_the_window():
     ("E", lambda t: obs.record_etl("E", t, t + 1), ["E/etl"]),
     ("ParallelWrapper.fit",
      lambda t: obs.record_worker_step("w0", t, t + 1, t + 2, t + 3,
-                                      nbytes=64, staged_ahead=True),
+                                      nbytes=64, staged_ahead=True,
+                                      ahead=True),
      ["ParallelWrapper.fit/step", "ParallelWrapper.fit/h2d",
       "ParallelWrapper.fit/dispatch",
       "ParallelWrapper.fit/collective_sync"]),
@@ -522,11 +523,14 @@ def test_worker_step_recording_and_heartbeat(tmp_path):
     trace.enable(str(tmp_path / "w.jsonl"))
     before = metrics.WORKER_STEP.labels(worker="procX").count
     ahead = metrics.WORKER_STAGED_AHEAD.labels(worker="procX").value
+    launched = metrics.WORKER_AHEAD.labels(worker="procX").value
     t0 = obs.now()
-    for staged_ahead in (False, True, True):
+    for staged_ahead, step_ahead in ((False, False), (True, False),
+                                     (True, True)):
         obs.record_worker_step("procX", t0, t0 + 0.001, t0 + 0.002,
                                t0 + 0.010, nbytes=4096,
-                               staged_ahead=staged_ahead)
+                               staged_ahead=staged_ahead,
+                               ahead=step_ahead)
     trace.disable()
     assert metrics.WORKER_STEP.labels(worker="procX").count \
         == before + 3
@@ -535,17 +539,44 @@ def test_worker_step_recording_and_heartbeat(tmp_path):
     # during the step before (over WORKER_STEP's count)
     assert metrics.WORKER_STAGED_AHEAD.labels(worker="procX").value \
         == ahead + 2
+    # ... and steps launched before their predecessor's loss was read
+    assert metrics.WORKER_AHEAD.labels(worker="procX").value \
+        == launched + 1
     recs = [r for r in trace.records()
             if r.name == "ParallelWrapper.fit"][-3:]
     assert [r.phases for r in recs] == [
         ("h2d", "dispatch", "collective_sync")] * 3
     assert [r.counts for r in recs] == [
-        {"worker": "procX", "bytes": 4096, "staged_ahead": s}
-        for s in (0, 1, 1)]
+        {"worker": "procX", "bytes": 4096, "staged_ahead": s, "ahead": a}
+        for s, a in ((0, 0), (1, 0), (1, 1))]
     assert not health.check(stale_after=30)["procX"]["stale"]
     names = {e["name"] for e in trace.events()}
     assert "ParallelWrapper.fit/step" in names
     assert "ParallelWrapper.fit/collective_sync" in names
+    health.reset()
+
+
+def test_worker_pipeline_counters_are_exported():
+    """Both engagement counters of the wrapper's loop reach /metrics
+    as declared families, a sample a worker, and the runbook names
+    them; the heartbeat reads what it read."""
+    import os
+    health.reset()
+    t0 = obs.now()
+    obs.record_worker_step("procY", t0, t0 + 0.001, t0 + 0.002,
+                           t0 + 0.010, nbytes=8, staged_ahead=True,
+                           ahead=True)
+    text = metrics.REGISTRY.exposition()
+    samples = metrics.parse_exposition(text)
+    for name in ("dl4j_tpu_worker_staged_ahead_total",
+                 "dl4j_tpu_worker_steps_ahead_total"):
+        assert metrics.FAMILIES[name] == "counter"
+        assert f"# TYPE {name} counter" in text
+        assert samples[(name, (("worker", "procY"),))] >= 1
+    ops = open(os.path.join(os.path.dirname(__file__), os.pardir,
+                            "docs", "OPS.md")).read()
+    assert "dl4j_tpu_worker_steps_ahead_total" in ops
+    assert set(health.check(stale_after=30)) == {"procY"}
     health.reset()
 
 

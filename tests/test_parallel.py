@@ -476,9 +476,11 @@ class _FailsAt:
 @pytest.mark.parametrize("site", ["worker_step", "listener"])
 def test_fit_failure_drops_the_batch_staged_ahead(site, overlap):
     """A failure in step 3 raises as before, has advanced the iterator
-    by at most one batch beyond it, and leaves a wrapper whose next
-    ``fit`` trains from what step 2 (``worker_step``: the fault fires
-    before the dispatch) or step 3 (a listener) left."""
+    by at most one batch beyond the last step launched, and leaves a
+    wrapper whose next ``fit`` trains from what step 2 (``worker_step``:
+    the fault fires before step 3's dispatch, step 2 is in flight and
+    booked first) or step 4 (a listener of step 3 runs with step 4 on
+    the chips: it is read and booked, then the error raised) left."""
     from deeplearning4j_tpu.resilience import faults
     batches = _plan_batches((64,) * 6)
     kw = {"sharded_update": True, "gather_overlap": overlap,
@@ -492,13 +494,13 @@ def test_fit_failure_drops_the_batch_staged_ahead(site, overlap):
             with pytest.raises(faults.InjectedFault):
                 w.fit(feed)
     else:
-        done = 3
+        done = 4
         net.listeners.append(_FailsAt(3))
         with pytest.raises(FloatingPointError, match="step 3"):
             w.fit(feed)
         net.listeners.clear()
     assert net.iteration == done
-    assert done <= feed.pulled <= 4     # at most one beyond step 3
+    assert done <= feed.pulled <= done + 1      # at most one beyond
     assert not w._params_stale          # fit's finally materialised
 
     ref = _net()
@@ -533,6 +535,423 @@ def test_fit_defers_the_iterators_error_until_the_step_is_booked():
     assert net.iteration == 2
     assert [i for i, _ in log.calls] == [1, 2]
     assert log.calls[-1][1] == net.score_
+
+
+# --- fit launches step n+1 before it reads step n's loss --------------------
+
+def _dropout_net(seed=42):
+    """A net whose step depends on its ``rng`` and carries layer state
+    (batch norm), so a wrong fold or a wrong order shows in the bits."""
+    from deeplearning4j_tpu.nn.layers import BatchNormalization
+    conf = (NeuralNetConfiguration.builder()
+            .seed(seed)
+            .updater(upd.Nesterovs(learning_rate=0.05, momentum=0.9))
+            .list()
+            .layer(DenseLayer(n_out=16, activation="tanh", dropout=0.25))
+            .layer(BatchNormalization())
+            .layer(OutputLayer(n_out=2, activation="softmax",
+                               loss="mcxent"))
+            .set_input_type(InputType.feed_forward(4))
+            .build())
+    return MultiLayerNetwork(conf).init()
+
+
+def _logged_wrapper(net=None, **kw):
+    """A wrapper over a net with a step log, and the rng every launch
+    was handed (every builder's signature ends ``x, y, rng[, it]``)."""
+    net = _dropout_net() if net is None else net
+    net.listeners.append(_StepLog())
+    kw.setdefault("prefetch_buffer", 0)
+    w = ParallelWrapper(net, workers=8, **kw)
+    w._ensure_ready()
+    rngs, real = [], w._step
+
+    def step(*args):
+        rngs.append(np.asarray(
+            args[-2 if w.mode == ParallelWrapper.AVERAGING else -1]))
+        return real(*args)
+
+    w._step = step
+    return w, net, rngs
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+_AHEAD_MODES = {k: _STAGING_MODES[k]
+                for k in ("sync", "sync-sharded", "encoded", "averaging")}
+
+
+@pytest.mark.parametrize("mode", sorted(_AHEAD_MODES))
+def test_run_ahead_order_equals_the_blocking_order(mode):
+    """One call of n batches (every step but the first launched before
+    its predecessor's loss was read) against n calls of one batch (a
+    call's first step finds the pipeline empty: the blocking order by
+    construction, same step program): bit-equal losses, the same
+    listener sequence, bit-equal final state, and every step's rng the
+    one its iteration number folds."""
+    batches = _plan_batches((64,) * 6)
+    w, net, rngs = _logged_wrapper(**_AHEAD_MODES[mode])
+    seen = len(_fit_records())
+    w.fit(batches)
+    recs = _fit_records()[seen:]
+    w1, net1, rngs1 = _logged_wrapper(**_AHEAD_MODES[mode])
+    for b in batches:
+        w1.fit([b])
+
+    assert [r.counts["ahead"] for r in recs] == [0, 1, 1, 1, 1, 1]
+    log, log1 = net.listeners[0].calls, net1.listeners[0].calls
+    assert [i for i, _ in log] == [1, 2, 3, 4, 5, 6]
+    assert log == log1                  # iteration and score, in order
+    assert len(set(s for _, s in log)) == 6     # and they do differ
+    assert net.iteration == net1.iteration == 6
+    assert net.score_ == net1.score_ == log[-1][1]
+    _assert_trees_equal(net.params, net1.params)
+    _assert_trees_equal(net.opt_state, net1.opt_state)
+    _assert_trees_equal(net.state, net1.state)
+    _assert_trees_equal(w._dp_state, w1._dp_state)
+    # step i (0-based) folds i, not net.iteration at its launch (i - 1)
+    want = [np.asarray(jax.random.fold_in(
+        jax.random.PRNGKey(net.conf.seed), i)) for i in range(6)]
+    for got in (rngs, rngs1):
+        assert len(got) == 6
+        for g, r in zip(got, want):
+            np.testing.assert_array_equal(g, r)
+
+
+def test_ahead_count_and_counter_follow_the_pipeline():
+    """``ahead`` is 0 on a call's and an epoch's first step and 1 on
+    the rest, the counter grows by their sum, the last step of each
+    epoch is read outside a launch with its listeners seeing its own
+    epoch, and nothing is in flight when ``fit`` returns."""
+    from deeplearning4j_tpu import obs
+    from deeplearning4j_tpu.obs import trace
+
+    class Epochs(_StepLog):
+        def iteration_done(self, net, iteration, epoch):
+            self.calls.append((iteration, epoch, net.epoch))
+
+    net = _dropout_net()
+    net.listeners.append(Epochs())
+    w = ParallelWrapper(net, workers=8, prefetch_buffer=0)
+    worker = f"proc{jax.process_index()}"
+    count = lambda: obs.metrics.WORKER_AHEAD.labels(worker=worker).value
+    steps = lambda: obs.metrics.WORKER_STEP.labels(worker=worker).count
+    c0, s0, seen = count(), steps(), len(_fit_records())
+    sync = lambda: obs.metrics.WORKER_SYNC.labels(worker=worker).get()
+    y0 = sync()
+    w.fit(_plan_batches((64,) * 4), epochs=2)
+    recs = _fit_records()[seen:]
+    assert [r.counts["ahead"] for r in recs] == [0, 1, 1, 1, 0, 1, 1, 1]
+    assert [r.counts["staged_ahead"] for r in recs] == \
+        [0, 1, 1, 1, 0, 1, 1, 1]
+    assert count() - c0 == 6 and steps() - s0 == 8
+    # an epoch's last step is read outside a launch, in no record's
+    # collective_sync: its wait joins the sync counter all the same
+    in_records = sum(r.stamps[3] - r.stamps[2] for r in recs)
+    assert sync() - y0 > in_records
+    # a record's collective_sync is the read made in ITS iteration:
+    # zero long where the pipeline was empty
+    for r in recs:
+        t2, t3 = r.stamps[2], r.stamps[3]
+        assert (t3 == t2) == (r.counts["ahead"] == 0)
+    assert w._flight is None
+    assert net.iteration == 8 and net.epoch == 2
+    assert net.listeners[0].calls == \
+        [(i, 0, 0) for i in (1, 2, 3, 4)] + [(i, 1, 1) for i in (5, 6, 7, 8)]
+
+    w.fit(_plan_batches((64,)))          # a call of one step
+    assert [r.counts["ahead"] for r in _fit_records()[seen:]][8:] == [0]
+    assert w._flight is None and net.iteration == 9
+
+
+class _LossThatFails:
+    """A step's loss whose read raises, as a device fault surfaces."""
+
+    def __float__(self):
+        raise RuntimeError("the device lost step 3")
+
+
+@pytest.mark.parametrize("site", ["worker_step", "read", "listener",
+                                  "iterator"])
+def test_fault_with_a_step_in_flight(site):
+    """An error of the host's side finds a sound step on the chips: it
+    is read, booked and shown to the listeners, then the error raised,
+    in the order the blocking loop had. A step whose READ raises takes
+    the step launched on its outputs with it: no record, no listener
+    call, no iteration."""
+    from deeplearning4j_tpu.resilience import faults
+    batches = _plan_batches((64,) * 6)
+    w, net, rngs = _logged_wrapper()
+    seen = len(_fit_records())
+    if site == "worker_step":
+        # fires at the top of iteration 3, step 2 in flight
+        booked, launched, error = 2, 2, faults.InjectedFault
+        with faults.active("worker_step:error=InjectedFault:nth=3:max=1"):
+            with pytest.raises(error):
+                w.fit(batches)
+    elif site == "read":
+        # step 3's read raises in iteration 4, step 4 launched
+        booked, launched, error = 2, 4, RuntimeError
+        spied = w._step
+
+        def step(*args):
+            out = spied(*args)
+            return out[:-1] + (_LossThatFails(),) \
+                if len(rngs) == 3 else out
+
+        w._step = step
+        with pytest.raises(error, match="lost step 3"):
+            w.fit(batches)
+    elif site == "listener":
+        # step 3's listener raises with step 4 on the chips
+        booked, launched, error = 4, 4, FloatingPointError
+        net.listeners.append(_FailsAt(3))
+        with pytest.raises(error, match="step 3"):
+            w.fit(batches)
+    else:
+        # the pull for batch 4 raises under step 3, step 2 unread
+        booked, launched, error = 3, 3, OSError
+
+        def feed():
+            yield from batches[:3]
+            raise OSError("the reader lost its file")
+
+        with pytest.raises(error, match="lost its file"):
+            w.fit(feed())
+    recs = _fit_records()[seen:]
+    assert len(rngs) == launched
+    assert w._flight is None
+    assert net.iteration == booked
+    assert [i for i, _ in net.listeners[0].calls] == \
+        list(range(1, booked + 1))
+    # a record a step that was read or drained; the step dropped unread
+    # (and, in its iteration, unrecorded) leaves none
+    assert len(recs) == (3 if site == "read" else booked)
+    if site != "read":
+        # what the host's error left is what `booked` blocking steps
+        # leave, and training goes on from there
+        ref, netr, _ = _logged_wrapper()
+        for b in batches[:booked]:
+            ref.fit([b])
+        assert net.listeners[0].calls == netr.listeners[0].calls
+        _assert_trees_equal(net.params, netr.params)
+        _assert_trees_equal(net.opt_state, netr.opt_state)
+        net.listeners[:] = net.listeners[:1]
+        w.fit(batches[booked:booked + 2])
+        ref.fit(batches[booked:booked + 2])
+        assert net.listeners[0].calls == netr.listeners[0].calls
+        _assert_trees_equal(net.params, netr.params)
+
+
+@pytest.mark.parametrize("site", ["read", "listener", "interrupt"])
+def test_drain_that_fails_keeps_the_first_error(site):
+    """The error that stopped the loop is the one the caller sees
+    (a retry policy classifies it), whatever the drain of the step in
+    flight raises in its turn: that rides on it as a note. An
+    interrupt drains nothing: no wait, no listener, the flight is
+    dropped unread."""
+    from deeplearning4j_tpu.resilience import faults
+    batches = _plan_batches((64,) * 6)
+    w, net, rngs = _logged_wrapper()
+    if site == "read":
+        # the worker_step site fires at the top of iteration 3 with
+        # step 2 in flight, and step 2's read then raises
+        spied = w._step
+
+        def step(*args):
+            out = spied(*args)
+            return out[:-1] + (_LossThatFails(),) \
+                if len(rngs) == 2 else out
+
+        w._step = step
+        with faults.active("worker_step:error=ConnectionError:nth=3:max=1"):
+            with pytest.raises(ConnectionError) as err:
+                w.fit(batches)
+        assert any("lost step 3" in n for n in err.value.__notes__)
+        booked, calls = 1, [1]
+    elif site == "listener":
+        # step 3's listener raises with step 4 on the chips; the drain
+        # books step 4 and calls the listeners once more: one raises
+        class FailsFrom(_FailsAt):
+            def iteration_done(self, net, iteration, epoch):
+                if iteration >= self.iteration:
+                    raise FloatingPointError(f"step {iteration} went wrong")
+
+        net.listeners.append(FailsFrom(3))
+        with pytest.raises(FloatingPointError, match="step 3") as err:
+            w.fit(batches)
+        assert any("step 4" in n for n in err.value.__notes__)
+        booked, calls = 4, [1, 2, 3, 4]
+    else:
+        class Interrupts:
+            def iteration_done(self, net, iteration, epoch):
+                if iteration == 3:
+                    raise KeyboardInterrupt
+
+        net.listeners.append(Interrupts())
+        with pytest.raises(KeyboardInterrupt):
+            w.fit(batches)
+        assert len(rngs) == 4           # step 4 was on the chips
+        booked, calls = 3, [1, 2, 3]
+    assert w._flight is None
+    assert net.iteration == booked
+    assert [i for i, _ in net.listeners[0].calls] == calls
+
+
+class _SavesEvery:
+    """A listener that reads the net's state at its cadence and says
+    so, as ``CheckpointListener`` does."""
+
+    def __init__(self, every, says_so=True):
+        self.every, self.saved = every, {}
+        if says_so:
+            self.reads_state = lambda it: it % self.every == 0
+
+    def iteration_done(self, net, iteration, epoch):
+        if iteration % self.every == 0:
+            self.saved[iteration] = jax.tree.map(
+                np.asarray, (net.params, net.opt_state, net.state))
+
+
+@pytest.mark.parametrize("mode", ["sync", "sync-sharded"])
+def test_listener_that_reads_state_finds_its_own_step(mode):
+    """Where a listener says that it reads the net's state at an
+    iteration, no step runs ahead of that one: what it saves at
+    iteration k is what k steps left, the step after it starts from
+    an empty pipeline, and the training is the blocking order's."""
+    from deeplearning4j_tpu.train.listeners import (CheckpointListener,
+                                                    EvaluativeListener,
+                                                    TrainingListener)
+    assert not TrainingListener().reads_state(3)
+    ck = CheckpointListener("/tmp/unused-by-this-test",
+                            save_every_n_iterations=3)
+    ev = EvaluativeListener(None, frequency_iters=2)
+    assert [ck.reads_state(i) for i in (2, 3, 6)] == [False, True, True]
+    assert [ev.reads_state(i) for i in (2, 3, 6)] == [True, False, True]
+    assert not EvaluativeListener(None).reads_state(2)
+
+    batches = _plan_batches((64,) * 7)
+    w, net, rngs = _logged_wrapper(**_AHEAD_MODES[mode])
+    saves = _SavesEvery(3)
+    net.listeners.append(saves)
+    seen = len(_fit_records())
+    w.fit(batches)
+    assert [r.counts["ahead"] for r in _fit_records()[seen:]] == \
+        [0, 1, 1, 0, 1, 1, 0]
+    ref, netr, _ = _logged_wrapper(**_AHEAD_MODES[mode])
+    quiet = _SavesEvery(3, says_so=False)
+    netr.listeners.append(quiet)
+    for b in batches:
+        ref.fit([b])
+    assert sorted(saves.saved) == sorted(quiet.saved) == [3, 6]
+    for k in (3, 6):
+        _assert_trees_equal(saves.saved[k], quiet.saved[k])
+    assert net.listeners[0].calls == netr.listeners[0].calls
+    _assert_trees_equal(net.params, netr.params)
+    _assert_trees_equal(net.opt_state, netr.opt_state)
+
+
+class _Elastic:
+    """The calls an elastic context sees, in order."""
+
+    def __init__(self):
+        self.calls = []
+
+    def pre_step(self, iteration):
+        self.calls.append(("pre", iteration))
+
+    def run(self, fn):
+        self.calls.append(("run",))
+        return fn()
+
+    def sync(self, value):
+        self.calls.append(("sync",))
+        return float(value)
+
+    def post_step(self, iteration, loss):
+        self.calls.append(("post", iteration, loss))
+
+
+@pytest.mark.parametrize("reader", ["elastic", "diagnostic"])
+def test_modes_that_read_each_step_keep_no_step_in_flight(reader):
+    """Under an elastic context every step is read before the next is
+    launched, ``pre_step``/``post_step`` bracket each step as before;
+    a diagnostic step that is due drains the step in flight, runs
+    alone and is read at once. Both train as one call a step does."""
+    batches = _plan_batches((64,) * 6)
+    net = _dropout_net()
+    if reader == "diagnostic":
+        net.monitor_numerics(every=3)
+    w, net, rngs = _logged_wrapper(net)
+    if reader == "elastic":
+        w.elastic = _Elastic()
+    else:
+        diag = w._ensure_diag_step(net._numerics)
+        w._diag_step = lambda *a: (rngs.append(np.asarray(a[-1])),
+                                   diag(*a))[1]
+    seen = len(_fit_records())
+    order = []              # steps launched when step i's listeners run
+
+    class Launched:
+        def iteration_done(self, net, iteration, epoch):
+            order.append(len(rngs))
+
+    net.listeners.append(Launched())
+    w.fit(batches)
+    ahead = [r.counts["ahead"] for r in _fit_records()[seen:]]
+    if reader == "elastic":
+        assert ahead == [0] * 6
+        # step i's listeners ran before step i + 1 was launched
+        assert order == [1, 2, 3, 4, 5, 6]
+        log = net.listeners[0].calls
+        want = []
+        for i in range(6):
+            want += [("pre", i), ("run",), ("sync",),
+                     ("post", i, log[i][1])]
+        assert w.elastic.calls == want
+    else:
+        # iterations 2 and 5 (the 3rd and 6th step) are diagnostic:
+        # nothing in flight before, in or after them
+        assert ahead == [0, 1, 0, 0, 1, 0]
+        assert order == [2, 2, 3, 5, 5, 6]
+        assert net.last_numerics["iteration"] == 6
+    assert w._flight is None and net.iteration == 6
+    # the same steps, each read before the next by construction
+    netr = _dropout_net()
+    if reader == "diagnostic":
+        netr.monitor_numerics(every=3)
+    ref, netr, _ = _logged_wrapper(netr)
+    for b in batches:
+        ref.fit([b])
+    assert net.listeners[0].calls == netr.listeners[0].calls
+    _assert_trees_equal(net.params, netr.params)
+    _assert_trees_equal(net.state, netr.state)
+
+
+def test_lockstep_budget_is_never_overrun_by_a_launch(monkeypatch):
+    """Multi-host: with a budget of 3 steps an epoch agreed across the
+    processes, exactly 3 steps are launched however many batches the
+    local iterator holds, and the third is read before the epoch ends."""
+    from jax.experimental import multihost_utils as mhu
+    replies = iter([3, 10 ** 6])        # the peers': step count, batch
+
+    def allgather(a):
+        return np.asarray([int(np.asarray(a)[0]), next(replies)])
+
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(mhu, "process_allgather", allgather)
+    w, net, rngs = _logged_wrapper()
+    seen = len(_fit_records())
+    w.fit(_plan_batches((64,) * 5))     # a list: sized, as it must be
+    assert len(rngs) == 3               # launches
+    assert [r.counts["ahead"] for r in _fit_records()[seen:]] == [0, 1, 1]
+    assert net.iteration == 3 and w._flight is None
+    assert [i for i, _ in net.listeners[0].calls] == [1, 2, 3]
 
 
 def _multi_io_graph(seed=1):

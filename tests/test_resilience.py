@@ -397,6 +397,48 @@ def test_fault_matrix_worker_step_recovers(tmp_path):
     assert np.isfinite(float(net.score(ds)))
 
 
+def test_wrapper_crash_after_periodic_save_resumes_bit_equal(tmp_path):
+    """The wrapper launches step n+1 before it calls step n's
+    listeners; the periodic checkpoint and ``progress.json`` say that
+    they read the net's state, so no step runs ahead of a saved one:
+    a crash after a save restores a zip that holds exactly its
+    iteration's weights and momentum, and the resumed run ends bit
+    for bit where the uninterrupted one does."""
+    import jax
+    from deeplearning4j_tpu.parallel import ParallelWrapper
+    ds = _data()
+
+    def run(plan, where):
+        net = _mlp()
+        pw = ParallelWrapper(net, mode=ParallelWrapper.SYNC,
+                             prefetch_buffer=0)
+        trainer = FaultTolerantTrainer(net, where,
+                                       save_every_n_iterations=2,
+                                       max_restarts=4, train_with=pw)
+        if plan is None:
+            trainer.fit(_iter(ds), epochs=3)
+        else:
+            with faults.active(plan):
+                trainer.fit(_iter(ds), epochs=3)
+        return net, trainer
+
+    base, _ = run(None, tmp_path / "base")
+    # 4 batches an epoch: the 8th loop body is epoch 2's last, with
+    # step 7 on the chips and the zip of iteration 6 on disk, written
+    # by step 6's listeners in the body that launched step 7
+    net, trainer = run("worker_step:error=ConnectionError:nth=8:max=1",
+                       tmp_path / "crashed")
+    assert trainer.restarts == 1
+    assert net.iteration == base.iteration == 12 and net.epoch == 3
+    # the tracker says so at the save's cadence
+    reads = trainer._tracker.reads_state
+    assert [reads(i) for i in (2, 3, 4)] == [True, False, True]
+    for tree, want in ((net.params, base.params),
+                       (net.opt_state, base.opt_state)):
+        for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # =========================================================================
 # serving load-shedding + deadlines + graceful drain
 # =========================================================================

@@ -46,6 +46,24 @@ Step-latency histograms, ETL waits, serving queue depth, retrace
 sentry and compile-cache counters all appear as `dl4j_tpu_*`
 families.
 
+**Is the wrapper's pipeline full?** `ParallelWrapper.fit` keeps one
+batch and one step in flight. Over the count of
+`dl4j_tpu_worker_step_latency_seconds`,
+`dl4j_tpu_worker_staged_ahead_total` is the share of steps whose batch
+was on the chips before the step before them ended, and
+`dl4j_tpu_worker_steps_ahead_total` the share launched before their
+predecessor's loss was read: both read (n-1)/n for calls of n batches,
+and the second reads 0 under an elastic context and falls with the
+numerics monitor's cadence (a diagnostic step runs alone) and with a
+checkpoint's or an evaluation's (a listener whose `reads_state` says
+it saves or evaluates at an iteration has that step read before the
+next is launched, so the zip holds its own iteration's weights). Since
+a step's blocking read is its predecessor's,
+`dl4j_tpu_worker_collective_sync_seconds_total` is the time the host
+waited under a running step, not time the chips stood still; it holds
+every step's wait, a call's last step's too, which is read after the
+loop.
+
 **Watch a long run.** `tools/tpu_watch.py` samples the same
 surfaces from OUTSIDE the run — it touches no JAX backend and starts
 no process, so the run keeps its chip to itself:

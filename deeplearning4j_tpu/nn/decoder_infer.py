@@ -14,8 +14,13 @@ mixer is chosen where the cache object is built, never in the block.
 The dense layouts' cache objects live here, the paged pool's with the
 pager (``serving/kv_pager.py``). ``dims`` is anything with
 ``n_layers``, ``n_heads``, ``n_kv_heads``, ``rope_theta`` and
-``tie_embeddings``: the zoo model itself. ARCHITECTURE.md §15 has the
-picture, and why the training block stays apart.
+``tie_embeddings``: the zoo model itself (a latent mixer's sizes are
+its ``latent``, its expert layers' its ``experts``). ARCHITECTURE.md
+§15 has the picture, and why the training block stays apart.
+
+The feed-forward is chosen by what a block's parameters HOLD, never by
+a flag: ``Wg``/``Wu``/``Wd`` a dense SwiGLU, ``moe`` the expert layer
+of ``ops/moe.py`` (this chip's experts beside the shared one).
 
 Imports ``ops/`` and ``nn/layers/``, never ``zoo/`` or ``serving/``.
 """
@@ -24,11 +29,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.layers.attention import (rotary_embedding,
-                                                    scaled_dot_attention)
+from deeplearning4j_tpu.nn.layers.attention import (
+    latent_attention_expanded, rotary_embedding, scaled_dot_attention)
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import devtime
-from deeplearning4j_tpu.ops import fused_norms, retention
+from deeplearning4j_tpu.ops import fused_norms, latent, moe, retention
 
 
 def rms(x, gamma):
@@ -78,28 +83,51 @@ def qkv(mha, h, dims, rotate):
     return rotate(q), rotate(k), v
 
 
-def block(pblk, x, attend, li: int):
+def ffn(pblk, h, experts=None, live=None):
+    """The feed-forward of normed rows ``h``, by what the block holds:
+    ``(y, counts)``, ``counts`` an expert layer's held experts' pairs
+    (``ops.moe.layer``; ``experts`` its ``ExpertSpec``, ``live`` the
+    rows that carry a token), None for a dense SwiGLU."""
+    if "moe" in pblk:
+        return moe.layer(pblk["moe"], h, experts, live=live)
+    h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
+    return h @ pblk["Wd"], None
+
+
+def block(pblk, x, attend, li: int, experts=None, counts=None,
+          live=None):
     """One decoder block over rows ``x [..., F]``: ``ln1`` → mixer →
-    ``Wo`` + residual → ``ln2`` → SwiGLU → residual."""
+    ``Wo`` + residual → ``ln2`` → feed-forward → residual. An expert
+    layer's counts are appended to ``counts`` where a list is given;
+    it routes the ``live`` rows only (:func:`ffn`)."""
     mha = pblk["mha"]
     a = attend(li, mha, rms(x, pblk["ln1"]["gamma"]))
-    x = x + a @ mha["Wo"] + mha["bo"]
-    h = rms(x, pblk["ln2"]["gamma"])
-    h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
-    return x + h @ pblk["Wd"]
+    x = x + a @ mha["Wo"]
+    if "bo" in mha:
+        x = x + mha["bo"]
+    y, pairs = ffn(pblk, rms(x, pblk["ln2"]["gamma"]), experts, live)
+    if pairs is not None and counts is not None:
+        counts.append(pairs)
+    return x + y
 
 
 def stack(params, toks, dims, attend, scope: str,
-          block_scope: str = ""):
+          block_scope: str = "", counts=None, live=None):
     """Token ids ``toks`` (any shape) through the embedding and every
     block, to the rows before the final norm. The devtime scopes are
     HLO metadata only: block i's device time gets the name
-    ``{block_scope or scope}.block_{i}``."""
+    ``{block_scope or scope}.block_{i}``. A caller that wants the
+    expert layers' pair counts passes a list as ``counts``: each
+    expert layer appends its ``[n_held]``. ``live`` (bool, ``toks``'
+    shape) marks the rows that carry a token; the expert layers route
+    no other (a bucket's padding, a slot without a sequence)."""
     with devtime.scope(f"{scope}.embed"):
         x = params["layer_0"]["W"][toks]
+    experts = getattr(dims, "experts", None)
     for i in range(dims.n_layers):
         with devtime.scope(f"{block_scope or scope}.block_{i}"):
-            x = block(params[f"layer_{i + 1}"], x, attend, i)
+            x = block(params[f"layer_{i + 1}"], x, attend, i, experts,
+                      counts, live)
     return x
 
 
@@ -269,6 +297,44 @@ def causal_prefill(dims, keep):
         keep(li, k, v)
         return scaled_dot_attention(q, k, v, causal=True).reshape(
             *h.shape[:-1], -1)
+    return attend
+
+
+class DenseLatent(_AtPosition):
+    """Latent attention against ONE ``[rows, T, kv_rank + rope]`` array
+    a layer: the position's latent row is written, and the absorbed
+    form reads the rows up to it (``ops/latent.py``)."""
+
+    def attend(self, li, mha, h):
+        dims, pos = self.dims, self.pos
+        spec = dims.latent
+        rows = h.shape[0]
+        q_nope, q_rope, row = latent.project(
+            mha, h, spec, dims.n_heads, dims.rope_theta,
+            jnp.full((rows,), pos))
+        cache = jax.lax.dynamic_update_index_in_dim(
+            self.caches[li], row.astype(self.caches[li].dtype), pos, 1)
+        self.caches[li] = cache
+        o = latent.attend_rows(
+            latent.absorb(mha, q_nope, q_rope, spec), cache,
+            jnp.full((rows,), pos + 1), latent.softmax_scale(spec),
+            spec.kv_rank)
+        return latent.unabsorb(mha, o, spec)
+
+
+def latent_prefill(dims, keep):
+    """The ``attend`` of a whole padded prompt ``h [B, Tb, F]`` under
+    latent attention: the EXPANDED form
+    (``latent_attention_expanded``: K and V of every position from its
+    latent, flash-dispatched as :func:`causal_prefill`'s), each
+    layer's latent rows ``[B, Tb, kv_rank + rope]`` handed to
+    ``keep(li, rows)``. Padding rows as in :func:`causal_prefill`."""
+    def attend(li, mha, h):
+        with devtime.scope("ops.latent_prefill"):
+            a, rows = latent_attention_expanded(
+                mha, h, dims.latent, dims.n_heads, dims.rope_theta)
+        keep(li, rows)
+        return a
     return attend
 
 

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -433,6 +433,92 @@ class PowerRetention(Layer):
         return o, state
 
 
+def latent_attention_expanded(mha, h, spec, n_heads: int, theta: float):
+    """Causal latent attention of whole sequences ``h [B, T, F]`` in
+    the EXPANDED form (``ops/latent.py``): every position's K and V
+    made from its latent, through :func:`scaled_dot_attention`
+    (flash-dispatched on the TPU). That takes ONE width for queries,
+    keys and values, so all three are padded with zeros to whole
+    128-lane tiles (192-wide keys and 128-wide values to 256: zeros
+    add nothing to a score, and the values' tail is cut off again),
+    and the softmax scale is folded into the query. Returns the
+    mixer's output ``[B, T, H * v]`` and the positions' latent rows
+    ``[B, T, kv_rank + rope]`` (what a cache keeps)."""
+    from deeplearning4j_tpu.ops import latent
+    b, t, f = h.shape
+    width = latent.lanes(spec.nope + spec.rope)
+
+    def padded(*parts):
+        have = sum(p.shape[-1] for p in parts)
+        return jnp.concatenate(parts + (jnp.zeros(
+            (b, t, n_heads, width - have), parts[0].dtype),), axis=-1)
+
+    q_nope, q_rope, row = latent.project(
+        mha, h.reshape(b * t, f), spec, n_heads, theta,
+        jnp.tile(jnp.arange(t), b))
+    row = row.reshape(b, t, -1)
+    k_nope, v = latent.expand(mha, row, spec, n_heads)
+    q = padded(q_nope.reshape(b, t, n_heads, -1),
+               q_rope.reshape(b, t, n_heads, -1))
+    k = padded(k_nope, jnp.broadcast_to(
+        row[:, :, None, spec.kv_rank:], (b, t, n_heads, spec.rope)))
+    # scaled_dot_attention divides by the root of the width
+    fold = latent.softmax_scale(spec) * width ** 0.5
+    a = scaled_dot_attention((q * fold).astype(q.dtype), k, padded(v),
+                             causal=True)
+    return a[..., :spec.v].reshape(b, t, -1), row
+
+
+@register_layer
+@dataclass
+class LatentAttention(Layer):
+    """Multi-head latent attention (``ops/latent.py`` has the
+    equations): queries through a normed low-rank ``c_q``, keys and
+    values expanded from ONE normed latent a position beside one
+    rotary key shared by all heads. Its parameters take
+    :class:`MultiHeadAttention`'s place, ``params["mha"]``: ``Wqa``,
+    ``qa_gamma``, ``Wqb``, ``Wkva``, ``kv_gamma``, ``Wkvb``, ``Wo``
+    (no biases, as published). The training forward is the expanded
+    form (:func:`latent_attention_expanded`), differentiated through
+    ``scaled_dot_attention``. Causal by
+    construction; ``spec`` is an ``ops.latent.LatentSpec`` (or the
+    dict a serialized layer carries)."""
+    n_in: Optional[int] = None
+    n_heads: int = 1
+    rope_theta: float = 10000.0
+    spec: Optional[Any] = None
+
+    def init(self, key, input_shape, dtype=jnp.float32):
+        from deeplearning4j_tpu.ops.latent import LatentSpec
+        f = self.n_in or input_shape[-1]
+        spec = LatentSpec.of(self.spec)
+        h = self.n_heads
+        wi = winit.get(self.weight_init or "xavier")
+        ks = jax.random.split(key, 5)
+        params = {
+            "Wqa": wi(ks[0], (f, spec.q_rank), dtype),
+            "qa_gamma": jnp.ones((spec.q_rank,), dtype),
+            "Wqb": wi(ks[1], (spec.q_rank, h * (spec.nope + spec.rope)),
+                      dtype),
+            "Wkva": wi(ks[2], (f, spec.row), dtype),
+            "kv_gamma": jnp.ones((spec.kv_rank,), dtype),
+            "Wkvb": wi(ks[3], (spec.kv_rank, h * (spec.nope + spec.v)),
+                       dtype),
+            "Wo": wi(ks[4], (h * spec.v, f), dtype)}
+        return params, {}, (input_shape[0], f)
+
+    def apply(self, params, state, x, *, train=False, rng=None,
+              mask=None):
+        from deeplearning4j_tpu.ops.latent import LatentSpec
+        a, _ = latent_attention_expanded(
+            params, x, LatentSpec.of(self.spec), self.n_heads,
+            self.rope_theta)
+        o = a @ params["Wo"]
+        if mask is not None:
+            o = o * mask[..., None].astype(o.dtype)
+        return o, state
+
+
 @register_layer
 @dataclass
 class TransformerDecoderBlock(Layer):
@@ -457,10 +543,18 @@ class TransformerDecoderBlock(Layer):
     rope_theta: float = 10000.0
     sequence_parallel: Optional[str] = None
     remat: bool = False
-    #: the sequence mixer: "softmax" (attention over every cached key)
-    #: or "power_retention" (:class:`PowerRetention`; its parameters
-    #: take the same place, ``params["mha"]``)
+    #: the sequence mixer: "softmax" (attention over every cached key),
+    #: "power_retention" (:class:`PowerRetention`) or "latent"
+    #: (:class:`LatentAttention`, sized by ``latent``); its parameters
+    #: take the same place, ``params["mha"]``
     mixer: str = "softmax"
+    #: an ``ops.latent.LatentSpec`` (``mixer="latent"``)
+    latent: Optional[Any] = None
+    #: the feed-forward: "dense" (SwiGLU of ``ffn_mult`` widths) or
+    #: "experts" (``ops/moe.py``'s layer, sized by ``experts``, an
+    #: ``ops.moe.ExpertSpec``: its parameters under ``params["moe"]``)
+    ffn: str = "dense"
+    experts: Optional[Any] = None
 
     def _subs(self):
         if not hasattr(self, "_mha"):
@@ -476,9 +570,18 @@ class TransformerDecoderBlock(Layer):
                     n_in=f, n_heads=self.n_heads,
                     n_kv_heads=self.n_kv_heads,
                     rope_theta=self.rope_theta)
+            elif self.mixer == "latent":
+                if self.sequence_parallel:
+                    raise ValueError(
+                        "mixer='latent' has no sequence-parallel form "
+                        "here: the ring carries KV heads, not latents")
+                self._mha = LatentAttention(
+                    n_in=f, n_heads=self.n_heads,
+                    rope_theta=self.rope_theta, spec=self.latent)
             elif self.mixer != "softmax":
-                raise ValueError(f"mixer={self.mixer!r} "
-                                 "('softmax' | 'power_retention')")
+                raise ValueError(
+                    f"mixer={self.mixer!r} "
+                    "('softmax' | 'power_retention' | 'latent')")
             else:
                 self._mha = MultiHeadAttention(
                     n_in=f, n_out=f, n_heads=self.n_heads,
@@ -496,12 +599,31 @@ class TransformerDecoderBlock(Layer):
         pa, _, _ = self._mha.init(ks[0], input_shape, dtype)
         p1, _, _ = self._ln1.init(ks[1], input_shape, dtype)
         p2, _, _ = self._ln2.init(ks[2], input_shape, dtype)
-        hid = int(round(f * self.ffn_mult))
-        params = {"mha": pa, "ln1": p1, "ln2": p2,
-                  # SwiGLU: (silu(x W_gate) ⊙ x W_up) W_down
-                  "Wg": wi(ks[3], (f, hid), dtype),
-                  "Wu": wi(ks[4], (f, hid), dtype),
-                  "Wd": wi(ks[5], (hid, f), dtype)}
+        params = {"mha": pa, "ln1": p1, "ln2": p2}
+        if self.ffn == "experts":
+            from deeplearning4j_tpu.ops.moe import ExpertSpec
+            e = ExpertSpec.of(self.experts)
+            ke = jax.random.split(ks[3], 7)
+            moe = {  # the router stays float32 whatever the dtype
+                "Wr": wi(ke[0], (f, e.n_routed), jnp.float32),
+                "br": jnp.zeros((e.n_routed,), jnp.float32),
+                "Weg": wi(ke[1], (e.n_held, f, e.width), dtype),
+                "Weu": wi(ke[2], (e.n_held, f, e.width), dtype),
+                "Wed": wi(ke[3], (e.n_held, e.width, f), dtype)}
+            if e.n_shared:
+                shared = e.n_shared * e.width
+                moe.update(Wsg=wi(ke[4], (f, shared), dtype),
+                           Wsu=wi(ke[5], (f, shared), dtype),
+                           Wsd=wi(ke[6], (shared, f), dtype))
+            params["moe"] = moe
+        elif self.ffn != "dense":
+            raise ValueError(f"ffn={self.ffn!r} ('dense' | 'experts')")
+        else:
+            hid = int(round(f * self.ffn_mult))
+            # SwiGLU: (silu(x W_gate) ⊙ x W_up) W_down
+            params.update(Wg=wi(ks[3], (f, hid), dtype),
+                          Wu=wi(ks[4], (f, hid), dtype),
+                          Wd=wi(ks[5], (hid, f), dtype))
         return params, {}, tuple(input_shape)
 
     def _body(self, params, x, mask, train, rng):
@@ -516,6 +638,13 @@ class TransformerDecoderBlock(Layer):
         # add-then-norm pair
         h, x = fused_norms.add_rms_norm(x, a, params["ln2"]["gamma"],
                                         eps=self._ln2.eps)
+        if "moe" in params:
+            # the plain form: every held expert on every row, masked
+            # by the routing (autodiff runs no data-dependent loop)
+            from deeplearning4j_tpu.ops import moe
+            h, _ = moe.layer(params["moe"], h,
+                             moe.ExpertSpec.of(self.experts), plain=True)
+            return x + self._maybe_dropout(h, train, r2)
         h = jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])
         return x + self._maybe_dropout(h @ params["Wd"], train, r2)
 

@@ -114,6 +114,8 @@ FAMILIES = {
     "dl4j_tpu_serving_kv_pages_walked": "gauge",
     "dl4j_tpu_serving_state_pool_bytes": "gauge",
     "dl4j_tpu_serving_state_bytes_moved": "counter",
+    "dl4j_tpu_serving_latent_rows_read_total": "counter",
+    "dl4j_tpu_serving_expert_pairs_total": "counter",
     # speculative multi-token decode (serving/scheduler.py)
     "dl4j_tpu_serving_spec_accept_rate": "histogram",
     "dl4j_tpu_serving_spec_drafted_total": "counter",
@@ -552,6 +554,16 @@ SERVING_STATE_MOVED = REGISTRY.counter(
     "dl4j_tpu_serving_state_bytes_moved",
     "bytes of recurrent state the decode steps have read and written "
     "(logical size d(d+1)/2 rows a kv head, float32; both directions)")
+SERVING_LATENT_ROWS = REGISTRY.counter(
+    "dl4j_tpu_serving_latent_rows_read_total",
+    "cached positions the decode steps' latent attention has read: "
+    "each step the sum of its live slots' lengths, one latent row a "
+    "position and layer (0 for a model without a latent pool)")
+SERVING_EXPERT_PAIRS = REGISTRY.counter(
+    "dl4j_tpu_serving_expert_pairs_total",
+    "token-expert pairs the experts held here have computed, over "
+    "all expert layers, decode steps and prefills (pairs routed to "
+    "experts that other chips hold are not counted)")
 
 # speculative multi-token decode + copy-on-write prefix sharing
 # (serving/scheduler.py + serving/kv_pager.py): accept rate is the

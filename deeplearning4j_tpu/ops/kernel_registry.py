@@ -73,6 +73,17 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "closes": ("paged_decode.block_*",),
         "gate": "paged_decode",
     },
+    "latent_decode_attention": {
+        "module": "ops/pallas_kernels.py",
+        "fallback": "_reference_latent_attention",
+        "parity":
+            "tests/test_pallas.py::test_latent_decode_matches_reference",
+        "scope": "ops.latent_decode_attention",
+        # a latent model's decode blocks share the softmax model's
+        # scope names; paged_decode_attention already claims them
+        "closes": (),
+        "gate": "latent_decode",
+    },
     "retention_decode": {
         "module": "ops/pallas_kernels.py",
         "fallback": "_reference_retention_decode",

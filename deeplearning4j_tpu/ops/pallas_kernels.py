@@ -25,6 +25,9 @@ Kernels:
   by the kernel's own double-buffered DMAs, float32 online softmax.
   ``_reference_paged_attention`` is its fallback and the attention of
   the multi-row paged programs.
+- ``latent_decode_attention`` — the same page walk over the paged
+  LATENT pool of a latent-attention model: a page is the ``[block,
+  kv_rank + rope]`` matrix every absorbed query head reads at once.
 - ``threshold_encode`` / ``threshold_decode`` — fused gradient
   threshold compression (reference libnd4j ops ``encode_threshold`` /
   ``decode_threshold``): one VMEM pass computes the ternary
@@ -1077,6 +1080,197 @@ def paged_decode_attention(q, pool, li, pt, n_live,
                                   pt, n_live,
                                   pages_per_chunk=min(chunk, pt.shape[1]),
                                   interpret=_interpret())
+
+
+# ---------------------------------------------------------------------------
+# latent decode attention over the paged latent pool
+# ---------------------------------------------------------------------------
+#
+# The latent pool (serving/kv_pager.py) is ``[L, P, block, W]``: one
+# page of one layer is ``block`` positions' latent rows ``[c_kv |
+# k_rope]`` (``W = kv_rank + rope``), no KV heads. The kernel is
+# ``_paged_decode_kernel``'s page walk (scalar-prefetched page table
+# and lengths, one contiguous DMA a page into a double buffer, float32
+# online softmax) with a page used as the ``[block, W]`` matrix it is:
+# ALL the absorbed queries ``[H, W]`` meet a chunk's rows in one
+# matmul, and the values are the same rows' first ``kv_rank`` columns.
+# There is no head to mask. ``W`` is the STORED width, whole 128-lane
+# tiles (the pager pads a row's tail with zeros: the TPU tiles the
+# minor dimension by 128 lanes, so a 576-wide row takes 640 in HBM
+# either way, and Mosaic slices whole tiles only).
+
+#: positions of a chunk folded per loop iteration
+_LATENT_CHUNK_ROWS = 1024
+
+
+def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
+                          buf, sem, m, l, acc, *, scale: float,
+                          block: int, chunk: int, max_pages: int,
+                          kv_rank: int):
+    # li_ref [1], pt_ref [S*MP], n_ref [S]: scalar-prefetch operands in
+    # SMEM; q_ref [H, W], o_ref [H, kv_rank] (this slot's blocks);
+    # pool_ref [L, P, block, W], left in HBM; buf [2, chunk, block, W]
+    b = pl.program_id(0)
+    li = li_ref[0]
+    n_pos = n_ref[b]                  # live positions; 0 = inactive
+    n_pages = (n_pos + block - 1) // block
+    n_chunks = (n_pages + chunk - 1) // chunk
+    h, width = q_ref.shape
+    rows = chunk * block
+
+    @pl.when(b == 0)
+    def _():
+        # an unfetched tail meets p == 0 in the p·V matmul, and
+        # 0 · NaN is NaN (as in ``_paged_decode_kernel``)
+        buf[...] = jnp.zeros_like(buf)
+
+    m[...] = jnp.full_like(m, -jnp.inf)
+    l[...] = jnp.zeros_like(l)
+    acc[...] = jnp.zeros_like(acc)
+
+    def chunk_dma(c, slot, go):
+        def page(j, carry):
+            pid = pt_ref[b * max_pages + c * chunk + j]
+            go(pltpu.make_async_copy(pool_ref.at[li, pid],
+                                     buf.at[slot, j], sem.at[slot]))
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(chunk, n_pages - c * chunk), page,
+                      0)
+
+    @pl.when(n_chunks > 0)
+    def _():
+        chunk_dma(0, 0, lambda cp: cp.start())
+
+    rel = lax.broadcasted_iota(jnp.int32, (h, rows), 1)
+    contract = (((1,), (1,)), ((), ()))
+
+    def body(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            chunk_dma(c + 1, 1 - slot, lambda cp: cp.start())
+
+        chunk_dma(c, slot, lambda cp: cp.wait())
+        kv = buf[slot].reshape(rows, width)
+        s = lax.dot_general(q_ref[...], kv, contract,
+                            preferred_element_type=jnp.float32)
+        # every chunk walked holds a live position, so the running
+        # maximum is finite from the first on
+        s = jnp.where(rel < n_pos - c * rows, s * scale, -jnp.inf)
+        m_prev = m[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l[...] = jnp.broadcast_to(
+            l[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
+            l.shape)
+        acc[...] = acc[...] * alpha + jnp.dot(
+            p.astype(kv.dtype), kv[:, :kv_rank],
+            preferred_element_type=jnp.float32)
+        m[...] = jnp.broadcast_to(m_new, m.shape)
+        return carry
+
+    lax.fori_loop(0, n_chunks, body, 0)
+    o_ref[...] = (acc[...] / jnp.maximum(l[:, :1], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "kv_rank", "pages_per_chunk", "interpret"))
+def _latent_decode_call(q, pool, li, pt, n_live, scale, kv_rank,
+                        pages_per_chunk, interpret):
+    """ONE lowering for every layer of a step (the layer index is a
+    scalar operand), as ``_paged_decode_call``."""
+    s_, h, width = q.shape
+    _, _, block, _ = pool.shape
+    mp = pt.shape[1]
+    chunk = pages_per_chunk
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=scale,
+                          block=block, chunk=chunk, max_pages=mp,
+                          kv_rank=kv_rank),
+        out_shape=jax.ShapeDtypeStruct((s_, h, kv_rank), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s_,),
+            in_specs=[pl.BlockSpec((None, h, width),
+                                   lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, h, kv_rank),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk, block, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, kv_rank), jnp.float32),
+            ]),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(li.reshape(1).astype(jnp.int32), pt.reshape(-1).astype(jnp.int32),
+      n_live.astype(jnp.int32), q, pool)
+
+
+def _reference_latent_attention(q, pool, li, pt, n_live, scale, kv_rank):
+    """The absorbed latent attention of one query row a slot in plain
+    jnp: gather the slot's pages through its page-table row, in
+    position order, mask past its length, float32 softmax. The
+    registered fallback of :func:`latent_decode_attention`. ``q``
+    [S, H, W]; ``pool`` [L, P, block, W]; ``n_live`` [S] live
+    positions (0: an inactive slot, zeros). Returns [S, H, kv_rank]."""
+    from deeplearning4j_tpu.ops import latent
+    rows = pool[li, pt].reshape(q.shape[0], -1, pool.shape[-1])
+    return latent.attend_rows(q, rows.astype(q.dtype), n_live, scale,
+                              kv_rank)
+
+
+def _use_latent_kernel(q, pool, kv_rank: int) -> bool:
+    """The dispatch line of :func:`latent_decode_attention`: the
+    platform gate every kernel uses, a pool in the query's dtype, the
+    stored row and the latent's lanes whole 128-lane tiles (values are
+    a lane slice of a page's rows) and a page whole sublane tiles."""
+    from deeplearning4j_tpu.ops.kernel_registry import gate_active
+    if not gate_active("latent_decode"):
+        return False
+    return (pool.dtype == q.dtype and q.dtype != jnp.float64
+            and kv_rank % 128 == 0 and pool.shape[3] % 128 == 0
+            and pool.shape[2] % (32 // pool.dtype.itemsize) == 0)
+
+
+def latent_decode_attention(q, pool, li, pt, n_live, scale: float,
+                            kv_rank: int,
+                            pages_per_chunk: Optional[int] = None):
+    """Single-token decode attention of a latent-attention block over
+    the paged latent pool, read in place, in the ABSORBED form
+    (``ops/latent.py``). ``q`` [S, H, W] (``[q_nope Wk^T | q_rope]``,
+    one row a slot); ``pool`` [L, P, block, W'] latent rows, stored
+    ``W' >= W`` wide with a zero tail (the query is padded to match:
+    zeros meet zeros); ``li`` the
+    layer; ``pt`` [S, MP] i32 page table; ``n_live`` [S] i32 live
+    positions a slot, the one just written included, 0 for an inactive
+    slot; ``scale`` the softmax scale. Returns the weighted sums of
+    latents [S, H, kv_rank]; an inactive slot's rows are zeros. Every
+    head reads the same rows: a fetched page serves all H queries.
+    Shapes the kernel does not take (:func:`_use_latent_kernel`) run
+    :func:`_reference_latent_attention`."""
+    from deeplearning4j_tpu.obs import devtime
+    with devtime.scope("ops.latent_decode_attention"):
+        q = jnp.pad(q, ((0, 0), (0, 0),
+                        (0, pool.shape[3] - q.shape[2])))
+        if not _use_latent_kernel(q, pool, kv_rank):
+            return _reference_latent_attention(q, pool, li, pt, n_live,
+                                               scale, kv_rank)
+        block = pool.shape[2]
+        chunk = pages_per_chunk or max(1, _LATENT_CHUNK_ROWS // block)
+        return _latent_decode_call(
+            q, pool, jnp.asarray(li, jnp.int32), pt, n_live,
+            scale=float(scale), kv_rank=kv_rank,
+            pages_per_chunk=min(chunk, pt.shape[1]),
+            interpret=_interpret())
 
 
 # ---------------------------------------------------------------------------

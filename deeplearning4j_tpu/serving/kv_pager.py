@@ -59,6 +59,25 @@ the same; ``pages_for`` answers 1, and nothing is ever shared (a
 state is no pure function of a prefix's tokens alone that another
 sequence could adopt mid-way: there is no chain index to consult).
 
+A model whose blocks attend through a latent
+(``CausalTransformerLM(mixer="latent")``, ``ops/latent.py``) gets the
+third kind of page (``latent_dim``): ONE compressed row a position and
+no KV heads,
+
+- ``rows`` ``[L, P, block, W']`` in the compute dtype: page ``p`` of
+  layer ``l`` holds ``block`` consecutive positions' ``[c_kv (normed)
+  | k_rope (rotated)]``, the ``[block, W']`` matrix that
+  ``ops.latent_decode_attention`` reads with every head at once.
+  ``W'`` is ``latent_dim`` rounded up to whole 128-lane tiles, the
+  tail zero (``ops.latent.lanes``): the TPU tiles the minor
+  dimension by 128 lanes, so a 576-wide row takes 640 in HBM whatever
+  the shape says, and the kernel's DMAs move whole tiles only. Bytes
+  are counted by ``latent_dim``.
+
+Pages are counted, reserved and freed as KV pages are (``pages_for``
+by ``block``); they are not shared and not quantised yet (the
+scheduler refuses both for such a model).
+
 Page 0 is the reserved **trash page**: inactive slots' writes and
 unallocated page-table entries route there, so a fixed-shape step can
 always scatter/gather without corrupting live sequences (reads of
@@ -90,8 +109,9 @@ The layouts above are known HERE and to the kernels that read them
 (``ops/pallas_kernels.py``), nowhere else: a program of the scheduler
 reaches its pool through the **cache objects** at the end of this
 file (``nn/decoder_infer.py``'s contract, ``attend(li, mha, h)``):
-:meth:`KVPager.rows` (R rows a slot; KV pages or states, decided once
-by ``state_rows``) and :class:`StateChunk`, and through
+:meth:`KVPager.rows` (R rows a slot; KV pages, states or latent pages,
+decided once by ``state_rows`` and ``latent_dim``) and
+:class:`StateChunk`, and through
 :meth:`KVPager.write_prompt` and :meth:`KVPager.copy_page`.
 """
 from __future__ import annotations
@@ -104,9 +124,10 @@ import numpy as np
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import metrics as _metrics
-from deeplearning4j_tpu.ops import retention
+from deeplearning4j_tpu.ops import latent, retention
 from deeplearning4j_tpu.ops.pallas_kernels import (
-    _reference_paged_attention, paged_decode_attention, retention_decode)
+    _reference_paged_attention, latent_decode_attention,
+    paged_decode_attention, retention_decode)
 
 
 class PageTableError(RuntimeError):
@@ -125,10 +146,16 @@ class KVPager:
     def __init__(self, *, n_layers: int, n_kv_heads: int, head_dim: int,
                  n_pages: int, block: int, cache_quant: Optional[str],
                  dtype: str = "float32",
-                 state_rows: Optional[int] = None):
+                 state_rows: Optional[int] = None,
+                 latent_dim: Optional[int] = None):
         if state_rows is not None and cache_quant is not None:
             raise ValueError("a recurrent-state pool is float32: "
                              "cache_quant does not apply to it")
+        if latent_dim is not None and (cache_quant is not None
+                                       or state_rows is not None):
+            raise ValueError("a latent pool holds one compressed row "
+                             "a position in the compute dtype: neither "
+                             "cache_quant nor state_rows applies to it")
         if block < 1 or block & (block - 1):
             raise ValueError(f"block={block} must be a power of two "
                              "(pages must tile the power-of-two "
@@ -147,8 +174,14 @@ class KVPager:
         self.cache_quant = cache_quant
         #: rows of a kv head's stored state (None: a KV-page pool)
         self.state_rows = state_rows
+        #: values of a position's latent row (None: no latent pool)
+        self.latent_dim = latent_dim
         shape = (n_layers, n_pages, block, n_kv_heads, 2 * head_dim)
-        if state_rows is not None:
+        if latent_dim is not None:
+            self._pool: Tuple = (jnp.zeros(
+                (n_layers, n_pages, block, latent.lanes(latent_dim)),
+                jnp.dtype(dtype)),)
+        elif state_rows is not None:
             self._pool: Tuple = (
                 jnp.zeros((n_layers, n_pages, n_kv_heads, state_rows,
                            head_dim), jnp.float32),
@@ -212,7 +245,8 @@ class KVPager:
         positions, ``act`` bool broadcastable to [S, R] (False rows
         write nothing a live sequence reads). Its ``pool`` is the pool
         after the rows."""
-        kind = PagedKV if self.state_rows is None else PagedState
+        kind = (PagedLatent if self.latent_dim is not None
+                else PagedKV if self.state_rows is None else PagedState)
         return kind(dims, pool, pt, pos, act)
 
     @staticmethod
@@ -222,7 +256,17 @@ class KVPager:
         (``decoder_infer.causal_prefill``'s ``keep``), ``page_ids`` the
         sequence's first ``Tb / block`` pages in position order. The
         pool's own layout, so nothing is transposed on the way, and
-        all layers go in one scatter."""
+        all layers go in one scatter. A latent pool takes each layer's
+        latent rows ``[1, Tb, latent_dim]``
+        (``decoder_infer.latent_prefill``'s ``keep``)."""
+        if pool[0].ndim == 4:
+            (rows,) = pool
+            lat = jnp.stack([r[0] for r in layers])     # [L, Tb, W]
+            n_l, tb, width = lat.shape
+            block, stored = rows.shape[2:]
+            lat = jnp.pad(lat, ((0, 0), (0, 0), (0, stored - width)))
+            return (rows.at[:, page_ids].set(lat.reshape(
+                n_l, tb // block, block, stored).astype(rows.dtype)),)
         kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
                         for k, v in layers])    # [L, Tb, Hkv, 2D]
         n_l, tb, n_kv, d2 = kv.shape
@@ -559,6 +603,40 @@ class PagedKV(_Rows):
         else:
             a = _reference_paged_attention(q, pool, li, pt, pos)
         return a.reshape(S * R, -1)
+
+
+class PagedLatent(_Rows):
+    """One position a slot against the latent pool: the row's latent
+    ``[c_kv (normed) | k_rope (rotated)]`` goes to page ``pt[s, pos //
+    block]`` at offset ``pos % block`` (an inactive slot's, and a
+    position past the slot's page table, to the trash page), and the
+    ABSORBED form reads the slot's pages as they are stored
+    (``ops.latent_decode_attention``; ``ops/latent.py`` has the
+    algebra). (R is 1: the scheduler refuses the multi-row programs
+    for a latent model at construction.)"""
+
+    def attend(self, li, mha, h):
+        dims, pt = self.dims, self.pt
+        spec = dims.latent
+        (rows,) = self.pool
+        S = h.shape[0]
+        block = rows.shape[2]
+        pos = self.pos.reshape(S)
+        q_nope, q_rope, row = latent.project(
+            mha, h, spec, dims.n_heads, dims.rope_theta, pos)
+        inb = jnp.broadcast_to(self.act, (S, 1))[:, 0] & (
+            pos < pt.shape[1] * block)
+        pidx = jnp.minimum(pos // block, pt.shape[1] - 1)
+        pids = jnp.where(inb, jnp.take_along_axis(
+            pt, pidx[:, None], axis=1)[:, 0], 0)
+        row = jnp.pad(row, ((0, 0), (0, rows.shape[3] - row.shape[1])))
+        rows = rows.at[li, pids, pos % block].set(row.astype(rows.dtype))
+        self.pool = (rows,)
+        o = latent_decode_attention(
+            latent.absorb(mha, q_nope, q_rope, spec), rows, li, pt,
+            jnp.where(inb, pos + 1, 0), latent.softmax_scale(spec),
+            spec.kv_rank)
+        return latent.unabsorb(mha, o, spec)
 
 
 class PagedState(_Rows):

@@ -54,6 +54,16 @@ itself; the decode step updates every live slot's state in place
 Prefix sharing and speculative decode would need snapshots of a state
 and are refused at construction for such a model.
 
+A model of ``mixer="latent"`` blocks admits by the bucket prefill that
+softmax has (``decoder_infer.latent_prefill``: K and V expanded from
+each position's latent, the latent rows kept as the sequence's pages)
+and decodes by the same step over ``kv_pager.PagedLatent``; where its
+feed-forward routes (``ops/moe.py``) the step hands the held experts'
+pair counts back beside the tokens, in the one read that fetches
+them. ``prefix_sharing``, ``spec_k > 1`` and ``cache_quant`` are
+refused for it: a latent page's content-addressed sharing, a
+multi-row absorbed read and an int8 latent are not written yet.
+
 The loop keeps ONE decode step in flight: :meth:`DecodeScheduler.step`
 launches step n+1 from step n's device-resident outputs and only then
 reads step n's tokens, so neither the read-back nor the next launch
@@ -149,12 +159,15 @@ class _InFlight:
     launch (a token goes to that request and to no other), and the
     launching iteration's first stamp."""
 
-    __slots__ = ("nxt", "slots", "t0")
+    __slots__ = ("nxt", "slots", "t0", "pairs")
 
-    def __init__(self, nxt, slots, t0: float):
+    def __init__(self, nxt, slots, t0: float, pairs=None):
         self.nxt = nxt
         self.slots = slots
         self.t0 = t0
+        #: an expert model's held experts' pair counts
+        #: [expert layers, n_held], on the device; else None
+        self.pairs = pairs
 
 
 class DecodeScheduler:
@@ -215,6 +228,24 @@ class DecodeScheduler:
         #: a retention model: one fixed-size state page a sequence
         self.recurrent = getattr(model, "mixer",
                                  "softmax") == "power_retention"
+        #: a latent-attention model: one compressed row a position
+        self.latent = getattr(model, "latent", None)
+        if self.latent is not None:
+            for name, on, why in (
+                    ("prefix_sharing", self.prefix_sharing,
+                     "its multi-row suffix prefill reads KV heads"),
+                    ("spec_k", self.spec_k != 1,
+                     "the verify step's multi-row read has no "
+                     "absorbed form yet"),
+                    ("cache_quant", bool(model.cache_quant),
+                     "a latent row has no int8 form yet")):
+                if on:
+                    raise ValueError(f"{name} with mixer='latent': {why}")
+        #: expert layers of the model (their counts come back with
+        #: every step's tokens)
+        experts = getattr(model, "experts", None)
+        self.expert_layers = (0 if experts is None
+                              else model.n_layers - experts.first_dense)
         state_rows = None
         if self.recurrent:
             from deeplearning4j_tpu.ops.retention import (
@@ -252,7 +283,8 @@ class DecodeScheduler:
                      else 1 + self.max_slots * self.max_pages_per_seq),
             cache_quant=model.cache_quant,
             dtype=model.compute_dtype or "float32",
-            state_rows=state_rows)
+            state_rows=state_rows,
+            latent_dim=None if self.latent is None else self.latent.row)
         # per-slot host state, mirrored into the small int arrays the
         # fixed-shape step consumes each iteration
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
@@ -333,8 +365,10 @@ class DecodeScheduler:
                  temps, top_p, ctr):
             cache = self.pager.rows(model, pool, page_table,
                                     lengths[:, None], active[:, None])
+            pairs = [] if self.expert_layers else None
             x = di.stack(params, prev, model, cache.attend,
-                         "paged_decode", block_scope)
+                         "paged_decode", block_scope, counts=pairs,
+                         live=active)
             logits = di.logits(params, x, model, "paged_decode")
             key = jax.random.fold_in(
                 jax.random.PRNGKey(self.seed), ctr)
@@ -345,8 +379,11 @@ class DecodeScheduler:
             # carry lengths forward ON DEVICE: steady-state steps feed
             # back (nxt, lengths+active) without any host->device
             # upload — only admissions/retirements dirty the feed
-            return nxt, cache.pool, lengths + active.astype(
-                lengths.dtype)
+            out = (nxt, cache.pool,
+                   lengths + active.astype(lengths.dtype))
+            # an expert model's step also says what its held experts
+            # computed: [expert layers, n_held] pairs, read with nxt
+            return out + (jnp.stack(pairs),) if pairs else out
 
         # pool is donated: the caller always rebinds the returned pool
         # (scheduler invariant), so XLA may alias in/out and the step
@@ -410,6 +447,7 @@ class DecodeScheduler:
         executable per power-of-two bucket, exactly the ``generate()``
         compile set."""
         import jax
+        import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
@@ -417,13 +455,23 @@ class DecodeScheduler:
         def admit(params, pool, page_ids, prompt_pad, t0, temp, top_p,
                   ctr):
             kv = []
-            x = di.stack(params, prompt_pad, model, di.causal_prefill(
-                model, lambda li, k, v: kv.append((k, v))), "prefill")
+            attend = (di.causal_prefill(
+                model, lambda li, k, v: kv.append((k, v)))
+                if self.latent is None else di.latent_prefill(
+                    model, lambda li, rows: kv.append(rows)))
+            pairs = [] if self.expert_layers else None
+            # the experts route the prompt's rows, not the bucket's
+            # padding
+            x = di.stack(params, prompt_pad, model, attend, "prefill",
+                         counts=pairs,
+                         live=jnp.arange(prompt_pad.shape[1])[None] < t0)
             row = jax.lax.dynamic_index_in_dim(x, t0 - 1, axis=1,
                                                keepdims=False)
-            return (self.pager.write_prompt(pool, page_ids, kv),
-                    self._first_token(params, row, "prefill", temp,
-                                      top_p, ctr))
+            out = (self.pager.write_prompt(pool, page_ids, kv),
+                   self._first_token(params, row, "prefill", temp,
+                                     top_p, ctr))
+            return out + (sum(jnp.sum(p) for p in pairs),) if pairs \
+                else out
         return sentry.jit(admit, name="serving.prefill",
                           donate_argnums=(1,))
 
@@ -606,7 +654,7 @@ class DecodeScheduler:
                         jnp.asarray(c * tb, jnp.int32), *tail)
                     self.pager.pool = pool
             else:
-                pool, g0 = fn(
+                pool, g0, *pairs = fn(
                     params, self.pager.pool,
                     jnp.asarray(np.asarray(pages[:tb // self.block],
                                            np.int32)),
@@ -614,6 +662,8 @@ class DecodeScheduler:
                 self.pager.pool = pool
             ts2 = obs.now()
             first = int(np.asarray(g0)[0])  # blocking device sync
+            pairs = int(np.asarray(pairs[0])) if (
+                not self.recurrent and pairs) else 0
         except BaseException:
             # a failed prefill must not leak the reservation (the
             # slot was never occupied; its table row resets)
@@ -625,9 +675,12 @@ class DecodeScheduler:
         obs.record_step("serving.prefill", ts0, ts1, ts2, ts3,
                         args={"bucket": tb, "t0": t0, "slot": slot,
                               "chunks": n_chunks,
+                              **({"expert_pairs": pairs}
+                                 if self.expert_layers else {}),
                               "rid": getattr(req, "rid", None)},
                         cause=self.cause)
         obs.metrics.SERVING_PREFILL.observe(ts3 - ts0)
+        obs.metrics.SERVING_EXPERT_PAIRS.inc(pairs)
         if self.prefix_sharing:
             # publish this prompt's page chain so later admissions
             # with the same prefix can adopt the pages instead of
@@ -814,12 +867,17 @@ class DecodeScheduler:
         # in flight adds to it: no device read (a retention model
         # walks no KV page: it moves its live slots' states, once
         # each way)
-        kv_pages = 0 if self.recurrent else int(
-            np.sum((self._lengths[act] + pending) // self.block + 1))
+        paged = not self.recurrent and self.latent is None
+        kv_pages = int(np.sum((self._lengths[act] + pending)
+                              // self.block + 1)) if paged else 0
         state_bytes = (len(act) * self.state_bytes_per_slot
                        if self.recurrent else 0)
+        # cached positions a latent step's attention reads, the one
+        # being written included
+        latent_rows = 0 if self.latent is None else int(
+            np.sum(self._lengths[act] + pending + 1))
         ts1 = obs.now()
-        nxt, pool, len_next = self._step_fn(
+        nxt, pool, len_next, *pairs = self._step_fn(
             self.model.decode_params(self.net), self.pager.pool,
             f["pt"], f["lengths"], f["active"], f["prev"], f["temps"],
             f["top_p"], jnp.asarray(self._ctr, jnp.int32))
@@ -827,22 +885,31 @@ class DecodeScheduler:
         # feed the step's own outputs back: no h2d on the clean path
         f["prev"], f["lengths"] = nxt, len_next
         self._inflight = _InFlight(
-            nxt, [(i, self._slots[i]) for i in act], ts0)
+            nxt, [(i, self._slots[i]) for i in act], ts0,
+            pairs[0] if pairs else None)
         ts2 = obs.now()
         # the blocking read: the predecessor's tokens, while the step
         # just launched runs; that step's own where the mode needs
         # them before its next launch
         due = prior if self._run_ahead else self._inflight
-        n, ts3 = self._collect(due) if due is not None else (0, ts2)
+        n, ts3, experts = (self._collect(due) if due is not None
+                           else (0, ts2, (0, 0, 0)))
         # ``deliver`` (ts3 → here) is the push/retire loop of the step
-        # that was read
+        # that was read; the expert counts are that step's too (the
+        # host learns them with its tokens), the others the launched
+        # step's
+        args = {"active": len(act), "kv_pages": kv_pages,
+                "state_bytes": state_bytes, "ahead": pending}
+        if self.latent is not None:
+            args["latent_rows"] = latent_rows
+        if self.expert_layers:
+            (args["expert_pairs"], args["experts_hit"],
+             args["expert_pairs_max"]) = experts
         obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
-                        args={"active": len(act), "kv_pages": kv_pages,
-                              "state_bytes": state_bytes,
-                              "ahead": pending},
-                        cause=self.cause, end=obs.now())
+                        args=args, cause=self.cause, end=obs.now())
         obs.metrics.SERVING_KV_WALKED.set(kv_pages)
         obs.metrics.SERVING_STATE_MOVED.inc(state_bytes)
+        obs.metrics.SERVING_LATENT_ROWS.inc(latent_rows)
         obs.metrics.SERVING_AHEAD.inc(pending)
         return n
 
@@ -855,11 +922,20 @@ class DecodeScheduler:
         finished. Observes ``SERVING_STEP`` once a device step with
         the wall time this step added: from the read before it, or
         from its own launch where that came later. Returns ``(tokens
-        delivered, when the read returned)``."""
+        delivered, when the read returned, an expert model's (pairs
+        its held experts computed, held experts hit, the fullest
+        expert's pairs) over the expert layers)``."""
         if self._inflight is fl:
             self._inflight = None
         toks = np.asarray(fl.nxt)       # blocking device sync
         t_read = obs.now()
+        experts = (0, 0, 0)
+        if fl.pairs is not None:
+            # computed by the same program: ready when the tokens are
+            pairs = np.asarray(fl.pairs)
+            experts = (int(pairs.sum()), int((pairs > 0).sum()),
+                       int(pairs.max(axis=1).sum()))
+            obs.metrics.SERVING_EXPERT_PAIRS.inc(experts[0])
         self.steps += 1
         n = 0
         for i, s in fl.slots:
@@ -886,7 +962,7 @@ class DecodeScheduler:
         self._t_read = t_read
         obs.metrics.SERVING_TOKENS.inc(n)
         self.tokens_out += n
-        return n, t_read
+        return n, t_read, experts
 
     def drain(self) -> int:
         """Read the step in flight, if there is one, outside a launch:
@@ -897,7 +973,7 @@ class DecodeScheduler:
         if fl is None:
             return 0
         t0 = obs.now()
-        n, _ = self._collect(fl)
+        n, *_ = self._collect(fl)
         obs.record("serving.drain", t0, obs.now(), self.cause, tokens=n)
         return n
 

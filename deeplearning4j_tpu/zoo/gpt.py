@@ -31,7 +31,7 @@ from deeplearning4j_tpu.nn.layers import (EmbeddingSequenceLayer,
                                           TransformerDecoderBlock)
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.nn import updaters as upd
-from deeplearning4j_tpu.ops import retention
+from deeplearning4j_tpu.ops import moe, retention
 
 
 def prompt_bucket(t0: int, max_len: Optional[int] = None) -> int:
@@ -43,6 +43,12 @@ def prompt_bucket(t0: int, max_len: Optional[int] = None) -> int:
     retrace on the first live request."""
     tb = max(16, 1 << (max(int(t0), 1) - 1).bit_length())
     return tb if max_len is None else min(tb, max_len)
+
+
+def _stays_float32(path) -> bool:
+    """A leaf the compute dtype does not reach: an expert layer's
+    router (``ops.moe.FLOAT32_LEAVES``)."""
+    return getattr(path[-1], "key", None) in moe.FLOAT32_LEAVES
 
 
 @jax.tree_util.register_pytree_node_class
@@ -115,13 +121,37 @@ class CausalTransformerLM(ZooModel):
                  cache_quant: Optional[str] = None,
                  seed: int = 123, updater=None,
                  compute_dtype: Optional[str] = None,
-                 mixer: str = "softmax"):
+                 mixer: str = "softmax", latent=None, experts=None):
         # the blocks' sequence mixer: "softmax" attention over a KV
-        # cache, or "power_retention" (ops/retention.py): a fixed-size
-        # recurrent state per sequence, whatever its length
-        if mixer not in ("softmax", "power_retention"):
-            raise ValueError(f"mixer={mixer!r} "
-                             "('softmax' | 'power_retention')")
+        # cache, "power_retention" (ops/retention.py): a fixed-size
+        # recurrent state per sequence, whatever its length, or
+        # "latent" (ops/latent.py, sized by ``latent``, a
+        # ``LatentSpec``): one compressed row a cached position
+        if mixer not in ("softmax", "power_retention", "latent"):
+            raise ValueError(
+                f"mixer={mixer!r} "
+                "('softmax' | 'power_retention' | 'latent')")
+        if (mixer == "latent") != (latent is not None):
+            raise ValueError("mixer='latent' and latent=LatentSpec(...) "
+                             "come together")
+        if mixer == "latent" and (cache_quant or sequence_parallel
+                                  or serve_quant):
+            raise ValueError(
+                "mixer='latent' caches one compressed row a position "
+                "in the compute dtype: cache_quant, serve_quant and "
+                "sequence_parallel do not apply to it")
+        if experts is not None and serve_quant:
+            raise ValueError(
+                "serve_quant quantises 2-D matrices: an expert layer's "
+                "float32 router and its stacked experts have no int8 "
+                "form here")
+        #: ``ops.latent.LatentSpec`` of a latent mixer, else None
+        self.latent = latent
+        #: ``ops.moe.ExpertSpec``: the layers after its ``first_dense``
+        #: route over all published experts and compute the ones this
+        #: chip holds, beside the shared expert; None: every
+        #: feed-forward is a dense SwiGLU
+        self.experts = experts
         if mixer == "power_retention" and (cache_quant
                                            or sequence_parallel):
             raise ValueError(
@@ -178,13 +208,17 @@ class CausalTransformerLM(ZooModel):
              .layer(EmbeddingSequenceLayer(n_in=self.vocab_size,
                                            n_out=self.hidden,
                                            weight_init="normal")))
-        for _ in range(self.n_layers):
+        for i in range(self.n_layers):
+            routed = (self.experts is not None
+                      and i >= self.experts.first_dense)
             b.layer(TransformerDecoderBlock(
                 n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
                 ffn_mult=self.ffn_mult, rope_theta=self.rope_theta,
                 dropout=self.dropout or None, remat=self.remat,
                 sequence_parallel=self.sequence_parallel,
-                mixer=self.mixer))
+                mixer=self.mixer, latent=self.latent,
+                ffn="experts" if routed else "dense",
+                experts=self.experts if routed else None))
         b.layer(RMSNorm())
         # fused-from-logits sparse softmax CE over the vocabulary —
         # integer next-token labels, no [B,T,V] one-hot materialised
@@ -348,8 +382,9 @@ class CausalTransformerLM(ZooModel):
         transformer analog of the reference's rnnTimeStep; any drift
         from TransformerDecoderBlock's training forward is caught by
         test_generate_matches_training_forward)."""
-        cache = (di.DenseState if self.mixer == "power_retention"
-                 else di.DenseKV)(self, caches, pos)
+        cache = {"power_retention": di.DenseState,
+                 "latent": di.DenseLatent}.get(
+                     self.mixer, di.DenseKV)(self, caches, pos)
         x = di.stack(params, tok, self, cache.attend, "decode")
         return di.logits(params, x, self, "decode"), tuple(cache.caches)
 
@@ -369,12 +404,20 @@ class CausalTransformerLM(ZooModel):
                     bsz, self.n_kv_heads,
                     self.hidden // self.n_heads)] * self.n_layers)
             caches, attend = cache.caches, cache.attend
+        elif self.mixer == "latent":
+            caches = []
+            attend = di.latent_prefill(
+                self, lambda li, rows: caches.append(jnp.pad(
+                    rows, ((0, 0), (0, cache_len - tb), (0, 0)))))
         else:
             caches = []
             attend = di.causal_prefill(
                 self, lambda li, k, v: caches.append(di.dense_kv(
                     k, v, cache_len, bool(self.cache_quant))))
-        x = di.stack(params, toks, self, attend, "prefill")
+        # (expert layers route the prompt's rows, not the padding)
+        x = di.stack(params, toks, self, attend, "prefill",
+                     live=jnp.broadcast_to(jnp.arange(tb)[None] < t0,
+                                           toks.shape))
         x_last = jax.lax.dynamic_index_in_dim(x, t0 - 1, axis=1,
                                               keepdims=False)
         return di.logits(params, x_last, self, "prefill"), tuple(caches)
@@ -406,8 +449,9 @@ class CausalTransformerLM(ZooModel):
             params = out
         if self.compute_dtype is not None:
             from deeplearning4j_tpu import dtypes
-            params = jax.tree.map(
-                lambda w: w if isinstance(w, QuantizedWeight)
+            params = jax.tree_util.tree_map_with_path(
+                lambda path, w: w if (isinstance(w, QuantizedWeight)
+                                      or _stays_float32(path))
                 else dtypes.cast_float_tree(w, self.compute_dtype),
                 params,
                 is_leaf=lambda x: isinstance(x, QuantizedWeight))
@@ -431,10 +475,13 @@ class CausalTransformerLM(ZooModel):
         is: no copy is made."""
         if self.compute_dtype is None and self.serve_quant is None:
             return net.params
-        leaves = jax.tree.leaves(net.params)
+        flat = jax.tree_util.tree_leaves_with_path(net.params)
+        leaves = [l for _, l in flat]
         if self.serve_quant is None and all(
-                l.dtype == jnp.dtype(self.compute_dtype) for l in leaves
-                if jnp.issubdtype(l.dtype, jnp.floating)):
+                l.dtype == jnp.dtype(self.compute_dtype)
+                for path, l in flat
+                if jnp.issubdtype(l.dtype, jnp.floating)
+                and not _stays_float32(path)):
             # weights served from a checkpoint already in the compute
             # dtype: the cast would make a second copy of every leaf
             return net.params

@@ -15,6 +15,7 @@ import pytest
 from deeplearning4j_tpu.nn.layers.base import (_LAYER_REGISTRY,
                                                layer_from_dict)
 from deeplearning4j_tpu.nn import layers as L
+from deeplearning4j_tpu.ops.latent import LatentSpec
 
 KEY = jax.random.PRNGKey(3)
 
@@ -77,6 +78,9 @@ SPECS = {
     "RecurrentAttentionLayer": (dict(n_out=4, n_heads=2), (5, 4)),
     "MultiHeadAttention": (dict(n_out=4, n_heads=2), (5, 4)),
     "PowerRetention": (dict(n_heads=2, n_kv_heads=1), (5, 16)),
+    "LatentAttention": (dict(n_heads=2, spec=LatentSpec(
+        q_rank=6, kv_rank=4, nope=4, rope=2, v=4,
+        yarn=(4.0, 8, 32.0, 1.0, 1.0, 1.0))), (5, 8)),
     "TransformerEncoderBlock": (dict(n_heads=2, ffn_mult=2), (5, 4)),
     "PositionalEmbeddingLayer": ({}, (5, 4)),
     "ClsTokenPoolLayer": ({}, (5, 4)),

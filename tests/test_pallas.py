@@ -360,6 +360,92 @@ def test_paged_decode_matches_reference(monkeypatch, rng, dtype, tol,
     assert (out[~live] == 0).all()          # inactive: zeros, no walk
 
 
+# ---------------------------------------------------------------------------
+# latent decode attention
+# ---------------------------------------------------------------------------
+def _latent_case(rng, dtype, h, kv_rank, rope, block, mp, n_live,
+                 layers=2):
+    """As :func:`_paged_case`, for the latent pool: a live row is
+    ``[latent | rotary key | zero tail]`` up to whole 128-lane tiles,
+    the trash page and every page no table row names hold NaN."""
+    s_ = len(n_live)
+    width = kv_rank + rope
+    stored = -(-width // 128) * 128
+    pool = np.full((layers, 1 + s_ * mp, block, stored), np.nan,
+                   np.float32)
+    pt = np.zeros((s_, mp), np.int32)
+    for i, n in enumerate(n_live):
+        used = -(-n // block)
+        pt[i, :used] = 1 + i * mp + np.arange(used)
+        pool[:, pt[i, :used], :, :width] = rng.standard_normal(
+            (layers, used, block, width))
+        pool[:, pt[i, :used], :, width:] = 0.0
+    q = jnp.asarray(rng.standard_normal((s_, h, width)), dtype)
+    return (q, jnp.asarray(pool, dtype), jnp.asarray(pt),
+            jnp.asarray(n_live, jnp.int32))
+
+
+@pytest.mark.parametrize("pages_per_chunk", [2, None])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_latent_decode_matches_reference(monkeypatch, rng, dtype, tol,
+                                         pages_per_chunk):
+    """The kernel (interpret mode) against the registered fallback,
+    layer 1 of 2: 16 absorbed query heads over rows of 128 + 64 values
+    stored 256 wide, block 16, 6 pages a slot."""
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    q, pool, pt, n_live = _latent_case(rng, dtype, 16, 128, 64, 16, 6,
+                                       _PAGED_N_LIVE)
+    assert pool.shape[-1] == 256
+    padded = jnp.pad(q, ((0, 0), (0, 0), (0, 64)))
+    assert pk._use_latent_kernel(padded, pool, 128)
+    out = np.asarray(pk.latent_decode_attention(
+        q, pool, 1, pt, n_live, 0.11, 128,
+        pages_per_chunk=pages_per_chunk), np.float32)
+    ref = np.asarray(pk._reference_latent_attention(
+        padded, jnp.nan_to_num(pool), 1, pt, n_live, 0.11, 128),
+        np.float32)
+    live = np.asarray(n_live) > 0
+    assert out.shape == (8, 16, 128)
+    assert not np.isnan(out).any()          # trash was never read
+    assert np.abs(out[live] - ref[live]).max() < tol
+    assert (out[~live] == 0).all() and (ref[~live] == 0).all()
+
+
+def test_latent_decode_dispatch_line(monkeypatch, rng):
+    """The kernel takes a pool in the query's dtype whose stored rows
+    and latent are whole 128-lane tiles, on the kernel platform; every
+    other shape runs the reference, and says the same."""
+    calls = []
+    real = pk._latent_decode_call
+    monkeypatch.setattr(
+        pk, "_latent_decode_call",
+        lambda *a, **kw: calls.append(a[0].shape) or real(*a, **kw))
+
+    def run(kv_rank, rope, pool_dtype=jnp.float32):
+        q, pool, pt, n_live = _latent_case(rng, jnp.float32, 4, kv_rank,
+                                           rope, 8, 3, (5, 0, 24))
+        pool = jnp.nan_to_num(pool).astype(pool_dtype)
+        out = pk.latent_decode_attention(q, pool, 0, pt, n_live, 0.2,
+                                         kv_rank)
+        ref = pk._reference_latent_attention(
+            jnp.pad(q, ((0, 0), (0, 0),
+                        (0, pool.shape[-1] - q.shape[-1]))),
+            pool, 0, pt, n_live, 0.2, kv_rank)
+        assert out.shape == (3, 4, kv_rank)
+        assert float(jnp.abs(out[0] - ref[0]).max()) < 2e-5
+        assert float(jnp.abs(out[1]).max()) == 0.0      # inactive
+        return len(calls)
+
+    assert run(128, 64) == 0                # CPU: the fallback
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    assert run(128, 64) == 1                # over the line: the kernel
+    assert run(256, 32) == 2
+    assert run(64, 64) == 2                 # latent under a lane tile
+    assert run(128, 64, jnp.bfloat16) == 2  # pool not the query's dtype
+
+
 def test_paged_decode_dispatch_line(monkeypatch, rng):
     """Which shapes the kernel takes is read off the operands: a
     128-lane head over 8-row kv tiles in a float pool on the kernel

@@ -178,6 +178,18 @@ class _Req:
         raise e
 
 
+def _own_records(mark):
+    """The ring's records since ``mark`` that THIS thread made. The
+    ring is the process's: a gateway that another test of the same
+    worker left decoding on its own thread adds ``serving.decode_step``
+    records of its own, and "the last one" was then not ours."""
+    import threading
+
+    from deeplearning4j_tpu import obs
+    me = threading.get_ident()
+    return [r for r in obs.trace.records(since=mark) if r.tid == me]
+
+
 _TOY = dict(num_hidden_layers=2, num_attention_heads=4,
             num_key_value_heads=2, head_dim=16, rope_theta=10000.0,
             rms_norm_eps=1e-6,
@@ -284,7 +296,7 @@ def test_records_count_state_bytes_and_chunks(retention_lm, monkeypatch):
     for r in reqs:
         assert sched.admit(r)
     sched.step()
-    recs = obs.trace.records(since=mark)
+    recs = _own_records(mark)
     chunks = [r.counts["chunks"] for r in recs
               if r.name == "serving.prefill"]
     assert chunks == [3, 1]
@@ -350,7 +362,7 @@ def test_row_launched_ahead_rewrites_its_own_state_page_only(
     sched.step()                    # launches the fourth, reads the third
     assert x.done and len(x.tokens) == 4 and len(nb.tokens) == 4
     assert sched._inflight is not None and not sched.pager.owned(x)
-    rec = [r for r in obs.trace.records(since=mark)
+    rec = [r for r in _own_records(mark)
            if r.name == "serving.decode_step"][-1]
     live = 2 if ends_by == "eos" else 1
     assert rec.counts["ahead"] == 1 and rec.counts["active"] == live

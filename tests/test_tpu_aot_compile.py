@@ -410,3 +410,103 @@ def test_softmax_decode_step_holds_nothing_of_the_retention_path(chip):
           for a in sched._step_feed_shapes())).as_text()
     assert "retention" not in text
     assert "paged_decode.block_1" in text or "paged_decode" in text
+
+
+# -- the latent, mixture-of-experts cell: 64 slots x 5 layers ---------------
+
+def _latent_sched(chip):
+    """The scheduler and the shapes of ``deepseekv3.decode-saturated``:
+    the configuration as the benchmark's builder reads it, bf16 leaves
+    and a float32 router, nothing of the 4.6 B parameters made."""
+    import json
+    from pathlib import Path
+    from benchmarks.models import latent_moe_lm as builder
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.ops.moe import FLOAT32_LEAVES
+    from deeplearning4j_tpu.serving import DecodeScheduler
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmarks" / "configs"
+                      / "deepseek-v3-5l-ep16.json").read_text())
+    latent, experts = builder.specs(cfg)
+    model = CausalTransformerLM(
+        vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"], max_len=8192,
+        ffn_mult=cfg["intermediate_size"] / cfg["hidden_size"],
+        rope_theta=float(cfg["rope_theta"]), tie_embeddings=False,
+        updater=upd.Sgd(learning_rate=0.0), compute_dtype="bfloat16",
+        seed=1, mixer="latent", latent=latent, experts=experts)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda: model.init().params))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        jax.ShapeDtypeStruct(
+            s.shape, jnp.float32 if getattr(path[-1], "key", None)
+            in FLOAT32_LEAVES else BF16, sharding=chip)
+        for path, s in flat])
+    sched = DecodeScheduler(model, None, max_slots=64, block=16,
+                            max_context=6144, n_pages=2)
+    pool = jax.ShapeDtypeStruct((5, 1 + 64 * 384, 16, 640), BF16,
+                                sharding=chip)
+    return sched, params, pool
+
+
+def test_latent_decode_step_compiles_for_v5e_without_touching_the_pool(
+        chip):
+    """The whole ``serving.decode_step`` of the latent cell: one
+    ``latent_decode_attention`` kernel a layer, lowered ONCE; nothing
+    of the pool's size but each layer's in-place scatter of the new
+    row (no gather of the pool, no copy in front of the kernel); the
+    experts' loop multiplies a slice of the stacked experts where it
+    lies; 11.66 GB of arguments (9.15 of weights, 2.52 of pool, whose
+    576-wide rows take 640 lanes) and a few MB of temporaries."""
+    import re
+    sched, params, pool = _latent_sched(chip)
+    assert sched.pager.pool[0].shape[2:] == pool.shape[2:]
+    lowered = sched._step_fn.lower(
+        params, (pool,),
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes()))
+    funcs = re.findall(r"func\.func private @(\w*latent_decode\w*)",
+                       lowered.as_text())
+    assert len(funcs) == 1, funcs           # one lowering for 5 layers
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    kernels = re.findall(
+        r"= \S+ custom-call\([^\n]*tpu_custom_call[^\n]*"
+        r"latent_decode_attention", hlo)
+    assert len(kernels) == 5, len(kernels)
+    assert set(_pool_sized_ops(hlo, pool)) <= {
+        "parameter", "fusion", "scatter", "bitcast"}, \
+        _pool_sized_ops(hlo, pool)
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    for comp in re.split(r"\n(?=\S)", hlo):
+        if shape in comp and not comp.startswith("ENTRY"):
+            assert " gather(" not in comp and " copy(" not in comp, \
+                comp[:400]
+    # four expert layers, one loop each; an expert's matrices reach
+    # the matmul as a slice of the stack, never as a copy of their own
+    assert len(re.findall(r"= \([^\n]*\) while\(", hlo)) == 4
+    assert not re.findall(r"= bf16\[7168,2048\]\S* copy\(", hlo)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+    assert mem.alias_size_in_bytes >= 2.5e9         # the pool, in place
+    assert 11.6e9 < mem.argument_size_in_bytes < 11.7e9, mem
+
+
+def test_latent_prefill_bucket_compiles_for_v5e(chip):
+    """The 4,096-row bucket, the largest the cell admits: the expanded
+    form in blocks of rows and the experts' tiles fit beside weights
+    and pool (1.4 GB of temporaries, by the compiler's count)."""
+    sched, params, pool = _latent_sched(chip)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    compiled = sched._admit_fn(4096).lower(
+        params, (pool,), sds((256,), i32), sds((1, 4096), i32),
+        sds((), i32), sds((), f32), sds((), f32), sds((), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2.5e9
+    assert mem.temp_size_in_bytes < 2 * 10 ** 9, mem

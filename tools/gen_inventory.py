@@ -460,6 +460,26 @@ step's attention read (the sum over active slots of
 `serving.decode_step` record): against `max_slots × max_context /
 block` page-table entries it is the share of the pool a step touches,
 and what the paged kernel's time should follow (ARCHITECTURE.md §17).
+A retention model (`mixer="power_retention"`) walks no KV page:
+`dl4j_tpu_serving_state_pool_bytes` is its recurrent-state pool as
+stored (one fixed-size page a sequence; 0 for a KV pool) and
+`dl4j_tpu_serving_state_bytes_moved` counts the state bytes its decode
+steps read and wrote (`state_bytes` on every `serving.decode_step`
+record): over the steps' device time it is the bandwidth the state
+kernel reaches (ARCHITECTURE.md §15).
+A latent-attention model (`mixer="latent"`) walks latent pages:
+`dl4j_tpu_serving_latent_rows_read_total` counts the cached positions
+its decode steps' attention read (`latent_rows` on every
+`serving.decode_step` record: one row of `kv_rank + rope` values a
+position and layer). Where its feed-forward routes,
+`dl4j_tpu_serving_expert_pairs_total` counts the token-expert pairs
+the experts HELD HERE computed, decode steps and prefills alike; a
+step's record carries `expert_pairs`, `experts_hit` (held experts with
+at least one pair, summed over the expert layers: what the step had to
+read of the experts' weights) and `expert_pairs_max` (the fullest held
+expert's pairs, summed over the layers: max over mean is the load
+imbalance). These three are of the step whose tokens that call READ;
+`latent_rows` is of the step it launched.
 """
 
 # hand-maintained operations doc, re-emitted on every regeneration

@@ -242,6 +242,7 @@ SCOPE_SITES = {
     "ops/pallas_kernels.py": ("flash_attention", "flash_block_fwd",
                               "flash_block_bwd",
                               "paged_decode_attention",
+                              "latent_decode_attention",
                               "retention_decode",
                               "threshold_encode", "threshold_decode"),
     "ops/fused_norms.py": ("rms_norm", "add_rms_norm", "layer_norm"),

@@ -27,7 +27,8 @@ Kernels:
   the multi-row paged programs.
 - ``latent_decode_attention`` — the same page walk over the paged
   LATENT pool of a latent-attention model: a page is the ``[block,
-  kv_rank + rope]`` matrix every absorbed query head reads at once.
+  kv_rank + rope]`` matrix every absorbed query head reads at once,
+  and the walk is one pipeline over all slots of a call.
 - ``threshold_encode`` / ``threshold_decode`` — fused gradient
   threshold compression (reference libnd4j ops ``encode_threshold`` /
   ``decode_threshold``): one VMEM pass computes the ternary
@@ -1098,67 +1099,114 @@ def paged_decode_attention(q, pool, li, pt, n_live,
 # tiles (the pager pads a row's tail with zeros: the TPU tiles the
 # minor dimension by 128 lanes, so a 576-wide row takes 640 in HBM
 # either way, and Mosaic slices whole tiles only).
+#
+# Unlike ``_paged_decode_kernel`` the walk is ONE software pipeline
+# over all (slot, chunk) items of a call, in slot order: while an
+# item's rows are multiplied, the NEXT item's pages are in flight into
+# the buffer's other half, be that the same slot's next chunk or the
+# first chunk of the next live slot. The grid stays one step a slot
+# (its output block is the slot's); the buffer, its semaphores and the
+# copies in flight carry over the steps' edges. Only a call's first
+# item is waited for with nothing to multiply meanwhile.
 
 #: positions of a chunk folded per loop iteration
 _LATENT_CHUNK_ROWS = 1024
 
 
+def latent_chunk_pages(block: int, max_pages: int) -> int:
+    """Pages of one (slot, chunk) item of the kernel's walk, from the
+    operands' shapes (the scheduler counts a step's items by it)."""
+    return min(max(1, _LATENT_CHUNK_ROWS // block), max_pages)
+
+
 def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
-                          buf, sem, m, l, acc, *, scale: float,
+                          buf, sem, turn, m, l, acc, *, scale: float,
                           block: int, chunk: int, max_pages: int,
-                          kv_rank: int):
+                          n_pool: int, kv_rank: int):
     # li_ref [1], pt_ref [S*MP], n_ref [S]: scalar-prefetch operands in
     # SMEM; q_ref [H, W], o_ref [H, kv_rank] (this slot's blocks);
-    # pool_ref [L, P, block, W], left in HBM; buf [2, chunk, block, W]
+    # pool_ref [L*P, block, W], left in HBM; buf [2*chunk, block, W],
+    # its halves end to end; turn [1] in SMEM: the half the next item
+    # multiplied lies in. Pool and buffer are indexed by ONE page
+    # number each, its terms that do not change over an item summed
+    # once in front of the loop: the scalar core issues a copy in 12
+    # instruction bundles (37 with a layer and a half to multiply out
+    # a page, and the bounds checks `_latent_decode_call` turns off)
     b = pl.program_id(0)
-    li = li_ref[0]
+    n_slots = pl.num_programs(0)
     n_pos = n_ref[b]                  # live positions; 0 = inactive
-    n_pages = (n_pos + block - 1) // block
-    n_chunks = (n_pages + chunk - 1) // chunk
-    h, width = q_ref.shape
     rows = chunk * block
+    n_chunks = (n_pos + rows - 1) // rows
+    width = q_ref.shape[1]
+    pool0 = li_ref[0] * n_pool
+
+    def live_from(j):
+        # the first live slot at or after j; S where there is none
+        return lax.while_loop(
+            lambda j: (j < n_slots)
+            & (n_ref[jnp.minimum(j, n_slots - 1)] == 0),
+            lambda j: j + 1, j)
+
+    def n_pages(s, c):
+        return jnp.minimum(chunk, (n_ref[s] + block - 1) // block
+                           - c * chunk)
+
+    def issue(s, c, half):
+        # the page copies of item (slot s, chunk c) into buf's half
+        first = s * max_pages + c * chunk
+        buf0 = half * chunk
+        done = sem.at[half]
+
+        def page(j, carry):
+            pltpu.make_async_copy(pool_ref.at[pool0 + pt_ref[first + j]],
+                                  buf.at[buf0 + j], done).start()
+            return carry
+
+        lax.fori_loop(0, n_pages(s, c), page, 0)
+
+    def await_(n, half):
+        # a copy adds its bytes to the half's semaphore and a wait
+        # takes its descriptor's bytes off: one wait a set bit of the
+        # item's page count ``n``, not one a page
+        k = 1
+        while k <= chunk:
+            @pl.when(n & k != 0)
+            def _(k=k):
+                pltpu.make_async_copy(pool_ref.at[pl.ds(0, k)],
+                                      buf.at[pl.ds(0, k)],
+                                      sem.at[half]).wait()
+            k *= 2
 
     @pl.when(b == 0)
     def _():
         # an unfetched tail meets p == 0 in the p·V matmul, and
         # 0 · NaN is NaN (as in ``_paged_decode_kernel``)
         buf[...] = jnp.zeros_like(buf)
+        turn[0] = 0
+        first = live_from(0)
+
+        @pl.when(first < n_slots)
+        def _():
+            issue(first, 0, 0)
 
     m[...] = jnp.full_like(m, -jnp.inf)
     l[...] = jnp.zeros_like(l)
     acc[...] = jnp.zeros_like(acc)
 
-    def chunk_dma(c, slot, go):
-        def page(j, carry):
-            pid = pt_ref[b * max_pages + c * chunk + j]
-            go(pltpu.make_async_copy(pool_ref.at[li, pid],
-                                     buf.at[slot, j], sem.at[slot]))
-            return carry
-
-        lax.fori_loop(0, jnp.minimum(chunk, n_pages - c * chunk), page,
-                      0)
-
-    @pl.when(n_chunks > 0)
-    def _():
-        chunk_dma(0, 0, lambda cp: cp.start())
-
-    rel = lax.broadcasted_iota(jnp.int32, (h, rows), 1)
     contract = (((1,), (1,)), ((), ()))
+    # the slot whose first chunk follows this one's last; an inactive
+    # slot walks nothing and looks for nothing
+    after = live_from(jnp.where(n_pos > 0, b + 1, n_slots))
+    half0 = turn[0]
 
-    def body(c, carry):
-        slot = c % 2
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            chunk_dma(c + 1, 1 - slot, lambda cp: cp.start())
-
-        chunk_dma(c, slot, lambda cp: cp.wait())
-        kv = buf[slot].reshape(rows, width)
+    def fold(kv, left):
+        # kv [R, W]: the chunk's first R rows, ``left`` of them live
         s = lax.dot_general(q_ref[...], kv, contract,
                             preferred_element_type=jnp.float32)
+        rel = lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # every chunk walked holds a live position, so the running
         # maximum is finite from the first on
-        s = jnp.where(rel < n_pos - c * rows, s * scale, -jnp.inf)
+        s = jnp.where(rel < left, s * scale, -jnp.inf)
         m_prev = m[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -1170,9 +1218,33 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
             p.astype(kv.dtype), kv[:, :kv_rank],
             preferred_element_type=jnp.float32)
         m[...] = jnp.broadcast_to(m_new, m.shape)
+
+    # a chunk is multiplied up to the quarter that holds its last live
+    # row: the matmuls' shapes are static, so each size is a branch
+    sizes = sorted({-(-chunk * k // 4) for k in range(1, 5)})
+
+    def body(c, carry):
+        half = (half0 + c) % 2
+        more = c + 1 < n_chunks
+        s_next = jnp.where(more, b, after)
+
+        @pl.when(s_next < n_slots)
+        def _():
+            issue(s_next, jnp.where(more, c + 1, 0), 1 - half)
+
+        pages = n_pages(b, c)
+        await_(pages, half)
+        left = n_pos - c * rows
+        buf0 = pl.multiple_of(half * chunk, chunk)
+        for lo, hi in zip([0] + sizes, sizes):
+            @pl.when((lo < pages) & (pages <= hi))
+            def _(hi=hi):
+                fold(buf[pl.ds(buf0, hi)].reshape(hi * block, width),
+                     left)
         return carry
 
     lax.fori_loop(0, n_chunks, body, 0)
+    turn[0] = (half0 + n_chunks) % 2
     o_ref[...] = (acc[...] / jnp.maximum(l[:, :1], 1e-30)
                   ).astype(o_ref.dtype)
 
@@ -1184,13 +1256,13 @@ def _latent_decode_call(q, pool, li, pt, n_live, scale, kv_rank,
     """ONE lowering for every layer of a step (the layer index is a
     scalar operand), as ``_paged_decode_call``."""
     s_, h, width = q.shape
-    _, _, block, _ = pool.shape
+    n_l, n_p, block, _ = pool.shape
     mp = pt.shape[1]
     chunk = pages_per_chunk
     return pl.pallas_call(
         functools.partial(_latent_decode_kernel, scale=scale,
                           block=block, chunk=chunk, max_pages=mp,
-                          kv_rank=kv_rank),
+                          n_pool=n_p, kv_rank=kv_rank),
         out_shape=jax.ShapeDtypeStruct((s_, h, kv_rank), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -1201,18 +1273,27 @@ def _latent_decode_call(q, pool, li, pt, n_live, scale, kv_rank,
             out_specs=pl.BlockSpec((None, h, kv_rank),
                                    lambda b, *_: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, chunk, block, width), pool.dtype),
+                pltpu.VMEM((2 * chunk, block, width), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((h, 128), jnp.float32),
                 pltpu.VMEM((h, 128), jnp.float32),
                 pltpu.VMEM((h, kv_rank), jnp.float32),
             ]),
+        # the buffer and the copies in flight carry from slot to slot:
+        # the grid is a sequence, not a parallel map. The compiler's
+        # check of every copy's two addresses is two thirds of the
+        # scalar core's work a page (22% of the kernel's time): a page
+        # number is the pager's own, a buffer row the loop's counter
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         interpret=interpret,
         name="latent_decode_attention",
     )(li.reshape(1).astype(jnp.int32), pt.reshape(-1).astype(jnp.int32),
-      n_live.astype(jnp.int32), q, pool)
+      n_live.astype(jnp.int32), q,
+      # the layers' pages end to end: a bitcast, not a copy
+      pool.reshape(n_l * n_p, block, width))
 
 
 def _reference_latent_attention(q, pool, li, pt, n_live, scale, kv_rank):
@@ -1264,12 +1345,11 @@ def latent_decode_attention(q, pool, li, pt, n_live, scale: float,
         if not _use_latent_kernel(q, pool, kv_rank):
             return _reference_latent_attention(q, pool, li, pt, n_live,
                                                scale, kv_rank)
-        block = pool.shape[2]
-        chunk = pages_per_chunk or max(1, _LATENT_CHUNK_ROWS // block)
+        chunk = min(pages_per_chunk or latent_chunk_pages(
+            pool.shape[2], pt.shape[1]), pt.shape[1])
         return _latent_decode_call(
             q, pool, jnp.asarray(li, jnp.int32), pt, n_live,
-            scale=float(scale), kv_rank=kv_rank,
-            pages_per_chunk=min(chunk, pt.shape[1]),
+            scale=float(scale), kv_rank=kv_rank, pages_per_chunk=chunk,
             interpret=_interpret())
 
 

@@ -88,6 +88,7 @@ import numpy as np
 
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.nn import decoder_infer as di
+from deeplearning4j_tpu.ops.pallas_kernels import latent_chunk_pages
 from deeplearning4j_tpu.serving.kv_pager import KVPager, StateChunk
 from deeplearning4j_tpu.zoo.gpt import prompt_bucket
 
@@ -241,6 +242,10 @@ class DecodeScheduler:
                      "a latent row has no int8 form yet")):
                 if on:
                     raise ValueError(f"{name} with mixer='latent': {why}")
+            #: positions of one (slot, chunk) item of the decode
+            #: kernel's page walk
+            self._latent_chunk_rows = self.block * latent_chunk_pages(
+                self.block, self.max_pages_per_seq)
         #: expert layers of the model (their counts come back with
         #: every step's tokens)
         experts = getattr(model, "experts", None)
@@ -873,9 +878,14 @@ class DecodeScheduler:
         state_bytes = (len(act) * self.state_bytes_per_slot
                        if self.recurrent else 0)
         # cached positions a latent step's attention reads, the one
-        # being written included
-        latent_rows = 0 if self.latent is None else int(
-            np.sum(self._lengths[act] + pending + 1))
+        # being written included, and the (slot, chunk) items a
+        # layer's walk of them has (all but the first issued ahead)
+        latent_rows = latent_chunks = 0
+        if self.latent is not None:
+            lens = self._lengths[act] + pending + 1
+            latent_rows = int(np.sum(lens))
+            latent_chunks = int(np.sum(
+                -(-lens // self._latent_chunk_rows)))
         ts1 = obs.now()
         nxt, pool, len_next, *pairs = self._step_fn(
             self.model.decode_params(self.net), self.pager.pool,
@@ -902,6 +912,7 @@ class DecodeScheduler:
                 "state_bytes": state_bytes, "ahead": pending}
         if self.latent is not None:
             args["latent_rows"] = latent_rows
+            args["latent_chunks"] = latent_chunks
         if self.expert_layers:
             (args["expert_pairs"], args["experts_hit"],
              args["expert_pairs_max"]) = experts

@@ -532,9 +532,13 @@ def test_paged_latent_writes_its_own_pages_only(latent_lm):
     assert not after[..., SPEC.row:].any() and after[..., :SPEC.row].any()
 
 
-def test_records_count_latent_rows_and_expert_pairs(latent_lm):
+def test_records_count_latent_rows_and_expert_pairs(latent_lm,
+                                                    monkeypatch):
     from deeplearning4j_tpu import obs
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
     model, net = latent_lm
+    # the walk's items are counted by the kernel's own chunk: 2 pages
+    monkeypatch.setattr(pk, "_LATENT_CHUNK_ROWS", 32)
     sched = DecodeScheduler(model, net, max_slots=2, block=16,
                             max_context=96)
     mark = obs.now()
@@ -562,6 +566,11 @@ def test_records_count_latent_rows_and_expert_pairs(latent_lm):
     # the slots' lengths and the position each step writes
     assert first.counts["latent_rows"] == 41 + 8
     assert second.counts["latent_rows"] == 42 + 9
+    # 41 and 8 rows in chunks of 32: (2 + 1) items a layer's walk, of
+    # which all but the first are issued ahead; then 42 and 9
+    assert first.counts["latent_chunks"] == 2 + 1
+    assert second.counts["latent_chunks"] == 2 + 1
+    assert pk.latent_chunk_pages(16, 96 // 16) == 2
     c = second.counts
     assert c["ahead"] == 1 and c["kv_pages"] == c["state_bytes"] == 0
     # 2 rows x 2 expert layers, 4 a token of which some are held here

@@ -385,18 +385,37 @@ def _latent_case(rng, dtype, h, kv_rank, rope, block, mp, n_live,
             jnp.asarray(n_live, jnp.int32))
 
 
+# the walk is one pipeline over every (slot, chunk) item of a call:
+# what a slot's edge can get wrong, at 2 pages (32 rows) a chunk
+_LATENT_N_LIVE = {
+    "ragged": _PAGED_N_LIVE,
+    "first-inactive": (0, 40, 96, 5, 64, 33, 1, 17),
+    "last-inactive": (40, 96, 5, 64, 33, 1, 17, 0),
+    "inactive-between": (33, 0, 0, 64, 0, 1, 0, 96),
+    "none-live": (0,) * 8,          # zeros, no copy issued or awaited
+    "one-row": (1,) * 8,
+    "whole-chunks": (32, 64, 96, 32, 32, 64, 96, 96),
+    "chunk-plus-one-row": (33, 65, 33, 1, 65, 33, 65, 33),
+    # 1, 2 and 3 chunks in turn: the buffer's half flips, or does not,
+    # across every kind of slot edge
+    "chunks-1-2-3": (20, 50, 90, 30, 60, 96, 10, 40),
+}
+
+
+@pytest.mark.parametrize("n_live", list(_LATENT_N_LIVE.values()),
+                         ids=list(_LATENT_N_LIVE))
 @pytest.mark.parametrize("pages_per_chunk", [2, None])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 3e-2)],
                          ids=["float32", "bfloat16"])
 def test_latent_decode_matches_reference(monkeypatch, rng, dtype, tol,
-                                         pages_per_chunk):
+                                         pages_per_chunk, n_live):
     """The kernel (interpret mode) against the registered fallback,
     layer 1 of 2: 16 absorbed query heads over rows of 128 + 64 values
     stored 256 wide, block 16, 6 pages a slot."""
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     q, pool, pt, n_live = _latent_case(rng, dtype, 16, 128, 64, 16, 6,
-                                       _PAGED_N_LIVE)
+                                       n_live)
     assert pool.shape[-1] == 256
     padded = jnp.pad(q, ((0, 0), (0, 0), (0, 64)))
     assert pk._use_latent_kernel(padded, pool, 128)
@@ -409,7 +428,7 @@ def test_latent_decode_matches_reference(monkeypatch, rng, dtype, tol,
     live = np.asarray(n_live) > 0
     assert out.shape == (8, 16, 128)
     assert not np.isnan(out).any()          # trash was never read
-    assert np.abs(out[live] - ref[live]).max() < tol
+    assert np.abs(out[live] - ref[live]).max(initial=0.0) < tol
     assert (out[~live] == 0).all() and (ref[~live] == 0).all()
 
 

@@ -494,6 +494,39 @@ def test_latent_decode_step_compiles_for_v5e_without_touching_the_pool(
     assert 11.6e9 < mem.argument_size_in_bytes < 11.7e9, mem
 
 
+def test_latent_decode_kernel_compiles_alone_for_v5e(chip):
+    """The kernel alone at the cell's shapes and its own chunk, so
+    Mosaic's verdict on the walk is known before a chip run: the
+    scalar scan for the next live slot and the buffer's turn kept in
+    SMEM over the grid's steps, pool and buffer indexed by one page
+    number, one wait a bit of an item's page count, a fold a quarter
+    of the chunk; two halves of 1,024 rows x 640 lanes in VMEM. The
+    pool reaches the kernel as the parameter it is: the layers' pages
+    end to end are a bitcast."""
+    import re
+    s_, h, w, kv_rank, mp, block = 64, 128, 640, 512, 384, 16
+    pool = jax.ShapeDtypeStruct((5, 1 + s_ * mp, block, w), BF16,
+                                sharding=chip)
+    chunk = pallas_kernels.latent_chunk_pages(block, mp)
+    assert chunk * block == pallas_kernels._LATENT_CHUNK_ROWS
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    compiled = pallas_kernels._latent_decode_call.lower(
+        sds((s_, h, w), BF16), pool, sds((), jnp.int32),
+        sds((s_, mp), jnp.int32), sds((s_,), jnp.int32), scale=0.1147,
+        kv_rank=kv_rank, pages_per_chunk=chunk, interpret=False).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(
+        r"= \S+ custom-call\([^\n]*tpu_custom_call[^\n]*"
+        r"latent_decode_attention", hlo)) == 1
+    assert _pool_sized_ops(hlo, pool) == ["parameter"], hlo[:2000]
+    flat = jax.ShapeDtypeStruct((5 * pool.shape[1], block, w), BF16)
+    assert _pool_sized_ops(hlo, flat) == ["bitcast"], hlo[:2000]
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_latent_prefill_bucket_compiles_for_v5e(chip):
     """The 4,096-row bucket, the largest the cell admits: the expanded
     form in blocks of rows and the experts' tiles fit beside weights

@@ -471,7 +471,12 @@ A latent-attention model (`mixer="latent"`) walks latent pages:
 `dl4j_tpu_serving_latent_rows_read_total` counts the cached positions
 its decode steps' attention read (`latent_rows` on every
 `serving.decode_step` record: one row of `kv_rank + rope` values a
-position and layer). Where its feed-forward routes,
+position and layer; `latent_chunks` beside it counts the (slot, chunk)
+items a layer's page walk of that step has, by the decode kernel's own
+chunk: all but a call's first are copied while their predecessor is
+multiplied, and `latent_chunks` x the chunk's rows over `latent_rows`
+bounds the rows the kernel multiplies for one it needs; a record
+count only, no `/metrics` name). Where its feed-forward routes,
 `dl4j_tpu_serving_expert_pairs_total` counts the token-expert pairs
 the experts HELD HERE computed, decode steps and prefills alike; a
 step's record carries `expert_pairs`, `experts_hit` (held experts with
@@ -479,7 +484,7 @@ at least one pair, summed over the expert layers: what the step had to
 read of the experts' weights) and `expert_pairs_max` (the fullest held
 expert's pairs, summed over the layers: max over mean is the load
 imbalance). These three are of the step whose tokens that call READ;
-`latent_rows` is of the step it launched.
+`latent_rows` and `latent_chunks` are of the step it launched.
 """
 
 # hand-maintained operations doc, re-emitted on every regeneration

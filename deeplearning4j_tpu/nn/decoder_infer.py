@@ -95,20 +95,27 @@ def ffn(pblk, h, experts=None, live=None):
 
 
 def block(pblk, x, attend, li: int, experts=None, counts=None,
-          live=None):
+          live=None, scope: str = "block"):
     """One decoder block over rows ``x [..., F]``: ``ln1`` → mixer →
     ``Wo`` + residual → ``ln2`` → feed-forward → residual. An expert
     layer's counts are appended to ``counts`` where a list is given;
-    it routes the ``live`` rows only (:func:`ffn`)."""
+    it routes the ``live`` rows only (:func:`ffn`). The block's two
+    halves carry a devtime scope each, ``{scope}.mixer`` and
+    ``{scope}.ffn`` (HLO metadata only), so that a dense model's
+    device time splits the way an expert or retention model's does
+    through its ``ops.*`` scopes."""
     mha = pblk["mha"]
-    a = attend(li, mha, rms(x, pblk["ln1"]["gamma"]))
-    x = x + a @ mha["Wo"]
-    if "bo" in mha:
-        x = x + mha["bo"]
-    y, pairs = ffn(pblk, rms(x, pblk["ln2"]["gamma"]), experts, live)
-    if pairs is not None and counts is not None:
-        counts.append(pairs)
-    return x + y
+    with devtime.scope(f"{scope}.mixer"):
+        a = attend(li, mha, rms(x, pblk["ln1"]["gamma"]))
+        x = x + a @ mha["Wo"]
+        if "bo" in mha:
+            x = x + mha["bo"]
+    with devtime.scope(f"{scope}.ffn"):
+        y, pairs = ffn(pblk, rms(x, pblk["ln2"]["gamma"]), experts,
+                       live)
+        if pairs is not None and counts is not None:
+            counts.append(pairs)
+        return x + y
 
 
 def stack(params, toks, dims, attend, scope: str,
@@ -116,18 +123,21 @@ def stack(params, toks, dims, attend, scope: str,
     """Token ids ``toks`` (any shape) through the embedding and every
     block, to the rows before the final norm. The devtime scopes are
     HLO metadata only: block i's device time gets the name
-    ``{block_scope or scope}.block_{i}``. A caller that wants the
-    expert layers' pair counts passes a list as ``counts``: each
-    expert layer appends its ``[n_held]``. ``live`` (bool, ``toks``'
-    shape) marks the rows that carry a token; the expert layers route
-    no other (a bucket's padding, a slot without a sequence)."""
+    ``{block_scope or scope}.block_{i}``, its halves
+    ``….block_{i}.mixer`` and ``….block_{i}.ffn``. A caller that
+    wants the expert layers' pair counts passes a list as ``counts``:
+    each expert layer appends its ``[n_held]``. ``live`` (bool,
+    ``toks``' shape) marks the rows that carry a token; the expert
+    layers route no other (a bucket's padding, a slot without a
+    sequence)."""
     with devtime.scope(f"{scope}.embed"):
         x = params["layer_0"]["W"][toks]
     experts = getattr(dims, "experts", None)
     for i in range(dims.n_layers):
-        with devtime.scope(f"{block_scope or scope}.block_{i}"):
+        name = f"{block_scope or scope}.block_{i}"
+        with devtime.scope(name):
             x = block(params[f"layer_{i + 1}"], x, attend, i, experts,
-                      counts, live)
+                      counts, live, name)
     return x
 
 

@@ -25,25 +25,37 @@ chosen from measured hot spots, so the hot spots must first be
    :class:`Observatory` (cadence, ``DL4J_TPU_DEVTIME``) runs a short
    ``jax.profiler.trace`` window around real steps and parses the
    resulting ``*.xplane.pb`` with a dependency-free protobuf
-   wire-format reader (:func:`read_xspace` — ``jax.profiler
-   .ProfileData`` does not exist on the pinned jaxlib, and the
-   tensorboard plugin's proto module is absent from the wheel).
-   XLA-op execution events carry ``hlo_op``/``hlo_module`` stats and
-   picosecond durations — the device's own account of where time
-   went; ``tools/xprof_summary.py`` reads captures through the same
-   parser.
+   wire-format reader (:func:`read_xspace` — the pinned
+   ``jax.profiler.ProfileData`` shows an event's own stats but not
+   those of its METADATA entry, where a TPU keeps an op's program and
+   framework path, and the tensorboard plugin's proto module is absent
+   from the wheel); ``tools/xprof_summary.py`` reads captures through
+   the same parser.
 
-3. **Attribution.** The post-optimization HLO of the executed
-   programs (``Compiled.as_text()`` — the retrace sentry keeps its
-   AOT executables, :func:`sentry_executables`) maps each timed op
-   name to its ``metadata={op_name="...dl4j.<scope>..."}`` scope;
-   per-op FLOP/byte estimates parsed from the HLO shapes give each
-   scope an achieved-vs-roofline utilization (:func:`roofline`,
-   peaks by ``device_kind`` from ``environment.DEVICE_PEAKS``),
-   and ``Compiled.cost_analysis()`` program totals provide the
-   per-module cross-check (the ``modules`` section: XLA's own
-   FLOPs/bytes against measured device time, independent of the
-   shape-regex estimates).
+3. **Attribution** (:func:`joined_events`, THE join: the operator's
+   :func:`capture` / :func:`gap_report` and the benchmark's
+   ``readers/trace_scope.py`` read the same one). The trace says
+   itself what ran under which scope, in two forms
+   (:func:`op_events`): a TPU's "XLA Ops" event is named by its whole
+   HLO instruction, lies inside the "XLA Modules" event of its program
+   (``jit_admit(<program id>)``: programs that share a name are told
+   apart by the id, never by the name), nests its loop bodies (time is
+   SELF time), and its metadata carries the framework op path with
+   every ``dl4j.`` scope on it, beside XLA's own operation and byte
+   counts; the CPU's thunk events carry ``hlo_op`` / ``hlo_module`` /
+   ``program_id`` and no path. In both, the trace's
+   ``/host:metadata`` plane holds each executed program's
+   ``HloProto`` (:func:`trace_scope_maps`): an instruction without a
+   scope of its own takes its consumer's, its caller's or its body's
+   (:func:`_resolve_scopes`). Nothing is asked of the process that ran
+   the programs and nothing is kept at compile or warm-up time; maps
+   made of ``Compiled.as_text()`` (``executables=``, the retrace
+   sentry keeps its AOT executables, :func:`sentry_executables`) only
+   add per-op FLOP/byte estimates parsed from the HLO shapes where the
+   trace has none, and ``Compiled.cost_analysis()`` program totals for
+   the per-module cross-check (the ``modules`` section). Each scope
+   gets an achieved-vs-roofline utilization (:func:`roofline`, peaks
+   by ``device_kind`` from ``environment.DEVICE_PEAKS``).
 
 4. **Gap report.** :func:`gap_report` ranks scopes by device-time
    share with utilization, fusion count, and a ``pallas_candidate``
@@ -69,6 +81,7 @@ import shutil
 import struct
 import tempfile
 import threading
+from bisect import bisect_right
 from pathlib import Path
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
@@ -142,7 +155,7 @@ def scope(name: str):
 #   events=4,duration_ps=9,display_name=11}; XEvent{metadata_id=1,
 #   offset_ps=2,duration_ps=3,stats=4,timestamp_ns=7};
 #   XStat{metadata_id=1,double=2,uint64=3,int64=4,str=5,bytes=6,ref=7};
-#   XEventMetadata{id=1,name=2,display_name=4};
+#   XEventMetadata{id=1,name=2,display_name=4,stats=5};
 #   XStatMetadata{id=1,name=2}; map entry{key=1,value=2}.
 
 def _varint(buf: bytes, i: int) -> Tuple[int, int]:
@@ -211,12 +224,20 @@ def _stat(buf: bytes, stat_names: Dict[int, str]) -> Tuple[str, Any]:
 def read_xspace(path) -> Dict[str, Any]:
     """Parse one ``*.xplane.pb`` into plain dicts::
 
-        {"planes": [{"name", "lines": [{"name", "timestamp_ns",
+        {"planes": [{"name", "programs": {id: {"name", "stats"}},
+                     "lines": [{"name", "timestamp_ns",
                      "events": [{"name", "dur_ps", "offset_ps",
-                                 "stats": {...}}]}]}]}
+                                 "stats": {...}, "meta": {...}}]}]}]}
 
     Event names and ref-valued stats are resolved through the plane's
-    metadata tables."""
+    metadata tables. ``meta`` holds the stats of the event's METADATA
+    entry (one dict shared by every event of that entry): on a TPU that
+    is where an op's framework path (``tf_op``), ``program_id``,
+    ``flops`` and ``bytes_accessed`` live; the event's own stats are
+    its timing alone. ``programs`` is the plane's metadata table as it
+    stands where the plane has no line: the ``/host:metadata`` plane,
+    whose entries hold each executed program's ``Hlo Proto`` under its
+    program id."""
     buf = Path(path).read_bytes()
     planes = []
     for fno, _wt, pbuf in _fields(buf):
@@ -224,7 +245,7 @@ def read_xspace(path) -> Dict[str, Any]:
             continue
         name = ""
         line_bufs: List[bytes] = []
-        ev_names: Dict[int, str] = {}
+        meta_bufs: Dict[int, bytes] = {}
         stat_names: Dict[int, str] = {}
         for pf, _pw, pv in _fields(pbuf):
             if pf == 2:
@@ -233,11 +254,7 @@ def read_xspace(path) -> Dict[str, Any]:
                 line_bufs.append(pv)
             elif pf == 4:
                 k, v = _map_entry(pv)
-                em_name = ""
-                for ef, _ew, evv in _fields(v):
-                    if ef == 2:
-                        em_name = evv.decode("utf-8", "replace")
-                ev_names[k] = em_name
+                meta_bufs[k] = v
             elif pf == 5:
                 k, v = _map_entry(pv)
                 sm_name = ""
@@ -245,6 +262,18 @@ def read_xspace(path) -> Dict[str, Any]:
                     if sf == 2:
                         sm_name = svv.decode("utf-8", "replace")
                 stat_names[k] = sm_name
+        # the stat names may follow the event metadata in the file
+        ev_meta: Dict[int, Tuple[str, Dict[str, Any]]] = {}
+        for k, v in meta_bufs.items():
+            em_name, em_stats = "", {}
+            for ef, _ew, evv in _fields(v):
+                if ef == 2:
+                    em_name = evv.decode("utf-8", "replace")
+                elif ef == 5:
+                    sk, sv = _stat(evv, stat_names)
+                    em_stats[sk] = sv
+            ev_meta[k] = (em_name, em_stats)
+        none = ("", {})
         lines = []
         for lbuf in line_bufs:
             lname, ts_ns = "", 0
@@ -269,12 +298,18 @@ def read_xspace(path) -> Dict[str, Any]:
                         elif ef == 4:
                             k, v = _stat(ev, stat_names)
                             stats[k] = v
-                    events.append({"name": ev_names.get(mid, str(mid)),
+                    em_name, em_stats = ev_meta.get(mid, none)
+                    events.append({"name": em_name or str(mid),
                                    "offset_ps": off_ps,
-                                   "dur_ps": dur_ps, "stats": stats})
+                                   "dur_ps": dur_ps, "stats": stats,
+                                   "meta": em_stats})
             lines.append({"name": lname, "timestamp_ns": ts_ns,
                           "events": events})
-        planes.append({"name": name, "lines": lines})
+        plane = {"name": name, "lines": lines}
+        if not lines:
+            plane["programs"] = {k: {"name": n, "stats": st}
+                                 for k, (n, st) in ev_meta.items()}
+        planes.append(plane)
     return {"planes": planes}
 
 
@@ -299,37 +334,150 @@ def xplane_paths(path) -> List[str]:
     return [str(q) for q in sorted(by_session[newest])]
 
 
+#: an "XLA Modules" event is named ``<HLO module>(<program id>)``
+_PROGRAM_RE = re.compile(r"^(.*)\((\d+)\)$")
+
+
+def _self_ps(spans: List[Tuple[int, int]]) -> List[int]:
+    """Self time of each ``(start, duration)``: its duration less what
+    the spans nested inside it cover (on a device's "XLA Ops" line a
+    ``while`` holds its body's ops, each inside the one before it or
+    after it, never across)."""
+    order = sorted(range(len(spans)),
+                   key=lambda i: (spans[i][0], -spans[i][1]))
+    own = [d for _s, d in spans]
+    stack: List[Tuple[int, int]] = []       # (end, index)
+    for i in order:
+        s, d = spans[i]
+        while stack and stack[-1][0] <= s:
+            stack.pop()
+        if stack and s + d <= stack[-1][0]:
+            own[stack[-1][1]] -= d
+        stack.append((s + d, i))
+    return own
+
+
+def _hbm_bytes(meta: Dict[str, Any]) -> float:
+    """The bytes one execution of an instruction moves to or from HBM,
+    from its metadata's ``memory_access_breakdown`` (a serialized list
+    of ``{operation_type=1, memory_space=2, bytes_accessed=3}``, space
+    1 being HBM): ``bytes_accessed`` counts reads of what an async
+    copy already brought into on-chip memory too, and a scope of such
+    reads would stand above the HBM roofline. Without the breakdown,
+    ``bytes_accessed`` as it is."""
+    breakdown = meta.get("memory_access_breakdown")
+    if not isinstance(breakdown, bytes):
+        return float(meta.get("bytes_accessed") or 0)
+    total = 0
+    for f, _w, entry in _fields(breakdown):
+        if f != 1:
+            continue
+        space = nbytes = 0
+        for ef, _ew, v in _fields(entry):
+            if ef == 2:
+                space = v
+            elif ef == 3:
+                nbytes = v
+        if space == 1:
+            total += nbytes
+    return float(total)
+
+
 def op_events(xspace: Dict[str, Any]) -> List[Dict[str, Any]]:
-    """XLA-op *execution* events from one parsed xplane: device planes
-    contribute their "XLA Ops" lines; the CPU thunk executor (this
-    jaxlib's XLA:CPU) reports per-op events on host lines whose stats
-    carry ``hlo_op``/``hlo_module``. Returns
-    ``[{"op", "module", "dur_ns", "plane"}, ...]``."""
+    """XLA-op *execution* events from one parsed xplane, in the two
+    forms the pinned JAX writes:
+
+    - **a TPU's device plane**: the "XLA Ops" line. An event is named
+      by its whole HLO instruction (``%fusion.12 = bf16[...] fusion(
+      ...)``): ``op`` is the head of it, ``text`` the whole. Its
+      program is the "XLA Modules" event of the same plane that
+      contains it, named ``jit_admit(<program id>)``: ``module`` is
+      the name, ``program_id`` the number (several programs may share
+      one name: a bucket each), ``launch_ns`` that event's start. The
+      event's METADATA gives ``op_name`` (its ``tf_op`` stat: the
+      framework path with every ``dl4j.`` scope on it), ``flops``,
+      ``bytes`` (:func:`_hbm_bytes`) and ``kind`` (XLA's own,
+      ``hlo_category``). A loop
+      holds its body, so ``self_ns`` is the duration less the events
+      nested inside. An event outside every program whose metadata
+      names none has ``module`` ``""``: it cannot be joined.
+    - **the CPU thunk executor**: host lines whose events carry
+      ``hlo_op`` / ``hlo_module`` / ``program_id`` stats; nothing
+      nests (``self_ns`` is ``dur_ns``) and nothing carries a scope.
+
+    Returns ``[{"op", "module", "program_id", "launch_ns",
+    "start_ns", "dur_ns", "self_ns", "plane", "device_line", ...},
+    ...]``."""
     out = []
     for plane in xspace["planes"]:
         device = "/device:" in plane["name"]
+        programs: List[Tuple[int, int, str, int]] = []
+        if device:
+            for line in plane["lines"]:
+                if line["name"] != "XLA Modules":
+                    continue
+                base = line["timestamp_ns"] * 1000
+                for e in line["events"]:
+                    m = _PROGRAM_RE.match(e["name"])
+                    programs.append((
+                        base + e["offset_ps"],
+                        base + e["offset_ps"] + e["dur_ps"],
+                        m.group(1) if m else e["name"],
+                        int(m.group(2)) if m else 0))
+            programs.sort()
+        starts = [p[0] for p in programs]
+        name_of = {p[3]: p[2] for p in programs if p[3]}
+        hbm: Dict[int, float] = {}      # by metadata entry
         for line in plane["lines"]:
-            dev_line = device and line["name"] in ("XLA Ops",
-                                                   "XLA Modules")
-            if dev_line and line["name"] == "XLA Modules":
-                continue            # per-op granularity only
+            base = line["timestamp_ns"] * 1000
+            if device and line["name"] == "XLA Ops":
+                evs = [e for e in line["events"] if e["dur_ps"]]
+                own = _self_ps([(base + e["offset_ps"], e["dur_ps"])
+                                for e in evs])
+                for e, self_ps in zip(evs, own):
+                    start = base + e["offset_ps"]
+                    meta = e["meta"]
+                    pid = int(meta.get("program_id") or 0)
+                    i = bisect_right(starts, start) - 1
+                    launch = None
+                    if i >= 0 and start < programs[i][1]:
+                        launch, _end, module, pid = programs[i]
+                    else:
+                        module = name_of.get(pid, "")
+                    rec = {"op": e["name"].split(" = ", 1)[0].lstrip("%"),
+                           "text": e["name"], "module": module,
+                           "program_id": pid,
+                           "launch_ns": None if launch is None
+                           else launch / 1e3,
+                           "start_ns": start / 1e3,
+                           "dur_ns": e["dur_ps"] / 1e3,
+                           "self_ns": self_ps / 1e3,
+                           "plane": plane["name"], "device_line": True}
+                    if meta.get("tf_op"):
+                        rec["op_name"] = str(meta["tf_op"])
+                    if "hlo_category" in meta:
+                        rec["kind"] = str(meta["hlo_category"])
+                        rec["flops"] = float(meta.get("flops") or 0)
+                        if id(meta) not in hbm:
+                            hbm[id(meta)] = _hbm_bytes(meta)
+                        rec["bytes"] = hbm[id(meta)]
+                    out.append(rec)
+                continue
             for e in line["events"]:
                 mod = e["stats"].get("hlo_module")
-                if not (dev_line or mod is not None):
+                if mod is None or not e["dur_ps"]:
                     continue
-                op = e["stats"].get("hlo_op") or e["name"]
-                if not e["dur_ps"]:
-                    continue
-                rec = {"op": str(op), "module": str(mod or ""),
-                       "dur_ns": e["dur_ps"] / 1e3,
-                       "plane": plane["name"]}
-                # TPU device planes stamp the framework op path on the
-                # event itself ("tf_op") — a scope source that needs
-                # no compiled-HLO join at all
-                tf_op = e["stats"].get("tf_op")
-                if tf_op:
-                    rec["op_name"] = str(tf_op)
-                out.append(rec)
+                dur = e["dur_ps"] / 1e3
+                out.append({"op": str(e["stats"].get("hlo_op")
+                                      or e["name"]),
+                            "module": str(mod),
+                            "program_id": int(
+                                e["stats"].get("program_id") or 0),
+                            "launch_ns": None,
+                            "start_ns": (base + e["offset_ps"]) / 1e3,
+                            "dur_ns": dur, "self_ns": dur,
+                            "plane": plane["name"],
+                            "device_line": False})
     return out
 
 
@@ -437,23 +585,132 @@ def _line_shapes(kind: str, rhs: str,
                      if n in shape_of]
 
 
+#: instructions that compute nothing: where one carries no ``dl4j.``
+#: scope of its own, the time it takes is the time of bringing an
+#: operand to whoever reads it (an async slice of a weight matrix
+#: awaited in front of its matmul, a parameter's layout copy)
+_MOVES = {"copy", "copy-start", "copy-done", "async-start",
+          "async-update", "async-done", "slice", "dynamic-slice",
+          "bitcast", "get-tuple-element", "tuple", "broadcast",
+          "convert", "pad", "reshape", "transpose"}
+
+
+def _scope_path(op_name: str) -> Tuple[Tuple[str, ...], bool]:
+    """Every ``dl4j.`` scope on one framework op path, outermost
+    first and each once (a nested ``jit`` repeats its caller's), and
+    whether the path is a backward one."""
+    return (tuple(dict.fromkeys(_SCOPE_RE.findall(op_name))),
+            "transpose(" in op_name)
+
+
+def _resolve_scopes(instrs: List[Dict[str, Any]]) -> None:
+    """Give every instruction of one program its scope path, in
+    program order ``[{"name", "comp", "kind", "named", "path",
+    "backward", "operands", "callees"}, ...]`` (``named``: it has a
+    framework path of its own, scoped or not); sets ``via`` to how it was found:
+
+    - ``own``: the instruction's own ``metadata op_name`` holds it;
+    - ``consumer``: it only moves data (:data:`_MOVES`, or a custom
+      call the compiler made itself, with no framework path at all:
+      the TPU's ``ConcatBitcast`` of a weight's async slices) and the
+      first instruction of its computation that reads what it moved
+      has a scope: a ``slice-done`` the device waits in is time of
+      the matmul behind it;
+    - ``caller``: the instruction that calls its computation has one
+      (a loop body's bookkeeping: XLA:CPU's scatter loops);
+    - ``body``: a ``while`` / ``conditional`` / ``call`` of its own
+      takes the first scope found inside what it calls (only its own
+      few microseconds are booked there, never its body's time).
+
+    An instruction none of these reaches keeps an empty path."""
+    members: Dict[str, List[Dict[str, Any]]] = {}
+    users: Dict[str, List[Dict[str, Any]]] = {}
+    caller_of: Dict[str, Dict[str, Any]] = {}
+    for ins in instrs:
+        members.setdefault(ins["comp"], []).append(ins)
+        for o in ins["operands"]:
+            users.setdefault(o, []).append(ins)
+        for c in ins["callees"]:
+            caller_of.setdefault(c, ins)
+    memo: Dict[Tuple[str, bool], Any] = {}
+
+    def inside(comp: str, depth: int):
+        for ins in members.get(comp, ()):
+            if ins["path"]:
+                return ins["path"], ins["backward"]
+        if depth < 4:
+            for ins in members.get(comp, ()):
+                for c in ins["callees"]:
+                    got = inside(c, depth + 1)
+                    if got:
+                        return got
+        return None
+
+    def resolve(ins, depth: int, body_ok: bool):
+        if ins["path"]:
+            return ins["path"], ins["backward"], "own"
+        key = (ins["name"], body_ok)
+        if key in memo or depth > 8:
+            return memo.get(key)
+        memo[key] = None                    # a cycle finds nothing
+        got = None
+        if ins["kind"] in _MOVES or (ins["kind"] == "custom-call"
+                                     and not ins["named"]):
+            for user in users.get(ins["name"], ()):
+                if user["comp"] != ins["comp"]:
+                    continue
+                r = resolve(user, depth + 1, False)
+                if r:
+                    got = (r[0], r[1], "consumer")
+                    break
+        caller = caller_of.get(ins["comp"])
+        if got is None and caller is not None and caller is not ins:
+            r = resolve(caller, depth + 1, False)
+            if r:
+                got = (r[0], ins["backward"] or r[1], "caller")
+        if got is None and body_ok and ins["kind"] in _CONTAINER_KINDS:
+            for c in ins["callees"]:
+                r = inside(c, 0)
+                if r:
+                    got = (r[0], r[1], "body")
+                    break
+        memo[key] = got
+        return got
+
+    for ins in instrs:
+        r = resolve(ins, 0, True)
+        ins["via"] = r[2] if r else None
+        if r and not ins["path"]:
+            ins["path"], ins["backward"] = r[0], r[1]
+
+
+def _scope_map(module: str, instrs: List[Dict[str, Any]]
+               ) -> Dict[str, Any]:
+    _resolve_scopes(instrs)
+    return {"module": module, "ops": {
+        i["name"]: {"scope": i["path"][-1] if i["path"] else None,
+                    "path": i["path"], "via": i["via"],
+                    "backward": i["backward"], "kind": i["kind"],
+                    "flops": i["flops"], "bytes": i["bytes"]}
+        for i in instrs}}
+
+
 def hlo_scope_map(hlo_text: str) -> Dict[str, Any]:
     """Map one executable's post-optimization HLO to attribution data:
-    ``{"module": name, "ops": {op_name: {"scope", "backward", "kind",
-    "flops", "bytes"}}}``. ``scope`` is the INNERMOST ``dl4j.`` scope
-    on the op's ``metadata op_name`` path; ops with no metadata of
-    their own (while-loop bookkeeping, region bodies — XLA:CPU's
-    scatter loops are made of these) INHERIT the scope of the op that
-    calls their computation, so a conv-backward scatter's thousands of
-    body iterations attribute to the conv layer, not to noise. None
-    when no caller on the chain is annotated (optimizer update,
-    loss, ...)."""
+    ``{"module": name, "ops": {op_name: {"scope", "path", "via",
+    "backward", "kind", "flops", "bytes"}}}``. ``path`` is every
+    ``dl4j.`` scope on the op's ``metadata op_name``, outermost first,
+    ``scope`` the INNERMOST of them. An op with none of its own takes
+    one from its consumer, its caller or its body
+    (:func:`_resolve_scopes`; ``via`` says which): XLA:CPU's scatter
+    loops are made of un-annotated body ops, so a conv-backward
+    scatter's thousands of iterations attribute to the conv layer, not
+    to noise. None when nothing on those chains is annotated
+    (optimizer update, loss, ...)."""
     m = _HLO_MODULE_RE.search(hlo_text)
     module = m.group(1) if m else ""
-    ops: Dict[str, Dict[str, Any]] = {}
+    instrs: List[Dict[str, Any]] = []
     shape_of: Dict[str, Tuple[str, str]] = {}   # op -> result shape
-    comp_of: Dict[str, str] = {}       # op -> enclosing computation
-    caller_of: Dict[str, str] = {}     # computation -> calling op
     current_comp = ""
     for raw in hlo_text.splitlines():
         line = raw.strip()
@@ -481,42 +738,120 @@ def hlo_scope_map(hlo_text: str) -> Dict[str, Any]:
             shape_of[op] = first.groups()
         if not kind or kind == "parameter":
             continue
-        for callee in _CALLEE_RE.findall(rhs):
-            caller_of.setdefault(callee, op)
+        body = rhs.split(", metadata=", 1)[0]
+        callees = _CALLEE_RE.findall(body)
         nm = _OP_NAME_RE.search(rhs)
-        scope_ = None
-        backward = False
-        if nm:
-            hits = _SCOPE_RE.findall(nm.group(1))
-            scope_ = hits[-1] if hits else None
-            backward = "transpose(" in nm.group(1)
+        path, backward = _scope_path(nm.group(1)) if nm else ((), False)
         shapes = _line_shapes(kind, rhs, shape_of)
         flops, bytes_ = _op_cost(kind, rhs, shapes)
-        comp_of[op] = current_comp
-        ops[op] = {"scope": scope_, "backward": backward,
-                   "kind": kind, "flops": flops, "bytes": bytes_,
-                   "has_meta": nm is not None}
-    # scope inheritance: un-annotated ops take their calling op's
-    # resolved scope (bounded walk — call graphs are shallow)
-    def resolve(op: str, depth: int = 0) -> Tuple[Optional[str], bool]:
-        info = ops.get(op)
-        if info is None or depth > 8:
-            return None, False
-        if info["scope"] is not None:
-            return info["scope"], info["backward"]
-        caller = caller_of.get(comp_of.get(op, ""))
-        if caller is None or caller == op:
-            return None, info["backward"]
-        sc, bwd = resolve(caller, depth + 1)
-        return sc, (info["backward"] or bwd) if sc is not None \
-            else info["backward"]
+        instrs.append({"name": op, "comp": current_comp, "kind": kind,
+                       "named": nm is not None,
+                       "path": path, "backward": backward,
+                       "operands": [n for n in _OPERAND_RE.findall(body)
+                                    if n not in callees],
+                       "callees": callees,
+                       "flops": flops, "bytes": bytes_})
+    return _scope_map(module, instrs)
 
-    for op, info in ops.items():
-        if info["scope"] is None:
-            sc, bwd = resolve(op)
-            info["scope"], info["backward"] = sc, bwd
-        info.pop("has_meta", None)
-    return {"module": module, "ops": ops}
+
+# Field numbers from xla/service/hlo.proto and xla/xla_data.proto:
+#   HloProto.hlo_module=1; HloModuleProto{name=1,computations=3};
+#   HloComputationProto{name=1,instructions=2,id=5};
+#   HloInstructionProto{name=1,opcode=2,metadata=7,id=35,
+#   operand_ids=36,called_computation_ids=38}; OpMetadata.op_name=2.
+
+def _int64s(wire_type: int, value) -> List[int]:
+    """A repeated int64 field's values, packed or not."""
+    if wire_type == 0:
+        return [value]
+    out, i = [], 0
+    while i < len(value):
+        v, i = _varint(value, i)
+        out.append(v)
+    return out
+
+
+def hlo_proto_scope_map(proto: bytes) -> Dict[str, Any]:
+    """:func:`hlo_scope_map` of a serialized ``HloProto``: what a
+    trace's ``/host:metadata`` plane holds of each program it saw
+    run. No shapes are read: the TPU's op events carry XLA's own
+    operation and byte counts, so ``flops`` and ``bytes`` are 0 here."""
+    module = ""
+    comps: List[Tuple[int, str, List[bytes]]] = []
+    for f, _w, v in _fields(proto):
+        if f != 1:
+            continue
+        for mf, _mw, mv in _fields(v):
+            if mf == 1:
+                module = mv.decode("utf-8", "replace")
+            elif mf == 3:
+                cid, cname, ibufs = 0, "", []
+                for cf, _cw, cv in _fields(mv):
+                    if cf == 1:
+                        cname = cv.decode("utf-8", "replace")
+                    elif cf == 2:
+                        ibufs.append(cv)
+                    elif cf == 5:
+                        cid = cv
+                comps.append((cid, cname, ibufs))
+    comp_name = {cid: cname for cid, cname, _ in comps}
+    instrs: List[Dict[str, Any]] = []
+    name_of: Dict[int, str] = {}
+    for _cid, cname, ibufs in comps:
+        for ibuf in ibufs:
+            ins = {"name": "", "comp": cname, "kind": "", "path": (),
+                   "named": False,
+                   "backward": False, "operands": [], "callees": [],
+                   "flops": 0.0, "bytes": 0.0}
+            iid = 0
+            for f, w, v in _fields(ibuf):
+                if f == 1:
+                    ins["name"] = v.decode("utf-8", "replace")
+                elif f == 2:
+                    ins["kind"] = v.decode("utf-8", "replace")
+                elif f == 7:
+                    for mf, _mw, mv in _fields(v):
+                        if mf == 2 and mv:
+                            ins["named"] = True
+                            ins["path"], ins["backward"] = _scope_path(
+                                mv.decode("utf-8", "replace"))
+                elif f == 35:
+                    iid = v
+                elif f == 36:
+                    ins["operands"] += _int64s(w, v)
+                elif f == 38:
+                    ins["callees"] += _int64s(w, v)
+            name_of[iid] = ins["name"]
+            if ins["kind"] != "parameter":
+                instrs.append(ins)
+    for ins in instrs:
+        ins["operands"] = [name_of[o] for o in ins["operands"]
+                           if o in name_of]
+        ins["callees"] = [comp_name[c] for c in ins["callees"]
+                          if c in comp_name]
+    return _scope_map(module, instrs)
+
+
+def trace_scope_maps(xspace: Dict[str, Any],
+                     program_ids: Optional[Iterable[int]] = None
+                     ) -> Dict[int, Any]:
+    """Scope maps of the programs one trace saw run, from the trace
+    itself, keyed by program id: its ``/host:metadata`` plane holds
+    each program's ``HloProto``. Nothing is asked of the process that
+    ran them, so a trace read after its owner was freed, or on another
+    machine, joins all the same. ``program_ids`` limits the parsing to
+    the programs wanted (a proto of 1.7 MB takes a quarter second)."""
+    wanted = None if program_ids is None else set(program_ids)
+    maps: Dict[int, Any] = {}
+    for plane in xspace["planes"]:
+        if plane["name"] != "/host:metadata":
+            continue
+        for pid, entry in plane.get("programs", {}).items():
+            proto = entry["stats"].get("Hlo Proto")
+            if isinstance(proto, bytes) and (wanted is None
+                                             or pid in wanted):
+                maps[pid] = hlo_proto_scope_map(proto)
+    return maps
 
 
 def sentry_executables(*fns) -> List[Any]:
@@ -535,7 +870,10 @@ def sentry_executables(*fns) -> List[Any]:
 
 def executable_maps(executables: Iterable[Any]) -> Dict[str, Any]:
     """Scope maps keyed by HLO module name, plus merged
-    ``cost_analysis()`` program totals per module."""
+    ``cost_analysis()`` program totals per module. ``programs`` counts
+    the executables that came under one name (the gateway's prefill
+    buckets are all ``jit_admit``): the join takes a map by its name
+    only where that is 1."""
     maps: Dict[str, Any] = {}
     for ex in executables or ():
         try:
@@ -550,6 +888,8 @@ def executable_maps(executables: Iterable[Any]) -> Dict[str, Any]:
             sm["program_bytes"] = float(ca.get("bytes accessed", 0.0))
         except Exception:
             sm["program_flops"] = sm["program_bytes"] = 0.0
+        sm["programs"] = 1 + maps.get(sm["module"],
+                                      {"programs": 0})["programs"]
         maps[sm["module"]] = sm
     return maps
 
@@ -650,85 +990,146 @@ def collective_kind(op_or_kind: str) -> Optional[str]:
     return _PRIMITIVE_KIND[m.group(1)] if m else None
 
 
+#: the scope of an event whose program the trace does not tell
+UNJOINED = "unjoined"
+
+
+def joined_events(paths: Iterable[str],
+                  maps: Optional[Dict[Any, Any]] = None
+                  ) -> List[Dict[str, Any]]:
+    """THE join: every op event of ``paths`` (:func:`op_events`) with
+    the scope it ran under. Each event gains ``path`` (its ``dl4j.``
+    scopes, outermost first; empty where it has none), ``scope`` (the
+    innermost, or None), ``via`` (where the path came from: ``trace``,
+    the event's own framework path, else :func:`_resolve_scopes`'s
+    word), ``backward``, ``kind``, ``flops`` and ``bytes``.
+
+    The path's sources, in this order: the framework op path the TPU's
+    trace holds with the event; the program's map by its PROGRAM ID,
+    read from the trace's own ``/host:metadata`` plane
+    (:func:`trace_scope_maps`); a map of ``maps`` by the program's
+    NAME, and that only where one program of that name is in ``maps``
+    and one in the trace: two buckets of ``jit_admit`` hold a
+    ``fusion.12`` each, and a name alone never says whose. An event
+    whose program cannot be told at all (``module`` empty) gets the
+    scope :data:`UNJOINED`, never another program's."""
+    out: List[Dict[str, Any]] = []
+    for p in paths:
+        out.extend(_join_xspace(read_xspace(p), maps or {}))
+    return out
+
+
+def _join_xspace(xs: Dict[str, Any], maps: Dict[Any, Any]
+                 ) -> List[Dict[str, Any]]:
+    events = op_events(xs)
+    ids_of: Dict[str, set] = {}
+    for ev in events:
+        ids_of.setdefault(ev["module"], set()).add(ev["program_id"])
+    by_id = trace_scope_maps(
+        xs, {i for ids in ids_of.values() for i in ids})
+    for ev in events:
+        own = by_id.get(ev["program_id"])
+        named = maps.get(ev["module"]) if ev["module"] else None
+        if named is not None and (named.get("programs", 1) != 1
+                                  or len(ids_of[ev["module"]]) != 1):
+            named = None
+        # the trace's own map says whose instruction it is; a map made
+        # of an executable also estimates its operations and bytes
+        info = cost = None
+        for m in (own, named):
+            if m is not None and ev["op"] in m["ops"]:
+                info = info or m["ops"][ev["op"]]
+                cost = m["ops"][ev["op"]]
+        path, backward, via = (), False, None
+        if "op_name" in ev:
+            path, backward = _scope_path(ev["op_name"])
+            via = "trace" if path else None
+        if not path and info is not None and info["path"]:
+            path, backward, via = (info["path"], info["backward"],
+                                   info["via"])
+        if not ev["module"]:
+            path, via = (UNJOINED,), None
+        ev["path"], ev["scope"] = path, (path[-1] if path else None)
+        ev["via"], ev["backward"] = via, backward
+        ev["map"] = named or own
+        ev.setdefault("kind", info["kind"] if info is not None
+                      else _op_class(ev["op"]))
+        ev.setdefault("flops", cost["flops"] if cost else 0.0)
+        ev.setdefault("bytes", cost["bytes"] if cost else 0.0)
+    return events
+
+
 def attribute(paths: Iterable[str],
               maps: Optional[Dict[str, Any]] = None,
               peaks: Optional[Tuple[float, float]] = None
               ) -> Dict[str, Any]:
-    """Join timed op events from ``paths`` (xplane files — every host
-    of one session) with the executables' scope maps into per-scope
-    device-time totals. Ops outside every annotated region aggregate
-    under ``op:<class>`` scopes (the xprof class view), so the report
-    always accounts for 100% of measured device time."""
-    maps = maps or {}
+    """Per-scope device-time totals of ``paths`` (xplane files — every
+    host of one session) from :func:`joined_events`, by SELF time (a
+    loop on a device's line holds its body's ops; on the CPU's host
+    lines nothing nests and a container is left out). Ops outside
+    every annotated region aggregate under ``op:<class>`` scopes (the
+    xprof class view), ops whose program cannot be told under
+    :data:`UNJOINED`, so the report always accounts for 100% of
+    measured device time."""
     peak_f, peak_b = peaks or peaks_from_env()
     scopes: Dict[str, Dict[str, Any]] = {}
-    module_ns: Dict[str, float] = {}
-    module_op_count: Dict[Tuple[str, str], int] = {}
+    module_ns: Dict[Any, float] = {}
+    module_op_count: Dict[Tuple[Any, str], int] = {}
+    module_launches: Dict[Any, set] = {}
+    module_map: Dict[Any, Any] = {}
     total_ns = 0.0
     attributed_ns = 0.0
     steps: List[float] = []
     n_planes = 0
+    events: List[Dict[str, Any]] = []
     for p in paths:
         xs = read_xspace(p)
         n_planes += len(xs["planes"])
         steps.extend(step_durations_ns(xs))
-        for ev in op_events(xs):
-            mod_map = maps.get(ev["module"])
-            if mod_map is None and ev["module"]:
-                # module-name fingerprint suffixes: accept a UNIQUE
-                # prefix match, never a blind any-module scan —
-                # default HLO names (fusion.1, broadcast.4) collide
-                # across programs and would book one program's time
-                # to another's scope
-                cands = [m for k, m in maps.items()
-                         if k and (ev["module"].startswith(k)
-                                   or k.startswith(ev["module"]))]
-                if len(cands) == 1:
-                    mod_map = cands[0]
-            info = mod_map["ops"].get(ev["op"]) \
-                if mod_map is not None else None
-            kind_ = info["kind"] if info else _op_class(ev["op"])
-            if kind_ in _CONTAINER_KINDS:
-                continue            # children report their own time
-            sc = info["scope"] if info and info["scope"] else None
-            if sc is None and "op_name" in ev:
-                hits = _SCOPE_RE.findall(ev["op_name"])
-                sc = hits[-1] if hits else None
-            # unattributed ops bucket by class; collectives by their
-            # HLO kind whatever the instruction was named after
-            key = sc if sc is not None else (
-                f"op:{collective_kind(ev['op']) or _op_class(ev['op'])}")
-            e = scopes.get(key)
-            if e is None:
-                e = scopes[key] = {
-                    "device_ns": 0.0, "ops": 0, "fusions": 0,
-                    "backward_ns": 0.0, "custom_call_ns": 0.0,
-                    "collective_ns": 0.0,
-                    "flops": 0.0, "bytes": 0.0, "kinds": {}}
-            dur = ev["dur_ns"]
-            total_ns += dur
-            if mod_map is not None:
-                module_ns[mod_map["module"]] = \
-                    module_ns.get(mod_map["module"], 0.0) + dur
-                mk = (mod_map["module"], ev["op"])
-                module_op_count[mk] = module_op_count.get(mk, 0) + 1
-            e["device_ns"] += dur
-            e["ops"] += 1
-            kind = info["kind"] if info else _op_class(ev["op"])
-            e["kinds"][kind] = e["kinds"].get(kind, 0) + 1
-            if "fusion" in kind or "fusion" in ev["op"]:
-                e["fusions"] += 1
-            if "custom-call" in kind or "custom-call" in ev["op"]:
-                e["custom_call_ns"] += dur
-            if collective_kind(kind) or collective_kind(ev["op"]):
-                e["collective_ns"] += dur
-            if info is not None:
-                e["flops"] += info["flops"]
-                e["bytes"] += info["bytes"]
-                if info["backward"]:
-                    e["backward_ns"] += dur
-            if sc is not None:
-                attributed_ns += dur
+        events.extend(_join_xspace(xs, maps or {}))
+    for ev in events:
+        kind = ev["kind"]
+        if kind in _CONTAINER_KINDS and not ev["device_line"]:
+            continue                # children report their own time
+        sc = ev["scope"]
+        # unattributed ops bucket by class; collectives by their
+        # HLO kind whatever the instruction was named after
+        key = sc if sc is not None else (
+            f"op:{collective_kind(ev['op']) or _op_class(ev['op'])}")
+        e = scopes.get(key)
+        if e is None:
+            e = scopes[key] = {
+                "device_ns": 0.0, "ops": 0, "fusions": 0,
+                "backward_ns": 0.0, "custom_call_ns": 0.0,
+                "collective_ns": 0.0,
+                "flops": 0.0, "bytes": 0.0, "kinds": {}}
+        dur = ev["self_ns"]
+        total_ns += dur
+        if ev["module"]:
+            mk = (ev["module"], ev["program_id"])
+            module_ns[mk] = module_ns.get(mk, 0.0) + dur
+            module_map[mk] = ev["map"]
+            if ev["launch_ns"] is not None:
+                module_launches.setdefault(mk, set()).add(
+                    (ev["plane"], ev["launch_ns"]))
+            elif ev["map"] is not None:
+                ok = (mk, ev["op"])
+                module_op_count[ok] = module_op_count.get(ok, 0) + 1
+        e["device_ns"] += dur
+        e["ops"] += 1
+        e["kinds"][kind] = e["kinds"].get(kind, 0) + 1
+        if "fusion" in kind or "fusion" in ev["op"]:
+            e["fusions"] += 1
+        if "custom-call" in kind or "custom-call" in ev["op"]:
+            e["custom_call_ns"] += dur
+        if collective_kind(kind) or collective_kind(ev["op"]):
+            e["collective_ns"] += dur
+        e["flops"] += ev["flops"]
+        e["bytes"] += ev["bytes"]
+        if ev["backward"]:
+            e["backward_ns"] += dur
+        if sc is not None and sc != UNJOINED:
+            attributed_ns += dur
     out_scopes: Dict[str, Dict[str, Any]] = {}
     for key, e in scopes.items():
         sec = e["device_ns"] / 1e9
@@ -749,23 +1150,29 @@ def attribute(paths: Iterable[str],
                                        peak_f, peak_b)
         out_scopes[key] = rec
     # program-level cross-check: XLA's OWN cost_analysis() totals per
-    # executed module against its measured device time — the roofline
+    # executed program against its measured device time — the roofline
     # number that does not depend on the regex shape estimates.
-    # Executions per module = the MIN occurrence count over its
-    # mapped non-container ops in the window: every top-level op runs
-    # exactly once per execution (count == executions), loop-body ops
-    # run more — min is robust to loop overcount and only
+    # Executions of a program = its events on the "XLA Modules" line
+    # where the trace has one (a TPU); else the MIN occurrence count
+    # over its mapped non-container ops in the window: every top-level
+    # op runs exactly once per execution (count == executions),
+    # loop-body ops run more — min is robust to loop overcount and only
     # underestimates for conditional arms, which merely makes the
-    # per-execution roofline conservative.
+    # per-execution roofline conservative. Programs that share a name
+    # are listed apart, as ``name(program id)``.
     modules: Dict[str, Dict[str, Any]] = {}
-    for mod, ns in module_ns.items():
-        mm = maps.get(mod)
-        if mm is None:
+    sharing = [name for name, _pid in module_ns]
+    for mk, ns in module_ns.items():
+        mm = module_map[mk] or {}
+        if mk in module_launches:
+            execs = len(module_launches[mk])
+        elif not mm:
             continue
-        counts = [c for (m, op), c in module_op_count.items()
-                  if m == mod and op in mm["ops"]
-                  and mm["ops"][op]["kind"] not in _CONTAINER_KINDS]
-        execs = min(counts) if counts else 1
+        else:
+            counts = [c for (m, op), c in module_op_count.items()
+                      if m == mk and op in mm["ops"]
+                      and mm["ops"][op]["kind"] not in _CONTAINER_KINDS]
+            execs = min(counts) if counts else 1
         rec: Dict[str, Any] = {
             "device_ms": round(ns / 1e6, 6),
             "executions": max(1, execs),
@@ -777,7 +1184,8 @@ def attribute(paths: Iterable[str],
                 rec["program_flops"] * rec["executions"],
                 rec["program_bytes"] * rec["executions"],
                 ns / 1e9, peak_f, peak_b)
-        modules[mod] = rec
+        modules[mk[0] if sharing.count(mk[0]) == 1
+                else f"{mk[0]}({mk[1]})"] = rec
     return {
         "total_device_ms": round(total_ns / 1e6, 6),
         "attributed_ms": round(attributed_ns / 1e6, 6),
@@ -1121,8 +1529,9 @@ def measure_capture_overhead(step_seconds: Optional[float] = None,
 
 
 __all__ = ["scope", "capture", "attribute", "gap_report", "roofline",
-           "read_xspace", "xplane_paths", "op_events",
-           "step_durations_ns", "hlo_scope_map", "executable_maps",
+           "read_xspace", "xplane_paths", "op_events", "joined_events",
+           "step_durations_ns", "hlo_scope_map", "hlo_proto_scope_map",
+           "trace_scope_maps", "executable_maps", "UNJOINED",
            "sentry_executables", "peaks_from_env", "Observatory",
            "configure", "configure_from_env", "disable",
            "step_started", "step_ended", "captures",

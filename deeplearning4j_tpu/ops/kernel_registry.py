@@ -43,7 +43,8 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "scope": "ops.flash_attention",
         "closes": ("*.MultiHeadAttention", "*.SelfAttentionLayer",
                    "*.TransformerEncoderBlock",
-                   "*.TransformerDecoderBlock", "prefill.block_*"),
+                   "*.TransformerDecoderBlock",
+                   "prefill.block_*.mixer"),
         "gate": "flash",
     },
     "flash_block_fwd": {
@@ -69,8 +70,9 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
             "tests/test_pallas.py::test_paged_decode_matches_reference",
         "scope": "ops.paged_decode_attention",
         # the serving decode step's blocks: their attention now reads
-        # the KV pages in place (the projections and the MLP stay XLA)
-        "closes": ("paged_decode.block_*",),
+        # the KV pages in place (the projections stay XLA; the MLP is
+        # the block's other half, ``.ffn``, and stays an open scope)
+        "closes": ("paged_decode.block_*.mixer",),
         "gate": "paged_decode",
     },
     "latent_decode_attention": {
@@ -91,8 +93,9 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
             "tests/test_pallas.py::test_retention_decode_matches_reference",
         "scope": "ops.retention_decode",
         # the retention model's decode blocks: their mixer streams the
-        # state pages through VMEM (projections and the MLP stay XLA)
-        "closes": ("retention_decode.block_*",),
+        # state pages through VMEM (the projections stay XLA; the MLP
+        # is the block's other half, ``.ffn``, and stays an open scope)
+        "closes": ("retention_decode.block_*.mixer",),
         "gate": "retention_decode",
     },
     "threshold_encode": {

@@ -570,11 +570,12 @@ def test_xprof_summary_comm_mode(tmp_path):
     spec.loader.exec_module(xp)
     out = xp.summarize_comm(str(tmp_path))
     # offline twin of tpu_watch --comm: per-scope collective table
-    # from the kept xplane session. XLA:CPU event names carry no
-    # op_name metadata, so the maps=None join lands in the per-kind
-    # buckets — on a TPU capture the dl4j.* scopes appear instead
+    # from the kept xplane session. No executable is handed over: the
+    # join reads each program's HLO from the trace's own
+    # ``/host:metadata`` plane, by program id, so the dl4j.* scopes
+    # appear here as they do on a TPU capture
     assert "collective" in out
-    assert "op:reduce-scatter" in out and "op:all-gather" in out
+    assert "zero.reduce_scatter" in out and "zero.all_gather" in out
     assert "| scope | collective ms |" in out
     assert "estimate-only" in out        # non-TPU capture is flagged
     assert "wire-bound scopes:" in out

@@ -340,3 +340,263 @@ def test_xprof_summary_reads_capture_dir_and_file(tmp_path):
                 Path(planes[0]).parent / "hostB.xplane.pb")
     merged = xprof_summary.summarize(str(d), top=5)
     assert f"planes: {len(planes) + 1} file(s)" in merged
+
+
+# -------------------------------------------------------------------------
+# the join on the two forms of a trace (ISSUE 41)
+# -------------------------------------------------------------------------
+# A TPU's trace, as JAX 0.9.0 writes it (PERF.md §3): an op event is
+# named by its whole HLO instruction and carries timing stats only; its
+# METADATA entry holds ``tf_op`` (the framework path, scopes and all),
+# ``program_id`` and XLA's own counts; the "XLA Modules" line names a
+# program ``jit_admit(<id>)``; a loop holds its body; the
+# ``/host:metadata`` plane holds each program's ``HloProto``. The CPU's:
+# events on host lines with ``hlo_op`` / ``hlo_module`` / ``program_id``
+# stats of their own, nothing in their metadata.
+
+def _vi(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        b = n & 0x7F
+        n >>= 7
+        out.append(b | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _msg(*fields) -> bytes:
+    """``(field_no, value)`` pairs: an int is a varint, bytes/str a
+    length-delimited field (a nested message is its bytes)."""
+    out = bytearray()
+    for fno, v in fields:
+        if isinstance(v, int):
+            out += _vi(fno << 3) + _vi(v)
+        else:
+            v = v.encode() if isinstance(v, str) else v
+            out += _vi(fno << 3 | 2) + _vi(len(v)) + v
+    return bytes(out)
+
+
+class _Plane:
+    """One XPlane under construction: stat names and event metadata
+    are entered as they are used."""
+
+    def __init__(self, name):
+        self.name, self.stats, self.meta, self.lines = name, {}, [], []
+
+    def stat(self, name, value):
+        sid = self.stats.setdefault(name, len(self.stats) + 1)
+        if isinstance(value, int):
+            return _msg((1, sid), (3, value))
+        return _msg((1, sid), (6 if isinstance(value, bytes) else 5,
+                               value))
+
+    def event_meta(self, name, key=None, **stats):
+        key = key or len(self.meta) + 1
+        self.meta.append((key, _msg(
+            (1, key), (2, name),
+            *((5, self.stat(k, v)) for k, v in stats.items()))))
+        return key
+
+    def line(self, name, events):
+        """``events``: ``(metadata id, start ns, duration ns, stats)``."""
+        self.lines.append(_msg((2, name), *(
+            (4, _msg((1, mid), (2, s * 1000), (3, d * 1000),
+                     *((4, self.stat(k, v)) for k, v in st.items())))
+            for mid, s, d, st in events)))
+
+    def encode(self):
+        return _msg(
+            (2, self.name), *((3, ln) for ln in self.lines),
+            *((4, _msg((1, k), (2, m))) for k, m in self.meta),
+            *((5, _msg((1, sid), (2, _msg((1, sid), (2, name)))))
+              for name, sid in self.stats.items()))
+
+
+def _hlo_proto(module, comps):
+    """``comps``: ``[(id, name, [(id, name, opcode, op_name, operands,
+    callees)])]`` as an ``HloProto``."""
+    return _msg((1, _msg((1, module), *(
+        (3, _msg((1, cname), (5, cid), *(
+            (2, _msg((1, name), (2, kind), (35, iid),
+                     *(((7, _msg((2, op_name))),) if op_name else ()),
+                     *((36, o) for o in operands),
+                     *((38, c) for c in callees)))
+            for iid, name, kind, op_name, operands, callees in instrs)))
+        for cid, cname, instrs in comps))))
+
+
+_STEP = "jit(step)/dl4j.paged_decode.block_0/"
+
+
+def _tpu_form(tmp_path):
+    """Two ``jit_admit`` buckets whose ``fusion.12`` collide, one
+    ``jit_step`` with a ``while`` over two runs of its body, a
+    ``slice-done`` in front of the matmul it feeds, and one event that
+    lies in no program and names none."""
+    dev = _Plane("/device:TPU:0")
+    mod = {pid: dev.event_meta(f"{name}({pid})")
+           for pid, name in ((111, "jit_admit"), (222, "jit_admit"),
+                             (333, "jit_step"))}
+    fus = "%fusion.12 = bf16[512,4096]{1,0:T(8,128)(2,1)} fusion(...)"
+    ops = {
+        "mixer": dev.event_meta(
+            fus, program_id=111, hlo_category="convolution fusion",
+            flops=4000, bytes_accessed=100,
+            tf_op="jit(admit)/dl4j.prefill.block_0/"
+                  "dl4j.prefill.block_0.mixer/dot_general:"),
+        # 200 bytes accessed, 150 of them in HBM (memory space 1)
+        "ffn": dev.event_meta(
+            fus, program_id=222, hlo_category="convolution fusion",
+            flops=8000, bytes_accessed=200,
+            memory_access_breakdown=_msg(
+                (1, _msg((1, 1), (2, 1), (3, 150))),
+                (1, _msg((1, 1), (2, 3), (3, 50)))),
+            tf_op="jit(admit)/dl4j.prefill.block_0/"
+                  "dl4j.prefill.block_0.ffn/dot_general:"),
+        "while": dev.event_meta(
+            "%while.1 = (s32[], bf16[64,7168]) while(...), body=%body",
+            program_id=333, hlo_category="while"),
+        "body": dev.event_meta(
+            "%fusion.7 = bf16[16,2048]{1,0} fusion(...), kind=kOutput",
+            program_id=333, hlo_category="convolution fusion",
+            tf_op=_STEP + "dl4j.ops.moe_experts/while/body/"
+                  "dot_general:"),
+        "done": dev.event_meta(
+            "%slice-done.1 = bf16[1024,14336] async-done(%slice-start.1)",
+            program_id=333, hlo_category="async-done"),
+        "lost": dev.event_meta("%copy.3 = bf16[8]{0} copy(%p.1)"),
+    }
+    dev.line("XLA Modules", [(mod[111], 1000, 200, {}),
+                             (mod[222], 2000, 400, {}),
+                             (mod[333], 3000, 1500, {})])
+    dev.line("XLA Ops", [
+        (ops["mixer"], 1050, 100, {}), (ops["ffn"], 2050, 300, {}),
+        (ops["while"], 3100, 1000, {}), (ops["body"], 3150, 400, {}),
+        (ops["body"], 3600, 400, {}), (ops["done"], 4200, 250, {}),
+        (ops["lost"], 9000, 50, {})])
+    host = _Plane("/host:metadata")
+    host.event_meta("jit_step(333)", key=333, **{"Hlo Proto": _hlo_proto(
+        "jit_step", [
+            (2, "body", [(5, "fusion.7", "fusion",
+                          _STEP + "dl4j.ops.moe_experts/dot_general",
+                          [], [])]),
+            (1, "main", [
+                (1, "slice-start.1", "async-start", "", [], []),
+                (2, "slice-done.1", "async-done", "", [1], []),
+                (3, "fusion.9", "fusion", _STEP + "dl4j.paged_decode"
+                 ".block_0.ffn/dot_general", [2], []),
+                (4, "while.1", "while", "", [3], [2])])])})
+    path = tmp_path / "tpu.xplane.pb"
+    path.write_bytes(_msg((1, dev.encode()), (1, host.encode())))
+    return str(path), None
+
+
+_CPU_HLO = """HloModule jit_f, entry_computation_layout={()->f32[8]{0}}
+
+ENTRY %main.3 () -> f32[8] {
+  %dot.1 = f32[8]{0} dot(f32[8,8]{1,0} %p.0, f32[8]{0} %p.1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_type="dot_general" op_name="jit(f)/dl4j.layer_0.DenseLayer/dot_general"}
+  ROOT %copy.2 = f32[8]{0} copy(f32[8]{0} %dot.1)
+}
+"""
+
+
+def _cpu_form(tmp_path):
+    """The thunk executor's events, joined through a map made of the
+    executable's text. ``jit_g`` has no map (a name that only STARTS
+    like a mapped one is another program's), and a ``tf_op`` stat on
+    the EVENT is not where the pinned JAX writes a framework path."""
+    cpu = _Plane("/host:CPU")
+    names = {n: cpu.event_meta(n) for n in ("dot.1", "copy.2", "while.5")}
+    cpu.line("tf_XLAPjRtCpuClient/1", [
+        (names["dot.1"], 100, 700,
+         {"hlo_op": "dot.1", "hlo_module": "jit_f", "program_id": 5}),
+        (names["copy.2"], 900, 100,
+         {"hlo_op": "copy.2", "hlo_module": "jit_f", "program_id": 5}),
+        (names["while.5"], 2000, 500,
+         {"hlo_op": "while.5", "hlo_module": "jit_f_other",
+          "program_id": 6}),
+        (names["dot.1"], 2100, 300,
+         {"hlo_op": "dot.1", "hlo_module": "jit_f_other",
+          "program_id": 6, "tf_op": "jit(g)/dl4j.layer_9.Probe/dot:"})])
+    path = tmp_path / "cpu.xplane.pb"
+    path.write_bytes(_msg((1, cpu.encode())))
+    return str(path), {"jit_f": devtime.hlo_scope_map(_CPU_HLO)}
+
+
+#: form -> scope -> device_ms, every nanosecond of the fixture
+_JOINED = {
+    "tpu": {"prefill.block_0.mixer": 100e-6,      # program 111's fusion.12
+            "prefill.block_0.ffn": 300e-6,        # program 222's fusion.12
+            # the body's two runs, and the loop's own 200 ns by its body
+            "ops.moe_experts": 1000e-6,
+            # a wait for a weight's slice is time of the matmul behind it
+            "paged_decode.block_0.ffn": 250e-6,
+            devtime.UNJOINED: 50e-6},
+    # jit_f's dot by the map, its copy unscoped; jit_f_other has no
+    # map, and its while is a container on a host line: its children
+    # report their own time
+    "cpu": {"layer_0.DenseLayer": 700e-6, "op:copy": 100e-6,
+            "op:dot": 300e-6},
+}
+
+
+@pytest.mark.parametrize("form", ["tpu", "cpu"])
+def test_join_lands_each_op_in_its_own_programs_scope(form, tmp_path):
+    path, maps = (_tpu_form if form == "tpu" else _cpu_form)(tmp_path)
+    att = devtime.attribute([path], maps=maps, peaks=(1e12, 1e11))
+    got = {k: v["device_ms"] for k, v in att["scopes"].items()}
+    want = _JOINED[form]
+    assert got == pytest.approx(want)
+    assert att["total_device_ms"] == pytest.approx(sum(want.values()))
+    events = devtime.joined_events([path], maps)
+    by_op = {(e["program_id"], e["op"]): e for e in events}
+    if form == "tpu":
+        assert by_op[(111, "fusion.12")]["path"] == (
+            "prefill.block_0", "prefill.block_0.mixer")
+        assert by_op[(222, "fusion.12")]["scope"] == "prefill.block_0.ffn"
+        assert by_op[(333, "while.1")]["self_ns"] == pytest.approx(200)
+        assert by_op[(333, "while.1")]["via"] == "body"
+        assert by_op[(333, "slice-done.1")]["via"] == "consumer"
+        assert by_op[(0, "copy.3")]["module"] == ""
+        assert by_op[(0, "copy.3")]["scope"] == devtime.UNJOINED
+        # XLA's own counts ride along, and a program is counted by its
+        # events on the Modules line, two names apart
+        assert att["scopes"]["prefill.block_0.ffn"]["flops"] == 8000
+        assert att["scopes"]["prefill.block_0.ffn"]["bytes"] == 150
+        assert att["scopes"]["prefill.block_0.mixer"]["bytes"] == 100
+        assert {k: v["executions"] for k, v in att["modules"].items()} \
+            == {"jit_admit(111)": 1, "jit_admit(222)": 1, "jit_step": 1}
+        assert att["scope_coverage"] == pytest.approx(1650 / 1700)
+    else:
+        assert by_op[(5, "dot.1")]["via"] == "own"
+        assert by_op[(6, "dot.1")]["scope"] is None
+        assert att["modules"]["jit_f"]["executions"] == 1
+
+
+def test_a_map_is_never_taken_by_name_where_two_programs_share_it(
+        tmp_path):
+    """Two executables under one module name: neither's map may speak
+    for an event that names only the module."""
+    cpu = _Plane("/host:CPU")
+    mid = cpu.event_meta("dot.1")
+    cpu.line("tf_XLAPjRtCpuClient/1", [
+        (mid, 100, 700, {"hlo_op": "dot.1", "hlo_module": "jit_f",
+                         "program_id": 5})])
+    path = tmp_path / "two.xplane.pb"
+    path.write_bytes(_msg((1, cpu.encode())))
+
+    class Text:
+        def __init__(self, text):
+            self.text = text
+
+        def as_text(self):
+            return self.text
+
+    one = devtime.executable_maps([Text(_CPU_HLO)])
+    two = devtime.executable_maps([Text(_CPU_HLO), Text(
+        _CPU_HLO.replace("layer_0.DenseLayer", "layer_7.Other"))])
+    assert one["jit_f"]["programs"] == 1 and two["jit_f"]["programs"] == 2
+    scope = {n: devtime.joined_events([str(path)], m)[0]["scope"]
+             for n, m in (("one", one), ("two", two))}
+    assert scope == {"one": "layer_0.DenseLayer", "two": None}

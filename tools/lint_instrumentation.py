@@ -237,7 +237,7 @@ FAMILY_TOKEN_ALLOWLIST = {
 SCOPE_SITES = {
     "nn/multilayer.py": ("_forward",),
     "nn/graph.py": ("_forward",),
-    "nn/decoder_infer.py": ("stack", "logits"),
+    "nn/decoder_infer.py": ("stack", "block", "logits"),
     "parallel/zero.py": ("scatter_mean", "gather"),
     "ops/pallas_kernels.py": ("flash_attention", "flash_block_fwd",
                               "flash_block_bwd",

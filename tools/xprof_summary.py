@@ -3,9 +3,10 @@ PR 2, of an ``obs`` span-trace JSONL.
 
 XProf mode parses the ``*.xplane.pb`` a ``jax.profiler.trace`` run
 writes (e.g. ``perf_dossier.py --trace DIR``) through the
-dependency-free wire parser in ``obs/devtime.py`` (this jaxlib has no
-``jax.profiler.ProfileData``, and the tensorboard plugin wheel ships
-no xplane proto) and prints:
+dependency-free wire parser in ``obs/devtime.py`` (the pinned
+``jax.profiler.ProfileData`` does not show an event's metadata stats,
+where a TPU keeps an op's program and framework path, and the
+tensorboard plugin wheel ships no xplane proto) and prints:
 
 - steps observed and mean device step time (cross-checks the
   wall-clock differencing protocol in ``perf_dossier._timeit``);
@@ -79,11 +80,16 @@ def summarize(trace_path: str, top: int = 10):
         xs = devtime.read_xspace(p)
         steps.extend(devtime.step_durations_ns(xs))
         for ev in devtime.op_events(xs):
-            cls = _classify(ev["op"])
-            if cls in ("while", "conditional", "call"):
+            # a TPU names an event by its whole instruction ("text":
+            # the fusion's kind is on it) and nests a loop's body
+            # inside the loop: self time there; on the CPU's host
+            # lines nothing nests and a container is left out
+            cls = _classify(ev.get("text", ev["op"]))
+            if cls in ("while", "conditional", "call") \
+                    and not ev["device_line"]:
                 continue        # containers: children counted already
-            per_op[ev["op"]] += ev["dur_ns"]
-            per_class[cls] += ev["dur_ns"]
+            per_op[ev["op"]] += ev["self_ns"]
+            per_class[cls] += ev["self_ns"]
             counts[cls] += 1
     total = sum(per_class.values())
     if not total:

@@ -22,6 +22,7 @@ import optax
 
 from deeplearning4j_tpu import dtypes
 from deeplearning4j_tpu import obs
+from deeplearning4j_tpu.nn import _fit_ahead
 from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.nn.config import InputType
 from deeplearning4j_tpu.nn.layers.base import Layer, layer_from_dict
@@ -553,12 +554,27 @@ class ComputationGraph:
             self._output_fn = None
             self._diag_step_fn = None
 
-    def _fit_group(self, group):
-        """Run a group of uniformly-shaped batches (same mask
-        structure) in one scanned call (see ``_make_train_loop``)."""
-        nm = self._numerics
-        if nm is not None and any(nm.due(self.iteration + i)
-                                  for i in range(len(group))):
+    def _fit_group(self, flight):
+        """Stage the group of uniformly-shaped batches (same mask
+        structure) that ``fit`` has gathered in ``flight.pending`` and
+        launch it as one scanned call (see
+        ``_make_train_loop``) behind the group in ``flight``, whose
+        losses are read only then (``_fit_ahead``); the group launched
+        stays in flight. What is in flight is read FIRST where this is
+        not its next whole group (another length or signature), where
+        a diagnostic step is due in this group, and, between this
+        group's staging and its launch, where a listener reads the
+        state the group in flight leaves."""
+        nm, group = self._numerics, flight.pending
+        key = (len(group), _group_sig(*group[0]))
+        # the iteration this group WILL start at: a group in flight
+        # takes ``self.iteration`` only when it is read
+        first = self.iteration + flight.steps()
+        diag_due = nm is not None and any(nm.due(first + i)
+                                          for i in range(len(group)))
+        if diag_due or not flight.takes(key):
+            flight.drain()
+        if diag_due:
             # a diagnostic step is due inside this group: the scanned
             # loop has no per-step aux outputs, so run the group's
             # batches individually (the cadence path, not the hot one)
@@ -571,6 +587,7 @@ class ComputationGraph:
         self._refresh_ambient_trace()
         if self._train_loop_fn is None:
             self._train_loop_fn = self._make_train_loop()
+        flight.wait_staged()
         # h2d is the staging alone: what came before is ``prep``
         t0 = obs.now()
         inputs = {n: jnp.stack([jnp.asarray(np.asarray(item[0][i]))
@@ -589,10 +606,14 @@ class ComputationGraph:
                   for j, n in enumerate(self.conf.outputs)
                   if lms0 and j < len(lms0) and lms0[j] is not None}
         t1 = obs.now()
+        staged_ahead = len(flight)      # staged while a loop ran
+        if flight.reads_state():
+            flight.drain()
+        th = obs.now()
         # the rng stack's small programs are dispatched while the
         # staged bytes are still on their way, under ``dispatch``
         base = jax.random.PRNGKey(self.conf.seed)
-        rngs = jnp.stack([jax.random.fold_in(base, self.iteration + i)
+        rngs = jnp.stack([jax.random.fold_in(base, first + i)
                           for i in range(len(group))])
         try:
             self.params, self.opt_state, self.state, losses = \
@@ -610,26 +631,16 @@ class ComputationGraph:
                         f"on device — try a smaller value); crash dump "
                         f"written to {path}") from e
             raise
-        t2 = obs.now()
-        losses = np.asarray(losses)   # one host transfer for the group
-        t3 = obs.now()
-        staged = sum(a.nbytes for a in jax.tree.leaves(
-            (inputs, labels, masks, lmasks)))
-        obs.record_step("ComputationGraph.fit", t0, t1, t2, t3,
-                        args={"steps": len(group), "bytes": staged,
-                              "iteration": self.iteration},
-                        cause=self._call_id, start=start)
-        tl0 = obs.now()
-        for loss in losses:
-            self.score_ = float(loss)
-            self.iteration += 1
-            for l in self.listeners:
-                l.iteration_done(self, self.iteration, self.epoch)
-        if nm is not None:
-            nm.note_score(self.score_)
-        if self.listeners:
-            obs.record("ComputationGraph.fit/listeners", tl0,
-                       obs.now(), self._call_id)
+        staged = (inputs, labels, masks, lmasks)
+        flight.groups.append(_fit_ahead.Group(
+            losses, staged, key, (start, t0, t1, th, obs.now()),
+            {"steps": len(group),
+             "bytes": sum(a.nbytes for a in jax.tree.leaves(staged)),
+             "iteration": first, "staged_ahead": staged_ahead,
+             # launched with the loop before it unread
+             "ahead": len(flight)}))
+        if len(flight) > 1:
+            flight.read()
 
     #: cause id of the running ``fit`` call's records: its first
     #: iteration number
@@ -643,10 +654,24 @@ class ComputationGraph:
         ``features_masks``: sequence aligned with inputs ([B,T] each or
         None); ``labels_masks``: aligned with outputs — reference
         MultiDataSet mask semantics (per-position loss masking, e.g.
-        MLM masked positions)."""
+        MLM masked positions).
+
+        ``steps_per_loop=k > 1`` runs ``k`` uniformly-shaped batches a
+        dispatched executable and keeps ONE such group in flight
+        (``_fit_ahead``): the iterator is pulled one group ahead of the
+        listeners; a listener that does not say ``reads_state`` may
+        find ``net.params`` one group newer than the iteration it is
+        told; ``fit`` returns, and raises, with nothing in flight."""
         # no frame is added around the loop for the call's record: the
         # time jax takes to lower ``fit``'s program moves by seconds
-        # with the Python stack it is traced under (PERF.md, PR 24)
+        # with the Python stack it is traced under (PERF.md, PR 24).
+        # Not with its depth alone: with the WORDS its frames hold.
+        # CPython frees a 16 KiB chunk of its frame stack as soon as
+        # the chunk's first frame returns, so a hot loop of the tracer
+        # whose callees fall just over a chunk's end pays an mmap and
+        # a munmap a call; five more locals in ``fit``, ``_flush_group``
+        # and ``_fit_group`` together doubled the loop's lowering
+        # (PERF.md, PR 42: the three hold 80 words, as they did)
         tc0 = obs.now()
         self._call_id = self.iteration   # cause of this call's records
         if labels is not None:
@@ -658,66 +683,74 @@ class ComputationGraph:
                        self._call_id)
             return self
         it = features
-        for _ in range(epochs):
-            for l in self.listeners:
-                l.on_epoch_start(self)
-            if hasattr(it, "reset"):
-                it.reset()
-            group: list = []
-            prev_sig = None
-            src = iter(it)
-            while True:
-                te0 = obs.now()     # iterator wait = ETL attribution
-                try:
-                    mds = next(src)
-                except StopIteration:
-                    break
-                obs.record_etl("ComputationGraph.fit", te0, obs.now(),
-                               self._call_id)
-                if hasattr(mds, "features"):
-                    xs = (mds.features
-                          if isinstance(mds.features, list)
-                          else [mds.features])
-                    ys = (mds.labels if isinstance(mds.labels, list)
-                          else [mds.labels])
-                    fms = getattr(mds, "features_masks", None)
-                    lms = getattr(mds, "labels_masks", None)
-                else:
-                    xs, ys = mds
-                    xs = xs if isinstance(xs, list) else [xs]
-                    ys = ys if isinstance(ys, list) else [ys]
-                    fms = lms = None
-                if steps_per_loop > 1:
-                    # group uniformly-shaped batches (masks included —
-                    # masked BERT batches keep the device loop) into
-                    # one scanned call; a shape or mask-structure
-                    # change flushes the group
-                    sig = _group_sig(xs, ys, fms, lms)
-                    if group and sig != prev_sig:
-                        self._flush_group(group)
-                    group.append((xs, ys, fms, lms))
-                    prev_sig = sig
-                    if len(group) == steps_per_loop:
-                        self._flush_group(group)
-                else:
-                    self._flush_group(group)
-                    self._fit_batch(xs, ys, fms, lms)
-            self._flush_group(group)
-            for l in self.listeners:
-                l.on_epoch_end(self)
-            self.epoch += 1
+        flight = _fit_ahead.Flight(self, "ComputationGraph.fit",
+                                   self._call_id)
+        try:
+            for _ in range(epochs):
+                for l in self.listeners:
+                    l.on_epoch_start(self)
+                if hasattr(it, "reset"):
+                    it.reset()
+                prev_sig = None
+                src = iter(it)
+                while True:
+                    te0 = obs.now()     # iterator wait = ETL attribution
+                    try:
+                        mds = next(src)
+                    except StopIteration:
+                        break
+                    obs.record_etl("ComputationGraph.fit", te0,
+                                   obs.now(), self._call_id)
+                    if hasattr(mds, "features"):
+                        xs = (mds.features
+                              if isinstance(mds.features, list)
+                              else [mds.features])
+                        ys = (mds.labels
+                              if isinstance(mds.labels, list)
+                              else [mds.labels])
+                        fms = getattr(mds, "features_masks", None)
+                        lms = getattr(mds, "labels_masks", None)
+                    else:
+                        xs, ys = mds
+                        xs = xs if isinstance(xs, list) else [xs]
+                        ys = ys if isinstance(ys, list) else [ys]
+                        fms = lms = None
+                    if steps_per_loop > 1:
+                        # group uniformly-shaped batches (masks
+                        # included — masked BERT batches keep the
+                        # device loop) into one scanned call; a shape
+                        # or mask-structure change flushes the group
+                        sig = _group_sig(xs, ys, fms, lms)
+                        if flight.pending and sig != prev_sig:
+                            self._flush_group(flight)
+                        flight.pending.append((xs, ys, fms, lms))
+                        prev_sig = sig
+                        if len(flight.pending) == steps_per_loop:
+                            self._flush_group(flight)
+                    else:
+                        self._flush_group(flight)
+                        self._fit_batch(xs, ys, fms, lms)
+                self._flush_group(flight)
+                flight.drain()      # an epoch ends with nothing in flight
+                for l in self.listeners:
+                    l.on_epoch_end(self)
+                self.epoch += 1
+        except BaseException:
+            flight.settle()         # read what is in flight, then raise
+            raise
         obs.record("ComputationGraph.fit/call", tc0, obs.now(),
                    self._call_id)
         return self
 
-    def _flush_group(self, group):
-        if not group:
-            return
-        if len(group) == 1:
-            self._fit_batch(*group[0])
+    def _flush_group(self, flight):
+        if len(flight.pending) > 1:
+            self._fit_group(flight)
         else:
-            self._fit_group(list(group))
-        group.clear()
+            # a single batch is no next group: it runs alone
+            flight.drain()
+            if flight.pending:
+                self._fit_batch(*flight.pending[0])
+        flight.pending.clear()
 
     def _fit_batch(self, xs, ys, fms=None, lms=None):
         t0 = obs.now()

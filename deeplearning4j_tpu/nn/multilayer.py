@@ -25,6 +25,7 @@ import optax
 
 from deeplearning4j_tpu import dtypes
 from deeplearning4j_tpu import obs
+from deeplearning4j_tpu.nn import _fit_ahead
 from deeplearning4j_tpu.nn.config import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.core import OutputLayer, LossLayer
@@ -463,10 +464,25 @@ class MultiLayerNetwork:
             self._output_fn = None
             self._diag_step_fn = None
 
-    def _fit_group(self, group):
-        nm = self._numerics
-        if nm is not None and any(nm.due(self.iteration + i)
-                                  for i in range(len(group))):
+    def _fit_group(self, flight):
+        """Stage the group of uniformly-shaped batches gathered in
+        ``flight.pending`` and launch it as one scanned call behind
+        the group in flight (see
+        ``ComputationGraph._fit_group``). An armed ``devtime`` or
+        ``commtime`` capture window brackets a loop from its start to
+        its blocking read, so under one every group runs alone."""
+        nm, group = self._numerics, flight.pending
+        key = (len(group), np.shape(group[0][0]), np.shape(group[0][1]))
+        # the iteration this group WILL start at: a group in flight
+        # takes ``self.iteration`` only when it is read
+        first = self.iteration + flight.steps()
+        diag_due = nm is not None and any(nm.due(first + i)
+                                          for i in range(len(group)))
+        alone = (obs.devtime._MONITOR is not None
+                 or obs.commtime._MONITOR is not None)
+        if diag_due or alone or not flight.takes(key):
+            flight.drain()
+        if diag_due:
             # a diagnostic step is due inside this group: the scanned
             # loop has no per-step aux outputs, so run the group's
             # batches individually (the cadence path, not the hot one)
@@ -479,17 +495,22 @@ class MultiLayerNetwork:
         self._refresh_ambient_trace()
         if self._train_loop_fn is None:
             self._train_loop_fn = self._make_train_loop()
-        obs.devtime.step_started(self.iteration)
-        obs.commtime.step_started(self.iteration)
+        obs.devtime.step_started(first)
+        obs.commtime.step_started(first)
+        flight.wait_staged()
         # h2d is the staging alone: what came before is ``prep``
         t0 = obs.now()
         xs = jnp.stack([jnp.asarray(np.asarray(x)) for x, _ in group])
         ys = jnp.stack([jnp.asarray(np.asarray(y)) for _, y in group])
         t1 = obs.now()
+        staged_ahead = len(flight)      # staged while a loop ran
+        if flight.reads_state():
+            flight.drain()
+        th = obs.now()
         # the rng stack's small programs are dispatched while the
         # staged bytes are still on their way, under ``dispatch``
         base = jax.random.PRNGKey(self.conf.seed)
-        rngs = jnp.stack([jax.random.fold_in(base, self.iteration + i)
+        rngs = jnp.stack([jax.random.fold_in(base, first + i)
                           for i in range(len(group))])
         try:
             self.params, self.opt_state, self.state, losses = \
@@ -506,35 +527,27 @@ class MultiLayerNetwork:
                         f"on device — try a smaller value); crash dump "
                         f"written to {path}") from e
             raise
-        t2 = obs.now()
-        losses = np.asarray(losses)   # blocking device sync
-        t3 = obs.now()
-        obs.devtime.step_ended(self._train_loop_fn)
-        obs.commtime.step_ended(self._train_loop_fn)
-        obs.record_step("MultiLayerNetwork.fit", t0, t1, t2, t3,
-                        args={"steps": len(group),
-                              "bytes": xs.nbytes + ys.nbytes},
-                        start=start)
-        tl0 = obs.now()
-        for loss in losses:
-            self.score_ = float(loss)
-            self.iteration += 1
-            for l in self.listeners:
-                l.iteration_done(self, self.iteration, self.epoch)
-        if nm is not None:
-            nm.note_score(self.score_)
-        if self.listeners:
-            obs.record("MultiLayerNetwork.fit/listeners", tl0,
-                       obs.now())
+        flight.groups.append(_fit_ahead.Group(
+            losses, (xs, ys), key, (start, t0, t1, th, obs.now()),
+            {"steps": len(group), "bytes": xs.nbytes + ys.nbytes,
+             "iteration": first, "staged_ahead": staged_ahead,
+             # launched with the loop before it unread
+             "ahead": len(flight)}))
+        if alone or len(flight) > 1:
+            flight.read()       # alone: this group's own, at once
+        if alone:
+            obs.devtime.step_ended(self._train_loop_fn)
+            obs.commtime.step_ended(self._train_loop_fn)
 
-    def _flush_group(self, group):
-        if not group:
-            return
-        if len(group) == 1:
-            self._fit_batch(*group[0])
+    def _flush_group(self, flight):
+        if len(flight.pending) > 1:
+            self._fit_group(flight)
         else:
-            self._fit_group(list(group))
-        group.clear()
+            # a single batch is no next group: it runs alone
+            flight.drain()
+            if flight.pending:
+                self._fit_batch(*flight.pending[0])
+        flight.pending.clear()
 
     def fit(self, features, labels=None, *, epochs: int = 1,
             features_mask=None, labels_mask=None, steps_per_loop: int = 1):
@@ -546,6 +559,11 @@ class MultiLayerNetwork:
         ``steps_per_loop``: batches are grouped and run K steps per
         dispatched executable (scanned device loop) — amortises
         host/dispatch latency; mask-free uniformly-shaped batches only.
+        ONE such group is kept in flight (``_fit_ahead``): the iterator
+        is pulled one group ahead of the listeners; a listener that
+        does not say ``reads_state`` may find ``net.params`` one group
+        newer than the iteration it is told; ``fit`` returns, and
+        raises, with nothing in flight.
         """
         if labels is not None:
             self._fit_batch(features, labels, features_mask, labels_mask)
@@ -558,44 +576,52 @@ class MultiLayerNetwork:
                             getattr(ds, "labels_mask", None))
             return self
         it = features
-        for _ in range(epochs):
-            for l in self.listeners:
-                l.on_epoch_start(self)
-            if hasattr(it, "reset"):
-                it.reset()
-            group: list = []
-            src = iter(it)
-            while True:
-                te0 = obs.now()     # iterator wait = ETL attribution
-                try:
-                    ds = next(src)
-                except StopIteration:
-                    break
-                obs.record_etl("MultiLayerNetwork.fit", te0, obs.now())
-                if hasattr(ds, "features"):
-                    x, y = ds.features, ds.labels
-                    fm = getattr(ds, "features_mask", None)
-                    lm = getattr(ds, "labels_mask", None)
-                else:
-                    x, y = ds
-                    fm = lm = None
-                tbptt = (self.conf.backprop_type == "TruncatedBPTT"
-                         and np.ndim(x) == 3)
-                if steps_per_loop > 1 and fm is None and lm is None \
-                        and not tbptt:
-                    if group and (np.shape(group[-1][0]) != np.shape(x)
-                                  or np.shape(group[-1][1]) != np.shape(y)):
-                        self._flush_group(group)
-                    group.append((x, y))
-                    if len(group) == steps_per_loop:
-                        self._flush_group(group)
-                else:
-                    self._flush_group(group)
-                    self._fit_batch(x, y, fm, lm)
-            self._flush_group(group)
-            for l in self.listeners:
-                l.on_epoch_end(self)
-            self.epoch += 1
+        flight = _fit_ahead.Flight(self, "MultiLayerNetwork.fit")
+        try:
+            for _ in range(epochs):
+                for l in self.listeners:
+                    l.on_epoch_start(self)
+                if hasattr(it, "reset"):
+                    it.reset()
+                src = iter(it)
+                while True:
+                    te0 = obs.now()     # iterator wait = ETL attribution
+                    try:
+                        ds = next(src)
+                    except StopIteration:
+                        break
+                    obs.record_etl("MultiLayerNetwork.fit", te0,
+                                   obs.now())
+                    if hasattr(ds, "features"):
+                        x, y = ds.features, ds.labels
+                        fm = getattr(ds, "features_mask", None)
+                        lm = getattr(ds, "labels_mask", None)
+                    else:
+                        x, y = ds
+                        fm = lm = None
+                    tbptt = (self.conf.backprop_type == "TruncatedBPTT"
+                             and np.ndim(x) == 3)
+                    if steps_per_loop > 1 and fm is None and lm is None \
+                            and not tbptt:
+                        group = flight.pending
+                        if group and (
+                                np.shape(group[-1][0]) != np.shape(x)
+                                or np.shape(group[-1][1]) != np.shape(y)):
+                            self._flush_group(flight)
+                        group.append((x, y))
+                        if len(group) == steps_per_loop:
+                            self._flush_group(flight)
+                    else:
+                        self._flush_group(flight)
+                        self._fit_batch(x, y, fm, lm)
+                self._flush_group(flight)
+                flight.drain()      # an epoch ends with nothing in flight
+                for l in self.listeners:
+                    l.on_epoch_end(self)
+                self.epoch += 1
+        except BaseException:
+            flight.settle()         # read what is in flight, then raise
+            raise
         return self
 
     def _fit_batch(self, x, y, fmask=None, lmask=None):

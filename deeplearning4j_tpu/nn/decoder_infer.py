@@ -10,13 +10,22 @@ supply the ONE thing they differ in, a **cache object**:
 reads it, and returns the mixer's output flattened over heads. It is
 built inside the traced program, holds the arrays it rewrites while
 the program is traced, and hands them back afterwards. The model's
-mixer is chosen where the cache object is built, never in the block.
+mixer is chosen where the cache object is built, never in the block:
+a hybrid decoder, whose layers differ in kind, gets ONE cache object
+that goes by each layer's kind (:class:`ByKind`).
 The dense layouts' cache objects live here, the paged pool's with the
 pager (``serving/kv_pager.py``). ``dims`` is anything with
-``n_layers``, ``n_heads``, ``n_kv_heads``, ``rope_theta`` and
-``tie_embeddings``: the zoo model itself (a latent mixer's sizes are
-its ``latent``, its expert layers' its ``experts``). ARCHITECTURE.md
-§15 has the picture, and why the training block stays apart.
+``n_layers``, ``n_heads``, ``n_kv_heads``, ``rope_theta`` (None: no
+positional term) and ``tie_embeddings``: the zoo model itself (a
+latent mixer's sizes are its ``latent``, its expert layers' its
+``experts``, a hybrid's kinds and Mamba sizes its ``hybrid``). What a
+published decoder multiplies by is read from ``dims`` too, each where
+``dims`` has it and it is not None (:func:`scalar`):
+``embedding_multiplier``, ``residual_multiplier``, ``logits_scaling``,
+``attention_multiplier`` (the scores' scale in ``d^-1/2``'s place) and
+``norm_eps``; a decoder without one holds no multiply for it, so its
+programs lower to what they were. ARCHITECTURE.md §15 has the picture,
+and why the training block stays apart.
 
 The feed-forward is chosen by what a block's parameters HOLD, never by
 a flag: ``Wg``/``Wu``/``Wd`` a dense SwiGLU, ``moe`` the expert layer
@@ -33,13 +42,13 @@ from deeplearning4j_tpu.nn.layers.attention import (
     latent_attention_expanded, rotary_embedding, scaled_dot_attention)
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import devtime
-from deeplearning4j_tpu.ops import fused_norms, latent, moe, retention
+from deeplearning4j_tpu.ops import fused_norms, latent, moe, retention, ssm
 
 
-def rms(x, gamma):
+def rms(x, gamma, eps=RMSNORM_EPS):
     """RMSNorm over the trailing axis, platform-helper dispatched
     (ops/fused_norms.py): fused Pallas kernel on TPU, else plain XLA."""
-    return fused_norms.rms_norm(x, gamma, eps=RMSNORM_EPS)
+    return fused_norms.rms_norm(x, gamma, eps=eps)
 
 
 def quant_kv(kvr, channel_axis: int):
@@ -61,7 +70,10 @@ def rotary_rows(x, theta: float, pos):
     ``rotary_embedding(x[:, None], offset=pos_scalar)[:, 0]`` (same
     f32 angle math, same half-split pairing). Folding the two into one
     helper changes what the TPU compiler makes of the decode step
-    (PERF.md §6, PR 28), so there are two."""
+    (PERF.md §6, PR 28), so there are two. ``theta=None``: no
+    positional term, ``x`` as it is."""
+    if theta is None:
+        return x
     half = x.shape[-1] // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # [N, D/2]
@@ -72,12 +84,30 @@ def rotary_rows(x, theta: float, pos):
                             x1 * sin + x2 * cos], axis=-1)
 
 
+def scalar(dims, name: str):
+    """``dims``' published scalar ``name``, or None (``dims`` is
+    anything with the decoder's sizes: most have no such scalar)."""
+    return getattr(dims, name, None)
+
+
+def q_fold(dims, head_dim: int):
+    """What a query is multiplied by so that the attention's own
+    ``d^-1/2`` gives ``dims.attention_multiplier`` (1/8 for 64-wide
+    heads at 1/64: a power of two, exact in any float); None where
+    ``dims`` has no such scale."""
+    scale = scalar(dims, "attention_multiplier")
+    return None if scale is None else float(scale) * head_dim ** 0.5
+
+
 def qkv(mha, h, dims, rotate):
     """The softmax mixer's operands from normed rows ``h [..., F]``:
     ``q [..., H, D]``, ``k [..., Hkv, D]`` (both rotated), ``v``
-    (``ops.retention.project`` is the retention mixer's)."""
+    (``ops.retention.project`` is the retention mixer's). A published
+    score scale is folded into the query, so that every attention
+    behind this keeps its own ``d^-1/2``."""
     lead = h.shape[:-1]
     q = (h @ mha["Wq"]).reshape(*lead, dims.n_heads, -1)
+    q = _times(q, q_fold(dims, q.shape[-1]))
     k = (h @ mha["Wk"]).reshape(*lead, dims.n_kv_heads, -1)
     v = (h @ mha["Wv"]).reshape(*lead, dims.n_kv_heads, -1)
     return rotate(q), rotate(k), v
@@ -94,8 +124,15 @@ def ffn(pblk, h, experts=None, live=None):
     return h @ pblk["Wd"], None
 
 
+def _times(v, by):
+    """``v`` times a published scalar; ``v`` itself, and no multiply
+    in the program, where the scalar is None."""
+    return v if by is None else v * jnp.asarray(by, v.dtype)
+
+
 def block(pblk, x, attend, li: int, experts=None, counts=None,
-          live=None, scope: str = "block"):
+          live=None, scope: str = "block", residual=None,
+          eps: float = RMSNORM_EPS):
     """One decoder block over rows ``x [..., F]``: ``ln1`` → mixer →
     ``Wo`` + residual → ``ln2`` → feed-forward → residual. An expert
     layer's counts are appended to ``counts`` where a list is given;
@@ -103,19 +140,22 @@ def block(pblk, x, attend, li: int, experts=None, counts=None,
     halves carry a devtime scope each, ``{scope}.mixer`` and
     ``{scope}.ffn`` (HLO metadata only), so that a dense model's
     device time splits the way an expert or retention model's does
-    through its ``ops.*`` scopes."""
+    through its ``ops.*`` scopes. ``residual`` multiplies each half's
+    addition to the residual stream (a published
+    ``residual_multiplier``; None: no multiply); ``eps`` is the two
+    norms'."""
     mha = pblk["mha"]
     with devtime.scope(f"{scope}.mixer"):
-        a = attend(li, mha, rms(x, pblk["ln1"]["gamma"]))
-        x = x + a @ mha["Wo"]
+        a = attend(li, mha, rms(x, pblk["ln1"]["gamma"], eps))
+        x = x + _times(a @ mha["Wo"], residual)
         if "bo" in mha:
-            x = x + mha["bo"]
+            x = x + _times(mha["bo"], residual)
     with devtime.scope(f"{scope}.ffn"):
-        y, pairs = ffn(pblk, rms(x, pblk["ln2"]["gamma"]), experts,
-                       live)
+        y, pairs = ffn(pblk, rms(x, pblk["ln2"]["gamma"], eps),
+                       experts, live)
         if pairs is not None and counts is not None:
             counts.append(pairs)
-        return x + y
+        return x + _times(y, residual)
 
 
 def stack(params, toks, dims, attend, scope: str,
@@ -131,13 +171,16 @@ def stack(params, toks, dims, attend, scope: str,
     layers route no other (a bucket's padding, a slot without a
     sequence)."""
     with devtime.scope(f"{scope}.embed"):
-        x = params["layer_0"]["W"][toks]
+        x = _times(params["layer_0"]["W"][toks],
+                   scalar(dims, "embedding_multiplier"))
     experts = getattr(dims, "experts", None)
+    residual = scalar(dims, "residual_multiplier")
+    eps = scalar(dims, "norm_eps") or RMSNORM_EPS
     for i in range(dims.n_layers):
         name = f"{block_scope or scope}.block_{i}"
         with devtime.scope(name):
             x = block(params[f"layer_{i + 1}"], x, attend, i, experts,
-                      counts, live, name)
+                      counts, live, name, residual=residual, eps=eps)
     return x
 
 
@@ -145,11 +188,16 @@ def logits(params, x, dims, scope: str):
     """Final norm and LM head over the rows whose logits are wanted
     (never a whole prompt's). A tied head is the embedding matrix
     read transposed in the dot: nothing is materialised."""
+    scaling = scalar(dims, "logits_scaling")
     with devtime.scope(f"{scope}.lm_head"):
-        x = rms(x, params[f"layer_{dims.n_layers + 1}"]["gamma"])
+        x = rms(x, params[f"layer_{dims.n_layers + 1}"]["gamma"],
+                scalar(dims, "norm_eps") or RMSNORM_EPS)
         head = params[f"layer_{dims.n_layers + 2}"]
         hw = (params["layer_0"]["W"].T if dims.tie_embeddings
               else head["W"])
+        # on the normed rows, where the training graph's final norm
+        # has it (1/8 is exact either side of the product)
+        x = _times(x, None if scaling is None else 1.0 / scaling)
         return x @ hw + head["b"]
 
 
@@ -199,6 +247,42 @@ def pick(logits, temperature, top_p, key, *, sample, top_k, nucleus):
         return jax.random.categorical(key, lf, axis=-1).astype(
             jnp.int32)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+# -- a cache object a layer KIND ----------------------------------------------
+
+class Attend:
+    """A bare ``attend`` function as a cache object."""
+
+    def __init__(self, attend):
+        self.attend = attend
+
+
+class ByKind:
+    """A hybrid decoder's cache object: layer ``li``'s rows go to the
+    cache object of the layer's KIND (``softmax=``, ``mamba2=``),
+    under the layer's index among the layers of that kind, since each
+    kind's caches (a list of dense arrays, a pool of pages) are
+    stacked over its own layers only. ``caches`` and ``pool`` hand
+    back what the kinds' objects hold, softmax first."""
+
+    ORDER = ("softmax", "mamba2")
+
+    def __init__(self, spec, **by_kind):
+        self.spec = spec
+        self.by = by_kind
+
+    def attend(self, li, mha, h):
+        return self.by[self.spec.kinds[li]].attend(
+            self.spec.index(li), mha, h)
+
+    @property
+    def caches(self):
+        return tuple(self.by[k].caches for k in self.ORDER)
+
+    @property
+    def pool(self):
+        return sum((tuple(self.by[k].pool) for k in self.ORDER), ())
 
 
 # -- the dense cache objects -------------------------------------------------
@@ -409,3 +493,45 @@ class RetentionRows:
                 history=self.history(li), start=start)
         self.keep(li, *carried)
         return a.reshape(b, c, -1)
+
+
+class DenseSSM(_AtPosition):
+    """A Mamba-2 layer's "cache" is what the sequence carries: the
+    float32 state ``[rows, N, H P]`` and the convolution's tail
+    ``[rows, K - 1, channels]``, both rewritten once a position."""
+
+    def attend(self, li, mha, h):
+        a, state, tail = ssm.mixer_rows(mha, h, self.dims.hybrid,
+                                        *self.caches[li])
+        self.caches[li] = (state, tail)
+        return a
+
+
+class SSMRows:
+    """Mamba-2 over a chunk of rows a sequence, by the chunked form:
+    ``h`` [B, C, F] (or, from a program that runs its rows flat, the
+    [C, F] of ONE sequence), ``valid`` [B, C] a prefix of the chunk (a
+    row that is not valid leaves state and tail as they were). Dense
+    prefill runs it once over the padded prompt from empty states,
+    which ``caches`` then holds; the gateway's admission runs it chunk
+    after chunk against the sequence's state page through the pager's
+    subclass, which overrides the two hooks."""
+
+    def __init__(self, dims, valid, caches=()):
+        self.dims = dims
+        self.valid = valid
+        self.caches = list(caches)
+
+    def state(self, li):            # (state, tail) before the chunk
+        return self.caches[li]
+
+    def keep(self, li, state, tail):
+        self.caches[li] = (state, tail)
+
+    def attend(self, li, mha, h):
+        flat = h.ndim == 2
+        a, *carried = ssm.mixer_chunk(
+            mha, h[None] if flat else h, self.dims.hybrid, self.valid,
+            *self.state(li))
+        self.keep(li, *carried)
+        return a[0] if flat else a

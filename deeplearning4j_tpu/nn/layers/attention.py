@@ -43,7 +43,9 @@ def rotary_embedding(x, theta: float = 10000.0, offset=0):
     permute Wq/Wk columns when importing interleaved-RoPE weights).
     Scores depend only on RELATIVE position — the modern long-context
     positional scheme. ``offset`` shifts the position index (KV-cache
-    decoding)."""
+    decoding). ``theta=None``: no positional term, ``x`` as it is."""
+    if theta is None:
+        return x
     b, t, h, d = x.shape
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -151,7 +153,9 @@ class MultiHeadAttention(Layer):
     sequence_parallel: Optional[str] = None
     n_kv_heads: Optional[int] = None   # grouped-query attention
     rope: bool = False                 # rotary position embeddings
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0
+    #: the scores' scale in ``d^-1/2``'s place (folded into the query)
+    score_scale: Optional[float] = None
 
     _SP_MODES = (None, "ring", "ulysses", "zigzag_ring")
 
@@ -238,6 +242,9 @@ class MultiHeadAttention(Layer):
         if self.rope:
             q = rotary_embedding(q, self.rope_theta)
             k = rotary_embedding(k, self.rope_theta)
+        if self.score_scale is not None:
+            q = q * jnp.asarray(
+                self.score_scale * math.sqrt(q.shape[-1]), q.dtype)
         o = _merge_heads(self._attend(q, k, v, mask))
         if self.project_out:
             o = o @ params["Wo"] + params["bo"]
@@ -521,6 +528,75 @@ class LatentAttention(Layer):
 
 @register_layer
 @dataclass
+class Mamba2Mixer(Layer):
+    """The Mamba-2 sequence mixer (``ops/ssm.py`` has the equations):
+    one in-projection to gate, convolved ``x | B | C`` and step sizes,
+    a short causal depthwise convolution, the selective state-space
+    recurrence with a float32 state a head, a gated RMSNorm. Its
+    parameters take :class:`MultiHeadAttention`'s place,
+    ``params["mha"]``: ``Win``, ``conv_w`` ``[taps, channels]``,
+    ``conv_b``, ``dt_bias``, ``A_log``, ``D``, ``norm_gamma`` and
+    ``Wo`` (no projection bias, as published). The training forward is
+    the chunked form in plain ``jnp``, differentiated by autodiff.
+    Init is the published one: ``A`` uniform in [1, 16], the step's
+    bias the inverse softplus of a log-uniform step in [1e-3, 1e-1],
+    ``D`` = 1, the convolution uniform by its fan-in. Causal by
+    construction; ``spec`` is an ``ops.ssm.HybridSpec`` (or the dict a
+    serialized layer carries)."""
+    n_in: Optional[int] = None
+    spec: Optional[Any] = None
+
+    def init(self, key, input_shape, dtype=jnp.float32):
+        from deeplearning4j_tpu.ops.ssm import HybridSpec
+        f = self.n_in or input_shape[-1]
+        spec = HybridSpec.of(self.spec)
+        wi = winit.get(self.weight_init or "xavier")
+        ks = jax.random.split(key, 6)
+        n_h, taps = spec.n_heads, spec.d_conv
+        step = jnp.exp(jax.random.uniform(
+            ks[2], (n_h,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        bound = 1.0 / math.sqrt(taps)
+        params = {
+            "Win": wi(ks[0], (f, spec.in_width), dtype),
+            "conv_w": jax.random.uniform(
+                ks[1], (taps, spec.conv_dim), dtype, -bound, bound),
+            "conv_b": jax.random.uniform(
+                ks[5], (spec.conv_dim,), dtype, -bound, bound),
+            # softplus(dt_bias) = step
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[3], (n_h,), jnp.float32, 1.0, 16.0)).astype(dtype),
+            "D": jnp.ones((n_h,), dtype),
+            "norm_gamma": jnp.ones((spec.d_inner,), dtype),
+            "Wo": wi(ks[4], (spec.d_inner, f), dtype)}
+        return params, {}, (input_shape[0], f)
+
+    def apply(self, params, state, x, *, train=False, rng=None,
+              mask=None):
+        from deeplearning4j_tpu.ops import ssm
+        spec = ssm.HybridSpec.of(self.spec)
+        b, t, _ = x.shape
+        zero = ssm.zero_state(b, spec, x.dtype)
+        if mask is None:
+            y, _, _ = ssm.mixer_chunk(params, x, spec,
+                                      jnp.ones((b, t), bool), *zero)
+            return y @ params["Wo"], state
+        # A sequence is its valid rows, wherever the mask puts them:
+        # ``mixer_chunk`` wants them first (its convolution takes the
+        # rows before a position as they lie), so they are moved
+        # there, in order, and the outputs moved back.
+        valid = mask.astype(bool)
+        order = jnp.argsort(~valid, axis=1, stable=True)
+        y, _, _ = ssm.mixer_chunk(
+            params, jnp.take_along_axis(x, order[..., None], axis=1),
+            spec, jnp.take_along_axis(valid, order, axis=1), *zero)
+        y = jnp.take_along_axis(y, jnp.argsort(order, axis=1)[..., None],
+                                axis=1)
+        return (y @ params["Wo"]) * mask[..., None].astype(y.dtype), state
+
+
+@register_layer
+@dataclass
 class TransformerDecoderBlock(Layer):
     """Pre-RMSNorm causal decoder block (modern-LM style): grouped-
     query attention with rotary embeddings + SwiGLU MLP, residuals
@@ -540,16 +616,27 @@ class TransformerDecoderBlock(Layer):
     # float allowed: 8/3 is the LLaMA convention that makes a SwiGLU
     # block parameter-match a classic 4x two-matrix MLP
     ffn_mult: float = 4
-    rope_theta: float = 10000.0
+    #: None: no positional term (the mixer is all a position has)
+    rope_theta: Optional[float] = 10000.0
     sequence_parallel: Optional[str] = None
     remat: bool = False
     #: the sequence mixer: "softmax" (attention over every cached key),
-    #: "power_retention" (:class:`PowerRetention`) or "latent"
-    #: (:class:`LatentAttention`, sized by ``latent``); its parameters
+    #: "power_retention" (:class:`PowerRetention`), "latent"
+    #: (:class:`LatentAttention`, sized by ``latent``) or "mamba2"
+    #: (:class:`Mamba2Mixer`, sized by ``hybrid``); its parameters
     #: take the same place, ``params["mha"]``
     mixer: str = "softmax"
     #: an ``ops.latent.LatentSpec`` (``mixer="latent"``)
     latent: Optional[Any] = None
+    #: an ``ops.ssm.HybridSpec``: the Mamba sizes (``mixer="mamba2"``)
+    hybrid: Optional[Any] = None
+    #: what a published decoder multiplies each half's addition to the
+    #: residual stream by (None: nothing)
+    residual_multiplier: Optional[float] = None
+    #: the softmax mixer's score scale in ``d^-1/2``'s place
+    score_scale: Optional[float] = None
+    #: eps of the block's two norms (None: :data:`core.RMSNORM_EPS`)
+    norm_eps: Optional[float] = None
     #: the feed-forward: "dense" (SwiGLU of ``ffn_mult`` widths) or
     #: "experts" (``ops/moe.py``'s layer, sized by ``experts``, an
     #: ``ops.moe.ExpertSpec``: its parameters under ``params["moe"]``)
@@ -578,18 +665,26 @@ class TransformerDecoderBlock(Layer):
                 self._mha = LatentAttention(
                     n_in=f, n_heads=self.n_heads,
                     rope_theta=self.rope_theta, spec=self.latent)
+            elif self.mixer == "mamba2":
+                if self.sequence_parallel:
+                    raise ValueError(
+                        "mixer='mamba2' has no sequence-parallel form: "
+                        "the state passes from chunk to chunk")
+                self._mha = Mamba2Mixer(n_in=f, spec=self.hybrid)
             elif self.mixer != "softmax":
                 raise ValueError(
-                    f"mixer={self.mixer!r} "
-                    "('softmax' | 'power_retention' | 'latent')")
+                    f"mixer={self.mixer!r} ('softmax' | "
+                    "'power_retention' | 'latent' | 'mamba2')")
             else:
                 self._mha = MultiHeadAttention(
                     n_in=f, n_out=f, n_heads=self.n_heads,
                     n_kv_heads=self.n_kv_heads, causal=True, rope=True,
                     rope_theta=self.rope_theta,
+                    score_scale=self.score_scale,
                     sequence_parallel=self.sequence_parallel)
-            self._ln1 = RMSNorm()
-            self._ln2 = RMSNorm()
+            eps = {} if self.norm_eps is None else {"eps": self.norm_eps}
+            self._ln1 = RMSNorm(**eps)
+            self._ln2 = RMSNorm(**eps)
 
     def init(self, key, input_shape, dtype=jnp.float32):
         f = self.n_in = self.n_in or input_shape[-1]
@@ -633,6 +728,9 @@ class TransformerDecoderBlock(Layer):
         h, _ = self._ln1.apply(params["ln1"], {}, x)
         a, _ = self._mha.apply(params["mha"], {}, h, train=train,
                                rng=r1, mask=mask)
+        res = self.residual_multiplier
+        if res is not None:
+            a = a * jnp.asarray(res, a.dtype)
         # residual add + RMSNorm as ONE fused epilogue on TPU
         # (ops/fused_norms.py); gate-off runs the exact pre-existing
         # add-then-norm pair
@@ -644,9 +742,12 @@ class TransformerDecoderBlock(Layer):
             from deeplearning4j_tpu.ops import moe
             h, _ = moe.layer(params["moe"], h,
                              moe.ExpertSpec.of(self.experts), plain=True)
-            return x + self._maybe_dropout(h, train, r2)
-        h = jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])
-        return x + self._maybe_dropout(h @ params["Wd"], train, r2)
+        else:
+            h = jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])
+            h = h @ params["Wd"]
+        if res is not None:
+            h = h * jnp.asarray(res, h.dtype)
+        return x + self._maybe_dropout(h, train, r2)
 
     def apply(self, params, state, x, *, train=False, rng=None,
               mask=None):

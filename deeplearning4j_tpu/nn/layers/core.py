@@ -128,6 +128,9 @@ class EmbeddingLayer(Layer):
     n_in: Optional[int] = None     # vocab size
     n_out: int = 0
     has_bias: bool = False
+    #: the looked-up rows times this (a decoder's published
+    #: ``embedding_multiplier``); None: as they are
+    multiplier: Optional[float] = None
 
     def init(self, key, input_shape, dtype=jnp.float32):
         params = {"W": winit.get(self.weight_init or "xavier")(
@@ -141,6 +144,8 @@ class EmbeddingLayer(Layer):
         if idx.ndim == 2 and idx.shape[-1] == 1:
             idx = idx[..., 0]
         y = params["W"][idx]
+        if self.multiplier is not None:
+            y = y * jnp.asarray(self.multiplier, y.dtype)
         if self.has_bias:
             y = y + params["b"]
         return self._act()(y), state
@@ -274,6 +279,9 @@ class RMSNorm(Layer):
     transformer stack uses. No reference counterpart (its transformer
     support predates RMSNorm); provided for the native LM family."""
     eps: float = RMSNORM_EPS
+    #: the normed rows times this (a decoder's final norm under a
+    #: published ``logits_scaling`` s takes 1 / s); None: as they are
+    multiplier: Optional[float] = None
 
     def init(self, key, input_shape, dtype=jnp.float32):
         c = input_shape[-1]
@@ -284,8 +292,10 @@ class RMSNorm(Layer):
         # RMSNorm on TPU, the exact pre-existing XLA expression
         # otherwise (gate-off programs byte-identical)
         from deeplearning4j_tpu.ops import fused_norms
-        return fused_norms.rms_norm(x, params["gamma"],
-                                    eps=self.eps), state
+        y = fused_norms.rms_norm(x, params["gamma"], eps=self.eps)
+        if self.multiplier is not None:
+            y = y * jnp.asarray(self.multiplier, y.dtype)
+        return y, state
 
 
 @register_layer

@@ -549,11 +549,13 @@ SERVING_KV_WALKED = REGISTRY.gauge(
 SERVING_STATE_POOL = REGISTRY.gauge(
     "dl4j_tpu_serving_state_pool_bytes",
     "bytes of the recurrent-state pool as stored (0 for a KV-page "
-    "pool): one fixed-size page a sequence, trash page included")
+    "pool): one fixed-size page a sequence, trash page included; a "
+    "hybrid decoder's states and convolution tails, beside its KV pages")
 SERVING_STATE_MOVED = REGISTRY.counter(
     "dl4j_tpu_serving_state_bytes_moved",
     "bytes of recurrent state the decode steps have read and written "
-    "(logical size d(d+1)/2 rows a kv head, float32; both directions)")
+    "(a retention model's logical size d(d+1)/2 rows a kv head, float32; "
+    "a hybrid's Mamba states and tails; both directions)")
 SERVING_LATENT_ROWS = REGISTRY.counter(
     "dl4j_tpu_serving_latent_rows_read_total",
     "cached positions the decode steps' latent attention has read: "

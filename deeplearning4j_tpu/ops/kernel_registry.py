@@ -98,6 +98,17 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "closes": ("retention_decode.block_*.mixer",),
         "gate": "retention_decode",
     },
+    "ssm_decode": {
+        "module": "ops/pallas_kernels.py",
+        "fallback": "_reference_ssm_decode",
+        "parity":
+            "tests/test_ssm.py::test_ssm_decode_matches_reference",
+        "scope": "ops.ssm_decode",
+        # a hybrid decoder's decode blocks share the softmax model's
+        # scope names; paged_decode_attention already claims them
+        "closes": (),
+        "gate": "ssm_decode",
+    },
     "threshold_encode": {
         "module": "ops/pallas_kernels.py",
         "fallback": "_jnp_threshold_encode",

@@ -851,6 +851,14 @@ def flash_attention(q, k, v, causal: bool = False,
 # MXU's time goes by the K and V rows pushed through it, which this
 # does not change; what it spares is picking one head's rows out of
 # every tile (a sublane gather per head, on packed bf16 rows).
+#
+# A head of 64 features (``packed``): K and V are the two HALVES of the
+# one 128-lane row a position and kv head, and a lane slice at 64 is
+# not a whole tile. So nothing is sliced: the query comes padded with
+# zeros over the V lanes (``q . [K | V] = q . K`` exactly), the
+# weighted sum takes whole rows, and the caller keeps the V half of
+# the ``[H, 128]`` it gets back. The MXU multiplies twice the width;
+# the bytes read, which bound the call, are the same.
 
 #: rows of a chunk (positions x kv heads) folded per loop iteration.
 #: On the v5e at the serving cells' shapes (16 x 8 rows a page): six
@@ -863,7 +871,7 @@ _PAGED_CHUNK_ROWS = 2048
 def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
                          buf, sem, m, l, acc, *, scale: float,
                          block: int, n_kv: int, chunk: int,
-                         max_pages: int, d: int):
+                         max_pages: int, d: int, packed: bool = False):
     # li_ref [1], pt_ref [S*MP], n_ref [S]: scalar-prefetch operands in
     # SMEM; q_ref/o_ref [H, D] (this slot's block); pool_ref
     # [L, P, block*Hkv, 2D], left in HBM; buf [2, chunk, block*Hkv, 2D]
@@ -919,7 +927,7 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
 
         chunk_dma(c, slot, lambda cp: cp.wait())
         kv = buf[slot].reshape(rows, 2 * d)
-        s = lax.dot_general(q_ref[...], kv[:, :d],
+        s = lax.dot_general(q_ref[...], kv if packed else kv[:, :d],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         live = jnp.logical_and(own, rel < n_pos - c * (chunk * block))
@@ -934,7 +942,7 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
             l[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
             l.shape)
         acc[...] = acc[...] * alpha + jnp.dot(
-            p.astype(kv.dtype), kv[:, d:],
+            p.astype(kv.dtype), kv if packed else kv[:, d:],
             preferred_element_type=jnp.float32)
         m[...] = jnp.broadcast_to(m_new, m.shape)
         return carry
@@ -956,24 +964,30 @@ def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
     n_l, n_p, block, n_kv, _ = pool.shape
     mp = pt.shape[1]
     chunk = pages_per_chunk
-    return pl.pallas_call(
+    # a 64-wide head: the query padded over the V lanes, whole rows
+    # out, the V half kept (see above)
+    packed = d % 128 != 0
+    w = 2 * d if packed else d
+    if packed:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, d)))
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=1.0 / (d ** 0.5),
                           block=block, n_kv=n_kv, chunk=chunk,
-                          max_pages=mp, d=d),
-        out_shape=jax.ShapeDtypeStruct((s_, h, d), q.dtype),
+                          max_pages=mp, d=d, packed=packed),
+        out_shape=jax.ShapeDtypeStruct((s_, h, w), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(s_,),
-            in_specs=[pl.BlockSpec((None, h, d), lambda b, *_: (b, 0, 0)),
+            in_specs=[pl.BlockSpec((None, h, w), lambda b, *_: (b, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((None, h, d),
+            out_specs=pl.BlockSpec((None, h, w),
                                    lambda b, *_: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, chunk, block * n_kv, 2 * d), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((h, 128), jnp.float32),
                 pltpu.VMEM((h, 128), jnp.float32),
-                pltpu.VMEM((h, d), jnp.float32),
+                pltpu.VMEM((h, w), jnp.float32),
             ]),
         # the zeroed buffers carry from slot to slot: the grid is a
         # sequence, not a parallel map
@@ -986,6 +1000,7 @@ def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
       # a page as the matrix it is: with whole sublane tiles a
       # position (_use_paged_kernel) this is a bitcast, not a copy
       pool.reshape(n_l, n_p, block * n_kv, 2 * d))
+    return out[..., d:] if packed else out
 
 
 def _reference_paged_attention(q, pool, li, pt, pos):
@@ -1041,7 +1056,8 @@ def _use_paged_kernel(q, pool) -> bool:
     trace time from the operands: the platform gate every kernel uses
     (``kernel_registry.gate_active``), a float pool (the int8 pool's
     codes and scales keep the reference path), a head that fills whole
-    128-lane tiles (K and V are lane slices of one page) and kv heads
+    128-lane tiles (K and V are lane slices of one page) or half of
+    one (K and V are the halves of ONE tile: the packed form) and kv heads
     that fill whole 8-row tiles (only then is a page's
     ``[block, Hkv, 2D]`` the same bytes as the ``[block * Hkv, 2D]``
     matrix the kernel reads; otherwise XLA would re-tile the whole
@@ -1051,7 +1067,8 @@ def _use_paged_kernel(q, pool) -> bool:
         return False
     kv = pool[0]
     return (kv.dtype == q.dtype and q.dtype != jnp.float64
-            and q.shape[-1] % 128 == 0 and kv.shape[3] % 8 == 0)
+            and (q.shape[-1] % 128 == 0 or q.shape[-1] == 64)
+            and kv.shape[3] % 8 == 0)
 
 
 def paged_decode_attention(q, pool, li, pt, n_live,
@@ -1572,6 +1589,164 @@ def retention_decode(q, k, v, g, pool, li, pages, active,
             y, s_pool, z_pool = _reference_retention_decode(
                 *args, pool, li, pages, active, eps)
         return y.reshape(q.shape).astype(q.dtype), (s_pool, z_pool)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 decode over the paged state pool (a hybrid decoder's step)
+# ---------------------------------------------------------------------------
+#
+# One decode position of a Mamba-2 layer (ops/ssm.py has the
+# mathematics and the stored layout) for every live slot, in place: the
+# grid walks the slots; a step streams ONE slot's state ``[N, H P]``
+# float32 HBM -> VMEM (Pallas' own double-buffered pipeline, the block
+# chosen through the scalar-prefetched page ids), computes ``a H +
+# B (x) (Delta x)`` a 128-lane column block at a time, contracts each
+# with ``C`` over its rows, and writes the state back through
+# ``input_output_aliases``. An inactive slot maps to the block of the
+# last live slot before it and skips its body, as in
+# ``_retention_decode_kernel``: an unchanged block index is not moved.
+#
+# The arithmetic is the VPU's (2.6 MFLOP against 4.2 MB moved a slot
+# and layer: the call is bound by the memory's bandwidth). The state's
+# lanes are (head, feature) columns, so the decay, ``Delta x`` and the
+# output are lane rows as the caller has them; only ``B`` and ``C``,
+# one a slot, turn into sublane columns (a broadcast and ONE square
+# transpose each).
+
+#: lanes of one column block of the state
+_SSM_COLS = 128
+
+
+def _ssm_decode_kernel(li_ref, page_ref, act_ref, b_ref, c_ref, decay_ref,
+                       dx_ref, h_in, y_ref, h_out, *, n: int, cols: int):
+    # scalar prefetch: li [1], page / act [S]. b_ref / c_ref [1, N],
+    # decay_ref / dx_ref / y_ref [1, H P], h_in / h_out [N, H P]
+    del li_ref, page_ref
+
+    @pl.when(act_ref[pl.program_id(0)] != 0)
+    def _():
+        bcol = jnp.broadcast_to(b_ref[...], (n, n)).T   # [n, :] = B[n]
+        ccol = jnp.broadcast_to(c_ref[...], (n, n)).T
+
+        def column(j, carry):
+            at = pl.ds(pl.multiple_of(j * _SSM_COLS, _SSM_COLS), _SSM_COLS)
+            new = (jnp.broadcast_to(decay_ref[:, at], (n, _SSM_COLS))
+                   * h_in[:, at]
+                   + bcol * jnp.broadcast_to(dx_ref[:, at],
+                                             (n, _SSM_COLS)))
+            h_out[:, at] = new
+            y_ref[:, at] = jnp.sum(new * ccol, axis=0, keepdims=True)
+            return carry
+
+        lax.fori_loop(0, cols // _SSM_COLS, column, 0)
+
+    @pl.when(act_ref[pl.program_id(0)] == 0)
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssm_decode_call(b, c, decay, dx, pool, li, pages, active, interpret):
+    """ONE lowering for every Mamba layer of a step (the layer index is
+    a scalar operand), as :func:`_paged_decode_call`."""
+    n_s, n = b.shape
+    cols = dx.shape[1]
+    act = active.astype(jnp.int32)
+    # an inactive slot rides the block of the nearest live slot before
+    # it, or of the first live slot when none precedes it
+    idx = jnp.arange(n_s, dtype=jnp.int32)
+    last = lax.cummax(jnp.where(act != 0, idx, -1))
+    src = jnp.where(last >= 0, last, jnp.argmax(act).astype(jnp.int32))
+    page = jnp.where(jnp.any(act != 0), pages.astype(jnp.int32)[src], 0)
+
+    def pooled(s, li_ref, page_ref, act_ref):
+        return (li_ref[0], page_ref[s], 0, 0)
+
+    def row(s, *_):
+        return (s, 0, 0)
+
+    vec = pl.BlockSpec((None, 1, n), row)
+    lanes = pl.BlockSpec((None, 1, cols), row)
+    state = pl.BlockSpec((None, None, n, cols), pooled)
+    y, pool = pl.pallas_call(
+        functools.partial(_ssm_decode_kernel, n=n, cols=cols),
+        out_shape=(jax.ShapeDtypeStruct((n_s, 1, cols), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_s,),
+            in_specs=[vec, vec, lanes, lanes, state],
+            out_specs=(lanes, state)),
+        # operands count from the scalar-prefetch ones: the pool is
+        # the 8th
+        input_output_aliases={7: 1},
+        # the unchanged-block rule above needs the grid in order
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_SSM_VMEM_BYTES),
+        interpret=interpret,
+        name="ssm_decode",
+    )(li.reshape(1).astype(jnp.int32), page, act, b[:, None, :],
+      c[:, None, :], decay[:, None, :], dx[:, None, :], pool)
+    return y[:, 0], pool
+
+
+#: the state block in and out, double-buffered (4 x 2.1 MB at 128 x
+#: 4096), and room for the compiler's own
+_SSM_VMEM_BYTES = 32 * 1024 * 1024
+
+
+def _reference_ssm_decode(b, c, decay, dx, pool, li, pages, active):
+    """One Mamba-2 decode position per slot in plain jnp over the paged
+    state pool: the registered fallback of :func:`ssm_decode` (the CPU
+    runs it, the parity test compares against it). An inactive slot
+    reads and writes the trash page and returns zeros."""
+    pids = jnp.where(active, pages, 0)
+    new = (decay[:, None, :] * pool[li, pids]
+           + b[:, :, None] * dx[:, None, :])
+    y = jnp.sum(new * c[:, :, None], axis=1)
+    return (jnp.where(active[:, None], y, 0.0),
+            pool.at[li, pids].set(new))
+
+
+def _use_ssm_kernel(b, dx) -> bool:
+    """The dispatch line of :func:`ssm_decode`: the platform gate every
+    kernel uses, a state of 128 values a column (``B`` and ``C`` turn
+    into columns by one square transpose) and whole 128-lane column
+    blocks."""
+    from deeplearning4j_tpu.ops.kernel_registry import gate_active
+    return (gate_active("ssm_decode") and b.shape[-1] == _SSM_COLS
+            and dx.shape[-1] % _SSM_COLS == 0)
+
+
+def ssm_decode(x, b, c, delta, a_neg, d_skip, pool, li, pages, active):
+    """One decode position of a Mamba-2 layer for every slot, the
+    float32 state updated in place in the paged pool. ``x`` [S, H P]
+    (convolved), ``b``/``c`` [S, N], ``delta`` [S, H] float32 (after
+    the softplus), ``a_neg``/``d_skip`` [H]; ``pool`` the pager's state
+    array ``[L, P, N, H P]`` float32 (``serving/kv_pager.py``); ``li``
+    the layer's index in the pool (Python int or i32 scalar); ``pages``
+    [S] i32 each slot's state page; ``active`` [S] bool. Returns ``(y
+    [S, H P] float32, pool)``: ``y = H_t C + D x``; an inactive slot's
+    row is zeros and its page is neither read nor written. Shapes the
+    kernel does not take (:func:`_use_ssm_kernel`) run
+    :func:`_reference_ssm_decode`."""
+    from deeplearning4j_tpu.obs import devtime
+    with devtime.scope("ops.ssm_decode"):
+        p = x.shape[-1] // delta.shape[-1]
+        xf = x.astype(jnp.float32)
+        args = (b.astype(jnp.float32), c.astype(jnp.float32),
+                jnp.repeat(jnp.exp(delta * a_neg), p, axis=-1),
+                jnp.repeat(delta, p, axis=-1) * xf)
+        if _use_ssm_kernel(args[0], args[3]):
+            y, pool = _ssm_decode_call(
+                *args, pool, jnp.asarray(li, jnp.int32), pages, active,
+                interpret=_interpret())
+        else:
+            y, pool = _reference_ssm_decode(*args, pool, li, pages,
+                                            active)
+        skip = jnp.repeat(d_skip.astype(jnp.float32), p, axis=-1) * xf
+        return y + jnp.where(active[:, None], skip, 0.0), pool
 
 
 # ---------------------------------------------------------------------------

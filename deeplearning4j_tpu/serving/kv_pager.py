@@ -78,6 +78,30 @@ Pages are counted, reserved and freed as KV pages are (``pages_for``
 by ``block``); they are not shared and not quantised yet (the
 scheduler refuses both for such a model).
 
+A HYBRID decoder (``CausalTransformerLM(mixer="hybrid")``: Mamba-2
+state-space layers beside softmax attention layers, ``ops/ssm.py``)
+holds TWO kinds of per-sequence state in this one pager (``ssm``), the
+pool tuple ``(kv, H, tail)``, each array stacked over the layers of ITS
+kind only (a ``[n_layers, ...]`` of each would pay every kind's bytes
+for every layer):
+
+- ``kv`` ``[L_attn, P, block, Hkv, 2D]``: the KV pages above, for the
+  attention layers; allocated, reserved and freed by the free list;
+- ``H`` ``[L_ssm, 1 + slots, N, H P]`` float32: a sequence's state in
+  each Mamba layer, the stored matrix of ``ops/ssm.py`` (row ``n`` the
+  state value ``n`` of every (head, feature) column), which
+  ``ops.ssm_decode`` streams through VMEM and writes back in place;
+- ``tail`` ``[L_ssm, 1 + slots, (K - 1) * channels]`` in the compute
+  dtype: the last ``K - 1`` un-convolved rows of each layer's
+  convolution, flat (one page is one lane-aligned run).
+
+A sequence's ONE state page is ``slot + 1`` (page 0 the trash page):
+there are exactly as many as decode slots, so the slot IS the
+reservation and the state side has no free list, no refcount and no
+invariant of its own; what can leak is a KV page, and ``free_pages``
+counts those. A state page comes to its next sequence as its last one
+left it: admission starts from an empty state when ``start`` is 0.
+
 Page 0 is the reserved **trash page**: inactive slots' writes and
 unallocated page-table entries route there, so a fixed-shape step can
 always scatter/gather without corrupting live sequences (reads of
@@ -109,10 +133,17 @@ The layouts above are known HERE and to the kernels that read them
 (``ops/pallas_kernels.py``), nowhere else: a program of the scheduler
 reaches its pool through the **cache objects** at the end of this
 file (``nn/decoder_infer.py``'s contract, ``attend(li, mha, h)``):
-:meth:`KVPager.rows` (R rows a slot; KV pages, states or latent pages,
-decided once by ``state_rows`` and ``latent_dim``) and
-:class:`StateChunk`, and through
-:meth:`KVPager.write_prompt` and :meth:`KVPager.copy_page`.
+:meth:`KVPager.rows` (R rows a slot) builds the ONE class the pager
+chose with its pool, :attr:`KVPager.cache` (:class:`PagedKV`,
+:class:`PagedState`, :class:`PagedLatent`, or for a hybrid
+:class:`PagedHybrid`, which goes PER LAYER by the layer's kind,
+``decoder_infer.ByKind``), and through :meth:`KVPager.write_prompt`
+and :meth:`KVPager.copy_page`. What else depends on the kind of page
+the class says itself: whether a step walks KV pages, which arrays of
+the pool are recurrent state, and the cache class of chunk admission
+(``cache.chunk``: :class:`StateChunk`, :class:`HybridChunk`), which in
+turn says where it finds a sequence's state (``where``) and what a
+prompt's chunks hand on beside the pool (``carried``).
 """
 from __future__ import annotations
 
@@ -124,10 +155,10 @@ import numpy as np
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import metrics as _metrics
-from deeplearning4j_tpu.ops import latent, retention
+from deeplearning4j_tpu.ops import latent, retention, ssm
 from deeplearning4j_tpu.ops.pallas_kernels import (
     _reference_paged_attention, latent_decode_attention,
-    paged_decode_attention, retention_decode)
+    paged_decode_attention, retention_decode, ssm_decode)
 
 
 class PageTableError(RuntimeError):
@@ -140,14 +171,28 @@ class KVPager:
     """Fixed pool of refcounted KV pages with free-list allocation.
 
     ``n_pages`` counts the trash page: usable capacity is
-    ``n_pages - 1`` pages of ``block`` tokens each.
+    ``n_pages - 1`` pages of ``block`` tokens each. The pool holds one
+    of four kinds of pages, chosen here once: KV pages (``(codes,)``,
+    or ``(codes, scales)`` under ``cache_quant``), recurrent-state
+    pages (``state_rows``: ``(S, Z)``), latent pages (``latent_dim``:
+    ``(rows,)``), or a hybrid decoder's KV pages beside its state
+    pages (``ssm``: ``(kv, H, tail)``; ``n_layers`` then counts the
+    attention layers and ``ssm`` is ``(an ops.ssm.HybridSpec, decode
+    slots)``).
     """
 
     def __init__(self, *, n_layers: int, n_kv_heads: int, head_dim: int,
                  n_pages: int, block: int, cache_quant: Optional[str],
                  dtype: str = "float32",
                  state_rows: Optional[int] = None,
-                 latent_dim: Optional[int] = None):
+                 latent_dim: Optional[int] = None,
+                 ssm: Optional[Tuple] = None):
+        if ssm is not None and (cache_quant is not None
+                                or state_rows is not None
+                                or latent_dim is not None):
+            raise ValueError("a hybrid pool holds float KV pages beside "
+                             "float32 state pages: cache_quant, "
+                             "state_rows and latent_dim do not apply")
         if state_rows is not None and cache_quant is not None:
             raise ValueError("a recurrent-state pool is float32: "
                              "cache_quant does not apply to it")
@@ -177,16 +222,47 @@ class KVPager:
         #: values of a position's latent row (None: no latent pool)
         self.latent_dim = latent_dim
         shape = (n_layers, n_pages, block, n_kv_heads, 2 * head_dim)
-        if latent_dim is not None:
+        #: bytes of state ONE live slot's decode step reads and writes
+        #: (0: the pool holds no recurrent state)
+        self.state_bytes_per_slot = 0
+        #: the class of the step's cache object (:meth:`rows`), chosen
+        #: HERE, once, with the pool it addresses. The class says what
+        #: else depends on the kind of page: whether a step walks KV
+        #: pages (``walks_kv``), which of the pool's arrays are
+        #: recurrent state (``state``) and, as ``chunk``, the cache
+        #: object of chunk admission where the kind admits by chunks.
+        self.cache = PagedKV
+        if ssm is not None:
+            self.cache = PagedHybrid
+            spec, slots = ssm
+            n_ssm = len(spec.layers("mamba2"))
+            tail = (spec.d_conv - 1) * spec.conv_dim
+            self._pool: Tuple = (
+                jnp.zeros(shape, jnp.dtype(dtype)),
+                jnp.zeros((n_ssm, 1 + slots, spec.d_state, spec.d_inner),
+                          jnp.float32),
+                jnp.zeros((n_ssm, 1 + slots, tail), jnp.dtype(dtype)))
+            # the state and the tail of every Mamba layer, both ways
+            self.state_bytes_per_slot = 2 * n_ssm * (
+                4 * spec.d_state * spec.d_inner
+                + tail * jnp.dtype(dtype).itemsize)
+        elif latent_dim is not None:
+            self.cache = PagedLatent
             self._pool: Tuple = (jnp.zeros(
                 (n_layers, n_pages, block, latent.lanes(latent_dim)),
                 jnp.dtype(dtype)),)
         elif state_rows is not None:
+            self.cache = PagedState
             self._pool: Tuple = (
                 jnp.zeros((n_layers, n_pages, n_kv_heads, state_rows,
                            head_dim), jnp.float32),
                 jnp.zeros((n_layers, n_pages, n_kv_heads, head_dim,
                            head_dim), jnp.float32))
+            # by the logical size (d (d + 1) / 2 rows of d values and
+            # the normaliser's, float32, both ways)
+            self.state_bytes_per_slot = (
+                2 * 4 * n_layers * n_kv_heads
+                * retention.logical_state_rows(head_dim) * (head_dim + 1))
         elif cache_quant == "int8":
             self._pool: Tuple = (
                 jnp.zeros(shape, jnp.int8),
@@ -218,16 +294,16 @@ class KVPager:
         self._tenant_pages: Dict[str, int] = {}
         self._tenant_labels: set = set()
         self.max_tenant_labels = 64
-        _metrics.SERVING_STATE_POOL.set(
-            self.pool_bytes() if state_rows is not None else 0)
+        _metrics.SERVING_STATE_POOL.set(self.state_pool_bytes())
         self._gauge()
 
     # -- device pool -----------------------------------------------------
     @property
     def pool(self) -> Tuple:
         """The layer-stacked device arrays the jitted step reads and
-        rewrites: ``(codes,)``, ``(codes, scales)`` or, for a
-        recurrent-state pool, ``(S, Z)``."""
+        rewrites: ``(codes,)`` or ``(codes, scales)`` of KV pages,
+        ``(S, Z)`` of a recurrent-state pool, ``(rows,)`` of a latent
+        pool, ``(kv, H, tail)`` of a hybrid's."""
         return self._pool
 
     @pool.setter
@@ -238,6 +314,17 @@ class KVPager:
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize
                    for a in self._pool)
 
+    def state_pool_bytes(self) -> int:
+        """Bytes of the recurrent state the pool holds as stored,
+        trash page included (0 for KV and latent pages alone)."""
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in self._pool[self.cache.state])
+
+    @property
+    def walks_kv(self) -> bool:
+        """Whether a decode step's attention walks KV pages."""
+        return self.cache.walks_kv
+
     # -- inside a traced program, over ITS pool -------------------------
     def rows(self, dims, pool, pt, pos, act):
         """The cache object of R rows a slot: ``pt`` [S, MP] i32 the
@@ -245,9 +332,7 @@ class KVPager:
         positions, ``act`` bool broadcastable to [S, R] (False rows
         write nothing a live sequence reads). Its ``pool`` is the pool
         after the rows."""
-        kind = (PagedLatent if self.latent_dim is not None
-                else PagedKV if self.state_rows is None else PagedState)
-        return kind(dims, pool, pt, pos, act)
+        return self.cache(dims, pool, pt, pos, act)
 
     @staticmethod
     def write_prompt(pool, page_ids, layers):
@@ -541,6 +626,18 @@ class KVPager:
 # -- the pool's cache objects (nn/decoder_infer.py's contract) ---------------
 
 class _Rows:
+    """What :class:`KVPager` asks of the step's cache class beside the
+    ``decoder_infer`` contract, for all that depends on the kind of
+    page it addresses."""
+    #: whether a decode step's attention walks KV pages
+    walks_kv = False
+    #: which of the pool's arrays are recurrent state
+    state = slice(0, 0)
+    #: the cache class of chunk admission, ``chunk(dims, pool,
+    #: carried, where, start, valid)`` (None: the kind admits a prompt
+    #: as ONE bucket)
+    chunk = None
+
     def __init__(self, dims, pool, pt, pos, act):
         self.dims = dims
         self.pool = pool
@@ -560,6 +657,7 @@ class PagedKV(_Rows):
     spec-decode fence leans on that). A position past the slot's page
     table is clamped EXPLICITLY and routed to the trash page: JAX
     gathers clamp silently, and junk must never land in a live page."""
+    walks_kv = True
 
     def attend(self, li, mha, h):
         dims, pool, pt, pos = self.dims, self.pool, self.pt, self.pos
@@ -639,12 +737,51 @@ class PagedLatent(_Rows):
         return latent.unabsorb(mha, o, spec)
 
 
+class StateChunk(di.RetentionRows):
+    """One chunk of a retention prompt (batch 1) against the
+    sequence's state page: a layer reads the state the chunk before
+    left there (an empty one when ``start`` is 0: a page comes off the
+    free list as its last owner left it) and writes its own back; what
+    its queries need of the chunks before they read from ``history``
+    (``ops.retention.zero_history``, one layer a row), which gets this
+    chunk's rows added: it is what a prompt's chunks hand on beside
+    the pool (``carried``). ``pool`` and ``carried`` are both after
+    the chunk."""
+
+    def __init__(self, dims, pool, carried, page, start, valid):
+        super().__init__(dims, start, valid)
+        self.pool = pool
+        self.carried = carried
+        self.page = page
+
+    @staticmethod
+    def where(slot: int, pages: List[int], page_row):
+        """Where the sequence in ``slot`` keeps its state: the one
+        page it was allocated."""
+        return jnp.asarray(pages[0], jnp.int32)
+
+    def state(self, li):
+        return tuple(jnp.where(self.start > 0, a[li, self.page], 0.0)[None]
+                     for a in self.pool)
+
+    def history(self, li):
+        return tuple(a[li, None] for a in self.carried)
+
+    def keep(self, li, state, hist):
+        self.pool = tuple(a.at[li, self.page].set(new[0])
+                          for a, new in zip(self.pool, state))
+        self.carried = tuple(a.at[li].set(new[0])
+                             for a, new in zip(self.carried, hist))
+
+
 class PagedState(_Rows):
     """One position a slot against the state pool: the slot's ONE
     state page (``pt``'s only column) is updated in place and read
     (``ops.retention_decode``); an inactive slot's page is neither.
     (R is 1: the scheduler refuses the multi-row programs for a
     retention model at construction.)"""
+    state = slice(None)
+    chunk = StateChunk
 
     def attend(self, li, mha, h):
         dims = self.dims
@@ -659,31 +796,118 @@ class PagedState(_Rows):
         return a.reshape(S, -1)
 
 
-class StateChunk(di.RetentionRows):
-    """One chunk of a retention prompt (batch 1) against the
-    sequence's state page: a layer reads the state the chunk before
-    left there (an empty one when ``start`` is 0: a page comes off the
-    free list as its last owner left it) and writes its own back; what
-    its queries need of the chunks before they read from ``history``
-    (``ops.retention.zero_history``, one layer a row), which gets this
-    chunk's rows added. ``pool`` and ``hist`` are both after the
-    chunk."""
+class PagedSSM:
+    """One position a slot against a hybrid's state pool ``(H, tail)``:
+    slot ``s`` owns state page ``s + 1``; its state is updated in
+    place and read (``ops.ssm_decode``), its convolution's tail moved
+    on by one row. An inactive slot's state page is neither read nor
+    written, and its tail goes to the trash page. ``li`` counts the
+    Mamba layers (``decoder_infer.ByKind``)."""
 
-    def __init__(self, dims, pool, history, page, start, valid):
-        super().__init__(dims, start, valid)
+    def __init__(self, dims, pool, act):
+        self.dims = dims
         self.pool = pool
-        self.hist = history
+        self.act = act
+
+    def attend(self, li, mha, h):
+        spec = self.dims.hybrid
+        state, tails = self.pool
+        S = h.shape[0]
+        act = jnp.broadcast_to(self.act, (S, 1))[:, 0]
+        pages = jnp.arange(1, S + 1, dtype=jnp.int32)
+        pids = jnp.where(act, pages, 0)
+
+        def update(x, b, c, delta, a_neg, d_skip):  # -> (y, the pool)
+            return ssm_decode(x, b, c, delta, a_neg, d_skip, state, li,
+                              pages, act)
+
+        a, state, tail = ssm.mixer_rows(
+            mha, h, spec, None,
+            tails[li, pids].reshape(S, spec.d_conv - 1, -1), update)
+        self.pool = (state, tails.at[li, pids].set(tail.reshape(S, -1)))
+        return a
+
+
+class SSMChunk(di.SSMRows):
+    """One chunk of a prompt (batch 1) against the sequence's state
+    page of a hybrid's pool ``(H, tail)``: a Mamba layer reads what
+    the chunk before left there (an empty state and a zero tail when
+    ``start`` is 0: a page comes to a sequence as its last owner left
+    it) and writes its own back."""
+
+    def __init__(self, dims, pool, page, start, valid):
+        super().__init__(dims, valid)
+        self.pool = pool
         self.page = page
+        self.start = start
 
     def state(self, li):
-        return tuple(jnp.where(self.start > 0, a[li, self.page], 0.0)[None]
-                     for a in self.pool)
+        state, tails = self.pool
+        k1 = self.dims.hybrid.d_conv - 1
+        return (jnp.where(self.start > 0, state[li, self.page], 0.0)[None],
+                jnp.where(self.start > 0, tails[li, self.page],
+                          0).reshape(1, k1, -1))
 
-    def history(self, li):
-        return tuple(a[li, None] for a in self.hist)
+    def keep(self, li, state, tail):
+        pool, tails = self.pool
+        self.pool = (pool.at[li, self.page].set(state[0]),
+                     tails.at[li, self.page].set(tail.reshape(-1)))
 
-    def keep(self, li, state, hist):
-        self.pool = tuple(a.at[li, self.page].set(new[0])
-                          for a, new in zip(self.pool, state))
-        self.hist = tuple(a.at[li].set(new[0])
-                          for a, new in zip(self.hist, hist))
+
+class _OneSlot:
+    """A chunk's rows ``[1, C, F]`` as the flat rows of ONE slot that
+    :class:`PagedKV` takes, and back."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def attend(self, li, mha, h):
+        return self.inner.attend(li, mha, h[0])[None]
+
+    @property
+    def pool(self):
+        return self.inner.pool
+
+
+class HybridChunk(di.ByKind):
+    """One chunk of a hybrid's prompt (rows ``[1, C]`` at positions
+    ``start ..``): its Mamba layers against the sequence's state page
+    (:class:`SSMChunk`), its attention layers as C rows of ONE slot
+    against the KV pages of the sequence's page-table row, which the
+    chunks before have written (:class:`PagedKV`). ``where`` is both:
+    ``(state page, page-table row [MP])``. The pools hold all a later
+    chunk reads, so the chunks hand nothing on beside them
+    (``carried`` is empty)."""
+
+    def __init__(self, dims, pool, carried, where, start, valid):
+        page, pt_row = where
+        pos = start + jnp.arange(valid.shape[1], dtype=jnp.int32)[None]
+        super().__init__(
+            dims.hybrid, mamba2=SSMChunk(dims, pool[1:], page, start, valid),
+            softmax=_OneSlot(PagedKV(dims, pool[:1], pt_row[None], pos,
+                                     valid)))
+        self.carried = carried
+
+    @staticmethod
+    def where(slot: int, pages: List[int], page_row):
+        """Where the sequence in ``slot`` keeps its state: state page
+        ``slot + 1`` (the slot IS the reservation) and the KV pages of
+        its page-table row (a copy: the host's mirror is written while
+        programs run)."""
+        return (jnp.asarray(slot + 1, jnp.int32),
+                jnp.asarray(np.array(page_row, np.int32)))
+
+
+class PagedHybrid(di.ByKind):
+    """The step's cache object over a hybrid's pool ``(kv, H, tail)``:
+    an attention layer's rows go to :class:`PagedKV` over the KV
+    pages, a Mamba layer's to :class:`PagedSSM` over the state pages,
+    each under the layer's index among ITS kind."""
+    walks_kv = True
+    state = slice(1, None)
+    chunk = HybridChunk
+
+    def __init__(self, dims, pool, pt, pos, act):
+        super().__init__(
+            dims.hybrid, softmax=PagedKV(dims, pool[:1], pt, pos, act),
+            mamba2=PagedSSM(dims, pool[1:], act))

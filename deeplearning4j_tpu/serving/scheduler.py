@@ -17,7 +17,7 @@ decode contract, PAPERS.md: arxiv 2604.23467).
 Every program here is the ONE inference block and stack loop of
 ``nn/decoder_infer.py`` — the same ``generate()`` runs — over a cache
 object the pager builds for the program's pool
-(``kv_pager``: ``KVPager.rows``, ``.write_prompt``, ``StateChunk``): what a
+(``kv_pager``: ``KVPager.rows``, ``.write_prompt``, ``.cache.chunk``): what a
 layer's rows write and read is the whole difference between the dense
 path and this one, and the pool's layout is the pager's alone. The
 single-token step's attention is ``ops.paged_decode_attention``: on
@@ -54,6 +54,18 @@ itself; the decode step updates every live slot's state in place
 Prefix sharing and speculative decode would need snapshots of a state
 and are refused at construction for such a model.
 
+A model of ``mixer="hybrid"`` (Mamba-2 layers beside attention layers,
+``ops/ssm.py``) holds BOTH kinds of per-sequence state in the one
+pager: KV pages off the free list for its attention layers, and for
+its Mamba layers the state page that belongs to its decode slot.
+Admission is the same chunk program (``hybrid.chunk`` rows): a Mamba
+layer carries state and convolution tail in the state page, an
+attention layer runs the chunk's rows as the rows of one slot against
+the KV pages the chunks before have written. The decode step is the
+same ``stack`` over a cache object that goes by each layer's kind.
+``prefix_sharing``, ``spec_k > 1`` and ``cache_quant`` are refused for
+it, each for what it would need (state snapshots; an int8 state).
+
 A model of ``mixer="latent"`` blocks admits by the bucket prefill that
 softmax has (``decoder_infer.latent_prefill``: K and V expanded from
 each position's latent, the latent rows kept as the sequence's pages)
@@ -82,14 +94,14 @@ drives it); requests are duck-typed: ``.prompt`` (1-D int32),
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.ops.pallas_kernels import latent_chunk_pages
-from deeplearning4j_tpu.serving.kv_pager import KVPager, StateChunk
+from deeplearning4j_tpu.serving.kv_pager import KVPager
 from deeplearning4j_tpu.zoo.gpt import prompt_bucket
 
 #: every ``_build_*`` jitted entry point in this module must have an
@@ -107,10 +119,11 @@ WARMUP_FEEDS = {
         "t0 i32, temp f32, top_p f32, ctr i32) — one signature per "
         "power-of-two prompt bucket (prompt_bucket), each warmed",
     "_build_chunk_admit_fn":
-        "(params, pool, history, page i32, tokens[1,chunk]i32, "
+        "(params, pool, carried, where, tokens[1,chunk]i32, "
         "start i32, t0 i32, temp f32, top_p f32, ctr i32) — a "
-        "retention model's prefill: one signature total "
-        "(PREFILL_CHUNK rows), warmed once in place of the buckets",
+        "retention or hybrid model's prefill: one signature total "
+        "(prefill_chunk rows; carried and where as the pager's chunk "
+        "class gives them), warmed once in place of the buckets",
     "_build_spec_step_fn":
         "(params, pool, page_table[S,MP]i32, lengths[S]i32, "
         "active[S]bool, prev[S]i32, drafts[S,k-1]i32) — one "
@@ -252,44 +265,52 @@ class DecodeScheduler:
         self.expert_layers = (0 if experts is None
                               else model.n_layers - experts.first_dense)
         state_rows = None
-        if self.recurrent:
-            from deeplearning4j_tpu.ops.retention import (
-                logical_state_rows, state_rows as rows_of, zero_history)
+        #: a hybrid decoder's spec: state pages beside KV pages
+        hybrid = getattr(model, "hybrid", None)
+        if self.recurrent or hybrid is not None:
             for name, on in (("prefix_sharing", self.prefix_sharing),
                              ("spec_k", self.spec_k != 1)):
                 if on:
                     raise ValueError(
-                        f"{name} with mixer='power_retention': a "
+                        f"{name} with mixer={model.mixer!r}: a "
                         "recurrent state cannot be adopted at a page "
                         "boundary nor rolled back after a rejected "
                         "draft; both need an index of state "
                         "snapshots, which this scheduler does not "
                         "keep")
+            #: rows of the ONE prefill program such a model admits by
+            #: (``None``: the power-of-two buckets)
+            self.prefill_chunk = min(
+                PREFILL_CHUNK if hybrid is None else hybrid.chunk, mc)
+        else:
+            self.prefill_chunk = None
+        #: what the chunks of the prompt being admitted hand on beside
+        #: the pool (one admission at a time)
+        self._prefill_hist: Tuple = ()
+        if self.recurrent:
+            from deeplearning4j_tpu.ops.retention import (
+                state_rows as rows_of, zero_history)
             state_rows = rows_of(hd)
             self.max_pages_per_seq = 1
-            self.prefill_chunk = min(PREFILL_CHUNK, mc)
-            #: bytes of state one live slot's decode step reads and
-            #: writes, by the logical size (d (d + 1) / 2 rows of
-            #: d values and the normaliser's, float32, both ways)
-            self.state_bytes_per_slot = (
-                2 * 4 * model.n_layers * model.n_kv_heads
-                * logical_state_rows(hd) * (hd + 1))
-            #: the prompt being admitted, as its later chunks read it:
-            #: every layer's keys, values and cumulative log-gates,
-            #: for prompts up to max_context (one admission at a time)
+            # the prompt as its later chunks read it: every layer's
+            # keys, values and cumulative log-gates, for prompts up to
+            # max_context
             self._prefill_hist = zero_history(
                 model.n_layers,
                 -(-mc // self.prefill_chunk) * self.prefill_chunk,
                 model.n_kv_heads, hd, model.compute_dtype or "float32")
         self.pager = KVPager(
-            n_layers=model.n_layers, n_kv_heads=model.n_kv_heads,
+            n_layers=(model.n_layers if hybrid is None
+                      else len(hybrid.layers("softmax"))),
+            n_kv_heads=model.n_kv_heads,
             head_dim=hd, block=self.block,
             n_pages=(int(n_pages) if n_pages
                      else 1 + self.max_slots * self.max_pages_per_seq),
             cache_quant=model.cache_quant,
             dtype=model.compute_dtype or "float32",
             state_rows=state_rows,
-            latent_dim=None if self.latent is None else self.latent.row)
+            latent_dim=None if self.latent is None else self.latent.row,
+            ssm=None if hybrid is None else (hybrid, self.max_slots))
         # per-slot host state, mirrored into the small int arrays the
         # fixed-shape step consumes each iteration
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
@@ -329,7 +350,7 @@ class DecodeScheduler:
         self.cause = None
         self._step_fn = self._build_step_fn()
         self._chunk_fn = (self._build_chunk_admit_fn()
-                          if self.recurrent else None)
+                          if self.prefill_chunk else None)
         self._admit_fns: Dict[int, object] = {}
         self._spec_fn = (self._build_spec_step_fn(self.spec_k)
                          if self.spec_k > 1 else None)
@@ -481,14 +502,19 @@ class DecodeScheduler:
                           donate_argnums=(1,))
 
     def _build_chunk_admit_fn(self):
-        """A retention model's prefill: ONE program of
+        """A retention or hybrid model's prefill: ONE program of
         ``prefill_chunk`` rows, run ``ceil(t0 / chunk)`` times for a
         prompt of ``t0`` tokens. A call runs its rows by the chunked
-        form against the sequence's state page (``StateChunk``):
+        form against the sequence's state page (the chunk class of
+        the pager's cache, ``KVPager.cache.chunk``; ``where`` is where
+        that class finds the sequence, ``carried`` what its chunks
+        hand on beside the pool):
         after the last call the page holds the state after position
         ``t0 - 1`` exactly, since rows at and past ``t0`` are masked
-        out of it. The head runs only in the call that holds row
-        ``t0 - 1``; the others return token 0."""
+        out of it (and a hybrid's attention layers have written the
+        KV of positions below ``t0`` into the sequence's pages).
+        The head runs only in the call that holds row ``t0 - 1``; the
+        others return token 0."""
         import jax
         import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
@@ -496,11 +522,12 @@ class DecodeScheduler:
         model = self.model
         chunk = self.prefill_chunk
 
-        def admit(params, pool, history, page, toks, start, t0, temp,
+        def admit(params, pool, carried, where, toks, start, t0, temp,
                   top_p, ctr):
             valid = (start + jnp.arange(chunk, dtype=jnp.int32)
                      < t0)[None, :]
-            cache = StateChunk(model, pool, history, page, start, valid)
+            cache = self.pager.cache.chunk(model, pool, carried, where,
+                                           start, valid)
             x = di.stack(params, toks, model, cache.attend,
                          "chunk_prefill")               # [1, C, F]
 
@@ -512,10 +539,18 @@ class DecodeScheduler:
 
             g0 = jax.lax.cond(t0 <= start + chunk, first_token,
                               lambda x: jnp.zeros((1,), jnp.int32), x)
-            return cache.pool, cache.hist, g0
+            return cache.pool, cache.carried, g0
 
         return sentry.jit(admit, name="serving.prefill",
                           donate_argnums=(1, 2))
+
+    def _chunk_where_shapes(self):
+        """Shapes of the chunk program's ``where``, for lowering."""
+        import jax
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            self.pager.cache.chunk.where(
+                0, [0], np.zeros(self.max_pages_per_seq, np.int32)))
 
     def _admit_fn(self, tb: int):
         fn = self._admit_fns.get(tb)
@@ -583,8 +618,15 @@ class DecodeScheduler:
         the prefilled bucket plus every decode write (positions
         ``t0 .. t0+max_new-2``) — reserved up front so an admitted
         sequence can never stall mid-flight on an empty free list."""
-        tb = prompt_bucket(t0, self.max_context)
+        tb = (0 if self.prefill_chunk     # padding rows write no page
+              else prompt_bucket(t0, self.max_context))
         return self.pager.pages_for(max(tb, t0 + max_new - 1))
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one live slot's decode step reads
+        and writes (the pager's count; 0 for a model with none)."""
+        return self.pager.state_bytes_per_slot
 
     def free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -621,15 +663,16 @@ class DecodeScheduler:
             if match is not None:
                 return self._admit_shared(req, slot, prompt, t0,
                                           max_new, match)
-        # a retention model's prompt runs as chunks of one program; a
-        # softmax model's as ONE bucket
-        tb = (self.prefill_chunk if self.recurrent
+        # a model with a recurrent state runs its prompt as chunks of
+        # one program; one with none as ONE bucket
+        chunked = self._chunk_fn is not None
+        tb = (self.prefill_chunk if chunked
               else prompt_bucket(t0, self.max_context))
-        n_chunks = -(-t0 // tb) if self.recurrent else 1
+        n_chunks = -(-t0 // tb) if chunked else 1
         # resolve (possibly build) the bucket executable BEFORE taking
         # pages: everything after the reservation is under the
         # release-on-failure try below
-        fn = self._chunk_fn if self.recurrent else self._admit_fn(tb)
+        fn = self._chunk_fn if chunked else self._admit_fn(tb)
         pages = self.pager.alloc(self.pages_needed(t0, max_new), req)
         if pages is None:
             return False
@@ -650,12 +693,12 @@ class DecodeScheduler:
                     (self._temp_one if temp is None
                      else jnp.asarray(temp, jnp.float32)),
                     self._topp_dev, jnp.asarray(self._ctr, jnp.int32))
-            if self.recurrent:
-                page = jnp.asarray(pages[0], jnp.int32)
+            if chunked:
+                where = self.pager.cache.chunk.where(slot, pages, row)
                 for c in range(n_chunks):
                     pool, self._prefill_hist, g0 = fn(
                         params, self.pager.pool, self._prefill_hist,
-                        page, jnp.asarray(pad[:, c * tb:(c + 1) * tb]),
+                        where, jnp.asarray(pad[:, c * tb:(c + 1) * tb]),
                         jnp.asarray(c * tb, jnp.int32), *tail)
                     self.pager.pool = pool
             else:
@@ -668,7 +711,7 @@ class DecodeScheduler:
             ts2 = obs.now()
             first = int(np.asarray(g0)[0])  # blocking device sync
             pairs = int(np.asarray(pairs[0])) if (
-                not self.recurrent and pairs) else 0
+                not chunked and pairs) else 0
         except BaseException:
             # a failed prefill must not leak the reservation (the
             # slot was never occupied; its table row resets)
@@ -812,11 +855,16 @@ class DecodeScheduler:
         if self._feed_dirty or self._dev_feed is None:
             assert self._inflight is None, \
                 "feed rebuilt from a mirror one step behind the device"
+            # copies: on a CPU backend ``jnp.asarray`` may ALIAS an
+            # aligned host array, and the mirror is written (a
+            # retirement zeroes its page-table row, ``_collect`` moves
+            # lengths and prev on) while the step launched from this
+            # feed may not have read it yet
             self._dev_feed = {
-                "pt": jnp.asarray(self._page_table),
-                "lengths": jnp.asarray(self._lengths),
-                "prev": jnp.asarray(self._prev),
-                "temps": jnp.asarray(self._temps),
+                "pt": jnp.asarray(self._page_table.copy()),
+                "lengths": jnp.asarray(self._lengths.copy()),
+                "prev": jnp.asarray(self._prev.copy()),
+                "temps": jnp.asarray(self._temps.copy()),
                 "top_p": jnp.asarray(
                     1.0 if self.top_p is None else self.top_p,
                     jnp.float32),
@@ -871,12 +919,11 @@ class DecodeScheduler:
         # written included), from the host's mirror and what the step
         # in flight adds to it: no device read (a retention model
         # walks no KV page: it moves its live slots' states, once
-        # each way)
-        paged = not self.recurrent and self.latent is None
+        # each way; a hybrid does both)
         kv_pages = int(np.sum((self._lengths[act] + pending)
-                              // self.block + 1)) if paged else 0
-        state_bytes = (len(act) * self.state_bytes_per_slot
-                       if self.recurrent else 0)
+                              // self.block + 1)) if (
+                                  self.pager.walks_kv) else 0
+        state_bytes = len(act) * self.pager.state_bytes_per_slot
         # cached positions a latent step's attention reads, the one
         # being written included, and the (slot, chunk) items a
         # layer's walk of them has (all but the first issued ahead)
@@ -1194,13 +1241,14 @@ class DecodeScheduler:
         compiled = seconds > 0
         scalars = (sds((), i32), sds((), jnp.float32),
                    sds((), jnp.float32), sds((), i32))
-        if self.recurrent:
+        if self._chunk_fn is not None:
             # every prompt runs the one chunk program
             buckets = [self.prefill_chunk]
             warmed = [self._chunk_fn.warmup(
                 params, pool_sds,
                 tuple(sds(a.shape, a.dtype) for a in self._prefill_hist),
-                sds((), i32), sds((1, self.prefill_chunk), i32),
+                self._chunk_where_shapes(),
+                sds((1, self.prefill_chunk), i32),
                 sds((), i32), *scalars)]
         else:
             buckets = sorted({prompt_bucket(t, self.max_context)

@@ -113,7 +113,8 @@ class CausalTransformerLM(ZooModel):
     def __init__(self, vocab_size: int = 50257, hidden: int = 768,
                  n_layers: int = 12, n_heads: int = 12,
                  n_kv_heads: Optional[int] = None, max_len: int = 1024,
-                 ffn_mult: float = 4, rope_theta: float = 10000.0,
+                 ffn_mult: float = 4,
+                 rope_theta: Optional[float] = 10000.0,
                  dropout: float = 0.0,
                  sequence_parallel: Optional[str] = None,
                  remat: bool = False, tie_embeddings: bool = False,
@@ -121,19 +122,59 @@ class CausalTransformerLM(ZooModel):
                  cache_quant: Optional[str] = None,
                  seed: int = 123, updater=None,
                  compute_dtype: Optional[str] = None,
-                 mixer: str = "softmax", latent=None, experts=None):
+                 mixer: str = "softmax", latent=None, experts=None,
+                 hybrid=None,
+                 embedding_multiplier: Optional[float] = None,
+                 residual_multiplier: Optional[float] = None,
+                 logits_scaling: Optional[float] = None,
+                 attention_multiplier: Optional[float] = None,
+                 norm_eps: Optional[float] = None):
         # the blocks' sequence mixer: "softmax" attention over a KV
         # cache, "power_retention" (ops/retention.py): a fixed-size
-        # recurrent state per sequence, whatever its length, or
-        # "latent" (ops/latent.py, sized by ``latent``, a
-        # ``LatentSpec``): one compressed row a cached position
-        if mixer not in ("softmax", "power_retention", "latent"):
+        # recurrent state per sequence, whatever its length, "latent"
+        # (ops/latent.py, sized by ``latent``, a ``LatentSpec``): one
+        # compressed row a cached position, or "hybrid" (sized by
+        # ``hybrid``, an ``ops.ssm.HybridSpec``): a kind PER LAYER,
+        # Mamba-2 state-space layers beside softmax attention layers.
+        # ``rope_theta=None`` leaves the attention without positions.
+        # What a published decoder multiplies by, whatever its mixers
+        # (each None: not applied, no multiply in any program): the
+        # embedding's rows by ``embedding_multiplier``, each half's
+        # addition to the residual stream by ``residual_multiplier``,
+        # the logits by ``1 / logits_scaling``, the softmax layers'
+        # scores by ``attention_multiplier`` in ``d^-1/2``'s place;
+        # ``norm_eps`` is the eps of the blocks' and the final norm
+        # (None: the zoo's)
+        if mixer not in ("softmax", "power_retention", "latent",
+                         "hybrid"):
             raise ValueError(
-                f"mixer={mixer!r} "
-                "('softmax' | 'power_retention' | 'latent')")
+                f"mixer={mixer!r} ('softmax' | 'power_retention' | "
+                "'latent' | 'hybrid')")
         if (mixer == "latent") != (latent is not None):
             raise ValueError("mixer='latent' and latent=LatentSpec(...) "
                              "come together")
+        if (mixer == "hybrid") != (hybrid is not None):
+            raise ValueError("mixer='hybrid' and hybrid=HybridSpec(...) "
+                             "come together")
+        if hybrid is not None:
+            if len(hybrid.kinds) != n_layers:
+                raise ValueError(
+                    f"hybrid.kinds names {len(hybrid.kinds)} layers, "
+                    f"n_layers={n_layers}")
+            if cache_quant or serve_quant or sequence_parallel:
+                raise ValueError(
+                    "mixer='hybrid' keeps a float32 state beside its KV "
+                    "cache and a convolution among its 2-D leaves: "
+                    "cache_quant, serve_quant and sequence_parallel do "
+                    "not apply to it")
+        #: ``ops.ssm.HybridSpec`` of a hybrid decoder (its layers'
+        #: kinds, its Mamba sizes), else None
+        self.hybrid = hybrid
+        self.embedding_multiplier = embedding_multiplier
+        self.residual_multiplier = residual_multiplier
+        self.logits_scaling = logits_scaling
+        self.attention_multiplier = attention_multiplier
+        self.norm_eps = norm_eps
         if mixer == "latent" and (cache_quant or sequence_parallel
                                   or serve_quant):
             raise ValueError(
@@ -205,9 +246,10 @@ class CausalTransformerLM(ZooModel):
              .updater(self.updater)
              .compute_data_type(self.compute_dtype)
              .list()
-             .layer(EmbeddingSequenceLayer(n_in=self.vocab_size,
-                                           n_out=self.hidden,
-                                           weight_init="normal")))
+             .layer(EmbeddingSequenceLayer(
+                 n_in=self.vocab_size, n_out=self.hidden,
+                 weight_init="normal",
+                 multiplier=self.embedding_multiplier)))
         for i in range(self.n_layers):
             routed = (self.experts is not None
                       and i >= self.experts.first_dense)
@@ -216,10 +258,20 @@ class CausalTransformerLM(ZooModel):
                 ffn_mult=self.ffn_mult, rope_theta=self.rope_theta,
                 dropout=self.dropout or None, remat=self.remat,
                 sequence_parallel=self.sequence_parallel,
-                mixer=self.mixer, latent=self.latent,
+                mixer=(self.mixer if self.hybrid is None
+                       else self.hybrid.kinds[i]),
+                latent=self.latent, hybrid=self.hybrid,
+                residual_multiplier=self.residual_multiplier,
+                score_scale=self.attention_multiplier,
+                norm_eps=self.norm_eps,
                 ffn="experts" if routed else "dense",
                 experts=self.experts if routed else None))
-        b.layer(RMSNorm())
+        # (a published logits_scaling divides the normed rows: the
+        # head sees them as decoder_infer.logits hands them on)
+        b.layer(RMSNorm(
+            **({} if self.norm_eps is None else {"eps": self.norm_eps}),
+            multiplier=(None if self.logits_scaling is None
+                        else 1.0 / self.logits_scaling)))
         # fused-from-logits sparse softmax CE over the vocabulary —
         # integer next-token labels, no [B,T,V] one-hot materialised
         b.layer(RnnOutputLayer(n_out=self.vocab_size,
@@ -382,9 +434,14 @@ class CausalTransformerLM(ZooModel):
         transformer analog of the reference's rnnTimeStep; any drift
         from TransformerDecoderBlock's training forward is caught by
         test_generate_matches_training_forward)."""
-        cache = {"power_retention": di.DenseState,
-                 "latent": di.DenseLatent}.get(
-                     self.mixer, di.DenseKV)(self, caches, pos)
+        if self.hybrid is not None:
+            cache = di.ByKind(
+                self.hybrid, softmax=di.DenseKV(self, caches[0], pos),
+                mamba2=di.DenseSSM(self, caches[1], pos))
+        else:
+            cache = {"power_retention": di.DenseState,
+                     "latent": di.DenseLatent}.get(
+                         self.mixer, di.DenseKV)(self, caches, pos)
         x = di.stack(params, tok, self, cache.attend, "decode")
         return di.logits(params, x, self, "decode"), tuple(cache.caches)
 
@@ -404,6 +461,23 @@ class CausalTransformerLM(ZooModel):
                     bsz, self.n_kv_heads,
                     self.hidden // self.n_heads)] * self.n_layers)
             caches, attend = cache.caches, cache.attend
+        elif self.hybrid is not None:
+            # each layer's cache by ITS kind: KV rows for the attention
+            # layers, state and convolution tail for the Mamba ones
+            from deeplearning4j_tpu.ops import ssm
+            kv = []
+            rows = di.SSMRows(
+                self, jnp.broadcast_to(jnp.arange(tb)[None, :] < t0,
+                                       (bsz, tb)),
+                [ssm.zero_state(bsz, self.hybrid,
+                                params["layer_0"]["W"].dtype)]
+                * len(self.hybrid.layers("mamba2")))
+            attend = di.ByKind(
+                self.hybrid, mamba2=rows, softmax=di.Attend(
+                    di.causal_prefill(
+                        self, lambda li, k, v: kv.append(di.dense_kv(
+                            k, v, cache_len, False))))).attend
+            caches = (kv, rows.caches)
         elif self.mixer == "latent":
             caches = []
             attend = di.latent_prefill(

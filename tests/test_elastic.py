@@ -159,6 +159,13 @@ def test_agreement_supersedes_proposal_naming_dead_member(tmp_path):
                          "coordinator": "a", "addr": "127.0.0.1",
                          "port": 31001})
     t[0] += 6.0                     # c's lease expires
+    # (so have a's and b's, on this clock: their heartbeats renew them.
+    # Left expired, whichever of the two agrees first evicts the other
+    # and commits alone, and the other then waits for its ack on a
+    # clock that stands still: a wrong epoch, or a run cut at its time
+    # limit, on a loaded machine)
+    a.renew()
+    b.renew()
     recs = {}
     th = threading.Thread(
         target=lambda: recs.__setitem__("a", a.agree_membership(15.0)))

@@ -16,6 +16,7 @@ from deeplearning4j_tpu.nn.layers.base import (_LAYER_REGISTRY,
                                                layer_from_dict)
 from deeplearning4j_tpu.nn import layers as L
 from deeplearning4j_tpu.ops.latent import LatentSpec
+from deeplearning4j_tpu.ops.ssm import HybridSpec
 
 KEY = jax.random.PRNGKey(3)
 
@@ -81,6 +82,9 @@ SPECS = {
     "LatentAttention": (dict(n_heads=2, spec=LatentSpec(
         q_rank=6, kv_rank=4, nope=4, rope=2, v=4,
         yarn=(4.0, 8, 32.0, 1.0, 1.0, 1.0))), (5, 8)),
+    "Mamba2Mixer": (dict(spec=HybridSpec(
+        kinds=("mamba2",), d_inner=16, n_heads=2, d_state=4, chunk=4)),
+        (5, 8)),
     "TransformerEncoderBlock": (dict(n_heads=2, ffn_mult=2), (5, 4)),
     "PositionalEmbeddingLayer": ({}, (5, 4)),
     "ClsTokenPoolLayer": ({}, (5, 4)),

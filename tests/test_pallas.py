@@ -466,9 +466,10 @@ def test_latent_decode_dispatch_line(monkeypatch, rng):
 
 
 def test_paged_decode_dispatch_line(monkeypatch, rng):
-    """Which shapes the kernel takes is read off the operands: a
-    128-lane head over 8-row kv tiles in a float pool on the kernel
-    platform; everything else runs the reference, and says the same."""
+    """Which shapes the kernel takes is read off the operands: a head
+    of 128 lanes, or of 64 (K and V the halves of one tile), over
+    8-row kv tiles in a float pool on the kernel platform; everything
+    else runs the reference, and says the same."""
     calls = []
     real = pk._paged_decode_call
     monkeypatch.setattr(
@@ -495,10 +496,11 @@ def test_paged_decode_dispatch_line(monkeypatch, rng):
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     assert run(8, 8, 128) == 1              # over the line: the kernel
     assert run(16, 8, 128) == 2
-    assert run(8, 8, 64) == 2               # head under 128 lanes
-    assert run(12, 12, 128) == 2            # kv heads not whole tiles
-    assert run(4, 2, 128) == 2
-    assert run(8, 8, 128, quant=True) == 2  # int8 codes and scales
+    assert run(8, 8, 64) == 3               # half a tile: the packed form
+    assert run(8, 8, 32) == 3               # neither a tile nor half
+    assert run(12, 12, 128) == 3            # kv heads not whole tiles
+    assert run(4, 2, 128) == 3
+    assert run(8, 8, 128, quant=True) == 3  # int8 codes and scales
 
 
 def test_reference_scan_matches_full_attention(rng):

@@ -543,3 +543,149 @@ def test_latent_prefill_bucket_compiles_for_v5e(chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2.5e9
     assert mem.temp_size_in_bytes < 2 * 10 ** 9, mem
+
+
+# -- the hybrid state-space cell (granite4h.chat-saturated) ----------------
+
+_HYB = dict(slots=64, n=128, cols=4096, ssm_layers=36, heads=32,
+            kv_heads=8, head=64, attn_layers=4, max_pages=192, block=16)
+
+
+def _state_sized_ops(hlo, pool):
+    """Opcodes of the instructions whose result is the float32 state
+    pool's shape."""
+    import re
+    shape = "f32[" + ",".join(map(str, pool.shape)) + "]"
+    return set(re.findall(r"= " + re.escape(shape) + r"\S* ([\w\-]+)\(",
+                          hlo))
+
+
+def test_ssm_decode_compiles_for_v5e(chip):
+    """The kernel alone at the cell's sizes: Mosaic takes it (the
+    broadcast-and-transpose of ``B`` and ``C``, 8 MB of pipelined
+    state blocks under the raised VMEM limit), and the 4.9 GB pool
+    goes through in place: aliased, no temporary of its size."""
+    c = _HYB
+    f32 = jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    pool = sds((c["ssm_layers"], 1 + c["slots"], c["n"], c["cols"]))
+
+    def decode(x, b, cc, delta, a_neg, d_skip, pool, pages, active):
+        return pallas_kernels.ssm_decode(x, b, cc, delta, a_neg, d_skip,
+                                         pool, 7, pages, active)
+
+    compiled = jax.jit(decode, donate_argnums=(6,)).lower(
+        sds((c["slots"], c["cols"]), BF16), sds((c["slots"], c["n"])),
+        sds((c["slots"], c["n"])), sds((c["slots"], 64)), sds((64,)),
+        sds((64,), BF16), pool, sds((c["slots"],), jnp.int32),
+        sds((c["slots"],), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssm_decode" in hlo
+    assert _state_sized_ops(hlo, pool) <= {
+        "parameter", "get-tuple-element", "bitcast"}
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (8 << 20), mem
+    assert mem.alias_size_in_bytes >= 4.9e9      # the pool, in place
+
+
+def test_paged_decode_compiles_for_v5e_at_heads_of_64(chip):
+    """The packed form at the hybrid cell's sizes: 32 query heads of
+    64 over 8 kv heads whose K and V share one 128-lane row; one
+    kernel, the 1.6 GB pool read in place."""
+    c = _HYB
+    pool = jax.ShapeDtypeStruct(
+        (c["attn_layers"], 1 + c["slots"] * c["max_pages"], c["block"],
+         c["kv_heads"], 2 * c["head"]), BF16, sharding=chip)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def attend(q, pool, pt, n_live):
+        assert pallas_kernels._use_paged_kernel(q, (pool,))
+        return pallas_kernels.paged_decode_attention(q, (pool,), 2, pt,
+                                                     n_live)
+
+    compiled = jax.jit(attend).lower(
+        sds((c["slots"], c["heads"], c["head"]), BF16), pool,
+        sds((c["slots"], c["max_pages"]), jnp.int32),
+        sds((c["slots"],), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert _pool_sized_ops(hlo, pool) == ["parameter"], hlo[:2000]
+    assert compiled.memory_analysis().temp_size_in_bytes < (4 << 20)
+
+
+def _hybrid_sched(chip, monkeypatch):
+    """The scheduler and the shapes of ``granite4h.chat-saturated``:
+    the configuration as the benchmark's builder reads it, bf16
+    leaves, nothing of the 3.2 B parameters and nothing of the 6.6 GB
+    of pools made (the pager's ``zeros`` are shapes while it is
+    built)."""
+    import json
+    from pathlib import Path
+    from benchmarks.models import hybrid_ssm_lm as builder
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.serving import DecodeScheduler, kv_pager
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmarks" / "configs"
+                      / "granite-4.0-h-micro.json").read_text())
+    model = CausalTransformerLM(
+        vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], max_len=4096,
+        ffn_mult=cfg["shared_intermediate_size"] / cfg["hidden_size"],
+        rope_theta=None, tie_embeddings=True,
+        updater=upd.Sgd(learning_rate=0.0), compute_dtype="bfloat16",
+        seed=1, mixer="hybrid", hybrid=builder.spec(cfg),
+        **builder.scalars(cfg))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, BF16, sharding=chip),
+        jax.eval_shape(lambda: model.init().params))
+    with monkeypatch.context() as mp:
+        mp.setattr(kv_pager.jnp, "zeros",
+                   lambda shape, dtype=None: jax.ShapeDtypeStruct(
+                       shape, dtype, sharding=chip))
+        sched = DecodeScheduler(model, None, max_slots=_HYB["slots"],
+                                block=16, max_context=3072)
+    return sched, params, sched.pager.pool
+
+
+def test_hybrid_decode_step_compiles_for_v5e_in_place(chip, monkeypatch):
+    """The whole ``serving.decode_step`` of the hybrid cell, 40 layers:
+    ONE lowering of each kernel for all the layers of its kind, 36 + 4
+    kernel calls, the float32 state pool touched by nothing but them,
+    12.97 GB of arguments (weights 6.38, state 4.91, tails 0.06, KV
+    1.61) and the step's temporaries under 64 MB beside them."""
+    import re
+    sched, params, pool = _hybrid_sched(chip, monkeypatch)
+    assert [a.shape for a in pool] == [
+        (4, 1 + 64 * 192, 16, 8, 128), (36, 65, 128, 4096),
+        (36, 65, 3 * 4352)]
+    lowered = sched._step_fn.lower(
+        params, pool,
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes()))
+    text = lowered.as_text()
+    for kernel in ("ssm_decode", "paged_decode"):
+        funcs = re.findall(r"func\.func private @(\w*" + kernel + r"\w*)",
+                           text)
+        assert len(funcs) == 1, funcs
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert sum("ssm_decode" in ln for ln in calls) == 36
+    assert sum("paged_decode_attention" in ln for ln in calls) == 4
+    touched = _state_sized_ops(hlo, pool[1])
+    assert touched and touched <= {"parameter", "get-tuple-element",
+                                   "bitcast"}, touched
+    mem = compiled.memory_analysis()
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9, mem
+    assert mem.alias_size_in_bytes >= 6.5e9
+    assert mem.temp_size_in_bytes < (64 << 20), mem

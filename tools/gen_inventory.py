@@ -467,6 +467,14 @@ stored (one fixed-size page a sequence; 0 for a KV pool) and
 steps read and wrote (`state_bytes` on every `serving.decode_step`
 record): over the steps' device time it is the bandwidth the state
 kernel reaches (ARCHITECTURE.md §15).
+A hybrid decoder (`mixer="hybrid"`: Mamba-2 layers beside attention
+layers) does both at once: its steps count `state_bytes` (the float32
+states and the convolution tails of its Mamba layers, a live slot's
+read once and written once) AND `kv_pages` (what its attention layers
+walk), `dl4j_tpu_serving_state_pool_bytes` reads its state pool as
+stored (states and tails, trash page included) and
+`dl4j_tpu_serving_kv_pages_free` its KV pages alone: a sequence's state
+page is its decode slot's, so only a KV page can leak.
 A latent-attention model (`mixer="latent"`) walks latent pages:
 `dl4j_tpu_serving_latent_rows_read_total` counts the cached positions
 its decode steps' attention read (`latent_rows` on every

@@ -243,7 +243,7 @@ SCOPE_SITES = {
                               "flash_block_bwd",
                               "paged_decode_attention",
                               "latent_decode_attention",
-                              "retention_decode",
+                              "retention_decode", "ssm_decode",
                               "threshold_encode", "threshold_decode"),
     "ops/fused_norms.py": ("rms_norm", "add_rms_norm", "layer_norm"),
 }

@@ -33,7 +33,7 @@ from deeplearning4j_tpu.nn.layers.special import FrozenLayer
 from deeplearning4j_tpu.nn.multilayer import _FUSABLE
 from deeplearning4j_tpu.nn.vertices import (GraphVertex, vertex_from_dict)
 from deeplearning4j_tpu.ops import losses as losses_mod
-from deeplearning4j_tpu.perf import sentry
+from deeplearning4j_tpu.perf import aot_store, sentry
 from deeplearning4j_tpu.resilience import faults
 
 
@@ -535,7 +535,10 @@ class ComputationGraph:
                  rng_stack))
             return p, o, s, losses
 
+        # said so that a warm start loads the loop by a key that
+        # needs no trace (perf/aot_store.py)
         return sentry.jit(loop, name="ComputationGraph.train_loop",
+                          identity=lambda: aot_store.net_identity(self),
                           donate_argnums=(0, 1, 2))
 
     def _refresh_ambient_trace(self):

@@ -34,7 +34,7 @@ from deeplearning4j_tpu.nn.layers.recurrent import (
 from deeplearning4j_tpu.nn.layers.special import FrozenLayer
 from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.ops import losses as losses_mod
-from deeplearning4j_tpu.perf import sentry
+from deeplearning4j_tpu.perf import aot_store, sentry
 from deeplearning4j_tpu.resilience import faults
 
 # losses that support the fused from_logits path, keyed by activation
@@ -443,7 +443,10 @@ class MultiLayerNetwork:
                 (x_stack, y_stack, rng_stack))
             return p, o, s, losses
 
+        # said so that a warm start loads the loop by a key that
+        # needs no trace (perf/aot_store.py)
         return sentry.jit(loop, name="MultiLayerNetwork.train_loop",
+                          identity=lambda: aot_store.net_identity(self),
                           donate_argnums=(0, 1, 2))
 
     def _refresh_ambient_trace(self):

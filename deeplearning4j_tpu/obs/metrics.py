@@ -84,6 +84,8 @@ FAMILIES = {
     "dl4j_tpu_retrace_unplanned_shapes": "gauge",
     "dl4j_tpu_retrace_compiles_total": "counter",
     "dl4j_tpu_aot_hits_total": "counter",
+    "dl4j_tpu_aot_store_hits_total": "counter",
+    "dl4j_tpu_aot_store_misses_total": "counter",
     "dl4j_tpu_compile_time_seconds_total": "counter",
     "dl4j_tpu_compile_cache_requests_total": "counter",
     "dl4j_tpu_compile_cache_hits_total": "counter",
@@ -763,6 +765,14 @@ def _perf_collector():
     yield ("dl4j_tpu_aot_hits_total", "counter",
            "live calls served by a warmed AOT executable",
            [({"function": n}, s["aot_hits"]) for n, s in rows])
+    yield ("dl4j_tpu_aot_store_hits_total", "counter",
+           "executables loaded from the compile store by a key that "
+           "needed no trace",
+           [({"function": n}, s["store_hits"]) for n, s in rows])
+    yield ("dl4j_tpu_aot_store_misses_total", "counter",
+           "keyed programs the compile store did not hold: traced, "
+           "compiled and put",
+           [({"function": n}, s["store_misses"]) for n, s in rows])
     yield ("dl4j_tpu_compile_time_seconds_total", "counter",
            "wall time XLA spent compiling sentried entry points",
            [({}, sentry.total_compile_time_s())])

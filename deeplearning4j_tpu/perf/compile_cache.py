@@ -158,6 +158,17 @@ def configure(cache_dir: Optional[str] = None,
     return cache_dir
 
 
+def note_store_load() -> None:
+    """An executable loaded from the compile store by its key
+    (``perf/aot_store.py``): a compile request served without a
+    compile, as a hit of the XLA plane is. (A key that is absent
+    counts nothing here: the compile that follows makes its own
+    request of the XLA plane.)"""
+    with _LOCK:
+        _state["requests"] += 1
+        _state["hits"] += 1
+
+
 def active_store():
     """The :class:`~deeplearning4j_tpu.perf.compile_store.CompileStore`
     the cache is routed through, or None (flat dir / disabled)."""

@@ -86,11 +86,16 @@ def program_fingerprint(**parts: Any) -> str:
 class CompileStore:
     """One fence's view of the content-addressed store rooted at
     ``root``. Counters: puts / hits / misses (fence mismatch or
-    absent) / quarantined (corrupt entries moved aside)."""
+    absent) / quarantined (corrupt entries moved aside) / evicted
+    (entries removed to keep ``max_bytes``)."""
 
     def __init__(self, root, *, jaxlib: Optional[str] = None,
-                 topology: Optional[str] = None):
+                 topology: Optional[str] = None,
+                 max_bytes: Optional[int] = None):
         self.root = Path(os.path.expanduser(str(root)))
+        #: byte limit of the object plane (None: none): a ``put``
+        #: removes the entries least recently got until it holds
+        self.max_bytes = max_bytes
         self.jaxlib = jaxlib if jaxlib is not None else default_jaxlib()
         self.topology = (topology if topology is not None
                          else default_topology())
@@ -102,7 +107,7 @@ class CompileStore:
         self.objects_dir = self.fence_dir / "objects"
         self._lock = threading.Lock()
         self._counters = {"puts": 0, "hits": 0, "misses": 0,
-                          "quarantined": 0}
+                          "quarantined": 0, "evicted": 0}
         self.xla_dir.mkdir(parents=True, exist_ok=True)
         self.objects_dir.mkdir(parents=True, exist_ok=True)
 
@@ -135,7 +140,34 @@ class CompileStore:
         path = atomic_write_bytes(self.entry_path(fingerprint), blob)
         with self._lock:
             self._counters["puts"] += 1
+        if self.max_bytes is not None:
+            self._evict(keep=Path(path))
         return Path(path)
+
+    def _evict(self, keep: Path) -> None:
+        """Remove the entries least recently got (a ``get`` stamps
+        the file's time) until the plane holds ``max_bytes``; the
+        entry just put stays. A reader that loses a race with this
+        sees a miss."""
+        entries, total = [], 0
+        for p in self.objects_dir.glob("*" + ENTRY_SUFFIX):
+            try:
+                st = p.stat()
+            except OSError:
+                continue
+            total += st.st_size
+            if p != keep:
+                entries.append((st.st_mtime, st.st_size, p))
+        for _mtime, size, p in sorted(entries):
+            if total <= self.max_bytes:
+                break
+            try:
+                p.unlink()
+            except OSError:
+                continue
+            total -= size
+            with self._lock:
+                self._counters["evicted"] += 1
 
     # -- read -------------------------------------------------------------
     def get(self, fingerprint: str) -> Optional[bytes]:
@@ -151,6 +183,11 @@ class CompileStore:
                 self._counters["misses"] += 1
             return None
         payload = self._validate(path, blob, fingerprint)
+        if payload is not None and self.max_bytes is not None:
+            try:                    # least-recent-load order
+                os.utime(path)
+            except OSError:
+                pass
         with self._lock:
             self._counters["hits" if payload is not None
                            else "misses"] += 1
@@ -183,6 +220,14 @@ class CompileStore:
             self._quarantine(path, "size/crc mismatch")
             return None
         return payload
+
+    def quarantine(self, fingerprint: str, reason: str) -> None:
+        """Move ``fingerprint``'s entry aside: its bytes were sound
+        but its reader could not use them (an executable the backend
+        refuses to load)."""
+        path = self.entry_path(fingerprint)
+        if path.exists():
+            self._quarantine(path, reason)
 
     def _quarantine(self, path: Path, reason: str) -> None:
         """Move a damaged entry to ``<fence>/corrupt/`` — out of every

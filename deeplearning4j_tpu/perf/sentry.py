@@ -32,6 +32,19 @@ JAX inside its caller's: a reader takes the union of the records'
 intervals, and :attr:`FunctionStats.phase_s` (plain sums, nested ones
 counted twice) says where one function's ``compile_time_s`` went:
 tracing, lowering, or compiling/loading.
+
+**A key that needs no trace.** ``jit(fn, name=..., identity=...)``
+takes a callable of the entry point's owner that returns, as plain
+data, everything the traced program depends on that is not an
+argument (``None``, or ``aot_store.CannotSay`` raised: "I cannot
+say", and nothing here changes).
+With one, a new signature is first looked up in the compile store's
+object plane (``perf/aot_store.py``) by a key computed WITHOUT
+tracing; on a hit the stored executable is loaded straight into the
+table :meth:`SentryJit.warmup` fills, and the program is neither
+traced nor lowered (``store_hits``; ``traces`` stays 0). On a miss it
+is lowered and compiled through the AOT path, serialized and put
+(``store_misses``).
 """
 from __future__ import annotations
 
@@ -166,6 +179,9 @@ class FunctionStats:
         self.warmed = 0               # compiles done ahead of traffic
         self.aot_hits = 0             # live calls served by a warmed
                                       # executable (zero-compile proof)
+        self.store_hits = 0           # executables loaded by their key,
+                                      # neither traced nor lowered
+        self.store_misses = 0         # keyed programs compiled and put
         self.compile_time_s = 0.0     # wall-time spent compiling
         # seconds JAX reported per compile phase while this function
         # was on the compiling thread's stack
@@ -210,6 +226,8 @@ class FunctionStats:
                 "compiles": self.compiles,
                 "warmed": self.warmed,
                 "aot_hits": self.aot_hits,
+                "store_hits": self.store_hits,
+                "store_misses": self.store_misses,
                 "compile_time_s": self.compile_time_s,
                 **{f"{k}_s": v for k, v in self.phase_s.items()},
             }
@@ -228,13 +246,21 @@ class SentryJit:
     cache), so a warmed signature is routed straight to its stored
     executable — the first real call on it neither traces nor compiles
     (``aot_hits`` in the stats is the proof).
+
+    With an ``identity`` (module doc) and a persistent cache
+    directory, both ``warmup`` and the first call on a new signature
+    first ask the compile store for the executable by its key, and
+    fill the same table from the stored bytes.
     """
 
     def __init__(self, fn, name: Optional[str] = None,
-                 budget: Optional[int] = None, **jit_kwargs):
+                 budget: Optional[int] = None, identity=None,
+                 **jit_kwargs):
         import jax
         self._fn = fn
         self._aot: Dict[tuple, Any] = {}   # sig -> Compiled
+        self._identity = identity
+        self._jit_kwargs = jit_kwargs
         self.name = name or getattr(fn, "__name__", "jit_fn")
         self.stats = FunctionStats(self.name, budget)
         stats = self.stats
@@ -250,8 +276,11 @@ class SentryJit:
 
     def __call__(self, *args, **kwargs):
         st = self.stats
-        if self._aot:
-            compiled = self._aot.get(signature((args, kwargs)))
+        if self._aot or self._identity is not None:
+            sig = signature((args, kwargs))
+            compiled = self._aot.get(sig)
+            if compiled is None and self._identity is not None:
+                compiled = self._by_key(sig, args, kwargs)
             if compiled is not None:
                 try:
                     out = compiled(*args, **kwargs)
@@ -282,23 +311,102 @@ class SentryJit:
         """AOT-compile for the given argument signature (concrete
         arrays and ``ShapeDtypeStruct``s mix freely), keep the
         executable for dispatch, and mark the signature PLANNED.
-        Idempotent per signature. Returns compile seconds (0.0 when
-        the signature was already traced)."""
+        Idempotent per signature. Returns the seconds it took to have
+        the executable, compiled or loaded by its key (0.0 when the
+        signature was already traced or loaded)."""
         st = self.stats
         sig = signature((args, kwargs))
         st.note_plan(sig)
         with _LOCK:
-            if sig in st.signatures:
-                return 0.0          # already traced/compiled
+            if sig in st.signatures or (self._identity is not None
+                                        and sig in self._aot):
+                return 0.0          # already traced/compiled/loaded
         t0 = time.perf_counter()
-        with _compiling(st):
-            self._aot[sig] = self._jitted.lower(*args,
-                                                **kwargs).compile()
+        keyed = self._keyed(args, kwargs)
+        if keyed is None:
+            with _compiling(st):
+                self._aot[sig] = self._jitted.lower(*args,
+                                                    **kwargs).compile()
+        else:
+            self._aot[sig] = self._load_or_put(keyed, args, kwargs)
         dt = time.perf_counter() - t0
         with _LOCK:
             st.warmed += 1
             st.compile_time_s += dt
         return dt
+
+    # -- the compile store ------------------------------------------------
+    def _keyed(self, args, kwargs):
+        """``(store, key)`` of this signature's executable, or None:
+        no identity, an owner that cannot say, or no cache directory
+        (then the entry point takes the path it always took)."""
+        if self._identity is None:
+            return None
+        from deeplearning4j_tpu.perf import aot_store
+        store = aot_store.store()
+        if store is None:
+            return None
+        try:
+            said = self._identity()
+            if said is not None:
+                return store, aot_store.program_key(
+                    self.name, self._jit_kwargs, args, kwargs, said)
+        except aot_store.CannotSay:
+            pass
+        self._identity = None       # the owner cannot say: for good
+        return None
+
+    def _load_or_put(self, keyed, args, kwargs):
+        """The executable under ``keyed``: loaded, or lowered and
+        compiled as always and then put. A load makes the records a
+        persistent-cache hit makes (``compile/backend_compile`` around
+        ``compile/cache_retrieval``) and counts as one."""
+        from deeplearning4j_tpu.obs import trace
+        from deeplearning4j_tpu.perf import aot_store, compile_cache
+
+        store, key = keyed
+        st = self.stats
+        t0 = trace.now()
+        compiled = aot_store.load(store, key, args, kwargs)
+        if compiled is not None:
+            t1 = trace.now()
+            trace.record("compile/cache_retrieval", t0, t1, st.name,
+                         fun=st.name)
+            trace.record("compile/backend_compile", t0, trace.now(),
+                         st.name, fun=st.name)
+            compile_cache.note_store_load()
+            with _LOCK:
+                st.store_hits += 1
+                st.phase_s["cache_retrieval"] += t1 - t0
+                st.phase_s["backend_compile"] += t1 - t0
+            return compiled
+        with _LOCK:
+            st.store_misses += 1
+        with _compiling(st):
+            with aot_store.no_xla_write():
+                compiled = self._jitted.lower(*args, **kwargs).compile()
+            if not aot_store.save(store, key, compiled):
+                # not storable: leave it in the XLA plane as before
+                self._identity = None
+                compiled = self._jitted.lower(*args, **kwargs).compile()
+        return compiled
+
+    def _by_key(self, sig, args, kwargs):
+        """A live call on a signature nothing warmed: the executable
+        by its key, kept for this signature's later calls; None where
+        the entry point has no key."""
+        keyed = self._keyed(args, kwargs)
+        if keyed is None:
+            return None
+        st = self.stats
+        before = st.traces
+        t0 = time.perf_counter()
+        compiled = self._aot[sig] = self._load_or_put(keyed, args, kwargs)
+        with _LOCK:
+            st.compile_time_s += time.perf_counter() - t0
+            if st.traces != before:
+                st.compiles += 1
+        return compiled
 
     # AOT inspection passthroughs
     def lower(self, *args, **kwargs):
@@ -310,9 +418,13 @@ class SentryJit:
 
 
 def jit(fn, *, name: Optional[str] = None,
-        budget: Optional[int] = None, **jit_kwargs) -> SentryJit:
-    """Drop-in ``jax.jit`` with retrace accounting (see module doc)."""
-    return SentryJit(fn, name=name, budget=budget, **jit_kwargs)
+        budget: Optional[int] = None, identity=None,
+        **jit_kwargs) -> SentryJit:
+    """Drop-in ``jax.jit`` with retrace accounting; ``identity`` lets
+    a warm start load the program by a key that needs no trace (see
+    module doc)."""
+    return SentryJit(fn, name=name, budget=budget, identity=identity,
+                     **jit_kwargs)
 
 
 # -- global controls --------------------------------------------------------
@@ -342,7 +454,8 @@ def stats() -> Dict[str, Dict[str, Any]]:
         recs = [(s.name, s.snapshot()) for s in _live_stats()]
     out: Dict[str, Dict[str, Any]] = {}
     for name, snap in recs:
-        if snap["traces"] == 0 and snap["warmed"] == 0:
+        if snap["traces"] == 0 and snap["warmed"] == 0 \
+                and snap["store_hits"] == 0:
             continue
         if name not in out:
             out[name] = snap
@@ -372,6 +485,7 @@ def reset() -> None:
     with _LOCK:
         for s in _live_stats():
             s.traces = s.compiles = s.warmed = s.aot_hits = 0
+            s.store_hits = s.store_misses = 0
             s.compile_time_s = 0.0
             s.phase_s = dict.fromkeys(COMPILE_PHASES.values(), 0.0)
             s.signatures.clear()

@@ -359,6 +359,32 @@ class DecodeScheduler:
                         if self.prefix_sharing else None)
 
     # -- jitted entry points (lint rule 7: sentry.jit, WARMUP_FEEDS) -----
+    def _identity(self, **program):
+        """What one of this scheduler's traced programs depends on
+        beside its arguments, as ``sentry.jit(identity=...)`` takes
+        it: a warm start then loads the program by a key that needs no
+        trace (``perf/aot_store.py``). The model and the pager are
+        said attribute by attribute (their specs, sizes and dtypes, the
+        pager's cache class by name; of the model all but what only
+        training reads: its init seed and its updater), the scheduler
+        by every constructor argument a builder closes over and what
+        ``__init__`` derives from them; ``program`` is the builder's
+        own (a bucket, a draft width). A model of a class from outside
+        the package cannot be said (``aot_store.CannotSay``), and the
+        programs are traced."""
+        from deeplearning4j_tpu.perf import aot_store
+        own = {k: getattr(self, k) for k in (
+            "max_slots", "block", "max_context", "max_pages_per_seq",
+            "prefill_chunk", "spec_k", "sample", "top_k", "top_p",
+            "seed", "prefix_sharing", "expert_layers", "recurrent")}
+        dims = {k: v for k, v in vars(self.model).items()
+                if not k.startswith("_") and k not in ("seed", "updater")}
+        return aot_store.describe({
+            "model": type(self.model), "dims": dims,
+            "pager": self.pager,
+            "pool": [(a.shape, a.dtype) for a in self.pager.pool],
+            "scheduler": own, "program": program})
+
     def _first_token(self, params, row, scope, temp, top_p, ctr):
         """A prefill's TTFT token from the prompt's last row ``[1, F]``:
         the head, then the pick rule of the decode step under the
@@ -416,7 +442,7 @@ class DecodeScheduler:
         # writes pages in place — without this, every call on a
         # donation-capable backend copies the whole multi-MB pool
         return sentry.jit(step, name="serving.decode_step",
-                          donate_argnums=(1,))
+                          identity=self._identity, donate_argnums=(1,))
 
     def _build_spec_step_fn(self, k: int):
         """Speculative verify step: score ``prev`` plus the k-1 host
@@ -462,6 +488,7 @@ class DecodeScheduler:
             return m, e, cache.pool, lengths + e, prev_next
 
         return sentry.jit(step, name=f"serving.spec_step_k{k}",
+                          identity=lambda: self._identity(k=k),
                           donate_argnums=(1,))
 
     def _build_admit_fn(self, tb: int):
@@ -499,6 +526,7 @@ class DecodeScheduler:
             return out + (sum(jnp.sum(p) for p in pairs),) if pairs \
                 else out
         return sentry.jit(admit, name="serving.prefill",
+                          identity=lambda: self._identity(bucket=tb),
                           donate_argnums=(1,))
 
     def _build_chunk_admit_fn(self):
@@ -542,6 +570,7 @@ class DecodeScheduler:
             return cache.pool, cache.carried, g0
 
         return sentry.jit(admit, name="serving.prefill",
+                          identity=lambda: self._identity(chunk=chunk),
                           donate_argnums=(1, 2))
 
     def _chunk_where_shapes(self):
@@ -591,6 +620,7 @@ class DecodeScheduler:
                 params, row, "suffix_prefill", temp, top_p, ctr)
 
         return sentry.jit(admit, name="serving.suffix_prefill",
+                          identity=lambda: self._identity(suffix=sb),
                           donate_argnums=(1,))
 
     def _build_cow_fn(self):
@@ -604,7 +634,7 @@ class DecodeScheduler:
         # donation-capable backend rather than a whole-pool copy —
         # this keeps shared admissions O(suffix), not O(pool)
         return sentry.jit(self.pager.copy_page, name="serving.cow_copy",
-                          donate_argnums=(0,))
+                          identity=self._identity, donate_argnums=(0,))
 
     def _suffix_fn(self, sb: int):
         fn = self._suffix_fns.get(sb)

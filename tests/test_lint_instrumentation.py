@@ -335,6 +335,42 @@ def test_lint_rule7_missing_feed_table(tmp_path):
     assert any("no WARMUP_FEEDS dict literal" in p for p in problems)
 
 
+def test_lint_rule7_scheduler_and_train_loop_say_what_they_are(tmp_path):
+    """Rule 7: an entry point built in serving/scheduler.py, or a
+    train loop anywhere, without identity= is flagged; one with it,
+    and any other entry point, is not."""
+    pkg = tmp_path / "pkg"
+    (pkg / "serving").mkdir(parents=True)
+    (pkg / "nn").mkdir()
+    (pkg / "serving" / "scheduler.py").write_text(
+        "from deeplearning4j_tpu.perf import sentry\n"
+        "from deeplearning4j_tpu import obs\n"
+        "WARMUP_FEEDS = {'_build_step_fn': 'feed',\n"
+        "                '_build_mute_fn': 'feed'}\n"
+        "class S:\n"
+        "    def _build_step_fn(self):\n"
+        "        return sentry.jit(lambda x: x, name='s.step',\n"
+        "                          identity=self._identity)\n"
+        "    def _build_mute_fn(self):\n"
+        "        return sentry.jit(lambda x: x, name='s.mute')\n"
+        "    def warmup(self):\n"
+        "        assert WARMUP_FEEDS\n"
+        "        obs.record_step('e', 0.0, 0.0, 0.0, 0.0)\n")
+    (pkg / "nn" / "net.py").write_text(
+        "from deeplearning4j_tpu.perf import sentry\n"
+        "from deeplearning4j_tpu import obs\n"
+        "obs.record_step('e', 0.0, 0.0, 0.0, 0.0)\n"
+        "said = sentry.jit(lambda x: x, name='Net.train_loop',\n"
+        "                  identity=lambda: {})\n"
+        "mute = sentry.jit(lambda x: x, name='Net.train_loop')\n"
+        "step = sentry.jit(lambda x: x, name='Net.train_step')\n")
+    problems = [p for p in lint_instrumentation.run(pkg)
+                if "without identity=" in p]
+    assert len(problems) == 2
+    assert any("serving/scheduler.py:10" in p for p in problems)
+    assert any("nn/net.py:6" in p for p in problems)
+
+
 def _spec_scheduler(tmp_path, text):
     sdir = tmp_path / "pkg" / "serving"
     sdir.mkdir(parents=True, exist_ok=True)

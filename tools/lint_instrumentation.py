@@ -77,7 +77,10 @@ Twelve AST rules over ``deeplearning4j_tpu/``:
    entry) is a compile the warmup can never reach: the first live
    request pays it mid-traffic. Same shape as rule 4 (the
    ``ParallelWrapper`` feed-table rule): builders ⊆ feeds ⊆ builders,
-   and ``warmup`` must actually read the table.
+   and ``warmup`` must actually read the table. And every entry point
+   built in ``serving/scheduler.py``, like every ``*.train_loop`` in
+   the package, says what it is (``sentry.jit(..., identity=...)``),
+   so that a warm start loads it by a key that needs no trace.
 
 8. **The device-time observatory's scope contract holds.** Per-layer
    device-time attribution (``obs/devtime.py``, ARCHITECTURE.md §16)
@@ -722,6 +725,34 @@ def _lint_serving_jits(package_dir: Path) -> List[str]:
             problems.append(
                 f"{rel}: no warmup() reads WARMUP_FEEDS — the feed "
                 "table is dead and serving entry points cold-trace")
+    return problems
+
+
+def _lint_program_identities(package_dir: Path) -> List[str]:
+    """Rule 7, the key that needs no trace: every entry point built in
+    ``serving/scheduler.py``, and every ``*.train_loop`` anywhere in
+    the package, passes ``identity=`` to ``sentry.jit`` — without one
+    a warm start traces and lowers the program again only to find its
+    executable (``perf/aot_store.py``)."""
+    problems: List[str] = []
+    for path in sorted(package_dir.rglob("*.py")):
+        rel = path.relative_to(package_dir).as_posix()
+        try:
+            tree = ast.parse(path.read_text())
+        except SyntaxError:
+            continue                # rule-agnostic: lint_file reports it
+        for c in _sentry_jit_calls(tree):
+            kws = {k.arg: k.value for k in c.keywords}
+            name = kws.get("name")
+            loop = (isinstance(name, ast.Constant)
+                    and str(name.value).endswith("train_loop"))
+            if (rel == SCHEDULER_PATH or loop) and "identity" not in kws:
+                problems.append(
+                    f"{rel}:{c.lineno}: sentry.jit without identity= — "
+                    "a serving program or a train loop that does not "
+                    "say what it is: every warm start traces and "
+                    "lowers it again to find an executable it could "
+                    "load by a key (perf/aot_store.py)")
     return problems
 
 
@@ -1374,6 +1405,7 @@ def run(package_dir: Path = PACKAGE,
     problems.extend(_lint_metric_families(package_dir, tools_dir,
                                           docs_dir))
     problems.extend(_lint_serving_jits(package_dir))
+    problems.extend(_lint_program_identities(package_dir))
     problems.extend(_lint_spec_decode(package_dir, tools_dir,
                                       docs_dir))
     problems.extend(_lint_devtime_scopes(package_dir, tools_dir,

@@ -16,7 +16,10 @@ that goes by each layer's kind (:class:`ByKind`).
 The dense layouts' cache objects live here, the paged pool's with the
 pager (``serving/kv_pager.py``). ``dims`` is anything with
 ``n_layers``, ``n_heads``, ``n_kv_heads``, ``rope_theta`` (None: no
-positional term) and ``tie_embeddings``: the zoo model itself (a
+positional term; with ``rope_layers`` the layers that rotate, the
+others carrying no positions: :func:`layer_theta`), ``windowed`` (a
+:class:`WindowSpec`: the layers whose keys a sliding window bounds,
+:func:`layer_window`) and ``tie_embeddings``: the zoo model itself (a
 latent mixer's sizes are its ``latent``, its expert layers' its
 ``experts``, a hybrid's kinds and Mamba sizes its ``hybrid``). What a
 published decoder multiplies by is read from ``dims`` too, each where
@@ -34,6 +37,9 @@ of ``ops/moe.py`` (this chip's experts beside the shared one).
 Imports ``ops/`` and ``nn/layers/``, never ``zoo/`` or ``serving/``.
 """
 from __future__ import annotations
+
+import dataclasses
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +90,58 @@ def rotary_rows(x, theta: float, pos):
                             x1 * sin + x2 * cos], axis=-1)
 
 
+@dataclasses.dataclass(frozen=True)
+class WindowSpec:
+    """A decoder whose softmax layers are of two KINDS: a ``"full"``
+    layer's query sees every earlier key, a ``"window"`` layer's the
+    last ``window`` keys only, its own included (key ``j`` is visible
+    to query ``t`` iff ``t - window < j <= t``). One kind a layer; the
+    kinds share the mixer's parameters and arithmetic and differ in
+    what a cache must keep (:class:`ByKind`)."""
+    window: int
+    kinds: Tuple[str, ...]
+
+    KINDS = ("full", "window")
+
+    def __post_init__(self):
+        object.__setattr__(self, "kinds", tuple(self.kinds))
+        bad = sorted(set(self.kinds) - set(self.KINDS))
+        if bad or self.window < 1:
+            raise ValueError(f"window={self.window}, layer kinds {bad} "
+                             f"({' | '.join(self.KINDS)})")
+
+    def layers(self, kind: str) -> Tuple[int, ...]:
+        """The model's layers of ``kind``, in order."""
+        return tuple(i for i, k in enumerate(self.kinds) if k == kind)
+
+    def index(self, li: int) -> int:
+        """Layer ``li``'s place among the layers of its own kind."""
+        return self.kinds[:li].count(self.kinds[li])
+
+    def to_dict(self) -> dict:
+        return {"window": self.window, "kinds": list(self.kinds)}
+
+    @classmethod
+    def of(cls, value) -> "WindowSpec":
+        return value if isinstance(value, cls) else cls(**dict(value))
+
+
+def layer_theta(dims, li: int):
+    """Layer ``li``'s rotary base: ``dims.rope_theta``, or None (no
+    positional term) where ``dims.rope_layers`` names the rotated
+    layers and ``li`` is not among them."""
+    layers = getattr(dims, "rope_layers", None)
+    return dims.rope_theta if layers is None or li in layers else None
+
+
+def layer_window(dims, li: int):
+    """The sliding window of layer ``li``'s keys, or None (every
+    earlier key is visible)."""
+    spec = getattr(dims, "windowed", None)
+    return (spec.window if spec is not None
+            and spec.kinds[li] == "window" else None)
+
+
 def scalar(dims, name: str):
     """``dims``' published scalar ``name``, or None (``dims`` is
     anything with the decoder's sizes: most have no such scalar)."""
@@ -113,13 +171,15 @@ def qkv(mha, h, dims, rotate):
     return rotate(q), rotate(k), v
 
 
-def ffn(pblk, h, experts=None, live=None):
+def ffn(pblk, h, experts=None, live=None, route_rows=None):
     """The feed-forward of normed rows ``h``, by what the block holds:
     ``(y, counts)``, ``counts`` an expert layer's held experts' pairs
     (``ops.moe.layer``; ``experts`` its ``ExpertSpec``, ``live`` the
-    rows that carry a token), None for a dense SwiGLU."""
+    rows that carry a token, ``route_rows`` the rows its router reads
+    where they are not ``h``), None for a dense SwiGLU."""
     if "moe" in pblk:
-        return moe.layer(pblk["moe"], h, experts, live=live)
+        return moe.layer(pblk["moe"], h, experts, live=live,
+                         route_rows=route_rows)
     h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
     return h @ pblk["Wd"], None
 
@@ -143,16 +203,21 @@ def block(pblk, x, attend, li: int, experts=None, counts=None,
     through its ``ops.*`` scopes. ``residual`` multiplies each half's
     addition to the residual stream (a published
     ``residual_multiplier``; None: no multiply); ``eps`` is the two
-    norms'."""
+    norms'. An expert layer whose router sits BEFORE the mixer
+    (``experts.route_before_mixer``) routes by the pre-attention
+    normed rows."""
     mha = pblk["mha"]
     with devtime.scope(f"{scope}.mixer"):
-        a = attend(li, mha, rms(x, pblk["ln1"]["gamma"], eps))
+        h1 = rms(x, pblk["ln1"]["gamma"], eps)
+        a = attend(li, mha, h1)
         x = x + _times(a @ mha["Wo"], residual)
         if "bo" in mha:
             x = x + _times(mha["bo"], residual)
     with devtime.scope(f"{scope}.ffn"):
         y, pairs = ffn(pblk, rms(x, pblk["ln2"]["gamma"], eps),
-                       experts, live)
+                       experts, live,
+                       h1 if "moe" in pblk
+                       and experts.route_before_mixer else None)
         if pairs is not None and counts is not None:
             counts.append(pairs)
         return x + _times(y, residual)
@@ -259,14 +324,17 @@ class Attend:
 
 
 class ByKind:
-    """A hybrid decoder's cache object: layer ``li``'s rows go to the
-    cache object of the layer's KIND (``softmax=``, ``mamba2=``),
-    under the layer's index among the layers of that kind, since each
-    kind's caches (a list of dense arrays, a pool of pages) are
-    stacked over its own layers only. ``caches`` and ``pool`` hand
-    back what the kinds' objects hold, softmax first."""
-
-    ORDER = ("softmax", "mamba2")
+    """The cache object of a decoder whose layers differ in KIND (a
+    hybrid's ``softmax=`` and ``mamba2=``; a windowed decoder's
+    ``full=`` and ``window=``): layer ``li``'s rows go to the cache
+    object of the layer's kind, under the layer's index among the
+    layers of that kind, since each kind's caches (a list of dense
+    arrays, a pool of pages) are stacked over its own layers only.
+    ``caches`` and ``pool`` hand back what the kinds' objects hold, in
+    the order the kinds were given (the order of the pager's pool
+    tuple). A kind's object that must know the MODEL's layer (a
+    rotation that differs by layer) is built with the layers of its
+    kind (``spec.layers(kind)``)."""
 
     def __init__(self, spec, **by_kind):
         self.spec = spec
@@ -278,11 +346,11 @@ class ByKind:
 
     @property
     def caches(self):
-        return tuple(self.by[k].caches for k in self.ORDER)
+        return tuple(c.caches for c in self.by.values())
 
     @property
     def pool(self):
-        return sum((tuple(self.by[k].pool) for k in self.ORDER), ())
+        return sum((tuple(c.pool) for c in self.by.values()), ())
 
 
 # -- the dense cache objects -------------------------------------------------
@@ -297,8 +365,10 @@ class _AtPosition:
         self.caches = list(caches)
         self.pos = pos
 
-    def rotate(self, z):        # [rows, heads, d]
-        return rotary_embedding(z[:, None], self.dims.rope_theta,
+    def rotate(self, z, li=None):       # [rows, heads, d]
+        theta = (self.dims.rope_theta if li is None
+                 else layer_theta(self.dims, li))
+        return rotary_embedding(z[:, None], theta,
                                 offset=self.pos)[:, 0]
 
 
@@ -314,7 +384,7 @@ class DenseKV(_AtPosition):
     def attend(self, li, mha, h):
         dims, pos = self.dims, self.pos
         rows, dt = h.shape[0], h.dtype
-        q, k, v = qkv(mha, h, dims, self.rotate)
+        q, k, v = qkv(mha, h, dims, lambda z: self.rotate(z, li))
         n_kv, hd = k.shape[1:]
         kv = jnp.concatenate([k, v], axis=2)        # [rows, Kv, 2D]
         ckv = self.caches[li]
@@ -355,6 +425,10 @@ class DenseKV(_AtPosition):
         if k_scale is not None:
             s = (s * k_scale).astype(dt)
         live = jnp.arange(ck.shape[3])[None, None, None, :] <= pos
+        window = layer_window(dims, li)
+        if window is not None:      # the dense cache keeps every row
+            live = live & (jnp.arange(ck.shape[3])[None, None, None, :]
+                           > pos - window)
         w = jax.nn.softmax(jnp.where(live, s, -1e9), axis=-1)
         if v_scale is not None:
             w = (w * v_scale).astype(dt)
@@ -384,13 +458,22 @@ def causal_prefill(dims, keep):
     lays them out as the decode steps will read them (:func:`dense_kv`,
     or the pager's pages). Rows past the prompt's end hold padding
     junk: causality keeps it out of every real row's context, and
-    decode overwrites row ``p`` before attending at ``p``."""
+    decode overwrites row ``p`` before attending at ``p``. A layer
+    rotates, and bounds its keys by a window, as ``dims`` says of it
+    (:func:`layer_theta`, :func:`layer_window`)."""
     def attend(li, mha, h):
-        q, k, v = qkv(mha, h, dims,
-                      lambda z: rotary_embedding(z, dims.rope_theta))
+        q, k, v = qkv(mha, h, dims, lambda z: rotary_embedding(
+            z, layer_theta(dims, li)))
         keep(li, k, v)
-        return scaled_dot_attention(q, k, v, causal=True).reshape(
-            *h.shape[:-1], -1)
+        spec = getattr(dims, "windowed", None)
+        if spec is None:
+            return scaled_dot_attention(q, k, v, causal=True).reshape(
+                *h.shape[:-1], -1)
+        # a scope of the layer's kind, as the step's page walks have
+        with devtime.scope(f"attn.{spec.kinds[li]}"):
+            return scaled_dot_attention(
+                q, k, v, causal=True,
+                window=layer_window(dims, li)).reshape(*h.shape[:-1], -1)
     return attend
 
 

@@ -96,10 +96,12 @@ def _use_flash(q, k, causal: bool = False) -> bool:
             and jax.default_backend() == "tpu")
 
 
-def scaled_dot_attention(q, k, v, mask=None, causal=False):
+def scaled_dot_attention(q, k, v, mask=None, causal=False, window=None):
     """q,k,v: [B, T, H, D] (head axis 2); ``k``/``v`` may carry fewer
     heads (GQA); Tq and Tk may differ (causal is then END-ALIGNED:
     query i attends keys ≤ i + Tk − Tq). mask: [B, Tk] key mask.
+    ``window`` (causal only): a query sees the last ``window`` keys
+    up to its own, its own included.
 
     Explicit einsum+softmax (not jax.nn.dot_product_attention, which is
     not exact in float64 — breaks gradient checking). Platform-helper
@@ -108,13 +110,23 @@ def scaled_dot_attention(q, k, v, mask=None, causal=False):
     — O(Tk) memory, 1.2-1.7x faster than the einsum at T>=4k, and
     GQA-native (one kv block read per head group).
     """
-    d = q.shape[-1]
     if _use_flash(q, k, causal):
         # masked sequences take the flash path too (per-example key
         # mask operand in the kernel) — every padded-batch NLP workload
         # stays O(T) memory instead of falling back to the [T,T] einsum
         from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
+        if window is not None:      # forward only (inference prefill)
+            return flash_attention(q, k, v, causal=causal, mask=mask,
+                                   window=window)
         return flash_attention(q, k, v, causal=causal, mask=mask)
+    return plain_attention(q, k, v, mask, causal, window)
+
+
+def plain_attention(q, k, v, mask=None, causal=False, window=None):
+    """:func:`scaled_dot_attention`'s einsum form, whatever the
+    platform and the lengths: what autodiff runs for a windowed layer
+    (the windowed flash kernel has no backward)."""
+    d = q.shape[-1]
     k = repeat_kv_heads(k, q.shape[2])
     v = repeat_kv_heads(v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
@@ -125,6 +137,9 @@ def scaled_dot_attention(q, k, v, mask=None, causal=False):
     if causal:
         tq, tk = logits.shape[-2:]
         cm = jnp.tril(jnp.ones((tq, tk), bool), tk - tq)
+        if window is not None:
+            cm = cm & ~jnp.tril(jnp.ones((tq, tk), bool),
+                                tk - tq - window)
         logits = jnp.where(cm, logits, neg)
     w = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
@@ -156,6 +171,13 @@ class MultiHeadAttention(Layer):
     rope_theta: Optional[float] = 10000.0
     #: the scores' scale in ``d^-1/2``'s place (folded into the query)
     score_scale: Optional[float] = None
+    #: a sliding window over the keys (causal only): a query sees the
+    #: last ``window`` keys, its own included; by the plain masked form
+    window: Optional[int] = None
+    #: a head's width where it is not ``n_out / n_heads`` (a published
+    #: decoder whose heads' joined width differs from its hidden
+    #: width: ``Wq [n_in, H d]``, ``Wo [H d, n_out]``)
+    head_dim: Optional[int] = None
 
     _SP_MODES = (None, "ring", "ulysses", "zigzag_ring")
 
@@ -170,6 +192,11 @@ class MultiHeadAttention(Layer):
                 f"unknown sequence_parallel mode "
                 f"{self.sequence_parallel!r} (ring|ulysses|zigzag_ring)")
         n_heads = q.shape[2]
+        if self.window is not None:
+            if self.sequence_parallel or not self.causal:
+                raise ValueError("a sliding window is causal and has "
+                                 "no sequence-parallel form")
+            return plain_attention(q, k, v, mask, True, self.window)
         if self.sequence_parallel:
             from deeplearning4j_tpu.parallel.mesh import active_context
             ctx = active_context()
@@ -215,21 +242,22 @@ class MultiHeadAttention(Layer):
     def init(self, key, input_shape, dtype=jnp.float32):
         n_in = self.n_in or input_shape[-1]
         n_out = self.n_out or n_in
-        if n_out % self.n_heads:
+        if self.head_dim is None and n_out % self.n_heads:
             raise ValueError(f"n_out={n_out} not divisible by "
                              f"n_heads={self.n_heads}")
         n_kv = self.n_kv_heads or self.n_heads
         if self.n_heads % n_kv:
             raise ValueError(f"n_heads={self.n_heads} not divisible "
                              f"by n_kv_heads={n_kv}")
-        kv_out = (n_out // self.n_heads) * n_kv
+        hd = self.head_dim or n_out // self.n_heads
+        kv_out = hd * n_kv
         wi = winit.get(self.weight_init or "xavier")
         kq, kk, kv_, ko = jax.random.split(key, 4)
-        params = {"Wq": wi(kq, (n_in, n_out), dtype),
+        params = {"Wq": wi(kq, (n_in, hd * self.n_heads), dtype),
                   "Wk": wi(kk, (n_in, kv_out), dtype),
                   "Wv": wi(kv_, (n_in, kv_out), dtype)}
         if self.project_out:
-            params["Wo"] = wi(ko, (n_out, n_out), dtype)
+            params["Wo"] = wi(ko, (hd * self.n_heads, n_out), dtype)
             params["bo"] = jnp.zeros((n_out,), dtype)
         t = input_shape[0]
         return params, {}, (t, n_out)
@@ -642,6 +670,10 @@ class TransformerDecoderBlock(Layer):
     #: ``ops.moe.ExpertSpec``: its parameters under ``params["moe"]``)
     ffn: str = "dense"
     experts: Optional[Any] = None
+    #: the softmax mixer's sliding window (None: every earlier key)
+    window: Optional[int] = None
+    #: a softmax head's width where it is not ``n_in / n_heads``
+    head_dim: Optional[int] = None
 
     def _subs(self):
         if not hasattr(self, "_mha"):
@@ -681,7 +713,11 @@ class TransformerDecoderBlock(Layer):
                     n_kv_heads=self.n_kv_heads, causal=True, rope=True,
                     rope_theta=self.rope_theta,
                     score_scale=self.score_scale,
-                    sequence_parallel=self.sequence_parallel)
+                    sequence_parallel=self.sequence_parallel,
+                    **({} if self.window is None
+                       else {"window": self.window}),
+                    **({} if self.head_dim is None
+                       else {"head_dim": self.head_dim}))
             eps = {} if self.norm_eps is None else {"eps": self.norm_eps}
             self._ln1 = RMSNorm(**eps)
             self._ln2 = RMSNorm(**eps)
@@ -726,6 +762,7 @@ class TransformerDecoderBlock(Layer):
         r1, r2 = (jax.random.split(rng) if rng is not None
                   else (None, None))
         h, _ = self._ln1.apply(params["ln1"], {}, x)
+        h1 = h
         a, _ = self._mha.apply(params["mha"], {}, h, train=train,
                                rng=r1, mask=mask)
         res = self.residual_multiplier
@@ -740,8 +777,11 @@ class TransformerDecoderBlock(Layer):
             # the plain form: every held expert on every row, masked
             # by the routing (autodiff runs no data-dependent loop)
             from deeplearning4j_tpu.ops import moe
-            h, _ = moe.layer(params["moe"], h,
-                             moe.ExpertSpec.of(self.experts), plain=True)
+            spec = moe.ExpertSpec.of(self.experts)
+            h, _ = moe.layer(
+                params["moe"], h, spec, plain=True,
+                **({"route_rows": h1} if spec.route_before_mixer
+                   else {}))
         else:
             h = jax.nn.silu(h @ params["Wg"]) * (h @ params["Wu"])
             h = h @ params["Wd"]

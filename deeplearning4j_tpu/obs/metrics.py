@@ -114,6 +114,10 @@ FAMILIES = {
     "dl4j_tpu_serving_kv_page_occupancy": "gauge",
     "dl4j_tpu_serving_kv_pages_reserved": "gauge",
     "dl4j_tpu_serving_kv_pages_walked": "gauge",
+    "dl4j_tpu_serving_kv_pages": "gauge",
+    "dl4j_tpu_serving_kv_rows_read_total": "counter",
+    "dl4j_tpu_serving_kv_rows_unwindowed_total": "counter",
+    "dl4j_tpu_serving_ring_pages_overwritten_total": "counter",
     "dl4j_tpu_serving_state_pool_bytes": "gauge",
     "dl4j_tpu_serving_state_bytes_moved": "counter",
     "dl4j_tpu_serving_latent_rows_read_total": "counter",
@@ -548,6 +552,25 @@ SERVING_KV_WALKED = REGISTRY.gauge(
     "KV pages the last decode step's attention read: the sum over "
     "active slots of ceil(length / block), against max_slots x "
     "max_pages_per_seq page-table entries")
+SERVING_KV_PAGES_HELD = REGISTRY.gauge(
+    "dl4j_tpu_serving_kv_pages",
+    "KV pages live sequences hold, by kind, of a pool with two kinds "
+    "of KV pages (a windowed decoder's): 'full' pages off the free "
+    "list, 'window' pages of the slots' rings (at most ring a "
+    "sequence)", ("kind",))
+SERVING_KV_ROWS_READ = REGISTRY.counter(
+    "dl4j_tpu_serving_kv_rows_read_total",
+    "cached positions the decode steps' page walks of a windowed "
+    "decoder had to read, over all its layers: a full layer every "
+    "live position, a window layer the last window of them")
+SERVING_KV_ROWS_UNWINDOWED = REGISTRY.counter(
+    "dl4j_tpu_serving_kv_rows_unwindowed_total",
+    "cached positions the same steps would have read had every layer "
+    "kept every position (1 - read / this: the share the window saves)")
+SERVING_RING_OVERWRITES = REGISTRY.counter(
+    "dl4j_tpu_serving_ring_pages_overwritten_total",
+    "ring pages of window layers that a decode step began to write "
+    "over (their positions had left every later query's window)")
 SERVING_STATE_POOL = REGISTRY.gauge(
     "dl4j_tpu_serving_state_pool_bytes",
     "bytes of the recurrent-state pool as stored (0 for a KV-page "

@@ -15,8 +15,18 @@ groups stay, the best ``top_k`` experts inside them are chosen (ties
 to the lower index); the weights are the chosen experts' ``s``
 (without ``b``), normalised to sum 1, times ``scale``.
 
+A second scoring rule, ``score="softmax_topk"`` (no groups, no bias):
+the ``top_k`` largest router logits are chosen (ties to the lower
+index) and weighed by the softmax over THEM, which is the softmax
+over all outputs renormalised over the chosen. The rows the router
+reads may differ from the rows the experts multiply (``route_rows``:
+a block whose router sits before its attention hands the
+pre-attention normed rows; ``ExpertSpec.route_before_mixer``).
+
 ``y = shared(h) + sum_{e chosen, e held here} w_e expert_e(h)``, each
-a SwiGLU. Parameters of one layer (``pblk["moe"]``): ``Wr [F,
+a gated unit: SwiGLU (``unit="swiglu"``, ``silu(h Wg) * (h Wu)``) or
+ReGLU (``unit="reglu"``, ``relu`` in ``silu``'s place). Parameters of
+one layer (``pblk["moe"]``): ``Wr [F,
 n_routed]`` and ``br [n_routed]`` (float32, whatever the compute
 dtype), the held experts' ``Weg``/``Weu [n_held, F, W]`` and ``Wed
 [n_held, W, F]``, the shared expert's ``Wsg``/``Wsu [F, n_shared W]``
@@ -47,6 +57,15 @@ from deeplearning4j_tpu.obs import devtime
 FLOAT32_LEAVES = ("Wr", "br")
 
 
+#: the routing rules: DeepSeek-V3's ``noaux_tc`` (sigmoid scores, a
+#: correction bias, groups), or the ``top_k`` largest logits weighed
+#: by the softmax over them
+SCORES = ("sigmoid_groups", "softmax_topk")
+
+#: the gate's activation of an expert's unit ``act(h Wg) * (h Wu)``
+UNITS = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
 @dataclass(frozen=True)
 class ExpertSpec:
     """The expert layers of a model, and this chip's share of them:
@@ -55,7 +74,11 @@ class ExpertSpec:
     published; ``n_shared`` shared experts; ``top_k`` a token, chosen
     within the best ``topk_group`` of ``n_group`` groups; ``scale``
     the routed scaling factor; ``first_dense`` leading layers keep a
-    dense feed-forward."""
+    dense feed-forward. ``score`` is the routing rule
+    (:data:`SCORES`), ``unit`` the experts' gated unit
+    (:data:`UNITS`); ``route_before_mixer``: the router reads the
+    block's PRE-attention normed rows, not the rows the experts
+    multiply."""
     width: int
     n_held: int
     n_routed: int
@@ -66,8 +89,15 @@ class ExpertSpec:
     n_shared: int = 1
     offset: int = 0
     first_dense: int = 0
+    score: str = "sigmoid_groups"
+    unit: str = "swiglu"
+    route_before_mixer: bool = False
 
     def __post_init__(self):
+        if self.score not in SCORES:
+            raise ValueError(f"score={self.score!r} ({' | '.join(SCORES)})")
+        if self.unit not in UNITS:
+            raise ValueError(f"unit={self.unit!r} ({' | '.join(UNITS)})")
         if self.n_routed % self.n_group:
             raise ValueError(f"n_routed={self.n_routed} not divisible "
                              f"by n_group={self.n_group}")
@@ -88,13 +118,18 @@ class ExpertSpec:
 
 
 def route(h, w_r, bias, *, n_group: int, topk_group: int, top_k: int,
-          scale: float):
+          scale: float, score: str = "sigmoid_groups"):
     """Rows ``h [T, F]`` to ``ids [T, top_k]`` i32 over ALL of
     ``w_r``'s outputs and their weights ``[T, top_k]`` float32."""
     with devtime.scope("ops.moe_route"):
-        s = jax.nn.sigmoid(jnp.dot(
-            h.astype(jnp.float32), w_r.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
+        s = jnp.dot(h.astype(jnp.float32), w_r.astype(jnp.float32),
+                    precision=lax.Precision.HIGHEST)
+        if score == "softmax_topk":
+            top, ids = lax.top_k(s, top_k)
+            w = jax.nn.softmax(top, axis=-1)
+            return ids.astype(jnp.int32), (w if scale == 1.0
+                                           else w * scale)
+        s = jax.nn.sigmoid(s)
         c = s + bias.astype(jnp.float32)
         t, e = c.shape
         per = e // n_group
@@ -109,8 +144,14 @@ def route(h, w_r, bias, *, n_group: int, topk_group: int, top_k: int,
         return ids.astype(jnp.int32), w
 
 
-def swiglu(h, wg, wu, wd):
-    return (jax.nn.silu(h @ wg) * (h @ wu)) @ wd
+def gated(h, wg, wu, wd, unit: str = "swiglu"):
+    """One gated unit over rows ``h``: ``(act(h Wg) * (h Wu)) Wd``."""
+    return (UNITS[unit](h @ wg) * (h @ wu)) @ wd
+
+
+#: rows an expert layer routes at a time (the serving form): no bucket
+#: of the cells that came before is longer
+ROUTE_BLOCK = 4096
 
 
 def _tile_rows(n_pairs: int) -> int:
@@ -123,10 +164,11 @@ def _tile_rows(n_pairs: int) -> int:
     return rows
 
 
-def experts(h, p, ids, weights, held):
+def experts(h, p, ids, weights, held, unit: str = "swiglu"):
     """The held experts' part of the layer for rows ``h [T, F]``
     routed by ``ids``/``weights`` (:func:`route`); ``held`` is this
-    chip's static ``(offset, count)``. Returns ``(y [T, F], counts
+    chip's static ``(offset, count)``, ``unit`` the experts' gated
+    unit. Returns ``(y [T, F], counts
     [count] i32)``: ``counts[e]`` is the pairs held expert ``e``
     computed.
 
@@ -159,8 +201,8 @@ def experts(h, p, ids, weights, held):
         r0 = starts[e] + (i - (tile_ends[e] - tiles[e])) * bt
         live = (r0 + jnp.arange(bt)) < ends[e]
         x = h[lax.dynamic_slice(tok, (r0,), (bt,))]
-        y = swiglu(x, *(lax.dynamic_index_in_dim(p[w], e, 0, False)
-                        for w in ("Weg", "Weu", "Wed")))
+        y = gated(x, *(lax.dynamic_index_in_dim(p[w], e, 0, False)
+                       for w in ("Weg", "Weu", "Wed")), unit=unit)
         y = jnp.where(live[:, None], y, jnp.zeros_like(y))
         # a tile's dead rows lie over the next group's: added as
         # zeros, whichever of the two tiles comes first
@@ -178,7 +220,7 @@ def experts(h, p, ids, weights, held):
     return y.astype(h.dtype), sizes
 
 
-def experts_plain(h, p, ids, weights, held):
+def experts_plain(h, p, ids, weights, held, unit: str = "swiglu"):
     """:func:`experts` by the plain form: every held expert applied
     to every row, masked by the routing."""
     offset, count = held
@@ -186,13 +228,14 @@ def experts_plain(h, p, ids, weights, held):
     w = jnp.sum(weights[..., None] * chose, axis=1)         # [T, E]
     g = jnp.einsum("tf,efw->etw", h, p["Weg"])
     u = jnp.einsum("tf,efw->etw", h, p["Weu"])
-    y = jnp.einsum("etw,ewf->etf", jax.nn.silu(g) * u, p["Wed"])
+    y = jnp.einsum("etw,ewf->etf", UNITS[unit](g) * u, p["Wed"])
     out = jnp.einsum("te,etf->tf", w, y.astype(jnp.float32))
     return out.astype(h.dtype), jnp.sum(chose, axis=(0, 1),
                                         dtype=jnp.int32)
 
 
-def layer(p, h, spec: ExpertSpec, plain: bool = False, live=None):
+def layer(p, h, spec: ExpertSpec, plain: bool = False, live=None,
+          route_rows=None):
     """One expert layer over rows ``h [..., F]``: ``(y, counts)``,
     ``y = shared(h) + this chip's experts' part`` and ``counts
     [n_held]`` the held experts' pairs. ``live`` (bool, ``h``'s
@@ -200,17 +243,32 @@ def layer(p, h, spec: ExpertSpec, plain: bool = False, live=None):
     padding and a slot without a sequence make no pair (they all hold
     one token and would all choose the same experts: whole tiles of
     work for rows nobody reads, more or fewer by the luck of that
-    token's route)."""
+    token's route). ``route_rows`` (``h``'s shape) are the rows the
+    router reads where they are not ``h`` itself."""
     rows = h.reshape(-1, h.shape[-1])
-    ids, weights = route(rows, p["Wr"], p["br"], n_group=spec.n_group,
-                         topk_group=spec.topk_group, top_k=spec.top_k,
-                         scale=spec.scale)
+    n = rows.shape[0]
+    if not plain and n > ROUTE_BLOCK and n % ROUTE_BLOCK == 0:
+        # a long bucket's rows, a block at a time: the sorted pairs'
+        # rows [block top_k, F] bound what the layer holds beside them
+        shaped = lambda z: None if z is None else z.reshape(
+            n // ROUTE_BLOCK, ROUTE_BLOCK, *z.shape[h.ndim - 1:])
+        y, counts = lax.map(
+            lambda b: layer(p, b[0], spec, live=b[1], route_rows=b[2]),
+            (shaped(h), shaped(live), shaped(route_rows)))
+        return y.reshape(h.shape), jnp.sum(counts, axis=0)
+    ids, weights = route(
+        rows if route_rows is None else route_rows.reshape(rows.shape),
+        p["Wr"], p["br"], n_group=spec.n_group,
+        topk_group=spec.topk_group, top_k=spec.top_k, scale=spec.scale,
+        score=spec.score)
     if live is not None:
         ids = jnp.where(live.reshape(-1, 1), ids, -1)   # held nowhere
     with devtime.scope("ops.moe_experts"):
         y, counts = (experts_plain if plain else experts)(
-            rows, p, ids, weights, (spec.offset, spec.n_held))
+            rows, p, ids, weights, (spec.offset, spec.n_held),
+            unit=spec.unit)
     if "Wsg" in p:
         with devtime.scope("ops.moe_shared"):
-            y = swiglu(rows, p["Wsg"], p["Wsu"], p["Wsd"]) + y
+            y = gated(rows, p["Wsg"], p["Wsu"], p["Wsd"],
+                      unit=spec.unit) + y
     return y.reshape(h.shape), counts

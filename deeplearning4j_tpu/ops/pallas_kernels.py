@@ -106,7 +106,8 @@ def _jnp_fallback(*xs) -> bool:
 
 def _flash_kernel(q_ref, k_ref, v_ref, km_ref, off_ref, o_ref, *rest,
                   scale: float, causal: bool, t_real: int,
-                  block_q: int, block_k: int):
+                  block_q: int, block_k: int,
+                  window: Optional[int] = None):
     # rest = (lse_ref?, acc, m, l): the lse output only exists on the
     # differentiated path (inference pays no extra HBM writes)
     lse_ref = rest[0] if len(rest) == 4 else None
@@ -131,6 +132,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, off_ref, o_ref, *rest,
         live = jnp.logical_and(
             live,
             k_off + j * block_k <= q_off + i * block_q + block_q - 1)
+        if window is not None:
+            # a sliding window: query t sees keys t - window < j <= t;
+            # a block whose last key lies at or below the first row's
+            # bound is out of every row's range
+            live = jnp.logical_and(
+                live, k_off + j * block_k + block_k - 1
+                > q_off + i * block_q - window)
 
     @pl.when(live)
     def _():
@@ -158,6 +166,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, off_ref, o_ref, *rest,
                 jnp.int32, (block_q, block_k), 0)
             mask = jnp.logical_and(
                 mask, off_ref[1] + kv_idx <= off_ref[0] + q_idx)
+            if window is not None:
+                mask = jnp.logical_and(
+                    mask, off_ref[1] + kv_idx
+                    > off_ref[0] + q_idx - window)
         s = jnp.where(mask, s, -jnp.inf)
 
         m_prev = m[:, :1]
@@ -233,14 +245,22 @@ def _reduce_kv_rows(dx, groups):
 
 def _flash_fwd(q, k, v, km, offs, causal: bool, block_q: int,
                block_k: int, return_lse: bool = False,
-               groups: int = 1):
+               groups: int = 1, window: Optional[int] = None,
+               q_off: int = 0):
     """q: [B·H, T, D] (heads folded); k,v: [B·H/groups, Tk, D] —
     grouped-query attention reads ONE kv block per head group straight
     from HBM via the BlockSpec index map (``b // groups``), never
     materialising the broadcast; km: [B·H/groups, Tk] key mask;
     offs: int32 [2] global (q, k) position offsets. Returns [BH, T, D]
     (and, for the vjp / ring composition, the per-row [BH, Tq, 1]
-    logsumexp)."""
+    logsumexp). ``window`` (causal only; ``q_off`` then the STATIC
+    query offset ``offs`` holds, its key offset 0): query ``t`` sees
+    keys ``t - window < j <= t``; a KV block out of every row's range
+    is neither multiplied nor fetched (its block index is clamped
+    into the range, and a block index that repeats is not copied
+    again)."""
+    if window is not None and not causal:
+        raise ValueError("a sliding window is causal")
     if km is None:
         km = _ones_km(k)
     if offs is None:
@@ -249,7 +269,8 @@ def _flash_fwd(q, k, v, km, offs, causal: bool, block_q: int,
         return _reference_scan(q, _expand_kv_rows(k, groups),
                                _expand_kv_rows(v, groups),
                                _expand_kv_rows(km, groups), offs,
-                               causal, return_lse=return_lse)
+                               causal, return_lse=return_lse,
+                               window=window)
     bh, t, d = q.shape
     if k.shape[0] * groups != bh:
         raise ValueError(f"kv rows ({k.shape[0]}) × groups ({groups}) "
@@ -277,6 +298,14 @@ def _flash_fwd(q, k, v, km, offs, causal: bool, block_q: int,
     offs = _align_vma(offs.astype(jnp.int32), vma)
     nq, nk = tq // block_q, tk // block_k
     g = groups
+    if window is None:
+        kv_block = lambda i, j: j
+    else:
+        def kv_block(i, j):     # the blocks a q block's rows can see
+            lo = jnp.maximum(q_off + i * block_q - window + 1, 0)
+            hi = jnp.minimum(q_off + i * block_q + block_q - 1,
+                             tk - 1)
+            return jnp.clip(j, lo // block_k, hi // block_k)
     oshape = _sds((bh, tq, dp), q.dtype, vma)
     ospec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0))
     lshape = _sds((bh, tq, 128), jnp.float32, vma)
@@ -284,15 +313,17 @@ def _flash_fwd(q, k, v, km, offs, causal: bool, block_q: int,
     res = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           t_real=tk_real, block_q=block_q,
-                          block_k=block_k),
+                          block_k=block_k,
+                          **({} if window is None
+                             else {"window": window})),
         out_shape=(oshape, lshape) if return_lse else oshape,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, dp),
-                         lambda b, i, j: (b // g, j, 0)),
+                         lambda b, i, j: (b // g, kv_block(i, j), 0)),
             pl.BlockSpec((1, block_k, dp),
-                         lambda b, i, j: (b // g, j, 0)),
+                         lambda b, i, j: (b // g, kv_block(i, j), 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda b, i, j: (b // g, 0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -314,7 +345,8 @@ def _flash_fwd(q, k, v, km, offs, causal: bool, block_q: int,
 
 
 def _reference_scan(q, k, v, km=None, offs=None, causal: bool = False,
-                    block: int = 512, return_lse: bool = False):
+                    block: int = 512, return_lse: bool = False,
+                    window: Optional[int] = None):
     """Differentiable O(T)-memory blockwise attention in plain jnp
     (lax.scan over kv blocks) — the backward path and CPU fallback.
     Same mask/offset semantics as the Pallas kernel."""
@@ -339,6 +371,9 @@ def _reference_scan(q, k, v, km=None, offs=None, causal: bool = False,
         mask = jnp.logical_and(kv_idx < tk_real, kmb[:, None, :] > 0)
         if causal:
             mask = jnp.logical_and(mask, k_off + kv_idx <= q_idx)
+        if window is not None:
+            mask = jnp.logical_and(mask,
+                                   k_off + kv_idx > q_idx - window)
         s = jnp.where(mask, s, -jnp.inf)
         m_blk = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_blk)
@@ -784,7 +819,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q, k, v, causal: bool = False,
                     mask: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None):
     """Blockwise attention, [B, T, H, D] layout (head axis 2) like
     ``scaled_dot_attention``; ``mask``: optional [B, Tk] key mask.
     ``k``/``v`` may carry FEWER heads than ``q`` (grouped-query
@@ -800,7 +836,11 @@ def flash_attention(q, k, v, causal: bool = False,
     Differentiable: the backward is a pair of Pallas kernels (dQ;
     dK/dV) that recompute the probability tile per block from the
     saved logsumexp — FlashAttention-2 style, no [T,T] materialisation
-    in either direction."""
+    in either direction. ``window`` (causal only): query ``t`` sees
+    the ``window`` keys ``t - window < j <= t``, its own included; KV
+    blocks out of a q block's range are not read, so a long prompt
+    costs ``T x window``. The windowed forward has no backward yet (a
+    window layer trains through the masked plain form)."""
     b, t, h, d = q.shape
     h_kv = k.shape[2]
     if h % h_kv:
@@ -825,9 +865,16 @@ def flash_attention(q, k, v, causal: bool = False,
     # own device time gets its own name in the gap report
     from deeplearning4j_tpu.obs import devtime
     with devtime.scope("ops.flash_attention"):
-        o = _flash(fold(q), fold(k), fold(v), km, causal, block_q,
-                   block_k, h // h_kv,
-                   k.shape[1] - t if causal else 0)
+        if window is not None:
+            q_off = k.shape[1] - t
+            o = _flash_fwd(fold(q), fold(k), fold(v), km,
+                           _static_offs(q_off), causal, block_q,
+                           block_k, groups=h // h_kv, window=window,
+                           q_off=q_off)
+        else:
+            o = _flash(fold(q), fold(k), fold(v), km, causal, block_q,
+                       block_k, h // h_kv,
+                       k.shape[1] - t if causal else 0)
     return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
@@ -871,7 +918,8 @@ _PAGED_CHUNK_ROWS = 2048
 def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
                          buf, sem, m, l, acc, *, scale: float,
                          block: int, n_kv: int, chunk: int,
-                         max_pages: int, d: int, packed: bool = False):
+                         max_pages: int, d: int, packed: bool = False,
+                         window: Optional[int] = None):
     # li_ref [1], pt_ref [S*MP], n_ref [S]: scalar-prefetch operands in
     # SMEM; q_ref/o_ref [H, D] (this slot's block); pool_ref
     # [L, P, block*Hkv, 2D], left in HBM; buf [2, chunk, block*Hkv, 2D]
@@ -879,6 +927,19 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
     li = li_ref[0]
     n_pos = n_ref[b]                  # live positions; 0 = inactive
     n_pages = (n_pos + block - 1) // block
+    if window is not None:
+        # the query, at n_pos - 1, sees positions >= n_pos - window:
+        # the walk starts at the page that holds the first of them,
+        # and reads the slot's row of the page table modulo its
+        # length (a row shorter than the sequence is a RING: page p
+        # of the sequence lies in entry p % max_pages). The remainder
+        # is taken ONCE a slot: a walk has max_pages pages at most,
+        # so it wraps once at most, and a compare does for a page (a
+        # remainder a page cost the walk a fifth of its time, PR 46)
+        lo = jnp.maximum(n_pos - window, 0)
+        first = lo // block
+        n_pages = n_pages - first
+        turn = first % max_pages
     n_chunks = (n_pages + chunk - 1) // chunk
     h = q_ref.shape[0]
     rows = chunk * block * n_kv
@@ -899,7 +960,11 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
         # copies unrolled at three sites: those cost every start of
         # the gateway most of a second of tracing and lowering
         def page(j, carry):
-            pid = pt_ref[b * max_pages + c * chunk + j]
+            at = c * chunk + j
+            if window is not None:
+                at = at + turn
+                at = jnp.where(at >= max_pages, at - max_pages, at)
+            pid = pt_ref[b * max_pages + at]
             go(pltpu.make_async_copy(pool_ref.at[li, pid],
                                      buf.at[slot, j], sem.at[slot]))
             return carry
@@ -930,7 +995,13 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
         s = lax.dot_general(q_ref[...], kv if packed else kv[:, :d],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-        live = jnp.logical_and(own, rel < n_pos - c * (chunk * block))
+        if window is None:
+            live = jnp.logical_and(own,
+                                   rel < n_pos - c * (chunk * block))
+        else:       # the first page's head and the last one's tail
+            base = (first + c * chunk) * block
+            live = jnp.logical_and(
+                own, jnp.logical_and(rel < n_pos - base, rel >= lo - base))
         # every chunk walked holds a live position of every kv head,
         # so each row's running maximum is finite from the first on
         s = jnp.where(live, s * scale, -jnp.inf)
@@ -953,15 +1024,21 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("pages_per_chunk", "interpret"))
+                   static_argnames=("pages_per_chunk", "interpret",
+                                    "window", "n_kv"))
 def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
-                       interpret):
+                       interpret, window=None, n_kv=None):
     """ONE lowering for every layer of a step: the layer index is a
     scalar operand, so the unrolled blocks of ``serving.decode_step``
     share this jitted function's single ``func`` in the lowered
-    module."""
+    module. A FOLDED pool ``[L, P, block * Hkv, 2D]`` (``n_kv``
+    given) is read as it is stored."""
     s_, h, d = q.shape
-    n_l, n_p, block, n_kv, _ = pool.shape
+    if pool.ndim == 4:
+        n_l, n_p, rows, _ = pool.shape
+        block = rows // n_kv
+    else:
+        n_l, n_p, block, n_kv, _ = pool.shape
     mp = pt.shape[1]
     chunk = pages_per_chunk
     # a 64-wide head: the query padded over the V lanes, whole rows
@@ -973,7 +1050,9 @@ def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=1.0 / (d ** 0.5),
                           block=block, n_kv=n_kv, chunk=chunk,
-                          max_pages=mp, d=d, packed=packed),
+                          max_pages=mp, d=d, packed=packed,
+                          **({} if window is None
+                             else {"window": window})),
         out_shape=jax.ShapeDtypeStruct((s_, h, w), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -1003,7 +1082,8 @@ def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
     return out[..., d:] if packed else out
 
 
-def _reference_paged_attention(q, pool, li, pt, pos):
+def _reference_paged_attention(q, pool, li, pt, pos, window=None,
+                               n_kv=None):
     """Attention of R query rows per slot over the paged pool in plain
     jnp: gather the slot's pages through its page-table row, put them
     back in position order, mask past each row's position. The
@@ -1020,8 +1100,25 @@ def _reference_paged_attention(q, pool, li, pt, pos):
     factoring out of the einsums, same ``-1e9`` mask), which is what
     keeps paged greedy decode token-identical to dense ``generate()``:
     trash and stale positions sit past ``pos`` and get exact-zero
-    softmax weight."""
+    softmax weight. ``window``: row r attends positions ``> pos[s, r]
+    - window`` only, and, as in the kernel, the walk starts at the
+    page of the first of them and reads ``pt``'s row modulo its
+    length (a row shorter than the sequence is a ring); a folded pool
+    ``[L, P, block * Hkv, 2D]`` comes with its ``n_kv``."""
     s_, r, h, d = q.shape
+    if pool[0].ndim == 4:
+        kv4 = pool[0]
+        pool = (kv4.reshape(*kv4.shape[:2], kv4.shape[2] // n_kv, n_kv,
+                            kv4.shape[3]),)
+    base = None
+    if window is not None:
+        block, mp = pool[0].shape[2], pt.shape[1]
+        first = jnp.maximum(pos.min(axis=1) + 1 - window, 0) // block
+        walk = min(mp, -(-(window + r - 1) // block) + 1)
+        pt = jnp.take_along_axis(
+            pt, (first[:, None] + jnp.arange(walk)[None, :]) % mp,
+            axis=1)
+        base = (first * block)[:, None, None, None, None]
     n_kv = pool[0].shape[3]
     g = h // n_kv
     dt = q.dtype
@@ -1041,8 +1138,12 @@ def _reference_paged_attention(q, pool, li, pt, pos):
         jnp.asarray(d, dt))
     if k_scale is not None:
         s = (s * k_scale).astype(dt)
-    live = (jnp.arange(ck.shape[2])[None, None, None, None, :]
-            <= pos[:, None, None, :, None])
+    at = jnp.arange(ck.shape[2])[None, None, None, None, :]
+    if base is not None:
+        at = at + base
+    live = at <= pos[:, None, None, :, None]
+    if window is not None:
+        live = live & (at > pos[:, None, None, :, None] - window)
     s = jnp.where(live, s, -1e9)
     w = jax.nn.softmax(s, axis=-1)
     if v_scale is not None:
@@ -1066,13 +1167,19 @@ def _use_paged_kernel(q, pool) -> bool:
     if len(pool) != 1 or not gate_active("paged_decode"):
         return False
     kv = pool[0]
+    # a folded pool's page IS the matrix the kernel reads, whatever
+    # its kv heads, given whole (packed) sublane tiles a page
+    whole = (kv.shape[2] % 16 == 0 if kv.ndim == 4
+             else kv.shape[3] % 8 == 0)
     return (kv.dtype == q.dtype and q.dtype != jnp.float64
             and (q.shape[-1] % 128 == 0 or q.shape[-1] == 64)
-            and kv.shape[3] % 8 == 0)
+            and whole)
 
 
 def paged_decode_attention(q, pool, li, pt, n_live,
-                           pages_per_chunk: Optional[int] = None):
+                           pages_per_chunk: Optional[int] = None,
+                           window: Optional[int] = None,
+                           n_kv: Optional[int] = None):
     """Single-token decode attention over the paged KV pool, read in
     place. ``q`` [S, H, D] (one query row per slot, RoPE applied);
     ``pool`` the pager's tuple; ``li`` the layer (Python int or i32
@@ -1083,21 +1190,35 @@ def paged_decode_attention(q, pool, li, pt, n_live,
     group's query heads. ``pages_per_chunk`` (default: 2,048 rows'
     worth) is the loop's unit; every size comes from the operands'
     shapes. Shapes the kernel does not take (:func:`_use_paged_kernel`)
-    run :func:`_reference_paged_attention`."""
+    run :func:`_reference_paged_attention`. ``window``: the query (at
+    ``n_live - 1``) sees the last ``window`` positions only, and the
+    walk starts at the first page that holds one of them: pages
+    before it are not read. Page ``p`` of a slot is then entry ``p %
+    MP`` of its row of ``pt``, so a row of ``ceil(window / block) +
+    1`` entries is a ring that serves a sequence of any length (the
+    pager's window pages), and a row as long as the sequence is read
+    as ever. A FOLDED pool ``[L, P, block * Hkv, 2D]``
+    (the pager's layout for two kinds of KV pages) comes with its
+    ``n_kv``."""
     from deeplearning4j_tpu.obs import devtime
+    extra = {} if window is None else {"window": window}
+    folded = pool[0].ndim == 4
+    if folded:
+        extra["n_kv"] = n_kv
     with devtime.scope("ops.paged_decode_attention"):
         if not _use_paged_kernel(q, pool):
             a = _reference_paged_attention(
-                q[:, None], pool, li, pt, (n_live - 1)[:, None])[:, 0]
+                q[:, None], pool, li, pt, (n_live - 1)[:, None],
+                **extra)[:, 0]
             return jnp.where((n_live > 0)[:, None, None], a,
                              jnp.zeros_like(a))
-        _, _, block, n_kv, _ = pool[0].shape
-        chunk = pages_per_chunk or max(
-            1, _PAGED_CHUNK_ROWS // (block * n_kv))
+        rows = (pool[0].shape[2] if folded
+                else pool[0].shape[2] * pool[0].shape[3])
+        chunk = pages_per_chunk or max(1, _PAGED_CHUNK_ROWS // rows)
         return _paged_decode_call(q, pool[0], jnp.asarray(li, jnp.int32),
                                   pt, n_live,
                                   pages_per_chunk=min(chunk, pt.shape[1]),
-                                  interpret=_interpret())
+                                  interpret=_interpret(), **extra)
 
 
 # ---------------------------------------------------------------------------

@@ -27,9 +27,14 @@ carries as its donated pool argument):
   PLACE (with the kv heads outside the positions,
   ``[L, P, Hkv, block, 2D]``, the TPU compiler re-tiled the whole pool
   around every layer's scatter: seven 2 GB copies a step, read off the
-  compiled step, PR 25). With ``Hkv`` a multiple of 8 the tiled bytes
-  are the plain ones: the pool takes exactly its
-  ``L * P * block * Hkv * 2D`` elements on the device. (The previous
+  compiled step, PR 25). The pool takes exactly its
+  ``L * P * block * Hkv * 2D`` elements on the device, for 8 KV
+  heads and for 4 alike (the compiler tiles the two minor dimensions
+  by ``Hkv`` rows where ``Hkv`` is under 8; read off the compiled
+  programs' argument bytes and the chip's, PR 46); but only with
+  ``Hkv`` a multiple of 8 is a page the ``[block * Hkv, 2D]`` matrix
+  the decode kernel reads, byte for byte: see the FOLDED layout
+  below. (The previous
   layout, ``[L, P, Hkv, 2D, block]``, was the dense cache's: the
   minor dimension was the page's 16 positions, an eighth of a
   128-lane tile.) dtype is ``int8`` under ``cache_quant="int8"``
@@ -102,6 +107,45 @@ invariant of its own; what can leak is a KV page, and ``free_pages``
 counts those. A state page comes to its next sequence as its last one
 left it: admission starts from an empty state when ``start`` is 0.
 
+A WINDOWED decoder (``CausalTransformerLM(window=...)``: softmax
+layers of two kinds, ``decoder_infer.WindowSpec``) holds KV pages of
+TWO kinds in this one pager (``windowed``), the pool tuple ``(kv_full,
+kv_window)``, each stacked over the layers of its kind, both FOLDED:
+
+- ``kv_full`` ``[L_full, P, block * Hkv, 2D]``: a full layer keeps
+  every position; pages off the free list, reserved at admission for
+  the sequence's whole life, as the KV pages above;
+- ``kv_window`` ``[L_window, 1 + slots * ring, block * Hkv, 2D]``,
+  ``ring = ceil(window / block) + 1``: a window layer's query sees the
+  last ``window`` keys, which lie in at most ``ring`` pages, so decode
+  slot ``s`` OWNS the ``ring`` pages from ``1 + s * ring`` on and
+  writes them as a ring: position ``t`` goes to the slot's page
+  ``(t // block) % ring``, over the page that held positions ``ring *
+  block`` earlier, all of them out of every later query's window. The
+  slot IS the reservation (as a hybrid's state page is): no free list,
+  no refcount, no release in mid-flight, and a sequence can never hold
+  more than ``ring`` pages of a window layer. (A second free list used
+  as a ring would let short sequences leave pages to long ones; at 48
+  slots the ring rows are 2.4 GB of 4.3 GB of pool, and what a short
+  sequence leaves unused no admission could take without a release in
+  mid-flight, which the scheduler's whole-life reservation rules out.)
+  The step reads a ring through the slot's own ``ring`` entries of a
+  page table (:class:`PagedWindowKV`), which
+  ``ops.paged_decode_attention(window=)`` reads modulo their number.
+
+A folded page ``[block * Hkv, 2D]`` is the matrix
+``ops.paged_decode_attention`` reads, stored as that: positions and
+kv heads share the sublane dimension, so the bytes are the plain ones
+whatever ``Hkv`` is. The unfolded ``[block, Hkv, 2D]`` is the same
+bytes only where ``Hkv`` fills whole 8-row tiles: for 4 KV heads the
+TPU compiler gives it 4-row tiles (``T(4,128)(2,1)`` in bf16: still
+the plain bytes, no padding), whose order in memory is not the
+matrix's (``T(8,128)(2,1)``), and the kernel's view of a page would be
+a copy of the whole pool in front of every call.
+``prefix_sharing``, ``spec_k`` and ``cache_quant`` are refused for
+such a model: a shared page that a ring overwrites, a rejected draft's
+row that has already overwritten a visible one, an int8 ring.
+
 Page 0 is the reserved **trash page**: inactive slots' writes and
 unallocated page-table entries route there, so a fixed-shape step can
 always scatter/gather without corrupting live sequences (reads of
@@ -143,7 +187,10 @@ the class says itself: whether a step walks KV pages, which arrays of
 the pool are recurrent state, and the cache class of chunk admission
 (``cache.chunk``: :class:`StateChunk`, :class:`HybridChunk`), which in
 turn says where it finds a sequence's state (``where``) and what a
-prompt's chunks hand on beside the pool (``carried``).
+prompt's chunks hand on beside the pool (``carried``). A windowed
+decoder's class is :class:`PagedWindowed` (``ByKind`` over
+:class:`PagedKV` and :class:`PagedWindowKV`), and its bucket prefill's
+pages come from :meth:`KVPager.prompt_pages`.
 """
 from __future__ import annotations
 
@@ -151,9 +198,11 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
+from deeplearning4j_tpu.obs import devtime
 from deeplearning4j_tpu.obs import metrics as _metrics
 from deeplearning4j_tpu.ops import latent, retention, ssm
 from deeplearning4j_tpu.ops.pallas_kernels import (
@@ -167,6 +216,12 @@ class PageTableError(RuntimeError):
     :meth:`KVPager.check_invariants`, the churn tests' fence."""
 
 
+def ring_pages(window: int, block: int) -> int:
+    """Pages of a window layer's ring: the ``window`` keys a query
+    sees lie in at most ``ceil(window / block) + 1`` pages."""
+    return -(-window // block) + 1
+
+
 class KVPager:
     """Fixed pool of refcounted KV pages with free-list allocation.
 
@@ -178,7 +233,10 @@ class KVPager:
     ``(rows,)``), or a hybrid decoder's KV pages beside its state
     pages (``ssm``: ``(kv, H, tail)``; ``n_layers`` then counts the
     attention layers and ``ssm`` is ``(an ops.ssm.HybridSpec, decode
-    slots)``).
+    slots)``), or a windowed decoder's two kinds of KV pages
+    (``windowed``: ``(kv_full, kv_window)``, both folded; ``n_layers``
+    then counts the FULL layers and ``windowed`` is ``(a
+    decoder_infer.WindowSpec, decode slots)``).
     """
 
     def __init__(self, *, n_layers: int, n_kv_heads: int, head_dim: int,
@@ -186,7 +244,14 @@ class KVPager:
                  dtype: str = "float32",
                  state_rows: Optional[int] = None,
                  latent_dim: Optional[int] = None,
-                 ssm: Optional[Tuple] = None):
+                 ssm: Optional[Tuple] = None,
+                 windowed: Optional[Tuple] = None):
+        if windowed is not None and (
+                cache_quant is not None or state_rows is not None
+                or latent_dim is not None or ssm is not None):
+            raise ValueError("a windowed pool holds float KV pages of "
+                             "two kinds: cache_quant, state_rows, "
+                             "latent_dim and ssm do not apply")
         if ssm is not None and (cache_quant is not None
                                 or state_rows is not None
                                 or latent_dim is not None):
@@ -232,7 +297,21 @@ class KVPager:
         #: recurrent state (``state``) and, as ``chunk``, the cache
         #: object of chunk admission where the kind admits by chunks.
         self.cache = PagedKV
-        if ssm is not None:
+        #: pages of a window layer's ring (0: no window layers) and
+        #: the decode slots that own one each
+        self.ring = self.rings = 0
+        if windowed is not None:
+            self.cache = PagedWindowed
+            spec, slots = windowed
+            self.rings = slots
+            self.ring = ring_pages(spec.window, block)
+            page = (block * n_kv_heads, 2 * head_dim)
+            self._pool: Tuple = (
+                jnp.zeros((n_layers, n_pages, *page), jnp.dtype(dtype)),
+                jnp.zeros((len(spec.layers("window")),
+                           1 + slots * self.ring, *page),
+                          jnp.dtype(dtype)))
+        elif ssm is not None:
             self.cache = PagedHybrid
             spec, slots = ssm
             n_ssm = len(spec.layers("mamba2"))
@@ -303,7 +382,8 @@ class KVPager:
         """The layer-stacked device arrays the jitted step reads and
         rewrites: ``(codes,)`` or ``(codes, scales)`` of KV pages,
         ``(S, Z)`` of a recurrent-state pool, ``(rows,)`` of a latent
-        pool, ``(kv, H, tail)`` of a hybrid's."""
+        pool, ``(kv, H, tail)`` of a hybrid's, ``(kv_full, kv_window)``
+        of a windowed decoder's."""
         return self._pool
 
     @pool.setter
@@ -334,8 +414,35 @@ class KVPager:
         after the rows."""
         return self.cache(dims, pool, pt, pos, act)
 
+    def prompt_pages(self, slot: int, pages: List[int], tb: int,
+                     t0: int):
+        """What :meth:`write_prompt` takes as ``page_ids`` for the
+        sequence in ``slot`` with ``pages`` off the free list, a
+        bucket of ``tb`` rows and ``t0`` prompt tokens: the first ``tb
+        / block`` pages; for a windowed pool beside them the bucket's
+        pages a window layer keeps (``src [ring]``: the last ``ring``
+        up to the one that holds position ``t0 - 1``) and the slot's
+        ring pages they go to (``dst [ring]``; the trash page where
+        the prompt has fewer)."""
+        ids = np.asarray(pages[:tb // self.block], np.int32)
+        if not self.ring:
+            return jnp.asarray(ids)
+        src = (t0 - 1) // self.block - self.ring + 1 + np.arange(
+            self.ring, dtype=np.int32)
+        dst = np.where(src >= 0, 1 + slot * self.ring + src % self.ring,
+                       0).astype(np.int32)
+        return (jnp.asarray(ids), jnp.asarray(np.maximum(src, 0)),
+                jnp.asarray(dst))
+
+    def prompt_pages_shapes(self, tb: int):
+        """:meth:`prompt_pages` as shapes, for lowering."""
+        import jax
+        sds = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)
+        ids = sds(tb // self.block)
+        return (ids, sds(self.ring), sds(self.ring)) if self.ring else ids
+
     @staticmethod
-    def write_prompt(pool, page_ids, layers):
+    def write_prompt(pool, page_ids, layers, spec=None):
         """``pool`` with one sequence's bucket prefill written as whole
         pages: ``layers`` each layer's ``(k, v) [1, Tb, Hkv, D]``
         (``decoder_infer.causal_prefill``'s ``keep``), ``page_ids`` the
@@ -343,7 +450,22 @@ class KVPager:
         pool's own layout, so nothing is transposed on the way, and
         all layers go in one scatter. A latent pool takes each layer's
         latent rows ``[1, Tb, latent_dim]``
-        (``decoder_infer.latent_prefill``'s ``keep``)."""
+        (``decoder_infer.latent_prefill``'s ``keep``). A windowed pool
+        (``spec`` its ``WindowSpec``, ``page_ids`` as
+        :meth:`prompt_pages` gives them) takes every full layer's
+        pages and, of a window layer's, the last ``ring``."""
+        if spec is not None:
+            full, window = pool
+            ids, src, dst = page_ids
+            kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
+                            for k, v in layers])    # [L, Tb, Hkv, 2D]
+            tb = kv.shape[1]
+            rows = full.shape[2]
+            kv = kv.reshape(kv.shape[0], tb * kv.shape[2] // rows, rows,
+                            kv.shape[3]).astype(full.dtype)
+            at = lambda kind: jnp.asarray(spec.layers(kind), jnp.int32)
+            return (full.at[:, ids].set(kv[at("full")]),
+                    window.at[:, dst].set(kv[at("window")][:, src]))
         if pool[0].ndim == 4:
             (rows,) = pool
             lat = jnp.stack([r[0] for r in layers])     # [L, Tb, W]
@@ -576,6 +698,13 @@ class KVPager:
         _metrics.SERVING_KV_OCCUPANCY.set(
             (usable - len(self._free)) / usable)
         _metrics.SERVING_PREFIX_SHARED.set(self.shared_pages())
+        if self.ring:
+            # the slot is the reservation: a sequence's window pages
+            # are counted from its full pages, not held anywhere
+            held = _metrics.SERVING_KV_PAGES_HELD
+            held.labels(kind="full").set(usable - len(self._free))
+            held.labels(kind="window").set(sum(
+                min(len(p), self.ring) for p in self._pages_of.values()))
         for tenant, n in self._tenant_pages.items():
             _metrics.SERVING_KV_RESERVED.labels(tenant=tenant).set(n)
 
@@ -621,6 +750,14 @@ class KVPager:
                     raise PageTableError(
                         f"chain entry {key[:2]} references freed "
                         f"page {p}")
+        if self.ring and self._pool[1].shape[1] != \
+                1 + self.rings * self.ring:
+            # what keeps a sequence to ``ring`` pages of a window
+            # layer is the pool's shape: a slot's ring IS its pages
+            raise PageTableError(
+                f"the window pool has {self._pool[1].shape[1]} pages, "
+                f"not the trash page and {self.rings} rings of "
+                f"{self.ring}")
 
 
 # -- the pool's cache objects (nn/decoder_infer.py's contract) ---------------
@@ -656,25 +793,51 @@ class PagedKV(_Rows):
     rows, so a row's arithmetic is the same whatever R is (the
     spec-decode fence leans on that). A position past the slot's page
     table is clamped EXPLICITLY and routed to the trash page: JAX
-    gathers clamp silently, and junk must never land in a live page."""
+    gathers clamp silently, and junk must never land in a live page.
+    Over a FOLDED pool ``[L, P, block * Hkv, 2D]`` (a windowed
+    decoder's) R is 1; ``layers`` then names the model's layer of each
+    layer of this pool (a rotation may differ by layer)."""
     walks_kv = True
+
+    def __init__(self, dims, pool, pt, pos, act, layers=None):
+        super().__init__(dims, pool, pt, pos, act)
+        self.layers = layers
+        #: positions a query sees (None: all)
+        self.window = None
+
+    def pages(self, block: int):
+        """Where each row's KV goes, ``(page [S, R], in bounds [S,
+        R])``: the slot's page-table entry of the row's position."""
+        pt, pos = self.pt, self.pos
+        inb = self.act & (pos < pt.shape[1] * block)
+        pidx = jnp.minimum(pos // block, pt.shape[1] - 1)
+        return (jnp.where(inb, jnp.take_along_axis(pt, pidx, axis=1), 0),
+                inb)
+
+    def read(self, q, pool, li, inb, n_kv):
+        """The decode step's read: each slot's pages in place."""
+        return paged_decode_attention(
+            q, pool, li, self.pt,
+            jnp.where(inb[:, 0], self.pos[:, 0] + 1, 0),
+            window=self.window, n_kv=n_kv)
 
     def attend(self, li, mha, h):
         dims, pool, pt, pos = self.dims, self.pool, self.pt, self.pos
         S, R = pos.shape
-        block = pool[0].shape[2]
         pflat = pos.reshape(S * R)
+        theta = (dims.rope_theta if self.layers is None
+                 else di.layer_theta(dims, self.layers[li]))
         q, k, v = di.qkv(mha, h, dims, lambda z: di.rotary_rows(
-            z, dims.rope_theta, pflat))
+            z, theta, pflat))
         n_kv, hd = k.shape[1:]
+        if pool[0].ndim == 4:
+            return self._attend_folded(li, q, k, v)
+        block = pool[0].shape[2]
         q = q.reshape(S, R, dims.n_heads, hd)
         kv = jnp.concatenate([k.reshape(S, R, n_kv, hd),
                               v.reshape(S, R, n_kv, hd)],
                              axis=3)                    # [S, R, Kv, 2D]
-        cap = pt.shape[1] * block
-        inb = self.act & (pos < cap)
-        pidx = jnp.minimum(pos // block, pt.shape[1] - 1)
-        pids = jnp.where(inb, jnp.take_along_axis(pt, pidx, axis=1), 0)
+        pids, inb = self.pages(block)
         offs = pos % block
         if len(pool) == 2:
             codes, scales = pool
@@ -701,6 +864,91 @@ class PagedKV(_Rows):
         else:
             a = _reference_paged_attention(q, pool, li, pt, pos)
         return a.reshape(S * R, -1)
+
+    def _attend_folded(self, li, q, k, v):
+        """One position a slot against a folded pool: the position's
+        ``[Hkv, 2D]`` goes to rows ``offset * Hkv ..`` of its page as
+        ONE window of the scatter, in place."""
+        (kvpool,) = self.pool
+        S, n_kv, hd = k.shape
+        block = kvpool.shape[2] // n_kv
+        pids, inb = self.pages(block)
+        kv = jnp.concatenate([k, v], axis=2).astype(kvpool.dtype)
+        at = jnp.stack([jnp.full((S,), li, jnp.int32), pids[:, 0],
+                        (self.pos[:, 0] % block) * n_kv], axis=1)
+        kvpool = lax.scatter(
+            kvpool, at, kv, lax.ScatterDimensionNumbers(
+                update_window_dims=(1, 2), inserted_window_dims=(0, 1),
+                scatter_dims_to_operand_dims=(0, 1, 2)))
+        self.pool = (kvpool,)
+        return self.read(q, self.pool, li, inb, n_kv).reshape(S, -1)
+
+
+class PagedWindowKV(PagedKV):
+    """One position a slot against a windowed decoder's RING pool
+    ``[L_window, 1 + slots * ring, block * Hkv, 2D]``: slot ``s`` owns
+    pages ``1 + s * ring ..``, position ``t`` lies in its page ``(t //
+    block) % ring``. The slot's ring is its row of the page table,
+    ``ring`` entries long, and ``paged_decode_attention(window=)``
+    reads such a row modulo its length: the walk starts at the page
+    of the window's first position, masks that page's head and the
+    last one's stale tail, and takes ``ring`` pages at most."""
+
+    def __init__(self, dims, pool, pos, act, layers):
+        ring = (pool[0].shape[1] - 1) // pos.shape[0]
+        base = 1 + ring * jnp.arange(pos.shape[0], dtype=jnp.int32)
+        super().__init__(dims, pool, base[:, None] + jnp.arange(
+            ring, dtype=jnp.int32)[None, :], pos, act, layers)
+        self.ring = ring
+        self.window = dims.windowed.window
+
+    def pages(self, block: int):
+        inb = jnp.broadcast_to(self.act, self.pos.shape)
+        at = (self.pos // block) % self.ring
+        return (jnp.where(inb, jnp.take_along_axis(self.pt, at, axis=1),
+                          0), inb)
+
+
+class _Scoped:
+    """A cache object whose ``attend`` runs under a devtime scope."""
+
+    def __init__(self, inner, name: str):
+        self.inner = inner
+        self.name = name
+
+    def attend(self, li, mha, h):
+        with devtime.scope(self.name):
+            return self.inner.attend(li, mha, h)
+
+    @property
+    def pool(self):
+        return self.inner.pool
+
+
+class PagedWindowed(di.ByKind):
+    """The step's cache object over a windowed decoder's pool
+    ``(kv_full, kv_window)``: a full layer's rows go to
+    :class:`PagedKV` over the pages of the slot's page-table row, a
+    window layer's to :class:`PagedWindowKV` over the slot's ring,
+    each under the layer's index among ITS kind and under a scope of
+    its kind (``attn.full``, ``attn.window``: the two page walks'
+    device times are told apart by it)."""
+    walks_kv = True
+    state = slice(0, 0)
+    chunk = None
+
+    def __init__(self, dims, pool, pt, pos, act):
+        spec = dims.windowed
+        if pos.shape[1] != 1:
+            raise ValueError("a windowed pool serves one position a "
+                             "slot (no multi-row program)")
+        super().__init__(
+            spec,
+            full=_Scoped(PagedKV(dims, pool[:1], pt, pos, act,
+                                 spec.layers("full")), "attn.full"),
+            window=_Scoped(PagedWindowKV(dims, pool[1:], pos, act,
+                                         spec.layers("window")),
+                           "attn.window"))
 
 
 class PagedLatent(_Rows):
@@ -882,10 +1130,11 @@ class HybridChunk(di.ByKind):
     def __init__(self, dims, pool, carried, where, start, valid):
         page, pt_row = where
         pos = start + jnp.arange(valid.shape[1], dtype=jnp.int32)[None]
-        super().__init__(
-            dims.hybrid, mamba2=SSMChunk(dims, pool[1:], page, start, valid),
+        super().__init__(        # in the pool tuple's order
+            dims.hybrid,
             softmax=_OneSlot(PagedKV(dims, pool[:1], pt_row[None], pos,
-                                     valid)))
+                                     valid)),
+            mamba2=SSMChunk(dims, pool[1:], page, start, valid))
         self.carried = carried
 
     @staticmethod

@@ -76,6 +76,17 @@ them. ``prefix_sharing``, ``spec_k > 1`` and ``cache_quant`` are
 refused for it: a latent page's content-addressed sharing, a
 multi-row absorbed read and an int8 latent are not written yet.
 
+A model with WINDOWED softmax layers (``CausalTransformerLM(window=
+...)``) holds KV pages of two kinds in the one pager: pages off the
+free list for its full layers, reserved for a sequence's whole life
+as ever, and for its window layers the ring of pages that belongs to
+its decode slot. Admission is the bucket prefill; of a window layer's
+rows it keeps the last ``ring`` pages only
+(``KVPager.prompt_pages``). ``prefix_sharing``, ``spec_k > 1`` and
+``cache_quant`` are refused for it: a shared page that a ring
+overwrites, a rejected draft's row that has already overwritten a
+visible one, an int8 ring.
+
 The loop keeps ONE decode step in flight: :meth:`DecodeScheduler.step`
 launches step n+1 from step n's device-resident outputs and only then
 reads step n's tokens, so neither the read-back nor the next launch
@@ -238,7 +249,8 @@ class DecodeScheduler:
                     "rule compares per-row argmax against the draft; "
                     "under sampling it would skew the distribution")
         self.prefix_sharing = bool(prefix_sharing)
-        hd = model.hidden // model.n_heads
+        hd = (getattr(model, "head_dim", None)
+              or model.hidden // model.n_heads)
         #: a retention model: one fixed-size state page a sequence
         self.recurrent = getattr(model, "mixer",
                                  "softmax") == "power_retention"
@@ -265,6 +277,21 @@ class DecodeScheduler:
         self.expert_layers = (0 if experts is None
                               else model.n_layers - experts.first_dense)
         state_rows = None
+        #: a windowed decoder's spec: ring pages beside full pages
+        self.windowed = getattr(model, "windowed", None)
+        if self.windowed is not None:
+            for name, on, why in (
+                    ("prefix_sharing", self.prefix_sharing,
+                     "a shared page of a window layer would be "
+                     "overwritten by its first owner's ring"),
+                    ("spec_k", self.spec_k != 1,
+                     "a rejected draft's row may already have "
+                     "overwritten a ring page a later query sees"),
+                    ("cache_quant", bool(model.cache_quant),
+                     "a ring page has no int8 form yet")):
+                if on:
+                    raise ValueError(
+                        f"{name} with windowed layers: {why}")
         #: a hybrid decoder's spec: state pages beside KV pages
         hybrid = getattr(model, "hybrid", None)
         if self.recurrent or hybrid is not None:
@@ -300,7 +327,9 @@ class DecodeScheduler:
                 -(-mc // self.prefill_chunk) * self.prefill_chunk,
                 model.n_kv_heads, hd, model.compute_dtype or "float32")
         self.pager = KVPager(
-            n_layers=(model.n_layers if hybrid is None
+            n_layers=(len(self.windowed.layers("full"))
+                      if self.windowed is not None
+                      else model.n_layers if hybrid is None
                       else len(hybrid.layers("softmax"))),
             n_kv_heads=model.n_kv_heads,
             head_dim=hd, block=self.block,
@@ -310,7 +339,9 @@ class DecodeScheduler:
             dtype=model.compute_dtype or "float32",
             state_rows=state_rows,
             latent_dim=None if self.latent is None else self.latent.row,
-            ssm=None if hybrid is None else (hybrid, self.max_slots))
+            ssm=None if hybrid is None else (hybrid, self.max_slots),
+            **({} if self.windowed is None else
+               {"windowed": (self.windowed, self.max_slots)}))
         # per-slot host state, mirrored into the small int arrays the
         # fixed-shape step consumes each iteration
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
@@ -520,7 +551,10 @@ class DecodeScheduler:
                          live=jnp.arange(prompt_pad.shape[1])[None] < t0)
             row = jax.lax.dynamic_index_in_dim(x, t0 - 1, axis=1,
                                                keepdims=False)
-            out = (self.pager.write_prompt(pool, page_ids, kv),
+            out = (self.pager.write_prompt(
+                pool, page_ids, kv,
+                **({} if self.windowed is None
+                   else {"spec": self.windowed})),
                    self._first_token(params, row, "prefill", temp,
                                      top_p, ctr))
             return out + (sum(jnp.sum(p) for p in pairs),) if pairs \
@@ -734,8 +768,7 @@ class DecodeScheduler:
             else:
                 pool, g0, *pairs = fn(
                     params, self.pager.pool,
-                    jnp.asarray(np.asarray(pages[:tb // self.block],
-                                           np.int32)),
+                    self.pager.prompt_pages(slot, pages, tb, t0),
                     jnp.asarray(pad), *tail)
                 self.pager.pool = pool
             ts2 = obs.now()
@@ -954,6 +987,8 @@ class DecodeScheduler:
                               // self.block + 1)) if (
                                   self.pager.walks_kv) else 0
         state_bytes = len(act) * self.pager.state_bytes_per_slot
+        walks = self._window_walks(
+            self._lengths[act] + pending) if self.windowed else None
         # cached positions a latent step's attention reads, the one
         # being written included, and the (slot, chunk) items a
         # layer's walk of them has (all but the first issued ahead)
@@ -993,6 +1028,13 @@ class DecodeScheduler:
         if self.expert_layers:
             (args["expert_pairs"], args["experts_hit"],
              args["expert_pairs_max"]) = experts
+        if walks is not None:
+            args.update(walks)
+            obs.metrics.SERVING_KV_ROWS_READ.inc(walks["kv_rows_read"])
+            obs.metrics.SERVING_KV_ROWS_UNWINDOWED.inc(
+                walks["kv_rows_unwindowed"])
+            obs.metrics.SERVING_RING_OVERWRITES.inc(
+                walks["ring_overwrites"])
         obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
                         args=args, cause=self.cause, end=obs.now())
         obs.metrics.SERVING_KV_WALKED.set(kv_pages)
@@ -1000,6 +1042,30 @@ class DecodeScheduler:
         obs.metrics.SERVING_LATENT_ROWS.inc(latent_rows)
         obs.metrics.SERVING_AHEAD.inc(pending)
         return n
+
+    def _window_walks(self, at) -> dict:
+        """What a windowed decoder's step reads, from the positions
+        ``at`` its live slots write (the host's mirror: no device
+        read): ``kv_pages_window``, the pages ONE window layer's walk
+        reads (``kv_pages`` is one full layer's); ``kv_rows_read``,
+        the cached positions all layers' walks must read (a full
+        layer ``at + 1``, a window layer the last ``window`` of
+        them), and ``kv_rows_unwindowed``, what they would read with
+        no window; ``ring_overwrites``, the ring pages this step
+        begins to write over."""
+        spec, block = self.windowed, self.block
+        n = np.asarray(at, np.int64) + 1
+        n_full = len(spec.layers("full"))
+        n_win = len(spec.layers("window"))
+        first = np.maximum(n - spec.window, 0) // block
+        return {
+            "kv_pages_window": int(np.sum(-(-n // block) - first)),
+            "kv_rows_read": int(n_full * n.sum() + n_win * np.minimum(
+                n, spec.window).sum()),
+            "kv_rows_unwindowed": int((n_full + n_win) * n.sum()),
+            "ring_overwrites": int(n_win * np.sum(
+                ((n - 1) % block == 0)
+                & ((n - 1) // block >= self.pager.ring)))}
 
     def _collect(self, fl: _InFlight) -> tuple:
         """Read one launched step's tokens (the blocking device sync)
@@ -1284,7 +1350,7 @@ class DecodeScheduler:
             buckets = sorted({prompt_bucket(t, self.max_context)
                               for t in prompt_lens})
             warmed = [self._admit_fn(tb).warmup(
-                params, pool_sds, sds((tb // self.block,), i32),
+                params, pool_sds, self.pager.prompt_pages_shapes(tb),
                 sds((1, tb), i32), *scalars) for tb in buckets]
         compiled += sum(dt > 0 for dt in warmed)
         seconds += sum(warmed)

@@ -128,7 +128,9 @@ class CausalTransformerLM(ZooModel):
                  residual_multiplier: Optional[float] = None,
                  logits_scaling: Optional[float] = None,
                  attention_multiplier: Optional[float] = None,
-                 norm_eps: Optional[float] = None):
+                 norm_eps: Optional[float] = None,
+                 window: Optional[int] = None, window_layers=None,
+                 rope_layers=None, head_dim: Optional[int] = None):
         # the blocks' sequence mixer: "softmax" attention over a KV
         # cache, "power_retention" (ops/retention.py): a fixed-size
         # recurrent state per sequence, whatever its length, "latent"
@@ -136,7 +138,13 @@ class CausalTransformerLM(ZooModel):
         # compressed row a cached position, or "hybrid" (sized by
         # ``hybrid``, an ``ops.ssm.HybridSpec``): a kind PER LAYER,
         # Mamba-2 state-space layers beside softmax attention layers.
-        # ``rope_theta=None`` leaves the attention without positions.
+        # ``rope_theta=None`` leaves the attention without positions;
+        # ``rope_layers`` names the layers that rotate where only some
+        # do (the others carry no positional term at all). ``window``
+        # bounds the keys a softmax layer's query sees to the last
+        # ``window``, its own included, in the layers ``window_layers``
+        # names (None: all): one more KIND of softmax layer, not a
+        # mixer (what differs is what a cache must keep).
         # What a published decoder multiplies by, whatever its mixers
         # (each None: not applied, no multiply in any program): the
         # embedding's rows by ``embedding_multiplier``, each half's
@@ -167,6 +175,32 @@ class CausalTransformerLM(ZooModel):
                     "cache and a convolution among its 2-D leaves: "
                     "cache_quant, serve_quant and sequence_parallel do "
                     "not apply to it")
+        if (window is not None or rope_layers is not None) \
+                and mixer != "softmax":
+            raise ValueError("window and rope_layers are the softmax "
+                             "mixer's")
+        if window is not None and (cache_quant or serve_quant
+                                   or sequence_parallel):
+            raise ValueError(
+                "a windowed decoder's window layers keep a ring of KV "
+                "pages and mask by the plain form: cache_quant, "
+                "serve_quant and sequence_parallel do not apply to it")
+        if head_dim is not None and mixer != "softmax":
+            raise ValueError("head_dim is the softmax mixer's")
+        #: a softmax head's width where it is not ``hidden / n_heads``
+        self.head_dim = head_dim
+        if window_layers is not None and window is None:
+            raise ValueError("window_layers without a window")
+        #: ``decoder_infer.WindowSpec`` of a decoder with windowed
+        #: softmax layers (its window, a kind a layer), else None
+        self.windowed = None if window is None else di.WindowSpec(
+            int(window), tuple(
+                "window" if window_layers is None
+                or i in set(window_layers) else "full"
+                for i in range(n_layers)))
+        #: the layers that rotate (None: all, by ``rope_theta``)
+        self.rope_layers = (None if rope_layers is None
+                            else tuple(sorted(set(rope_layers))))
         #: ``ops.ssm.HybridSpec`` of a hybrid decoder (its layers'
         #: kinds, its Mamba sizes), else None
         self.hybrid = hybrid
@@ -255,7 +289,10 @@ class CausalTransformerLM(ZooModel):
                       and i >= self.experts.first_dense)
             b.layer(TransformerDecoderBlock(
                 n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-                ffn_mult=self.ffn_mult, rope_theta=self.rope_theta,
+                ffn_mult=self.ffn_mult,
+                rope_theta=di.layer_theta(self, i),
+                window=di.layer_window(self, i),
+                head_dim=self.head_dim,
                 dropout=self.dropout or None, remat=self.remat,
                 sequence_parallel=self.sequence_parallel,
                 mixer=(self.mixer if self.hybrid is None

@@ -354,6 +354,10 @@ SCHED_CASES = {
     "model.logits_scaling": ({}, dict(logits_scaling=4.0)),
     "model.attention_multiplier": ({}, dict(attention_multiplier=0.1)),
     "model.norm_eps": ({}, dict(norm_eps=1e-3)),
+    "model.window": ({}, dict(window=8)),
+    "model.window_layers": ({}, dict(window=8, window_layers=[0])),
+    "model.rope_layers": ({}, dict(rope_layers=[0])),
+    "model.head_dim": ({}, dict(head_dim=8)),
 }
 #: what a served program reads only as arguments, or not at all: the
 #: key must NOT move with these, or every new seed is a cold start

@@ -236,7 +236,7 @@ def test_no_token_is_dropped_when_every_row_chooses_one_expert():
     w = jnp.full((300, 4), 0.625)
     got, counts = M.experts(h, p, ids, w, (0, 4))
     np.testing.assert_array_equal(counts, [0, 0, 300, 0])
-    want = 0.625 * M.swiglu(h, p["Weg"][2], p["Weu"][2], p["Wed"][2])
+    want = 0.625 * M.gated(h, p["Weg"][2], p["Weu"][2], p["Wed"][2])
     np.testing.assert_allclose(got, want, atol=2e-5)
     assert float(jnp.min(jnp.max(jnp.abs(got), axis=1))) > 0
 
@@ -263,7 +263,7 @@ def test_rows_without_a_token_make_no_pair(plain):
     np.testing.assert_array_equal(counts, want)
     np.testing.assert_allclose(got[:7], alone, atol=2e-5)
     np.testing.assert_allclose(
-        got[7:], M.swiglu(pad, p["Wsg"], p["Wsu"], p["Wsd"]), atol=2e-5)
+        got[7:], M.gated(pad, p["Wsg"], p["Wsu"], p["Wsd"]), atol=2e-5)
     # routed all the same, the padding alone would have made pairs
     _, routed = M.layer(p, rows, EXPERTS, plain=plain)
     assert int(routed.sum()) >= int(want.sum()) + 9
@@ -284,7 +284,7 @@ def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
              offset=0)
     with jax.default_matmul_precision("highest"):
         want, _ = ref.experts_ffn(whole, h, d, "float32")
-    shared = M.swiglu(h, whole["Wsg"], whole["Wsu"], whole["Wsd"])
+    shared = M.gated(h, whole["Wsg"], whole["Wsu"], whole["Wsd"])
     total, pairs = shared, 0
     for rank in range(8):
         spec = M.ExpertSpec(width=32, n_held=4, n_routed=32, top_k=4,

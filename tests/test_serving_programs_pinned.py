@@ -1,0 +1,110 @@
+"""The serving programs of the decoders the benchmark held before the
+windowed one, pinned by the sha256 of their lowered text.
+
+A PR that adds a kind of layer, page or expert rule beside these must
+leave their step and prefill (or chunk) programs as they were: that is
+how it can say that no existing cell moves before a chip has run one.
+The hashes below are what the commit before PR 46 lowered, for the
+benchmark's toy configurations through the real builders and
+``DecodeScheduler`` (the code paths are the widths' own), once as the
+CPU lowers them (the plain fallbacks) and once with the kernels forced
+(``DL4J_TPU_KERNEL_FORCE=1``: what a TPU traces, in interpret mode).
+PR 46's own programs lower to the same text.
+
+A PR that MEANS to change one of these programs replaces its hashes
+here, in plain sight, and says so in ``CHANGES.md``; the text carries
+no source locations, so moving code changes nothing.
+
+What the toy widths cannot show: ``ops.moe.layer`` routes a bucket of
+more than ``ROUTE_BLOCK`` (4,096) rows a block at a time, and no toy
+bucket is that long. Neither is the DeepSeek cell's longest (4,096,
+not above it), so that branch is not taken in any cell pinned here.
+"""
+import hashlib
+import importlib.util
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+#: cell -> mode -> program -> sha256[:16] of ``lower().as_text()``
+PINNED = {
+    "mistral7b.chat-saturated": {
+        "plain": {"step": "c1504e32290e447a", "admit16": "8942b110f3b5152a",
+                  "admit64": "85305781cd81e6a6"},
+        "kernels": {"step": "f05e0258633bcbd1",
+                    "admit16": "31f012447a4a9ffe",
+                    "admit64": "4730be7afa423d41"}},
+    "brumby14b.decode-saturated": {
+        "plain": {"step": "10bffa502cea6987", "chunk": "1e9c9d88a2f52f80"},
+        "kernels": {"step": "4aeb03494500f1bf",
+                    "chunk": "e9ce8c0d1a36f05a"}},
+    "deepseekv3.decode-saturated": {
+        "plain": {"step": "aa9309f15c76f849", "admit16": "688578e8ff9f0e2c",
+                  "admit64": "cf8aaf7566861420"},
+        "kernels": {"step": "9d1f7fde90657376",
+                    "admit16": "6e0fc83288a27d98",
+                    "admit64": "aeaad40084a304d7"}},
+    "granite4h.chat-saturated": {
+        "plain": {"step": "ccc14242d5877cf1", "chunk": "9ba93a13f711d334"},
+        "kernels": {"step": "8ae93b0aba4ee239",
+                    "chunk": "ff401f5cc2fdf0cb"}},
+}
+
+
+def _sha(lowered) -> str:
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()[:16]
+
+
+def _programs(cell: str) -> dict:
+    from benchmarks import run
+    from deeplearning4j_tpu.serving import DecodeScheduler
+    # the benchmark's own rehearsal sizes (its conftest, by path: the
+    # name is this directory's too)
+    toy = importlib.util.spec_from_file_location(
+        "benchmarks_tests_conftest",
+        ROOT / "benchmarks" / "tests" / "conftest.py")
+    rehearsal = importlib.util.module_from_spec(toy)
+    toy.loader.exec_module(rehearsal)
+    spec = rehearsal.toy_spec(cell)
+    cfg = spec["config"]
+    built = run.Context.plugin("models", cfg["builder"]).build(
+        cfg, 7, lambda w: None)
+    model, net = built["model"], built["net"]
+    gw = spec["workload"]["driver_params"]["gateway"]
+    sched = DecodeScheduler(model, net, max_slots=gw["max_slots"],
+                            block=gw.get("block", 16),
+                            max_context=gw["max_context"])
+    params = model.decode_params(net)
+    sds, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    pool = tuple(sds(a.shape, a.dtype) for a in sched.pager.pool)
+    scalars = (sds((), i32), sds((), f32), sds((), f32), sds((), i32))
+    out = {"step": _sha(sched._step_fn.lower(
+        params, pool, *sched._step_feed_shapes()))}
+    if sched._chunk_fn is not None:
+        out["chunk"] = _sha(sched._chunk_fn.lower(
+            params, pool,
+            tuple(sds(a.shape, a.dtype) for a in sched._prefill_hist),
+            sched._chunk_where_shapes(),
+            sds((1, sched.prefill_chunk), i32), sds((), i32), *scalars))
+    else:
+        for tb in (16, 64):
+            out[f"admit{tb}"] = _sha(sched._admit_fn(tb).lower(
+                params, pool, sched.pager.prompt_pages_shapes(tb),
+                sds((1, tb), i32), *scalars))
+    return out
+
+
+@pytest.mark.parametrize("mode", ["plain", "kernels"])
+@pytest.mark.parametrize("cell", sorted(PINNED))
+def test_the_earlier_decoders_programs_lower_to_the_pinned_text(
+        monkeypatch, cell, mode):
+    if mode == "kernels":
+        monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    assert _programs(cell) == PINNED[cell][mode]

@@ -689,3 +689,126 @@ def test_hybrid_decode_step_compiles_for_v5e_in_place(chip, monkeypatch):
     assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9, mem
     assert mem.alias_size_in_bytes >= 6.5e9
     assert mem.temp_size_in_bytes < (64 << 20), mem
+
+
+# -- the windowed expert cell: 48 slots x 9,728 positions, 8 layers (2
+# full + 6 window), 4 kv heads of 128, 64 experts of 768 ------------------
+
+def _windowed_sched(chip, monkeypatch):
+    """The scheduler and the shapes of
+    ``smallthinker21b.longmix-saturated``: the configuration as the
+    benchmark's builder reads it, bf16 leaves and float32 routers,
+    nothing of the 4.0 B parameters and nothing of the 4.3 GB of pools
+    made."""
+    import json
+    from pathlib import Path
+    from benchmarks.models import window_moe_lm as builder
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.ops.moe import FLOAT32_LEAVES
+    from deeplearning4j_tpu.serving import DecodeScheduler, kv_pager
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmarks" / "configs"
+                      / "smallthinker-21ba3b-8l.json").read_text())
+    experts, layers = builder.specs(cfg)
+    model = CausalTransformerLM(
+        vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        max_len=16384, rope_theta=float(cfg["rope_theta"]),
+        tie_embeddings=False, norm_eps=1e-6,
+        updater=upd.Sgd(learning_rate=0.0), compute_dtype="bfloat16",
+        seed=1, experts=experts, **layers)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda: model.init().params))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        jax.ShapeDtypeStruct(
+            a.shape, jnp.float32 if getattr(path[-1], "key", None)
+            in FLOAT32_LEAVES else BF16, sharding=chip)
+        for path, a in flat])
+    with monkeypatch.context() as mp:
+        mp.setattr(kv_pager.jnp, "zeros",
+                   lambda shape, dtype=None: jax.ShapeDtypeStruct(
+                       shape, dtype, sharding=chip))
+        sched = DecodeScheduler(model, None, max_slots=48, block=16,
+                                max_context=9728)
+    return sched, params, sched.pager.pool
+
+
+def _pool_ops(hlo, a):
+    import re
+    shape = "bf16[" + ",".join(map(str, a.shape)) + "]"
+    return set(re.findall(r"= " + re.escape(shape) + r"\S* ([\w\-]+)\(",
+                          hlo))
+
+
+def test_windowed_decode_step_compiles_for_v5e_in_place(chip, monkeypatch):
+    """The whole ``serving.decode_step`` of the windowed expert cell:
+    the page-walk kernel lowered once a KIND (with the window and
+    without), 2 + 6 calls over the two FOLDED pools (a page of 4 KV
+    heads is the ``[64, 256]`` matrix the kernel reads: no copy of a
+    pool in front of it), each layer's new row written in place, 12.28
+    GB of arguments (weights 7.94, full pages 1.91, rings 2.43) and the
+    step's temporaries under 64 MB beside them."""
+    import re
+    sched, params, pool = _windowed_sched(chip, monkeypatch)
+    assert [a.shape for a in pool] == [
+        (2, 1 + 48 * 608, 64, 256), (6, 1 + 48 * 257, 64, 256)]
+    lowered = sched._step_fn.lower(
+        params, pool,
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes()))
+    funcs = re.findall(r"func\.func private @(\w*paged_decode\w*)",
+                       lowered.as_text())
+    assert len(funcs) == 2, funcs
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert sum("paged_decode_attention" in ln for ln in calls) == 8
+    for a in pool:
+        assert _pool_ops(hlo, a) <= {
+            "parameter", "get-tuple-element", "bitcast", "fusion",
+            "scatter", "dynamic-update-slice"}, _pool_ops(hlo, a)
+    mem = compiled.memory_analysis()
+    assert 12.2e9 < mem.argument_size_in_bytes < 12.35e9, mem
+    assert mem.alias_size_in_bytes >= 4.3e9     # both pools, in place
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+
+
+def test_windowed_bucket_prefill_compiles_for_v5e(chip, monkeypatch):
+    """The largest bucket (8,192 rows): the windowed flash kernel in
+    the six window layers, the unwindowed one in the two full layers,
+    both pools written in place (a window layer's last 257 pages
+    only), and, with the experts routing 4,096 rows at a time, under
+    1.6 GB of temporaries beside the 12.28 GB of arguments: 13.7 of the
+    chip's 15.75 GB."""
+    sched, params, pool = _windowed_sched(chip, monkeypatch)
+    i32 = jnp.int32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    ids = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                       sched.pager.prompt_pages_shapes(8192))
+    assert [a.shape for a in ids] == [(512,), (257,), (257,)]
+    compiled = sched._admit_fn(8192).lower(
+        params, pool, ids, sds((1, 8192), i32), sds((), i32),
+        sds((), jnp.float32), sds((), jnp.float32),
+        sds((), i32)).compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert sum("attn.window/dl4j.ops.flash_attention" in ln
+               for ln in calls) == 6
+    assert sum("attn.full/dl4j.ops.flash_attention" in ln
+               for ln in calls) == 2
+    for a in pool:
+        assert " copy(" not in " ".join(
+            ln for ln in hlo.splitlines()
+            if "bf16[" + ",".join(map(str, a.shape)) + "]" in ln.split(
+                " = ")[-1][:60])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4.3e9
+    assert mem.temp_size_in_bytes < 1.6e9, mem

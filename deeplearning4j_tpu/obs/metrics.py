@@ -122,6 +122,7 @@ FAMILIES = {
     "dl4j_tpu_serving_state_bytes_moved": "counter",
     "dl4j_tpu_serving_latent_rows_read_total": "counter",
     "dl4j_tpu_serving_expert_pairs_total": "counter",
+    "dl4j_tpu_moe_expert_layers_traced_total": "counter",
     # speculative multi-token decode (serving/scheduler.py)
     "dl4j_tpu_serving_spec_accept_rate": "histogram",
     "dl4j_tpu_serving_spec_drafted_total": "counter",
@@ -591,6 +592,12 @@ SERVING_EXPERT_PAIRS = REGISTRY.counter(
     "token-expert pairs the experts held here have computed, over "
     "all expert layers, decode steps and prefills (pairs routed to "
     "experts that other chips hold are not counted)")
+MOE_EXPERT_LAYERS = REGISTRY.counter(
+    "dl4j_tpu_moe_expert_layers_traced_total",
+    "expert layers TRACED by the form ops.moe.experts chose for them "
+    "from their shapes (path=kernel: the pipelined tile kernel; "
+    "path=loop: the tile loop): decided once a program, so a program "
+    "loaded by its key counts nothing", ("path",))
 
 # speculative multi-token decode + copy-on-write prefix sharing
 # (serving/scheduler.py + serving/kv_pager.py): accept rate is the

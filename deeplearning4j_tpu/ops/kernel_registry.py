@@ -109,6 +109,18 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "closes": (),
         "gate": "ssm_decode",
     },
+    "experts": {
+        "module": "ops/moe.py",
+        "fallback": "_experts_loop",
+        "parity":
+            "tests/test_window_moe.py::test_expert_kernel_matches_the_loop_and_the_plain_form",
+        "scope": "ops.moe_experts",
+        # the expert layer's own scope inside a block's ``.ffn``; an
+        # expert too large to lie in VMEM twice keeps the loop under
+        # the same scope (the shape fallback underneath the gate)
+        "closes": ("ops.moe_experts",),
+        "gate": "moe_experts",
+    },
     "threshold_encode": {
         "module": "ops/pallas_kernels.py",
         "fallback": "_jnp_threshold_encode",

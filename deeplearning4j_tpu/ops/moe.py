@@ -34,20 +34,47 @@ and ``Wsd``.
 
 :func:`experts` is the serving form: held token-expert pairs sorted by
 expert and multiplied a tile of rows at a time against that expert's
-matrices, in a loop whose length is the number of tiles the routing
-filled (an expert nobody chose costs no read of its weights).
+matrices, over the tiles the routing filled (an expert nobody chose
+costs no read of its weights). It has two forms, one algorithm at two
+sizes of tile whose fixed cost weighs differently:
+
+- the LOOP (:func:`_experts_loop`, plain ``jnp``): a ``fori_loop`` of
+  data-dependent length whose body takes its expert's matrices by a
+  dynamic index and adds its rows into the pairs' output. XLA runs a
+  ``while`` body's ops one after another, so a tile pays its weights'
+  read in full and some 10 us of small ops besides: a tenth of a tile
+  of 88 MB, two fifths of one of 12 MB;
+- the KERNEL (:func:`_experts_tiled`, Pallas): every group padded to
+  whole tiles, so that a tile belongs to ONE expert and each row is
+  written once; the grid walks the tiles, a scalar-prefetched table
+  names each tile's expert, and the weights come by ``BlockSpec``:
+  the pipeline fetches tile ``i + 1``'s expert while tile ``i`` is
+  multiplied, and consecutive tiles of one expert name the same block,
+  so an expert's weights are fetched ONCE however many tiles it fills.
+  The gated unit runs inside (float32 products, the unit in float32,
+  rounded to the compute dtype once, before the down projection).
+
+The rule between them (:func:`_use_expert_kernel`) is read from the
+operands at trace time: the platform gate, ``F`` and ``W`` whole
+128-lane tiles, a float compute dtype, and one expert's three matrices
+small enough to lie in VMEM twice over (:data:`_EXPERT_MAX_BYTES`).
+Above that the loop runs. Which form a traced layer took is tallied
+(``dl4j_tpu_moe_expert_layers_traced_total{path=}``).
 :func:`experts_plain` applies every held expert to every row and
 masks by the routing: the form autodiff runs (``fit`` at test size),
-and the other's check.
+and the others' check.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.obs import devtime
 
@@ -170,7 +197,46 @@ def experts(h, p, ids, weights, held, unit: str = "swiglu"):
     chip's static ``(offset, count)``, ``unit`` the experts' gated
     unit. Returns ``(y [T, F], counts
     [count] i32)``: ``counts[e]`` is the pairs held expert ``e``
-    computed.
+    computed. The kernel where :func:`_use_expert_kernel` takes the
+    operands, else the loop; the traced layer's path is tallied."""
+    from deeplearning4j_tpu.obs import metrics
+    from deeplearning4j_tpu.perf import sentry
+    kernel = _use_expert_kernel(h, p)
+    path = "kernel" if kernel else "loop"
+    metrics.MOE_EXPERT_LAYERS.labels(path=path).inc()
+    sentry.note_traced(f"expert_layers_{path}", expert_f=h.shape[-1],
+                       expert_w=p["Weg"].shape[-1], expert_held=held[1])
+    with devtime.scope("ops.moe_experts"):
+        if kernel:
+            return _experts_tiled(h, p, ids, weights, held, unit)
+        return _experts_loop(h, p, ids, weights, held, unit)
+
+
+def _sorted_pairs(ids, held):
+    """The ``T top_k`` pairs by held expert: ``mine [T, top_k]`` (the
+    pair's expert is held here), ``order [n]`` (the pairs sorted by
+    held expert, stably; pairs of experts not held last) and ``sizes
+    [count]`` i32 (the pairs of each held expert)."""
+    offset, count = held
+    n = ids.shape[0] * ids.shape[1]
+    local = ids - offset
+    mine = (local >= 0) & (local < count)
+    key = jnp.where(mine, local, count).reshape(n)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
+                    axis=0, dtype=jnp.int32)
+    return mine, order, sizes
+
+
+def _sorted_rank(order):
+    """Where each pair stands among the sorted: ``order``'s inverse."""
+    n = order.shape[0]
+    return jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+
+
+def _experts_loop(h, p, ids, weights, held, unit):
+    """:func:`experts` by the loop.
 
     The ``T top_k`` pairs are sorted by held expert (pairs of experts
     not held go last and are never multiplied); a group's rows are
@@ -179,15 +245,9 @@ def experts(h, p, ids, weights, held, unit: str = "swiglu"):
     gather back and weigh. Every size is static (all pairs have a
     row: nothing can be dropped); only the loop's length follows the
     routing."""
-    offset, count = held
     t, k = ids.shape
     n, f = t * k, h.shape[-1]
-    local = ids - offset
-    mine = (local >= 0) & (local < count)
-    key = jnp.where(mine, local, count).reshape(n)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
-                    axis=0, dtype=jnp.int32)
+    mine, order, sizes = _sorted_pairs(ids, held)
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
     bt = _tile_rows(n)
@@ -211,12 +271,178 @@ def experts(h, p, ids, weights, held, unit: str = "swiglu"):
 
     out = lax.fori_loop(0, tile_ends[-1], tile,
                         jnp.zeros((n + bt, f), h.dtype))
-    where = jnp.zeros((n,), jnp.int32).at[order].set(
-        jnp.arange(n, dtype=jnp.int32)).reshape(t, k)
+    where = _sorted_rank(order).reshape(t, k)
     w = jnp.where(mine, weights, 0.0)
     y = jnp.zeros((t, f), jnp.float32)
     for j in range(k):
         y = y + w[:, j, None] * out[where[:, j]].astype(jnp.float32)
+    return y.astype(h.dtype), sizes
+
+
+#: one expert's three matrices, at most, for the kernel to take them:
+#: they lie in VMEM twice over (this tile's and the next's) beside a
+#: tile's rows in and out and its float32 products, some 5 MB at 128
+#: rows, of the 128 MiB a v5e core has. SmallThinker's expert is 11.8
+#: MB, DeepSeek-V3's 88.1 MB
+_EXPERT_MAX_BYTES = 32 * 1024 * 1024
+
+
+def _use_expert_kernel(h, p) -> bool:
+    """The dispatch line of :func:`experts`, decided at trace time from
+    the operands: the platform gate every kernel uses
+    (``kernel_registry.gate_active``), a float compute dtype the
+    weights share, ``F`` and ``W`` whole 128-lane tiles (a block is
+    an expert's whole matrix, and Mosaic slices whole tiles only) and
+    an expert small enough to be double-buffered whole
+    (:data:`_EXPERT_MAX_BYTES`)."""
+    from deeplearning4j_tpu.ops.kernel_registry import gate_active
+    _, f, w = p["Weg"].shape
+    return (gate_active("moe_experts")
+            and h.dtype in (jnp.bfloat16, jnp.float32)
+            and all(p[m].dtype == h.dtype for m in ("Weg", "Weu", "Wed"))
+            and f % 128 == 0 and w % 128 == 0
+            and 3 * f * w * h.dtype.itemsize <= _EXPERT_MAX_BYTES)
+
+
+def _kernel_tile_rows(n_pairs: int, count: int) -> int:
+    """Rows of the kernel's tile: near the mean size of a HELD
+    expert's group, a power of two from 16 (a packed bf16 sublane
+    tile) to 128. An expert's weights are fetched once however many
+    tiles it fills, so a larger tile buys only a fetch better hidden
+    (the pipeline looks one tile ahead; a 4,096-row block of the v5e
+    cell: the kernel 2.03 ms a layer at 512 rows against 2.29 at 128)
+    and pays it back in the padded rows it gathers (the whole layer
+    3.85 against 3.88 ms), the memory they take and the size of the
+    program."""
+    rows = 16
+    while rows < 128 and rows * count < n_pairs:
+        rows *= 2
+    return rows
+
+
+def _expert_tiles_kernel(e_ref, n_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                         o_ref, *, unit):
+    """One tile of ``bt`` rows, all of ONE expert, whose three
+    matrices the pipeline has brought: the gated unit with float32
+    products, rounded once in front of the down projection. A grid
+    step past the routing's last tile does nothing (its blocks are
+    the last live tile's, already resident)."""
+    del e_ref
+
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        a = (UNITS[unit](g) * u).astype(x.dtype)
+        o_ref[...] = jnp.dot(
+            a, wd_ref[0],
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _expert_tiles_call(x, wg, wu, wd, tile_expert, n_live, *, bt, unit,
+                       interpret):
+    """``x [n_tiles bt, F]``, tile ``i`` the rows of expert
+    ``tile_expert[i]``, through that expert's unit: ``[n_tiles bt,
+    F]``. Only the first ``n_live[0]`` tiles are multiplied and
+    written; the rest name the last live tile's blocks, so that no
+    copy is issued for them."""
+    rows, f = x.shape
+    w = wg.shape[-1]
+    size = x.dtype.itemsize
+
+    def row_block(i, e, n):
+        return jnp.minimum(i, jnp.maximum(n[0] - 1, 0)), 0
+
+    def expert_block(i, e, n):
+        return e[i], 0, 0
+
+    # both buffers of the three matrices and of the rows in and out,
+    # the float32 products, and room for the compiler's own
+    vmem = (2 * 3 * f * w * size + 4 * bt * f * size
+            + 4 * bt * (2 * w + f) + (8 << 20))
+    return pl.pallas_call(
+        functools.partial(_expert_tiles_kernel, unit=unit),
+        out_shape=jax.ShapeDtypeStruct((rows, f), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows // bt,),
+            in_specs=[pl.BlockSpec((bt, f), row_block),
+                      pl.BlockSpec((1, f, w), expert_block),
+                      pl.BlockSpec((1, f, w), expert_block),
+                      pl.BlockSpec((1, w, f), expert_block)],
+            out_specs=pl.BlockSpec((bt, f), row_block)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem),
+        name="moe_expert_tiles",
+        interpret=interpret,
+    )(tile_expert, n_live, x, wg, wu, wd)
+
+
+def _pick(table, idx):
+    """``table[idx]`` for a short ``table`` and many ``idx`` as a
+    one-hot select and sum (0 where ``idx`` is past the table): an
+    element gather of thousands of indices is a megabyte of program
+    on the TPU."""
+    hot = idx[:, None] == jnp.arange(table.shape[0])[None, :]
+    return jnp.sum(jnp.where(hot, table[None, :], 0), axis=1)
+
+
+def _experts_tiled(h, p, ids, weights, held, unit):
+    """:func:`experts` by the kernel.
+
+    The sorted pairs are laid out with every group padded to whole
+    tiles of ``_kernel_tile_rows``: a static ``(n + count (bt - 1)) //
+    bt`` tiles hold them whatever the routing. The pairs' rows are
+    gathered from ``h`` once, the kernel multiplies the tiles the
+    routing filled, each row written once, and the tokens gather their
+    pairs' rows back (once, all of them) and weigh them. Rows of
+    padding and of tiles past the last are never read back: every size
+    is static and nothing can be dropped."""
+    from deeplearning4j_tpu.ops import pallas_kernels
+    offset, count = held
+    t, k = ids.shape
+    n, f = t * k, h.shape[-1]
+    mine, order, sizes = _sorted_pairs(ids, held)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    bt = _kernel_tile_rows(n, count)
+    n_tiles = (n + count * (bt - 1)) // bt      # sum of ceil(size / bt)
+    tiles = (sizes + bt - 1) // bt
+    tile_ends = jnp.cumsum(tiles)
+    tile_starts = tile_ends - tiles
+    n_live = tile_ends[-1]
+    # a sorted row's padded row lies `shift` of its group further on
+    shift = tile_starts * bt - starts
+    # tile i's expert; a tile past the last live one names the last
+    # live tile's, so that its weights are not fetched again
+    i = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
+                    jnp.maximum(n_live - 1, 0))
+    e = jnp.minimum(jnp.sum(tile_ends[None, :] <= i[:, None], axis=1,
+                            dtype=jnp.int32), count - 1)
+    # a tile's rows are consecutive sorted pairs from its first on (a
+    # group's padding reads the rows that follow it, tokens of the
+    # next group or token 0: computed and never read back)
+    tok = jnp.concatenate([(order // k).astype(jnp.int32),
+                           jnp.zeros((bt,), jnp.int32)])
+    tok = tok[((i * bt - _pick(shift, e))[:, None]
+               + jnp.arange(bt)).reshape(-1)]
+    out = _expert_tiles_call(
+        h[tok], p["Weg"], p["Weu"], p["Wed"], e,
+        n_live.reshape(1), bt=bt, unit=unit,
+        interpret=pallas_kernels._interpret())
+    # a pair's row; a pair of an expert not held has none (what lies
+    # at `where` there may never have been written: masked below)
+    where = _sorted_rank(order) + _pick(
+        shift, jnp.where(mine, ids - offset, count).reshape(n))
+    # ONE gather of all pairs' rows, the j-th pairs of all tokens
+    # together (token by token it takes half as long again), kept a
+    # gather of its own in front of the weighing
+    rows = lax.optimization_barrier(
+        out[where.reshape(t, k).T.reshape(n)]).reshape(k, t, f)
+    rows = jnp.where(mine.T[:, :, None], rows, jnp.zeros_like(rows))
+    y = jnp.sum(weights.T[:, :, None] * rows.astype(jnp.float32), axis=0)
     return y.astype(h.dtype), sizes
 
 
@@ -263,10 +489,13 @@ def layer(p, h, spec: ExpertSpec, plain: bool = False, live=None,
         score=spec.score)
     if live is not None:
         ids = jnp.where(live.reshape(-1, 1), ids, -1)   # held nowhere
-    with devtime.scope("ops.moe_experts"):
-        y, counts = (experts_plain if plain else experts)(
-            rows, p, ids, weights, (spec.offset, spec.n_held),
-            unit=spec.unit)
+    held = (spec.offset, spec.n_held)
+    if plain:
+        with devtime.scope("ops.moe_experts"):
+            y, counts = experts_plain(rows, p, ids, weights, held,
+                                      unit=spec.unit)
+    else:
+        y, counts = experts(rows, p, ids, weights, held, unit=spec.unit)
     if "Wsg" in p:
         with devtime.scope("ops.moe_shared"):
             y = gated(rows, p["Wsg"], p["Wsu"], p["Wsd"],

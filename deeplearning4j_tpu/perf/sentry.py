@@ -31,7 +31,10 @@ host arrays, a weights' maker). A nested jit's tracing is reported by
 JAX inside its caller's: a reader takes the union of the records'
 intervals, and :attr:`FunctionStats.phase_s` (plain sums, nested ones
 counted twice) says where one function's ``compile_time_s`` went:
-tracing, lowering, or compiling/loading.
+tracing, lowering, or compiling/loading. What a traced body decides at
+trace time it can say there (:func:`note_traced`: which form of an
+expert layer a program holds): the counts ride on the
+``compile/jaxpr_trace`` record of the sentried function being traced.
 
 **A key that needs no trace.** ``jit(fn, name=..., identity=...)``
 takes a callable of the entry point's owner that returns, as plain
@@ -91,12 +94,29 @@ def _on_duration(event: str, duration: float, **kw) -> None:
     from deeplearning4j_tpu.obs import trace
     owner = getattr(_tls, "owner", None)
     t1 = trace.now()
+    said = {}
+    if (phase == "jaxpr_trace" and owner is not None
+            and kw.get("fun_name") == owner.fun):
+        # the sentried function's own trace ends (nested jits' ended
+        # before it): what its body noted goes on this record
+        said, _tls.notes = _tls.notes, {}
     trace.record("compile/" + phase, t1 - duration, t1,
                  owner.name if owner is not None else "eager",
-                 fun=kw.get("fun_name"))
+                 fun=kw.get("fun_name"), **said)
     if owner is not None:
         with _LOCK:
             owner.phase_s[phase] += duration
+
+
+def note_traced(tally: str, **facts) -> None:
+    """Called by a body WHILE a sentried function traces it: adds one
+    to ``tally`` and sets ``facts`` among the counts of that trace's
+    ``compile/jaxpr_trace`` record. Outside a sentried call (eager, a
+    plain ``jax.jit``, :meth:`SentryJit.lower`) nothing is kept."""
+    notes = getattr(_tls, "notes", None)
+    if notes is not None:
+        notes[tally] = notes.get(tally, 0) + 1
+        notes.update(facts)
 
 
 def install_compile_listener() -> None:
@@ -115,11 +135,13 @@ def _compiling(stats: "FunctionStats"):
     meanwhile (the outermost sentried function wins a nested call)."""
     prev = getattr(_tls, "owner", None)
     if prev is None:
-        _tls.owner = stats
+        _tls.owner, _tls.notes = stats, {}
     try:
         yield
     finally:
         _tls.owner = prev
+        if prev is None:
+            _tls.notes = None
 
 
 # strict()/budget() context overrides (None -> read the env flags)
@@ -173,6 +195,7 @@ class FunctionStats:
 
     def __init__(self, name: str, budget: Optional[int]):
         self.name = name
+        self.fun = name               # the traced function's own name
         self.budget = budget          # None -> global flag/override
         self.traces = 0               # total tracings (incl. planned)
         self.compiles = 0             # compiles observed on live calls
@@ -270,6 +293,7 @@ class SentryJit:
             stats.note_trace(signature((args, kwargs)))
             return fn(*args, **kwargs)
 
+        stats.fun = counted.__name__    # what JAX calls its trace
         self._jitted = jax.jit(counted, **jit_kwargs)
         with _LOCK:
             _REGISTRY.append(weakref.ref(stats))

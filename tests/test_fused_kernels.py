@@ -473,9 +473,9 @@ def test_gap_report_marks_closed_scopes(monkeypatch):
 def test_registry_entries_resolve():
     """Every registry entry's fallback exists and is callable, and the
     closed gauge semantics follow gate_active."""
-    from deeplearning4j_tpu.ops import fused_norms, pallas_kernels
+    from deeplearning4j_tpu.ops import fused_norms, moe, pallas_kernels
     mods = {"ops/pallas_kernels.py": pallas_kernels,
-            "ops/fused_norms.py": fused_norms}
+            "ops/fused_norms.py": fused_norms, "ops/moe.py": moe}
     for name, entry in kernel_registry.KERNEL_REGISTRY.items():
         mod = mods[entry["module"]]
         assert callable(getattr(mod, entry["fallback"])), name

@@ -226,6 +226,35 @@ def test_sorted_experts_equal_the_plain_form(rows):
     np.testing.assert_array_equal(plain_counts, want_counts)
 
 
+@pytest.mark.parametrize("width,dtype", [
+    (32, jnp.float32), (128, jnp.float16)],
+    ids=["not_lane_aligned", "a_dtype_the_kernel_does_not_take"])
+def test_the_expert_layers_here_keep_the_loop(monkeypatch, width, dtype):
+    """The rule that engages the expert kernel reads the operands
+    alone. Even with the kernels forced, this model's test widths (64
+    wide rows, 32 wide experts) are not whole lane tiles and keep the
+    tile loop, as does a compute dtype the kernel does not take; the
+    traced layers are tallied as ``loop``, none as ``kernel``. (An
+    expert of the PUBLISHED widths is too large to lie in VMEM twice:
+    ``tests/test_tpu_aot_compile.py``.)"""
+    from deeplearning4j_tpu import obs
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    e = M.ExpertSpec(width=width, n_held=4, n_routed=32, top_k=4,
+                     n_group=4, topk_group=2, scale=2.5, n_shared=1)
+    f = 64 if width == 32 else 128
+    p = _moe_params(jax.random.PRNGKey(0), f=f, e=e, dtype=dtype)
+    h = jax.random.normal(jax.random.PRNGKey(1), (9, f)).astype(dtype)
+    assert not M._use_expert_kernel(h, p)
+    tally = lambda: dict(obs.metrics.MOE_EXPERT_LAYERS.snapshot())
+    before = tally()
+    text = jax.jit(lambda p, h: M.layer(p, h, e)).lower(p, h).as_text()
+    assert "stablehlo.while" in text
+    after = tally()
+    assert after['{path="loop"}'] == before.get('{path="loop"}', 0) + 1
+    assert after.get('{path="kernel"}', 0) == before.get(
+        '{path="kernel"}', 0)
+
+
 def test_no_token_is_dropped_when_every_row_chooses_one_expert():
     """The worst routing for a capacity: all 300 rows send a pair to
     held expert 2 (and their others elsewhere). Every row gets its

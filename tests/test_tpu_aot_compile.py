@@ -767,6 +767,10 @@ def test_windowed_decode_step_compiles_for_v5e_in_place(chip, monkeypatch):
     calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
              and " custom-call(" in ln]
     assert sum("paged_decode_attention" in ln for ln in calls) == 8
+    # the experts' tiles: one pipelined kernel a layer, and no loop
+    assert sum("moe_expert_tiles" in ln for ln in calls) == 8
+    assert not [ln for ln in hlo.splitlines()
+                if " while(" in ln and "moe_experts" in ln]
     for a in pool:
         assert _pool_ops(hlo, a) <= {
             "parameter", "get-tuple-element", "bitcast", "fusion",
@@ -812,3 +816,87 @@ def test_windowed_bucket_prefill_compiles_for_v5e(chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 4.3e9
     assert mem.temp_size_in_bytes < 1.6e9, mem
+
+
+# -- the experts' two forms at the two cells' PUBLISHED widths -------------
+
+def _expert_layer(chip, rows, *, f, w, held, routed, top_k, **rule):
+    """``ops.moe.layer`` lowered for the described chip from shapes
+    (no weights are made): one chip's expert layer over ``rows``."""
+    from deeplearning4j_tpu.ops import moe
+    spec = moe.ExpertSpec(width=w, n_held=held, n_routed=routed,
+                          top_k=top_k, **rule)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    p = {"Wr": sds((f, routed), jnp.float32),
+         "br": sds((routed,), jnp.float32),
+         "Weg": sds((held, f, w)), "Weu": sds((held, f, w)),
+         "Wed": sds((held, w, f))}
+    if spec.n_shared:
+        p.update(Wsg=sds((f, spec.n_shared * w)),
+                 Wsu=sds((f, spec.n_shared * w)),
+                 Wsd=sds((spec.n_shared * w, f)))
+    return jax.jit(lambda p, h, live: moe.layer(p, h, spec, live=live)
+                   ).lower(p, sds((rows, f)), sds((rows,), jnp.bool_))
+
+
+_SMALLTHINKER = dict(f=2560, w=768, held=64, routed=64, top_k=6,
+                     n_shared=0, score="softmax_topk", unit="reglu",
+                     route_before_mixer=True)
+_DEEPSEEK = dict(f=7168, w=2048, held=16, routed=256, top_k=8, n_group=8,
+                 topk_group=4, scale=2.5, n_shared=1)
+
+
+@pytest.mark.parametrize("rows", [48, 64, 4096])
+def test_the_rule_on_shapes_parts_the_two_expert_cells(chip, rows):
+    """The witness that DeepSeek's programs are untouched and
+    SmallThinker's changed, at the widths the pinned toy programs
+    cannot show: with the kernels forced, 16 held experts of 88.1 MB
+    keep the ``while`` over tiles and hold no call of the kernel; 64
+    held experts of 11.8 MB hold the kernel's call and no ``while``."""
+    from deeplearning4j_tpu.ops import moe
+    small = _expert_layer(chip, rows, **_SMALLTHINKER).as_text()
+    assert "moe_expert_tiles" in small and "stablehlo.while" not in small
+    big = _expert_layer(chip, rows, **_DEEPSEEK).as_text()
+    assert "stablehlo.while" in big and "moe_expert_tiles" not in big
+    assert 3 * 2560 * 768 * 2 < moe._EXPERT_MAX_BYTES < 3 * 7168 * 2048 * 2
+
+
+def test_a_buckets_expert_layer_compiles_for_v5e_beside_its_loop(
+        chip, monkeypatch):
+    """One 4,096-row block of a SmallThinker bucket through the
+    kernel: Mosaic takes an expert's three matrices twice over beside
+    a 128-row tile (it fits VMEM), and the layer's temporaries pass
+    the loop's by no more than the pairs' rows gathered once (the loop
+    gathers a tile at a time) and the rows that pad every group to
+    whole tiles, in and out. (In the whole bucket program the kernel
+    form holds LESS than the loop's: ``test_windowed_bucket_prefill``'s
+    bound.)"""
+    from deeplearning4j_tpu.ops import moe
+    kernel = _expert_layer(chip, 4096, **_SMALLTHINKER).compile()
+    calls = [ln for ln in kernel.as_text().splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert sum("moe_expert_tiles" in ln for ln in calls) == 1
+    monkeypatch.setattr(moe, "_use_expert_kernel", lambda h, p: False)
+    loop = _expert_layer(chip, 4096, **_SMALLTHINKER).compile()
+    assert "moe_expert_tiles" not in loop.as_text()
+    n, bt = 4096 * 6, moe._kernel_tile_rows(4096 * 6, 64)
+    assert bt == 128
+    padded = (n + 64 * (bt - 1)) // bt * bt - n
+    grew = (kernel.memory_analysis().temp_size_in_bytes
+            - loop.memory_analysis().temp_size_in_bytes)
+    assert grew <= (n + 2 * padded) * 2560 * 2, (grew, padded)
+
+
+def test_the_largest_expert_the_rule_takes_compiles_for_v5e(chip):
+    """An expert at the rule's edge (three 4,096 x 1,280 bf16 matrices,
+    31.5 of the 32 MiB) in a 128-row tile: what the rule lets through,
+    Mosaic takes."""
+    from deeplearning4j_tpu.ops import moe
+    assert 3 * 4096 * 1280 * 2 <= moe._EXPERT_MAX_BYTES
+    hlo = _expert_layer(chip, 4096, f=4096, w=1280, held=8, routed=8,
+                        top_k=2, n_shared=0, score="softmax_topk",
+                        unit="swiglu").compile().as_text()
+    assert "moe_expert_tiles" in hlo

@@ -397,6 +397,166 @@ def test_the_sigmoid_groups_rule_is_bit_equal_to_the_parent_s(plain):
         "sigmoid_groups", "swiglu", False)
 
 
+# -- the expert kernel against the loop and the plain form -------------------
+
+_KF, _KW = 256, 128         # lane-aligned: the widths the rule takes
+
+
+def _one_expert(t):
+    """Every row's first pair on held expert 2, its second held
+    nowhere."""
+    return np.stack([np.full(t, 2), np.full(t, -1)], 1)
+
+
+def _sized(t, sizes):
+    """``[t, 2]`` ids whose pairs fill the held experts' groups to
+    ``sizes`` exactly, in a scattered order; what is left over is held
+    nowhere."""
+    flat = np.concatenate([np.full(n, e) for e, n in enumerate(sizes)]
+                          + [np.full(2 * t - sum(sizes), -1)])
+    return np.random.default_rng(t).permutation(flat).reshape(t, 2)
+
+
+#: name -> (rows, ids or None for the router's own, live rows or None);
+#: most at 32 rows, so that they share their compiled programs
+_KERNEL_CASES = {
+    "routed_1": (1, None, None),
+    "routed_32": (32, None, None),
+    "routed_300": (300, None, None),
+    # 64 pairs over 4 held experts make 16-row tiles: a group that is
+    # empty, one of exactly a tile, one of a tile and a row
+    "empty_exact_plus_one": (32, _sized(32, [16, 17, 0, 31]), None),
+    "one_row_each": (32, _sized(32, [1, 1, 1, 1]), None),
+    "all_on_one_expert": (32, _one_expert(32), None),
+    "no_live_row": (32, None, 0),
+    "some_live_rows": (32, None, 9),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+@pytest.mark.parametrize("held", [(0, 4), (3, 4)],
+                         ids=["all_held", "offset_3_of_8"])
+@pytest.mark.parametrize("unit", ["swiglu", "reglu"])
+def test_expert_kernel_matches_the_loop_and_the_plain_form(
+        monkeypatch, unit, held, case):
+    """``ops.moe.experts`` at lane-aligned widths with the kernels
+    forced (the Pallas kernel, interpret mode) against the tile loop
+    and against every held expert on every row: ``y`` within the
+    parity tests' tolerance, ``counts`` the loop's exactly. With
+    ``offset`` 3 of 8 published, pairs of experts 0-2 and 7 are held
+    nowhere here and add nothing."""
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    rows, ids, live = _KERNEL_CASES[case]
+    offset, count = held
+    n_routed = 4 if offset == 0 else 8
+    p = _moe_params(jax.random.PRNGKey(3), n_held=count, f=_KF, w=_KW,
+                    n_routed=n_routed)
+    h = jax.random.normal(jax.random.PRNGKey(rows), (rows, _KF))
+    if ids is None:
+        ids, w = M.route(h, p["Wr"], p["br"], n_group=1, topk_group=1,
+                         top_k=2, scale=1.0, score="softmax_topk")
+    else:
+        ids = jnp.asarray(np.where(ids >= 0, ids + offset, -1), jnp.int32)
+        w = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(7),
+                                             (rows, 2)), axis=-1)
+    if live is not None:
+        ids = jnp.where((jnp.arange(rows) < live)[:, None], ids, -1)
+    assert M._use_expert_kernel(h, p)
+    got, counts = jax.jit(M.experts, static_argnums=(4, 5))(
+        h, p, ids, w, held, unit)
+    loop, loop_counts = jax.jit(M._experts_loop, static_argnums=(4, 5))(
+        h, p, ids, w, held, unit)
+    plain, plain_counts = jax.jit(M.experts_plain, static_argnums=(4, 5))(
+        h, p, ids, w, held, unit)
+    np.testing.assert_array_equal(counts, loop_counts)
+    np.testing.assert_array_equal(counts, plain_counts)
+    np.testing.assert_allclose(got, loop, atol=3e-5)
+    np.testing.assert_allclose(got, plain, atol=3e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    if live == 0:
+        assert int(counts.sum()) == 0 and not np.asarray(got).any()
+    elif offset == 0 and _KERNEL_CASES[case][1] is None:
+        assert int(counts.sum()) == 2 * (rows if live is None else live)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_expert_kernel_through_the_layer_s_blocks(monkeypatch, dtype, tol):
+    """A bucket of more than ``ROUTE_BLOCK`` rows goes through
+    ``layer``'s ``lax.map`` a block at a time, the kernel inside it,
+    with rows of padding that make no pair; in bfloat16 the kernel
+    rounds once where the loop rounds three times, and stays within
+    bfloat16 (of the largest output) of the float32 plain form."""
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    spec = M.ExpertSpec(width=_KW, n_held=4, n_routed=4, top_k=2,
+                        n_shared=0, score="softmax_topk", unit="reglu",
+                        route_before_mixer=True)
+    p = _moe_params(jax.random.PRNGKey(4), n_held=4, f=_KF, w=_KW,
+                    n_routed=4)
+    rows = 2 * M.ROUTE_BLOCK
+    b = jax.random.normal(jax.random.PRNGKey(5), (rows, _KF))
+    a = jax.random.normal(jax.random.PRNGKey(6), (rows, _KF))
+    live = jnp.arange(rows) < rows - 700
+    cast = lambda tree: jax.tree.map(
+        lambda z: z.astype(dtype) if z.ndim == 3 else z, tree)
+    got, counts = jax.jit(lambda p, b, a, m: M.layer(
+        p, b, spec, live=m, route_rows=a))(cast(p), b.astype(dtype), a,
+                                           live)
+    want, want_counts = M.layer(p, b, spec, plain=True, live=live,
+                                route_rows=a)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert int(counts.sum()) == 2 * (rows - 700)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               atol=tol * float(np.abs(want).max()))
+    assert got.dtype == dtype and not np.asarray(got[-700:]).any()
+
+
+def test_the_traced_layers_path_is_tallied(monkeypatch):
+    """Which form a traced expert layer took is decided once a
+    program, so that is what is counted: ``/metrics``'
+    ``dl4j_tpu_moe_expert_layers_traced_total{path=}`` and, with the
+    layer's widths and held count, the ``compile/jaxpr_trace`` record
+    of the sentried program. At aligned widths with the kernels forced
+    it is the kernel; at the toy widths the loop."""
+    from deeplearning4j_tpu import obs
+    from deeplearning4j_tpu.obs import trace
+    from deeplearning4j_tpu.perf import sentry
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    tally = lambda: dict(obs.metrics.MOE_EXPERT_LAYERS.snapshot())
+    spec = M.ExpertSpec(width=_KW, n_held=4, n_routed=4, top_k=2,
+                        n_shared=0, score="softmax_topk", unit="reglu")
+    p = _moe_params(jax.random.PRNGKey(4), n_held=4, f=_KF, w=_KW,
+                    n_routed=4)
+    h = jax.random.normal(jax.random.PRNGKey(5), (12, _KF))
+    before, t0 = tally(), trace.now()
+
+    def two_layers(p, h):
+        y, _ = M.layer(p, h, spec)
+        return M.layer(p, h + y, spec)
+    sentry.jit(two_layers, name="test.two_expert_layers")(p, h)
+    after = tally()
+    assert after.get('{path="kernel"}', 0) - before.get(
+        '{path="kernel"}', 0) == 2
+    assert after.get('{path="loop"}', 0) == before.get('{path="loop"}', 0)
+    said = [r.counts for r in trace.records(t0)
+            if r.name == "compile/jaxpr_trace"
+            and r.cause == "test.two_expert_layers"
+            and r.counts and "expert_layers_kernel" in r.counts]
+    assert len(said) == 1, said
+    assert {k: said[0][k] for k in ("expert_layers_kernel", "expert_f",
+                                    "expert_w", "expert_held")} == {
+        "expert_layers_kernel": 2, "expert_f": _KF, "expert_w": _KW,
+        "expert_held": 4}
+    # the toy widths are not lane-aligned and keep the loop
+    toy = _moe_params(jax.random.PRNGKey(1))
+    M.layer(toy, jax.random.normal(jax.random.PRNGKey(2), (5, 64)),
+            EXPERTS)
+    assert tally()['{path="loop"}'] == after.get('{path="loop"}', 0) + 1
+    text = obs.metrics.REGISTRY.exposition()
+    assert 'dl4j_tpu_moe_expert_layers_traced_total{path="kernel"}' in text
+
+
 def test_expert_spec_checks_its_rule_and_unit():
     with pytest.raises(ValueError, match="score"):
         M.ExpertSpec(width=8, n_held=4, n_routed=4, top_k=2, score="top")

@@ -493,6 +493,14 @@ read of the experts' weights) and `expert_pairs_max` (the fullest held
 expert's pairs, summed over the layers: max over mean is the load
 imbalance). These three are of the step whose tokens that call READ;
 `latent_rows` and `latent_chunks` are of the step it launched.
+`dl4j_tpu_moe_expert_layers_traced_total{path="kernel"|"loop"}` says
+which form `ops.moe.experts` chose for the expert layers it TRACED
+(the pipelined tile kernel where an expert's three matrices lie in VMEM
+twice over, lane-aligned, on the TPU; else the tile loop): decided once
+a program from the operands' shapes, so a program loaded by its key
+counts nothing, and the traced program's `compile/jaxpr_trace` record
+carries `expert_layers_kernel` / `expert_layers_loop` with `expert_f`,
+`expert_w`, `expert_held`.
 """
 
 # hand-maintained operations doc, re-emitted on every regeneration

@@ -249,6 +249,7 @@ SCOPE_SITES = {
                               "retention_decode", "ssm_decode",
                               "threshold_encode", "threshold_decode"),
     "ops/fused_norms.py": ("rms_norm", "add_rms_norm", "layer_norm"),
+    "ops/moe.py": ("experts",),
 }
 
 # rule 8 source of truth for gap-report keys

@@ -22,7 +22,8 @@ Kernels:
 - ``paged_decode_attention`` — the serving decode step's attention
   over the paged KV pool, read in place: per slot a loop over the
   slot's pages bounded by its length, whole pages copied HBM -> VMEM
-  by the kernel's own double-buffered DMAs, float32 online softmax.
+  by the kernel's own double-buffered DMAs (one pipeline over all
+  slots of a call), float32 online softmax.
   ``_reference_paged_attention`` is its fallback and the attention of
   the multi-row paged programs.
 - ``latent_decode_attention`` — the same page walk over the paged
@@ -887,10 +888,24 @@ def flash_attention(q, k, v, causal: bool = False,
 # position the K (lanes ``0:D``) and V (lanes ``D:2D``) rows of every
 # kv head. The kernel never sees a gathered context: per slot it walks
 # the slot's page-table row up to the slot's length, copies whole
-# pages HBM -> VMEM with its own DMAs (the next chunk in flight while
-# this one is computed), and folds each chunk into a float32 online
-# softmax (the recurrence of ``_flash_kernel``). Pages past the
-# length, trash pages and inactive slots are never touched.
+# pages HBM -> VMEM with its own DMAs, and folds each chunk into a
+# float32 online softmax (the recurrence of ``_flash_kernel``). Pages
+# past the length, trash pages and inactive slots are never touched.
+#
+# The walk is ONE software pipeline over all (slot, chunk) items of a
+# call, in slot order (PR 40 gave it to the latent kernel below, PR 48
+# to this one; the two share the helpers that issue, await and look
+# ahead): while an item's rows are folded, the NEXT item's pages are
+# in flight into the buffer's other half, be that the same slot's next
+# chunk or the first chunk of the next live slot. The grid stays one
+# step a slot (its output block is the slot's); the buffer, its
+# semaphores and the copies in flight carry over the steps' edges, so
+# only a call's first item is waited for with nothing to fold. A copy
+# is issued by ONE page number into pool and buffer with the
+# compiler's bounds checks off (the scheduler holds every page number
+# under the pool's page count where it builds the feed), an item is
+# awaited once a set bit of its page count, and a chunk is folded up
+# to the quarter that holds its last fetched page.
 #
 # A page is read as the ``[block * Hkv, 2D]`` matrix it is: ALL query
 # heads meet ALL of a chunk's rows in one [H, D] x [D, rows] matmul,
@@ -907,42 +922,116 @@ def flash_attention(q, k, v, causal: bool = False,
 # the ``[H, 128]`` it gets back. The MXU multiplies twice the width;
 # the bytes read, which bound the call, are the same.
 
-#: rows of a chunk (positions x kv heads) folded per loop iteration.
-#: On the v5e at the serving cells' shapes (16 x 8 rows a page): six
-#: layers over 32 full slots took 5.08 / 3.51 / 2.95 ms at 512 /
-#: 1,024 / 2,048 rows (396 / 573 / 682 GB/s of live KV), and 0.74 /
-#: 0.67 / 0.66 ms at the steady cell's occupancy (my chip run 1, PR 25)
-_PAGED_CHUNK_ROWS = 2048
+#: rows of a chunk (positions x kv heads) folded per loop iteration:
+#: one constant, read against the operands' shapes. PERF.md §5 ("The
+#: KV walk, taken apart") has the sweep at the serving cells' shapes
+_PAGED_CHUNK_ROWS = 4096
+
+
+def _first_live(n_ref, j, n_slots):
+    """The first live slot at or after ``j``; ``n_slots`` where there
+    is none (a page walk's pipeline looks for the slot whose first
+    chunk follows this slot's last)."""
+    return lax.while_loop(
+        lambda j: (j < n_slots)
+        & (n_ref[jnp.minimum(j, n_slots - 1)] == 0),
+        lambda j: j + 1, j)
+
+
+def _issue_pages(pt_ref, pool_ref, buf, done, pool0, first, buf0, lo, hi):
+    """Start the copies of pages ``lo .. hi - 1`` of one (slot, chunk)
+    item: page ``j`` is entry ``first + j`` of the flat page table, a
+    row of the pool ``[L * P, ...]`` past ``pool0``, and goes to row
+    ``buf0 + j`` of the buffer ``[2 * chunk, ...]``. Pool and buffer
+    are indexed by ONE page number each, the terms that do not change
+    over an item summed by the caller: with the compiler's bounds
+    checks off the scalar core issues a copy in 10 to 12 instruction
+    bundles (33 to 41 with a layer and a half to multiply out a page,
+    a ring's compare and select, and both addresses of every copy
+    checked; PERF.md §5). The loop stays rolled."""
+    def page(j, carry):
+        pltpu.make_async_copy(pool_ref.at[pool0 + pt_ref[first + j]],
+                              buf.at[buf0 + j], done).start()
+        return carry
+
+    lax.fori_loop(lo, hi, page, 0)
+
+
+def _await_pages(pool_ref, buf, done, n, chunk):
+    """Wait for the ``n`` page copies of an item: a copy adds its
+    bytes to the half's semaphore and a wait takes its descriptor's
+    bytes off, so ONE wait a set bit of ``n`` does, not one a page."""
+    k = 1
+    while k <= chunk:
+        @pl.when(n & k != 0)
+        def _(k=k):
+            pltpu.make_async_copy(pool_ref.at[pl.ds(0, k)],
+                                  buf.at[pl.ds(0, k)], done).wait()
+        k *= 2
+
+
+def _fold_sizes(chunk: int):
+    """The static sizes (pages) a chunk is folded at: up to the quarter
+    that holds its last fetched page."""
+    return sorted({-(-chunk * k // 4) for k in range(1, 5)})
 
 
 def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
-                         buf, sem, m, l, acc, *, scale: float,
+                         buf, sem, turn, m, l, acc, *, scale: float,
                          block: int, n_kv: int, chunk: int,
-                         max_pages: int, d: int, packed: bool = False,
+                         max_pages: int, n_pool: int, d: int,
+                         packed: bool = False,
                          window: Optional[int] = None):
     # li_ref [1], pt_ref [S*MP], n_ref [S]: scalar-prefetch operands in
     # SMEM; q_ref/o_ref [H, D] (this slot's block); pool_ref
-    # [L, P, block*Hkv, 2D], left in HBM; buf [2, chunk, block*Hkv, 2D]
+    # [L*P, block*Hkv, 2D], left in HBM; buf [2*chunk, block*Hkv, 2D],
+    # its halves end to end; turn [1] in SMEM: the half the next item
+    # folded lies in. The walk is ``_latent_decode_kernel``'s: ONE
+    # software pipeline over all (slot, chunk) items of a call
     b = pl.program_id(0)
-    li = li_ref[0]
-    n_pos = n_ref[b]                  # live positions; 0 = inactive
-    n_pages = (n_pos + block - 1) // block
-    if window is not None:
-        # the query, at n_pos - 1, sees positions >= n_pos - window:
-        # the walk starts at the page that holds the first of them,
-        # and reads the slot's row of the page table modulo its
-        # length (a row shorter than the sequence is a RING: page p
-        # of the sequence lies in entry p % max_pages). The remainder
-        # is taken ONCE a slot: a walk has max_pages pages at most,
-        # so it wraps once at most, and a compare does for a page (a
-        # remainder a page cost the walk a fifth of its time, PR 46)
-        lo = jnp.maximum(n_pos - window, 0)
-        first = lo // block
-        n_pages = n_pages - first
-        turn = first % max_pages
-    n_chunks = (n_pages + chunk - 1) // chunk
+    n_slots = pl.num_programs(0)
     h = q_ref.shape[0]
-    rows = chunk * block * n_kv
+    pool0 = li_ref[0] * n_pool
+
+    def span(s):
+        # slot s's walk: (its pages, the sequence's page it starts at,
+        # that page's entry of the slot's row). A walk is held to the
+        # row's length, whatever length it is handed: with the bounds
+        # checks off, the page table is never read past a slot's row
+        pages = (n_ref[s] + block - 1) // block
+        if window is None:
+            return jnp.minimum(pages, max_pages), 0, 0
+        # the query, at n - 1, sees positions >= n - window: the walk
+        # starts at the page that holds the first of them, and reads
+        # the slot's row of the page table modulo its length (a row
+        # shorter than the sequence is a RING: page p of the sequence
+        # lies in entry p % max_pages)
+        first = jnp.maximum(n_ref[s] - window, 0) // block
+        return (jnp.minimum(pages - first, max_pages), first,
+                first % max_pages)
+
+    def issue(s, c, half):
+        # the page copies of item (slot s, chunk c) into buf's half
+        pages, _, entry = span(s)
+        n = jnp.minimum(chunk, pages - c * chunk)
+        done = sem.at[half]
+        buf0 = half * chunk
+        at = entry + c * chunk
+        if window is None:
+            _issue_pages(pt_ref, pool_ref, buf, done, pool0,
+                         s * max_pages + at, buf0, 0, n)
+            return
+        # a walk has max_pages pages at most, so it wraps once at
+        # most: an item is the run up to the row's end and the run
+        # from its start, their bounds computed once (a compare and a
+        # select a page cost the walk as much as the copy's issue)
+        at = jnp.where(at >= max_pages, at - max_pages, at)
+        till = jnp.minimum(n, max_pages - at)
+        row = s * max_pages + at
+        _issue_pages(pt_ref, pool_ref, buf, done, pool0, row, buf0, 0,
+                     till)
+        _issue_pages(pt_ref, pool_ref, buf, done, pool0,
+                     row - max_pages, buf0, till, n)
 
     @pl.when(b == 0)
     def _():
@@ -950,58 +1039,41 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
         # 0 · NaN is NaN: start from zeros, not from whatever bit
         # patterns VMEM holds (afterwards the tail is stale finite KV)
         buf[...] = jnp.zeros_like(buf)
+        turn[0] = 0
+        first = _first_live(n_ref, 0, n_slots)
+
+        @pl.when(first < n_slots)
+        def _():
+            issue(first, 0, 0)
 
     m[...] = jnp.full_like(m, -jnp.inf)
     l[...] = jnp.zeros_like(l)
     acc[...] = jnp.zeros_like(acc)
 
-    def chunk_dma(c, slot, go):
-        # a loop over the chunk's live pages, not ``chunk`` guarded
-        # copies unrolled at three sites: those cost every start of
-        # the gateway most of a second of tracing and lowering
-        def page(j, carry):
-            at = c * chunk + j
-            if window is not None:
-                at = at + turn
-                at = jnp.where(at >= max_pages, at - max_pages, at)
-            pid = pt_ref[b * max_pages + at]
-            go(pltpu.make_async_copy(pool_ref.at[li, pid],
-                                     buf.at[slot, j], sem.at[slot]))
-            return carry
+    n_pos = n_ref[b]                  # live positions; 0 = inactive
+    n_pages, first, _ = span(b)
+    n_chunks = (n_pages + chunk - 1) // chunk
+    # the slot whose first chunk follows this one's last; an inactive
+    # slot walks nothing and looks for nothing
+    after = _first_live(n_ref, jnp.where(n_pos > 0, b + 1, n_slots),
+                        n_slots)
+    half0 = turn[0]
+    contract = (((1,), (1,)), ((), ()))
 
-        lax.fori_loop(0, jnp.minimum(chunk, n_pages - c * chunk), page,
-                      0)
-
-    @pl.when(n_chunks > 0)
-    def _():
-        chunk_dma(0, 0, lambda cp: cp.start())
-
-    # row r of a chunk is kv head r % Hkv at the chunk's position
-    # r // Hkv; query head i reads kv head i // (H // Hkv)
-    row = lax.broadcasted_iota(jnp.int32, (h, rows), 1)
-    own = (lax.broadcasted_iota(jnp.int32, (h, rows), 0) // (h // n_kv)
-           == row % n_kv)
-    rel = row // n_kv
-
-    def body(c, carry):
-        slot = c % 2
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            chunk_dma(c + 1, 1 - slot, lambda cp: cp.start())
-
-        chunk_dma(c, slot, lambda cp: cp.wait())
-        kv = buf[slot].reshape(rows, 2 * d)
+    def fold(kv, base):
+        # kv [R, 2D]: the chunk's first R rows, the first of them at
+        # position ``base``. Row r is kv head r % Hkv at the chunk's
+        # position r // Hkv; query head i reads kv head i // (H // Hkv)
+        rows = kv.shape[0]
         s = lax.dot_general(q_ref[...], kv if packed else kv[:, :d],
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if window is None:
-            live = jnp.logical_and(own,
-                                   rel < n_pos - c * (chunk * block))
-        else:       # the first page's head and the last one's tail
-            base = (first + c * chunk) * block
-            live = jnp.logical_and(
-                own, jnp.logical_and(rel < n_pos - base, rel >= lo - base))
+                            contract, preferred_element_type=jnp.float32)
+        row = lax.broadcasted_iota(jnp.int32, (h, rows), 1)
+        own = (lax.broadcasted_iota(jnp.int32, (h, rows), 0)
+               // (h // n_kv) == row % n_kv)
+        rel = row // n_kv
+        live = jnp.logical_and(own, rel < n_pos - base)
+        if window is not None:  # the head of the walk's first page
+            live = jnp.logical_and(live, rel >= n_pos - window - base)
         # every chunk walked holds a live position of every kv head,
         # so each row's running maximum is finite from the first on
         s = jnp.where(live, s * scale, -jnp.inf)
@@ -1016,9 +1088,32 @@ def _paged_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
             p.astype(kv.dtype), kv if packed else kv[:, d:],
             preferred_element_type=jnp.float32)
         m[...] = jnp.broadcast_to(m_new, m.shape)
+
+    # the matmuls' shapes are static, so each size is a branch
+    sizes = _fold_sizes(chunk)
+
+    def body(c, carry):
+        half = (half0 + c) % 2
+        more = c + 1 < n_chunks
+        s_next = jnp.where(more, b, after)
+
+        @pl.when(s_next < n_slots)
+        def _():
+            issue(s_next, jnp.where(more, c + 1, 0), 1 - half)
+
+        pages = jnp.minimum(chunk, n_pages - c * chunk)
+        _await_pages(pool_ref, buf, sem.at[half], pages, chunk)
+        base = (first + c * chunk) * block
+        buf0 = pl.multiple_of(half * chunk, chunk)
+        for lo, hi in zip([0] + sizes, sizes):
+            @pl.when((lo < pages) & (pages <= hi))
+            def _(hi=hi):
+                fold(buf[pl.ds(buf0, hi)].reshape(hi * block * n_kv,
+                                                  2 * d), base)
         return carry
 
     lax.fori_loop(0, n_chunks, body, 0)
+    turn[0] = (half0 + n_chunks) % 2
     o_ref[...] = (acc[...] / jnp.maximum(l[:, :1], 1e-30)
                   ).astype(o_ref.dtype)
 
@@ -1050,7 +1145,7 @@ def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=1.0 / (d ** 0.5),
                           block=block, n_kv=n_kv, chunk=chunk,
-                          max_pages=mp, d=d, packed=packed,
+                          max_pages=mp, n_pool=n_p, d=d, packed=packed,
                           **({} if window is None
                              else {"window": window})),
         out_shape=jax.ShapeDtypeStruct((s_, h, w), q.dtype),
@@ -1062,23 +1157,31 @@ def _paged_decode_call(q, pool, li, pt, n_live, pages_per_chunk,
             out_specs=pl.BlockSpec((None, h, w),
                                    lambda b, *_: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, chunk, block * n_kv, 2 * d), pool.dtype),
+                pltpu.VMEM((2 * chunk, block * n_kv, 2 * d), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((h, 128), jnp.float32),
                 pltpu.VMEM((h, 128), jnp.float32),
                 pltpu.VMEM((h, w), jnp.float32),
             ]),
-        # the zeroed buffers carry from slot to slot: the grid is a
-        # sequence, not a parallel map
+        # the buffer and the copies in flight carry from slot to slot:
+        # the grid is a sequence, not a parallel map. The compiler's
+        # check of every copy's two addresses is two thirds of the
+        # scalar core's work a page: a buffer row is the loop's
+        # counter, and a page number is the pager's own, held under
+        # the pool's page count by the scheduler where it builds the
+        # feed (``DecodeScheduler._check_feed``)
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         interpret=interpret,
         name="paged_decode_attention",
     )(li.reshape(1).astype(jnp.int32), pt.reshape(-1).astype(jnp.int32),
       n_live.astype(jnp.int32), q,
-      # a page as the matrix it is: with whole sublane tiles a
-      # position (_use_paged_kernel) this is a bitcast, not a copy
-      pool.reshape(n_l, n_p, block * n_kv, 2 * d))
+      # the layers' pages end to end, a page as the matrix it is: with
+      # whole sublane tiles a position (_use_paged_kernel) this is a
+      # bitcast, not a copy
+      pool.reshape(n_l * n_p, block * n_kv, 2 * d))
     return out[..., d:] if packed else out
 
 
@@ -1187,7 +1290,7 @@ def paged_decode_attention(q, pool, li, pt, n_live,
     cache positions per slot, the one just written included, 0 for an
     inactive slot. Returns [S, H, D]; an inactive slot's rows are
     zeros. Grouped-query attention shares each fetched page among the
-    group's query heads. ``pages_per_chunk`` (default: 2,048 rows'
+    group's query heads. ``pages_per_chunk`` (default: 4,096 rows'
     worth) is the loop's unit; every size comes from the operands'
     shapes. Shapes the kernel does not take (:func:`_use_paged_kernel`)
     run :func:`_reference_paged_attention`. ``window``: the query (at
@@ -1238,7 +1341,7 @@ def paged_decode_attention(q, pool, li, pt, n_live,
 # minor dimension by 128 lanes, so a 576-wide row takes 640 in HBM
 # either way, and Mosaic slices whole tiles only).
 #
-# Unlike ``_paged_decode_kernel`` the walk is ONE software pipeline
+# As in ``_paged_decode_kernel`` the walk is ONE software pipeline
 # over all (slot, chunk) items of a call, in slot order: while an
 # item's rows are multiplied, the NEXT item's pages are in flight into
 # the buffer's other half, be that the same slot's next chunk or the
@@ -1265,11 +1368,7 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
     # SMEM; q_ref [H, W], o_ref [H, kv_rank] (this slot's blocks);
     # pool_ref [L*P, block, W], left in HBM; buf [2*chunk, block, W],
     # its halves end to end; turn [1] in SMEM: the half the next item
-    # multiplied lies in. Pool and buffer are indexed by ONE page
-    # number each, its terms that do not change over an item summed
-    # once in front of the loop: the scalar core issues a copy in 12
-    # instruction bundles (37 with a layer and a half to multiply out
-    # a page, and the bounds checks `_latent_decode_call` turns off)
+    # multiplied lies in (``_issue_pages`` has what a copy costs)
     b = pl.program_id(0)
     n_slots = pl.num_programs(0)
     n_pos = n_ref[b]                  # live positions; 0 = inactive
@@ -1278,42 +1377,15 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
     width = q_ref.shape[1]
     pool0 = li_ref[0] * n_pool
 
-    def live_from(j):
-        # the first live slot at or after j; S where there is none
-        return lax.while_loop(
-            lambda j: (j < n_slots)
-            & (n_ref[jnp.minimum(j, n_slots - 1)] == 0),
-            lambda j: j + 1, j)
-
     def n_pages(s, c):
         return jnp.minimum(chunk, (n_ref[s] + block - 1) // block
                            - c * chunk)
 
     def issue(s, c, half):
         # the page copies of item (slot s, chunk c) into buf's half
-        first = s * max_pages + c * chunk
-        buf0 = half * chunk
-        done = sem.at[half]
-
-        def page(j, carry):
-            pltpu.make_async_copy(pool_ref.at[pool0 + pt_ref[first + j]],
-                                  buf.at[buf0 + j], done).start()
-            return carry
-
-        lax.fori_loop(0, n_pages(s, c), page, 0)
-
-    def await_(n, half):
-        # a copy adds its bytes to the half's semaphore and a wait
-        # takes its descriptor's bytes off: one wait a set bit of the
-        # item's page count ``n``, not one a page
-        k = 1
-        while k <= chunk:
-            @pl.when(n & k != 0)
-            def _(k=k):
-                pltpu.make_async_copy(pool_ref.at[pl.ds(0, k)],
-                                      buf.at[pl.ds(0, k)],
-                                      sem.at[half]).wait()
-            k *= 2
+        _issue_pages(pt_ref, pool_ref, buf, sem.at[half], pool0,
+                     s * max_pages + c * chunk, half * chunk, 0,
+                     n_pages(s, c))
 
     @pl.when(b == 0)
     def _():
@@ -1321,7 +1393,7 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
         # 0 · NaN is NaN (as in ``_paged_decode_kernel``)
         buf[...] = jnp.zeros_like(buf)
         turn[0] = 0
-        first = live_from(0)
+        first = _first_live(n_ref, 0, n_slots)
 
         @pl.when(first < n_slots)
         def _():
@@ -1334,7 +1406,8 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
     contract = (((1,), (1,)), ((), ()))
     # the slot whose first chunk follows this one's last; an inactive
     # slot walks nothing and looks for nothing
-    after = live_from(jnp.where(n_pos > 0, b + 1, n_slots))
+    after = _first_live(n_ref, jnp.where(n_pos > 0, b + 1, n_slots),
+                        n_slots)
     half0 = turn[0]
 
     def fold(kv, left):
@@ -1359,7 +1432,7 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
 
     # a chunk is multiplied up to the quarter that holds its last live
     # row: the matmuls' shapes are static, so each size is a branch
-    sizes = sorted({-(-chunk * k // 4) for k in range(1, 5)})
+    sizes = _fold_sizes(chunk)
 
     def body(c, carry):
         half = (half0 + c) % 2
@@ -1371,7 +1444,7 @@ def _latent_decode_kernel(li_ref, pt_ref, n_ref, q_ref, pool_ref, o_ref,
             issue(s_next, jnp.where(more, c + 1, 0), 1 - half)
 
         pages = n_pages(b, c)
-        await_(pages, half)
+        _await_pages(pool_ref, buf, sem.at[half], pages, chunk)
         left = n_pos - c * rows
         buf0 = pl.multiple_of(half * chunk, chunk)
         for lo, hi in zip([0] + sizes, sizes):
@@ -1421,8 +1494,10 @@ def _latent_decode_call(q, pool, li, pt, n_live, scale, kv_rank,
         # the buffer and the copies in flight carry from slot to slot:
         # the grid is a sequence, not a parallel map. The compiler's
         # check of every copy's two addresses is two thirds of the
-        # scalar core's work a page (22% of the kernel's time): a page
-        # number is the pager's own, a buffer row the loop's counter
+        # scalar core's work a page (22% of the kernel's time): a
+        # buffer row is the loop's counter, and a page number is the
+        # pager's own, held under the pool's page count by the
+        # scheduler (``DecodeScheduler._check_feed``)
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             disable_bounds_checks=True),

@@ -112,7 +112,8 @@ import numpy as np
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.ops.pallas_kernels import latent_chunk_pages
-from deeplearning4j_tpu.serving.kv_pager import KVPager
+from deeplearning4j_tpu.serving.kv_pager import (KVPager, PageTableError,
+                                                 ring_pages)
 from deeplearning4j_tpu.zoo.gpt import prompt_bucket
 
 #: every ``_build_*`` jitted entry point in this module must have an
@@ -918,6 +919,7 @@ class DecodeScheduler:
         if self._feed_dirty or self._dev_feed is None:
             assert self._inflight is None, \
                 "feed rebuilt from a mirror one step behind the device"
+            self._check_feed()
             # copies: on a CPU backend ``jnp.asarray`` may ALIAS an
             # aligned host array, and the mirror is written (a
             # retirement zeroes its page-table row, ``_collect`` moves
@@ -940,6 +942,48 @@ class DecodeScheduler:
             self._dev_feed["active"] = jnp.asarray(active)
             self._fed_act = act
         return self._dev_feed
+
+    def _check_feed(self) -> None:
+        """The host's guard of what the step is about to be handed,
+        once a rebuilt feed (an admission, not a step) and before any
+        device call: the page walks' kernels issue their copies with
+        the compiler's bounds checks off (``ops/pallas_kernels.py``: a
+        check of both addresses was two thirds of the scalar core's
+        work a page), so a page number has to be the pager's own.
+        Every entry of the page table lies under the pool's page
+        count (KV, state and latent pages alike; the trash page 0 is
+        one of them); a slot's length, with the budget it has left,
+        does not reach past what its row of the table can serve; a
+        window layer's ring, which the step cuts from the window
+        pool's shape (so its entries are the pool's own), is long
+        enough that a walk of ``window`` positions wraps once at most.
+        There is no switch. Raises :class:`kv_pager.PageTableError`."""
+        pt, pager = self._page_table, self.pager
+        bad = np.argwhere((pt < 0) | (pt >= pager.n_pages))
+        if bad.size:
+            s, e = map(int, bad[0])
+            raise PageTableError(
+                f"slot {s}'s page-table entry {e} is {int(pt[s, e])}: "
+                f"the pool has pages 0..{pager.n_pages - 1}")
+        if pager.walks_kv or self.latent is not None:
+            room = pt.shape[1] * self.block
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                end = int(self._lengths[i]) + max(slot.remaining, 0)
+                if end > room:
+                    raise PageTableError(
+                        f"slot {i} reaches position {end}: its row of "
+                        f"{pt.shape[1]} pages serves {room}")
+        if self.windowed is not None:
+            held = pager.pool[1].shape[1]
+            need = ring_pages(self.windowed.window, self.block)
+            if (held - 1) // self.max_slots < need:
+                raise PageTableError(
+                    f"the window pool's {held} pages give each of "
+                    f"{self.max_slots} slots a ring of "
+                    f"{(held - 1) // self.max_slots}: a window of "
+                    f"{self.windowed.window} needs {need}")
 
     def step(self) -> int:
         """One continuous-batching iteration, one decode step in

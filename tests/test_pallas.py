@@ -328,22 +328,54 @@ def _paged_case(rng, dtype, h, n_kv, d, block, mp, n_live, layers=2):
 # block, block + 1, an inactive slot, a slot at max_context, ragged
 _PAGED_N_LIVE = (1, 15, 16, 17, 0, 96, 33, 70)
 
+# the walk is one pipeline over every (slot, chunk) item of a call (PR
+# 48, as the latent kernel's): what a slot's edge, an item's page
+# count and the fold's size can get wrong, at 8 pages of 16 a slot and
+# chunks of 2, 4 and 8 pages
+_PAGED_WALKS = {
+    "first-inactive": (0, 40, 128, 5, 64, 33, 1, 17),
+    "last-inactive": (40, 128, 5, 64, 33, 1, 17, 0),
+    "inactive-between": (33, 0, 0, 64, 0, 1, 0, 128),
+    "none-live": (0,) * 8,          # zeros, no copy issued or awaited
+    "one-live": (0, 0, 0, 0, 0, 77, 0, 0),
+    "one-row": (1,) * 8,
+    "one-page": (16,) * 8,
+    "whole-chunks": (64, 128, 64, 64, 128, 128, 64, 128),
+    "chunk-plus-one-row": (65, 33, 65, 17, 65, 1, 129 - 16, 65),
+    # an item's pages are awaited once a set bit of their count
+    "page-counts-of-one-bit": (16, 32, 64, 128, 10, 20, 50, 120),
+    "page-counts-of-all-bits": (48, 112, 40, 100, 33, 97, 48, 112),
+    # a short slot folds a quarter of the buffer a long one filled:
+    # the rest is stale, finite, and masked
+    "short-after-long": (128, 3, 128, 1, 100, 17, 128, 2),
+}
 
-@pytest.mark.parametrize("pages_per_chunk", [2, None])
-@pytest.mark.parametrize("h,n_kv", [(32, 8), (8, 8)],
-                         ids=["gqa4to1", "mha1to1"])
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 3e-2)],
-                         ids=["float32", "bfloat16"])
+_PAGED_CASES = [
+    pytest.param(dtype, tol, h, n_kv, chunk, 6, _PAGED_N_LIVE,
+                 id=f"{chunk}-{heads}-{dt}")
+    for dtype, tol, dt in ((jnp.float32, 2e-5, "float32"),
+                           (jnp.bfloat16, 3e-2, "bfloat16"))
+    for h, n_kv, heads in ((32, 8, "gqa4to1"), (8, 8, "mha1to1"))
+    for chunk in (2, None)
+] + [
+    pytest.param(jnp.float32, 2e-5, 32, 8, chunk, 8, n_live,
+                 id=f"{chunk}-{name}")
+    for name, n_live in _PAGED_WALKS.items() for chunk in (2, 4, None)
+]
+
+
+@pytest.mark.parametrize("dtype,tol,h,n_kv,pages_per_chunk,mp,n_live",
+                         _PAGED_CASES)
 def test_paged_decode_matches_reference(monkeypatch, rng, dtype, tol,
-                                        h, n_kv, pages_per_chunk):
+                                        h, n_kv, pages_per_chunk, mp,
+                                        n_live):
     """The kernel (interpret mode) against the registered fallback,
-    layer 1 of 2, block 16, 6 pages a slot. (The int8 pool keeps the
-    fallback on every platform: ``_use_paged_kernel``.)"""
+    layer 1 of 2, block 16, ``mp`` pages a slot. (The int8 pool keeps
+    the fallback on every platform: ``_use_paged_kernel``.)"""
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
-    block, mp = 16, 6
+    block = 16
     q, pool, pt, n_live = _paged_case(rng, dtype, h, n_kv, 128, block,
-                                      mp, _PAGED_N_LIVE)
+                                      mp, n_live)
     assert pk._use_paged_kernel(q, (pool,))
     out = np.asarray(pk.paged_decode_attention(
         q, (pool,), 1, pt, n_live, pages_per_chunk=pages_per_chunk),
@@ -356,8 +388,40 @@ def test_paged_decode_matches_reference(monkeypatch, rng, dtype, tol,
         np.float32)
     live = np.asarray(n_live) > 0
     assert not np.isnan(out).any()          # trash was never read
-    assert np.abs(out[live] - ref[live]).max() < tol
+    assert np.abs(out[live] - ref[live]).max(initial=0.0) < tol
     assert (out[~live] == 0).all()          # inactive: zeros, no walk
+
+
+@pytest.mark.parametrize("window", [None, 48], ids=["plain", "window"])
+def test_paged_decode_never_reads_the_table_past_a_slots_row(
+        monkeypatch, rng, window):
+    """The copies are issued with the compiler's bounds checks off, so
+    the kernel itself holds a walk to the row's length: a length past
+    what the row serves reads the row's pages and no entry of the
+    next slot's row (whose pages would change the answer)."""
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    q, pool, pt, n_live = _paged_case(rng, jnp.float32, 8, 8, 128, 16, 4,
+                                      (64, 64, 64))
+    extra = {} if window is None else {"window": window}
+    run = lambda n: np.asarray(pk.paged_decode_attention(
+        q, (pool,), 0, pt, jnp.asarray(n, jnp.int32), pages_per_chunk=2,
+        **extra))
+    if window is None:
+        want = run((64, 64, 64))
+        got = run((500, 64, 9000))
+        assert np.abs(got - want).max() < 2e-5
+    else:
+        # a window of 48 over pages of 16 needs a ring of 4: a row of 4
+        # pages read as a ring, at lengths that lap it
+        got = run((500, 64, 9000))
+        assert not np.isnan(got).any()
+        # ... and slot 0 reads ITS pages alone: change slot 1's
+        other = pool.at[:, pt[1]].set(7.0)
+        again = np.asarray(pk.paged_decode_attention(
+            q, (other,), 0, pt, jnp.asarray((500, 64, 9000), jnp.int32),
+            pages_per_chunk=2, **extra))
+        assert (again[0] == got[0]).all() and (again[2] == got[2]).all()
+        assert np.abs(again[1] - got[1]).max() > 1e-3
 
 
 # ---------------------------------------------------------------------------

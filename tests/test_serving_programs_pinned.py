@@ -1,5 +1,5 @@
-"""The serving programs of the decoders the benchmark held before the
-windowed one, pinned by the sha256 of their lowered text.
+"""The serving programs of the five decoders the benchmark holds,
+pinned by the sha256 of their lowered text.
 
 A PR that adds a kind of layer, page or expert rule beside these must
 leave their step and prefill (or chunk) programs as they were: that is
@@ -19,6 +19,18 @@ What the toy widths cannot show: ``ops.moe.layer`` routes a bucket of
 more than ``ROUTE_BLOCK`` (4,096) rows a block at a time, and no toy
 bucket is that long. Neither is the DeepSeek cell's longest (4,096,
 not above it), so that branch is not taken in any cell pinned here.
+
+Nor do they hold either page walk: a toy head is 16 to 32 wide and a
+toy latent 32, under the lines ``_use_paged_kernel`` and
+``_use_latent_kernel`` draw, so every toy step attends through the
+plain fallbacks in both modes (PR 48 rewrote ``_paged_decode_kernel``
+and no hash above moved). The two kernels are therefore pinned ALONE
+(``KERNELS_ALONE``), lowered in interpret mode at the smallest shapes
+their lines take: the latent kernel's text is PR 40's (PR 48 moved its
+issue, wait and search loops into helpers it shares with the KV walk,
+and its text did not change), the KV walk's is PR 48's. The
+SmallThinker cell's programs (PR 46, with PR 47's expert kernel not in
+them: toy experts are not lane-aligned) joined ``PINNED`` in PR 48.
 """
 import hashlib
 import importlib.util
@@ -55,6 +67,22 @@ PINNED = {
         "plain": {"step": "ccc14242d5877cf1", "chunk": "9ba93a13f711d334"},
         "kernels": {"step": "8ae93b0aba4ee239",
                     "chunk": "ff401f5cc2fdf0cb"}},
+    "smallthinker21b.longmix-saturated": {
+        "plain": {"step": "44b7c200447d1595", "admit16": "569daaa68cfb95fa",
+                  "admit64": "2a49ae68fab2c748"},
+        "kernels": {"step": "28a8d40a4afd12b5",
+                    "admit16": "cefdeac01637db08",
+                    "admit64": "95cac0be5747dad7"}},
+}
+
+#: kernel form -> sha256[:16] of its jitted call's lowered text, in
+#: interpret mode, four slots
+KERNELS_ALONE = {
+    "latent": "69aa35110c121500",
+    "latent_bf16_chunk4": "e5aebe3f91eae05f",
+    "paged": "eac4a722f7e6d094",
+    "paged_window_folded": "0e97385756935728",
+    "paged_heads_of_64": "72de30ede60e435f",
 }
 
 
@@ -108,3 +136,31 @@ def test_the_earlier_decoders_programs_lower_to_the_pinned_text(
     if mode == "kernels":
         monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     assert _programs(cell) == PINNED[cell][mode]
+
+
+def _kernel_alone(form: str):
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+    sds, i32, f32, s_, mp = jax.ShapeDtypeStruct, jnp.int32, jnp.float32, 4, 6
+    feed = lambda pages: (sds((), i32), sds((s_, pages), i32),
+                          sds((s_,), i32))
+    if form.startswith("latent"):
+        dt, chunk = ((jnp.bfloat16, 4) if form.endswith("chunk4")
+                     else (f32, 2))
+        return pk._latent_decode_call.lower(
+            sds((s_, 16, 256), dt), sds((2, 1 + s_ * mp, 16, 256), dt),
+            *feed(mp), scale=0.11, kv_rank=128, pages_per_chunk=chunk,
+            interpret=True)
+    if form == "paged_window_folded":       # a ring of 3, 4 kv heads
+        return pk._paged_decode_call.lower(
+            sds((s_, 8, 128), f32), sds((2, 1 + s_ * 3, 64, 256), f32),
+            *feed(3), pages_per_chunk=2, interpret=True, window=32,
+            n_kv=4)
+    d = 64 if form == "paged_heads_of_64" else 128
+    return pk._paged_decode_call.lower(
+        sds((s_, 32, d), f32), sds((2, 1 + s_ * mp, 16, 8, 2 * d), f32),
+        *feed(mp), pages_per_chunk=2, interpret=True)
+
+
+@pytest.mark.parametrize("form", sorted(KERNELS_ALONE))
+def test_the_page_walks_lower_to_the_pinned_text(form):
+    assert _sha(_kernel_alone(form)) == KERNELS_ALONE[form]

@@ -260,29 +260,44 @@ def test_shapes_the_ssm_kernel_does_not_take_run_the_fallback(
     assert np.isfinite(np.asarray(y)).all() and (np.asarray(y)[1] == 0).all()
 
 
-@pytest.mark.parametrize("pages_per_chunk", [2, None])
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 3e-2)],
-                         ids=["float32", "bfloat16"])
+_PACKED_WALKS = ("first-inactive", "inactive-between", "none-live",
+                 "chunk-plus-one-row", "page-counts-of-all-bits",
+                 "short-after-long")
+
+
+_PACKED_CASES = [
+    pytest.param(dtype, tol, chunk, None, id=f"{chunk}-{dt}")
+    for dtype, tol, dt in ((jnp.float32, 2e-5, "float32"),
+                           (jnp.bfloat16, 3e-2, "bfloat16"))
+    for chunk in (2, None)
+] + [pytest.param(jnp.float32, 2e-5, chunk, walk, id=f"{chunk}-{walk}")
+     for walk in _PACKED_WALKS for chunk in (2, None)]
+
+
+@pytest.mark.parametrize("dtype,tol,pages_per_chunk,walk", _PACKED_CASES)
 def test_paged_decode_takes_heads_of_64(monkeypatch, rng, dtype, tol,
-                                        pages_per_chunk):
+                                        pages_per_chunk, walk):
     """K and V as the two halves of ONE 128-lane row: the packed form
-    of the kernel (interpret mode) against the registered fallback."""
-    from test_pallas import _PAGED_N_LIVE, _paged_case
+    of the kernel (interpret mode) against the registered fallback,
+    over the ragged batch and over the walks that try the pipeline's
+    edges (``test_pallas._PAGED_WALKS``)."""
+    from test_pallas import _PAGED_N_LIVE, _PAGED_WALKS, _paged_case
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
-    q, pool, pt, n_live = _paged_case(rng, dtype, 32, 8, 64, 16, 6,
-                                      _PAGED_N_LIVE)
+    lengths, mp = ((_PAGED_N_LIVE, 6) if walk is None
+                   else (_PAGED_WALKS[walk], 8))
+    q, pool, pt, n_live = _paged_case(rng, dtype, 32, 8, 64, 16, mp,
+                                      lengths)
     assert pk._use_paged_kernel(q, (pool,))
     out = np.asarray(pk.paged_decode_attention(
         q, (pool,), 1, pt, n_live, pages_per_chunk=pages_per_chunk),
         np.float32)
-    assert out.shape == (len(_PAGED_N_LIVE), 32, 64)
+    assert out.shape == (len(lengths), 32, 64)
     ref = np.asarray(pk._reference_paged_attention(
         q[:, None], (jnp.nan_to_num(pool),), 1, pt,
         (n_live - 1)[:, None])[:, 0], np.float32)
     live = np.asarray(n_live) > 0
     assert not np.isnan(out).any()          # trash was never read
-    assert np.abs(out[live] - ref[live]).max() < tol
+    assert np.abs(out[live] - ref[live]).max(initial=0.0) < tol
     assert (out[~live] == 0).all()
     # heads of 32 fill neither a tile nor half of one
     assert not pk._use_paged_kernel(q[..., :32], (pool,))

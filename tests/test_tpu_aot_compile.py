@@ -781,6 +781,39 @@ def test_windowed_decode_step_compiles_for_v5e_in_place(chip, monkeypatch):
     assert mem.temp_size_in_bytes < (64 << 20), mem
 
 
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_paged_decode_compiles_alone_for_v5e_at_smallthinkers_shapes(
+        chip, kind):
+    """The page walk ALONE at ``smallthinker21b.longmix-saturated``'s
+    shapes: 28 query heads over a FOLDED pool of 32 KB pages (16
+    positions x 4 KV heads x 256 lanes), the six window layers' rings
+    of 257 pages read with ``window=4096`` and the two full layers'
+    rows of 608 without: one custom call, the pool a bitcast of the
+    parameter (the layers' pages end to end), and under 1 MiB of
+    temporaries (the 4 MB buffer is the kernel's own scratch)."""
+    layers, pages, extra = {"window": (6, 257, {"window": 4096}),
+                            "full": (2, 608, {})}[kind]
+    slots = 48
+    pool = jax.ShapeDtypeStruct((layers, 1 + slots * pages, 16 * 4, 256),
+                                BF16, sharding=chip)
+
+    def attend(q, kv, pt, n_live):
+        assert pallas_kernels._use_paged_kernel(q, (kv,))
+        return pallas_kernels.paged_decode_attention(
+            q, (kv,), 1, pt, n_live, n_kv=4, **extra)
+
+    compiled = jax.jit(attend).lower(
+        jax.ShapeDtypeStruct((slots, 28, 128), BF16, sharding=chip), pool,
+        jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip),
+    ).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode_attention" in hlo
+    assert _pool_ops(hlo, pool) == {"parameter"}, _pool_ops(hlo, pool)
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_windowed_bucket_prefill_compiles_for_v5e(chip, monkeypatch):
     """The largest bucket (8,192 rows): the windowed flash kernel in
     the six window layers, the unwindowed one in the two full layers,

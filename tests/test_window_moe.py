@@ -179,48 +179,72 @@ def _ring_case(rng, lengths, n_kv=4, d=128, block=16, window=32,
 #: lengths at the window's edge (32), one short, one past, inside the
 #: first page, an inactive slot, and two that have wrapped the ring
 _RING_N = (32, 31, 33, 5, 0, 100, 81)
+#: a window of 64 over pages of 16 is a ring of 5; read in items of 2
+#: (or 4) pages a walk of 5 pages wraps, by the entry it starts at (0
+#: to 4, the lengths in that order), nowhere, at an item's edge, inside
+#: an item, at an edge, inside the first item; then a second lap, the
+#: window's edge, short slots, an inactive slot first and last
+_RING_WRAPS = (0, 70, 86, 102, 118, 134, 150, 229, 64, 63, 65, 5, 1, 0)
+
+_RING_CASES = [
+    pytest.param(jnp.float32, 2e-5, 32, _RING_N, None, id="float32"),
+    pytest.param(jnp.bfloat16, 3e-2, 32, _RING_N, None, id="bfloat16"),
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 2,
+                 id="ring-of-5-items-of-2"),
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 4,
+                 id="ring-of-5-items-of-4"),
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, None,
+                 id="ring-of-5-one-item"),
+    pytest.param(jnp.float32, 2e-5, 64, (0,) * 4, 2, id="none-live"),
+]
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 3e-2)],
-                         ids=["float32", "bfloat16"])
-def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol):
+@pytest.mark.parametrize("dtype,tol,window,lengths,pages_per_chunk",
+                         _RING_CASES)
+def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol, window,
+                                         lengths, pages_per_chunk):
     """The kernel (interpret mode) over a folded ring pool of 4 KV
     heads through ``PagedWindowKV.read`` (the slot's ring as its row
-    of the page table, read modulo its length by the kernel), against
-    a literal softmax over the last 32 positions: a stale page, the
-    head of the first page and the tail of the last are never read
-    (NaN would poison the output)."""
+    of the page table, read modulo its length by the kernel; with
+    ``pages_per_chunk`` the same call made with items of that many
+    pages), against a literal softmax over the last ``window``
+    positions: a stale page, the head of the first page and the tail
+    of the last are never read (NaN would poison the output)."""
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     rng = np.random.default_rng(3)
     n_kv, d, block, h = 4, 128, 16, 8
-    pool, kv, ring = _ring_case(rng, _RING_N)
-    s_ = len(_RING_N)
+    pool, kv, ring = _ring_case(rng, lengths, window=window)
+    s_ = len(lengths)
     q = jnp.asarray(rng.standard_normal((s_, h, d)), dtype)
-    n = np.asarray(_RING_N)
+    n = np.asarray(lengths)
 
     class Dims:
-        windowed = di.WindowSpec(32, ["window", "window"])
+        windowed = di.WindowSpec(window, ["window", "window"])
     cache = kv_pager.PagedWindowKV(
         Dims, (jnp.asarray(pool, dtype),), jnp.asarray(n - 1)[:, None],
         jnp.asarray(n > 0)[:, None], (0, 1))
-    assert cache.ring == ring == 3
+    assert cache.ring == ring == window // block + 1
     assert pk._use_paged_kernel(q, cache.pool)
-    out = np.asarray(cache.read(q, cache.pool, 1, (n > 0)[:, None], n_kv),
-                     np.float32)
+    if pages_per_chunk is None:
+        out = cache.read(q, cache.pool, 1, (n > 0)[:, None], n_kv)
+    else:
+        out = pk.paged_decode_attention(
+            q, cache.pool, 1, cache.pt, jnp.asarray(n, jnp.int32),
+            window=window, n_kv=n_kv, pages_per_chunk=pages_per_chunk)
+    out = np.asarray(out, np.float32)
     assert not np.isnan(out).any()
     # the fallback reads a ring the same way
     ref = np.asarray(pk._reference_paged_attention(
         q[:, None], (jnp.asarray(np.nan_to_num(pool), dtype),), 1,
-        cache.pt, jnp.asarray(n - 1)[:, None], window=32,
+        cache.pt, jnp.asarray(n - 1)[:, None], window=window,
         n_kv=n_kv)[:, 0], np.float32)
-    assert np.abs(out[n > 0] - ref[n > 0]).max() < tol
+    assert np.abs(out[n > 0] - ref[n > 0]).max(initial=0.0) < tol
     g = h // n_kv
-    for s, length in enumerate(_RING_N):
+    for s, length in enumerate(lengths):
         if not length:
             assert (out[s] == 0).all()
             continue
-        lo = max(0, length - 32)
+        lo = max(0, length - window)
         keys = np.asarray(jnp.asarray(kv[1, s, lo:length], dtype),
                           np.float64)
         for hh in range(h):
@@ -230,12 +254,19 @@ def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol):
             assert np.abs(out[s, hh] - want).max() < tol, (s, hh)
 
 
-@pytest.mark.parametrize("n_live", [(32, 31, 33, 0, 96, 70)])
-def test_paged_decode_window_over_a_plain_page_table(monkeypatch, n_live):
+@pytest.mark.parametrize("folded", [False, True], ids=["5d", "folded"])
+@pytest.mark.parametrize("n_live", [(32, 31, 33, 0, 96, 70),
+                                    (0, 96, 1, 17, 96, 0),
+                                    (0, 0, 0, 0, 81, 0)],
+                         ids=["ragged", "inactive-first-and-last",
+                              "one-live"])
+def test_paged_decode_window_over_a_plain_page_table(monkeypatch, n_live,
+                                                     folded):
     """``paged_decode_attention(window=)`` over an ordinary page table
-    (every position kept): the walk starts at the first page that holds
-    a visible key, and pages before it, set to NaN here, are not
-    read; the fallback masks the same positions."""
+    (every position kept), the pool as ``[L, P, block, Hkv, 2D]`` and
+    folded to ``[L, P, block * Hkv, 2D]``: the walk starts at the
+    first page that holds a visible key, and pages before it, set to
+    NaN here, are not read; the fallback masks the same positions."""
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     rng = np.random.default_rng(5)
     block, mp, n_kv, d, h = 16, 6, 8, 128, 8
@@ -248,21 +279,27 @@ def test_paged_decode_window_over_a_plain_page_table(monkeypatch, n_live):
         poisoned[:, pt[s, :max(n - 32, 0) // block]] = np.nan
     q = jnp.asarray(rng.standard_normal((s_, h, d)), jnp.float32)
     n = jnp.asarray(n_live, jnp.int32)
+    as_stored = ((lambda a: a.reshape(1, -1, block * n_kv, 2 * d))
+                 if folded else (lambda a: a))
+    extra = {"n_kv": n_kv} if folded else {}
     got = np.asarray(pk.paged_decode_attention(
-        q, (jnp.asarray(poisoned, jnp.float32),), 0, jnp.asarray(pt), n,
-        window=32, pages_per_chunk=2))
+        q, (jnp.asarray(as_stored(poisoned), jnp.float32),), 0,
+        jnp.asarray(pt), n, window=32, pages_per_chunk=2, **extra))
     assert not np.isnan(got).any()
     want = np.asarray(pk._reference_paged_attention(
         q[:, None], (jnp.asarray(np.nan_to_num(pool), jnp.float32),), 0,
         jnp.asarray(pt), (n - 1)[:, None], window=32)[:, 0])
     live = np.asarray(n_live) > 0
     assert np.abs(got[live] - want[live]).max() < 2e-5
+    assert (got[~live] == 0).all()
     whole = np.asarray(pk._reference_paged_attention(
         q[:, None], (jnp.asarray(np.nan_to_num(pool), jnp.float32),), 0,
         jnp.asarray(pt), (n - 1)[:, None])[:, 0])
     past = np.asarray(n_live) > 32
     assert np.abs(whole[past] - want[past]).max() > 1e-3
-    assert np.abs(whole[live & ~past] - want[live & ~past]).max() < 2e-5
+    if (live & ~past).any():
+        assert np.abs(whole[live & ~past]
+                      - want[live & ~past]).max() < 2e-5
 
 
 # -- the expert layer's second rule ------------------------------------------

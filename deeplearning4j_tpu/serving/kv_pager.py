@@ -34,117 +34,26 @@ carries as its donated pool argument):
   programs' argument bytes and the chip's, PR 46); but only with
   ``Hkv`` a multiple of 8 is a page the ``[block * Hkv, 2D]`` matrix
   the decode kernel reads, byte for byte: see the FOLDED layout
-  below. (The previous
-  layout, ``[L, P, Hkv, 2D, block]``, was the dense cache's: the
-  minor dimension was the page's 16 positions, an eighth of a
-  128-lane tile.) dtype is ``int8`` under ``cache_quant="int8"``
-  (codes from ``nn.decoder_infer.quant_kv``, the same quantiser the
-  dense path uses — the pager-correctness fence demands token
-  identity), else the model's compute dtype.
+  (:class:`PagedWindowed`). dtype is ``int8`` under
+  ``cache_quant="int8"`` (codes from ``nn.decoder_infer.quant_kv``,
+  the same quantiser the dense path uses — the pager-correctness
+  fence demands token identity), else the model's compute dtype.
 - ``scales`` ``[L, P, Hkv, 2, block]`` f32 — per-(page, head, k/v
   half, position) dequant scales; present only under int8. Positions
   stay minor here: a minor dimension of 2 would pad every pair of
   scales to a 128-lane row.
 
-A model whose blocks keep a recurrent state instead of a KV cache
-(``CausalTransformerLM(mixer="power_retention")``) gets a pool of
-**fixed-size state pages** from the same pager (``state_rows``): ONE
-page a sequence whatever its length, the pair
-
-- ``S`` ``[L, P, Hkv, rows, d]`` float32: page ``p`` of layer ``l``
-  holds each kv head's second-power state in the stored layout of
-  ``ops/retention.py`` (``rows`` = ``retention.state_rows(d)``), one
-  head's ``[rows, d]`` a contiguous run of whole (8, 128) tiles, which
-  is what ``ops.retention_decode`` streams through VMEM and writes
-  back in place;
-- ``Z`` ``[L, P, Hkv, d, d]`` float32: the normaliser.
-
-Allocation, reservation, the free list and every invariant below are
-the same; ``pages_for`` answers 1, and nothing is ever shared (a
-state is no pure function of a prefix's tokens alone that another
-sequence could adopt mid-way: there is no chain index to consult).
-
-A model whose blocks attend through a latent
-(``CausalTransformerLM(mixer="latent")``, ``ops/latent.py``) gets the
-third kind of page (``latent_dim``): ONE compressed row a position and
-no KV heads,
-
-- ``rows`` ``[L, P, block, W']`` in the compute dtype: page ``p`` of
-  layer ``l`` holds ``block`` consecutive positions' ``[c_kv (normed)
-  | k_rope (rotated)]``, the ``[block, W']`` matrix that
-  ``ops.latent_decode_attention`` reads with every head at once.
-  ``W'`` is ``latent_dim`` rounded up to whole 128-lane tiles, the
-  tail zero (``ops.latent.lanes``): the TPU tiles the minor
-  dimension by 128 lanes, so a 576-wide row takes 640 in HBM whatever
-  the shape says, and the kernel's DMAs move whole tiles only. Bytes
-  are counted by ``latent_dim``.
-
-Pages are counted, reserved and freed as KV pages are (``pages_for``
-by ``block``); they are not shared and not quantised yet (the
-scheduler refuses both for such a model).
-
-A HYBRID decoder (``CausalTransformerLM(mixer="hybrid")``: Mamba-2
-state-space layers beside softmax attention layers, ``ops/ssm.py``)
-holds TWO kinds of per-sequence state in this one pager (``ssm``), the
-pool tuple ``(kv, H, tail)``, each array stacked over the layers of ITS
-kind only (a ``[n_layers, ...]`` of each would pay every kind's bytes
-for every layer):
-
-- ``kv`` ``[L_attn, P, block, Hkv, 2D]``: the KV pages above, for the
-  attention layers; allocated, reserved and freed by the free list;
-- ``H`` ``[L_ssm, 1 + slots, N, H P]`` float32: a sequence's state in
-  each Mamba layer, the stored matrix of ``ops/ssm.py`` (row ``n`` the
-  state value ``n`` of every (head, feature) column), which
-  ``ops.ssm_decode`` streams through VMEM and writes back in place;
-- ``tail`` ``[L_ssm, 1 + slots, (K - 1) * channels]`` in the compute
-  dtype: the last ``K - 1`` un-convolved rows of each layer's
-  convolution, flat (one page is one lane-aligned run).
-
-A sequence's ONE state page is ``slot + 1`` (page 0 the trash page):
-there are exactly as many as decode slots, so the slot IS the
-reservation and the state side has no free list, no refcount and no
-invariant of its own; what can leak is a KV page, and ``free_pages``
-counts those. A state page comes to its next sequence as its last one
-left it: admission starts from an empty state when ``start`` is 0.
-
-A WINDOWED decoder (``CausalTransformerLM(window=...)``: softmax
-layers of two kinds, ``decoder_infer.WindowSpec``) holds KV pages of
-TWO kinds in this one pager (``windowed``), the pool tuple ``(kv_full,
-kv_window)``, each stacked over the layers of its kind, both FOLDED:
-
-- ``kv_full`` ``[L_full, P, block * Hkv, 2D]``: a full layer keeps
-  every position; pages off the free list, reserved at admission for
-  the sequence's whole life, as the KV pages above;
-- ``kv_window`` ``[L_window, 1 + slots * ring, block * Hkv, 2D]``,
-  ``ring = ceil(window / block) + 1``: a window layer's query sees the
-  last ``window`` keys, which lie in at most ``ring`` pages, so decode
-  slot ``s`` OWNS the ``ring`` pages from ``1 + s * ring`` on and
-  writes them as a ring: position ``t`` goes to the slot's page
-  ``(t // block) % ring``, over the page that held positions ``ring *
-  block`` earlier, all of them out of every later query's window. The
-  slot IS the reservation (as a hybrid's state page is): no free list,
-  no refcount, no release in mid-flight, and a sequence can never hold
-  more than ``ring`` pages of a window layer. (A second free list used
-  as a ring would let short sequences leave pages to long ones; at 48
-  slots the ring rows are 2.4 GB of 4.3 GB of pool, and what a short
-  sequence leaves unused no admission could take without a release in
-  mid-flight, which the scheduler's whole-life reservation rules out.)
-  The step reads a ring through the slot's own ``ring`` entries of a
-  page table (:class:`PagedWindowKV`), which
-  ``ops.paged_decode_attention(window=)`` reads modulo their number.
-
-A folded page ``[block * Hkv, 2D]`` is the matrix
-``ops.paged_decode_attention`` reads, stored as that: positions and
-kv heads share the sublane dimension, so the bytes are the plain ones
-whatever ``Hkv`` is. The unfolded ``[block, Hkv, 2D]`` is the same
-bytes only where ``Hkv`` fills whole 8-row tiles: for 4 KV heads the
-TPU compiler gives it 4-row tiles (``T(4,128)(2,1)`` in bf16: still
-the plain bytes, no padding), whose order in memory is not the
-matrix's (``T(8,128)(2,1)``), and the kernel's view of a page would be
-a copy of the whole pool in front of every call.
-``prefix_sharing``, ``spec_k`` and ``cache_quant`` are refused for
-such a model: a shared page that a ring overwrites, a rejected draft's
-row that has already overwritten a visible one, an int8 ring.
+Those are KV pages (:class:`PagedKV`). The same pager holds the other
+kinds of page a decoder keeps a sequence's context in, each kind's
+layout with its class: ONE fixed-size recurrent-state page a sequence
+(:class:`PagedState`), one compressed latent row a position
+(:class:`PagedLatent`), a hybrid's KV pages beside its decode slot's
+state page (:class:`PagedHybrid`), a windowed decoder's full pages
+beside its decode slot's ring (:class:`PagedWindowed`). Allocation,
+reservation, the free list and every invariant below are the same
+for all; what belongs to a decode slot has no free list, no refcount
+and no invariant of its own: the slot IS the reservation, what can
+leak is a page off the free list, and ``free_pages`` counts those.
 
 Page 0 is the reserved **trash page**: inactive slots' writes and
 unallocated page-table entries route there, so a fixed-shape step can
@@ -173,29 +82,28 @@ both free and referenced, allocation conservation). The device arrays
 live here too so the scheduler can thread them through its jitted
 step and write the updated pool back.
 
-The layouts above are known HERE and to the kernels that read them
-(``ops/pallas_kernels.py``), nowhere else: a program of the scheduler
-reaches its pool through the **cache objects** at the end of this
-file (``nn/decoder_infer.py``'s contract, ``attend(li, mha, h)``):
-:meth:`KVPager.rows` (R rows a slot) builds the ONE class the pager
-chose with its pool, :attr:`KVPager.cache` (:class:`PagedKV`,
-:class:`PagedState`, :class:`PagedLatent`, or for a hybrid
-:class:`PagedHybrid`, which goes PER LAYER by the layer's kind,
-``decoder_infer.ByKind``), and through :meth:`KVPager.write_prompt`
-and :meth:`KVPager.copy_page`. What else depends on the kind of page
-the class says itself: whether a step walks KV pages, which arrays of
-the pool are recurrent state, and the cache class of chunk admission
-(``cache.chunk``: :class:`StateChunk`, :class:`HybridChunk`), which in
-turn says where it finds a sequence's state (``where``) and what a
-prompt's chunks hand on beside the pool (``carried``). A windowed
-decoder's class is :class:`PagedWindowed` (``ByKind`` over
-:class:`PagedKV` and :class:`PagedWindowKV`), and its bucket prefill's
-pages come from :meth:`KVPager.prompt_pages`.
+The layouts are known HERE and to the kernels that read them
+(``ops/pallas_kernels.py``), nowhere else, and so is everything that
+depends on the KIND of page: the class of the step's cache object
+(``nn/decoder_infer.py``'s contract, ``attend(li, mha, h)``) IS the
+kind, :attr:`KVPager.cache` (the two of a decoder whose layers differ
+go PER LAYER by the layer's kind, ``decoder_infer.ByKind``), picked
+from the model in ONE place (:meth:`KVPager.kind_of`). The class says
+all that differs by kind (:class:`_Rows` is the contract): the
+options it refuses, its pool's arrays and sizes, how a prompt is
+admitted (ONE bucket, or chunks of how many rows: ``chunk`` is then
+the cache class of chunk admission, which says where it finds a
+sequence's state, ``where``, and what a prompt's chunks hand on,
+``carried``), what a bucket prefill keeps and how it is written as
+pages, what a decode step reads (the step record's counts) and the
+guards that hold for it alone. The scheduler is the loop over *a*
+pager and asks it.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -206,8 +114,14 @@ from deeplearning4j_tpu.obs import devtime
 from deeplearning4j_tpu.obs import metrics as _metrics
 from deeplearning4j_tpu.ops import latent, retention, ssm
 from deeplearning4j_tpu.ops.pallas_kernels import (
-    _reference_paged_attention, latent_decode_attention,
-    paged_decode_attention, retention_decode, ssm_decode)
+    _reference_paged_attention, latent_chunk_pages,
+    latent_decode_attention, paged_decode_attention, retention_decode,
+    ssm_decode)
+
+#: rows of a retention model's one prefill program (clamped to the
+#: gateway's ``max_context``): at 512 rows the weights' matmuls are
+#: bound by the MXU, not by reading the weights once a call
+PREFILL_CHUNK = 512
 
 
 class PageTableError(RuntimeError):
@@ -223,20 +137,19 @@ def ring_pages(window: int, block: int) -> int:
 
 
 class KVPager:
-    """Fixed pool of refcounted KV pages with free-list allocation.
+    """Fixed pool of refcounted pages with free-list allocation.
 
     ``n_pages`` counts the trash page: usable capacity is
-    ``n_pages - 1`` pages of ``block`` tokens each. The pool holds one
-    of four kinds of pages, chosen here once: KV pages (``(codes,)``,
-    or ``(codes, scales)`` under ``cache_quant``), recurrent-state
-    pages (``state_rows``: ``(S, Z)``), latent pages (``latent_dim``:
-    ``(rows,)``), or a hybrid decoder's KV pages beside its state
-    pages (``ssm``: ``(kv, H, tail)``; ``n_layers`` then counts the
-    attention layers and ``ssm`` is ``(an ops.ssm.HybridSpec, decode
-    slots)``), or a windowed decoder's two kinds of KV pages
-    (``windowed``: ``(kv_full, kv_window)``, both folded; ``n_layers``
-    then counts the FULL layers and ``windowed`` is ``(a
-    decoder_infer.WindowSpec, decode slots)``).
+    ``n_pages - 1`` pages of ``block`` tokens each. The pool holds ONE
+    kind of page, which the arguments name: none of them KV pages
+    (:class:`PagedKV`; int8 under ``cache_quant``), ``state_rows``
+    :class:`PagedState`, ``latent_dim`` :class:`PagedLatent`, ``ssm``
+    (``(an ops.ssm.HybridSpec, decode slots)``) :class:`PagedHybrid`
+    and ``windowed`` (``(a decoder_infer.WindowSpec, decode slots)``)
+    :class:`PagedWindowed`; ``n_layers`` counts the layers whose pages
+    come off the free list (a hybrid's attention layers, a windowed
+    decoder's FULL layers). The kind is the class :attr:`cache`, and
+    it allocates.
     """
 
     def __init__(self, *, n_layers: int, n_kv_heads: int, head_dim: int,
@@ -246,26 +159,13 @@ class KVPager:
                  latent_dim: Optional[int] = None,
                  ssm: Optional[Tuple] = None,
                  windowed: Optional[Tuple] = None):
-        if windowed is not None and (
-                cache_quant is not None or state_rows is not None
-                or latent_dim is not None or ssm is not None):
-            raise ValueError("a windowed pool holds float KV pages of "
-                             "two kinds: cache_quant, state_rows, "
-                             "latent_dim and ssm do not apply")
-        if ssm is not None and (cache_quant is not None
-                                or state_rows is not None
-                                or latent_dim is not None):
-            raise ValueError("a hybrid pool holds float KV pages beside "
-                             "float32 state pages: cache_quant, "
-                             "state_rows and latent_dim do not apply")
-        if state_rows is not None and cache_quant is not None:
-            raise ValueError("a recurrent-state pool is float32: "
-                             "cache_quant does not apply to it")
-        if latent_dim is not None and (cache_quant is not None
-                                       or state_rows is not None):
-            raise ValueError("a latent pool holds one compressed row "
-                             "a position in the compute dtype: neither "
-                             "cache_quant nor state_rows applies to it")
+        given = {PagedWindowed: windowed, PagedHybrid: ssm,
+                 PagedLatent: latent_dim, PagedState: state_rows}
+        named = [k for k, v in given.items() if v is not None]
+        kind = named[0] if named else PagedKV
+        if len(named) > 1 or named and cache_quant is not None:
+            # the arguments name ONE kind, and int8 is PagedKV's alone
+            raise ValueError(kind.alone)
         if block < 1 or block & (block - 1):
             raise ValueError(f"block={block} must be a power of two "
                              "(pages must tile the power-of-two "
@@ -282,73 +182,17 @@ class KVPager:
         self.n_pages = n_pages
         self.block = block
         self.cache_quant = cache_quant
-        #: rows of a kv head's stored state (None: a KV-page pool)
-        self.state_rows = state_rows
-        #: values of a position's latent row (None: no latent pool)
-        self.latent_dim = latent_dim
-        shape = (n_layers, n_pages, block, n_kv_heads, 2 * head_dim)
+        #: the KIND of page, named HERE, once: the class of the step's
+        #: cache object (:meth:`rows`), which says all that depends on it
+        self.cache = kind
         #: bytes of state ONE live slot's decode step reads and writes
         #: (0: the pool holds no recurrent state)
         self.state_bytes_per_slot = 0
-        #: the class of the step's cache object (:meth:`rows`), chosen
-        #: HERE, once, with the pool it addresses. The class says what
-        #: else depends on the kind of page: whether a step walks KV
-        #: pages (``walks_kv``), which of the pool's arrays are
-        #: recurrent state (``state``) and, as ``chunk``, the cache
-        #: object of chunk admission where the kind admits by chunks.
-        self.cache = PagedKV
         #: pages of a window layer's ring (0: no window layers) and
         #: the decode slots that own one each
         self.ring = self.rings = 0
-        if windowed is not None:
-            self.cache = PagedWindowed
-            spec, slots = windowed
-            self.rings = slots
-            self.ring = ring_pages(spec.window, block)
-            page = (block * n_kv_heads, 2 * head_dim)
-            self._pool: Tuple = (
-                jnp.zeros((n_layers, n_pages, *page), jnp.dtype(dtype)),
-                jnp.zeros((len(spec.layers("window")),
-                           1 + slots * self.ring, *page),
-                          jnp.dtype(dtype)))
-        elif ssm is not None:
-            self.cache = PagedHybrid
-            spec, slots = ssm
-            n_ssm = len(spec.layers("mamba2"))
-            tail = (spec.d_conv - 1) * spec.conv_dim
-            self._pool: Tuple = (
-                jnp.zeros(shape, jnp.dtype(dtype)),
-                jnp.zeros((n_ssm, 1 + slots, spec.d_state, spec.d_inner),
-                          jnp.float32),
-                jnp.zeros((n_ssm, 1 + slots, tail), jnp.dtype(dtype)))
-            # the state and the tail of every Mamba layer, both ways
-            self.state_bytes_per_slot = 2 * n_ssm * (
-                4 * spec.d_state * spec.d_inner
-                + tail * jnp.dtype(dtype).itemsize)
-        elif latent_dim is not None:
-            self.cache = PagedLatent
-            self._pool: Tuple = (jnp.zeros(
-                (n_layers, n_pages, block, latent.lanes(latent_dim)),
-                jnp.dtype(dtype)),)
-        elif state_rows is not None:
-            self.cache = PagedState
-            self._pool: Tuple = (
-                jnp.zeros((n_layers, n_pages, n_kv_heads, state_rows,
-                           head_dim), jnp.float32),
-                jnp.zeros((n_layers, n_pages, n_kv_heads, head_dim,
-                           head_dim), jnp.float32))
-            # by the logical size (d (d + 1) / 2 rows of d values and
-            # the normaliser's, float32, both ways)
-            self.state_bytes_per_slot = (
-                2 * 4 * n_layers * n_kv_heads
-                * retention.logical_state_rows(head_dim) * (head_dim + 1))
-        elif cache_quant == "int8":
-            self._pool: Tuple = (
-                jnp.zeros(shape, jnp.int8),
-                jnp.zeros((n_layers, n_pages, n_kv_heads, 2, block),
-                          jnp.float32))
-        else:
-            self._pool = (jnp.zeros(shape, jnp.dtype(dtype)),)
+        self._pool: Tuple = kind.alloc(self, given.get(kind),
+                                       jnp.dtype(dtype))
         # host bookkeeping: LIFO free list (hot pages stay hot), the
         # page -> refcount map, and the per-owner page lists the
         # invariant checks cross-foot against the refcounts
@@ -376,14 +220,47 @@ class KVPager:
         _metrics.SERVING_STATE_POOL.set(self.state_pool_bytes())
         self._gauge()
 
+    @staticmethod
+    def kind_of(model, slots: int = 0):
+        """``(kind, named)``: the kind of page ``model``'s decoder
+        keeps a sequence's context in, and the constructor's arguments
+        that name it for ``slots`` decode slots (with ``n_layers``
+        where it is not the model's). The ONE place that asks the model."""
+        spec = getattr(model, "windowed", None)
+        if spec is not None:
+            return PagedWindowed, {"n_layers": len(spec.layers("full")),
+                                   "windowed": (spec, slots)}
+        spec = getattr(model, "hybrid", None)
+        if spec is not None:
+            return PagedHybrid, {"n_layers": len(spec.layers("softmax")),
+                                 "ssm": (spec, slots)}
+        if getattr(model, "latent", None) is not None:
+            return PagedLatent, {"latent_dim": model.latent.row}
+        if getattr(model, "mixer", "softmax") == "power_retention":
+            return PagedState, {
+                "state_rows": retention.state_rows(_head_dim(model))}
+        return PagedKV, {}
+
+    @classmethod
+    def for_model(cls, model, slots: int, block: int,
+                  n_pages: Optional[int], max_context: int) -> "KVPager":
+        """The pager of ``model``'s kind: the constructor's arguments,
+        derived (``n_pages`` None: enough for every slot at
+        ``max_context``)."""
+        kind, named = cls.kind_of(model, slots)
+        return cls(**{
+            "n_layers": model.n_layers, "n_kv_heads": model.n_kv_heads,
+            "head_dim": _head_dim(model), "block": block,
+            "n_pages": (int(n_pages) if n_pages else
+                        1 + slots * kind.pages_for(block, max_context)),
+            "cache_quant": model.cache_quant,
+            "dtype": model.compute_dtype or "float32", **named})
+
     # -- device pool -----------------------------------------------------
     @property
     def pool(self) -> Tuple:
         """The layer-stacked device arrays the jitted step reads and
-        rewrites: ``(codes,)`` or ``(codes, scales)`` of KV pages,
-        ``(S, Z)`` of a recurrent-state pool, ``(rows,)`` of a latent
-        pool, ``(kv, H, tail)`` of a hybrid's, ``(kv_full, kv_window)``
-        of a windowed decoder's."""
+        rewrites, as the kind laid them out."""
         return self._pool
 
     @pool.setter
@@ -400,11 +277,6 @@ class KVPager:
         return sum(int(np.prod(a.shape)) * a.dtype.itemsize
                    for a in self._pool[self.cache.state])
 
-    @property
-    def walks_kv(self) -> bool:
-        """Whether a decode step's attention walks KV pages."""
-        return self.cache.walks_kv
-
     # -- inside a traced program, over ITS pool -------------------------
     def rows(self, dims, pool, pt, pos, act):
         """The cache object of R rows a slot: ``pt`` [S, MP] i32 the
@@ -414,97 +286,28 @@ class KVPager:
         after the rows."""
         return self.cache(dims, pool, pt, pos, act)
 
-    def prompt_pages(self, slot: int, pages: List[int], tb: int,
-                     t0: int):
-        """What :meth:`write_prompt` takes as ``page_ids`` for the
-        sequence in ``slot`` with ``pages`` off the free list, a
-        bucket of ``tb`` rows and ``t0`` prompt tokens: the first ``tb
-        / block`` pages; for a windowed pool beside them the bucket's
-        pages a window layer keeps (``src [ring]``: the last ``ring``
-        up to the one that holds position ``t0 - 1``) and the slot's
-        ring pages they go to (``dst [ring]``; the trash page where
-        the prompt has fewer)."""
-        ids = np.asarray(pages[:tb // self.block], np.int32)
-        if not self.ring:
-            return jnp.asarray(ids)
-        src = (t0 - 1) // self.block - self.ring + 1 + np.arange(
-            self.ring, dtype=np.int32)
-        dst = np.where(src >= 0, 1 + slot * self.ring + src % self.ring,
-                       0).astype(np.int32)
-        return (jnp.asarray(ids), jnp.asarray(np.maximum(src, 0)),
-                jnp.asarray(dst))
-
-    def prompt_pages_shapes(self, tb: int):
-        """:meth:`prompt_pages` as shapes, for lowering."""
-        import jax
-        sds = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)
-        ids = sds(tb // self.block)
-        return (ids, sds(self.ring), sds(self.ring)) if self.ring else ids
-
-    @staticmethod
-    def write_prompt(pool, page_ids, layers, spec=None):
-        """``pool`` with one sequence's bucket prefill written as whole
-        pages: ``layers`` each layer's ``(k, v) [1, Tb, Hkv, D]``
-        (``decoder_infer.causal_prefill``'s ``keep``), ``page_ids`` the
-        sequence's first ``Tb / block`` pages in position order. The
-        pool's own layout, so nothing is transposed on the way, and
-        all layers go in one scatter. A latent pool takes each layer's
-        latent rows ``[1, Tb, latent_dim]``
-        (``decoder_infer.latent_prefill``'s ``keep``). A windowed pool
-        (``spec`` its ``WindowSpec``, ``page_ids`` as
-        :meth:`prompt_pages` gives them) takes every full layer's
-        pages and, of a window layer's, the last ``ring``."""
-        if spec is not None:
-            full, window = pool
-            ids, src, dst = page_ids
-            kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
-                            for k, v in layers])    # [L, Tb, Hkv, 2D]
-            tb = kv.shape[1]
-            rows = full.shape[2]
-            kv = kv.reshape(kv.shape[0], tb * kv.shape[2] // rows, rows,
-                            kv.shape[3]).astype(full.dtype)
-            at = lambda kind: jnp.asarray(spec.layers(kind), jnp.int32)
-            return (full.at[:, ids].set(kv[at("full")]),
-                    window.at[:, dst].set(kv[at("window")][:, src]))
-        if pool[0].ndim == 4:
-            (rows,) = pool
-            lat = jnp.stack([r[0] for r in layers])     # [L, Tb, W]
-            n_l, tb, width = lat.shape
-            block, stored = rows.shape[2:]
-            lat = jnp.pad(lat, ((0, 0), (0, 0), (0, stored - width)))
-            return (rows.at[:, page_ids].set(lat.reshape(
-                n_l, tb // block, block, stored).astype(rows.dtype)),)
-        kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
-                        for k, v in layers])    # [L, Tb, Hkv, 2D]
-        n_l, tb, n_kv, d2 = kv.shape
-        block = pool[0].shape[2]
-        paged = (n_l, tb // block, block, n_kv)
-        if len(pool) == 2:
-            codes, scales = pool
-            w8, s = di.quant_kv(kv.reshape(n_l, tb, n_kv, 2, d2 // 2), 4)
-            return (codes.at[:, page_ids].set(w8.reshape(*paged, d2)),
-                    scales.at[:, page_ids].set(
-                        s.reshape(*paged, 2).transpose(0, 1, 3, 4, 2)))
-        (kvpool,) = pool
-        return (kvpool.at[:, page_ids].set(
-            kv.reshape(*paged, d2).astype(kvpool.dtype)),)
-
     @staticmethod
     def copy_page(pool, src, dst):
         """``pool`` with page ``src`` copied over page ``dst`` (all
         layers, every array): the copy-on-write primitive."""
         return tuple(a.at[:, dst].set(a[:, src]) for a in pool)
 
+    # -- what the kind says, for THIS pool (:class:`_Rows`) -------------
+    def pages_for(self, n_tokens: int) -> int:
+        return self.cache.pages_for(self.block, n_tokens)
+
+    def prompt_pages(self, slot: int, pages: List[int], tb: int, t0: int):
+        return self.cache.prompt_pages(self, slot, pages, tb, t0)
+
+    def prompt_pages_shapes(self, tb: int):
+        """:meth:`prompt_pages` as shapes, for lowering."""
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            self.prompt_pages(0, [0] * (tb // self.block), tb, 1))
+
     # -- allocation ------------------------------------------------------
     def free_pages(self) -> int:
         return len(self._free)
-
-    def pages_for(self, n_tokens: int) -> int:
-        """Pages needed to hold ``n_tokens`` cache positions (a
-        recurrent state takes one page whatever the length)."""
-        if self.state_rows is not None:
-            return 1
-        return -(-int(n_tokens) // self.block)
 
     def alloc(self, n: int, owner) -> Optional[List[int]]:
         """Take ``n`` exclusive pages (refcount 1) for ``owner`` (any
@@ -698,13 +501,7 @@ class KVPager:
         _metrics.SERVING_KV_OCCUPANCY.set(
             (usable - len(self._free)) / usable)
         _metrics.SERVING_PREFIX_SHARED.set(self.shared_pages())
-        if self.ring:
-            # the slot is the reservation: a sequence's window pages
-            # are counted from its full pages, not held anywhere
-            held = _metrics.SERVING_KV_PAGES_HELD
-            held.labels(kind="full").set(usable - len(self._free))
-            held.labels(kind="window").set(sum(
-                min(len(p), self.ring) for p in self._pages_of.values()))
+        self.cache.gauge(self)
         for tenant, n in self._tenant_pages.items():
             _metrics.SERVING_KV_RESERVED.labels(tenant=tenant).set(n)
 
@@ -750,22 +547,27 @@ class KVPager:
                     raise PageTableError(
                         f"chain entry {key[:2]} references freed "
                         f"page {p}")
-        if self.ring and self._pool[1].shape[1] != \
-                1 + self.rings * self.ring:
-            # what keeps a sequence to ``ring`` pages of a window
-            # layer is the pool's shape: a slot's ring IS its pages
-            raise PageTableError(
-                f"the window pool has {self._pool[1].shape[1]} pages, "
-                f"not the trash page and {self.rings} rings of "
-                f"{self.ring}")
+        self.cache.check(self)
 
 
-# -- the pool's cache objects (nn/decoder_infer.py's contract) ---------------
+# -- the kinds of page: the pool's cache objects, and what each says ---------
+
+def _head_dim(dims) -> int:
+    return getattr(dims, "head_dim", None) or dims.hidden // dims.n_heads
+
+
+#: why neither sharing nor speculation serves a recurrent state
+_SNAPSHOTS = ("a recurrent state cannot be adopted at a page boundary nor "
+              "rolled back after a rejected draft; both need an index of "
+              "state snapshots, which this scheduler does not keep")
+
 
 class _Rows:
-    """What :class:`KVPager` asks of the step's cache class beside the
-    ``decoder_infer`` contract, for all that depends on the kind of
-    page it addresses."""
+    """A KIND of page: the step's cache object (``attend``, ``pool``:
+    the ``decoder_infer`` contract) and, as its class, all that the
+    pager and the scheduler ask of the kind (the defaults are KV
+    pages'; ``pager`` the :class:`KVPager` of the kind, ``dims`` the
+    model)."""
     #: whether a decode step's attention walks KV pages
     walks_kv = False
     #: which of the pool's arrays are recurrent state
@@ -774,6 +576,17 @@ class _Rows:
     #: carried, where, start, valid)`` (None: the kind admits a prompt
     #: as ONE bucket)
     chunk = None
+    #: why no other kind's argument, nor ``cache_quant``, goes with it
+    alone = ""
+    #: the scheduler's options the kind refuses, each with its reason,
+    #: and how the refusal names the model
+    refuses: Dict[str, str] = {}
+    serves = ""
+    #: the devtime scope of the step's blocks
+    scope = ""
+    #: the bucket prefill's ``attend``, ``prefill(dims, keep)``: what
+    #: ``keep(li, *kept)`` gets of a layer :meth:`write_prompt` takes
+    prefill = staticmethod(di.causal_prefill)
 
     def __init__(self, dims, pool, pt, pos, act):
         self.dims = dims
@@ -781,6 +594,63 @@ class _Rows:
         self.pt = pt
         self.pos = pos
         self.act = act
+
+    @staticmethod
+    def pages_for(block: int, n_tokens: int) -> int:
+        """Pages that hold ``n_tokens`` cache positions (of
+        ``max_context``: a sequence's row of the page table)."""
+        return -(-int(n_tokens) // block)
+
+    @staticmethod
+    def chunks(dims, max_context: int):
+        """How a prompt is admitted, ``(rows, carried)``: by chunks of
+        ONE program of ``rows`` rows (``carried`` what they hand on
+        beside the pool, at the start); ``rows`` None: by ONE bucket."""
+        return None, ()
+
+    @staticmethod
+    def prompt_pages(pager, slot: int, pages: List[int], tb: int, t0: int):
+        """What :meth:`write_prompt` takes as ``page_ids`` for the
+        sequence in ``slot`` with ``pages`` off the free list, a
+        bucket of ``tb`` rows and ``t0`` prompt tokens: the first ``tb
+        / block`` pages."""
+        return jnp.asarray(np.asarray(pages[:tb // pager.block], np.int32))
+
+    @classmethod
+    def step_reads(cls, pager, dims, at, row_pages: int) -> dict:
+        """What a decode step reads, as the step record's counts, from
+        the positions ``at`` its live slots write (the host's mirror:
+        no device read) and the ``row_pages`` of a slot's row of the
+        table; feeds the kind's counters. ``kv_pages``: the pages ONE
+        layer's walk reads, the position being written included;
+        ``state_bytes``: the live slots' states, once each way."""
+        reads = {"kv_pages": int(np.sum(at // pager.block + 1))
+                 if cls.walks_kv else 0,
+                 "state_bytes": len(at) * pager.state_bytes_per_slot}
+        _metrics.SERVING_KV_WALKED.set(reads["kv_pages"])
+        _metrics.SERVING_STATE_MOVED.inc(reads["state_bytes"])
+        return reads
+
+    @staticmethod
+    def check_feed(pager, dims, pt, ends: Dict[int, int]) -> None:
+        """The scheduler's guard of a rebuilt feed, for this kind: no
+        live slot's last position (``ends``, by slot: its length with
+        the budget it has left) reaches past what its row of the table
+        ``pt`` serves. Raises :class:`PageTableError`."""
+        for i, end in ends.items():
+            if pager.pages_for(end) > pt.shape[1]:
+                raise PageTableError(
+                    f"slot {i} reaches position {end}: its row of "
+                    f"{pt.shape[1]} pages serves "
+                    f"{pt.shape[1] * pager.block}")
+
+    @staticmethod
+    def check(pager) -> None:
+        """:meth:`KVPager.check_invariants`, for this kind alone."""
+
+    @staticmethod
+    def gauge(pager) -> None:
+        """The gauges of this kind alone."""
 
 
 class PagedKV(_Rows):
@@ -796,7 +666,8 @@ class PagedKV(_Rows):
     gathers clamp silently, and junk must never land in a live page.
     Over a FOLDED pool ``[L, P, block * Hkv, 2D]`` (a windowed
     decoder's) R is 1; ``layers`` then names the model's layer of each
-    layer of this pool (a rotation may differ by layer)."""
+    layer of this pool (a rotation may differ by layer). Float or int8
+    (``cache_quant``) is a choice inside this kind."""
     walks_kv = True
 
     def __init__(self, dims, pool, pt, pos, act, layers=None):
@@ -804,6 +675,40 @@ class PagedKV(_Rows):
         self.layers = layers
         #: positions a query sees (None: all)
         self.window = None
+
+    @staticmethod
+    def alloc(pager, named, dtype) -> Tuple:
+        shape = (pager.n_layers, pager.n_pages, pager.block,
+                 pager.n_kv_heads, 2 * pager.head_dim)
+        if pager.cache_quant == "int8":
+            return (jnp.zeros(shape, jnp.int8),
+                    jnp.zeros((pager.n_layers, pager.n_pages,
+                               pager.n_kv_heads, 2, pager.block),
+                              jnp.float32))
+        return (jnp.zeros(shape, dtype),)
+
+    @staticmethod
+    def write_prompt(dims, pool, page_ids, layers):
+        """``pool`` with one sequence's bucket prefill written as whole
+        pages: ``layers`` each layer's ``(k, v) [1, Tb, Hkv, D]``
+        (``decoder_infer.causal_prefill``'s ``keep``), ``page_ids`` the
+        sequence's first ``Tb / block`` pages in position order. The
+        pool's own layout, so nothing is transposed on the way, and
+        all layers go in one scatter."""
+        kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
+                        for k, v in layers])    # [L, Tb, Hkv, 2D]
+        n_l, tb, n_kv, d2 = kv.shape
+        block = pool[0].shape[2]
+        paged = (n_l, tb // block, block, n_kv)
+        if len(pool) == 2:
+            codes, scales = pool
+            w8, s = di.quant_kv(kv.reshape(n_l, tb, n_kv, 2, d2 // 2), 4)
+            return (codes.at[:, page_ids].set(w8.reshape(*paged, d2)),
+                    scales.at[:, page_ids].set(
+                        s.reshape(*paged, 2).transpose(0, 1, 3, 4, 2)))
+        (kvpool,) = pool
+        return (kvpool.at[:, page_ids].set(
+            kv.reshape(*paged, d2).astype(kvpool.dtype)),)
 
     def pages(self, block: int):
         """Where each row's KV goes, ``(page [S, R], in bounds [S,
@@ -886,13 +791,12 @@ class PagedKV(_Rows):
 
 class PagedWindowKV(PagedKV):
     """One position a slot against a windowed decoder's RING pool
-    ``[L_window, 1 + slots * ring, block * Hkv, 2D]``: slot ``s`` owns
-    pages ``1 + s * ring ..``, position ``t`` lies in its page ``(t //
-    block) % ring``. The slot's ring is its row of the page table,
-    ``ring`` entries long, and ``paged_decode_attention(window=)``
-    reads such a row modulo its length: the walk starts at the page
-    of the window's first position, masks that page's head and the
-    last one's stale tail, and takes ``ring`` pages at most."""
+    (:class:`PagedWindowed`). The slot's ring is its row of the page
+    table, ``ring`` entries long, and
+    ``paged_decode_attention(window=)`` reads such a row modulo its
+    length: the walk starts at the page of the window's first
+    position, masks that page's head and the last one's stale tail,
+    and takes ``ring`` pages at most."""
 
     def __init__(self, dims, pool, pos, act, layers):
         ring = (pool[0].shape[1] - 1) // pos.shape[0]
@@ -925,17 +829,60 @@ class _Scoped:
         return self.inner.pool
 
 
-class PagedWindowed(di.ByKind):
-    """The step's cache object over a windowed decoder's pool
-    ``(kv_full, kv_window)``: a full layer's rows go to
+class PagedWindowed(di.ByKind, _Rows):
+    """A WINDOWED decoder's pages (``CausalTransformerLM(window=...)``:
+    softmax layers of two kinds, ``decoder_infer.WindowSpec``), the
+    pool tuple ``(kv_full, kv_window)``, each stacked over the layers
+    of its kind (a ``[n_layers, ...]`` of each would pay every kind's
+    bytes for every layer), both FOLDED:
+
+    - ``kv_full`` ``[L_full, P, block * Hkv, 2D]``: a full layer keeps
+      every position; pages off the free list, reserved at admission
+      for the sequence's whole life, as KV pages are;
+    - ``kv_window`` ``[L_window, 1 + slots * ring, block * Hkv, 2D]``,
+      ``ring = ceil(window / block) + 1``: a window layer's query sees
+      the last ``window`` keys, which lie in at most ``ring`` pages, so
+      decode slot ``s`` OWNS the ``ring`` pages from ``1 + s * ring`` on
+      and writes them as a ring: position ``t`` goes to the slot's page
+      ``(t // block) % ring``, over the page that held positions ``ring
+      * block`` earlier, all of them out of every later query's window.
+      No release in mid-flight, and a sequence can never hold more
+      than ``ring`` pages of a window layer. (A second free list used
+      as a ring would let short sequences leave pages to long ones; at
+      48 slots the ring rows are 2.4 GB of 4.3 GB of pool, and what a
+      short sequence leaves unused no admission could take without a
+      release in mid-flight, which the scheduler's whole-life
+      reservation rules out.)
+
+    A folded page ``[block * Hkv, 2D]`` is the matrix
+    ``ops.paged_decode_attention`` reads, stored as that: positions
+    and kv heads share the sublane dimension, so the bytes are the
+    plain ones whatever ``Hkv`` is. The unfolded ``[block, Hkv, 2D]``
+    is the same bytes only where ``Hkv`` fills whole 8-row tiles: for
+    4 KV heads the TPU compiler gives it 4-row tiles
+    (``T(4,128)(2,1)`` in bf16: still the plain bytes, no padding),
+    whose order in memory is not the matrix's (``T(8,128)(2,1)``), and
+    the kernel's view of a page would be a copy of the whole pool in
+    front of every call.
+
+    As the step's cache object, a full layer's rows go to
     :class:`PagedKV` over the pages of the slot's page-table row, a
     window layer's to :class:`PagedWindowKV` over the slot's ring,
     each under the layer's index among ITS kind and under a scope of
     its kind (``attn.full``, ``attn.window``: the two page walks'
-    device times are told apart by it)."""
+    device times are told apart by it). Admission is the bucket
+    prefill; of a window layer's rows it keeps the last ``ring`` pages
+    only."""
     walks_kv = True
-    state = slice(0, 0)
-    chunk = None
+    alone = ("a windowed pool holds float KV pages of two kinds: "
+             "cache_quant, state_rows, latent_dim and ssm do not apply")
+    serves = "windowed layers"
+    refuses = {
+        "prefix_sharing": "a shared page of a window layer would be "
+                          "overwritten by its first owner's ring",
+        "spec_k": "a rejected draft's row may already have overwritten "
+                  "a ring page a later query sees",
+        "cache_quant": "a ring page has no int8 form yet"}
 
     def __init__(self, dims, pool, pt, pos, act):
         spec = dims.windowed
@@ -950,16 +897,176 @@ class PagedWindowed(di.ByKind):
                                          spec.layers("window")),
                            "attn.window"))
 
+    @staticmethod
+    def alloc(pager, named, dtype) -> Tuple:
+        spec, pager.rings = named
+        pager.ring = ring_pages(spec.window, pager.block)
+        page = (pager.block * pager.n_kv_heads, 2 * pager.head_dim)
+        return (jnp.zeros((pager.n_layers, pager.n_pages, *page), dtype),
+                jnp.zeros((len(spec.layers("window")),
+                           1 + pager.rings * pager.ring, *page), dtype))
+
+    @staticmethod
+    def prompt_pages(pager, slot: int, pages: List[int], tb: int, t0: int):
+        """Beside the first ``tb / block`` pages, the bucket's pages a
+        window layer keeps (``src [ring]``: the last ``ring`` up to
+        the one that holds position ``t0 - 1``) and the slot's ring
+        pages they go to (``dst [ring]``; the trash page where the
+        prompt has fewer)."""
+        ring = pager.ring
+        src = (t0 - 1) // pager.block - ring + 1 + np.arange(
+            ring, dtype=np.int32)
+        dst = np.where(src >= 0, 1 + slot * ring + src % ring,
+                       0).astype(np.int32)
+        return (_Rows.prompt_pages(pager, slot, pages, tb, t0),
+                jnp.asarray(np.maximum(src, 0)), jnp.asarray(dst))
+
+    @staticmethod
+    def write_prompt(dims, pool, page_ids, layers):
+        """Every full layer's pages and, of a window layer's, the last
+        ``ring`` (``page_ids`` as :meth:`prompt_pages` gives them)."""
+        spec = dims.windowed
+        full, window = pool
+        ids, src, dst = page_ids
+        kv = jnp.stack([jnp.concatenate([k[0], v[0]], axis=-1)
+                        for k, v in layers])    # [L, Tb, Hkv, 2D]
+        tb = kv.shape[1]
+        rows = full.shape[2]
+        kv = kv.reshape(kv.shape[0], tb * kv.shape[2] // rows, rows,
+                        kv.shape[3]).astype(full.dtype)
+        at = lambda kind: jnp.asarray(spec.layers(kind), jnp.int32)
+        return (full.at[:, ids].set(kv[at("full")]),
+                window.at[:, dst].set(kv[at("window")][:, src]))
+
+    @classmethod
+    def step_reads(cls, pager, dims, at, row_pages: int) -> dict:
+        """Beside ``kv_pages`` (ONE full layer's):
+        ``kv_pages_window``, the pages one window layer's walk reads;
+        ``kv_rows_read``, the cached positions all layers' walks must
+        read (a full layer ``at + 1``, a window layer the last
+        ``window`` of them), and ``kv_rows_unwindowed``, what they
+        would read with no window; ``ring_overwrites``, the ring pages
+        this step begins to write over."""
+        spec, block = dims.windowed, pager.block
+        n = np.asarray(at, np.int64) + 1
+        n_full = len(spec.layers("full"))
+        n_win = len(spec.layers("window"))
+        first = np.maximum(n - spec.window, 0) // block
+        reads = {
+            **super().step_reads(pager, dims, at, row_pages),
+            "kv_pages_window": int(np.sum(-(-n // block) - first)),
+            "kv_rows_read": int(n_full * n.sum() + n_win * np.minimum(
+                n, spec.window).sum()),
+            "kv_rows_unwindowed": int((n_full + n_win) * n.sum()),
+            "ring_overwrites": int(n_win * np.sum(
+                ((n - 1) % block == 0)
+                & ((n - 1) // block >= pager.ring)))}
+        _metrics.SERVING_KV_ROWS_READ.inc(reads["kv_rows_read"])
+        _metrics.SERVING_KV_ROWS_UNWINDOWED.inc(
+            reads["kv_rows_unwindowed"])
+        _metrics.SERVING_RING_OVERWRITES.inc(reads["ring_overwrites"])
+        return reads
+
+    @staticmethod
+    def check_feed(pager, dims, pt, ends: Dict[int, int]) -> None:
+        """And a window layer's ring, which the step cuts from the
+        window pool's shape (so its entries are the pool's own), is so
+        long that a walk of ``window`` positions wraps once at most."""
+        _Rows.check_feed(pager, dims, pt, ends)
+        held = pager.pool[1].shape[1]
+        window = dims.windowed.window
+        need = ring_pages(window, pager.block)
+        if (held - 1) // pager.rings < need:
+            raise PageTableError(
+                f"the window pool's {held} pages give each of "
+                f"{pager.rings} slots a ring of "
+                f"{(held - 1) // pager.rings}: a window of "
+                f"{window} needs {need}")
+
+    @staticmethod
+    def check(pager) -> None:
+        if pager.pool[1].shape[1] != 1 + pager.rings * pager.ring:
+            # what keeps a sequence to ``ring`` pages of a window
+            # layer is the pool's shape: a slot's ring IS its pages
+            raise PageTableError(
+                f"the window pool has {pager.pool[1].shape[1]} pages, "
+                f"not the trash page and {pager.rings} rings of "
+                f"{pager.ring}")
+
+    @staticmethod
+    def gauge(pager) -> None:
+        # the slot is the reservation: a sequence's window pages are
+        # counted from its full pages, not held anywhere
+        held = _metrics.SERVING_KV_PAGES_HELD
+        held.labels(kind="full").set(pager.n_pages - 1 - pager.free_pages())
+        held.labels(kind="window").set(sum(
+            min(len(p), pager.ring) for p in pager._pages_of.values()))
+
 
 class PagedLatent(_Rows):
-    """One position a slot against the latent pool: the row's latent
-    ``[c_kv (normed) | k_rope (rotated)]`` goes to page ``pt[s, pos //
-    block]`` at offset ``pos % block`` (an inactive slot's, and a
-    position past the slot's page table, to the trash page), and the
-    ABSORBED form reads the slot's pages as they are stored
-    (``ops.latent_decode_attention``; ``ops/latent.py`` has the
-    algebra). (R is 1: the scheduler refuses the multi-row programs
-    for a latent model at construction.)"""
+    """The pages of a model whose blocks attend through a latent
+    (``CausalTransformerLM(mixer="latent")``, ``ops/latent.py``): ONE
+    compressed row a position and no KV heads, the pool ``(rows,)``,
+    ``[L, P, block, W']`` in the compute dtype: page ``p`` of layer
+    ``l`` holds ``block`` consecutive positions' ``[c_kv (normed) |
+    k_rope (rotated)]``, the ``[block, W']`` matrix that
+    ``ops.latent_decode_attention`` reads with every head at once.
+    ``W'`` is ``latent_dim`` rounded up to whole 128-lane tiles, the
+    tail zero (``ops.latent.lanes``): the TPU tiles the minor
+    dimension by 128 lanes, so a 576-wide row takes 640 in HBM
+    whatever the shape says, and the kernel's DMAs move whole tiles
+    only. Bytes are counted by ``latent_dim``; pages are counted,
+    reserved and freed as KV pages are.
+
+    As the step's cache object, one position a slot: the row's latent
+    goes to page ``pt[s, pos // block]`` at offset ``pos % block`` (an
+    inactive slot's, and a position past the slot's page table, to the
+    trash page), and the ABSORBED form reads the slot's pages as they
+    are stored (``ops/latent.py`` has the algebra). Admission is the
+    bucket prefill softmax has, with K and V expanded from each
+    position's latent (``decoder_infer.latent_prefill``) and the
+    latent rows kept as the sequence's pages."""
+    alone = ("a latent pool holds one compressed row a position in the "
+             "compute dtype: neither cache_quant nor state_rows applies "
+             "to it")
+    serves = "mixer='latent'"
+    refuses = {
+        "prefix_sharing": "its multi-row suffix prefill reads KV heads",
+        "spec_k": "the verify step's multi-row read has no absorbed "
+                  "form yet",
+        "cache_quant": "a latent row has no int8 form yet"}
+    prefill = staticmethod(di.latent_prefill)
+
+    @staticmethod
+    def alloc(pager, named, dtype) -> Tuple:
+        return (jnp.zeros((pager.n_layers, pager.n_pages, pager.block,
+                           latent.lanes(named)), dtype),)
+
+    @staticmethod
+    def write_prompt(dims, pool, page_ids, layers):
+        """``layers`` each layer's latent rows ``([1, Tb, latent_dim],)``
+        (``decoder_infer.latent_prefill``'s ``keep``)."""
+        (rows,) = pool
+        lat = jnp.stack([r[0] for (r,) in layers])  # [L, Tb, W]
+        n_l, tb, width = lat.shape
+        block, stored = rows.shape[2:]
+        lat = jnp.pad(lat, ((0, 0), (0, 0), (0, stored - width)))
+        return (rows.at[:, page_ids].set(lat.reshape(
+            n_l, tb // block, block, stored).astype(rows.dtype)),)
+
+    @classmethod
+    def step_reads(cls, pager, dims, at, row_pages: int) -> dict:
+        """And ``latent_rows``, the cached positions the step's
+        attention reads, the one being written included, with
+        ``latent_chunks``, the (slot, chunk) items a layer's walk of
+        them has (all but the first issued ahead)."""
+        lens = np.asarray(at) + 1
+        item = pager.block * latent_chunk_pages(pager.block, row_pages)
+        reads = {**super().step_reads(pager, dims, at, row_pages),
+                 "latent_rows": int(np.sum(lens)),
+                 "latent_chunks": int(np.sum(-(-lens // item)))}
+        _metrics.SERVING_LATENT_ROWS.inc(reads["latent_rows"])
+        return reads
 
     def attend(self, li, mha, h):
         dims, pt = self.dims, self.pt
@@ -1023,13 +1130,62 @@ class StateChunk(di.RetentionRows):
 
 
 class PagedState(_Rows):
-    """One position a slot against the state pool: the slot's ONE
-    state page (``pt``'s only column) is updated in place and read
-    (``ops.retention_decode``); an inactive slot's page is neither.
-    (R is 1: the scheduler refuses the multi-row programs for a
-    retention model at construction.)"""
+    """The pages of a model whose blocks keep a recurrent state
+    instead of a KV cache
+    (``CausalTransformerLM(mixer="power_retention")``): ONE fixed-size
+    page a sequence whatever its length, the pool ``(S, Z)``:
+
+    - ``S`` ``[L, P, Hkv, rows, d]`` float32: page ``p`` of layer ``l``
+      holds each kv head's second-power state in the stored layout of
+      ``ops/retention.py`` (``rows`` = ``retention.state_rows(d)``),
+      one head's ``[rows, d]`` a contiguous run of whole (8, 128)
+      tiles, which is what ``ops.retention_decode`` streams through
+      VMEM and writes back in place;
+    - ``Z`` ``[L, P, Hkv, d, d]`` float32: the normaliser.
+
+    Nothing is ever shared (a state is no pure function of a prefix's
+    tokens alone that another sequence could adopt mid-way: there is
+    no chain index to consult). As the step's cache object, one
+    position a slot: the slot's state page (``pt``'s only column) is
+    updated in place and read; an inactive slot's page is neither.
+    Admission runs ONE program of :data:`PREFILL_CHUNK` rows ``ceil(t0
+    / chunk)`` times, carrying the state in the sequence's page, so no
+    prompt needs a bucket as long as itself."""
     state = slice(None)
     chunk = StateChunk
+    alone = ("a recurrent-state pool is float32: cache_quant does not "
+             "apply to it")
+    serves = "mixer='power_retention'"
+    refuses = {"prefix_sharing": _SNAPSHOTS, "spec_k": _SNAPSHOTS}
+    scope = "retention_decode"
+
+    @staticmethod
+    def alloc(pager, named, dtype) -> Tuple:
+        head = (pager.n_layers, pager.n_pages, pager.n_kv_heads)
+        # by the logical size (d (d + 1) / 2 rows of d values and the
+        # normaliser's, float32, both ways)
+        pager.state_bytes_per_slot = (
+            2 * 4 * pager.n_layers * pager.n_kv_heads
+            * retention.logical_state_rows(pager.head_dim)
+            * (pager.head_dim + 1))
+        return (jnp.zeros((*head, named, pager.head_dim), jnp.float32),
+                jnp.zeros((*head, pager.head_dim, pager.head_dim),
+                          jnp.float32))
+
+    @staticmethod
+    def pages_for(block: int, n_tokens: int) -> int:
+        return 1        # ONE state page a sequence, whatever its length
+
+    @staticmethod
+    def chunks(dims, max_context: int):
+        """Chunks of :data:`PREFILL_CHUNK` rows, which hand on the
+        prompt as its later chunks read it: every layer's keys, values
+        and cumulative log-gates, for prompts up to ``max_context``."""
+        rows = min(PREFILL_CHUNK, max_context)
+        return rows, retention.zero_history(
+            dims.n_layers, -(-max_context // rows) * rows,
+            dims.n_kv_heads, _head_dim(dims),
+            dims.compute_dtype or "float32")
 
     def attend(self, li, mha, h):
         dims = self.dims
@@ -1045,12 +1201,12 @@ class PagedState(_Rows):
 
 
 class PagedSSM:
-    """One position a slot against a hybrid's state pool ``(H, tail)``:
-    slot ``s`` owns state page ``s + 1``; its state is updated in
-    place and read (``ops.ssm_decode``), its convolution's tail moved
-    on by one row. An inactive slot's state page is neither read nor
-    written, and its tail goes to the trash page. ``li`` counts the
-    Mamba layers (``decoder_infer.ByKind``)."""
+    """One position a slot against a hybrid's state pool ``(H, tail)``
+    (:class:`PagedHybrid`): the slot's state is updated in place and
+    read (``ops.ssm_decode``), its convolution's tail moved on by one
+    row. An inactive slot's state page is neither read nor written,
+    and its tail goes to the trash page. ``li`` counts the Mamba
+    layers (``decoder_infer.ByKind``)."""
 
     def __init__(self, dims, pool, act):
         self.dims = dims
@@ -1147,16 +1303,57 @@ class HybridChunk(di.ByKind):
                 jnp.asarray(np.array(page_row, np.int32)))
 
 
-class PagedHybrid(di.ByKind):
-    """The step's cache object over a hybrid's pool ``(kv, H, tail)``:
-    an attention layer's rows go to :class:`PagedKV` over the KV
-    pages, a Mamba layer's to :class:`PagedSSM` over the state pages,
-    each under the layer's index among ITS kind."""
+class PagedHybrid(di.ByKind, _Rows):
+    """A HYBRID decoder's pages (``CausalTransformerLM(mixer=
+    "hybrid")``: Mamba-2 state-space layers beside softmax attention
+    layers, ``ops/ssm.py``), the pool tuple ``(kv, H, tail)``, each
+    array stacked over the layers of ITS kind only:
+
+    - ``kv`` ``[L_attn, P, block, Hkv, 2D]``: KV pages, for the
+      attention layers; allocated, reserved and freed by the free list;
+    - ``H`` ``[L_ssm, 1 + slots, N, H P]`` float32: a sequence's state
+      in each Mamba layer, the stored matrix of ``ops/ssm.py`` (row
+      ``n`` the state value ``n`` of every (head, feature) column),
+      which ``ops.ssm_decode`` streams through VMEM and writes back in
+      place;
+    - ``tail`` ``[L_ssm, 1 + slots, (K - 1) * channels]`` in the
+      compute dtype: the last ``K - 1`` un-convolved rows of each
+      layer's convolution, flat (one page is one lane-aligned run).
+
+    A sequence's ONE state page is ``slot + 1`` (page 0 the trash
+    page); it comes to its next sequence as its last one left it:
+    admission starts from an empty state when ``start`` is 0. As the
+    step's cache object, an attention layer's rows go to
+    :class:`PagedKV` over the KV pages, a Mamba layer's to
+    :class:`PagedSSM` over the state pages, each under the layer's
+    index among ITS kind. Admission is the chunk program
+    (``hybrid.chunk`` rows, :class:`HybridChunk`)."""
     walks_kv = True
     state = slice(1, None)
     chunk = HybridChunk
+    alone = ("a hybrid pool holds float KV pages beside float32 state "
+             "pages: cache_quant, state_rows and latent_dim do not apply")
+    serves = "mixer='hybrid'"
+    refuses = {"prefix_sharing": _SNAPSHOTS, "spec_k": _SNAPSHOTS}
 
     def __init__(self, dims, pool, pt, pos, act):
         super().__init__(
             dims.hybrid, softmax=PagedKV(dims, pool[:1], pt, pos, act),
             mamba2=PagedSSM(dims, pool[1:], act))
+
+    @staticmethod
+    def alloc(pager, named, dtype) -> Tuple:
+        spec, slots = named
+        n_ssm = len(spec.layers("mamba2"))
+        tail = (spec.d_conv - 1) * spec.conv_dim
+        # the state and the tail of every Mamba layer, both ways
+        pager.state_bytes_per_slot = 2 * n_ssm * (
+            4 * spec.d_state * spec.d_inner + tail * dtype.itemsize)
+        return PagedKV.alloc(pager, None, dtype) + (
+            jnp.zeros((n_ssm, 1 + slots, spec.d_state, spec.d_inner),
+                      jnp.float32),
+            jnp.zeros((n_ssm, 1 + slots, tail), dtype))
+
+    @staticmethod
+    def chunks(dims, max_context: int):
+        return min(dims.hybrid.chunk, max_context), ()

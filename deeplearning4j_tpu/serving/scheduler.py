@@ -17,7 +17,7 @@ decode contract, PAPERS.md: arxiv 2604.23467).
 Every program here is the ONE inference block and stack loop of
 ``nn/decoder_infer.py`` — the same ``generate()`` runs — over a cache
 object the pager builds for the program's pool
-(``kv_pager``: ``KVPager.rows``, ``.write_prompt``, ``.cache.chunk``): what a
+(``kv_pager``: ``KVPager.rows``, ``.cache.chunk``): what a
 layer's rows write and read is the whole difference between the dense
 path and this one, and the pool's layout is the pager's alone. The
 single-token step's attention is ``ops.paged_decode_attention``: on
@@ -44,48 +44,14 @@ sits in live pages ADOPTS them (refcount++), prefill runs only on the
 novel suffix, and any write to a page with refcount > 1 first clones
 it (copy-on-write) so siblings never observe the writer.
 
-A model of ``mixer="power_retention"`` blocks runs the same loop over
-a pool of fixed-size recurrent-state pages (one a sequence,
-``kv_pager.py``): admission runs ONE prefill program of
-:data:`PREFILL_CHUNK` rows ``ceil(t0 / chunk)`` times, carrying the
-state in the sequence's page, so no prompt needs a bucket as long as
-itself; the decode step updates every live slot's state in place
-(``ops.retention_decode``) and leaves an inactive slot's page alone.
-Prefix sharing and speculative decode would need snapshots of a state
-and are refused at construction for such a model.
-
-A model of ``mixer="hybrid"`` (Mamba-2 layers beside attention layers,
-``ops/ssm.py``) holds BOTH kinds of per-sequence state in the one
-pager: KV pages off the free list for its attention layers, and for
-its Mamba layers the state page that belongs to its decode slot.
-Admission is the same chunk program (``hybrid.chunk`` rows): a Mamba
-layer carries state and convolution tail in the state page, an
-attention layer runs the chunk's rows as the rows of one slot against
-the KV pages the chunks before have written. The decode step is the
-same ``stack`` over a cache object that goes by each layer's kind.
-``prefix_sharing``, ``spec_k > 1`` and ``cache_quant`` are refused for
-it, each for what it would need (state snapshots; an int8 state).
-
-A model of ``mixer="latent"`` blocks admits by the bucket prefill that
-softmax has (``decoder_infer.latent_prefill``: K and V expanded from
-each position's latent, the latent rows kept as the sequence's pages)
-and decodes by the same step over ``kv_pager.PagedLatent``; where its
-feed-forward routes (``ops/moe.py``) the step hands the held experts'
-pair counts back beside the tokens, in the one read that fetches
-them. ``prefix_sharing``, ``spec_k > 1`` and ``cache_quant`` are
-refused for it: a latent page's content-addressed sharing, a
-multi-row absorbed read and an int8 latent are not written yet.
-
-A model with WINDOWED softmax layers (``CausalTransformerLM(window=
-...)``) holds KV pages of two kinds in the one pager: pages off the
-free list for its full layers, reserved for a sequence's whole life
-as ever, and for its window layers the ring of pages that belongs to
-its decode slot. Admission is the bucket prefill; of a window layer's
-rows it keeps the last ``ring`` pages only
-(``KVPager.prompt_pages``). ``prefix_sharing``, ``spec_k > 1`` and
-``cache_quant`` are refused for it: a shared page that a ring
-overwrites, a rejected draft's row that has already overwritten a
-visible one, an int8 ring.
+What a sequence's cached context is MADE of (KV pages, a state page,
+latent rows, a hybrid's or a windowed decoder's two kinds) is the
+pager's: the class ``KVPager.cache`` is the kind of page, and it says
+which options it refuses, how a prompt is admitted (one bucket, or
+chunks of one program), what a bucket prefill keeps, what a step
+reads and the guards that hold for it alone (``kv_pager._Rows``).
+Where a model's feed-forward routes (``ops/moe.py``) the step hands
+the held experts' pair counts back with the tokens, in the one read.
 
 The loop keeps ONE decode step in flight: :meth:`DecodeScheduler.step`
 launches step n+1 from step n's device-resident outputs and only then
@@ -105,15 +71,13 @@ drives it); requests are duck-typed: ``.prompt`` (1-D int32),
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.nn import decoder_infer as di
-from deeplearning4j_tpu.ops.pallas_kernels import latent_chunk_pages
-from deeplearning4j_tpu.serving.kv_pager import (KVPager, PageTableError,
-                                                 ring_pages)
+from deeplearning4j_tpu.serving.kv_pager import KVPager, PageTableError
 from deeplearning4j_tpu.zoo.gpt import prompt_bucket
 
 #: every ``_build_*`` jitted entry point in this module must have an
@@ -157,11 +121,6 @@ WARMUP_FEEDS = {
 #: ``_build_spec_step_fn`` WARMUP_FEEDS entry and the warmup() body in
 #: lockstep (an off-grid k would cold-trace on the first spec step)
 SPEC_KS = (2, 4, 8)
-
-#: rows of a retention model's one prefill program (clamped to the
-#: gateway's ``max_context``): at 512 rows the weights' matmuls are
-#: bound by the MXU, not by reading the weights once a call
-PREFILL_CHUNK = 512
 
 
 class _Slot:
@@ -232,7 +191,6 @@ class DecodeScheduler:
             raise ValueError(f"block={self.block} must divide the "
                              "smallest prompt bucket (16)")
         self.max_context = mc
-        self.max_pages_per_seq = mc // self.block
         self.sample = bool(sample)
         self.top_k = top_k
         self.top_p = top_p
@@ -250,99 +208,28 @@ class DecodeScheduler:
                     "rule compares per-row argmax against the draft; "
                     "under sampling it would skew the distribution")
         self.prefix_sharing = bool(prefix_sharing)
-        hd = (getattr(model, "head_dim", None)
-              or model.hidden // model.n_heads)
-        #: a retention model: one fixed-size state page a sequence
-        self.recurrent = getattr(model, "mixer",
-                                 "softmax") == "power_retention"
-        #: a latent-attention model: one compressed row a position
-        self.latent = getattr(model, "latent", None)
-        if self.latent is not None:
-            for name, on, why in (
-                    ("prefix_sharing", self.prefix_sharing,
-                     "its multi-row suffix prefill reads KV heads"),
-                    ("spec_k", self.spec_k != 1,
-                     "the verify step's multi-row read has no "
-                     "absorbed form yet"),
-                    ("cache_quant", bool(model.cache_quant),
-                     "a latent row has no int8 form yet")):
-                if on:
-                    raise ValueError(f"{name} with mixer='latent': {why}")
-            #: positions of one (slot, chunk) item of the decode
-            #: kernel's page walk
-            self._latent_chunk_rows = self.block * latent_chunk_pages(
-                self.block, self.max_pages_per_seq)
+        kind, _ = KVPager.kind_of(model)
+        for name, on in (("prefix_sharing", self.prefix_sharing),
+                         ("spec_k", self.spec_k != 1),
+                         ("cache_quant", bool(model.cache_quant))):
+            if on and name in kind.refuses:
+                raise ValueError(
+                    f"{name} with {kind.serves}: {kind.refuses[name]}")
+        self.pager = KVPager.for_model(model, self.max_slots, self.block,
+                                       n_pages, mc)
+        #: entries of a sequence's row of the page table
+        self.max_pages_per_seq = self.pager.pages_for(mc)
+        #: the whole pool is recurrent state: one page a sequence
+        self.recurrent = kind.state == slice(None)
+        #: rows of the ONE prefill program the kind admits by (``None``:
+        #: the power-of-two buckets), and what the chunks of the prompt
+        #: being admitted hand on beside the pool
+        self.prefill_chunk, self._prefill_hist = kind.chunks(model, mc)
         #: expert layers of the model (their counts come back with
         #: every step's tokens)
         experts = getattr(model, "experts", None)
         self.expert_layers = (0 if experts is None
                               else model.n_layers - experts.first_dense)
-        state_rows = None
-        #: a windowed decoder's spec: ring pages beside full pages
-        self.windowed = getattr(model, "windowed", None)
-        if self.windowed is not None:
-            for name, on, why in (
-                    ("prefix_sharing", self.prefix_sharing,
-                     "a shared page of a window layer would be "
-                     "overwritten by its first owner's ring"),
-                    ("spec_k", self.spec_k != 1,
-                     "a rejected draft's row may already have "
-                     "overwritten a ring page a later query sees"),
-                    ("cache_quant", bool(model.cache_quant),
-                     "a ring page has no int8 form yet")):
-                if on:
-                    raise ValueError(
-                        f"{name} with windowed layers: {why}")
-        #: a hybrid decoder's spec: state pages beside KV pages
-        hybrid = getattr(model, "hybrid", None)
-        if self.recurrent or hybrid is not None:
-            for name, on in (("prefix_sharing", self.prefix_sharing),
-                             ("spec_k", self.spec_k != 1)):
-                if on:
-                    raise ValueError(
-                        f"{name} with mixer={model.mixer!r}: a "
-                        "recurrent state cannot be adopted at a page "
-                        "boundary nor rolled back after a rejected "
-                        "draft; both need an index of state "
-                        "snapshots, which this scheduler does not "
-                        "keep")
-            #: rows of the ONE prefill program such a model admits by
-            #: (``None``: the power-of-two buckets)
-            self.prefill_chunk = min(
-                PREFILL_CHUNK if hybrid is None else hybrid.chunk, mc)
-        else:
-            self.prefill_chunk = None
-        #: what the chunks of the prompt being admitted hand on beside
-        #: the pool (one admission at a time)
-        self._prefill_hist: Tuple = ()
-        if self.recurrent:
-            from deeplearning4j_tpu.ops.retention import (
-                state_rows as rows_of, zero_history)
-            state_rows = rows_of(hd)
-            self.max_pages_per_seq = 1
-            # the prompt as its later chunks read it: every layer's
-            # keys, values and cumulative log-gates, for prompts up to
-            # max_context
-            self._prefill_hist = zero_history(
-                model.n_layers,
-                -(-mc // self.prefill_chunk) * self.prefill_chunk,
-                model.n_kv_heads, hd, model.compute_dtype or "float32")
-        self.pager = KVPager(
-            n_layers=(len(self.windowed.layers("full"))
-                      if self.windowed is not None
-                      else model.n_layers if hybrid is None
-                      else len(hybrid.layers("softmax"))),
-            n_kv_heads=model.n_kv_heads,
-            head_dim=hd, block=self.block,
-            n_pages=(int(n_pages) if n_pages
-                     else 1 + self.max_slots * self.max_pages_per_seq),
-            cache_quant=model.cache_quant,
-            dtype=model.compute_dtype or "float32",
-            state_rows=state_rows,
-            latent_dim=None if self.latent is None else self.latent.row,
-            ssm=None if hybrid is None else (hybrid, self.max_slots),
-            **({} if self.windowed is None else
-               {"windowed": (self.windowed, self.max_slots)}))
         # per-slot host state, mirrored into the small int arrays the
         # fixed-shape step consumes each iteration
         self._slots: List[Optional[_Slot]] = [None] * self.max_slots
@@ -408,7 +295,7 @@ class DecodeScheduler:
         own = {k: getattr(self, k) for k in (
             "max_slots", "block", "max_context", "max_pages_per_seq",
             "prefill_chunk", "spec_k", "sample", "top_k", "top_p",
-            "seed", "prefix_sharing", "expert_layers", "recurrent")}
+            "seed", "prefix_sharing", "expert_layers")}
         dims = {k: v for k, v in vars(self.model).items()
                 if not k.startswith("_") and k not in ("seed", "updater")}
         return aot_store.describe({
@@ -441,7 +328,7 @@ class DecodeScheduler:
         from deeplearning4j_tpu.perf import sentry
 
         model = self.model
-        block_scope = "retention_decode" if self.recurrent else ""
+        block_scope = self.pager.cache.scope
 
         # pool is threaded through and returned so the caller rebinds
         # the pager's arrays (donation-friendly on accelerators)
@@ -540,10 +427,8 @@ class DecodeScheduler:
         def admit(params, pool, page_ids, prompt_pad, t0, temp, top_p,
                   ctr):
             kv = []
-            attend = (di.causal_prefill(
-                model, lambda li, k, v: kv.append((k, v)))
-                if self.latent is None else di.latent_prefill(
-                    model, lambda li, rows: kv.append(rows)))
+            attend = self.pager.cache.prefill(
+                model, lambda li, *kept: kv.append(kept))
             pairs = [] if self.expert_layers else None
             # the experts route the prompt's rows, not the bucket's
             # padding
@@ -552,10 +437,7 @@ class DecodeScheduler:
                          live=jnp.arange(prompt_pad.shape[1])[None] < t0)
             row = jax.lax.dynamic_index_in_dim(x, t0 - 1, axis=1,
                                                keepdims=False)
-            out = (self.pager.write_prompt(
-                pool, page_ids, kv,
-                **({} if self.windowed is None
-                   else {"spec": self.windowed})),
+            out = (self.pager.cache.write_prompt(model, pool, page_ids, kv),
                    self._first_token(params, row, "prefill", temp,
                                      top_p, ctr))
             return out + (sum(jnp.sum(p) for p in pairs),) if pairs \
@@ -565,19 +447,16 @@ class DecodeScheduler:
                           donate_argnums=(1,))
 
     def _build_chunk_admit_fn(self):
-        """A retention or hybrid model's prefill: ONE program of
+        """The prefill of a kind that admits by chunks: ONE program of
         ``prefill_chunk`` rows, run ``ceil(t0 / chunk)`` times for a
-        prompt of ``t0`` tokens. A call runs its rows by the chunked
-        form against the sequence's state page (the chunk class of
-        the pager's cache, ``KVPager.cache.chunk``; ``where`` is where
-        that class finds the sequence, ``carried`` what its chunks
-        hand on beside the pool):
-        after the last call the page holds the state after position
-        ``t0 - 1`` exactly, since rows at and past ``t0`` are masked
-        out of it (and a hybrid's attention layers have written the
-        KV of positions below ``t0`` into the sequence's pages).
-        The head runs only in the call that holds row ``t0 - 1``; the
-        others return token 0."""
+        prompt of ``t0`` tokens, each call against what the calls
+        before left (the chunk class of the pager's cache,
+        ``KVPager.cache.chunk``; ``where`` is where that class finds
+        the sequence, ``carried`` what its chunks hand on beside the
+        pool). After the last call the pool holds the sequence after
+        position ``t0 - 1`` exactly: rows at and past ``t0`` are masked
+        out of it. The head runs only in the call that holds row
+        ``t0 - 1``; the others return token 0."""
         import jax
         import jax.numpy as jnp
         from deeplearning4j_tpu.perf import sentry
@@ -686,12 +565,6 @@ class DecodeScheduler:
         tb = (0 if self.prefill_chunk     # padding rows write no page
               else prompt_bucket(t0, self.max_context))
         return self.pager.pages_for(max(tb, t0 + max_new - 1))
-
-    @property
-    def state_bytes_per_slot(self) -> int:
-        """Bytes of recurrent state one live slot's decode step reads
-        and writes (the pager's count; 0 for a model with none)."""
-        return self.pager.state_bytes_per_slot
 
     def free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -951,13 +824,10 @@ class DecodeScheduler:
         check of both addresses was two thirds of the scalar core's
         work a page), so a page number has to be the pager's own.
         Every entry of the page table lies under the pool's page
-        count (KV, state and latent pages alike; the trash page 0 is
-        one of them); a slot's length, with the budget it has left,
-        does not reach past what its row of the table can serve; a
-        window layer's ring, which the step cuts from the window
-        pool's shape (so its entries are the pool's own), is long
-        enough that a walk of ``window`` positions wraps once at most.
-        There is no switch. Raises :class:`kv_pager.PageTableError`."""
+        count (the trash page 0 is one of them), and what the kind of
+        page asks holds (``_Rows.check_feed``: a slot's row serves
+        its last position; a ring is long enough). There is no
+        switch. Raises :class:`kv_pager.PageTableError`."""
         pt, pager = self._page_table, self.pager
         bad = np.argwhere((pt < 0) | (pt >= pager.n_pages))
         if bad.size:
@@ -965,25 +835,9 @@ class DecodeScheduler:
             raise PageTableError(
                 f"slot {s}'s page-table entry {e} is {int(pt[s, e])}: "
                 f"the pool has pages 0..{pager.n_pages - 1}")
-        if pager.walks_kv or self.latent is not None:
-            room = pt.shape[1] * self.block
-            for i, slot in enumerate(self._slots):
-                if slot is None:
-                    continue
-                end = int(self._lengths[i]) + max(slot.remaining, 0)
-                if end > room:
-                    raise PageTableError(
-                        f"slot {i} reaches position {end}: its row of "
-                        f"{pt.shape[1]} pages serves {room}")
-        if self.windowed is not None:
-            held = pager.pool[1].shape[1]
-            need = ring_pages(self.windowed.window, self.block)
-            if (held - 1) // self.max_slots < need:
-                raise PageTableError(
-                    f"the window pool's {held} pages give each of "
-                    f"{self.max_slots} slots a ring of "
-                    f"{(held - 1) // self.max_slots}: a window of "
-                    f"{self.windowed.window} needs {need}")
+        pager.cache.check_feed(pager, self.model, pt, {
+            i: int(self._lengths[i]) + max(slot.remaining, 0)
+            for i, slot in enumerate(self._slots) if slot is not None})
 
     def step(self) -> int:
         """One continuous-batching iteration, one decode step in
@@ -1022,26 +876,11 @@ class DecodeScheduler:
         ts0 = obs.now()
         self._ctr += 1
         f = self._ensure_feed(act)
-        # the pages this step's attention walks (the position being
-        # written included), from the host's mirror and what the step
-        # in flight adds to it: no device read (a retention model
-        # walks no KV page: it moves its live slots' states, once
-        # each way; a hybrid does both)
-        kv_pages = int(np.sum((self._lengths[act] + pending)
-                              // self.block + 1)) if (
-                                  self.pager.walks_kv) else 0
-        state_bytes = len(act) * self.pager.state_bytes_per_slot
-        walks = self._window_walks(
-            self._lengths[act] + pending) if self.windowed else None
-        # cached positions a latent step's attention reads, the one
-        # being written included, and the (slot, chunk) items a
-        # layer's walk of them has (all but the first issued ahead)
-        latent_rows = latent_chunks = 0
-        if self.latent is not None:
-            lens = self._lengths[act] + pending + 1
-            latent_rows = int(np.sum(lens))
-            latent_chunks = int(np.sum(
-                -(-lens // self._latent_chunk_rows)))
+        # what the step reads, from the host's mirror and what the
+        # step in flight adds to it: no device read
+        reads = self.pager.cache.step_reads(
+            self.pager, self.model, self._lengths[act] + pending,
+            self.max_pages_per_seq)
         ts1 = obs.now()
         nxt, pool, len_next, *pairs = self._step_fn(
             self.model.decode_params(self.net), self.pager.pool,
@@ -1064,52 +903,14 @@ class DecodeScheduler:
         # that was read; the expert counts are that step's too (the
         # host learns them with its tokens), the others the launched
         # step's
-        args = {"active": len(act), "kv_pages": kv_pages,
-                "state_bytes": state_bytes, "ahead": pending}
-        if self.latent is not None:
-            args["latent_rows"] = latent_rows
-            args["latent_chunks"] = latent_chunks
+        args = {"active": len(act), **reads, "ahead": pending}
         if self.expert_layers:
             (args["expert_pairs"], args["experts_hit"],
              args["expert_pairs_max"]) = experts
-        if walks is not None:
-            args.update(walks)
-            obs.metrics.SERVING_KV_ROWS_READ.inc(walks["kv_rows_read"])
-            obs.metrics.SERVING_KV_ROWS_UNWINDOWED.inc(
-                walks["kv_rows_unwindowed"])
-            obs.metrics.SERVING_RING_OVERWRITES.inc(
-                walks["ring_overwrites"])
         obs.record_step("serving.decode_step", ts0, ts1, ts2, ts3,
                         args=args, cause=self.cause, end=obs.now())
-        obs.metrics.SERVING_KV_WALKED.set(kv_pages)
-        obs.metrics.SERVING_STATE_MOVED.inc(state_bytes)
-        obs.metrics.SERVING_LATENT_ROWS.inc(latent_rows)
         obs.metrics.SERVING_AHEAD.inc(pending)
         return n
-
-    def _window_walks(self, at) -> dict:
-        """What a windowed decoder's step reads, from the positions
-        ``at`` its live slots write (the host's mirror: no device
-        read): ``kv_pages_window``, the pages ONE window layer's walk
-        reads (``kv_pages`` is one full layer's); ``kv_rows_read``,
-        the cached positions all layers' walks must read (a full
-        layer ``at + 1``, a window layer the last ``window`` of
-        them), and ``kv_rows_unwindowed``, what they would read with
-        no window; ``ring_overwrites``, the ring pages this step
-        begins to write over."""
-        spec, block = self.windowed, self.block
-        n = np.asarray(at, np.int64) + 1
-        n_full = len(spec.layers("full"))
-        n_win = len(spec.layers("window"))
-        first = np.maximum(n - spec.window, 0) // block
-        return {
-            "kv_pages_window": int(np.sum(-(-n // block) - first)),
-            "kv_rows_read": int(n_full * n.sum() + n_win * np.minimum(
-                n, spec.window).sum()),
-            "kv_rows_unwindowed": int((n_full + n_win) * n.sum()),
-            "ring_overwrites": int(n_win * np.sum(
-                ((n - 1) % block == 0)
-                & ((n - 1) // block >= self.pager.ring)))}
 
     def _collect(self, fl: _InFlight) -> tuple:
         """Read one launched step's tokens (the blocking device sync)

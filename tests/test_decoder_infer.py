@@ -42,7 +42,7 @@ def _prefill(dims, pblk, pager, x, tables):
     """The padded prompts ``x [ROWS, 16, F]`` (``T0`` real rows each)
     into both layouts: -> (block output, dense caches, pool)."""
     pool = pager.pool
-    if pager.state_rows is None:
+    if pager.cache.chunk is None:
         dense = []
         out = di.block(pblk, x, di.causal_prefill(
             dims, lambda li, k, v: dense.append(di.dense_kv(
@@ -51,7 +51,8 @@ def _prefill(dims, pblk, pager, x, tables):
             kv = []
             di.block(pblk, x[r:r + 1], di.causal_prefill(
                 dims, lambda li, k, v: kv.append((k, v))), 0)
-            pool = pager.write_prompt(pool, jnp.asarray(tables[r, :2]), kv)
+            pool = pager.cache.write_prompt(
+                dims, pool, jnp.asarray(tables[r, :2]), kv)
         return out, dense, pool
     valid = jnp.broadcast_to(jnp.arange(16)[None] < T0, (ROWS, 16))
     rows = di.RetentionRows(

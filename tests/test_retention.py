@@ -13,7 +13,7 @@ import pytest
 
 from deeplearning4j_tpu.ops import retention as R
 from deeplearning4j_tpu.serving import DecodeScheduler
-from deeplearning4j_tpu.serving import scheduler as sched_mod
+from deeplearning4j_tpu.serving import kv_pager as pager_mod
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.zoo.gpt import CausalTransformerLM
 
@@ -202,7 +202,7 @@ def _served_logits(model, net, seq, t0, monkeypatch, chunk=16,
     programs: the chunked prefill into the sequence's state page, then
     THE paged block a position at a time over the pool. With
     ``state_dtype`` the pool is rounded to it after every program."""
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", chunk)
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", chunk)
     sched = DecodeScheduler(model, net, max_slots=3, block=16,
                             max_context=96)
     assert sched.prefill_chunk == chunk
@@ -287,7 +287,7 @@ def test_prefill_then_paged_decode_matches_the_reference_logits(
 def test_records_count_state_bytes_and_chunks(retention_lm, monkeypatch):
     from deeplearning4j_tpu import obs
     model, net = retention_lm
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     sched = DecodeScheduler(model, net, max_slots=2, block=16,
                             max_context=96)
     mark = obs.now()
@@ -304,7 +304,7 @@ def test_records_count_state_bytes_and_chunks(retention_lm, monkeypatch):
     # 2 slots x 2 layers x 2 kv heads x 136 rows x (16 + 1) x 4 bytes,
     # read and written
     per_slot = 2 * 4 * 2 * 2 * 136 * 17
-    assert sched.state_bytes_per_slot == per_slot
+    assert sched.pager.state_bytes_per_slot == per_slot
     assert step.counts["state_bytes"] == 2 * per_slot
     assert step.counts["kv_pages"] == 0
     assert (obs.metrics.SERVING_STATE_MOVED.snapshot()[""] - moved
@@ -345,7 +345,7 @@ def test_row_launched_ahead_rewrites_its_own_state_page_only(
     sequence, starts that one from an empty state."""
     from deeplearning4j_tpu import obs
     model, net = retention_lm
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     sched = DecodeScheduler(model, net, max_slots=2, block=16,
                             max_context=96)
     mark = obs.now()
@@ -366,7 +366,7 @@ def test_row_launched_ahead_rewrites_its_own_state_page_only(
            if r.name == "serving.decode_step"][-1]
     live = 2 if ends_by == "eos" else 1
     assert rec.counts["ahead"] == 1 and rec.counts["active"] == live
-    assert rec.counts["state_bytes"] == live * sched.state_bytes_per_slot
+    assert rec.counts["state_bytes"] == live * sched.pager.state_bytes_per_slot
     after = [np.asarray(a) for a in sched.pager.pool]
     changed = set()
     for a, b in zip(before, after):
@@ -397,7 +397,7 @@ def test_gateway_ends_every_stream_with_a_retention_step_in_flight(
     from deeplearning4j_tpu.resilience import faults
     from deeplearning4j_tpu.serving import SequenceAborted, ServingGateway
     model, net = retention_lm
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     gw = ServingGateway(model, net, max_slots=3, block=16,
                         max_context=96, start=False)
     rng = np.random.default_rng(5)

@@ -1220,8 +1220,8 @@ def test_retention_paged_decode_token_identical_to_dense(
     less than a chunk, a chunk exactly and several chunks, with the
     kernel in the step (forced, interpret mode; a 128-wide head) or the
     fallback."""
-    from deeplearning4j_tpu.serving import scheduler as sched_mod
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    from deeplearning4j_tpu.serving import kv_pager as pager_mod
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     if kernel:
         monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
         model = CausalTransformerLM(
@@ -1251,8 +1251,8 @@ def test_retention_state_pages_conserved_under_churn(retention,
     """Admit / step / evict churn over a pool with fewer pages than
     slots: one page a sequence, every invariant after every
     transition, the whole free list back at the end."""
-    from deeplearning4j_tpu.serving import scheduler as sched_mod
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    from deeplearning4j_tpu.serving import kv_pager as pager_mod
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     model, net = retention
     sched = DecodeScheduler(model, net, max_slots=4, block=16,
                             max_context=64, n_pages=4)
@@ -1290,8 +1290,8 @@ def test_retention_inactive_slot_state_is_untouched(retention,
     """A decode step reads and writes the live slots' pages only: the
     page of a sequence that has left, and every free page, come out of
     a step bit for bit as they went in."""
-    from deeplearning4j_tpu.serving import scheduler as sched_mod
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    from deeplearning4j_tpu.serving import kv_pager as pager_mod
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     model, net = retention
     sched = DecodeScheduler(model, net, max_slots=3, block=16,
                             max_context=64)
@@ -1384,8 +1384,8 @@ def test_step_in_flight_serves_the_tokens_of_the_drained_order(
     (same launches in the same order, so the same draws under
     sampling), and under greedy decoding what dense ``generate()``
     returns."""
-    from deeplearning4j_tpu.serving import scheduler as sched_mod
-    monkeypatch.setattr(sched_mod, "PREFILL_CHUNK", 16)
+    from deeplearning4j_tpu.serving import kv_pager as pager_mod
+    monkeypatch.setattr(pager_mod, "PREFILL_CHUNK", 16)
     model, net = tiny if mixer == "softmax" else retention
     kw = dict(max_slots=3, block=8 if mixer == "softmax" else 16,
               max_context=64)

@@ -584,9 +584,9 @@ def test_the_pool_stacks_each_kind_over_its_own_layers(hybrid_lm):
     assert kv.shape == (2, 1 + 3 * 6, 16, 2, 32)        # 2 attention
     assert state.shape == (4, 1 + 3, 16, 128)           # 4 Mamba
     assert tail.shape == (4, 1 + 3, 3 * 160)
-    assert sched.pager.walks_kv
+    assert sched.pager.cache.walks_kv
     per_slot = 2 * 4 * (4 * 16 * 128 + 4 * 3 * 160)
-    assert sched.state_bytes_per_slot == per_slot
+    assert sched.pager.state_bytes_per_slot == per_slot
     assert sched.pager.state_pool_bytes() == 4 * 4 * (
         4 * 16 * 128 + 4 * 3 * 160)
     assert (obs.metrics.SERVING_STATE_POOL.snapshot()[""]
@@ -611,11 +611,11 @@ def test_records_count_state_bytes_and_kv_pages_together(hybrid_lm):
         (3, 16, 37), (1, 16, 9)]
     step = [r for r in recs if r.name == "serving.decode_step"][-1]
     assert step.counts["active"] == 2
-    assert step.counts["state_bytes"] == 2 * sched.state_bytes_per_slot
+    assert step.counts["state_bytes"] == 2 * sched.pager.state_bytes_per_slot
     # positions 37 and 9 are being written: 3 pages and 1
     assert step.counts["kv_pages"] == 4
     assert (obs.metrics.SERVING_STATE_MOVED.snapshot()[""] - moved
-            == 2 * sched.state_bytes_per_slot)
+            == 2 * sched.pager.state_bytes_per_slot)
     assert obs.metrics.SERVING_KV_WALKED.snapshot()[""] == 4
 
 

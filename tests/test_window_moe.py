@@ -908,7 +908,9 @@ def test_records_count_the_two_walks(window_lm, monkeypatch):
     model, net = window_lm
     sched = DecodeScheduler(model, net, max_slots=2, block=BLOCK,
                             max_context=128)
-    got = sched._window_walks(np.array([39, 7]))
+    walks = lambda at: sched.pager.cache.step_reads(
+        sched.pager, model, np.array(at), sched.max_pages_per_seq)
+    got = walks([39, 7])
     # live positions 40 and 8: a window layer reads 32 and 8 of them
     assert got["kv_rows_read"] == 1 * (40 + 8) + 3 * (32 + 8)
     assert got["kv_rows_unwindowed"] == 4 * (40 + 8)
@@ -916,8 +918,8 @@ def test_records_count_the_two_walks(window_lm, monkeypatch):
     assert got["kv_pages_window"] == 4 + 1
     assert got["ring_overwrites"] == 0
     # position 40 opens page 5 of a ring of 5: the first overwrite
-    assert sched._window_walks(np.array([40]))["ring_overwrites"] == 3
-    assert sched._window_walks(np.array([41]))["ring_overwrites"] == 0
+    assert walks([40])["ring_overwrites"] == 3
+    assert walks([41])["ring_overwrites"] == 0
 
 
 def test_gateway_serves_the_windowed_expert_model(window_lm):

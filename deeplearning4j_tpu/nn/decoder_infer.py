@@ -15,9 +15,12 @@ a hybrid decoder, whose layers differ in kind, gets ONE cache object
 that goes by each layer's kind (:class:`ByKind`).
 The dense layouts' cache objects live here, the paged pool's with the
 pager (``serving/kv_pager.py``). ``dims`` is anything with
-``n_layers``, ``n_heads``, ``n_kv_heads``, ``rope_theta`` (None: no
+``n_layers``, ``n_heads`` (with ``heads_by_layer`` a count a LAYER:
+:func:`layer_heads`), ``n_kv_heads``, ``rope_theta`` (None: no
 positional term; with ``rope_layers`` the layers that rotate, the
-others carrying no positions: :func:`layer_theta`), ``windowed`` (a
+others carrying no positions; with ``rope_by_kind`` an
+``ops.rotary.RopeRule`` a KIND of softmax layer, base, rotated width,
+frequencies and factor: :func:`layer_theta`), ``windowed`` (a
 :class:`WindowSpec`: the layers whose keys a sliding window bounds,
 :func:`layer_window`) and ``tie_embeddings``: the zoo model itself (a
 latent mixer's sizes are its ``latent``, its expert layers' its
@@ -32,7 +35,16 @@ and why the training block stays apart.
 
 The feed-forward is chosen by what a block's parameters HOLD, never by
 a flag: ``Wg``/``Wu``/``Wd`` a dense SwiGLU, ``moe`` the expert layer
-of ``ops/moe.py`` (this chip's experts beside the shared one).
+of ``ops/moe.py`` (this chip's experts beside the shared one). So is
+the attention's output gate: a mixer that holds ``Wog [F, H]`` has its
+heads' outputs multiplied by ``sigmoid(h1 Wog)``, a scalar a head, in
+front of ``Wo`` (:func:`head_gate`; the retention mixer's ``Wgate`` is
+its decay's, another thing).
+
+A decoder with ONE head count, ONE plain rotary rule and no gate runs
+none of what serves the others: its programs lower to the text they
+had before a second kind of softmax layer could differ from the first
+in anything but its window (``tests/test_serving_programs_pinned.py``).
 
 Imports ``ops/`` and ``nn/layers/``, never ``zoo/`` or ``serving/``.
 """
@@ -45,10 +57,12 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.attention import (
-    latent_attention_expanded, rotary_embedding, scaled_dot_attention)
+    latent_attention_expanded, rotary_embedding, rotary_tables,
+    rotary_turn, scaled_dot_attention)
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import devtime
 from deeplearning4j_tpu.ops import fused_norms, latent, moe, retention, ssm
+from deeplearning4j_tpu.ops.rotary import RopeRule
 
 
 def rms(x, gamma, eps=RMSNORM_EPS):
@@ -77,9 +91,13 @@ def rotary_rows(x, theta: float, pos):
     f32 angle math, same half-split pairing). Folding the two into one
     helper changes what the TPU compiler makes of the decode step
     (PERF.md §6, PR 28), so there are two. ``theta=None``: no
-    positional term, ``x`` as it is."""
+    positional term, ``x`` as it is; a :class:`RopeRule` in its place
+    turns by the rule (a program with several layers of one rule
+    makes its cos and sin once: :class:`Turns`)."""
     if theta is None:
         return x
+    if isinstance(theta, RopeRule):
+        return Turns(per_row=True)(x, theta, pos)
     half = x.shape[-1] // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # [N, D/2]
@@ -90,14 +108,49 @@ def rotary_rows(x, theta: float, pos):
                             x1 * sin + x2 * cos], axis=-1)
 
 
+class Turns:
+    """The rotations of ONE traced program: ``turns(x, rule, pos)``
+    rotates ``x`` by a layer's ``rule`` (:func:`layer_theta`) at the
+    program's positions, the same at every call: ``pos [N]`` one a row
+    of ``x [N, H, D]`` (``per_row``: a continuous batch) or the scalar
+    offset of ``x [B, T, H, D]``'s first position. A plain base or
+    None goes to :func:`rotary_rows` / ``rotary_embedding`` as it
+    always has; a :class:`RopeRule`'s cos and sin are made ONCE a
+    program from the positions, in float32, however many layers turn
+    by it, under the ``attn.rotary`` scope with the turns themselves."""
+
+    def __init__(self, per_row: bool):
+        self.per_row = per_row
+        self._tables = {}
+
+    def __call__(self, x, rule, pos=0):
+        if not isinstance(rule, RopeRule):
+            return (rotary_rows(x, rule, pos) if self.per_row
+                    else rotary_embedding(x, rule, offset=pos))
+        with devtime.scope("attn.rotary"):
+            key = (rule, x.shape[-1])
+            if key not in self._tables:
+                if not self.per_row:
+                    pos = pos + jnp.arange(x.shape[1], dtype=jnp.float32)
+                self._tables[key] = rotary_tables(
+                    pos, rule.inv_freq(x.shape[-1]), rule.factor)
+            cos, sin = self._tables[key]
+            if self.per_row:
+                return rotary_turn(x, cos[:, None, :], sin[:, None, :])
+            return rotary_turn(x, cos[None, :, None, :],
+                               sin[None, :, None, :])
+
+
 @dataclasses.dataclass(frozen=True)
 class WindowSpec:
     """A decoder whose softmax layers are of two KINDS: a ``"full"``
     layer's query sees every earlier key, a ``"window"`` layer's the
     last ``window`` keys only, its own included (key ``j`` is visible
     to query ``t`` iff ``t - window < j <= t``). One kind a layer; the
-    kinds share the mixer's parameters and arithmetic and differ in
-    what a cache must keep (:class:`ByKind`)."""
+    kinds differ in what a cache must keep (:class:`ByKind`) and, where
+    ``dims`` says so, in their head count (:func:`layer_heads`) and
+    their rotary rule (:func:`layer_theta`); the KV heads and a head's
+    width are the model's, so both kinds' pages are one shape."""
     window: int
     kinds: Tuple[str, ...]
 
@@ -127,11 +180,23 @@ class WindowSpec:
 
 
 def layer_theta(dims, li: int):
-    """Layer ``li``'s rotary base: ``dims.rope_theta``, or None (no
-    positional term) where ``dims.rope_layers`` names the rotated
-    layers and ``li`` is not among them."""
+    """Layer ``li``'s rotary rule: the base ``dims.rope_theta``, or
+    None (no positional term) where ``dims.rope_layers`` names the
+    rotated layers and ``li`` is not among them; where
+    ``dims.rope_by_kind`` gives the kinds of a windowed decoder a rule
+    each, the :class:`RopeRule` (or None) of the layer's kind."""
+    by_kind = getattr(dims, "rope_by_kind", None)
+    if by_kind is not None:
+        return by_kind[dims.windowed.kinds[li]]
     layers = getattr(dims, "rope_layers", None)
     return dims.rope_theta if layers is None or li in layers else None
+
+
+def layer_heads(dims, li=None) -> int:
+    """Layer ``li``'s query heads: ``dims.heads_by_layer[li]`` where
+    the layers differ, else ``dims.n_heads``."""
+    by_layer = getattr(dims, "heads_by_layer", None)
+    return dims.n_heads if by_layer is None or li is None else by_layer[li]
 
 
 def layer_window(dims, li: int):
@@ -157,14 +222,15 @@ def q_fold(dims, head_dim: int):
     return None if scale is None else float(scale) * head_dim ** 0.5
 
 
-def qkv(mha, h, dims, rotate):
+def qkv(mha, h, dims, rotate, li=None):
     """The softmax mixer's operands from normed rows ``h [..., F]``:
-    ``q [..., H, D]``, ``k [..., Hkv, D]`` (both rotated), ``v``
-    (``ops.retention.project`` is the retention mixer's). A published
-    score scale is folded into the query, so that every attention
-    behind this keeps its own ``d^-1/2``."""
+    ``q [..., H, D]`` (``H`` layer ``li``'s: :func:`layer_heads`), ``k
+    [..., Hkv, D]`` (both rotated), ``v`` (``ops.retention.project``
+    is the retention mixer's). A published score scale is folded into
+    the query, so that every attention behind this keeps its own
+    ``d^-1/2``."""
     lead = h.shape[:-1]
-    q = (h @ mha["Wq"]).reshape(*lead, dims.n_heads, -1)
+    q = (h @ mha["Wq"]).reshape(*lead, layer_heads(dims, li), -1)
     q = _times(q, q_fold(dims, q.shape[-1]))
     k = (h @ mha["Wk"]).reshape(*lead, dims.n_kv_heads, -1)
     v = (h @ mha["Wv"]).reshape(*lead, dims.n_kv_heads, -1)
@@ -182,6 +248,18 @@ def ffn(pblk, h, experts=None, live=None, route_rows=None):
                          route_rows=route_rows)
     h = jax.nn.silu(h @ pblk["Wg"]) * (h @ pblk["Wu"])
     return h @ pblk["Wd"], None
+
+
+def head_gate(a, h, w_gate):
+    """The mixer's output ``a [..., H D]`` with each head's ``D``
+    values times ``sigmoid(h W_gate)``'s scalar of that head (``h`` the
+    normed rows the mixer read, ``w_gate [F, H]``): the logits and the
+    sigmoid in float32 on ``[..., H]``, the product in ``a``'s dtype,
+    so that nothing ``[..., H D]`` wide is made in float32."""
+    with devtime.scope("attn.gate"):
+        g = jax.nn.sigmoid(jnp.dot(
+            h, w_gate, preferred_element_type=jnp.float32)).astype(a.dtype)
+        return (a.reshape(*g.shape, -1) * g[..., None]).reshape(a.shape)
 
 
 def _times(v, by):
@@ -205,11 +283,14 @@ def block(pblk, x, attend, li: int, experts=None, counts=None,
     ``residual_multiplier``; None: no multiply); ``eps`` is the two
     norms'. An expert layer whose router sits BEFORE the mixer
     (``experts.route_before_mixer``) routes by the pre-attention
-    normed rows."""
+    normed rows. A mixer that holds ``Wog`` has its output gated a
+    head (:func:`head_gate`)."""
     mha = pblk["mha"]
     with devtime.scope(f"{scope}.mixer"):
         h1 = rms(x, pblk["ln1"]["gamma"], eps)
         a = attend(li, mha, h1)
+        if "Wog" in mha:
+            a = head_gate(a, h1, mha["Wog"])
         x = x + _times(a @ mha["Wo"], residual)
         if "bo" in mha:
             x = x + _times(mha["bo"], residual)
@@ -364,12 +445,12 @@ class _AtPosition:
         self.dims = dims
         self.caches = list(caches)
         self.pos = pos
+        self.turns = Turns(per_row=False)
 
     def rotate(self, z, li=None):       # [rows, heads, d]
         theta = (self.dims.rope_theta if li is None
                  else layer_theta(self.dims, li))
-        return rotary_embedding(z[:, None], theta,
-                                offset=self.pos)[:, 0]
+        return self.turns(z[:, None], theta, self.pos)[:, 0]
 
 
 class DenseKV(_AtPosition):
@@ -384,7 +465,7 @@ class DenseKV(_AtPosition):
     def attend(self, li, mha, h):
         dims, pos = self.dims, self.pos
         rows, dt = h.shape[0], h.dtype
-        q, k, v = qkv(mha, h, dims, lambda z: self.rotate(z, li))
+        q, k, v = qkv(mha, h, dims, lambda z: self.rotate(z, li), li)
         n_kv, hd = k.shape[1:]
         kv = jnp.concatenate([k, v], axis=2)        # [rows, Kv, 2D]
         ckv = self.caches[li]
@@ -419,7 +500,7 @@ class DenseKV(_AtPosition):
         # grouped einsums attend straight against the SMALL cache
         # (GQA's cache-bandwidth saving survives decode: no
         # [rows, total, H, hd] broadcast is ever materialised)
-        qg = q.reshape(rows, n_kv, dims.n_heads // n_kv, hd)
+        qg = q.reshape(rows, n_kv, q.shape[1] // n_kv, hd)
         s = jnp.einsum("bkgd,bkdt->bkgt", qg, ck) / jnp.sqrt(
             jnp.asarray(hd, dt))
         if k_scale is not None:
@@ -461,9 +542,11 @@ def causal_prefill(dims, keep):
     decode overwrites row ``p`` before attending at ``p``. A layer
     rotates, and bounds its keys by a window, as ``dims`` says of it
     (:func:`layer_theta`, :func:`layer_window`)."""
+    turns = Turns(per_row=False)
+
     def attend(li, mha, h):
-        q, k, v = qkv(mha, h, dims, lambda z: rotary_embedding(
-            z, layer_theta(dims, li)))
+        q, k, v = qkv(mha, h, dims,
+                      lambda z: turns(z, layer_theta(dims, li)), li)
         keep(li, k, v)
         spec = getattr(dims, "windowed", None)
         if spec is None:

@@ -36,16 +36,57 @@ def _merge_heads(x):
     return x.reshape(b, t, h * d)
 
 
-def rotary_embedding(x, theta: float = 10000.0, offset=0):
+def rotary_tables(pos, inv_freq, factor: float = 1.0):
+    """``cos, sin [len(pos), len(inv_freq)]`` of positions ``pos``
+    against the frequencies ``inv_freq``, float32, both times
+    ``factor`` (a published ``attention_factor``; 1: no multiply)."""
+    ang = pos.astype(jnp.float32)[:, None] * jnp.asarray(
+        inv_freq, jnp.float32)[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return cos, sin
+
+
+def rotary_turn(x, cos, sin):
+    """``x [..., D]`` with its leading ``R = 2 cos.shape[-1]`` features
+    turned, feature ``i`` with ``i + R/2`` (the half-split pairing
+    INSIDE the rotated features), the other ``D - R`` as they are;
+    ``cos``/``sin`` broadcast against ``x``'s leading axes."""
+    half = cos.shape[-1]
+    cos, sin = cos.astype(x.dtype), sin.astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    turned = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if 2 * half < x.shape[-1]:
+        turned.append(x[..., 2 * half:])
+    return jnp.concatenate(turned, axis=-1)
+
+
+def rotary_embedding(x, theta: float = 10000.0, offset=0, *,
+                     rotary_dim=None, inv_freq=None, factor: float = 1.0):
     """Rotary position embedding (RoPE) on [B, T, H, D] (D even):
     HALF-SPLIT pairing (GPT-NeoX convention — feature i rotates with
     feature i + D/2, NOT the interleaved (i, i+1) GPT-J convention;
     permute Wq/Wk columns when importing interleaved-RoPE weights).
     Scores depend only on RELATIVE position — the modern long-context
     positional scheme. ``offset`` shifts the position index (KV-cache
-    decoding). ``theta=None``: no positional term, ``x`` as it is."""
+    decoding). ``theta=None``: no positional term, ``x`` as it is.
+    A rule that is not the plain one (``ops.rotary.RopeRule``'s
+    fields): ``rotary_dim`` leading features of a head turn (feature
+    ``i`` with ``i + rotary_dim / 2``) and the rest pass; ``inv_freq``
+    ``[rotary_dim / 2]`` replaces ``theta``'s frequencies; ``factor``
+    multiplies cos and sin."""
     if theta is None:
         return x
+    if rotary_dim is not None or inv_freq is not None or factor != 1.0:
+        half = (rotary_dim or x.shape[-1]) // 2
+        if inv_freq is None:
+            inv_freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32)
+                                 / half)
+        cos, sin = rotary_tables(
+            offset + jnp.arange(x.shape[1], dtype=jnp.float32), inv_freq,
+            factor)
+        return rotary_turn(x, cos[None, :, None, :], sin[None, :, None, :])
     b, t, h, d = x.shape
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -178,6 +219,14 @@ class MultiHeadAttention(Layer):
     #: decoder whose heads' joined width differs from its hidden
     #: width: ``Wq [n_in, H d]``, ``Wo [H d, n_out]``)
     head_dim: Optional[int] = None
+    #: an ``ops.rotary.RopeRule`` (or the dict a serialized layer
+    #: carries) where the layer's rotation is not ``rope_theta``'s
+    #: plain one over the whole head: its base, rotated width,
+    #: frequencies and factor replace ``rope_theta``
+    rope_rule: Optional[Any] = None
+    #: a sigmoid gate a head on the attention's output, in front of
+    #: ``Wo``, read from the layer's input rows: ``Wog [n_in, H]``
+    gate: bool = False
 
     _SP_MODES = (None, "ring", "ulysses", "zigzag_ring")
 
@@ -259,8 +308,20 @@ class MultiHeadAttention(Layer):
         if self.project_out:
             params["Wo"] = wi(ko, (hd * self.n_heads, n_out), dtype)
             params["bo"] = jnp.zeros((n_out,), dtype)
+        if self.gate:
+            params["Wog"] = wi(jax.random.fold_in(key, 4),
+                               (n_in, self.n_heads), dtype)
         t = input_shape[0]
         return params, {}, (t, n_out)
+
+    def _rotate(self, z):
+        if self.rope_rule is None:
+            return rotary_embedding(z, self.rope_theta)
+        from deeplearning4j_tpu.ops.rotary import RopeRule
+        rule = RopeRule.of(self.rope_rule)
+        return rotary_embedding(
+            z, rule.theta, rotary_dim=rule.rotary_dim,
+            inv_freq=rule.inv_freq(z.shape[-1]), factor=rule.factor)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         n_kv = self.n_kv_heads or self.n_heads
@@ -268,12 +329,14 @@ class MultiHeadAttention(Layer):
         k = _split_heads(x @ params["Wk"], n_kv)
         v = _split_heads(x @ params["Wv"], n_kv)
         if self.rope:
-            q = rotary_embedding(q, self.rope_theta)
-            k = rotary_embedding(k, self.rope_theta)
+            q, k = self._rotate(q), self._rotate(k)
         if self.score_scale is not None:
             q = q * jnp.asarray(
                 self.score_scale * math.sqrt(q.shape[-1]), q.dtype)
-        o = _merge_heads(self._attend(q, k, v, mask))
+        o = self._attend(q, k, v, mask)
+        if self.gate:
+            o = o * jax.nn.sigmoid(x @ params["Wog"])[..., None]
+        o = _merge_heads(o)
         if self.project_out:
             o = o @ params["Wo"] + params["bo"]
         if mask is not None:
@@ -674,6 +737,11 @@ class TransformerDecoderBlock(Layer):
     window: Optional[int] = None
     #: a softmax head's width where it is not ``n_in / n_heads``
     head_dim: Optional[int] = None
+    #: the softmax mixer's rotary rule where it is not ``rope_theta``'s
+    #: plain one (an ``ops.rotary.RopeRule`` or its dict), and whether
+    #: a sigmoid gate a head multiplies its output in front of ``Wo``
+    rope_rule: Optional[Any] = None
+    attn_gate: bool = False
 
     def _subs(self):
         if not hasattr(self, "_mha"):
@@ -717,7 +785,10 @@ class TransformerDecoderBlock(Layer):
                     **({} if self.window is None
                        else {"window": self.window}),
                     **({} if self.head_dim is None
-                       else {"head_dim": self.head_dim}))
+                       else {"head_dim": self.head_dim}),
+                    **({} if self.rope_rule is None
+                       else {"rope_rule": self.rope_rule}),
+                    **({"gate": True} if self.attn_gate else {}))
             eps = {} if self.norm_eps is None else {"eps": self.norm_eps}
             self._ln1 = RMSNorm(**eps)
             self._ln2 = RMSNorm(**eps)

@@ -12,7 +12,8 @@ Per position, ``h`` the normed input of width F, H heads:
 - ``[c_kv | k_rope] = h Wkva`` (``kv_rank + rope``); ``c_kv <-
   RMSNorm(c_kv)``; ``k_rope`` is ONE key shared by all heads;
 - rotary positions on ``q_rope`` and ``k_rope``, ADJACENT features
-  (2i, 2i+1) paired, with YaRN frequencies (:func:`yarn_inv_freq`);
+  (2i, 2i+1) paired, with YaRN frequencies
+  (``ops.rotary.yarn_inv_freq`` over the ``rope`` features);
 - the position's **latent row** is ``[c_kv (normed) | k_rope
   (rotated)]``, ``kv_rank + rope`` values: all a cache keeps;
 - ``[k_nope | v] = c_kv Wkvb`` (H heads of ``nope + v``); scores
@@ -40,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.ops import fused_norms
+from deeplearning4j_tpu.ops.rotary import yarn_inv_freq
 
 
 def lanes(n: int) -> int:
@@ -80,33 +82,6 @@ class LatentSpec:
         return cls(**value)
 
 
-def yarn_inv_freq(spec: LatentSpec, theta: float) -> np.ndarray:
-    """The ``rope / 2`` rotary frequencies. Under YaRN each is a blend
-    of the original frequency and the one interpolated by ``factor``:
-    a linear ramp over the correction range between the dimensions
-    that turn ``beta_fast`` and ``beta_slow`` times within the
-    original context (frequencies faster than the first keep their
-    value, slower than the second are divided by ``factor``)."""
-    dim = spec.rope
-    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if spec.yarn is None:
-        return (1.0 / pos_freqs).astype(np.float32)
-    factor, original, beta_fast, beta_slow = spec.yarn[:4]
-
-    def correction_dim(turns):
-        return dim * math.log(original / (turns * 2 * math.pi)) / (
-            2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    keep = 1.0 - ramp       # 1: the original frequency is kept
-    inv = (1.0 / (factor * pos_freqs)) * (1.0 - keep) + (
-        1.0 / pos_freqs) * keep
-    return inv.astype(np.float32)
-
-
 def softmax_scale(spec: LatentSpec) -> float:
     """``(nope + rope)^-1/2 * m^2``, ``m = 0.1 mscale_all_dim
     ln(factor) + 1`` under YaRN (1 without)."""
@@ -140,8 +115,8 @@ def project(mha, h, spec: LatentSpec, n_heads: int, theta: float, pos):
     q = (_rms(h @ mha["Wqa"], mha["qa_gamma"]) @ mha["Wqb"]).reshape(
         n, n_heads, spec.nope + spec.rope)
     kva = h @ mha["Wkva"]
-    ang = (pos.astype(jnp.float32)[:, None]
-           * jnp.asarray(yarn_inv_freq(spec, theta))[None, :])
+    inv_freq = jnp.asarray(yarn_inv_freq(spec.rope, theta, spec.yarn))
+    ang = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
     row = jnp.concatenate(
         [_rms(kva[:, :spec.kv_rank], mha["kv_gamma"]),
          rotate(kva[:, spec.kv_rank:], ang)], axis=-1)

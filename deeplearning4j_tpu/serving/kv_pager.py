@@ -49,7 +49,10 @@ layout with its class: ONE fixed-size recurrent-state page a sequence
 (:class:`PagedState`), one compressed latent row a position
 (:class:`PagedLatent`), a hybrid's KV pages beside its decode slot's
 state page (:class:`PagedHybrid`), a windowed decoder's full pages
-beside its decode slot's ring (:class:`PagedWindowed`). Allocation,
+beside its decode slot's ring (:class:`PagedWindowed`: the two kinds
+of softmax layer may differ in their query heads and in their rotary
+rule, never in their KV heads or a head's width, so a page of either
+kind is one shape). Allocation,
 reservation, the free list and every invariant below are the same
 for all; what belongs to a decode slot has no free list, no refcount
 and no invariant of its own: the slot IS the reservation, what can
@@ -670,11 +673,15 @@ class PagedKV(_Rows):
     (``cache_quant``) is a choice inside this kind."""
     walks_kv = True
 
-    def __init__(self, dims, pool, pt, pos, act, layers=None):
+    def __init__(self, dims, pool, pt, pos, act, layers=None,
+                 turns=None):
         super().__init__(dims, pool, pt, pos, act)
         self.layers = layers
         #: positions a query sees (None: all)
         self.window = None
+        #: the program's rotations (``decoder_infer.Turns``: a rule's
+        #: cos and sin are made once, for both kinds' layers)
+        self.turns = turns or di.Turns(per_row=True)
 
     @staticmethod
     def alloc(pager, named, dtype) -> Tuple:
@@ -730,15 +737,17 @@ class PagedKV(_Rows):
         dims, pool, pt, pos = self.dims, self.pool, self.pt, self.pos
         S, R = pos.shape
         pflat = pos.reshape(S * R)
-        theta = (dims.rope_theta if self.layers is None
-                 else di.layer_theta(dims, self.layers[li]))
-        q, k, v = di.qkv(mha, h, dims, lambda z: di.rotary_rows(
-            z, theta, pflat))
+        # (the model's layer: a head count or a rotation may differ by it)
+        at = None if self.layers is None else self.layers[li]
+        theta = (dims.rope_theta if at is None
+                 else di.layer_theta(dims, at))
+        q, k, v = di.qkv(mha, h, dims,
+                         lambda z: self.turns(z, theta, pflat), at)
         n_kv, hd = k.shape[1:]
         if pool[0].ndim == 4:
             return self._attend_folded(li, q, k, v)
         block = pool[0].shape[2]
-        q = q.reshape(S, R, dims.n_heads, hd)
+        q = q.reshape(S, R, -1, hd)
         kv = jnp.concatenate([k.reshape(S, R, n_kv, hd),
                               v.reshape(S, R, n_kv, hd)],
                              axis=3)                    # [S, R, Kv, 2D]
@@ -798,11 +807,11 @@ class PagedWindowKV(PagedKV):
     position, masks that page's head and the last one's stale tail,
     and takes ``ring`` pages at most."""
 
-    def __init__(self, dims, pool, pos, act, layers):
+    def __init__(self, dims, pool, pos, act, layers, turns=None):
         ring = (pool[0].shape[1] - 1) // pos.shape[0]
         base = 1 + ring * jnp.arange(pos.shape[0], dtype=jnp.int32)
         super().__init__(dims, pool, base[:, None] + jnp.arange(
-            ring, dtype=jnp.int32)[None, :], pos, act, layers)
+            ring, dtype=jnp.int32)[None, :], pos, act, layers, turns)
         self.ring = ring
         self.window = dims.windowed.window
 
@@ -870,9 +879,14 @@ class PagedWindowed(di.ByKind, _Rows):
     window layer's to :class:`PagedWindowKV` over the slot's ring,
     each under the layer's index among ITS kind and under a scope of
     its kind (``attn.full``, ``attn.window``: the two page walks'
-    device times are told apart by it). Admission is the bucket
-    prefill; of a window layer's rows it keeps the last ``ring`` pages
-    only."""
+    device times are told apart by it). The kinds may differ in their
+    query heads and in their rotary rule (``dims.heads_by_layer``,
+    ``dims.rope_by_kind``: a layer's rows are projected and turned by
+    ITS layer's, ``decoder_infer.layer_heads`` / ``layer_theta``); the
+    KV heads and a head's width are the model's, so the two pools'
+    pages are one shape and the walks differ in the query group alone.
+    Admission is the bucket prefill; of a window layer's rows it keeps
+    the last ``ring`` pages only."""
     walks_kv = True
     alone = ("a windowed pool holds float KV pages of two kinds: "
              "cache_quant, state_rows, latent_dim and ssm do not apply")
@@ -889,12 +903,13 @@ class PagedWindowed(di.ByKind, _Rows):
         if pos.shape[1] != 1:
             raise ValueError("a windowed pool serves one position a "
                              "slot (no multi-row program)")
+        turns = di.Turns(per_row=True)
         super().__init__(
             spec,
             full=_Scoped(PagedKV(dims, pool[:1], pt, pos, act,
-                                 spec.layers("full")), "attn.full"),
+                                 spec.layers("full"), turns), "attn.full"),
             window=_Scoped(PagedWindowKV(dims, pool[1:], pos, act,
-                                         spec.layers("window")),
+                                         spec.layers("window"), turns),
                            "attn.window"))
 
     @staticmethod

@@ -32,6 +32,7 @@ from deeplearning4j_tpu.nn.layers import (EmbeddingSequenceLayer,
 from deeplearning4j_tpu.nn import decoder_infer as di
 from deeplearning4j_tpu.nn import updaters as upd
 from deeplearning4j_tpu.ops import moe, retention
+from deeplearning4j_tpu.ops.rotary import RopeRule
 
 
 def prompt_bucket(t0: int, max_len: Optional[int] = None) -> int:
@@ -130,7 +131,9 @@ class CausalTransformerLM(ZooModel):
                  attention_multiplier: Optional[float] = None,
                  norm_eps: Optional[float] = None,
                  window: Optional[int] = None, window_layers=None,
-                 rope_layers=None, head_dim: Optional[int] = None):
+                 rope_layers=None, head_dim: Optional[int] = None,
+                 heads_by_layer=None, rope_by_kind=None,
+                 attn_gate: bool = False):
         # the blocks' sequence mixer: "softmax" attention over a KV
         # cache, "power_retention" (ops/retention.py): a fixed-size
         # recurrent state per sequence, whatever its length, "latent"
@@ -144,7 +147,15 @@ class CausalTransformerLM(ZooModel):
         # bounds the keys a softmax layer's query sees to the last
         # ``window``, its own included, in the layers ``window_layers``
         # names (None: all): one more KIND of softmax layer, not a
-        # mixer (what differs is what a cache must keep).
+        # mixer (what differs is what a cache must keep). Properties
+        # of those two KINDS, where a published decoder's differ:
+        # ``heads_by_layer`` the query heads a LAYER (None: ``n_heads``
+        # everywhere; the KV heads and ``head_dim`` are the model's),
+        # ``rope_by_kind`` a rotary rule a kind (``{"full": RopeRule |
+        # None, "window": ...}``: base, rotated width, YaRN, factor; in
+        # ``rope_theta``/``rope_layers``' place), ``attn_gate`` a
+        # sigmoid gate a head on every softmax layer's output in front
+        # of ``Wo`` (``mha["Wog"]``).
         # What a published decoder multiplies by, whatever its mixers
         # (each None: not applied, no multiply in any program): the
         # embedding's rows by ``embedding_multiplier``, each half's
@@ -187,6 +198,35 @@ class CausalTransformerLM(ZooModel):
                 "serve_quant and sequence_parallel do not apply to it")
         if head_dim is not None and mixer != "softmax":
             raise ValueError("head_dim is the softmax mixer's")
+        if (heads_by_layer is not None or rope_by_kind is not None
+                or attn_gate) and mixer != "softmax":
+            raise ValueError("heads_by_layer, rope_by_kind and attn_gate "
+                             "are the softmax mixer's")
+        n_kv = n_kv_heads or n_heads
+        if heads_by_layer is not None and (
+                len(heads_by_layer) != n_layers or head_dim is None
+                or any(h % n_kv for h in heads_by_layer)):
+            raise ValueError(
+                f"heads_by_layer={tuple(heads_by_layer)} names a count of "
+                f"whole query groups over {n_kv} KV heads for each of "
+                f"{n_layers} layers, beside a head_dim")
+        if rope_by_kind is not None and (
+                window is None or rope_layers is not None
+                or set(rope_by_kind) != set(di.WindowSpec.KINDS)):
+            raise ValueError(
+                "rope_by_kind gives each kind of a windowed decoder "
+                f"({' and '.join(di.WindowSpec.KINDS)}) its rule, in "
+                "rope_layers' place")
+        #: the query heads a layer (None: ``n_heads`` in every layer)
+        self.heads_by_layer = (None if heads_by_layer is None
+                               else tuple(int(h) for h in heads_by_layer))
+        #: an ``ops.rotary.RopeRule`` (or None: no positions) a kind of
+        #: softmax layer, where the kinds rotate differently
+        self.rope_by_kind = (None if rope_by_kind is None else {
+            kind: None if rule is None else RopeRule.of(rule)
+            for kind, rule in rope_by_kind.items()})
+        #: whether the softmax layers hold a gate a head (``Wog``)
+        self.attn_gate = bool(attn_gate)
         #: a softmax head's width where it is not ``hidden / n_heads``
         self.head_dim = head_dim
         if window_layers is not None and window is None:
@@ -287,10 +327,15 @@ class CausalTransformerLM(ZooModel):
         for i in range(self.n_layers):
             routed = (self.experts is not None
                       and i >= self.experts.first_dense)
+            rule = di.layer_theta(self, i)
+            by_rule = isinstance(rule, RopeRule)
             b.layer(TransformerDecoderBlock(
-                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+                n_heads=di.layer_heads(self, i),
+                n_kv_heads=self.n_kv_heads,
                 ffn_mult=self.ffn_mult,
-                rope_theta=di.layer_theta(self, i),
+                rope_theta=rule.theta if by_rule else rule,
+                rope_rule=rule if by_rule else None,
+                attn_gate=self.attn_gate,
                 window=di.layer_window(self, i),
                 head_dim=self.head_dim,
                 dropout=self.dropout or None, remat=self.remat,

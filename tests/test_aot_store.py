@@ -39,6 +39,7 @@ from deeplearning4j_tpu.obs import trace
 from deeplearning4j_tpu.ops import latent as L
 from deeplearning4j_tpu.ops import moe as M
 from deeplearning4j_tpu.ops import ssm
+from deeplearning4j_tpu.ops.rotary import RopeRule
 from deeplearning4j_tpu.perf import aot_store, compile_cache, sentry
 from deeplearning4j_tpu.serving import DecodeScheduler
 from deeplearning4j_tpu.zoo.gpt import CausalTransformerLM
@@ -358,6 +359,13 @@ SCHED_CASES = {
     "model.window_layers": ({}, dict(window=8, window_layers=[0])),
     "model.rope_layers": ({}, dict(rope_layers=[0])),
     "model.head_dim": ({}, dict(head_dim=8)),
+    "model.heads_by_layer": ({}, dict(window=8, head_dim=16,
+                                      heads_by_layer=[4])),
+    "model.rope_by_kind": ({}, dict(window=8, rope_by_kind={
+        "full": RopeRule(theta=1e4),
+        "window": RopeRule(theta=1e4, rotary_dim=8,
+                           yarn=(4.0, 16.0, 4.0, 1.0), factor=1.1)})),
+    "model.attn_gate": ({}, dict(attn_gate=True)),
 }
 #: what a served program reads only as arguments, or not at all: the
 #: key must NOT move with these, or every new seed is a cold start

@@ -60,11 +60,10 @@ def latent_lm():
 # -- ops/latent.py -------------------------------------------------------
 
 def test_yarn_frequencies_ramp_between_the_two():
-    plain = L.yarn_inv_freq(L.LatentSpec(48, 32, 16, 64, 16), 1e4)
+    plain = L.yarn_inv_freq(64, 1e4)
     np.testing.assert_allclose(plain, 1e4 ** (-np.arange(32) / 32),
                                rtol=1e-6)
-    yarn = L.yarn_inv_freq(L.LatentSpec(
-        48, 32, 16, 64, 16, yarn=(40.0, 4096, 32.0, 1.0, 1.0, 1.0)), 1e4)
+    yarn = L.yarn_inv_freq(64, 1e4, (40.0, 4096, 32.0, 1.0, 1.0, 1.0))
     # fast frequencies keep their value, slow ones are divided by the
     # factor, and between them the blend falls monotonically
     np.testing.assert_allclose(yarn[:10], plain[:10], rtol=1e-6)
@@ -90,7 +89,7 @@ def test_rotation_pairs_adjacent_features_and_is_relative():
     # p - j alone
     rng = np.random.default_rng(0)
     q, k = rng.normal(size=(2, 1, 8)).astype(np.float32)
-    freq = jnp.asarray(L.yarn_inv_freq(SPEC, 1e4))
+    freq = jnp.asarray(L.yarn_inv_freq(SPEC.rope, 1e4, SPEC.yarn))
     dot = lambda p, j: float(jnp.sum(L.rotate(q, p * freq[None])
                                      * L.rotate(k, j * freq[None])))
     assert dot(9.0, 4.0) == pytest.approx(dot(25.0, 20.0), rel=1e-4)

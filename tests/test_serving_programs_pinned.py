@@ -1,4 +1,4 @@
-"""The serving programs of the five decoders the benchmark holds,
+"""The serving programs of the six decoders the benchmark holds,
 pinned by the sha256 of their lowered text.
 
 A PR that adds a kind of layer, page or expert rule beside these must
@@ -31,6 +31,12 @@ issue, wait and search loops into helpers it shares with the KV walk,
 and its text did not change), the KV walk's is PR 48's. The
 SmallThinker cell's programs (PR 46, with PR 47's expert kernel not in
 them: toy experts are not lane-aligned) joined ``PINNED`` in PR 48.
+PR 50 gave the two kinds of softmax layer a head count and a rotary
+rule each and the attention an output gate (``decoder_infer.qkv``,
+``block``, ``Turns``; ``PagedKV.attend``), and no hash of the five
+earlier decoders moved: a decoder with one head count, one plain
+rule and no gate traces none of it. Its own cell's programs (the
+gated windowed decoder) are pinned from PR 50 on.
 """
 import hashlib
 import importlib.util
@@ -73,6 +79,12 @@ PINNED = {
         "kernels": {"step": "28a8d40a4afd12b5",
                     "admit16": "cefdeac01637db08",
                     "admit64": "95cac0be5747dad7"}},
+    "lagunaxs2.agent-saturated": {
+        "plain": {"step": "ea01ab8afcc793a5", "admit16": "a5376d17a1147d77",
+                  "admit64": "99cac54177b4dcd8"},
+        "kernels": {"step": "a440c7e9228865bb",
+                    "admit16": "76ab924a15c8a075",
+                    "admit64": "d26c2639d5e98fbd"}},
 }
 
 #: kernel form -> sha256[:16] of its jitted call's lowered text, in

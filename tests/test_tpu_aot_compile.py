@@ -933,3 +933,135 @@ def test_the_largest_expert_the_rule_takes_compiles_for_v5e(chip):
                         top_k=2, n_shared=0, score="softmax_topk",
                         unit="swiglu").compile().as_text()
     assert "moe_expert_tiles" in hlo
+
+
+# -- laguna-xs.2-5l: one dense and four sparse layers, full layers of 48 heads
+# beside window layers of 64 over 8 kv heads of 128, 256 experts of 512 ----
+
+def _gated_sched(chip, monkeypatch):
+    """The scheduler and the shapes of ``lagunaxs2.agent-saturated``:
+    the configuration as the benchmark's builder reads it, bf16 leaves
+    and float32 routers, nothing of the 3.87 B parameters and nothing
+    of the 4.74 GB of pools made."""
+    import json
+    from pathlib import Path
+    from benchmarks.models import gated_window_moe_lm as builder
+    from deeplearning4j_tpu.nn import updaters as upd
+    from deeplearning4j_tpu.ops.moe import FLOAT32_LEAVES
+    from deeplearning4j_tpu.serving import DecodeScheduler, kv_pager
+    from deeplearning4j_tpu.zoo import CausalTransformerLM
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmarks" / "configs"
+                      / "laguna-xs.2-5l.json").read_text())
+    experts, layers = builder.specs(cfg)
+    model = CausalTransformerLM(
+        vocab_size=cfg["vocab_size"], hidden=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        ffn_mult=cfg["intermediate_size"] / cfg["hidden_size"],
+        max_len=16384, rope_theta=None, tie_embeddings=False,
+        norm_eps=1e-6, updater=upd.Sgd(learning_rate=0.0),
+        compute_dtype="bfloat16", seed=1, experts=experts, **layers)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        jax.eval_shape(lambda: model.init().params))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        jax.ShapeDtypeStruct(
+            a.shape, jnp.float32 if getattr(path[-1], "key", None)
+            in FLOAT32_LEAVES else BF16, sharding=chip)
+        for path, a in flat])
+    with monkeypatch.context() as mp:
+        mp.setattr(kv_pager.jnp, "zeros",
+                   lambda shape, dtype=None: jax.ShapeDtypeStruct(
+                       shape, dtype, sharding=chip))
+        sched = DecodeScheduler(model, None, max_slots=48, block=16,
+                                max_context=11264)
+    return sched, params, sched.pager.pool
+
+
+def test_gated_windowed_decode_step_compiles_for_v5e_in_place(
+        chip, monkeypatch):
+    """The whole ``serving.decode_step`` of the gated windowed cell:
+    the page walk lowered once a KIND, and the kinds now differ in
+    their QUERY GROUP too (6 heads a KV head without a window in the 2
+    full layers, 8 with ``window=512`` over a ring of 33 pages in the
+    3 window layers), 5 calls over the two folded pools; the experts'
+    tiles one pipelined kernel in each of the 4 sparse layers (none in
+    the dense one); each layer's new row written in place; 12.48 GB of
+    arguments (weights 7.74, full pages 4.43, rings 0.31) and the
+    step's temporaries under 96 MB beside them."""
+    import re
+    sched, params, pool = _gated_sched(chip, monkeypatch)
+    assert [a.shape for a in pool] == [
+        (2, 1 + 48 * 704, 128, 256), (3, 1 + 48 * 33, 128, 256)]
+    lowered = sched._step_fn.lower(
+        params, pool,
+        *(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+          for a in sched._step_feed_shapes()))
+    funcs = re.findall(r"func\.func private @(\w*paged_decode\w*)",
+                       lowered.as_text())
+    assert len(funcs) == 2, funcs
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    walks = [ln for ln in calls if "paged_decode_attention" in ln]
+    assert sum("attn.full" in ln for ln in walks) == 2
+    assert sum("attn.window" in ln for ln in walks) == 3
+    assert sum("moe_expert_tiles" in ln for ln in calls) == 4
+    assert not [ln for ln in hlo.splitlines()
+                if " while(" in ln and "moe_experts" in ln]
+    for a in pool:
+        assert _pool_ops(hlo, a) <= {
+            "parameter", "get-tuple-element", "bitcast", "fusion",
+            "scatter", "dynamic-update-slice"}, _pool_ops(hlo, a)
+    # the gate: a [48, H] sigmoid and a product in the compute dtype;
+    # what the step WRITES under its scope is bf16 (a v5e multiplies
+    # bf16 in float32 inside a fusion: registers, not a copy)
+    gated = [ln.split(" = ")[1] for ln in hlo.splitlines()
+             if "attn.gate" in ln and " fusion(" in ln
+             and ln.startswith("  %")]
+    assert len(gated) >= 10 and all(g.startswith("bf16[48,")
+                                    for g in gated), gated
+    # cos and sin once a RULE, not once a layer
+    assert hlo.count(" cosine(") == 2 and hlo.count(" sine(") == 2
+    mem = compiled.memory_analysis()
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.56e9, mem
+    assert mem.alias_size_in_bytes >= 4.7e9     # both pools, in place
+    assert mem.temp_size_in_bytes < (96 << 20), mem
+
+
+def test_gated_windowed_bucket_prefill_compiles_for_v5e(chip, monkeypatch):
+    """The largest bucket (8,192 rows): the windowed flash kernel
+    (``window=512``, narrower than a KV block) over 64 heads in the
+    three window layers, the unwindowed one over 48 heads in the two
+    full layers, both pools written in place (a window layer's last 33
+    pages only) and, with the experts routing 4,096 rows at a time,
+    1.30 GB of temporaries beside the 12.49 GB of arguments: 13.79 of
+    the chip's 15.75 GB."""
+    sched, params, pool = _gated_sched(chip, monkeypatch)
+    i32 = jnp.int32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    ids = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                       sched.pager.prompt_pages_shapes(8192))
+    assert [a.shape for a in ids] == [(512,), (33,), (33,)]
+    compiled = sched._admit_fn(8192).lower(
+        params, pool, ids, sds((1, 8192), i32), sds((), i32),
+        sds((), jnp.float32), sds((), jnp.float32),
+        sds((), i32)).compile()
+    hlo = compiled.as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert sum("attn.window/dl4j.ops.flash_attention" in ln
+               for ln in calls) == 3
+    assert sum("attn.full/dl4j.ops.flash_attention" in ln
+               for ln in calls) == 2
+    assert sum("moe_expert_tiles" in ln for ln in calls) == 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4.7e9
+    # 12.49 GB of arguments and 1.30 GB of temporaries: 13.79 GB
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.56e9, mem
+    assert mem.temp_size_in_bytes < 1.5e9, mem

@@ -98,26 +98,34 @@ def _masked_plain(q, k, v, window):
     return out
 
 
+@pytest.mark.parametrize("window,heads", [(WINDOW, 4), (8, 12), (8, 16)],
+                         ids=["two_blocks_wide", "narrower_than_a_block_6",
+                              "narrower_than_a_block_8"])
 @pytest.mark.parametrize("t", [31, 32, 33, 100],
                          ids=["one_short", "edge", "one_past", "long"])
-def test_flash_window_matches_the_masked_plain_form(t):
+def test_flash_window_matches_the_masked_plain_form(t, window, heads):
     """``flash_attention(window=)`` in interpret mode, 16-row blocks so
     that whole KV blocks fall out of range, against a literal masked
-    softmax; the plain einsum form (what autodiff runs) beside it."""
+    softmax; the plain einsum form (what autodiff runs) beside it. A
+    window NARROWER than a KV block (8 keys in blocks of 16: a query
+    block then skips every KV block but its own and the one before
+    it), with query groups of 6 and of 8 heads a KV head, is what a
+    decoder whose window layers hold 512 keys gives the kernel's
+    larger blocks."""
     rng = np.random.default_rng(t)
-    q = jnp.asarray(rng.standard_normal((2, t, 4, 16)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((2, t, heads, 16)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, t, 2, 16)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((2, t, 2, 16)), jnp.float32)
-    want = _masked_plain(q, k, v, WINDOW)
-    got = pk.flash_attention(q, k, v, causal=True, window=WINDOW,
+    want = _masked_plain(q, k, v, window)
+    got = pk.flash_attention(q, k, v, causal=True, window=window,
                              block_q=16, block_k=16)
     assert np.abs(np.asarray(got) - want).max() < 2e-5
-    plain = A.plain_attention(q, k, v, causal=True, window=WINDOW)
+    plain = A.plain_attention(q, k, v, causal=True, window=window)
     assert np.abs(np.asarray(plain) - want).max() < 2e-5
     # and the window is a window: the unwindowed form differs past it
     whole = pk.flash_attention(q, k, v, causal=True, block_q=16,
                                block_k=16)
-    assert (np.abs(np.asarray(whole) - want).max() > 1e-3) == (t > WINDOW)
+    assert (np.abs(np.asarray(whole) - want).max() > 1e-3) == (t > window)
 
 
 def test_flash_window_is_causal_only():
@@ -187,24 +195,35 @@ _RING_N = (32, 31, 33, 5, 0, 100, 81)
 _RING_WRAPS = (0, 70, 86, 102, 118, 134, 150, 229, 64, 63, 65, 5, 1, 0)
 
 _RING_CASES = [
-    pytest.param(jnp.float32, 2e-5, 32, _RING_N, None, id="float32"),
-    pytest.param(jnp.bfloat16, 3e-2, 32, _RING_N, None, id="bfloat16"),
-    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 2,
+    pytest.param(jnp.float32, 2e-5, 32, _RING_N, None, (4, 8),
+                 id="float32"),
+    pytest.param(jnp.bfloat16, 3e-2, 32, _RING_N, None, (4, 8),
+                 id="bfloat16"),
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 2, (4, 8),
                  id="ring-of-5-items-of-2"),
-    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 4,
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 4, (4, 8),
                  id="ring-of-5-items-of-4"),
-    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, None,
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, None, (4, 8),
                  id="ring-of-5-one-item"),
-    pytest.param(jnp.float32, 2e-5, 64, (0,) * 4, 2, id="none-live"),
+    pytest.param(jnp.float32, 2e-5, 64, (0,) * 4, 2, (4, 8),
+                 id="none-live"),
+    # 8 KV heads under query groups of 6 and of 8 (48 and 64 heads):
+    # one model's two kinds of layer, two shapes of the kernel
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, None, (8, 48),
+                 id="groups-of-6"),
+    pytest.param(jnp.float32, 2e-5, 64, _RING_WRAPS, 2, (8, 64),
+                 id="groups-of-8"),
+    pytest.param(jnp.bfloat16, 3e-2, 32, _RING_N, None, (8, 48),
+                 id="groups-of-6-bfloat16"),
 ]
 
 
-@pytest.mark.parametrize("dtype,tol,window,lengths,pages_per_chunk",
-                         _RING_CASES)
+@pytest.mark.parametrize(
+    "dtype,tol,window,lengths,pages_per_chunk,heads", _RING_CASES)
 def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol, window,
-                                         lengths, pages_per_chunk):
-    """The kernel (interpret mode) over a folded ring pool of 4 KV
-    heads through ``PagedWindowKV.read`` (the slot's ring as its row
+                                         lengths, pages_per_chunk, heads):
+    """The kernel (interpret mode) over a folded ring pool of 4 (or 8)
+    KV heads through ``PagedWindowKV.read`` (the slot's ring as its row
     of the page table, read modulo its length by the kernel; with
     ``pages_per_chunk`` the same call made with items of that many
     pages), against a literal softmax over the last ``window``
@@ -212,8 +231,8 @@ def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol, window,
     of the last are never read (NaN would poison the output)."""
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     rng = np.random.default_rng(3)
-    n_kv, d, block, h = 4, 128, 16, 8
-    pool, kv, ring = _ring_case(rng, lengths, window=window)
+    (n_kv, h), d, block = heads, 128, 16
+    pool, kv, ring = _ring_case(rng, lengths, n_kv=n_kv, window=window)
     s_ = len(lengths)
     q = jnp.asarray(rng.standard_normal((s_, h, d)), dtype)
     n = np.asarray(lengths)
@@ -254,6 +273,9 @@ def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol, window,
             assert np.abs(out[s, hh] - want).max() < tol, (s, hh)
 
 
+@pytest.mark.parametrize("h", [8, 48, 64],
+                         ids=["one_head_a_kv_head", "groups_of_6",
+                              "groups_of_8"])
 @pytest.mark.parametrize("folded", [False, True], ids=["5d", "folded"])
 @pytest.mark.parametrize("n_live", [(32, 31, 33, 0, 96, 70),
                                     (0, 96, 1, 17, 96, 0),
@@ -261,7 +283,7 @@ def test_paged_decode_window_over_a_ring(monkeypatch, dtype, tol, window,
                          ids=["ragged", "inactive-first-and-last",
                               "one-live"])
 def test_paged_decode_window_over_a_plain_page_table(monkeypatch, n_live,
-                                                     folded):
+                                                     folded, h):
     """``paged_decode_attention(window=)`` over an ordinary page table
     (every position kept), the pool as ``[L, P, block, Hkv, 2D]`` and
     folded to ``[L, P, block * Hkv, 2D]``: the walk starts at the
@@ -269,7 +291,7 @@ def test_paged_decode_window_over_a_plain_page_table(monkeypatch, n_live,
     NaN here, are not read; the fallback masks the same positions."""
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     rng = np.random.default_rng(5)
-    block, mp, n_kv, d, h = 16, 6, 8, 128, 8
+    block, mp, n_kv, d = 16, 6, 8, 128
     s_ = len(n_live)
     pool = rng.standard_normal((1, 1 + s_ * mp, block, n_kv, 2 * d))
     pool[:, 0] = np.nan

@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.attention import (
-    latent_attention_expanded, rotary_embedding, rotary_tables,
+    _use_flash, latent_attention_expanded, rotary_embedding, rotary_tables,
     rotary_turn, scaled_dot_attention)
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import devtime
@@ -531,15 +531,17 @@ def dense_kv(k, v, cache_len: int, quant: bool):
             jnp.pad(s, pad))
 
 
-def causal_prefill(dims, keep):
+def causal_prefill(dims, keep, lengths=None):
     """The ``attend`` of a whole padded prompt ``h [B, Tb, F]``: causal
     attention through ``scaled_dot_attention`` (flash-dispatched: long
     prompts take the Pallas O(T)-memory path on TPU), each layer's
     rotated keys and its values handed to ``keep(li, k, v)``, which
     lays them out as the decode steps will read them (:func:`dense_kv`,
-    or the pager's pages). Rows past the prompt's end hold padding
-    junk: causality keeps it out of every real row's context, and
-    decode overwrites row ``p`` before attending at ``p``. A layer
+    or the pager's pages). Rows past the prompt's end (``lengths``
+    int32 ``[B]``, where the caller knows it: the flash kernel then
+    spends nothing on them) hold padding junk, finite: causality keeps
+    it out of every real row's context, and decode overwrites row
+    ``p`` before attending at ``p``. A layer
     rotates, and bounds its keys by a window, as ``dims`` says of it
     (:func:`layer_theta`, :func:`layer_window`)."""
     turns = Turns(per_row=False)
@@ -548,15 +550,19 @@ def causal_prefill(dims, keep):
         q, k, v = qkv(mha, h, dims,
                       lambda z: turns(z, layer_theta(dims, li)), li)
         keep(li, k, v)
+        # the lengths go along where the kernel that reads them takes
+        # the call (the einsum has no use for them)
+        live = ({"lengths": lengths} if lengths is not None
+                and _use_flash(q, k, True) else {})
         spec = getattr(dims, "windowed", None)
         if spec is None:
-            return scaled_dot_attention(q, k, v, causal=True).reshape(
-                *h.shape[:-1], -1)
+            return scaled_dot_attention(
+                q, k, v, causal=True, **live).reshape(*h.shape[:-1], -1)
         # a scope of the layer's kind, as the step's page walks have
         with devtime.scope(f"attn.{spec.kinds[li]}"):
             return scaled_dot_attention(
-                q, k, v, causal=True,
-                window=layer_window(dims, li)).reshape(*h.shape[:-1], -1)
+                q, k, v, causal=True, window=layer_window(dims, li),
+                **live).reshape(*h.shape[:-1], -1)
     return attend
 
 
@@ -582,17 +588,19 @@ class DenseLatent(_AtPosition):
         return latent.unabsorb(mha, o, spec)
 
 
-def latent_prefill(dims, keep):
+def latent_prefill(dims, keep, lengths=None):
     """The ``attend`` of a whole padded prompt ``h [B, Tb, F]`` under
     latent attention: the EXPANDED form
     (``latent_attention_expanded``: K and V of every position from its
     latent, flash-dispatched as :func:`causal_prefill`'s), each
     layer's latent rows ``[B, Tb, kv_rank + rope]`` handed to
-    ``keep(li, rows)``. Padding rows as in :func:`causal_prefill`."""
+    ``keep(li, rows)``. Padding rows, and ``lengths``, as in
+    :func:`causal_prefill`."""
     def attend(li, mha, h):
         with devtime.scope("ops.latent_prefill"):
             a, rows = latent_attention_expanded(
-                mha, h, dims.latent, dims.n_heads, dims.rope_theta)
+                mha, h, dims.latent, dims.n_heads, dims.rope_theta,
+                lengths)
         keep(li, rows)
         return a
     return attend

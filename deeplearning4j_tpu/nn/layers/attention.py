@@ -137,12 +137,17 @@ def _use_flash(q, k, causal: bool = False) -> bool:
             and jax.default_backend() == "tpu")
 
 
-def scaled_dot_attention(q, k, v, mask=None, causal=False, window=None):
+def scaled_dot_attention(q, k, v, mask=None, causal=False, window=None,
+                         lengths=None):
     """q,k,v: [B, T, H, D] (head axis 2); ``k``/``v`` may carry fewer
     heads (GQA); Tq and Tk may differ (causal is then END-ALIGNED:
     query i attends keys ≤ i + Tk − Tq). mask: [B, Tk] key mask.
     ``window`` (causal only): a query sees the last ``window`` keys
-    up to its own, its own included.
+    up to its own, its own included. ``lengths`` (causal, forward
+    only): int32 ``[B]``, the rows of each batch row that carry a
+    token. What a row at or past it gets is unspecified: the flash
+    kernel spends nothing on it and returns zeros, the einsum ignores
+    the lengths.
 
     Explicit einsum+softmax (not jax.nn.dot_product_attention, which is
     not exact in float64 — breaks gradient checking). Platform-helper
@@ -156,11 +161,28 @@ def scaled_dot_attention(q, k, v, mask=None, causal=False, window=None):
         # mask operand in the kernel) — every padded-batch NLP workload
         # stays O(T) memory instead of falling back to the [T,T] einsum
         from deeplearning4j_tpu.ops.pallas_kernels import flash_attention
-        if window is not None:      # forward only (inference prefill)
+        if window is not None or lengths is not None:
+            # forward only (inference prefill)
             return flash_attention(q, k, v, causal=causal, mask=mask,
-                                   window=window)
+                                   window=window, lengths=lengths)
         return flash_attention(q, k, v, causal=causal, mask=mask)
     return plain_attention(q, k, v, mask, causal, window)
+
+
+def causal_pairs(t: int, n: int, lanes: int, dtype, window=None):
+    """What the flash kernel multiplies for ONE head of a causal
+    self-attention over ``t`` rows of which the first ``n`` carry a
+    token, in (query, key) pairs, ``(need, done)`` as plain integers:
+    ``need`` the keys the tokens see, ``done`` the kernel's block
+    areas (``ops.pallas_kernels.prefill_pairs``: the kernel's own
+    bounds). ``None`` where :func:`scaled_dot_attention` hands such a
+    call to the einsum: the kernel multiplies nothing there."""
+    from deeplearning4j_tpu.ops.pallas_kernels import prefill_pairs
+    dtype = jnp.dtype(dtype)
+    rows = jax.ShapeDtypeStruct((1, t, 1, lanes), dtype)
+    if not _use_flash(rows, rows, True):
+        return None
+    return prefill_pairs(t, n, window, lanes, dtype.itemsize)
 
 
 def plain_attention(q, k, v, mask=None, causal=False, window=None):
@@ -531,7 +553,8 @@ class PowerRetention(Layer):
         return o, state
 
 
-def latent_attention_expanded(mha, h, spec, n_heads: int, theta: float):
+def latent_attention_expanded(mha, h, spec, n_heads: int, theta: float,
+                              lengths=None):
     """Causal latent attention of whole sequences ``h [B, T, F]`` in
     the EXPANDED form (``ops/latent.py``): every position's K and V
     made from its latent, through :func:`scaled_dot_attention`
@@ -539,7 +562,8 @@ def latent_attention_expanded(mha, h, spec, n_heads: int, theta: float):
     keys and values, so all three are padded with zeros to whole
     128-lane tiles (192-wide keys and 128-wide values to 256: zeros
     add nothing to a score, and the values' tail is cut off again),
-    and the softmax scale is folded into the query. Returns the
+    and the softmax scale is folded into the query. ``lengths`` as
+    :func:`scaled_dot_attention`'s (a padded prompt's). Returns the
     mixer's output ``[B, T, H * v]`` and the positions' latent rows
     ``[B, T, kv_rank + rope]`` (what a cache keeps)."""
     from deeplearning4j_tpu.ops import latent
@@ -563,7 +587,7 @@ def latent_attention_expanded(mha, h, spec, n_heads: int, theta: float):
     # scaled_dot_attention divides by the root of the width
     fold = latent.softmax_scale(spec) * width ** 0.5
     a = scaled_dot_attention((q * fold).astype(q.dtype), k, padded(v),
-                             causal=True)
+                             causal=True, lengths=lengths)
     return a[..., :spec.v].reshape(b, t, -1), row
 
 

@@ -18,7 +18,11 @@ Kernels:
   ``parallel.ring_attention`` over ICI (``flash_block_fwd`` /
   ``flash_block_bwd`` below are the composition surface: the ring
   carries (out, lse) accumulators between Pallas calls and merges
-  them with exact log-sum-exp combination).
+  them with exact log-sum-exp combination). A causal forward that
+  keeps no ``lse`` (a serving bucket's prefill, ``generate()``'s, an
+  evaluation) runs ``_prefill_kernel``: K and V of a kv head whole in
+  VMEM, the loop over key blocks inside the kernel and bounded by the
+  diagonal, the window and the prompt's length.
 - ``paged_decode_attention`` — the serving decode step's attention
   over the paged KV pool, read in place: per slot a loop over the
   slot's pages bounded by its length, whole pages copied HBM -> VMEM
@@ -105,6 +109,29 @@ def _jnp_fallback(*xs) -> bool:
 #    fully above the diagonal are skipped without any work.
 
 
+def _fold_scores(s, v_dtype, load_v, m, l, acc):
+    """Fold one masked float32 score tile ``s [bq, bk]`` and its values
+    (``load_v()``: the ``[bk, d]`` tile of ``v_dtype``, read where it
+    is used) into the online softmax the scratch refs hold: ``m`` the
+    running maximum and ``l`` the running sum (lane 0 of 128 each),
+    ``acc [bq, d]`` the weighted values. Masked entries are ``-inf``.
+    ``_flash_kernel``'s grid steps and ``_prefill_kernel``'s loop
+    turns are this one recurrence."""
+    m_prev = m[:, :1]
+    m_blk = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_blk)
+    # exp(-inf - -inf) guard: rows with no live keys yet keep m=-inf
+    p = jnp.exp(s - jnp.where(jnp.isinf(m_new), 0.0, m_new))
+    alpha = jnp.exp(jnp.where(jnp.isinf(m_prev), -jnp.inf, m_prev)
+                    - jnp.where(jnp.isinf(m_new), 0.0, m_new))
+    alpha = jnp.where(jnp.isinf(m_prev), 0.0, alpha)
+
+    l[:, :1] = l[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc[:] = acc[:] * alpha + jnp.dot(p.astype(v_dtype), load_v(),
+                                      preferred_element_type=jnp.float32)
+    m[:, :1] = m_new
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, km_ref, off_ref, o_ref, *rest,
                   scale: float, causal: bool, t_real: int,
                   block_q: int, block_k: int,
@@ -173,20 +200,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, km_ref, off_ref, o_ref, *rest,
                     > off_ref[0] + q_idx - window)
         s = jnp.where(mask, s, -jnp.inf)
 
-        m_prev = m[:, :1]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        # exp(-inf - -inf) guard: rows with no live keys yet keep m=-inf
-        p = jnp.exp(s - jnp.where(jnp.isinf(m_new), 0.0, m_new))
-        alpha = jnp.exp(jnp.where(jnp.isinf(m_prev), -jnp.inf, m_prev)
-                        - jnp.where(jnp.isinf(m_new), 0.0, m_new))
-        alpha = jnp.where(jnp.isinf(m_prev), 0.0, alpha)
-
-        l[:, :1] = l[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * alpha + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0],
-            preferred_element_type=jnp.float32)
-        m[:, :1] = m_new
+        _fold_scores(s, v_ref.dtype, lambda: v_ref[0], m, l, acc)
 
     @pl.when(j == nk - 1)
     def _():
@@ -736,10 +750,275 @@ def _reference_bwd_block(q, k, v, out, lse, g, km, offs, causal):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+# --- the causal inference forward (a bucket's prefill) -----------------------
+#
+# ``_flash_kernel``'s grid is ``(B H, nq, nk)`` and every step of it
+# takes its turn: a step above the diagonal skips its body and is still
+# a step (and, without a window, a fetch), a row past the prompt's end
+# costs what a real one costs, and K and V are fetched again for every
+# q block of every query head of a group. The forward that needs no
+# ``lse`` (a bucket's prefill, the dense ``generate()`` prefill, an
+# evaluation) has a path of its own: K and V of ONE kv head lie in VMEM
+# whole, fetched once a kv head (their block index does not change over
+# the group's heads and the q blocks), and a q block walks its key
+# blocks in a loop INSIDE the kernel, bounded by the diagonal, the
+# window and the batch row's live length (:func:`prefill_visits`). A
+# key block out of range is neither a grid step nor a fetch. What a
+# turn of that loop costs is mostly FIXED a q row (the fold's column
+# arithmetic, the accumulator's read and write), so the key blocks are
+# wide, and the one at the diagonal is walked as a half where a half
+# is all that is left. PERF.md section 5 ("The flash prefill, taken
+# apart") has the readings.
+
+#: K and V of one kv head (one copy of each, padded) may take this much
+#: of VMEM on the causal inference path; the pipeline holds them twice
+_PREFILL_KV_BYTES = 8 * 1024 * 1024
+_PREFILL_VMEM_BYTES = 48 * 1024 * 1024
+
+
+class _Ints:
+    """``minimum`` and ``maximum`` of plain integers, as
+    :func:`prefill_visits` asks its ``xp`` for them."""
+    minimum = staticmethod(min)
+    maximum = staticmethod(max)
+
+
+def prefill_visits(i, n, block_q: int, half: int,
+                   window: Optional[int] = None, q_off: int = 0, xp=jnp):
+    """The keys q block ``i`` walks when the first ``n`` query rows are
+    live, in HALF key blocks of ``half`` keys: ``(lo, wide, narrow)``,
+    the first half block that holds a visible (query, key) pair, then
+    ``wide`` whole key blocks (two halves each, end to end from
+    ``lo``) and ``narrow`` (0 or 1) half block after them, up to the
+    last live row's diagonal. Row ``r`` stands at position ``q_off +
+    r`` and sees keys ``pos - window < j <= pos``. Pure integer
+    arithmetic on ``xp``'s minimum and maximum (``jnp`` in the kernel,
+    :class:`_Ints` for the host's count): ONE function bounds the
+    kernel's loops and counts what they multiply. The caller holds
+    ``i * block_q < n``."""
+    p0 = q_off + i * block_q                    # the first row's position
+    last = q_off + xp.minimum(i * block_q + block_q, n) - 1
+    lo = 0 * last if window is None else \
+        xp.maximum(p0 - window + 1, 0) // half
+    halves = last // half + 1 - lo
+    return lo, halves // 2, halves % 2
+
+
+def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest,
+                    scale: float, block_q: int, block_k: int, half: int,
+                    window: Optional[int], q_off: int, h_kv: int,
+                    t_real: int):
+    # len_ref [B] (scalar prefetch): live query rows a batch row;
+    # q_ref/o_ref [1, block_q, dp]; k_ref/v_ref [1, Tk, dp]: ONE kv
+    # head, whole; rest = (visits_ref?, acc, m, l): the count of the
+    # loop's turns is an output only where a test asks for it
+    acc, m, l = rest[-3:]
+    i = pl.program_id(2)
+    n = jnp.minimum(len_ref[pl.program_id(0) // h_kv], t_real)
+    r0 = i * block_q
+    if len(rest) == 4:
+        rest[0][0, 0] = 0
+
+    @pl.when(r0 >= n)
+    def _():
+        # no row of the block is live: no work, and ZEROS (a padded
+        # row's K and V go on to the pages of the layers above, and
+        # ``0 x NaN`` is ``NaN`` in a page walk's ``p v``)
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(r0 < n)
+    def _():
+        m[:] = jnp.full_like(m[:], -jnp.inf)
+        l[:] = jnp.zeros_like(l[:])
+        acc[:] = jnp.zeros_like(acc[:])
+        # the softmax scale folds into the q tile, once a q block
+        qs = q_ref[0] * q_ref.dtype.type(scale)
+        lo, wide, narrow = prefill_visits(i, n, block_q, half, window,
+                                          q_off)
+
+        def fold(width: int, first):
+            # ``width`` keys a turn from half block ``first`` on. The
+            # mask's arithmetic runs in every turn: the chip reads no
+            # difference without it (PERF.md section 5)
+            def turn(j, carry):
+                if len(rest) == 4:
+                    rest[0][0, 0] += 1
+                k0 = pl.multiple_of(first * half + j * width, half)
+                s = jnp.dot(qs, k_ref[0, pl.ds(k0, width), :].T,
+                            preferred_element_type=jnp.float32)
+                kv_idx = k0 + lax.broadcasted_iota(
+                    jnp.int32, (block_q, width), 1)
+                q_idx = q_off + r0 + lax.broadcasted_iota(
+                    jnp.int32, (block_q, width), 0)
+                mask = kv_idx <= q_idx
+                if window is not None:
+                    mask = jnp.logical_and(mask, kv_idx > q_idx - window)
+                _fold_scores(jnp.where(mask, s, -jnp.inf), v_ref.dtype,
+                             lambda: v_ref[0, pl.ds(k0, width), :],
+                             m, l, acc)
+                return carry
+            return turn
+
+        if half == block_k:     # no half to walk: whole blocks alone
+            lax.fori_loop(0, 2 * wide + narrow, fold(block_k, lo), 0)
+        else:
+            lax.fori_loop(0, wide, fold(block_k, lo), 0)
+            lax.fori_loop(0, narrow, fold(half, lo + 2 * wide), 0)
+        rows = r0 + lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        o_ref[0] = jnp.where(
+            rows < n, acc[:] / jnp.maximum(l[:, :1], 1e-30),
+            0.0).astype(o_ref.dtype)
+
+
+def _prefill_blocks(t: int, tk: int, d: int, window: Optional[int],
+                    block_q: Optional[int], block_k: Optional[int]):
+    """The causal inference path's blocks, ``_flash_blocks``' tuple and
+    the HALF key block behind it: the caller's where given, else 512 x
+    1024 (PERF.md section 5 has the sweep: a turn of the key loop has
+    a fixed cost a q row, the fold's column arithmetic and the
+    accumulator's read and write, that outweighs the pairs a wide
+    block multiplies past an edge), a q block no taller than the
+    window. A key block is walked whole where two halves are left up
+    to the diagonal and as ONE half where one is: the turns of wide
+    blocks, the pairs of narrow ones. A key block that does not halve
+    into whole 128-lane tiles is its own half, on the chip and in
+    interpret mode alike: the kernel then walks whole blocks alone,
+    ``2 * wide + narrow`` of them."""
+    if block_q is None:
+        block_q = 512 if window is None else max(
+            128, min(512, 1 << (int(window).bit_length() - 1)))
+    if block_k is None:
+        block_k = 1024
+    block_q, block_k, tq, tk, dp = _flash_blocks(t, tk, d, block_q, block_k)
+    half = block_k // 2 if block_k % 256 == 0 else block_k
+    return block_q, block_k, tq, tk, dp, half
+
+
+def _prefill_fits(t: int, tk: int, d: int, window: Optional[int],
+                  itemsize: int, block_q=None, block_k=None) -> bool:
+    """Whether K and V of ONE kv head, padded as the causal inference
+    path pads them, lie within ``_PREFILL_KV_BYTES``."""
+    *_, tkp, dp, _ = _prefill_blocks(t, tk, d, window, block_q, block_k)
+    return 2 * tkp * dp * itemsize <= _PREFILL_KV_BYTES
+
+
+def _prefill_qualifies(q, k, v, km, causal: bool, q_off: int,
+                       window: Optional[int], block_q, block_k) -> bool:
+    """Whether a forward without ``lse`` takes the causal inference
+    path, by what the call shows: causal with static offsets that hide
+    no query, no key mask, no manual axes, and K and V of a head
+    within the VMEM budget."""
+    return (causal and km is None and q_off >= 0
+            and not _vma(q, k, v)
+            and _prefill_fits(q.shape[1], k.shape[1], q.shape[2], window,
+                              k.dtype.itemsize, block_q, block_k))
+
+
+def _prefill_fwd(q, k, v, lengths, groups: int, window: Optional[int],
+                 q_off: int, block_q, block_k, count_visits: bool = False):
+    """The causal forward of ``_flash_fwd``'s operands by the kernel
+    whose key loop runs inside it; ``lengths`` int32 ``[B]``: the live
+    query rows a batch row (rows at and past it come back ZERO).
+    ``count_visits``: also the turns of the key loop in each grid
+    step, ``[B Hkv, groups, nq]`` (a test's eye on the loop)."""
+    bh, t, d = q.shape
+    bkv, tk_real = k.shape[:2]
+    block_q, block_k, tq, tk, dp, half = _prefill_blocks(
+        t, tk_real, d, window, block_q, block_k)
+
+    def pad(x, tpad):
+        return jnp.pad(x, ((0, 0), (0, tpad - x.shape[1]), (0, dp - d)))
+
+    g, nq = groups, tq // block_q
+    qspec = pl.BlockSpec((1, block_q, dp),
+                         lambda b, gi, i, n: (b * g + gi, i, 0))
+    kspec = pl.BlockSpec((1, tk, dp), lambda b, gi, i, n: (b, 0, 0))
+    oshape = jax.ShapeDtypeStruct((bh, tq, dp), q.dtype)
+    cshape = jax.ShapeDtypeStruct((bkv * g * nq, 1), jnp.int32)
+    cspec = pl.BlockSpec((1, 1), lambda b, gi, i, n: ((b * g + gi) * nq + i,
+                                                      0),
+                         memory_space=pltpu.SMEM)
+    res = pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=1.0 / (d ** 0.5),
+                          block_q=block_q, block_k=block_k, half=half,
+                          window=window, q_off=q_off,
+                          h_kv=bkv // lengths.shape[0], t_real=t),
+        out_shape=(oshape, cshape) if count_visits else oshape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bkv, g, nq),
+            in_specs=[qspec, kspec, kspec],
+            out_specs=(qspec, cspec) if count_visits else qspec,
+            scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32),
+                            pltpu.VMEM((block_q, 128), jnp.float32),
+                            pltpu.VMEM((block_q, 128), jnp.float32)]),
+        compiler_params=None if _interpret() else pltpu.CompilerParams(
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=_interpret(),
+    )(lengths.astype(jnp.int32), pad(q, tq), pad(k, tk), pad(v, tk))
+    if count_visits:
+        return res[0][:, :t, :d], res[1].reshape(bkv, g, nq)
+    return res[:, :t, :d]
+
+
+def prefill_pairs(t: int, n: int, window: Optional[int], d: int,
+                  itemsize: int):
+    """What a causal forward of ``t`` rows, the first ``n`` live, costs
+    ONE head in (query, key) pairs, ``(need, done)`` as plain
+    integers: ``need`` what the live tokens see (``min(r + 1,
+    window)`` keys a row), ``done`` the block areas the kernel
+    multiplies, by :func:`prefill_visits`: the very bounds of the
+    causal inference path's loops. (A call past that path's VMEM
+    budget runs ``_flash_kernel``'s grid: its live steps are the same
+    bounds at ITS blocks with every row of the bucket live.)"""
+    n = min(int(n), t)
+    seen = n if window is None else min(n, window)
+    need = seen * (seen + 1) // 2 + (n - seen) * seen
+    if _prefill_fits(t, t, d, window, itemsize):
+        bq, *_, half = _prefill_blocks(t, t, d, window, None, None)
+    else:
+        bq, half, *_ = _flash_blocks(t, t, d,
+                                     *_ring_block_defaults(None, None, t))
+        n = t
+    done = 0
+    for i in range(-(-n // bq)):
+        _, wide, narrow = prefill_visits(i, n, bq, half, window, xp=_Ints)
+        done += (2 * wide + narrow) * bq * half
+    return need, done
+
+
+def _flash_infer(q, k, v, km, causal: bool, block_q, block_k,
+                 groups: int = 1, window: Optional[int] = None,
+                 q_off: int = 0, lengths=None):
+    """A forward that keeps no ``lse``: the causal inference path where
+    the call qualifies for it (:func:`_prefill_qualifies`), else
+    ``_flash_kernel`` by ``_flash_fwd`` exactly as a training forward
+    runs it. ``lengths`` as :func:`flash_attention`'s (``None``: every
+    row is live)."""
+    if _prefill_qualifies(q, k, v, km, causal, q_off, window, block_q,
+                          block_k):
+        if lengths is None:     # one length a kv row: all of them
+            lengths = jnp.full((k.shape[0],), q.shape[1], jnp.int32)
+        return _prefill_fwd(q, k, v, lengths, groups, window, q_off,
+                            block_q, block_k)
+    out = _flash_fwd(q, k, v, km, _static_offs(q_off), causal,
+                     *_ring_block_defaults(block_q, block_k, k.shape[1]),
+                     groups=groups, window=window, q_off=q_off)
+    if lengths is None:
+        return out
+    live = jnp.repeat(lengths, q.shape[0] // lengths.shape[0])
+    return jnp.where(jnp.arange(q.shape[1])[None, :, None]
+                     < live[:, None, None], out, 0).astype(out.dtype)
+
+
 # --- ring composition surface ------------------------------------------------
 def _ring_block_defaults(block_q, block_k, tk):
-    """Measured v5e block policy shared with flash_attention: big q
-    blocks; block_k 512 up to 4k keys, 1024 beyond."""
+    """The blocks of ``_flash_kernel`` and its backward, the ring's and
+    ``flash_attention``'s training forward alike, from the v5e block
+    sweep (tools/flash_crossover.py era, causal fwd+bwd): big q blocks
+    amortise the backward's kv-side recompute, (1024, 512) wins up to
+    4k keys (-28% vs the old 256/1024 at T=2048), (1024, 1024) at 8k
+    keys (-16%); larger q blocks exceed VMEM at T=8k."""
     if block_q is None:
         block_q = 1024
     if block_k is None:
@@ -791,8 +1070,10 @@ def flash_block_bwd(q, k, v, out, lse, g, km=None, offs=None,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash(q, k, v, km, causal, block_q, block_k, groups=1, q_off=0):
-    return _flash_fwd(q, k, v, km, _static_offs(q_off), causal,
-                      block_q, block_k, groups=groups)
+    # not differentiated: no ``lse`` is kept (blocks ``None``: each
+    # path's own)
+    return _flash_infer(q, k, v, km, causal, block_q, block_k,
+                        groups=groups, q_off=q_off)
 
 
 def _static_offs(q_off: int):
@@ -802,8 +1083,9 @@ def _static_offs(q_off: int):
 def _flash_vjp_fwd(q, k, v, km, causal, block_q, block_k, groups,
                    q_off):
     out, lse = _flash_fwd(q, k, v, km, _static_offs(q_off), causal,
-                          block_q, block_k, return_lse=True,
-                          groups=groups)
+                          *_ring_block_defaults(block_q, block_k,
+                                                k.shape[1]),
+                          return_lse=True, groups=groups)
     return out, (q, k, v, km, out, lse)
 
 
@@ -811,7 +1093,9 @@ def _flash_vjp_bwd(causal, block_q, block_k, groups, q_off, res, g):
     q, k, v, km, out, lse = res
     dkm = None if km is None else jnp.zeros_like(km)
     return _flash_bwd(q, k, v, out, lse, g, km, _static_offs(q_off),
-                      causal, block_q, block_k, groups=groups) + (dkm,)
+                      causal, *_ring_block_defaults(block_q, block_k,
+                                                    k.shape[1]),
+                      groups=groups) + (dkm,)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -821,7 +1105,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     mask: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    lengths: Optional[jax.Array] = None):
     """Blockwise attention, [B, T, H, D] layout (head axis 2) like
     ``scaled_dot_attention``; ``mask``: optional [B, Tk] key mask.
     ``k``/``v`` may carry FEWER heads than ``q`` (grouped-query
@@ -839,23 +1124,21 @@ def flash_attention(q, k, v, causal: bool = False,
     saved logsumexp — FlashAttention-2 style, no [T,T] materialisation
     in either direction. ``window`` (causal only): query ``t`` sees
     the ``window`` keys ``t - window < j <= t``, its own included; KV
-    blocks out of a q block's range are not read, so a long prompt
-    costs ``T x window``. The windowed forward has no backward yet (a
-    window layer trains through the masked plain form)."""
+    blocks out of a q block's range are not read. The windowed forward
+    has no backward yet (a window layer trains through the masked
+    plain form). ``lengths`` (causal, forward only): int32 ``[B]``,
+    the live query rows of each batch row (a padded prompt's tokens);
+    rows at and past it cost nothing and come back ZERO. ``block_q``,
+    ``block_k``: ``None`` leaves each path its own (the training sweep's
+    for the forward that keeps ``lse`` and the backward, the causal
+    inference path's for a forward that keeps none)."""
     b, t, h, d = q.shape
     h_kv = k.shape[2]
     if h % h_kv:
         raise ValueError(f"q heads ({h}) not divisible by kv heads "
                          f"({h_kv})")
-    # defaults from the v5e block sweep (tools/flash_crossover.py era,
-    # causal fwd+bwd): big q blocks amortise the backward's kv-side
-    # recompute — (1024, 512) wins ≤4k keys (−28% vs the old 256/1024
-    # at T=2048), (1024, 1024) wins at 8k keys (−16%); larger q blocks
-    # exceed VMEM at T=8k
-    if block_q is None:
-        block_q = 1024
-    if block_k is None:
-        block_k = 512 if k.shape[1] <= 4096 else 1024
+    if lengths is not None and not causal:
+        raise ValueError("lengths bound a causal forward")
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(
         b * x.shape[2], x.shape[1], -1)
     km = None
@@ -866,16 +1149,14 @@ def flash_attention(q, k, v, causal: bool = False,
     # own device time gets its own name in the gap report
     from deeplearning4j_tpu.obs import devtime
     with devtime.scope("ops.flash_attention"):
-        if window is not None:
-            q_off = k.shape[1] - t
-            o = _flash_fwd(fold(q), fold(k), fold(v), km,
-                           _static_offs(q_off), causal, block_q,
-                           block_k, groups=h // h_kv, window=window,
-                           q_off=q_off)
+        q_off = k.shape[1] - t if causal else 0
+        if window is not None or lengths is not None:
+            o = _flash_infer(fold(q), fold(k), fold(v), km, causal,
+                             block_q, block_k, groups=h // h_kv,
+                             window=window, q_off=q_off, lengths=lengths)
         else:
             o = _flash(fold(q), fold(k), fold(v), km, causal, block_q,
-                       block_k, h // h_kv,
-                       k.shape[1] - t if causal else 0)
+                       block_k, h // h_kv, q_off)
     return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 

@@ -112,6 +112,7 @@ import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.nn import decoder_infer as di
+from deeplearning4j_tpu.nn.layers.attention import causal_pairs
 from deeplearning4j_tpu.nn.layers.core import RMSNORM_EPS
 from deeplearning4j_tpu.obs import devtime
 from deeplearning4j_tpu.obs import metrics as _metrics
@@ -619,6 +620,29 @@ class _Rows:
         / block`` pages."""
         return jnp.asarray(np.asarray(pages[:tb // pager.block], np.int32))
 
+    @staticmethod
+    def prefill_pairs(dims, tb: int, t0: int) -> dict:
+        """What the flash kernel spends on a prompt of ``t0`` tokens
+        in a bucket of ``tb`` rows, as the admission record's counts:
+        ``flash_pairs_need``, the (query, key) pairs its tokens see
+        over all layers and heads, and ``flash_pairs_done``, those the
+        kernel multiplies for them (``attention.causal_pairs``: the
+        kernel's own loop bounds). Nothing where the einsum takes the
+        bucket."""
+        heads: Dict[Optional[int], int] = {}    # by the layers' window
+        for li in range(dims.n_layers):
+            w = di.layer_window(dims, li)
+            heads[w] = heads.get(w, 0) + di.layer_heads(dims, li)
+        need = done = 0
+        for w, h in heads.items():
+            pairs = causal_pairs(tb, t0, _head_dim(dims),
+                                 dims.compute_dtype or "float32", w)
+            if pairs is None:
+                return {}
+            need += h * pairs[0]
+            done += h * pairs[1]
+        return {"flash_pairs_need": need, "flash_pairs_done": done}
+
     @classmethod
     def step_reads(cls, pager, dims, at, row_pages: int) -> dict:
         """What a decode step reads, as the step record's counts, from
@@ -1068,6 +1092,19 @@ class PagedLatent(_Rows):
         lat = jnp.pad(lat, ((0, 0), (0, 0), (0, stored - width)))
         return (rows.at[:, page_ids].set(lat.reshape(
             n_l, tb // block, block, stored).astype(rows.dtype)),)
+
+    @staticmethod
+    def prefill_pairs(dims, tb: int, t0: int) -> dict:
+        """The expanded form's: every head its own keys, padded to
+        whole 128-lane tiles (``latent_attention_expanded``)."""
+        spec = dims.latent
+        pairs = causal_pairs(tb, t0, latent.lanes(spec.nope + spec.rope),
+                             dims.compute_dtype or "float32")
+        if pairs is None:
+            return {}
+        heads = dims.n_layers * dims.n_heads
+        return {"flash_pairs_need": heads * pairs[0],
+                "flash_pairs_done": heads * pairs[1]}
 
     @classmethod
     def step_reads(cls, pager, dims, at, row_pages: int) -> dict:

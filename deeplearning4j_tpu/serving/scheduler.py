@@ -428,10 +428,10 @@ class DecodeScheduler:
                   ctr):
             kv = []
             attend = self.pager.cache.prefill(
-                model, lambda li, *kept: kv.append(kept))
+                model, lambda li, *kept: kv.append(kept), t0[None])
             pairs = [] if self.expert_layers else None
             # the experts route the prompt's rows, not the bucket's
-            # padding
+            # padding, and the flash kernel stops at the prompt's end
             x = di.stack(params, prompt_pad, model, attend, "prefill",
                          counts=pairs,
                          live=jnp.arange(prompt_pad.shape[1])[None] < t0)
@@ -631,6 +631,7 @@ class DecodeScheduler:
                     (self._temp_one if temp is None
                      else jnp.asarray(temp, jnp.float32)),
                     self._topp_dev, jnp.asarray(self._ctr, jnp.int32))
+            flash = {}      # a bucket's attention, in (query, key) pairs
             if chunked:
                 where = self.pager.cache.chunk.where(slot, pages, row)
                 for c in range(n_chunks):
@@ -645,6 +646,8 @@ class DecodeScheduler:
                     self.pager.prompt_pages(slot, pages, tb, t0),
                     jnp.asarray(pad), *tail)
                 self.pager.pool = pool
+                # (counted while the device runs the admission)
+                flash = self.pager.cache.prefill_pairs(self.model, tb, t0)
             ts2 = obs.now()
             first = int(np.asarray(g0)[0])  # blocking device sync
             pairs = int(np.asarray(pairs[0])) if (
@@ -662,6 +665,7 @@ class DecodeScheduler:
                               "chunks": n_chunks,
                               **({"expert_pairs": pairs}
                                  if self.expert_layers else {}),
+                              **flash,
                               "rid": getattr(req, "rid", None)},
                         cause=self.cause)
         obs.metrics.SERVING_PREFILL.observe(ts3 - ts0)

@@ -535,6 +535,8 @@ class CausalTransformerLM(ZooModel):
         final norm and the head run on that ONE row, never the
         [B, Tb, V] cube."""
         bsz, tb = toks.shape
+        # the flash kernel spends nothing on the padding's rows
+        lengths = jnp.full((bsz,), t0, jnp.int32)
         if self.mixer == "power_retention":
             cache = di.RetentionRows(
                 self, 0, jnp.broadcast_to(
@@ -558,18 +560,18 @@ class CausalTransformerLM(ZooModel):
                 self.hybrid, mamba2=rows, softmax=di.Attend(
                     di.causal_prefill(
                         self, lambda li, k, v: kv.append(di.dense_kv(
-                            k, v, cache_len, False))))).attend
+                            k, v, cache_len, False)), lengths))).attend
             caches = (kv, rows.caches)
         elif self.mixer == "latent":
             caches = []
             attend = di.latent_prefill(
                 self, lambda li, rows: caches.append(jnp.pad(
-                    rows, ((0, 0), (0, cache_len - tb), (0, 0)))))
+                    rows, ((0, 0), (0, cache_len - tb), (0, 0)))), lengths)
         else:
             caches = []
             attend = di.causal_prefill(
                 self, lambda li, k, v: caches.append(di.dense_kv(
-                    k, v, cache_len, bool(self.cache_quant))))
+                    k, v, cache_len, bool(self.cache_quant))), lengths)
         # (expert layers route the prompt's rows, not the padding)
         x = di.stack(params, toks, self, attend, "prefill",
                      live=jnp.broadcast_to(jnp.arange(tb)[None] < t0,

@@ -519,6 +519,14 @@ def test_prefill_then_paged_decode_matches_the_reference_logits(
         assert np.abs(got - other[1:]).max() > 100 * LOGIT_TOL, fault
 
 
+def test_bucket_prefill_by_the_flash_kernel_equals_the_einsum_path(
+        latent_lm, admits_alike_by_einsum_and_kernel):
+    """The expanded form's keys (48 wide here, padded to one 128-lane
+    tile) of every head through the kernel, the length beside them."""
+    admits_alike_by_einsum_and_kernel(*latent_lm, block=16,
+                                      max_context=128)
+
+
 def test_bf16_serving_stays_within_bf16_of_the_reference():
     """The same comparison in the compute dtype the cell serves in:
     weights kept as their bf16 rounding (the router in float32), the

@@ -2,13 +2,16 @@
 compiles with Mosaic on TPU). Reference coverage: libnd4j
 encode_threshold/decode_threshold ops and the attention platform-helper
 dispatch (SURVEY §2.1 platform helpers, §3.5 gradient compression)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops import pallas_kernels as pk
-from deeplearning4j_tpu.nn.layers.attention import scaled_dot_attention
+from deeplearning4j_tpu.nn.layers.attention import (plain_attention,
+                                                    scaled_dot_attention)
 
 
 # ---------------------------------------------------------------------------
@@ -266,6 +269,216 @@ def test_flash_block_bwd_composes(rng):
         argnums=(0, 1, 2))(q, k, v)
     for a, b in zip((dq, dk, dv), want):
         assert float(jnp.max(jnp.abs(a - b))) < 5e-5
+
+
+# -- the causal inference forward (a bucket's prefill) -----------------------
+_BUCKET, _BQ, _BK = 1024, 128, 256
+#: 1, a key block's edge - 1, the edge, the edge + 1, the bucket
+_LENGTHS = (1, _BK - 1, _BK, _BK + 1, _BUCKET)
+
+
+@functools.lru_cache(maxsize=None)
+def _prefill_case(window, groups, lanes):
+    """Operands of one (window, group, lanes) shape, the jitted kernel
+    path and the plain form of them: the lengths are traced, so the
+    five of a shape share ONE compilation."""
+    rng = np.random.default_rng(groups * 1000 + lanes)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, _BUCKET, h, lanes)),
+                           jnp.float32) for h in (groups, 1, 1))
+    run = jax.jit(lambda n: pk.flash_attention(
+        q, k, v, causal=True, window=window, lengths=n, block_q=_BQ,
+        block_k=_BK))
+    return run, np.asarray(plain_attention(q, k, v, causal=True,
+                                           window=window))
+
+
+@pytest.mark.parametrize("lanes", [128, 192], ids=["128", "192_to_256"])
+@pytest.mark.parametrize("groups", [1, 7, 8])
+@pytest.mark.parametrize("window", [None, 512, 2 * _BUCKET],
+                         ids=["full", "512", "wider_than_the_bucket"])
+@pytest.mark.parametrize("n", _LENGTHS)
+def test_prefill_path_matches_plain_attention_below_the_length(
+        n, window, groups, lanes):
+    """The causal inference path (interpret mode; ``block_q`` 128 under
+    ``block_k`` 256 and its half, so a q block's edges fall inside a
+    key block)
+    against the einsum on the rows below each batch row's length, two
+    different lengths a call; rows at and past a length come back
+    ZERO, whatever their block held."""
+    run, want = _prefill_case(window, groups, lanes)
+    lengths = (n, _LENGTHS[(_LENGTHS.index(n) + 2) % len(_LENGTHS)])
+    got = np.asarray(run(jnp.asarray(lengths, jnp.int32)))
+    assert got.shape == want.shape
+    for b, nb in enumerate(lengths):
+        assert np.abs(got[b, :nb] - want[b, :nb]).max() < 2e-5, (b, nb)
+        assert (got[b, nb:] == 0).all(), (b, nb)
+
+
+def test_prefill_path_takes_a_causal_suffix_and_no_lengths(rng):
+    """192 queries against 256 keys (the END-ALIGNED diagonal: a
+    static query offset of 64) and a call without lengths (every row
+    live: what an evaluation's forward asks for) take the path too."""
+    q = jnp.asarray(rng.standard_normal((2, 192, 4, 32)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, 256, 2, 32)),
+                        jnp.float32) for _ in range(2))
+    assert pk._prefill_qualifies(q[0].swapaxes(0, 1), k[0].swapaxes(0, 1),
+                                 None, None, True, 64, None, 32, 64)
+    for window in (None, 40):
+        want = plain_attention(q, k, v, causal=True, window=window)
+        got = pk.flash_attention(q, k, v, causal=True, window=window,
+                                 block_q=32, block_k=64)
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-5
+        short = pk.flash_attention(
+            q, k, v, causal=True, window=window, block_q=32, block_k=64,
+            lengths=jnp.asarray([70, 192], jnp.int32))
+        assert float(jnp.max(jnp.abs(short[0, :70] - want[0, :70]))) < 2e-5
+        assert float(jnp.max(jnp.abs(short[1] - want[1]))) < 2e-5
+        assert not np.asarray(short[0, 70:]).any()
+
+
+def _visible(t, n, window, q_off=0):
+    """[t, q_off + t] bool: what query row r < n sees."""
+    r = np.arange(t)[:, None] + q_off
+    j = np.arange(q_off + t)[None, :]
+    see = (j <= r) & (np.arange(t)[:, None] < n)
+    return see if window is None else see & (j > r - window)
+
+
+@pytest.mark.parametrize("bq,half", [(64, 64), (128, 256), (256, 128),
+                                     (32, 256), (512, 512)])
+@pytest.mark.parametrize("window", [None, 24, 512, 2048],
+                         ids=["full", "24", "512", "wider"])
+@pytest.mark.parametrize("n", _LENGTHS)
+def test_prefill_visits_are_the_blocks_that_hold_a_visible_pair(
+        n, window, bq, half):
+    """``prefill_visits`` against a brute-force enumeration of the
+    (q block, half key block) pairs with at least one visible (query <
+    length, key) pair: the wide blocks and the half after them cover
+    exactly those, end to end; and ``prefill_pairs`` adds up what the
+    tokens see and what the blocks cover."""
+    t = _BUCKET
+    see = _visible(t, n, window)
+    some = see.reshape(t // bq, bq, t // half, half).any(axis=(1, 3))
+    done = 0
+    for i in range(-(-n // bq)):
+        lo, wide, narrow = pk.prefill_visits(i, n, bq, half, window,
+                                             xp=pk._Ints)
+        assert narrow in (0, 1) and wide >= 0
+        assert list(range(lo, lo + 2 * wide + narrow)) \
+            == list(np.flatnonzero(some[i])), i
+        done += (2 * wide + narrow) * bq * half
+    assert not some[-(-n // bq):].any()
+    need = int(see.sum())
+    assert need == sum(min(r + 1, window or t) for r in range(n))
+    blocks = pk._prefill_blocks(t, t, 128, window, None, None)
+    if (bq, half) == (blocks[0], blocks[-1]):
+        assert pk.prefill_pairs(t, n, window, 128, 2) == (need, done)
+    assert pk.prefill_pairs(t, n, window, 128, 2)[0] == need
+
+
+@pytest.mark.parametrize("window", [None, 96, 4096],
+                         ids=["full", "96", "wider"])
+@pytest.mark.parametrize("t,bq,bk", [(256, 32, 64), (512, 64, 256)],
+                         ids=["its_own_half", "halved"])
+def test_prefill_kernel_turns_exactly_the_counted_blocks(rng, t, bq, bk,
+                                                         window):
+    """The kernel in interpret mode with a counter in its loops: every
+    grid step takes exactly the turns ``prefill_visits`` gives its q
+    block at its batch row's length, a dead q block none: a whole key
+    block a turn, then the half, where a block halves into 128-lane
+    tiles; where it is its own half, a turn a block, EVERY one of
+    them."""
+    b, h, hkv, d = 2, 4, 2, 32
+    half = pk._prefill_blocks(t, t, d, window, bq, bk)[-1]
+    assert half == (bk if bk % 256 else bk // 2)
+    q = jnp.asarray(rng.standard_normal((b * h, t, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((b * hkv, t, d)), jnp.float32)
+            for _ in range(2))
+    lengths = (t // 2 - 31, t)
+    _, seen = pk._prefill_fwd(q, k, v, jnp.asarray(lengths, jnp.int32),
+                              h // hkv, window, 0, bq, bk,
+                              count_visits=True)
+    seen = np.asarray(seen).reshape(b, hkv, h // hkv, t // bq)
+    turns = 0
+    for bi, n in enumerate(lengths):
+        for i in range(t // bq):
+            _, wide, narrow = (pk.prefill_visits(i, n, bq, half, window,
+                                                 xp=pk._Ints)
+                               if i * bq < n else (0, 0, 0))
+            want = wide + narrow if half != bk else 2 * wide + narrow
+            assert (seen[bi, :, :, i] == want).all(), (bi, i)
+            turns += want
+    assert turns > 0
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["full", "200"])
+@pytest.mark.parametrize("t,block_k", [(128, None), (512, 128), (1024, 384),
+                                       (640, None), (512, 33)])
+def test_prefill_path_folds_every_block_where_a_block_is_its_own_half(
+        rng, t, block_k, window):
+    """A key block that does not halve into whole 128-lane tiles (128,
+    384, 640 keys: what a bucket under 1,024 rows gets on the chip; 33
+    for an edge inside every block) is walked whole, block after
+    block, up to the diagonal: the rows below each length against the
+    einsum, zeros past it."""
+    bk, *_, half = pk._prefill_blocks(t, t, 32, window, 64, block_k)[1:]
+    assert half == bk == (block_k or t)
+    q = jnp.asarray(rng.standard_normal((2, t, 4, 32)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((2, t, 2, 32)), jnp.float32)
+            for _ in range(2))
+    lengths = (t // 2 + 1, t)
+    want = np.asarray(plain_attention(q, k, v, causal=True, window=window))
+    got = np.asarray(pk.flash_attention(
+        q, k, v, causal=True, window=window, block_q=64, block_k=block_k,
+        lengths=jnp.asarray(lengths, jnp.int32)))
+    for b, n in enumerate(lengths):
+        assert np.abs(got[b, :n] - want[b, :n]).max() < 2e-5, (b, n)
+        assert (got[b, n:] == 0).all(), (b, n)
+
+
+#: sha256[:16] of the lowered text (interpret mode: no source
+#: locations) of three calls that do NOT qualify for the causal
+#: inference path, as the commit before PR 51 lowered them
+_OLD_PATH_TEXT = {
+    "lse_asked": "8c1f21cf509b11a4",
+    "traced_offsets": "f26b0573d89aa182",
+    "kv_past_the_budget": "7c823eebc75c5c6e",
+    "kv_past_the_budget_window": "ca0afbe240efebe7",
+    "key_mask": "7f5cb0d5d8ec5cfc",
+}
+
+
+def _old_path_call(case):
+    sds, f32 = jax.ShapeDtypeStruct, jnp.float32
+    x = sds((2, 256, 4, 32), f32)
+    kv = sds((2, 256, 2, 32), f32)
+    if case == "lse_asked":         # the training forward and backward
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            pk.flash_attention(q, k, v, causal=True)),
+            argnums=(0, 1, 2))).lower(x, kv, kv)
+    if case == "traced_offsets":    # ring attention's composition
+        return jax.jit(lambda q, k, v, o: pk.flash_block_fwd(
+            q, k, v, None, o, True)).lower(
+                *(sds((8, 256, 32), f32),) * 3, sds((2,), jnp.int32))
+    if case == "key_mask":
+        return jax.jit(lambda q, k, v, m: pk.flash_attention(
+            q, k, v, causal=True, mask=m)).lower(
+                x, kv, kv, sds((2, 256), f32))
+    big = sds((1, 16384, 2, 128), f32)      # K and V: 16 MiB a head
+    window = 4096 if case.endswith("window") else None
+    return jax.jit(lambda q, k, v: pk.flash_attention(
+        q, k, v, causal=True, window=window)).lower(big, big, big)
+
+
+@pytest.mark.parametrize("case", sorted(_OLD_PATH_TEXT))
+def test_calls_that_do_not_qualify_lower_to_the_text_they_had(case):
+    """``lse`` asked, traced offsets, a key mask, K and V past the VMEM
+    budget: ``_flash_kernel`` exactly as before the causal inference
+    path existed."""
+    import hashlib
+    text = _old_path_call(case).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _OLD_PATH_TEXT[case]
 
 
 @_SLOW

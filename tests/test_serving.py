@@ -246,6 +246,17 @@ def test_paged_kernel_in_the_step_token_identical_to_dense(monkeypatch):
     sched.pager.check_invariants()
 
 
+def test_bucket_prefill_by_the_flash_kernel_equals_the_einsum_path(
+        admits_alike_by_einsum_and_kernel):
+    """Full softmax layers (4 heads over 2 kv heads): the admission
+    hands the kernel the prompt's length."""
+    model = CausalTransformerLM(vocab_size=64, hidden=64, n_layers=2,
+                                n_heads=4, n_kv_heads=2, max_len=128,
+                                seed=5)
+    admits_alike_by_einsum_and_kernel(model, model.init(), block=8,
+                                      max_context=128)
+
+
 # =========================================================================
 # pager invariants
 # =========================================================================

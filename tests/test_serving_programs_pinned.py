@@ -37,6 +37,15 @@ rule each and the attention an output gate (``decoder_infer.qkv``,
 earlier decoders moved: a decoder with one head count, one plain
 rule and no gate traces none of it. Its own cell's programs (the
 gated windowed decoder) are pinned from PR 50 on.
+PR 51 MEANT to change the bucket prefills of the four decoders that
+admit by buckets, with the kernels forced: their attention is the
+flash kernel's causal inference path now (``_prefill_kernel``: the
+key loop inside the kernel, the prompt's length an operand), so the
+eight ``admit16`` / ``admit64`` hashes under ``kernels`` are PR 51's
+(a toy bucket's ONE 128-key block is its own half, here as on the
+chip, and is walked by the kernel's loop of whole blocks).
+Every step, every chunk program and every ``plain`` program kept its
+text: the einsum path is handed no length.
 """
 import hashlib
 import importlib.util
@@ -57,8 +66,8 @@ PINNED = {
         "plain": {"step": "c1504e32290e447a", "admit16": "8942b110f3b5152a",
                   "admit64": "85305781cd81e6a6"},
         "kernels": {"step": "f05e0258633bcbd1",
-                    "admit16": "31f012447a4a9ffe",
-                    "admit64": "4730be7afa423d41"}},
+                    "admit16": "21b442f67e06be11",
+                    "admit64": "e65e34e730125d91"}},
     "brumby14b.decode-saturated": {
         "plain": {"step": "10bffa502cea6987", "chunk": "1e9c9d88a2f52f80"},
         "kernels": {"step": "4aeb03494500f1bf",
@@ -67,8 +76,8 @@ PINNED = {
         "plain": {"step": "aa9309f15c76f849", "admit16": "688578e8ff9f0e2c",
                   "admit64": "cf8aaf7566861420"},
         "kernels": {"step": "9d1f7fde90657376",
-                    "admit16": "6e0fc83288a27d98",
-                    "admit64": "aeaad40084a304d7"}},
+                    "admit16": "6782de41d4866d5a",
+                    "admit64": "c63ca8828efb0679"}},
     "granite4h.chat-saturated": {
         "plain": {"step": "ccc14242d5877cf1", "chunk": "9ba93a13f711d334"},
         "kernels": {"step": "8ae93b0aba4ee239",
@@ -77,14 +86,14 @@ PINNED = {
         "plain": {"step": "44b7c200447d1595", "admit16": "569daaa68cfb95fa",
                   "admit64": "2a49ae68fab2c748"},
         "kernels": {"step": "28a8d40a4afd12b5",
-                    "admit16": "cefdeac01637db08",
-                    "admit64": "95cac0be5747dad7"}},
+                    "admit16": "266ffac52296ed09",
+                    "admit64": "a630e7e39d8d1d3a"}},
     "lagunaxs2.agent-saturated": {
         "plain": {"step": "ea01ab8afcc793a5", "admit16": "a5376d17a1147d77",
                   "admit64": "99cac54177b4dcd8"},
         "kernels": {"step": "a440c7e9228865bb",
-                    "admit16": "76ab924a15c8a075",
-                    "admit64": "d26c2639d5e98fbd"}},
+                    "admit16": "38b9427e4fcadf46",
+                    "admit64": "048417f8dbdfaffd"}},
 }
 
 #: kernel form -> sha256[:16] of its jitted call's lowered text, in

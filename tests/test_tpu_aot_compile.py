@@ -19,6 +19,7 @@ interpret branch here, so the fixture pins ``_interpret`` to False in
 the two kernel modules for the duration of the module.
 """
 import os
+import re
 
 import pytest
 
@@ -104,6 +105,63 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(chip, case):
                    *shapes)
     # forward + backward (fused, or dQ and dK/dV) kernels
     assert hlo.count("tpu_custom_call") >= 2, case
+
+
+# the bucket prefill's attention in the three serving cells that admit
+# by buckets with differing head shapes: [heads, kv heads, lanes, window]
+PREFILL_SHAPES = {
+    "smallthinker_full": (28, 4, 128, None),
+    "smallthinker_window": (28, 4, 128, 4096),
+    "laguna_full": (48, 8, 128, None),
+    "laguna_window": (64, 8, 128, 512),
+    "deepseek_expanded": (128, 128, 256, None),
+}
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192])
+@pytest.mark.parametrize("shape", sorted(PREFILL_SHAPES))
+def test_prefill_path_compiles_for_v5e(chip, shape, bucket):
+    """The causal inference path (the key loop inside the kernel, K and
+    V of a kv head whole in VMEM, the prompt's length prefetched) at
+    the serving cells' head shapes and their two largest buckets: ONE
+    Mosaic kernel under the registry's scope, within the VMEM limit
+    it states (Mosaic refuses a kernel whose scoped VMEM passes it)."""
+    h, h_kv, d, window = PREFILL_SHAPES[shape]
+    q = jax.ShapeDtypeStruct((1, bucket, h, d), BF16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, bucket, h_kv, d), BF16, sharding=chip)
+    n = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    assert pallas_kernels._prefill_fits(bucket, bucket, d, window, 2)
+    hlo = jax.jit(lambda q, k, v, n: pallas_kernels.flash_attention(
+        q, k, v, causal=True, window=window, lengths=n)).lower(
+            q, kv, kv, n).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    assert len(calls) == 1 and "dl4j.ops.flash_attention" in calls[0]
+    stated, used = (int(re.search(
+        key + r'":\[\{"memory_space":"1","offset":"0","size":"(\d+)"',
+        calls[0]).group(1)) for key in ("scoped_memory_configs",
+                                        "used_scoped_memory_configs"))
+    assert stated == pallas_kernels._PREFILL_VMEM_BYTES
+    assert 0 < used <= stated
+
+
+@pytest.mark.parametrize("bucket", [128, 384, 640, 896])
+def test_prefill_path_compiles_where_a_key_block_is_its_own_half(chip,
+                                                                 bucket):
+    """A bucket under 1,024 rows (``DL4J_TPU_FLASH_MIN_T`` lowered, a
+    direct call) whose ONE key block does not halve into whole
+    128-lane tiles: the kernel's single loop of whole blocks compiles
+    (``tests/test_pallas.py`` holds its values to the einsum's)."""
+    h, h_kv, d, _ = PREFILL_SHAPES["smallthinker_full"]
+    assert pallas_kernels._prefill_blocks(
+        bucket, bucket, d, None, None, None)[-1] == bucket
+    q = jax.ShapeDtypeStruct((1, bucket, h, d), BF16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, bucket, h_kv, d), BF16, sharding=chip)
+    n = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=chip)
+    hlo = jax.jit(lambda q, k, v, n: pallas_kernels.flash_attention(
+        q, k, v, causal=True, lengths=n)).lower(
+            q, kv, kv, n).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 1
 
 
 def _norm_fwd_bwd(kind):

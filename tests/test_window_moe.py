@@ -862,6 +862,14 @@ def _served_logits(model, net, seq, t0):
     return req.tokens[0], np.stack(rows)
 
 
+def test_bucket_prefill_by_the_flash_kernel_equals_the_einsum_path(
+        window_lm, admits_alike_by_einsum_and_kernel):
+    """Window layers (32 keys) beside a full one: a prompt of 33
+    tokens in a bucket of 64 rows, the length handed to both kinds."""
+    admits_alike_by_einsum_and_kernel(*window_lm, block=BLOCK,
+                                      max_context=128)
+
+
 #: float32 on the CPU, logits up to 3 in size: the bucket prefill and
 #: the decode over pages differ from the reference's one pass in the
 #: order of float32 sums only (read: 3e-6 at most over the three

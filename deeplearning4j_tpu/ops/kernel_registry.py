@@ -94,7 +94,11 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
         "scope": "ops.retention_decode",
         # the retention model's decode blocks: their mixer streams the
         # state pages through VMEM (the projections stay XLA; the MLP
-        # is the block's other half, ``.ffn``, and stays an open scope)
+        # is the block's other half, ``.ffn``, and stays an open scope).
+        # Items are the live (slot, head) pairs, two a group, a state
+        # ONE copy each way, two groups of buffers read into and two
+        # written back from; reads and write-backs take turns; an
+        # inactive slot costs a compare
         "closes": ("retention_decode.block_*.mixer",),
         "gate": "retention_decode",
     },
@@ -105,7 +109,11 @@ KERNEL_REGISTRY: Dict[str, Dict[str, Any]] = {
             "tests/test_ssm.py::test_ssm_decode_matches_reference",
         "scope": "ops.ssm_decode",
         # a hybrid decoder's decode blocks share the softmax model's
-        # scope names; paged_decode_attention already claims them
+        # scope names; paged_decode_attention already claims them.
+        # Items are the live slots, eight a group, a state eight column
+        # parts a copy each, two groups of buffers updated in place;
+        # reads and write-backs take turns; an inactive slot costs a
+        # compare
         "closes": (),
         "gate": "ssm_decode",
     },

@@ -34,6 +34,11 @@ Kernels:
   LATENT pool of a latent-attention model: a page is the ``[block,
   kv_rank + rope]`` matrix every absorbed query head reads at once,
   and the walk is one pipeline over all slots of a call.
+- ``retention_decode`` / ``ssm_decode`` — one decode position of a
+  power-retention block or a Mamba-2 layer for every live slot, the
+  float32 recurrent state updated in place in the paged state pool:
+  no grid, the call's live items walked in groups, the groups' reads
+  and write-backs taking turns at the memory.
 - ``threshold_encode`` / ``threshold_decode`` — fused gradient
   threshold compression (reference libnd4j ops ``encode_threshold`` /
   ``decode_threshold``): one VMEM pass computes the ternary
@@ -1853,15 +1858,24 @@ def latent_decode_attention(q, pool, li, pt, n_live, scale: float,
 #
 # One decode position of a power-retention block (ops/retention.py has
 # the mathematics and the stored layout) for every live slot, in place:
-# the grid walks slots x kv heads; each step streams ONE head's state
-# ``[rows, d]`` HBM -> VMEM (Pallas' own double-buffered pipeline, the
-# block chosen through the scalar-prefetched page ids), computes
-# ``g * S + phi(k) v^T`` tile by tile, reads the group's query heads
-# from the same tiles, and writes the state back through
-# ``input_output_aliases``. An inactive slot maps to the block of the
-# last live (slot, head) before it and skips its body: the pipeline
-# sees an unchanged block index, so nothing of that slot is fetched or
-# written.
+# ``g * S + phi(k) v^T`` tile by tile for ONE kv head's state ``[rows,
+# d]`` float32 at a time, the group's query heads read from the same
+# tiles, the state and its normaliser ``[d, d]`` written back to their
+# pages (``input_output_aliases``).
+#
+# The state crosses as ``_ssm_decode_kernel``'s does (the comment
+# there, and PERF.md §5 "The state kernels, taken apart"): no grid, the
+# call's live (slot, head) ITEMS walked in groups of ``group``, the two
+# directions taking turns: group k + 1 is read, then group k written
+# back while group k + 1 is multiplied. A state is ONE copy each way
+# (its rows are contiguous; parts gave nothing). The multiplication
+# reads one ALLOCATION and writes another, as Pallas' own pipeline had
+# it: where old and new state are views of one scratch array (updated
+# in place, or rotating through three buffers), the compiler cannot
+# tell a tile's load from the store before it and the body runs 1.38
+# ms a call for 0.90. So: two groups of buffers read into, two written
+# back from, by the group's parity. An inactive slot is no item: its
+# pages are neither read nor written and its output rows are zeros.
 #
 # The arithmetic is the VPU's: the state is float32 and a float32
 # matmul on the MXU would push every state tile through it as weights,
@@ -1870,144 +1884,235 @@ def latent_decode_attention(q, pool, li, pt, n_live, scale: float,
 # sublane broadcasts, ``k_j v`` and ``q_j`` as whole tiles.
 
 
-def _retention_decode_kernel(li_ref, page_ref, head_ref, act_ref,
-                             q_ref, k_ref, v_ref, g_ref, s_in, z_in,
-                             o_ref, s_out, z_out, kb, qb, kv, *,
-                             nb: int, groups: int, eps: float):
-    # scalar prefetch: li [1], page/head/act [S]. q_ref [G, d], k_ref /
-    # v_ref / g_ref [1, d] (g repeated along the lanes), s_in/s_out
-    # [rows, d], z_in/z_out [d, d], o_ref [G, d]; scratch kb [d, d]
-    # (row j = k_j on every lane), qb [G, d, d] likewise, kv [d, d]
-    # (row j = k_j * v)
-    del li_ref, page_ref, head_ref
+def _list_live(act_ref, live):
+    """Write the live slots' numbers to ``live`` (SMEM), in order;
+    returns their count (the two state kernels' item list)."""
+    def scan(s, m):
+        @pl.when(act_ref[s] != 0)
+        def _():
+            live[m] = s
+        return m + jnp.where(act_ref[s] != 0, 1, 0)
+
+    return lax.fori_loop(0, act_ref.shape[0], scan, 0)
+
+
+def _take_turns(n_items, group: int, copies, multiply):
+    """The two state kernels' pipeline over ``n_items`` live items in
+    groups of ``group``: ``copies(k, count, back, start)`` issues
+    (``start``) or awaits the copies of group ``k``'s first ``count``
+    items, in or ``back``; ``multiply(k, count)`` updates them in VMEM.
+    Reads and write-backs take TURNS at the memory: group k - 1 goes
+    back while group k is multiplied, and only when its last copy is
+    out is group k + 1 fetched (two groups of buffers by a group's
+    parity: group k + 1 lands where group k - 1 lay)."""
+    n_groups = (n_items + group - 1) // group
+
+    def count(k):
+        return jnp.clip(n_items - k * group, 0, group)
+
+    # the first TWO groups are read at once (nothing is there to be
+    # written yet), so the first is multiplied under the second's flight
+    copies(0, count(0), back=False, start=True)
+    copies(1, count(1), back=False, start=True)
+    copies(0, count(0), back=False, start=False)
+
+    def phase(k, carry):
+        before = jnp.where(k > 0, count(k - 1), 0)
+        copies(k - 1, before, back=True, start=True)
+        multiply(k, count(k))
+        copies(k - 1, before, back=True, start=False)
+        copies(k + 1, jnp.where(k > 0, count(k + 1), 0), back=False,
+               start=True)
+        copies(k + 1, count(k + 1), back=False, start=False)
+        return carry
+
+    lax.fori_loop(0, n_groups, phase, 0)
+    last = jnp.where(n_groups > 0, count(n_groups - 1), 0)
+    copies(n_groups - 1, last, back=True, start=True)
+    copies(n_groups - 1, last, back=True, start=False)
+
+
+#: bytes of state one phase moves in one direction: the (slot, head)
+#: items a group are read from this against the state's shape
+_RETENTION_PHASE_BYTES = 10 * 1024 * 1024
+
+
+def _retention_form(n_items: int, rows: int, d: int) -> int:
+    """The (slot, head) items a phase, of ``n_items`` with a state
+    ``[rows, d]`` and a normaliser ``[d, d]`` float32 each."""
+    group = _RETENTION_PHASE_BYTES // (4 * (rows + d) * d)
+    return max(1, min(group, n_items))
+
+
+def _retention_item(q_ref, k_ref, v_ref, g_ref, s_in, z_in, o_ref, s_out,
+                    z_out, kb, qb, kv, *, nb: int, groups: int, eps: float):
+    # one (slot, head): q_ref / o_ref [G, d], k_ref / v_ref / g_ref
+    # [1, d] (g repeated along the lanes), s_in / s_out [rows, d], z_in
+    # / z_out [d, d]; scratch kb [d, d] (row j = k_j on every lane), qb
+    # [G, d, d] likewise, kv [d, d] (row j = k_j * v)
     d = 8 * nb
+    g8 = jnp.broadcast_to(g_ref[...], (8, d))
+    k_row = k_ref[...]
+    kb[...] = jnp.broadcast_to(k_row, (d, d)).T
+    for h in range(groups):
+        qb[h] = jnp.broadcast_to(q_ref[h:h + 1, :], (d, d)).T
+    kv[...] = kb[...] * v_ref[...]
+    sub = lax.broadcasted_iota(jnp.int32, (8, d), 0)
+    zeros = tuple(jnp.zeros((8, d), jnp.float32) for _ in range(groups))
 
-    @pl.when(act_ref[pl.program_id(0)] != 0)
-    def _():
-        g8 = jnp.broadcast_to(g_ref[...], (8, d))
-        k_row = k_ref[...]
-        kb[...] = jnp.broadcast_to(k_row, (d, d)).T
-        for h in range(groups):
-            qb[h] = jnp.broadcast_to(q_ref[h:h + 1, :], (d, d)).T
-        kv[...] = kb[...] * v_ref[...]
-        sub = lax.broadcasted_iota(jnp.int32, (8, d), 0)
-        zeros = tuple(jnp.zeros((8, d), jnp.float32)
-                      for _ in range(groups))
+    def tiles(base, i0, weight, acc):
+        # the 8 tiles of one stored block: tile a holds the pairs
+        # (i0 + a, 8J .. 8J+7); ``weight(a)`` is k_j v with the
+        # write side's multiplicity
+        ki = kb[pl.ds(i0, 8), :]
+        qi = [qb[h, pl.ds(i0, 8), :] for h in range(groups)]
+        acc = list(acc)
+        for a in range(8):
+            rows = pl.ds(pl.multiple_of(base + 8 * a, 8), 8)
+            t = (g8 * s_in[rows, :] + jnp.broadcast_to(
+                ki[a:a + 1, :], (8, d)) * weight(a))
+            s_out[rows, :] = t
+            for h in range(groups):
+                acc[h] = acc[h] + jnp.broadcast_to(
+                    qi[h][a:a + 1, :], (8, d)) * t
+        return tuple(acc)
 
-        def tiles(base, i0, weight, acc):
-            # the 8 tiles of one stored block: tile a holds the pairs
-            # (i0 + a, 8J .. 8J+7); ``weight(a)`` is k_j v with the
-            # write side's multiplicity
-            ki = kb[pl.ds(i0, 8), :]
-            qi = [qb[h, pl.ds(i0, 8), :] for h in range(groups)]
-            acc = list(acc)
-            for a in range(8):
-                rows = pl.ds(pl.multiple_of(base + 8 * a, 8), 8)
-                t = (g8 * s_in[rows, :] + jnp.broadcast_to(
-                    ki[a:a + 1, :], (8, d)) * weight(a))
-                s_out[rows, :] = t
-                for h in range(groups):
-                    acc[h] = acc[h] + jnp.broadcast_to(
-                        qi[h][a:a + 1, :], (8, d)) * t
-            return tuple(acc)
+    def column(jb, out):
+        j0 = pl.multiple_of(jb * 8, 8)
+        kvj = kv[pl.ds(j0, 8), :]
+        kvj2 = kvj + kvj
+        first = jb * (jb + 1) // 2      # stored blocks before J's
 
-        def column(jb, out):
-            j0 = pl.multiple_of(jb * 8, 8)
-            kvj = kv[pl.ds(j0, 8), :]
-            kvj2 = kvj + kvj
-            first = jb * (jb + 1) // 2      # stored blocks before J's
+        def block(ib, acc):
+            return tiles((first + ib) * 64,
+                         pl.multiple_of(ib * 8, 8), lambda a: kvj2,
+                         acc)
 
-            def block(ib, acc):
-                return tiles((first + ib) * 64,
-                             pl.multiple_of(ib * 8, 8), lambda a: kvj2,
-                             acc)
+        acc = lax.fori_loop(0, jb, block, zeros)
+        # the diagonal block: 1 on the diagonal, 2 above it, 0 for
+        # the mirror images below
+        acc = tiles((first + jb) * 64, j0, lambda a: kvj * jnp.where(
+            sub > a, 2.0, jnp.where(sub == a, 1.0, 0.0)), acc)
+        return tuple(out[h] + qb[h, pl.ds(j0, 8), :] * acc[h]
+                     for h in range(groups))
 
-            acc = lax.fori_loop(0, jb, block, zeros)
-            # the diagonal block: 1 on the diagonal, 2 above it, 0 for
-            # the mirror images below
-            acc = tiles((first + jb) * 64, j0, lambda a: kvj * jnp.where(
-                sub > a, 2.0, jnp.where(sub == a, 1.0, 0.0)), acc)
-            return tuple(out[h] + qb[h, pl.ds(j0, 8), :] * acc[h]
-                         for h in range(groups))
-
-        num = lax.fori_loop(0, nb, column, zeros)
-        z = (jnp.broadcast_to(g_ref[...], (d, d)) * z_in[...]
-             + kb[...] * k_row)
-        z_out[...] = z
-        for h in range(groups):
-            den = jnp.sum(jnp.sum(z * qb[h] * q_ref[h:h + 1, :], axis=0,
-                                  keepdims=True), axis=1, keepdims=True)
-            o_ref[h:h + 1, :] = (
-                jnp.sum(num[h], axis=0, keepdims=True)
-                / (den + eps)).astype(o_ref.dtype)
-
-    @pl.when(act_ref[pl.program_id(0)] == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    num = lax.fori_loop(0, nb, column, zeros)
+    z = (jnp.broadcast_to(g_ref[...], (d, d)) * z_in[...]
+         + kb[...] * k_row)
+    z_out[...] = z
+    for h in range(groups):
+        den = jnp.sum(jnp.sum(z * qb[h] * q_ref[h:h + 1, :], axis=0,
+                              keepdims=True), axis=1, keepdims=True)
+        o_ref[h:h + 1, :] = (
+            jnp.sum(num[h], axis=0, keepdims=True)
+            / (den + eps)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _retention_decode_kernel(li_ref, page_ref, act_ref, q_ref, k_ref,
+                             v_ref, g_ref, s_ref, z_ref, y_ref, s_out,
+                             z_out, live, s_old, z_old, s_new, z_new, sem,
+                             kb, qb, kv, *,
+                             nb: int, groups: int, eps: float, group: int,
+                             n_pool: int):
+    # li [1], page / act [S] in SMEM; q_ref / y_ref [S, Hkv, G, d],
+    # k_ref / v_ref / g_ref [S, Hkv, 1, d] whole in VMEM; s_ref / s_out
+    # [L * P * Hkv, rows, d] and z_ref / z_out [L * P * Hkv, d, d] in
+    # HBM, ONE array each; live [S] SMEM; s_old / s_new [2, group,
+    # rows, d] and z_old / z_new [2, group, d, d]: a group's states as
+    # read and as updated; sem [2, 2] (the group's parity, direction)
+    n_kv = q_ref.shape[1]
+    pool0 = li_ref[0] * n_pool
+    y_ref[...] = jnp.zeros_like(y_ref)
+
+    def copies(k, count, back: bool, start: bool):
+        half = k % 2
+
+        def item(a, carry):
+            i = k * group + a
+            row = (pool0 + page_ref[live[i // n_kv]]) * n_kv + i % n_kv
+            for old, new, src, dst in ((s_old, s_new, s_ref, s_out),
+                                       (z_old, z_new, z_ref, z_out)):
+                copy = pltpu.make_async_copy(
+                    *((new.at[half, a], dst.at[row]) if back
+                      else (src.at[row], old.at[half, a])),
+                    sem.at[half, int(back)])
+                copy.start() if start else copy.wait()
+            return carry
+
+        lax.fori_loop(0, count, item, 0)
+
+    def multiply(k, count):
+        half = k % 2
+
+        def item(a, carry):
+            i = k * group + a
+            s, h = live[i // n_kv], i % n_kv
+            _retention_item(
+                q_ref.at[s, h], k_ref.at[s, h], v_ref.at[s, h],
+                g_ref.at[s, h], s_old.at[half, a], z_old.at[half, a],
+                y_ref.at[s, h], s_new.at[half, a], z_new.at[half, a], kb,
+                qb, kv, nb=nb, groups=groups, eps=eps)
+            return carry
+
+        lax.fori_loop(0, count, item, 0)
+
+    _take_turns(_list_live(act_ref, live) * n_kv, group, copies, multiply)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "group", "interpret"))
 def _retention_decode_call(q, k, v, g, s_pool, z_pool, li, pages, active,
-                           eps, interpret):
+                           eps, group, interpret):
     """ONE lowering for every layer of a step (the layer index is a
     scalar operand), as :func:`_paged_decode_call`."""
     n_s, n_kv, groups, d = q.shape
+    n_l, n_p = s_pool.shape[:2]
     rows = s_pool.shape[3]
-    act = active.astype(jnp.int32)
-    # an inactive slot rides the block of the nearest live slot before
-    # it (that slot's last head), or of the first live slot (its first
-    # head) when none precedes it: an unchanged block is not moved
-    idx = jnp.arange(n_s, dtype=jnp.int32)
-    last = lax.cummax(jnp.where(act != 0, idx, -1))
-    src = jnp.where(last >= 0, last, jnp.argmax(act).astype(jnp.int32))
-    page = jnp.where(jnp.any(act != 0), pages.astype(jnp.int32)[src], 0)
-    head = jnp.where(last >= 0, n_kv - 1, 0).astype(jnp.int32)
-
-    def pooled(s, h, li_ref, page_ref, head_ref, act_ref):
-        return (li_ref[0], page_ref[s],
-                jnp.where(act_ref[s] != 0, h, head_ref[s]), 0, 0)
-
-    def row(s, h, *_):
-        return (s, h, 0, 0)
-
-    vec = pl.BlockSpec((None, None, 1, d), row)
-    state = pl.BlockSpec((None, None, None, rows, d), pooled)
-    norm = pl.BlockSpec((None, None, None, d, d), pooled)
-    qspec = pl.BlockSpec((None, None, groups, d), row)
-    y, s_pool, z_pool = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    y, s_new, z_new = pl.pallas_call(
         functools.partial(_retention_decode_kernel, nb=d // 8,
-                          groups=groups, eps=eps),
+                          groups=groups, eps=eps, group=group, n_pool=n_p),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype),
-                   jax.ShapeDtypeStruct(z_pool.shape, z_pool.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n_s, n_kv),
-            in_specs=[qspec, vec, vec, vec, state, norm],
-            out_specs=(qspec, state, norm),
-            scratch_shapes=[pltpu.VMEM((d, d), jnp.float32),
-                            pltpu.VMEM((groups, d, d), jnp.float32),
-                            pltpu.VMEM((d, d), jnp.float32)]),
-        # operands count from the scalar-prefetch ones: the pools are
-        # the 9th and 10th
-        input_output_aliases={8: 1, 9: 2},
-        # the unchanged-block rule above needs the grid in order
+                   jax.ShapeDtypeStruct((n_l * n_p * n_kv, rows, d),
+                                        s_pool.dtype),
+                   jax.ShapeDtypeStruct((n_l * n_p * n_kv, d, d),
+                                        z_pool.dtype)),
+        in_specs=[smem, smem, smem, vmem, vmem, vmem, vmem, hbm, hbm],
+        out_specs=(vmem, hbm, hbm),
+        scratch_shapes=[pltpu.SMEM((n_s,), jnp.int32)]
+        + [pltpu.VMEM((2, group, rows, d), jnp.float32),
+           pltpu.VMEM((2, group, d, d), jnp.float32)] * 2
+        + [pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((d, d), jnp.float32),
+                        pltpu.VMEM((groups, d, d), jnp.float32),
+                        pltpu.VMEM((d, d), jnp.float32)],
+        # the pools are the 8th and 9th operands
+        input_output_aliases={7: 1, 8: 2},
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_RETENTION_VMEM_BYTES),
+            vmem_limit_bytes=_retention_vmem_bytes(n_s, n_kv, groups, rows,
+                                                   d, group)),
         interpret=interpret,
         name="retention_decode",
-    )(li.reshape(1).astype(jnp.int32), page, head, act, q,
-      k[:, :, None, :], v[:, :, None, :],
+    )(li.reshape(1).astype(jnp.int32), pages.astype(jnp.int32),
+      active.astype(jnp.int32), q, k[:, :, None, :], v[:, :, None, :],
       jnp.broadcast_to(g[:, :, None, None], (n_s, n_kv, 1, d)),
-      s_pool, z_pool)
-    return y, s_pool, z_pool
+      # the layers' pages' heads end to end: bitcasts, not copies
+      s_pool.reshape(n_l * n_p * n_kv, rows, d),
+      z_pool.reshape(n_l * n_p * n_kv, d, d))
+    return y, s_new.reshape(s_pool.shape), z_new.reshape(z_pool.shape)
 
 
-#: the state block in and out, double-buffered (4 x 4.46 MB at d =
-#: 128), the normaliser's, the scratch tiles, and room for the
-#: compiler's own: over the 16 MiB the compiler allows by default
-_RETENTION_VMEM_BYTES = 48 * 1024 * 1024
+def _retention_vmem_bytes(n_s: int, n_kv: int, groups: int, rows: int,
+                          d: int, group: int) -> int:
+    """The kernel's VMEM: two groups of states and normalisers as read
+    and two as updated (4 x 2 x 4.52 MB at d = 128), the queries and
+    the output whole and ``k``, ``v``, ``g`` a padded tile a (slot,
+    head) (5 x 0.8 MB at 24 x 8), the scratch tiles, and 8 MB for the
+    compiler's own."""
+    small = n_s * n_kv * (2 * (-(-groups // 8) * 8) + 3 * 8) * d
+    return (4 * (4 * group * (rows + d) * d + small + (groups + 2) * d * d)
+            + (8 << 20))
 
 
 def _reference_retention_decode(q, k, v, g, pool, li, pages, active,
@@ -2051,6 +2156,7 @@ def retention_decode(q, k, v, g, pool, li, pages, active,
     run :func:`_reference_retention_decode`."""
     from deeplearning4j_tpu.obs import devtime
     from deeplearning4j_tpu.ops.retention import RETENTION_EPS
+    from deeplearning4j_tpu.perf import sentry
     eps = RETENTION_EPS if eps is None else float(eps)
     n_s, n_h, d = q.shape
     n_kv = k.shape[1]
@@ -2059,9 +2165,15 @@ def retention_decode(q, k, v, g, pool, li, pages, active,
                 k.astype(jnp.float32), v.astype(jnp.float32),
                 g.astype(jnp.float32))
         if _use_retention_kernel(q):
+            group = _retention_form(n_s * n_kv, *pool[0].shape[3:])
+            sentry.note_traced(
+                "retention_decode_kernels",
+                state_bytes=4 * (pool[0].shape[3] + d) * d, state_parts=1,
+                state_buffers=4 * group)
             y, s_pool, z_pool = _retention_decode_call(
                 *args, pool[0], pool[1], jnp.asarray(li, jnp.int32),
-                pages, active, eps=eps, interpret=_interpret())
+                pages, active, eps=eps, group=group,
+                interpret=_interpret())
         else:
             y, s_pool, z_pool = _reference_retention_decode(
                 *args, pool, li, pages, active, eps)
@@ -2073,104 +2185,170 @@ def retention_decode(q, k, v, g, pool, li, pages, active,
 # ---------------------------------------------------------------------------
 #
 # One decode position of a Mamba-2 layer (ops/ssm.py has the
-# mathematics and the stored layout) for every live slot, in place: the
-# grid walks the slots; a step streams ONE slot's state ``[N, H P]``
-# float32 HBM -> VMEM (Pallas' own double-buffered pipeline, the block
-# chosen through the scalar-prefetched page ids), computes ``a H +
-# B (x) (Delta x)`` a 128-lane column block at a time, contracts each
-# with ``C`` over its rows, and writes the state back through
-# ``input_output_aliases``. An inactive slot maps to the block of the
-# last live slot before it and skips its body, as in
-# ``_retention_decode_kernel``: an unchanged block index is not moved.
+# mathematics and the stored layout) for every live slot, in place:
+# ``a H + B (x) (Delta x)`` a 128-lane column block at a time, each
+# contracted with ``C`` over its rows, the state ``[N, H P]`` float32
+# read from its page and written back to it (``input_output_aliases``).
 #
-# The arithmetic is the VPU's (2.6 MFLOP against 4.2 MB moved a slot
-# and layer: the call is bound by the memory's bandwidth). The state's
-# lanes are (head, feature) columns, so the decay, ``Delta x`` and the
-# output are lane rows as the caller has them; only ``B`` and ``C``,
-# one a slot, turn into sublane columns (a broadcast and ONE square
-# transpose each).
+# The call is bound by the memory's bandwidth (2.6 MFLOP against 4.2 MB
+# moved a slot and layer), and what holds it is how the two directions
+# SHARE the memory (PERF.md §5, "The state kernels, taken apart"): a
+# read stream and a write stream in flight together reach 79% of the
+# bandwidth whatever the copies' number or size (XLA's own elementwise
+# pass in place: 80%), read alone 87% and written alone 83%. So the
+# directions take TURNS. The kernel has no grid: it lists the call's
+# live slots and walks them in GROUPS of ``group`` slots, ONE pipeline
+# over the call:
+#
+#   read group k + 1   |  (the core waits)
+#   write group k      |  group k + 1 is multiplied, in its buffer
+#   read group k + 2   |  ...
+#
+# Each state crosses as ``parts`` column ranges, a copy each (a write
+# of 128 rows x 2 KB is faster than one of 2 MB on end), all of a
+# phase's copies in flight at once; the next phase's are issued when
+# the last of this one's has landed. Two groups of buffers, updated in
+# place. An inactive slot is not on the list: it costs the scan's
+# compare, its page is neither read nor written, its output row is
+# zeros. The small operands and the output lie whole in VMEM.
+#
+# The arithmetic is the VPU's. The state's lanes are (head, feature)
+# columns, so the decay, ``Delta x`` and the output are lane rows as
+# the caller has them; only ``B`` and ``C``, one a slot, turn into
+# sublane columns (a broadcast and ONE square transpose each).
 
 #: lanes of one column block of the state
 _SSM_COLS = 128
+#: lanes of one copy of a slot's state (a column range of every row)
+_SSM_PART_COLS = 512
+#: bytes of state one phase moves in one direction: the slots a group
+#: are read from this against the state's shape. PERF.md §5 has the
+#: sweep at the serving cell's shapes
+_SSM_PHASE_BYTES = 16 * 1024 * 1024
+
+
+def _ssm_form(n_s: int, n: int, cols: int):
+    """``(group, parts)`` of ``n_s`` slots' states ``[n, cols]``
+    float32: the slots a phase and the copies a slot."""
+    parts = cols // _SSM_PART_COLS if cols % _SSM_PART_COLS == 0 else 1
+    group = _SSM_PHASE_BYTES // (4 * n * cols)
+    return max(1, min(group, n_s)), parts
 
 
 def _ssm_decode_kernel(li_ref, page_ref, act_ref, b_ref, c_ref, decay_ref,
-                       dx_ref, h_in, y_ref, h_out, *, n: int, cols: int):
-    # scalar prefetch: li [1], page / act [S]. b_ref / c_ref [1, N],
-    # decay_ref / dx_ref / y_ref [1, H P], h_in / h_out [N, H P]
-    del li_ref, page_ref
+                       dx_ref, pool_ref, y_ref, pool_out, live, buf, sem,
+                       dec, dxs, ys, *, n: int, cols: int, group: int,
+                       parts: int, n_pool: int):
+    # li [1], page / act [S] in SMEM; b_ref / c_ref [S, N], decay_ref /
+    # dx_ref / y_ref [S, H P] whole in VMEM; pool_ref / pool_out
+    # [L * P, N, H P] in HBM, ONE array; live [S] SMEM; buf [2, group,
+    # parts, N, H P / parts]; sem [2, 2] (half, direction); dec / dxs /
+    # ys [1, H P]: the slot's rows, staged
+    w = cols // parts
+    pool0 = li_ref[0] * n_pool
+    y_ref[...] = jnp.zeros_like(y_ref)
 
-    @pl.when(act_ref[pl.program_id(0)] != 0)
-    def _():
-        bcol = jnp.broadcast_to(b_ref[...], (n, n)).T   # [n, :] = B[n]
-        ccol = jnp.broadcast_to(c_ref[...], (n, n)).T
+    def copies(k, count, back: bool, start: bool):
+        half = k % 2
 
-        def column(j, carry):
-            at = pl.ds(pl.multiple_of(j * _SSM_COLS, _SSM_COLS), _SSM_COLS)
-            new = (jnp.broadcast_to(decay_ref[:, at], (n, _SSM_COLS))
-                   * h_in[:, at]
-                   + bcol * jnp.broadcast_to(dx_ref[:, at],
-                                             (n, _SSM_COLS)))
-            h_out[:, at] = new
-            y_ref[:, at] = jnp.sum(new * ccol, axis=0, keepdims=True)
+        def slot(a, carry):
+            row = pool0 + page_ref[live[k * group + a]]
+
+            def part(p, carry):
+                at = pl.ds(pl.multiple_of(p * w, _SSM_COLS), w)
+                here = buf.at[half, a, p]
+                copy = pltpu.make_async_copy(
+                    *((here, pool_out.at[row, :, at]) if back
+                      else (pool_ref.at[row, :, at], here)),
+                    sem.at[half, int(back)])
+                copy.start() if start else copy.wait()
+                return carry
+
+            return lax.fori_loop(0, parts, part, carry)
+
+        lax.fori_loop(0, count, slot, 0)
+
+    def multiply(k, count):
+        half = k % 2
+
+        def slot(a, carry):
+            s = live[k * group + a]
+            bcol = jnp.broadcast_to(b_ref[pl.ds(s, 1), :], (n, n)).T
+            ccol = jnp.broadcast_to(c_ref[pl.ds(s, 1), :], (n, n)).T
+            # a row of a slot only the call knows is loaded WHOLE:
+            # Mosaic takes no lane slice at a dynamic sublane
+            dec[...] = decay_ref[pl.ds(s, 1), :]
+            dxs[...] = dx_ref[pl.ds(s, 1), :]
+
+            def part(p, carry):
+                def column(j, carry):
+                    o = pl.multiple_of(j * _SSM_COLS, _SSM_COLS)
+                    at = pl.ds(pl.multiple_of(p * w + o, _SSM_COLS),
+                               _SSM_COLS)
+                    here = pl.ds(o, _SSM_COLS)
+                    new = (jnp.broadcast_to(dec[:, at], (n, _SSM_COLS))
+                           * buf[half, a, p, :, here]
+                           + bcol * jnp.broadcast_to(dxs[:, at],
+                                                     (n, _SSM_COLS)))
+                    buf[half, a, p, :, here] = new
+                    ys[:, at] = jnp.sum(new * ccol, axis=0, keepdims=True)
+                    return carry
+
+                return lax.fori_loop(0, w // _SSM_COLS, column, carry)
+
+            lax.fori_loop(0, parts, part, 0)
+            y_ref[pl.ds(s, 1), :] = ys[...]
             return carry
 
-        lax.fori_loop(0, cols // _SSM_COLS, column, 0)
+        lax.fori_loop(0, count, slot, 0)
 
-    @pl.when(act_ref[pl.program_id(0)] == 0)
-    def _():
-        y_ref[...] = jnp.zeros_like(y_ref)
+    _take_turns(_list_live(act_ref, live), group, copies, multiply)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _ssm_decode_call(b, c, decay, dx, pool, li, pages, active, interpret):
+@functools.partial(jax.jit, static_argnames=("group", "parts", "interpret"))
+def _ssm_decode_call(b, c, decay, dx, pool, li, pages, active, group, parts,
+                     interpret):
     """ONE lowering for every Mamba layer of a step (the layer index is
     a scalar operand), as :func:`_paged_decode_call`."""
     n_s, n = b.shape
     cols = dx.shape[1]
-    act = active.astype(jnp.int32)
-    # an inactive slot rides the block of the nearest live slot before
-    # it, or of the first live slot when none precedes it
-    idx = jnp.arange(n_s, dtype=jnp.int32)
-    last = lax.cummax(jnp.where(act != 0, idx, -1))
-    src = jnp.where(last >= 0, last, jnp.argmax(act).astype(jnp.int32))
-    page = jnp.where(jnp.any(act != 0), pages.astype(jnp.int32)[src], 0)
-
-    def pooled(s, li_ref, page_ref, act_ref):
-        return (li_ref[0], page_ref[s], 0, 0)
-
-    def row(s, *_):
-        return (s, 0, 0)
-
-    vec = pl.BlockSpec((None, 1, n), row)
-    lanes = pl.BlockSpec((None, 1, cols), row)
-    state = pl.BlockSpec((None, None, n, cols), pooled)
-    y, pool = pl.pallas_call(
-        functools.partial(_ssm_decode_kernel, n=n, cols=cols),
-        out_shape=(jax.ShapeDtypeStruct((n_s, 1, cols), jnp.float32),
-                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n_s,),
-            in_specs=[vec, vec, lanes, lanes, state],
-            out_specs=(lanes, state)),
-        # operands count from the scalar-prefetch ones: the pool is
-        # the 8th
+    n_l, n_p = pool.shape[:2]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    y, new = pl.pallas_call(
+        functools.partial(_ssm_decode_kernel, n=n, cols=cols, group=group,
+                          parts=parts, n_pool=n_p),
+        out_shape=(jax.ShapeDtypeStruct((n_s, cols), jnp.float32),
+                   jax.ShapeDtypeStruct((n_l * n_p, n, cols), pool.dtype)),
+        in_specs=[smem, smem, smem, vmem, vmem, vmem, vmem, hbm],
+        out_specs=(vmem, hbm),
+        scratch_shapes=[
+            pltpu.SMEM((n_s,), jnp.int32),
+            pltpu.VMEM((2, group, parts, n, cols // parts), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ] + [pltpu.VMEM((1, cols), jnp.float32)] * 3,
+        # the pool is the 8th operand
         input_output_aliases={7: 1},
-        # the unchanged-block rule above needs the grid in order
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_SSM_VMEM_BYTES),
+            vmem_limit_bytes=_ssm_vmem_bytes(n_s, n, cols, group)),
         interpret=interpret,
         name="ssm_decode",
-    )(li.reshape(1).astype(jnp.int32), page, act, b[:, None, :],
-      c[:, None, :], decay[:, None, :], dx[:, None, :], pool)
-    return y[:, 0], pool
+    )(li.reshape(1).astype(jnp.int32), pages.astype(jnp.int32),
+      active.astype(jnp.int32), b, c, decay, dx,
+      # the layers' pages end to end: a bitcast, not a copy
+      pool.reshape(n_l * n_p, n, cols))
+    return y, new.reshape(pool.shape)
 
 
-#: the state block in and out, double-buffered (4 x 2.1 MB at 128 x
-#: 4096), and room for the compiler's own
-_SSM_VMEM_BYTES = 32 * 1024 * 1024
+def _ssm_vmem_bytes(n_s: int, n: int, cols: int, group: int) -> int:
+    """The kernel's VMEM: two groups of states (2 x 8 x 2.1 MB at 128 x
+    4096), ``decay``, ``dx`` and the output whole (3 x 1 MB at 64
+    slots), ``B`` and ``C``, the staged rows, and 8 MB for the
+    compiler's own (the two transposes' tiles)."""
+    rows = -(-n_s // 8) * 8
+    return (4 * (2 * group * n * cols + 3 * rows * cols + 2 * rows * n
+                 + 3 * 8 * cols) + (8 << 20))
 
 
 def _reference_ssm_decode(b, c, decay, dx, pool, li, pages, active):
@@ -2209,6 +2387,7 @@ def ssm_decode(x, b, c, delta, a_neg, d_skip, pool, li, pages, active):
     kernel does not take (:func:`_use_ssm_kernel`) run
     :func:`_reference_ssm_decode`."""
     from deeplearning4j_tpu.obs import devtime
+    from deeplearning4j_tpu.perf import sentry
     with devtime.scope("ops.ssm_decode"):
         p = x.shape[-1] // delta.shape[-1]
         xf = x.astype(jnp.float32)
@@ -2216,9 +2395,13 @@ def ssm_decode(x, b, c, delta, a_neg, d_skip, pool, li, pages, active):
                 jnp.repeat(jnp.exp(delta * a_neg), p, axis=-1),
                 jnp.repeat(delta, p, axis=-1) * xf)
         if _use_ssm_kernel(args[0], args[3]):
+            group, parts = _ssm_form(x.shape[0], *pool.shape[2:])
+            sentry.note_traced(
+                "ssm_decode_kernels", state_bytes=4 * pool.shape[2] * pool.shape[3],
+                state_parts=parts, state_buffers=2 * group)
             y, pool = _ssm_decode_call(
                 *args, pool, jnp.asarray(li, jnp.int32), pages, active,
-                interpret=_interpret())
+                group=group, parts=parts, interpret=_interpret())
         else:
             y, pool = _reference_ssm_decode(*args, pool, li, pages,
                                             active)

@@ -955,16 +955,30 @@ def _retention_case(rng, d, n_slots=4, n_kv=2, groups=3, pages=6,
     return q, k, v, g, (s_pool, z_pool)
 
 
-@pytest.mark.parametrize("active", [
-    (True, False, True, True), (False, True, False, False),
-    (False, False, False, False), (True, True, True, True)],
-    ids=["gap", "one", "none", "all"])
-@pytest.mark.parametrize("d", [16, 128])
-def test_retention_decode_matches_reference(rng, d, active):
+_RETENTION_WALKS = {
+    "gap": (True, False, True, True), "one": (False, True, False, False),
+    "none": (False,) * 4, "all": (True,) * 4,
+    "last": (False, False, False, True),
+    "first-dead": (False, True, True, True),
+    "apart": (True, False, True, False),
+    # slots 0 and 1 lie on pages 3 and 2
+    "neighbours": (True, True, False, False)}
+
+
+@pytest.mark.parametrize("d,group,active", [
+    pytest.param(d, group, active, id=f"{d}-g{group}-{walk}")
+    for d, groups in ((16, (1, 2, 3, 5)), (128, (2,)))
+    for group in groups for walk, active in _RETENTION_WALKS.items()
+    # the lane-wide head is slow in interpret mode: its first four walks
+    if d == 16 or walk in ("gap", "one", "none", "all")])
+def test_retention_decode_matches_reference(rng, d, group, active):
     """The kernel (interpret mode) against the registered fallback,
-    layer 1 of 2: outputs, the live slots' updated pages, and every
-    other page of the pool bit for bit (an inactive slot's state is
-    neither read nor written, nor is any page the step does not own)."""
+    layer 1 of 2, at every number of (slot, head) items a phase (groups
+    that divide the live items and groups that do not): outputs, the
+    live slots' pages updated where they lie, and every other page of
+    the pool bit for bit (an inactive slot's state is neither read nor
+    written, nor is the trash page or any page the step does not
+    own)."""
     n_kv, groups = (1, 2) if d == 128 else (2, 3)
     q, k, v, g, pool = _retention_case(rng, d, n_kv=n_kv, groups=groups)
     pages = jnp.asarray([3, 2, 1, 4], jnp.int32)
@@ -974,7 +988,7 @@ def test_retention_decode_matches_reference(rng, d, active):
         *args, pool, 1, pages, act, 1e-6)
     yk, sk, zk = pk._retention_decode_call(
         *args, *pool, jnp.asarray(1, jnp.int32), pages, act, eps=1e-6,
-        interpret=True)
+        group=group, interpret=True)
     live = np.asarray(pages)[np.asarray(active)]
     scale = float(jnp.abs(yr).max()) + 1.0
     assert float(jnp.abs(yk - yr).max()) < 2e-5 * scale
@@ -983,7 +997,7 @@ def test_retention_decode_matches_reference(rng, d, active):
         if len(live):
             np.testing.assert_allclose(new[1, live], ref[1, live],
                                        rtol=1e-5, atol=1e-5)
-        rest = [p for p in range(1, 6) if p not in set(live.tolist())]
+        rest = [p for p in range(6) if p not in set(live.tolist())]
         np.testing.assert_array_equal(new[1, rest], old[1, rest])
         np.testing.assert_array_equal(new[0], old[0])   # other layer
 
@@ -1015,3 +1029,34 @@ def test_retention_decode_dispatch_line(monkeypatch, rng):
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     assert run(128) == 1                    # over the line: the kernel
     assert run(16) == 1                     # head under 128 lanes
+
+
+def test_a_traced_program_says_the_retention_kernel_s_form(monkeypatch,
+                                                           rng):
+    """As ``ops.moe.experts`` says which form of an expert layer a
+    program holds: the sentried program's ``compile/jaxpr_trace``
+    record counts the retention kernel's calls and carries a state's
+    bytes, the copies a state and the states of buffers in VMEM."""
+    from deeplearning4j_tpu.obs import trace
+    from deeplearning4j_tpu.perf import sentry
+    from deeplearning4j_tpu.ops import retention
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    q, k, v, g, pool = _retention_case(rng, 128, n_slots=2, n_kv=1,
+                                       groups=2, pages=3)
+    pages = jnp.asarray([2, 1], jnp.int32)
+    act = jnp.asarray([True, False])
+    t0 = trace.now()
+    sentry.jit(lambda *a: pk.retention_decode(*a[:4], a[4:6], 0, *a[6:]),
+               name="test.retention_layer")(q, k, v, g, *pool, pages, act)
+    said = [r.counts for r in trace.records(t0)
+            if r.name == "compile/jaxpr_trace"
+            and r.cause == "test.retention_layer" and r.counts
+            and "retention_decode_kernels" in r.counts]
+    assert len(said) == 1, said
+    rows = retention.state_rows(128)
+    assert {k: said[0][k] for k in (
+        "retention_decode_kernels", "state_bytes", "state_parts",
+        "state_buffers")} == {
+            "retention_decode_kernels": 1,
+            "state_bytes": 4 * (rows + 128) * 128, "state_parts": 1,
+            "state_buffers": 8}

@@ -219,21 +219,41 @@ def _decode_case(rng, n_s, active, layers=2, n=128, heads=4, p=64):
             jnp.arange(1, n_s + 1), jnp.asarray(active))
 
 
+#: (slots a phase, copies a slot) at the test's shapes (5 slots of
+#: [128, 256]): what the two constants give, and every other value
+#: they may: one or two 128-lane parts, groups that divide the live
+#: slots and groups that do not
+_SSM_FORMS = {"as-shaped": None, "g1-p2": (1, 2), "g2-p2": (2, 2),
+              "g2-p1": (2, 1), "g3-p1": (3, 1)}
+
+
+@pytest.mark.parametrize("form", list(_SSM_FORMS))
 @pytest.mark.parametrize("active", [
     (True,) * 5, (False, True, True, False, True),
-    (False, False, True, False, False), (False,) * 5],
-    ids=["all", "first-idle", "one", "none"])
-def test_ssm_decode_matches_reference(monkeypatch, rng, active):
+    (False, False, True, False, False), (False,) * 5,
+    (False, False, False, False, True), (True, False, True, False, True),
+    (False, True, True, False, False)],
+    ids=["all", "first-idle", "one", "none", "last", "apart",
+         "neighbours"])
+def test_ssm_decode_matches_reference(monkeypatch, rng, active, form):
     """The kernel (interpret mode) against the registered fallback,
-    layer 1 of 2: outputs, the live slots' pages, and every page the
-    call must not touch (the other layer, an inactive slot's) bit for
-    bit."""
+    layer 1 of 2, in every form its two constants can give it at these
+    shapes: outputs, the live slots' pages (updated where they lie),
+    and every page the call must not touch (the other layer, an
+    inactive slot's, the trash page) bit for bit."""
     args = _decode_case(rng, 5, active)
     pool, live = args[6], np.asarray(active)
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "0")
     y_ref, p_ref = pk.ssm_decode(*args)
     monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
     assert pk._use_ssm_kernel(args[1], args[0])
+    if _SSM_FORMS[form] is None:
+        assert pk._ssm_form(5, 128, 256) == (5, 1)
+    else:
+        group, parts = _SSM_FORMS[form]
+        monkeypatch.setattr(pk, "_SSM_PHASE_BYTES", group * 4 * 128 * 256)
+        monkeypatch.setattr(pk, "_SSM_PART_COLS", 256 // parts)
+        assert pk._ssm_form(5, 128, 256) == (group, parts)
     y, new = pk.ssm_decode(*args)
     np.testing.assert_allclose(y, y_ref, atol=2e-5)
     assert (np.asarray(y)[~live] == 0).all()
@@ -243,6 +263,7 @@ def test_ssm_decode_matches_reference(monkeypatch, rng, active):
     np.testing.assert_array_equal(new[0], pool[0])
     np.testing.assert_array_equal(new[1, pages[~live]],
                                   pool[1, pages[~live]])
+    np.testing.assert_array_equal(new[1, 0], pool[1, 0])    # the trash
     # the recurrence itself, in the stored layout
     decay = np.repeat(np.exp(np.asarray(args[3] * args[4])), 64, -1)
     dx = np.repeat(np.asarray(args[3]), 64, -1) * np.asarray(args[0])
@@ -258,6 +279,39 @@ def test_shapes_the_ssm_kernel_does_not_take_run_the_fallback(
     assert not pk._use_ssm_kernel(args[1], args[0])
     y, new = pk.ssm_decode(*args)
     assert np.isfinite(np.asarray(y)).all() and (np.asarray(y)[1] == 0).all()
+
+
+def test_a_traced_program_says_the_state_kernel_s_form(monkeypatch, rng):
+    """The kernel is chosen once a program, at trace time: the
+    ``compile/jaxpr_trace`` record of the sentried program carries how
+    many kernel calls it holds and their form (a state's bytes, the
+    copies a state, the states of buffers in VMEM); a program that
+    keeps the fallback says nothing."""
+    from deeplearning4j_tpu.obs import trace
+    from deeplearning4j_tpu.perf import sentry
+    args = _decode_case(rng, 5, (True, False, True, True, True))
+
+    def two_layers(*a):
+        y, pool = pk.ssm_decode(*a)
+        return pk.ssm_decode(*a[:6], pool, 0, *a[8:])
+
+    def said(name):
+        t0 = trace.now()
+        sentry.jit(two_layers, name=name)(*args)
+        return [r.counts for r in trace.records(t0)
+                if r.name == "compile/jaxpr_trace" and r.cause == name
+                and r.counts and "ssm_decode_kernels" in r.counts]
+
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "0")
+    assert said("test.two_ssm_layers.fallback") == []
+    monkeypatch.setenv("DL4J_TPU_KERNEL_FORCE", "1")
+    notes = said("test.two_ssm_layers")
+    assert len(notes) == 1, notes
+    assert {k: notes[0][k] for k in (
+        "ssm_decode_kernels", "state_bytes", "state_parts",
+        "state_buffers")} == {
+            "ssm_decode_kernels": 2, "state_bytes": 4 * 128 * 256,
+            "state_parts": 1, "state_buffers": 10}
 
 
 _PACKED_WALKS = ("first-inactive", "inactive-between", "none-live",
